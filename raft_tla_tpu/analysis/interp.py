@@ -49,8 +49,9 @@ import numpy as np
 
 _I64 = np.int64
 
-# Call-like primitives whose single inner jaxpr is evaluated inline.
-_CALL_PRIMS = ("pjit", "closed_call", "core_call", "remat", "checkpoint",
+# Call-like primitives whose single inner jaxpr is evaluated inline
+# ("jit" is what jax 0.9 names the primitive of a nested jax.jit).
+_CALL_PRIMS = ("jit", "closed_call", "core_call", "remat", "checkpoint",
                "custom_jvp_call", "custom_vjp_call")
 
 
@@ -70,18 +71,6 @@ def _out_aval(eqn, k: int = 0):
     return eqn.outvars[k].aval
 
 
-@functools.lru_cache(maxsize=1)
-def _literal_cls():
-    """``Literal`` moved to ``jax.extend.core`` (~0.4.35) and the
-    ``jax.core`` alias is removed in jax >= 0.6 — the CI analyze job
-    installs unpinned ``jax[cpu]``, so resolve it lazily."""
-    try:
-        from jax.extend.core import Literal
-    except ImportError:        # older jax without jax.extend.core
-        from jax.core import Literal
-    return Literal
-
-
 # ---------------------------------------------------------------------------
 # Shared evaluator
 
@@ -89,11 +78,12 @@ def _literal_cls():
 def eval_jaxpr(closed, args: list, domain) -> list:
     """Evaluate a ClosedJaxpr under ``domain``.  ``args`` are domain
     values (or anything ``domain.lift`` accepts) for the invars."""
+    from jax.extend.core import Literal
     jaxpr = closed.jaxpr
     env: Dict = {}
 
     def read(atom):
-        if isinstance(atom, _literal_cls()):
+        if isinstance(atom, Literal):
             return domain.lift(np.asarray(atom.val))
         return env[atom]
 

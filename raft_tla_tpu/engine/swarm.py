@@ -421,26 +421,11 @@ class SwarmEngine:
         # engine build.
         self._perf = None
         if perf:
-            from ..models.schema import state_width
             from ..obs import perf as perf_mod
-            B = self.batch
-            sw = state_width(dims)
-            i32 = jax.ShapeDtypeStruct((), jnp.int32)
-            u32 = jax.ShapeDtypeStruct((), jnp.uint32)
-            li32 = jax.ShapeDtypeStruct((B,), jnp.int32)
-            avals = (jax.ShapeDtypeStruct((B, sw), jnp.uint8),
-                     jax.ShapeDtypeStruct((2, sw), jnp.uint8),
-                     li32, li32,
-                     jax.ShapeDtypeStruct((B, max_depth), jnp.int32),
-                     jax.ShapeDtypeStruct((B, ring), jnp.uint32),
-                     jax.ShapeDtypeStruct((B, ring), jnp.uint32),
-                     li32, li32, li32, u32, i32, i32)
-            if hunt:
-                bl = jax.ShapeDtypeStruct((self.hunt_cells,), jnp.uint8)
-                avals = avals + (bl, bl)
             self._perf = perf_mod.build_accounting(
                 pipeline="swarm", chunk_fn=self._chunk,
-                chunk_avals=avals, dims=dims, B=B, K=dims.n_instances,
+                chunk_avals=self.chunk_avals(), dims=dims,
+                B=self.batch, K=dims.n_instances,
                 ring=ring, swarm_pipeline=self.pipeline_name,
                 metrics=self.metrics, engine="swarm")
         self._xla_chunks = xla_profile_chunks
@@ -458,6 +443,26 @@ class SwarmEngine:
         self._expand1 = jax.jit(build_expand(dims))
         self._fp1 = jax.jit(build_fingerprint(dims))
         self._last_trace: Optional[List[Tuple[int, PyState]]] = None
+
+    def chunk_avals(self, n_roots: int = 2) -> tuple:
+        """The walk chunk program's arguments as shapes (one full-width
+        slice of ``self.batch`` lanes): what the launch model traces and
+        what a compile for a described chip lowers."""
+        from ..models.schema import state_width
+        B, sw = self.batch, state_width(self.dims)
+        i32 = jax.ShapeDtypeStruct((), jnp.int32)
+        u32 = jax.ShapeDtypeStruct((), jnp.uint32)
+        li32 = jax.ShapeDtypeStruct((B,), jnp.int32)
+        ring = jax.ShapeDtypeStruct((B, self.ring), jnp.uint32)
+        avals = (jax.ShapeDtypeStruct((B, sw), jnp.uint8),
+                 jax.ShapeDtypeStruct((n_roots, sw), jnp.uint8),
+                 li32, li32,
+                 jax.ShapeDtypeStruct((B, self.max_depth), jnp.int32),
+                 ring, ring, li32, li32, li32, u32, i32, i32)
+        if self.hunt:
+            bl = jax.ShapeDtypeStruct((self.hunt_cells,), jnp.uint8)
+            avals = avals + (bl, bl)
+        return avals
 
     # -- explain.py duck-type surface ----------------------------------
     def replay(self, fp: int) -> List[Tuple[int, PyState]]:

@@ -3,17 +3,22 @@
 The checker's device pipeline is JAX/XLA; the host-side runtime pieces that
 TLC implements natively (trace store; checkpoint IO helpers) are C++ here
 too, built on first use with the ambient ``g++`` into a shared library next
-to the sources.  Everything degrades gracefully: if no compiler is available
-the pure-Python fallbacks in ``engine/trace.py`` are used instead.
+to the sources.  The library is trusted only when the content hash stored
+beside it equals the hash of the tracked sources, so a copied or restored
+tree never runs a library built from something else.  On a host without a
+compiler ``load()`` says why on stderr and returns None, and the engines
+use the pure-Python store in ``engine/trace.py``.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import threading
-from typing import Optional
+from typing import List, Optional
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_HERE, "libraftnative.so")
@@ -23,30 +28,53 @@ _LIB = None
 _TRIED = False
 
 
-def _build() -> bool:
-    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-           "-o", _SO] + _SRC
+def source_digest(src: List[str]) -> str:
+    h = hashlib.sha256()
+    for p in src:
+        with open(p, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def build_if_stale(src: List[str], so: str) -> bool:
+    """Build ``so`` from ``src`` unless ``so`` exists and the digest
+    stored beside it (``<so>.sha256``) equals the sources' content hash.
+    Returns whether it built; raises when the compiler fails."""
+    stamp = so + ".sha256"
+    digest = source_digest(src)
+    if os.path.exists(so) and os.path.exists(stamp):
+        with open(stamp) as f:
+            if f.read().strip() == digest:
+                return False
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except (OSError, subprocess.SubprocessError):
-        return False
+        subprocess.run(["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
+                        "-o", tmp] + src,
+                       check=True, capture_output=True, timeout=120)
+        os.replace(tmp, so)     # atomic: concurrent loaders see old or new
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    with open(stamp, "w") as f:
+        f.write(digest + "\n")
+    return True
 
 
 def load() -> Optional[ctypes.CDLL]:
-    """The shared library, building it if needed; None if unavailable."""
+    """The shared library, (re)built if stale; None if unavailable."""
     global _LIB, _TRIED
     with _LOCK:
         if _LIB is not None or _TRIED:
             return _LIB
         _TRIED = True
-        newest_src = max(os.path.getmtime(p) for p in _SRC)
-        if not os.path.exists(_SO) or os.path.getmtime(_SO) < newest_src:
-            if not _build():
-                return None
         try:
+            build_if_stale(_SRC, _SO)
             lib = ctypes.CDLL(_SO)
-        except OSError:
+        except (OSError, subprocess.SubprocessError) as e:
+            err = getattr(e, "stderr", b"") or b""
+            print(f"native: trace store unavailable ({type(e).__name__}: "
+                  f"{e}) {err.decode(errors='replace')[-400:]}",
+                  file=sys.stderr)
             return None
         u64p = ctypes.POINTER(ctypes.c_uint64)
         i32p = ctypes.POINTER(ctypes.c_int32)

@@ -1,7 +1,7 @@
 """Launch accounting + the per-run performance block (``--perf``).
 
 The engine's known bottleneck is kernel granularity — hundreds of small
-launches per chunk against a ~0.1-0.3 ms bandwidth floor (NORTHSTAR §c)
+launches per chunk against a ~0.1-0.3 ms bandwidth floor
 — yet no telemetry leg could attribute time to launches.  This module
 closes that gap with a **static launch model** plus a cheap dynamic
 feed:

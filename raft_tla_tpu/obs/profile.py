@@ -730,7 +730,7 @@ class XlaProfileCapture:
 
     Observational and fail-soft: the capture never changes what the
     engine computes, and a profiler that cannot start (unsupported
-    backend, missing permissions over a tunnel) records its failure in
+    backend, missing permissions) records its failure in
     the ``xla_profile`` event instead of killing the run.
     """
 

@@ -71,13 +71,12 @@ _WINDOW_RMW = frozenset(("scatter", "scatter-add", "scatter_add",
 
 def peak_bandwidth() -> Dict[str, object]:
     """{"bytes_per_sec", "source"} for the first visible device.
-    RAFT_PEAK_GBPS (GB/s) overrides; unknown accelerators fall back to
-    the assumed-CPU figure with a source that says so."""
+    RAFT_PEAK_GBPS (GB/s) overrides; an accelerator whose
+    ``device_kind`` is not in :data:`PEAK_BW_TABLE` raises — the CPU
+    figure is never lent to a device it does not describe."""
     env = os.environ.get("RAFT_PEAK_GBPS")
     if env:
-        # Malformed override falls through to detection: this runs
-        # inside the engines' fail-soft perf build AND its fallback
-        # handler, so raising here would fail the engine build.
+        # A malformed override falls through to detection.
         try:
             return {"bytes_per_sec": float(env) * 1e9,
                     "source": "RAFT_PEAK_GBPS override"}
@@ -85,23 +84,26 @@ def peak_bandwidth() -> Dict[str, object]:
             import sys
             print(f"perf: ignoring malformed RAFT_PEAK_GBPS={env!r} "
                   f"(want GB/s as a number)", file=sys.stderr)
-    kind, platform = "", "cpu"
-    try:
-        import jax
-        dev = jax.devices()[0]
-        kind = (getattr(dev, "device_kind", "") or "").lower()
-        platform = dev.platform
-    except Exception:
-        pass
-    if platform not in ("cpu",):
-        for sub, bw in PEAK_BW_TABLE:
-            if sub in kind:
-                return {"bytes_per_sec": bw,
-                        "source": f"datasheet ({kind or platform})"}
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
         return {"bytes_per_sec": CPU_ASSUMED_BW,
-                "source": f"assumed (unknown accelerator {kind!r})"}
-    return {"bytes_per_sec": CPU_ASSUMED_BW,
-            "source": "assumed (cpu ddr-class)"}
+                "source": "assumed (cpu ddr-class)"}
+    return {"bytes_per_sec": peak_bandwidth_for_kind(dev.device_kind),
+            "source": f"datasheet ({dev.device_kind})"}
+
+
+def peak_bandwidth_for_kind(device_kind: str) -> float:
+    """Datasheet peak HBM bytes/s for an accelerator's ``device_kind``;
+    raises for a kind :data:`PEAK_BW_TABLE` does not list."""
+    kind = (device_kind or "").lower()
+    for sub, bw in PEAK_BW_TABLE:
+        if sub in kind:
+            return bw
+    raise ValueError(
+        f"no peak bandwidth known for accelerator device_kind "
+        f"{device_kind!r}: add it to obs/roofline.py PEAK_BW_TABLE or "
+        f"set RAFT_PEAK_GBPS")
 
 
 # ---------------------------------------------------------------------------

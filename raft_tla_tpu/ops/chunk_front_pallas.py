@@ -29,10 +29,11 @@ Bit-identity: the converted bodies ARE the jaxprs of the same jnp model
 functions the XLA path runs (models/actions2.py masks/lane_out,
 models/schema.py flatten/unflatten, models/invariants.py dispatch) on
 the same values.  In interpret mode (CPU) executing them is executing
-those ops, so v4-vs-v2 engine differentials hold exactly; on TPU a
-Mosaic lowering that rejects the gather-heavy body degrades the whole
-front group back to the v3-style split stages at plan time
-(ops/pipeline_v4.py build-and-probe — fallback is the contract).
+those ops, so v4-vs-v2 engine differentials hold exactly.  On the TPU
+Mosaic rejects the gather-heavy body today (models/actions2.py
+``last_term``: "Shape mismatch in input, indices and output";
+tests/test_chip_compile.py pins it), and the v4 build fails there with
+that message (ops/pipeline_v4.py build-and-probe).
 
 Outputs mirror engine/chunk.py's front section exactly: the
 post-progress-limit enabled/overflow masks, the pre-progress-limit POR

@@ -8,7 +8,7 @@ NORTHSTAR.md §c).  This kernel keeps the whole mask VMEM-resident and
 compacts it with ONE sequential in-register scan: per flat candidate
 lane, append its index to the next free survivor slot.  No scatter, no
 sort, no intermediate HBM traffic — the formulation the fused-chunk
-decision rule (NORTHSTAR §d) wants priced next to both XLA lowerings.
+decision rule wants priced next to both XLA lowerings.
 
 Outputs are bit-identical to ``ops.compact.build_compactor`` (both
 methods; they agree by construction): ``(P, total, lane_id, kvalid)``
@@ -18,7 +18,7 @@ survivor order, and the same hash-spread addresses in dead slots.
 The sequential scan is priced for TPU VMEM residency; in interpret mode
 (CPU) it emulates at Python-traced-loop speed, so the v3 plan
 (ops/pipeline_v3.py) only selects it off-TPU when a test forces it —
-the automatic per-stage fallback keeps CPU runs on the XLA compactor.
+the platform policy keeps CPU runs on the XLA compactor.
 
 ``reduce_p`` (the mesh engine's pmin hook) is deliberately NOT
 supported: a cross-chip collective cannot live inside a Pallas stage,

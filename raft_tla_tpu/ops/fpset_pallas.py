@@ -1,11 +1,11 @@
 """Pallas probe/insert for the FPSet — the fused-chunk experiment's stage 1.
 
-Motivation (NORTHSTAR.md §c/§d): once the v2 delta pipeline removes the
+Motivation: once the v2 delta pipeline removes the
 expand/materialize cost, the measured TPU chunk's dominant residue is the
 hash insert (5.3 ms, *including* the dedup sort) and the enqueue scatter
 (14.5 ms) — and the whole chunk sits ~100× above the HBM bandwidth floor
 because it is hundreds of separate XLA kernels.  The decision rule for
-attacking that (NORTHSTAR §d item 3) is a single fused Pallas chunk; this
+attacking that is a single fused Pallas chunk; this
 module is its first, independently-testable stage: the table insert as ONE
 Pallas kernel.
 
@@ -38,9 +38,9 @@ Table reads/writes go through single-element async copies (the table
 lives in HBM; TPU has no vector gather from HBM — XLA's own gather is a
 DMA loop underneath).  The kernel is therefore also the *measurement
 instrument* for Mosaic's scalar-DMA round-trip cost, the number that
-decides whether the fully-fused chunk kernel (NORTHSTAR §d) is viable:
-the staged profile matrix (scripts/tpu_session.sh) times it next to the
-XLA insert on the same batch.
+decides whether the fully-fused chunk kernel is viable:
+scripts/profile_step.py times it next to the XLA insert on the same
+batch.
 
 Bit-identity is proven on CPU via interpret mode (`tests/test_fpset.py`,
 `tests/test_engine.py`); `interpret` defaults to automatic (real lowering
@@ -60,7 +60,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .fingerprint import SENTINEL
 from .fpset import FPSet, PROBE_ROUNDS, _pad_pow2, _probe_base
-from .pallas_compat import tpu_compiler_params
 
 _U32 = jnp.uint32
 _I32 = jnp.int32
@@ -86,26 +85,26 @@ def probe_insert_query(hi_ref, lo_ref, scr, sem, qh, ql, pending0,
         idx = ((h1 + step * h2) & _U32(c_mask)).astype(_I32)
         # Fetch the slot (4 B each lane of the key pair).
         rd_hi = pltpu.make_async_copy(
-            hi_ref.at[pl.ds(idx, 1)], scr.at[0], sem.at[0])
+            hi_ref.at[pl.ds(idx, 1)], scr.at[pl.ds(0, 1)], sem.at[0])
         rd_lo = pltpu.make_async_copy(
-            lo_ref.at[pl.ds(idx, 1)], scr.at[1], sem.at[1])
+            lo_ref.at[pl.ds(idx, 1)], scr.at[pl.ds(1, 1)], sem.at[1])
         rd_hi.start()
         rd_lo.start()
         rd_hi.wait()
         rd_lo.wait()
-        cur_hi = scr[0, 0]
-        cur_lo = scr[1, 0]
+        cur_hi = scr[0]
+        cur_lo = scr[1]
         is_match = (cur_hi == qh) & (cur_lo == ql)
         is_empty = (cur_hi == SENTINEL) & (cur_lo == SENTINEL)
         # Branch-free write-back: claim the slot when empty, else rewrite
         # the value just read (a no-op).  Unconditional DMA sidesteps
         # predicated-DMA lowering; sequential grid order makes it race-free.
-        scr[0, 0] = jnp.where(is_empty, qh, cur_hi)
-        scr[1, 0] = jnp.where(is_empty, ql, cur_lo)
+        scr[0] = jnp.where(is_empty, qh, cur_hi)
+        scr[1] = jnp.where(is_empty, ql, cur_lo)
         wr_hi = pltpu.make_async_copy(
-            scr.at[0], hi_ref.at[pl.ds(idx, 1)], sem.at[0])
+            scr.at[pl.ds(0, 1)], hi_ref.at[pl.ds(idx, 1)], sem.at[0])
         wr_lo = pltpu.make_async_copy(
-            scr.at[1], lo_ref.at[pl.ds(idx, 1)], sem.at[1])
+            scr.at[pl.ds(1, 1)], lo_ref.at[pl.ds(idx, 1)], sem.at[1])
         wr_hi.start()
         wr_lo.start()
         wr_hi.wait()
@@ -127,13 +126,13 @@ def probe_insert_query(hi_ref, lo_ref, scr, sem, qh, ql, pending0,
     return newf, pending
 
 
-def _kernel(qhi_ref, qlo_ref, valid_ref,   # [BLK] VMEM in blocks
+def _kernel(qhi_ref, qlo_ref, valid_ref,   # [BLK] SMEM in blocks
             hi_in, lo_in,                  # [C] ANY in (aliased to outputs)
             hi_ref, lo_ref,                # [C] ANY out — the same buffers;
                                            # all reads+writes go through these
-            new_ref,                       # [BLK] VMEM out block
-            fail_ref,                      # [1] out, revisited by all programs
-            scr, sem,                      # VMEM (2,1) u32 scratch + 2 DMA sems
+            new_ref,                       # [BLK] SMEM out block
+            fail_ref,                      # [1] SMEM out, revisited by all
+            scr, sem,                      # SMEM (2,) u32 scratch + 2 DMA sems
             *, c_mask: int, rounds: int):
     del hi_in, lo_in
     @pl.when(pl.program_id(0) == 0)
@@ -168,18 +167,21 @@ def _insert_padded(s: FPSet, qhi, qlo, valid, interpret: bool):
     hi, lo, is_new, fail = pl.pallas_call(
         kern,
         grid=(grid,),
+        # Every per-query value is read and written as a scalar, so the
+        # query blocks, the novelty block and the fail flag live in SMEM
+        # (Mosaic stores no scalars to VMEM).
         in_specs=[
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((blk,), lambda i: (i,)),
+            pl.BlockSpec((blk,), lambda i: (i,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((blk,), lambda i: (i,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((blk,), lambda i: (i,), memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            pl.BlockSpec((blk,), lambda i: (i,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((1,), lambda i: (0,), memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((c,), jnp.uint32),
@@ -188,11 +190,11 @@ def _insert_padded(s: FPSet, qhi, qlo, valid, interpret: bool):
             jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, 1), jnp.uint32),
+            pltpu.SMEM((2,), jnp.uint32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
         input_output_aliases={3: 0, 4: 1},
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             has_side_effects=True),
         interpret=interpret,

@@ -42,21 +42,20 @@ from jax.experimental.pallas import tpu as pltpu
 from .fingerprint import SENTINEL
 from .fpset import FPSet, PROBE_ROUNDS, _pad_pow2
 from .fpset_pallas import _BLOCK, probe_insert_query
-from .pallas_compat import tpu_compiler_params
 
 _U32 = jnp.uint32
 _I32 = jnp.int32
 
 
-def _kernel(qhi_ref, qlo_ref, valid_ref, enq_ref,   # [BLK] VMEM in blocks
+def _kernel(qhi_ref, qlo_ref, valid_ref, enq_ref,   # [BLK] SMEM in blocks
             nc_ref,                                 # [1] SMEM: next_count
             hi_in, lo_in,                           # [C] ANY (aliased)
             krows_ref,                              # [KP,SW] ANY in
             q_in,                                   # [QA,SW] ANY (aliased)
             hi_ref, lo_ref,                         # [C] ANY out
             q_ref,                                  # [QA,SW] ANY out
-            new_ref,                                # [BLK] VMEM out block
-            fail_ref, cnt_ref,                      # [1] outs, revisited
+            new_ref,                                # [BLK] SMEM out block
+            fail_ref, cnt_ref,                      # [1] SMEM outs, revisited
             scr, sem, rsem,                         # scratch + DMA sems
             *, c_mask: int, rounds: int, blk: int, trash_base: int):
     del hi_in, lo_in, q_in
@@ -66,9 +65,6 @@ def _kernel(qhi_ref, qlo_ref, valid_ref, enq_ref,   # [BLK] VMEM in blocks
         fail_ref[0] = _I32(0)
         cnt_ref[0] = nc_ref[0]
 
-    # Bound OUTSIDE the query loop: jax 0.4.x interpret mode cannot
-    # evaluate the program_id primitive once it is staged into an inner
-    # while jaxpr.
     gbase = pl.program_id(0) * blk
 
     def one_query(i, local_fail):
@@ -112,11 +108,13 @@ def _tail_padded(s: FPSet, qhi, qlo, valid, enq_ok, krows, qnext,
     hi, lo, q_out, is_new, fail, _cnt = pl.pallas_call(
         kern,
         grid=(grid,),
+        # Per-query values are scalars: SMEM blocks, as in
+        # ops/fpset_pallas.py (Mosaic stores no scalars to VMEM).
         in_specs=[
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((blk,), lambda i: (i,)),
+            pl.BlockSpec((blk,), lambda i: (i,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((blk,), lambda i: (i,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((blk,), lambda i: (i,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((blk,), lambda i: (i,), memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
@@ -127,9 +125,9 @@ def _tail_padded(s: FPSet, qhi, qlo, valid, enq_ok, krows, qnext,
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            pl.BlockSpec((blk,), lambda i: (i,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((1,), lambda i: (0,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((1,), lambda i: (0,), memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((c,), jnp.uint32),
@@ -140,12 +138,12 @@ def _tail_padded(s: FPSet, qhi, qlo, valid, enq_ok, krows, qnext,
             jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, 1), jnp.uint32),
+            pltpu.SMEM((2,), jnp.uint32),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA(()),
         ],
         input_output_aliases={5: 0, 6: 1, 8: 2},
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             has_side_effects=True),
         interpret=interpret,

@@ -4,7 +4,7 @@ and the Pallas stage kernels.
 The v3 pipeline is the v2 delta pipeline (models/actions2.py semantics,
 bit-identical by construction) with the chunk's stages progressively
 moved into Pallas kernels so the K-lane survivor window stops
-round-tripping to HBM between stages (NORTHSTAR.md §c/§d):
+round-tripping to HBM between stages:
 
     masks        guards-only enabled/overflow masks      [always XLA]
     compact      ops/compact_pallas.py sequential scan   [Pallas]
@@ -17,11 +17,13 @@ whole model's guard alphabet (a jaxpr program XLA already fuses into
 one kernel — a Pallas port would re-implement the spec), and the delta
 fingerprint is sparse gather arithmetic over the parent struct that
 only wins in Pallas once the struct itself is VMEM-resident (the
-staged next step).  The other stages resolve per platform/engine with
-AUTOMATIC fallback to the XLA lowering wherever a kernel cannot be
-built or probed — a v3 run never fails because one stage will not
-lower, it degrades that stage and records why (``V3Plan.stages`` /
-``reasons``, surfaced on ``EngineResult.fused_stages``).
+staged next step).  The other stages resolve per platform/engine.  On
+the TPU a Pallas kernel the compiler refuses fails the engine build
+with the compiler's message: a v3 run there either runs its kernels or
+does not run.  Off the chip (interpret mode) a stage whose kernel
+cannot be built or probed degrades to the XLA lowering and records why
+(``V3Plan.stages`` / ``reasons``, surfaced on
+``EngineResult.fused_stages``).
 
 Platform policy (overridable per stage with ``force`` for tests):
 
@@ -78,9 +80,10 @@ def resolve_plan(B: int, G: int, K: int, *, Q: int, sw: int = 8,
     on the mesh, whose collective-coupled stages are not forceable.
     Every Pallas choice is build-and-probe verified here at the REAL
     per-program shapes (the full [B, G] mask; the tail's real K-query
-    grid and sw-byte rows, over small HBM extents), so a kernel that
-    cannot construct or lower its blocks falls back NOW with a recorded
-    reason instead of failing the first chunk.  Residual risk: a
+    grid and sw-byte rows, over small HBM extents).  On the TPU a kernel
+    the compiler refuses fails the build HERE with the compiler's error
+    (see :func:`_raise_on_chip`); in interpret mode, off the chip, it
+    falls back with a recorded reason.  Residual risk: a
     lowering failure keyed to the total HBM extent (table/queue length)
     would still surface at the first chunk compile — extents are the
     one thing the probe shrinks."""
@@ -140,7 +143,8 @@ def resolve_plan(B: int, G: int, K: int, *, Q: int, sw: int = 8,
             compactor = cand
             stages["compact"] = "pallas"
             reasons.pop("compact", None)
-        except Exception as e:  # noqa: BLE001 — fallback is the contract
+        except Exception as e:  # noqa: BLE001 — interpret-mode fallback
+            _raise_on_chip(interpret)
             reasons["compact"] = (f"pallas compact failed to build/probe: "
                                   f"{type(e).__name__}: {str(e)[:160]}")
     elif "compact" not in reasons:
@@ -171,7 +175,8 @@ def resolve_plan(B: int, G: int, K: int, *, Q: int, sw: int = 8,
             _probe_tail(K, sw, interpret)
             tail = cand_tail
             stages["insert"] = stages["enqueue"] = "fused"
-        except Exception as e:  # noqa: BLE001 — fallback is the contract
+        except Exception as e:  # noqa: BLE001 — interpret-mode fallback
+            _raise_on_chip(interpret)
             reasons["insert"] = (f"fused tail failed to build/probe: "
                                  f"{type(e).__name__}: {str(e)[:160]}")
     if tail is None and "insert" not in reasons:
@@ -189,7 +194,8 @@ def resolve_plan(B: int, G: int, K: int, *, Q: int, sw: int = 8,
             try:
                 _probe_enqueue(K, sw, interpret)
                 stages["enqueue"] = "pallas"
-            except Exception as e:  # noqa: BLE001 — fallback contract
+            except Exception as e:  # noqa: BLE001 — interpret-mode fallback
+                _raise_on_chip(interpret)
                 reasons["enqueue"] = (f"pallas enqueue failed to "
                                       f"build/probe: {type(e).__name__}: "
                                       f"{str(e)[:160]}")
@@ -209,6 +215,16 @@ def resolve_plan(B: int, G: int, K: int, *, Q: int, sw: int = 8,
         launches["enqueue"] = 1
     return V3Plan(stages=stages, reasons=reasons, compactor=compactor,
                   tail=tail, enqueue_method=enq, launches=launches)
+
+
+def _raise_on_chip(interpret: bool) -> None:
+    """Called from an ``except`` block around a Pallas build/probe.  A
+    real Mosaic lowering (``interpret`` False: the platform is the TPU)
+    that was asked for and refused is an error carrying the compiler's
+    message — never a substituted XLA stage.  Only interpret mode (off
+    the chip) degrades the stage with a recorded reason."""
+    if not interpret:
+        raise
 
 
 def _probe_enqueue(K: int, sw: int, interpret: bool) -> None:

@@ -16,11 +16,12 @@ together — forcing (or failing to build) ANY of masks/compact/
 fingerprint splits the group back to the v3-style arrangement, where
 masks+fingerprint are the XLA jaxpr programs and compact resolves per
 the v3 platform policy.  The tail pair is the same fused group v3
-ships.  As everywhere else in ops/, fallback is the contract: every
-kernel is build-and-probe verified at plan time at the real per-program
-shapes, a stage that will not lower degrades with a recorded reason
-(``V4Plan.stages`` / ``reasons`` -> ``EngineResult.fused_stages``), and
-a v4 run never fails because a kernel refused to compile.
+ships.  Every kernel is build-and-probe verified at plan time at the
+real per-program shapes.  On the TPU a kernel the compiler refuses
+fails the build with the compiler's message (pipeline_v3
+``_raise_on_chip``); off the chip, in interpret mode, a stage that
+will not build degrades with a recorded reason (``V4Plan.stages`` /
+``reasons`` -> ``EngineResult.fused_stages``).
 
 Per-stage forcing comes from ``EngineConfig.v4_force_stages`` and the
 ``RAFT_V4_FORCE`` environment variable ("masks=xla,insert=xla" — env
@@ -45,7 +46,7 @@ from __future__ import annotations
 import os
 from typing import Any, Callable, Dict, NamedTuple, Optional
 
-from .pipeline_v3 import _probe_enqueue, _probe_tail
+from .pipeline_v3 import _probe_enqueue, _probe_tail, _raise_on_chip
 
 STAGES = ("masks", "compact", "fingerprint", "insert", "enqueue")
 FRONT_STAGES = ("masks", "compact", "fingerprint")
@@ -162,7 +163,8 @@ def resolve_plan(B: int, G: int, K: int, *, Q: int, sw: int = 8,
             front = cand
             for s in FRONT_STAGES:
                 stages[s] = "fused"
-        except Exception as e:  # noqa: BLE001 — fallback is the contract
+        except Exception as e:  # noqa: BLE001 — interpret-mode fallback
+            _raise_on_chip(interpret)
             degraded = (f"front kernel failed to build/probe: "
                         f"{type(e).__name__}: {str(e)[:160]}")
     if front is None:
@@ -190,7 +192,8 @@ def resolve_plan(B: int, G: int, K: int, *, Q: int, sw: int = 8,
                 jax.block_until_ready(cand(jnp.zeros((B, G), bool)))
                 compactor = cand
                 stages["compact"] = "pallas"
-            except Exception as e:  # noqa: BLE001 — fallback contract
+            except Exception as e:  # noqa: BLE001 — interpret-mode fallback
+                _raise_on_chip(interpret)
                 reasons["compact"] = (
                     f"pallas compact failed to build/probe: "
                     f"{type(e).__name__}: {str(e)[:160]}")
@@ -217,7 +220,8 @@ def resolve_plan(B: int, G: int, K: int, *, Q: int, sw: int = 8,
             _probe_tail(K, sw, interpret)
             tail = cand_tail
             stages["insert"] = stages["enqueue"] = "fused"
-        except Exception as e:  # noqa: BLE001 — fallback is the contract
+        except Exception as e:  # noqa: BLE001 — interpret-mode fallback
+            _raise_on_chip(interpret)
             reasons["insert"] = (f"fused tail failed to build/probe: "
                                  f"{type(e).__name__}: {str(e)[:160]}")
     if tail is None and "insert" not in reasons:
@@ -235,7 +239,8 @@ def resolve_plan(B: int, G: int, K: int, *, Q: int, sw: int = 8,
             try:
                 _probe_enqueue(K, sw, interpret)
                 stages["enqueue"] = "pallas"
-            except Exception as e:  # noqa: BLE001 — fallback contract
+            except Exception as e:  # noqa: BLE001 — interpret-mode fallback
+                _raise_on_chip(interpret)
                 reasons["enqueue"] = (f"pallas enqueue failed to "
                                       f"build/probe: {type(e).__name__}: "
                                       f"{str(e)[:160]}")

@@ -43,6 +43,7 @@ identical on a real TPU slice.
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import time
 from typing import Callable, Dict, List, Optional
@@ -123,7 +124,7 @@ class MeshBFSEngine:
             _ckpt.check_dims_checkpointable(dims)
         if cfg.insert_method != "xla":
             # The shard-local insert runs inside shard_map; the Pallas
-            # lowering is a single-host experiment (NORTHSTAR.md §d) and
+            # lowering is a single-host experiment and
             # must not be silently ignored here.
             raise NotImplementedError(
                 "MeshEngine supports insert_method='xla' only")
@@ -424,8 +425,8 @@ class MeshBFSEngine:
                     tuple(t[None] for t in tbuf_l), tcnt_l[None],
                     stats, vrow_g, vfp)
 
-        from ..utils.platform import compat_shard_map
-        shard = compat_shard_map(self.mesh)
+        shard = functools.partial(jax.shard_map, mesh=self.mesh,
+                                  check_vma=False)
         sx = P("x")
         rep = P()
         self._chunk = jax.jit(shard(
@@ -452,22 +453,13 @@ class MeshBFSEngine:
         self._perf = None
         if cfg.perf:
             from ..obs import perf as perf_mod
-            i32s = jax.ShapeDtypeStruct((n,), _I32)
-            scalar = jax.ShapeDtypeStruct((), _I32)
-            qav = jax.ShapeDtypeStruct((n, QL + PAD, sw), jnp.uint8)
-            sh_av = jax.ShapeDtypeStruct((n, self._CL), _U32)
-            tbuf_av = tuple(
-                jax.ShapeDtypeStruct((n, self._TA), d)
-                for d in (jnp.uint32, jnp.uint32, jnp.uint32,
-                          jnp.uint32, _I32))
             self._perf = perf_mod.build_accounting(
                 pipeline=(cfg.pipeline
                           if cfg.pipeline in ("v3", "v4")
                           else "v2" if self._v2 is not None
                           else "v1"),
                 chunk_fn=self._chunk,
-                chunk_avals=(qav, i32s, scalar, qav, i32s, sh_av,
-                             sh_av, i32s, tbuf_av, i32s, scalar),
+                chunk_avals=self.chunk_avals(),
                 plan=self._v3_plan, with_stages=False,
                 metrics=self.metrics, engine="mesh")
 
@@ -480,6 +472,23 @@ class MeshBFSEngine:
         self._fp_batch = jax.jit(jax.vmap(fingerprint))
         self._root_check = (build_root_check(inv_fns, fingerprint)
                             if inv_fns else None)
+
+    def chunk_avals(self) -> tuple:
+        """The mesh chunk program's arguments as shapes (leading axis =
+        chips): what the launch model traces and what a compile for a
+        described mesh lowers."""
+        n = self.n_dev
+        i32s = jax.ShapeDtypeStruct((n,), _I32)
+        scalar = jax.ShapeDtypeStruct((), _I32)
+        qav = jax.ShapeDtypeStruct((n, self._QL + self._PAD, self._sw),
+                                   jnp.uint8)
+        sh_av = jax.ShapeDtypeStruct((n, self._CL), _U32)
+        tbuf_av = tuple(
+            jax.ShapeDtypeStruct((n, self._TA), d)
+            for d in (jnp.uint32, jnp.uint32, jnp.uint32,
+                      jnp.uint32, _I32))
+        return (qav, i32s, scalar, qav, i32s, sh_av, sh_av, i32s,
+                tbuf_av, i32s, scalar)
 
     # ------------------------------------------------------------------
     def _grow_seen(self, shi, slo, ssize, new_cl=None):

@@ -95,9 +95,9 @@ def _build_agree(mesh: Mesh, reduce_fn):
     def agree(vals):
         return reduce_fn(vals[0], "x")
 
-    from ..utils.platform import compat_shard_map
-    return jax.jit(compat_shard_map(mesh)(
-        agree, in_specs=P("x"), out_specs=P()))
+    return jax.jit(jax.shard_map(
+        agree, mesh=mesh, in_specs=P("x"), out_specs=P(),
+        check_vma=False))
 
 
 def build_any(mesh: Mesh):
@@ -164,9 +164,9 @@ def build_budget_agree(mesh: Mesh):
         return jnp.stack([jax.lax.psum(v[0], "x"),
                           jax.lax.pmin(v[1], "x")])
 
-    from ..utils.platform import compat_shard_map
-    fn = jax.jit(compat_shard_map(mesh)(
-        agree, in_specs=P("x"), out_specs=P()))
+    fn = jax.jit(jax.shard_map(
+        agree, mesh=mesh, in_specs=P("x"), out_specs=P(),
+        check_vma=False))
 
     def budget(over: bool, allowed: int):
         local = np.tile(np.asarray([int(over), int(allowed)], np.int32),

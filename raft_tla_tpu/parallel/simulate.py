@@ -14,6 +14,7 @@ this is the TLC ``-workers N`` analog for simulation mode.
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Callable, Dict, List, Optional
 
@@ -63,8 +64,8 @@ class MeshSimulator:
                     abuf_o[None], jax.lax.psum(restarts, "x"),
                     g_vf, g_vinv, g_vroot, g_vlen, g_vacts, g_vchoice)
 
-        from ..utils.platform import compat_shard_map
-        shard = compat_shard_map(self.mesh)
+        shard = functools.partial(jax.shard_map, mesh=self.mesh,
+                                  check_vma=False)
         sx, rep = P("x"), P()
         self._chunk = jax.jit(shard(
             sharded,

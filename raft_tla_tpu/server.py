@@ -125,8 +125,9 @@ arbitrary filesystem path, whose parse errors can echo file contents —
 so the default bind is loopback and the service trusts every client the
 bind address admits (same model as TLC's distributed-mode RMI endpoints).
 Binding a non-loopback --host hands that power to the network segment;
-do it only behind a firewall/ssh tunnel, or pass cfg_text instead of
-path-based cfg and run the process with a restricted filesystem view.
+do it only behind a firewall or an ssh port-forward, or pass cfg_text
+instead of path-based cfg and run the process with a restricted
+filesystem view.
 """
 
 from __future__ import annotations
@@ -1105,9 +1106,13 @@ def main(argv=None):
                         "process — the natural scrape target for the "
                         "long-lived service")
     args = p.parse_args(argv)
+    from .utils.platform import enable_persistent_cache, force_cpu
     if args.platform == "cpu":
-        from .utils.platform import force_cpu
         force_cpu()
+    # Same compile cache, placed by the same rule, as the CLI: a
+    # restarted server re-loads its engines' programs instead of
+    # recompiling them.
+    enable_persistent_cache()
     srv = serve(args.host, args.port,
                 max_request_bytes=args.max_request_bytes,
                 idle_timeout_seconds=args.idle_timeout,

@@ -19,7 +19,7 @@ is used; a null ``parsed`` — a bench run that never emitted JSON — is
 malformed input, exit 2).
 
 Comparison rules (each axis only when BOTH runs carry it — early
-BENCH_r01–r05 files predate chunk_stages/coverage and still diff):
+BENCH_r04/r05 files predate chunk_stages/coverage and still diff):
 
 - headline ``value`` (distinct states/s) and ``generated_per_sec``:
   regression when NEW < OLD * (1 - max_regress).

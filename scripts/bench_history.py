@@ -18,7 +18,7 @@ bench JSON that lets ``bench_diff.py --history`` auto-resolve baselines.
         anomalies.
 
     python scripts/bench_history.py LEDGER.jsonl --import-legacy [DIR]
-        one-time seeding from the committed BENCH_r01..r05 /
+        one-time seeding from the committed BENCH_r04/r05 /
         MULTICHIP_r01..r05 round files (DIR defaults to the repo root)
         so the trajectory is non-empty from day one.  Legacy files
         predate host fingerprints, so every imported entry carries
@@ -61,9 +61,9 @@ def import_legacy(ledger: str, repo: str) -> int:
         if parsed:
             entry = history_mod.entry_from_bench(parsed, label=label)
         else:
-            # A round whose bench never emitted JSON (BENCH_r01's queue
-            # overflow): recorded as a failed run, not silently dropped
-            # — the trajectory should show the crash too.
+            # A round whose bench never emitted JSON: recorded as a
+            # failed run, not silently dropped — the trajectory should
+            # show the crash too.
             entry = history_mod.make_entry(
                 "bench", label=label,
                 verdict=f"no-json (rc {wrapper.get('rc')})")

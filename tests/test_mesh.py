@@ -7,27 +7,8 @@ chip generates it, and the union of per-chip FPSet shards must behave as one
 set.
 """
 
-import os
-
 import jax
 import pytest
-
-if os.cpu_count() == 1:
-    # On a single-core host, jaxlib 0.4.36's CPU client nondeterministically
-    # corrupts the glibc heap while executing the 8-virtual-device sharded
-    # programs (~50% of module runs; concentrated in the shard-growth
-    # dryrun pin, also seen as wrong-resume shard frontiers on the
-    # checkpoint test and a "corrupted double-linked list" abort).
-    # Observed 2026-08-07 on an untouched seed tree across every cache
-    # state (cold, warm, suite-pure, disabled), test order, process
-    # isolation, and both CPU runtimes (thunk and legacy) — a native race
-    # in concurrent device threads that only a multi-core host avoids.  A
-    # crashed pytest process loses the whole invocation's results, so the
-    # module skips rather than coin-flips; CI and any multi-core dev host
-    # run it in full.
-    pytest.skip("8-virtual-device mesh programs crash jaxlib 0.4.36's CPU "
-                "client on single-core hosts (native race; see module "
-                "comment)", allow_module_level=True)
 
 from raft_tla_tpu.engine.bfs import BFSEngine, EngineConfig
 from raft_tla_tpu.models import oracle as orc
@@ -39,36 +20,6 @@ from raft_tla_tpu.parallel.mesh import MeshBFSEngine
 
 DIMS = RaftDims(n_servers=3, n_values=2, max_log=4, n_msg_slots=24)
 BOUNDS = Bounds(max_term=2, max_log_len=1, max_msg_count=1)
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _compile_fresh_no_aot_cache():
-    """Force this module's mesh programs to COMPILE, never AOT-load.
-
-    jaxlib 0.4.36's CPU client is heap-layout fragile under the big
-    sharded programs (see utils/platform.py): when the mesh chunk /
-    resume executables come back through the persistent-cache AOT
-    deserializer instead of the compiler, this module reproduces
-    wrong-resume garbage (checkpoint resume reading corrupt shard
-    frontiers) followed by a glibc "corrupted double-linked list"
-    abort in test_dryrun_ground_truth_pinned — even with a suite-pure
-    cache written by a green cold run of this very suite (observed
-    2026-08-07 on a single-core host; compile path green every time,
-    load path corrupt every time).  Cache namespacing (conftest's
-    "unit8" tag) is not enough: the load path itself is the hazard for
-    THIS module, so it opts out of the persistent cache entirely and
-    restores it on exit.
-
-    The opt-out is necessary but not sufficient: after a few hundred
-    other tests have warm-loaded their programs, the corruption fires
-    here even on the compile path, so tier-1 (ROADMAP.md) additionally
-    runs this module as its own pytest invocation in a fresh process.
-    On single-core hosts neither helps — the module-level skip above
-    applies there instead."""
-    old = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    yield
-    jax.config.update("jax_compilation_cache_dir", old)
 
 
 def test_eight_device_mesh_available():

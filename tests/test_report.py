@@ -305,12 +305,12 @@ def test_bench_history_imports_legacy_rounds(tmp_path, capsys):
     assert bh.main([led, "--import-legacy"]) == 0
     entries = history_mod.read_history(led)
     labels = [e["label"] for e in entries]
-    # The committed r01-r05 trajectory seeds the ledger, crash rounds
-    # included.
-    assert "BENCH_r05" in labels and "BENCH_r01" in labels
-    assert "MULTICHIP_r05" in labels
-    r01 = next(e for e in entries if e["label"] == "BENCH_r01")
-    assert "no-json" in r01["verdict"]
+    # The committed round files that remain (BENCH_r04/r05, both CPU
+    # runs, and MULTICHIP_r01..r05) seed the ledger.
+    assert "BENCH_r05" in labels and "BENCH_r04" in labels
+    assert {f"MULTICHIP_r0{i}" for i in range(1, 6)} <= set(labels)
+    r04 = next(e for e in entries if e["label"] == "BENCH_r04")
+    assert r04["distinct_per_sec"] == pytest.approx(39455.3)
     r05 = next(e for e in entries if e["label"] == "BENCH_r05")
     assert r05["distinct_per_sec"] == pytest.approx(38351.8)
     # Legacy rounds predate host fingerprints: flagged unknown-host —

@@ -438,6 +438,31 @@ def test_supervisor_does_not_retry_usage_errors(tmp_path):
     assert [e for e in events if e["event"] == "supervise_giveup"]
 
 
+def test_supervise_parent_never_initialises_a_backend(tmp_path):
+    """One process per chip: the ``--supervise`` parent only spawns the
+    checking child, so it must never initialise a jax backend — a parent
+    holding the chip would make every child fail or hang."""
+    import os
+    import subprocess
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import sys\n"
+        "import raft_tla_tpu.resilience.supervisor as sup\n"
+        "sup.subprocess.call = lambda argv, env=None: 2\n"
+        "from raft_tla_tpu.cli import main\n"
+        f"rc = main(['check', {os.path.join(repo, 'configs/MCraft_noleader.cfg')!r},"
+        f" '--supervise', '--checkpoint-dir', {str(tmp_path / 'ck')!r},"
+        f" '--events-out', {str(tmp_path / 'ev.jsonl')!r}])\n"
+        "xb = sys.modules.get('jax._src.xla_bridge')\n"
+        "assert xb is None or not xb.backends_are_initialized()\n"
+        "print('PARENT_RC', rc)\n")
+    r = subprocess.run([sys.executable, "-c", code], cwd=repo,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"},
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "PARENT_RC 2" in r.stdout
+
+
 def test_strip_supervisor_flags():
     assert strip_supervisor_flags(
         ["check", "m.cfg", "--supervise", "5", "--batch", "64"]) == \

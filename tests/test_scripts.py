@@ -1,7 +1,7 @@
 """Every scripts/*.py entry point must run from a fresh clone (round 2
-proved they rot silently; VERDICT r3 item 9).  Each is smoke-invoked in a
-subprocess on CPU with tiny sizes — exit 0 and a sanity-check of stdout is
-the contract; real measurement happens on hardware via tpu_session.sh."""
+proved they rot silently).  Each is smoke-invoked in a subprocess on CPU
+with tiny sizes — exit 0 and a sanity-check of stdout is the contract;
+what runs on the chip is chip_smoke.py."""
 
 import json
 import os
@@ -25,7 +25,7 @@ def run_script(args, extra_env=None, timeout=600):
     return proc.stdout
 
 
-@pytest.mark.slow   # ~2 min CPU; the hardware form is tpu_session stage 3
+@pytest.mark.slow   # ~2 min CPU
 def test_profile_step_runs():
     out = run_script(["scripts/profile_step.py", "64"])
     assert "expand" in out and "insert" in out
@@ -38,14 +38,14 @@ def test_profile_fpset_runs():
     assert "hash insert" in out
 
 
-@pytest.mark.slow   # ~1 min CPU; hardware form is tpu_session stage 2
+@pytest.mark.slow   # ~1 min CPU
 def test_true_bench_runs():
     out = run_script(["scripts/true_bench.py"],
                      extra_env={"TB_BATCH": "64"})
     assert "ms/iter" in out
 
 
-@pytest.mark.slow   # ~2 min CPU; hardware form is tpu_session stage 4
+@pytest.mark.slow   # ~2 min CPU
 def test_leader_bench_runs():
     """The leader-rich bench must actually exercise the log-machinery
     kernels (ClientRequest/AppendEntries/AdvanceCommitIndex > 0 is asserted

@@ -22,6 +22,24 @@ def test_native_lib_builds():
     assert native.load() is not None, "g++ build of trace_store.cpp failed"
 
 
+def test_native_rebuilds_when_source_content_changes(tmp_path):
+    """Staleness is a content check, not an mtime one: an edited copy of
+    the source rebuilds even when the old library is newer on disk."""
+    import os
+    import shutil
+    src = str(tmp_path / "trace_store.cpp")
+    so = str(tmp_path / "lib.so")
+    shutil.copy(native._SRC[0], src)
+    assert native.build_if_stale([src], so) is True
+    assert native.build_if_stale([src], so) is False
+    with open(src, "a") as f:
+        f.write("\n// edited\n")
+    future = os.path.getmtime(src) + 3600
+    os.utime(so, (future, future))         # library "newer" than source
+    assert native.build_if_stale([src], so) is True
+    assert native.build_if_stale([src], so) is False
+
+
 def test_native_matches_python_store():
     lib = native.load()
     assert lib is not None
