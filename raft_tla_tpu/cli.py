@@ -800,7 +800,7 @@ def main(argv=None):
                         "fencing: expand / fingerprint / dedup-insert / "
                         "enqueue histograms land in --metrics-out, a "
                         "chunk_profile event in --events-out, and a "
-                        "stage-budget table on stderr at run end.  "
+                        "stage table on stderr at run end.  "
                         "Observational: engine results are bit-identical "
                         "with profiling on or off")
     c.add_argument("--perf", action="store_true",

@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import os
 import time
 from typing import Callable, Dict, List, Optional, Tuple
@@ -63,15 +64,74 @@ from ..obs import (ActionCoverage, MetricsRegistry, RunEventLog,
                    SpanTracer, all_device_memory_stats,
                    device_memory_stats, events_path, peak_host_rss_bytes,
                    phase_delta)
+from ..obs.metrics import SCOPE_PREFIX, innermost_span
 from ..obs.flight import RECORDER as _FLIGHT
 from ..resilience import faults as _faults
 from ..resilience.faults import is_resource_exhausted
 from ..ops import compact as compact_mod
 from ..ops import fpset
 from ..ops.fingerprint import build_fingerprint
-from .chunk import build_chunk_body
+from .chunk import build_chunk_body, named_stage, tag_stages
 
 _I32 = jnp.int32
+
+# What the host loop counts where it does the work, as the registry's
+# ``engine/<name>`` counters (process-cumulative, like every counter):
+# ``run_end`` carries a run's share, ``level_complete`` each level's.
+WORK_COUNTERS = ("chunk_calls", "passes", "ingest_calls",
+                 "parents_expanded")
+
+
+def work_counts(metrics, base: Optional[dict] = None) -> dict:
+    """{name: count} of ``WORK_COUNTERS``, less an earlier reading."""
+    base = base or {}
+    return {k: int(metrics.counter_value("engine/" + k)) - base.get(k, 0)
+            for k in WORK_COUNTERS}
+
+
+# -- compiles by span ------------------------------------------------------
+# jax reports every backend compile, and every load from the persistent
+# cache, through one process-wide monitoring hook.  The listener charges
+# each to the innermost span open on the compiling thread, in that
+# span's registry: ``compile/<span>`` (how many) and
+# ``compile_seconds/<span>``.  ``run_end.compiles`` is a run's share.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_watch_installed = False
+
+
+def _on_compile(event: str, duration: float, **_kw) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    span = innermost_span()
+    if span is not None:
+        span.registry.counter("compile/" + span.name)
+        span.registry.counter("compile_seconds/" + span.name,
+                              float(duration))
+
+
+def watch_compiles() -> None:
+    """Register the listener, once per process (jax keeps listeners for
+    the life of the process)."""
+    global _compile_watch_installed
+    if not _compile_watch_installed:
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _compile_watch_installed = True
+
+
+def compiles_by_span(metrics, base: Optional[dict] = None) -> dict:
+    """{span: [compiles, seconds]} from a registry's compile counters,
+    less an earlier reading of the same."""
+    counts = metrics.counters("compile/")
+    seconds = metrics.counters("compile_seconds/")
+    base = base or {}
+    out = {}
+    for name, n in counts.items():
+        n0, s0 = base.get(name, (0, 0.0))
+        if n > n0:
+            out[name] = [int(n - n0),
+                         round(seconds.get(name, 0.0) - s0, 6)]
+    return out
 
 
 @dataclasses.dataclass
@@ -210,7 +270,7 @@ class EngineConfig:
     # Per-stage chunk profiling (obs/profile.py): sample every Nth chunk
     # call through separately-fenced expand/fingerprint/dedup-insert/
     # enqueue stage programs, accumulating chunk_stage/* histograms and
-    # a run-end chunk_profile event + stage-budget table.  Observational
+    # a run-end chunk_profile event + stage table.  Observational
     # (the real fused chunk still does all the work — results are
     # bit-identical profiling on or off); None = unset (a --perf run
     # then samples every 16th call), 0 = explicitly disabled (perf will
@@ -612,8 +672,10 @@ class BFSEngine:
         # the registry; attaching it to the registry mirrors every
         # phase_timer block into a Chrome-trace span.
         if not hasattr(self, "tracer"):
-            self.tracer = SpanTracer(cfg.trace_out)
+            self.tracer = SpanTracer(cfg.trace_out,
+                                     annotate=jax.profiler.TraceAnnotation)
         self.metrics.tracer = self.tracer
+        watch_compiles()
         # Device-profiler capture is created per run (_telemetry_run);
         # the attribute must exist (and survive re-entrant re-inits) so
         # the chunk loop can always read it.
@@ -645,8 +707,7 @@ class BFSEngine:
                 # v3/v4 runs are profiled at the fused-stage
                 # granularity (v3: masks / compact / fingerprint /
                 # insert_enqueue; v4: front / insert_enqueue); v1/v2
-                # keep the classical decomposition so the NORTHSTAR
-                # budget rows stay comparable across PRs.
+                # keep the classical decomposition.
                 pipeline=(cfg.pipeline
                           if cfg.pipeline in ("v3", "v4") else "v1"),
                 v3_force=(cfg.v4_force_stages if cfg.pipeline == "v4"
@@ -706,31 +767,25 @@ class BFSEngine:
         self._sw, self._B, self._G, self._Q = sw, B, G, Q
         self._K, self._PAD = K, PAD
 
-        def absorb(crows, en, parent_hi, parent_lo, actions,
-                   qnext, next_count, seen):
-            """Shared tail: hash-insert candidates (which both dedups the
-            batch and probes/updates the FPSet in one pass — no sorts),
-            enqueue, report.  ``crows`` [K,SW] flat rows, ``en`` [K]
-            validity.  The StateBatch views are re-sliced from ``crows`` so
-            the rows are the only materialized candidate buffer."""
-            k = crows.shape[0]
+        # The ingest program's stages carry the chunk's names
+        # (engine/chunk.py STAGES), so one reduction reads both.
+        @functools.partial(named_stage, "construct")
+        def construct(crows):
             cands = jax.vmap(unflatten_state, (0, None))(crows, dims)
             fph, fpl = jax.vmap(fingerprint)(cands)
-            seen, new, fail = insert_fn(seen, fph, fpl, en)
-            n_new = jnp.sum(new, dtype=_I32)
-
             if inv_fns:
                 inv = jax.vmap(build_inv_id(inv_fns))(cands)
             else:
-                inv = jnp.full((k,), -1, _I32)
-            viol = new & (inv >= 0)
-            viol_any = jnp.any(viol)
-            vpos = jnp.argmax(viol)
-
+                inv = jnp.full(crows.shape[:1], -1, _I32)
             if constraint is not None:
                 cons_ok = jax.vmap(constraint)(cands)
             else:
-                cons_ok = jnp.ones((k,), bool)
+                cons_ok = jnp.ones(crows.shape[:1], bool)
+            return fph, fpl, inv, cons_ok
+
+        @functools.partial(named_stage, "enqueue")
+        def enqueue(qnext, next_count, crows, new, cons_ok):
+            k = crows.shape[0]
             enq = new & cons_ok
             pos = next_count + jnp.cumsum(enq.astype(_I32)) - 1
             # Disabled lanes scatter to distinct trash rows past Q (PAD =
@@ -738,28 +793,49 @@ class BFSEngine:
             # would serialize the scatter on TPU (ops/fpset.py design note 3).
             pos = jnp.where(enq, pos, Q + jnp.arange(k, dtype=_I32))
             qnext = qnext.at[pos].set(crows, mode="drop")
-            next_count = next_count + jnp.sum(enq, dtype=_I32)
+            return qnext, next_count + jnp.sum(enq, dtype=_I32)
 
+        @functools.partial(named_stage, "record")
+        def record(new, cols):
             # Compacted trace records for the n_new fresh states.  Non-new
             # lanes spread over k..2k-1 trash slots (sliced off below) — a
             # single shared drop index would serialize the five scatters
             # (ops/fpset.py design note 3).
+            k = new.shape[0]
             tpos = jnp.where(new, jnp.cumsum(new.astype(_I32)) - 1,
                              k + jnp.arange(k, dtype=_I32))
+            return tuple(jnp.zeros((2 * k,), x.dtype).at[tpos].set(x)[:k]
+                         for x in cols)
 
-            def compact(x):
-                return jnp.zeros((2 * k,), x.dtype).at[tpos].set(x)[:k]
+        @functools.partial(named_stage, "stats")
+        def stats(new, inv, crows, fph, fpl):
+            viol = new & (inv >= 0)
+            vpos = jnp.argmax(viol)
+            return jnp.sum(new, dtype=_I32), (
+                jnp.any(viol), inv[vpos], crows[vpos], fph[vpos], fpl[vpos])
 
-            tr = (compact(fph), compact(fpl),
-                  compact(parent_hi), compact(parent_lo), compact(actions))
-            vinfo = (viol_any, inv[vpos], crows[vpos], fph[vpos], fpl[vpos])
+        insert = named_stage("insert", insert_fn)
+
+        def absorb(crows, en, parent_hi, parent_lo, actions,
+                   qnext, next_count, seen):
+            """Shared tail: hash-insert candidates (which both dedups the
+            batch and probes/updates the FPSet in one pass — no sorts),
+            enqueue, report.  ``crows`` [K,SW] flat rows, ``en`` [K]
+            validity.  The StateBatch views are re-sliced from ``crows`` so
+            the rows are the only materialized candidate buffer."""
+            fph, fpl, inv, cons_ok = construct(crows)
+            seen, new, fail = insert(seen, fph, fpl, en)
+            qnext, next_count = enqueue(qnext, next_count, crows, new,
+                                        cons_ok)
+            tr = record(new, (fph, fpl, parent_hi, parent_lo, actions))
+            n_new, vinfo = stats(new, inv, crows, fph, fpl)
             return qnext, next_count, seen, n_new, fail, tr, vinfo
 
         def ingest(rows, valid, qnext, next_count, seen):
             sent = jnp.zeros(rows.shape[:1], jnp.uint32)
             acts = jnp.full(rows.shape[:1], -1, _I32)
             return absorb(rows, valid, sent, sent, acts,
-                          qnext, next_count, seen)
+                          qnext, tag_stages(next_count), seen)
 
         # -- the device-resident level loop --------------------------------
         # One host round-trip costs far more than one batch of device
@@ -845,22 +921,45 @@ class BFSEngine:
             por_mask=por_mask, por_priority=por_priority,
             fused_tail=fused_tail, fused_front=fused_front)
 
+        # What sits outside the ``while``: the counters' start, and the
+        # packing of what the host fetches.  The pools themselves go
+        # into and out of the loop untouched by either.
+        @functools.partial(named_stage, "prologue")
+        def prologue():
+            F = len(dims.family_sizes)
+            return (jnp.int32(0), jnp.int32(0), jnp.int32(0),
+                    jnp.bool_(False), jnp.zeros((sw,), jnp.uint8),
+                    jnp.bool_(False), jnp.int32(-1),
+                    jnp.zeros((sw,), jnp.uint8),
+                    jnp.uint32(0), jnp.uint32(0), jnp.bool_(False),
+                    jnp.zeros((F,), _I32), jnp.zeros((F,), _I32),
+                    jnp.int32(0), jnp.zeros((F,), _I32))
+
+        @functools.partial(named_stage, "epilogue")
+        def epilogue(offset, steps, next_count, seen_size, tcount, gen,
+                     newc, ovfc, dead_any, viol_any, vinv, vhi, vlo,
+                     fail_any, fam_counts, fam_new, expanded, fam_pruned):
+            # fam_counts/fam_new/expanded/fam_pruned ride in the SAME
+            # packed vector — the loop's one-fetch-per-call contract is
+            # load-bearing.  Layout: 13 scalars, then
+            # the per-family generated counts, then the per-family novel
+            # counts, then the per-family POR-pruned counts
+            # (obs/coverage.py reads the host side).
+            stats = jnp.concatenate([jnp.stack([
+                offset, steps, next_count, seen_size, tcount, gen, newc,
+                ovfc, dead_any.astype(_I32), viol_any.astype(_I32), vinv,
+                fail_any.astype(_I32), expanded]), fam_counts, fam_new,
+                fam_pruned])
+            return stats, jnp.stack([vhi, vlo])
+
         def chunk(qcur, cur_count, offset0, qnext, next_count, seen,
                   tbuf, tcount0, max_steps):
             # ``max_steps`` (<= CH) is a runtime argument: near a duration
             # budget the host shrinks it so the deadline is honored to
             # within ~one batch, not one whole chunk (TLCGet("duration")
             # promptness — Smokeraft.tla:90).
-            init = (offset0, jnp.int32(0), qnext, next_count, seen, tbuf,
-                    tcount0, jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                    jnp.bool_(False), jnp.zeros((sw,), jnp.uint8),
-                    jnp.bool_(False), jnp.int32(-1),
-                    jnp.zeros((sw,), jnp.uint8),
-                    jnp.uint32(0), jnp.uint32(0), jnp.bool_(False),
-                    jnp.zeros((len(dims.family_sizes),), _I32),
-                    jnp.zeros((len(dims.family_sizes),), _I32),
-                    jnp.int32(0),
-                    jnp.zeros((len(dims.family_sizes),), _I32))
+            init = (offset0, jnp.int32(0), qnext, tag_stages(next_count),
+                    seen, tbuf, tcount0, *prologue())
 
             def cond(c):
                 (offset, steps, _qn, next_count, seen_c, _tb, tcount,
@@ -887,19 +986,11 @@ class BFSEngine:
              gen, newc, ovfc, dead_any, drow, viol_any, vinv, vrow,
              vhi, vlo, fail_any, fam_counts, fam_new, expanded,
              fam_pruned) = out
-            # fam_counts/fam_new/expanded/fam_pruned ride in the SAME
-            # packed vector — the loop's one-fetch-per-call contract is
-            # load-bearing.  Layout: 13 scalars, then
-            # the per-family generated counts, then the per-family novel
-            # counts, then the per-family POR-pruned counts
-            # (obs/coverage.py reads the host side).
-            stats = jnp.concatenate([jnp.stack([
+            stats, vhl = epilogue(
                 offset, steps, next_count, seen.size, tcount, gen, newc,
-                ovfc, dead_any.astype(_I32), viol_any.astype(_I32), vinv,
-                fail_any.astype(_I32), expanded]), fam_counts, fam_new,
-                fam_pruned])
-            return (qnext, seen, tbuf, stats, drow, vrow,
-                    jnp.stack([vhi, vlo]))
+                ovfc, dead_any, viol_any, vinv, vhi, vlo, fail_any,
+                fam_counts, fam_new, expanded, fam_pruned)
+            return qnext, seen, tbuf, stats, drow, vrow, vhl
 
         def fp_rows(rows):
             return jax.vmap(fingerprint)(
@@ -1049,8 +1140,18 @@ class BFSEngine:
         whole run (postmortem on any abnormal death), and disarmed on
         every completed run regardless of stop_reason."""
         cfg, mt = self.config, self.metrics
+        if self.tracer.enabled:
+            self.tracer.reset()     # one trace file = one run
+        # One identifier for the spans of one verdict: this run's, and
+        # those of the replay of what it finds.
+        self.tracer.run = self._run_id = getattr(self, "_run_id", 0) + 1
+        run_span = mt.open_span("run", SCOPE_PREFIX,
+                                resume=resume is not None)
+        self._level_span = None
         self._evlog = evlog = RunEventLog(self._events_path())
         self._phase_base = mt.phase_seconds()
+        self._compile_base = compiles_by_span(mt)
+        self._work_base = self._work_level_base = work_counts(mt)
         # Observed-collision base: the counter is process-cumulative
         # (shared registries — server, warm engines), the report's
         # "observed dual-key collisions" is per-run.
@@ -1066,8 +1167,6 @@ class BFSEngine:
         # high-water mark, re-armed here so a warm shared registry
         # never carries a previous run's peak into this run's levels.
         self._hbm_watermark = 0
-        if self.tracer.enabled:
-            self.tracer.reset()     # one trace file = one run
         # Black box armed before the first event so run_start itself is
         # in the ring; the context snapshot is what the watch console
         # shows as "what is running" (pipeline + resolved fused plan).
@@ -1096,7 +1195,6 @@ class BFSEngine:
                 self._xla_profile_dir(), cfg.xla_profile_chunks)
         else:
             self._xla_capture = None
-        run_t0 = self._lvl_t0 = time.perf_counter()
         evlog.emit(
             "run_start", engine=type(self).__name__, dims=repr(self.dims),
             batch=cfg.batch, sync_every=cfg.sync_every,
@@ -1111,6 +1209,20 @@ class BFSEngine:
             err = e
             raise
         finally:
+            try:
+                self._end_run(err)
+            finally:
+                run_span.close()
+                if self.tracer.enabled:
+                    self.tracer.write()
+
+    def _end_run(self, err) -> None:
+        """What ``_telemetry_run`` owes a run that ended, however it
+        ended: the open level span closed, the run-end reports, the
+        ``run_end`` event, the flight recorder disarmed."""
+        cfg, mt, evlog = self.config, self.metrics, self._evlog
+        self._close_level_span()
+        with mt.phase_timer("run_end"):
             res = self._cur_res
             phases = phase_delta(mt.phase_seconds(), self._phase_base)
             if res is not None:
@@ -1129,27 +1241,30 @@ class BFSEngine:
                     # run with reporting enabled; same cadence knob here.
                     import sys as _sys
                     print(cov.render_table(), file=_sys.stderr)
-            # Counterexample auto-render (engine/explain.py): a traced
-            # violation writes <workdir>/counterexample.{txt,json}
-            # BEFORE the run_end emit so the event carries the path.
-            # A render failure (e.g. a detected fingerprint collision
-            # diverging the replay) is reported, never allowed to mask
-            # the run's own verdict.
-            ce_path = None
-            ce_dir = cfg.counterexample_dir or cfg.checkpoint_dir
-            if (err is None and res is not None
-                    and res.violation is not None
-                    and cfg.record_trace and ce_dir):
-                try:
-                    from .explain import write_counterexample
-                    res.counterexample = write_counterexample(
-                        self, res, ce_dir,
-                        basename=self._counterexample_base())
-                    ce_path = res.counterexample["txt"]
-                except Exception as e:
-                    import sys as _sys
-                    print(f"counterexample render failed: "
-                          f"{type(e).__name__}: {e}", file=_sys.stderr)
+        # Between the two ``run_end`` spans, because the render replays
+        # the trace and ``replay`` brings its own spans.
+        # Counterexample auto-render (engine/explain.py): a traced
+        # violation writes <workdir>/counterexample.{txt,json}
+        # BEFORE the run_end emit so the event carries the path.
+        # A render failure (e.g. a detected fingerprint collision
+        # diverging the replay) is reported, never allowed to mask
+        # the run's own verdict.
+        ce_path = None
+        ce_dir = cfg.counterexample_dir or cfg.checkpoint_dir
+        if (err is None and res is not None
+                and res.violation is not None
+                and cfg.record_trace and ce_dir):
+            try:
+                from .explain import write_counterexample
+                res.counterexample = write_counterexample(
+                    self, res, ce_dir,
+                    basename=self._counterexample_base())
+                ce_path = res.counterexample["txt"]
+            except Exception as e:
+                import sys as _sys
+                print(f"counterexample render failed: "
+                      f"{type(e).__name__}: {e}", file=_sys.stderr)
+        with mt.phase_timer("run_end"):
             # TLC-parity statespace report (obs/report.py): host-side
             # assembly over counters the loop already fetched — its own
             # ``statespace`` event, ``statespace/*`` gauges, and the
@@ -1230,7 +1345,12 @@ class BFSEngine:
                 levels=list(getattr(res, "levels", None) or []),
                 wall_seconds=getattr(res, "wall_seconds", None),
                 growth_stalls=len(getattr(res, "growth_stalls", ())),
-                phase_seconds=phases, memory=device_memory_stats(),
+                phase_seconds=phases,
+                # Counted in the loop (WORK_COUNTERS), and the compiles
+                # and cache loads of this run by the span they fell in.
+                **work_counts(mt, self._work_base),
+                compiles=compiles_by_span(mt, self._compile_base),
+                memory=device_memory_stats(),
                 # Peak host RSS + one probe per visible device; CPU-only
                 # platforms report {} per device rather than omitting
                 # the field (obs/events.py guards).
@@ -1240,11 +1360,6 @@ class BFSEngine:
             _FLIGHT.disarm()     # completed or already-dumped: no atexit dump
             evlog.close()
             self._evlog = RunEventLog(None)
-            if self.tracer.enabled:
-                self.tracer.complete(
-                    "run", run_t0, engine=type(self).__name__,
-                    stop_reason=getattr(res, "stop_reason", None))
-                self.tracer.write()
 
     def _events_path(self):
         """Single-controller resolution; the mesh engine overrides with
@@ -1280,17 +1395,39 @@ class BFSEngine:
         breakdown.  ``unattributed_seconds`` closes the accounting —
         phases + unattributed == elapsed since run_start — so a phase
         that silently stops being timed shows up as growing slack, not a
-        plausible-looking breakdown.  Also closes this level's span in
-        the Chrome trace (one ``level`` span per BFS level)."""
+        plausible-looking breakdown.  Runs as the ``level_end`` span, the
+        last inside the level's own, which it then closes (one ``level``
+        span per BFS level)."""
+        with self.metrics.phase_timer("level_end"):
+            self._level_event(res, frontier_rows)
+        self._close_level_span()
         if self.tracer.enabled:
-            self.tracer.complete("level", self._lvl_t0, level=res.diameter,
-                                 frontier_rows=frontier_rows,
-                                 distinct=res.distinct,
-                                 generated=res.generated)
             # Level-boundary durability: a crash loses at most the
             # current level's spans (atomic rewrite, off the hot loop).
             self.tracer.write()
-        self._lvl_t0 = time.perf_counter()
+
+    def _open_level_span(self, level: int) -> None:
+        """The ``level`` span of the level about to be built.
+        Loop-shaped, so held open on the engine; ``_emit_level_event``
+        closes it, or ``_end_run`` when the level is left unfinished."""
+        self._close_level_span()
+        self._level_span = self.metrics.open_span(
+            "level", SCOPE_PREFIX, level=level)
+
+    def _close_level_span(self) -> None:
+        span = getattr(self, "_level_span", None)
+        if span is not None:
+            span.close()
+            self._level_span = None
+
+    def _count_chunk_call(self, passes: int, parents: int) -> None:
+        """One chunk call's work, from the statistics just fetched."""
+        mt = self.metrics
+        mt.counter("engine/chunk_calls")
+        mt.counter("engine/passes", passes)
+        mt.counter("engine/parents_expanded", parents)
+
+    def _level_event(self, res, frontier_rows):
         evlog = self._evlog
         # Launch accounting level boundary (obs/perf.py): snapshot this
         # level's launch total so OOM/skew events correlate with launch
@@ -1356,13 +1493,15 @@ class BFSEngine:
             extra = {"frontier_skew": skew.get("frontier_skew"),
                      "seen_skew": skew.get("seen_skew"),
                      "shard_frontier": skew.get("shard_frontier")}
+        work = work_counts(self.metrics, self._work_level_base)
+        self._work_level_base = work_counts(self.metrics)
         evlog.emit(
             "level_complete", level=res.diameter,
             frontier_rows=frontier_rows, distinct=res.distinct,
             generated=res.generated, phase_seconds=phases,
             unattributed_seconds=round(
                 elapsed - sum(phases.values()), 6),
-            memory=mem, **extra)
+            memory=mem, **work, **extra)
 
     def _run_impl(self, init_states: Optional[List[PyState]] = None,
                   resume=None) -> EngineResult:
@@ -1447,12 +1586,21 @@ class BFSEngine:
         # the async-spill spare at the first swap) retraces and RECOMPILES
         # the whole chunk program mid-run — ~10 s of silently charged
         # wall time on a cold compilation cache.
-        dev = jax.devices()[0]
-        QA = Q + self._PAD
-        qcur = jax.device_put(jnp.zeros((QA, sw), jnp.uint8), dev)
-        qnext = jax.device_put(jnp.zeros((QA, sw), jnp.uint8), dev)
-        seen = jax.device_put(fpset.empty(self._seen_cap), dev)
-        next_count = jnp.int32(0)
+        with mt.phase_timer("run_init"):
+            dev = jax.devices()[0]
+            QA = Q + self._PAD
+            qcur = jax.device_put(jnp.zeros((QA, sw), jnp.uint8), dev)
+            qnext = jax.device_put(jnp.zeros((QA, sw), jnp.uint8), dev)
+            seen = jax.device_put(fpset.empty(self._seen_cap), dev)
+            next_count = jnp.int32(0)
+            # The async spill's spare queue (below).
+            free_q: List = [
+                jax.device_put(jnp.zeros((QA, sw), jnp.uint8), dev)]
+            TA = self._TA
+            tbuf = jax.device_put(
+                (jnp.zeros((TA,), jnp.uint32), jnp.zeros((TA,), jnp.uint32),
+                 jnp.zeros((TA,), jnp.uint32), jnp.zeros((TA,), jnp.uint32),
+                 jnp.zeros((TA,), _I32)), dev)
         # Host-resident level segments: the part of the current level that
         # does not fit the device queue (``pending``) and next-level
         # overflow drained mid-level (``spill_next``) — TLC's disk-backed
@@ -1465,7 +1613,6 @@ class BFSEngine:
         # the full next-queue and swaps in a spare buffer, so the drain
         # overlaps the following chunks' compute; the transfer is resolved
         # (and the buffer recycled) at the next drain or level boundary.
-        free_q: List = [jax.device_put(jnp.zeros((QA, sw), jnp.uint8), dev)]
         inflight: List = []        # [(device array, row count)]
 
         def resolve_spill():
@@ -1480,11 +1627,6 @@ class BFSEngine:
                     # their memmap regardless.)
                     spill_next.append(host[:cnt], copy=True)
                     free_q.append(arr)
-        TA = self._TA
-        tbuf = jax.device_put(
-            (jnp.zeros((TA,), jnp.uint32), jnp.zeros((TA,), jnp.uint32),
-             jnp.zeros((TA,), jnp.uint32), jnp.zeros((TA,), jnp.uint32),
-             jnp.zeros((TA,), _I32)), dev)
 
         # Warm-up: run both programs once with empty inputs (no semantic
         # effect: all-invalid masks insert nothing, zero-trip chunk) so XLA
@@ -1532,61 +1674,66 @@ class BFSEngine:
             # into a fresh hash table, reload the frontier, counters, and
             # trace records/roots.
             n_keys = resume.seen_hi.shape[0]
-            cap = self._seen_cap
-            while n_keys > fpset._capacity(cap) // 2:
-                cap *= 2
-            seen = fpset.from_host_keys(resume.seen_hi, resume.seen_lo, cap)
-            fr = np.ascontiguousarray(resume.frontier).astype(
-                ROW_DTYPE, casting="safe")
-            # A frontier larger than the device queue resumes as device
-            # rows + host segments (same split the spill path produces).
-            for i in range(Q, len(fr), Q):
-                # Views, not copies: the disk-backed pool copies into its
-                # memmap anyway, and the RAM pool holding views keeps the
-                # resume peak at one frontier (fr stays pinned via fr[:Q]).
-                pending.append(fr[i:i + Q])
-            fr = fr[:Q]
-            qcur = jax.device_put(
-                jnp.zeros((QA, sw), jnp.uint8).at[:len(fr)].set(
-                    jnp.asarray(fr)), dev)
-            cur_count = len(fr)
-            res.distinct = resume.distinct
-            res.generated = resume.generated
-            res.diameter = resume.diameter
-            res.levels = list(resume.levels)
-            res.action_counts = dict(resume.action_counts)
-            # Coverage resumes its generated series from the checkpoint
-            # so the run-end table still matches generated_by_action
-            # (distinct/expanded are not checkpointed; see
-            # coverage.disabled).  The registry counters are NOT seeded:
-            # they are process-cumulative, and an in-process degrade
-            # resume already accumulated the pre-crash increments — the
-            # progress line renders per-run totals from res instead.
-            coverage.seed_generated(resume.action_counts)
-            # Duration (TLCGet("duration")-style) accumulates across
-            # restarts: back-date t0 so wall_seconds, states/sec, and the
-            # max_seconds budget all measure total checking time.
-            t0 -= resume.wall_seconds
-            if cfg.record_trace:
-                if resume.distinct > 0 and resume.trace_fps.size == 0:
+            with mt.phase_timer("restore"):
+                cap = self._seen_cap
+                while n_keys > fpset._capacity(cap) // 2:
+                    cap *= 2
+                seen = fpset.from_host_keys(resume.seen_hi, resume.seen_lo,
+                                            cap)
+                fr = np.ascontiguousarray(resume.frontier).astype(
+                    ROW_DTYPE, casting="safe")
+                # A frontier larger than the device queue resumes as device
+                # rows + host segments (same split the spill path produces).
+                for i in range(Q, len(fr), Q):
+                    # Views, not copies: the disk-backed pool copies into
+                    # its memmap anyway, and the RAM pool holding views
+                    # keeps the resume peak at one frontier (fr stays
+                    # pinned via fr[:Q]).
+                    pending.append(fr[i:i + Q])
+                fr = fr[:Q]
+                qcur = jax.device_put(
+                    jnp.zeros((QA, sw), jnp.uint8).at[:len(fr)].set(
+                        jnp.asarray(fr)), dev)
+                cur_count = len(fr)
+                res.distinct = resume.distinct
+                res.generated = resume.generated
+                res.diameter = resume.diameter
+                res.levels = list(resume.levels)
+                res.action_counts = dict(resume.action_counts)
+                # Coverage resumes its generated series from the checkpoint
+                # so the run-end table still matches generated_by_action
+                # (distinct/expanded are not checkpointed; see
+                # coverage.disabled).  The registry counters are NOT seeded:
+                # they are process-cumulative, and an in-process degrade
+                # resume already accumulated the pre-crash increments — the
+                # progress line renders per-run totals from res instead.
+                coverage.seed_generated(resume.action_counts)
+                # Duration (TLCGet("duration")-style) accumulates across
+                # restarts: back-date t0 so wall_seconds, states/sec, and the
+                # max_seconds budget all measure total checking time.
+                t0 -= resume.wall_seconds
+                if cfg.record_trace:
+                    if resume.distinct > 0 and resume.trace_fps.size == 0:
+                        raise ValueError(
+                            "checkpoint was written with trace recording "
+                            "disabled; counterexample replay could never "
+                            "reach a root — resume with record_trace=False "
+                            "(--no-trace) or restart from scratch")
+                    trace.add_batch(resume.trace_fps, resume.trace_parents,
+                                    resume.trace_actions)
+                    trace.roots.update(resume.roots)
+                elif resume.trace_fps.size > 0 \
+                        and cfg.checkpoint_dir is not None:
                     raise ValueError(
-                        "checkpoint was written with trace recording "
-                        "disabled; counterexample replay could never reach "
-                        "a root — resume with record_trace=False "
-                        "(--no-trace) or restart from scratch")
-                trace.add_batch(resume.trace_fps, resume.trace_parents,
-                                resume.trace_actions)
-                trace.roots.update(resume.roots)
-            elif resume.trace_fps.size > 0 and cfg.checkpoint_dir is not None:
-                raise ValueError(
-                    "resuming a trace-carrying checkpoint with trace "
-                    "recording disabled would write trace-less snapshots "
-                    "into the same directory, shadowing the intact ones "
-                    "for any later trace-on resume; use a different "
-                    "checkpoint_dir or keep tracing enabled")
+                        "resuming a trace-carrying checkpoint with trace "
+                        "recording disabled would write trace-less snapshots "
+                        "into the same directory, shadowing the intact ones "
+                        "for any later trace-on resume; use a different "
+                        "checkpoint_dir or keep tracing enabled")
         else:
             # Ingest initial states in B-sized chunks (roots registered
             # above, before the clock).
+            self._open_level_span(0)
             for base in range(0, len(rows_np), B):
                 # StopAfter applies during root ingest too (a k=4 smoke
                 # run has 262k roots — TLCGet("duration") doesn't wait
@@ -1615,6 +1762,7 @@ class BFSEngine:
                         jnp.asarray(np.concatenate([chunk, pad])),
                         jnp.asarray(valid), qnext, next_count, seen)
                     res.distinct += int(n_new)
+                mt.counter("engine/ingest_calls")
                 mt.counter("engine/distinct", int(n_new))
                 with mt.phase_timer("trace_flush"):
                     self._record(trace, tr, int(n_new))
@@ -1678,6 +1826,7 @@ class BFSEngine:
                     and res.diameter >= cfg.max_diameter:
                 res.stop_reason = "diameter_budget"
                 break
+            self._open_level_span(res.diameter + 1)
             # Level loop: each _chunk call runs up to sync_every batches on
             # device; ONE packed stats fetch (plus a trace flush) per call
             # is the only host traffic — the host round-trip no longer
@@ -1743,18 +1892,20 @@ class BFSEngine:
                                      chunk=calls_in_level)
                         _faults.fire("oom", level=res.diameter,
                                      chunk=calls_in_level)
-                    t_call = time.time()
-                    # Device-profiler window (--xla-profile): bracket
-                    # the dispatch in a StepTraceAnnotation sharing the
-                    # SpanTracer's "chunk" span name; the capture stops
-                    # itself after N steps (obs/profile.py).  One call
-                    # site: the profiled and unprofiled paths must
-                    # never diverge.
+                    # Device-profiler window (--xla-profile): the
+                    # capture starts at the first dispatch and stops
+                    # itself after N (obs/profile.py).  One call site:
+                    # the profiled and unprofiled paths must never
+                    # diverge.
                     cap = self._xla_capture
                     step_cm = (cap.step() if cap is not None
                                and not cap.done
                                else contextlib.nullcontext())
-                    with mt.phase_timer("chunk"), step_cm:
+                    # The registry's count: what pairs this span with
+                    # the ``account`` span of the same call.
+                    call = int(mt.counter_value("engine/chunk_calls")) + 1
+                    with mt.phase_timer("chunk", call=call) as dispatch, \
+                            step_cm:
                         out = self._chunk(qcur, jnp.int32(cur_count),
                                           jnp.int32(offset), qnext,
                                           jnp.int32(next_count_h), seen,
@@ -1764,16 +1915,22 @@ class BFSEngine:
                     # The packed-stats fetch is the loop's one blocking
                     # device sync — its phase time IS the device compute
                     # the dispatch above overlapped.
-                    with mt.phase_timer("stats_fetch"):
+                    with mt.phase_timer("stats_fetch") as fetch:
                         st = np.asarray(out[3])
-                    if self._perf is not None and int(st[1]):
-                        # Launch accounting's dynamic half: batches +
-                        # measured seconds for this chunk call — host
-                        # arithmetic on values already fetched.
-                        self._perf.add_chunk(int(st[1]),
-                                             time.time() - t_call)
-                    if int(st[1]):       # st fetch synced: timing is real
-                        per = (time.time() - t_call) / int(st[1])
+                    passes = int(st[1])
+                    # The host bookkeeping of one call, under the numbers
+                    # the fetch brought: what a reader of a profiler
+                    # capture matches the call's device time to.
+                    account = mt.open_span("account", call=call,
+                                           passes=passes)
+                    self._count_chunk_call(passes, int(st[12]))
+                    call_seconds = dispatch.seconds + fetch.seconds
+                    if self._perf is not None and passes:
+                        # Launch accounting's dynamic half: the call's
+                        # passes and its seconds (dispatch + fetch).
+                        self._perf.add_chunk(passes, call_seconds)
+                    if passes:           # st fetch synced: timing is real
+                        per = call_seconds / passes
                         # Conservative estimator: jumps up to the latest
                         # cost instantly, decays slowly — per-batch cost
                         # grows with level depth (fuller probe chains,
@@ -1822,6 +1979,7 @@ class BFSEngine:
                         offset=offset, next_count=next_count_h,
                         seen_size=seen_size,
                         elapsed=round(time.time() - t0, 3))
+                    account.close()
                     if cfg.record_trace and tcount:
                         with mt.phase_timer("trace_flush"):
                             self._flush_trace(trace, tbuf, tcount)
@@ -1933,8 +2091,10 @@ class BFSEngine:
         res.wall_seconds = time.time() - t0
         # Final frontier snapshot (empty when exhausted): profiling tools
         # use it as a representative mid-level workload.
-        self._last_frontier = (np.asarray(qcur[:cur_count]) if cur_count
-                               else np.zeros((0, sw), ROW_DTYPE))
+        with mt.phase_timer("frontier_fetch"):
+            self._last_frontier = (np.asarray(qcur[:cur_count])
+                                   if cur_count
+                                   else np.zeros((0, sw), ROW_DTYPE))
         return res
 
     # ------------------------------------------------------------------
@@ -1950,7 +2110,13 @@ class BFSEngine:
         so a recorded slot-indexed action (Receive/Duplicate/Drop) may map
         to a different slot of the canonical parent.  The recorded id is
         preferred when it still matches, so labels stay stable."""
-        chain = self.trace.chain(fp)
+        with self.metrics.scope("replay"):
+            return self._replay(fp)
+
+    def _replay(self, fp: int) -> List[Tuple[int, PyState]]:
+        mt = self.metrics
+        with mt.phase_timer("trace_chain"):
+            chain = self.trace.chain(fp)
         if not chain:
             if fp in self.trace.roots:
                 # Depth-0 counterexample: the violating state IS a root —
@@ -1962,29 +2128,35 @@ class BFSEngine:
             raise KeyError("trace chain does not reach a root")
         state = self.trace.roots[root_fp]
         out = [(-1, state)]
-        for child_fp, g_rec in chain[1:]:
-            st = encode_state(state, self.dims)
-            cands, en, _ovf = self._expand1(st)
-            fph, fpl = self._fp_batch(cands)
-            fps = (np.asarray(fph).astype(np.uint64) << np.uint64(32)) \
-                | np.asarray(fpl).astype(np.uint64)
-            ok = np.asarray(en) & (fps == np.uint64(child_fp))
-            if not ok.any():
-                # A replay that cannot reproduce a recorded child is the
-                # one place a 64-bit fingerprint collision becomes HOST-
-                # OBSERVABLE — counted so the statespace report's
-                # "observed dual-key collisions" reflects detections,
-                # not just the calculated probability.
-                self.metrics.counter("engine/fp_collisions")
-                raise RuntimeError(
-                    f"replay divergence: no enabled candidate matches "
-                    f"fp {child_fp:#018x} (recorded action {g_rec})")
-            g = g_rec if 0 <= g_rec < ok.shape[0] and ok[g_rec] \
-                else int(np.argmax(ok))
-            row = jax.tree.map(lambda a: np.asarray(a)[g], cands)
-            state = decode_state(StateBatch(*row), self.dims)
+        for step, (child_fp, g_rec) in enumerate(chain[1:], 1):
+            with mt.phase_timer("replay_step", step=step):
+                state, g = self._replay_step(state, child_fp, g_rec)
             out.append((g, state))
         return out
+
+    def _replay_step(self, state, child_fp: int, g_rec: int):
+        """The successor of ``state`` whose fingerprint is ``child_fp``,
+        and the action instance that gives it."""
+        st = encode_state(state, self.dims)
+        cands, en, _ovf = self._expand1(st)
+        fph, fpl = self._fp_batch(cands)
+        fps = (np.asarray(fph).astype(np.uint64) << np.uint64(32)) \
+            | np.asarray(fpl).astype(np.uint64)
+        ok = np.asarray(en) & (fps == np.uint64(child_fp))
+        if not ok.any():
+            # A replay that cannot reproduce a recorded child is the
+            # one place a 64-bit fingerprint collision becomes HOST-
+            # OBSERVABLE — counted so the statespace report's
+            # "observed dual-key collisions" reflects detections,
+            # not just the calculated probability.
+            self.metrics.counter("engine/fp_collisions")
+            raise RuntimeError(
+                f"replay divergence: no enabled candidate matches "
+                f"fp {child_fp:#018x} (recorded action {g_rec})")
+        g = g_rec if 0 <= g_rec < ok.shape[0] and ok[g_rec] \
+            else int(np.argmax(ok))
+        row = jax.tree.map(lambda a: np.asarray(a)[g], cands)
+        return decode_state(StateBatch(*row), self.dims), g
 
     # ------------------------------------------------------------------
     def _grow_precompiled(self, seen, size, qcur, qnext, next_count, tbuf,
@@ -1994,23 +2166,20 @@ class BFSEngine:
         keep the rehash + compile off the duration clock — the StopAfter
         budget measures checking time, not compilation (same rule as the
         warm-up).  Returns (seen, qnext, tbuf, t0)."""
-        t_grow = time.time()
-        grown = self._maybe_grow_seen(seen, size)
-        if grown is not seen:
-            seen = grown
-            out = self._chunk(qcur, jnp.int32(0), jnp.int32(0), qnext,
-                              jnp.int32(next_count), seen, tbuf,
-                              jnp.int32(0), jnp.int32(1))
-            qnext, seen, tbuf = out[0], out[1], out[2]
-            stall = time.time() - t_grow
+        if self._seen_overloaded(seen, size):
+            with self.metrics.phase_timer("grow") as grow:
+                seen = self._maybe_grow_seen(seen, size)
+                out = self._chunk(qcur, jnp.int32(0), jnp.int32(0), qnext,
+                                  jnp.int32(next_count), seen, tbuf,
+                                  jnp.int32(0), jnp.int32(1))
+                qnext, seen, tbuf = out[0], out[1], out[2]
+            stall = grow.seconds
             t0 += stall
             # Off the clock, but recorded: a run that starts undersized
             # pays one of these per doubling — the evidence for sizing
-            # SEEN_CAPACITY up front.  The stall IS the phase time
-            # (rehash + precompile), observed directly.
+            # SEEN_CAPACITY up front.  The stall IS the span (rehash +
+            # precompile).
             self._growth_stalls.append((len(seen.hi), round(stall, 3)))
-            from ..obs import PHASE_PREFIX
-            self.metrics.observe(PHASE_PREFIX + "fpset_grow", stall)
             self.metrics.counter("engine/fpset_resizes")
             # The growth_stall event BENCH_r05 had to infer from outside:
             # capacity after, off-clock stall, live memory.
@@ -2018,6 +2187,12 @@ class BFSEngine:
                              stall_seconds=round(stall, 3),
                              memory=device_memory_stats())
         return seen, qnext, tbuf, t0
+
+    @staticmethod
+    def _seen_overloaded(seen, size=None) -> bool:
+        """Load past 0.5: time to grow (``_maybe_grow_seen``)."""
+        return ((int(seen.size) if size is None else size)
+                > seen.hi.shape[0] // 2)
 
     def _maybe_grow_seen(self, seen, size=None):
         """Double the FPSet (rehash through host keys) once load passes
@@ -2027,9 +2202,9 @@ class BFSEngine:
         the new table shape, so growth costs one compile per doubling;
         auto-sized tables (seen_capacity=None) start large enough that
         most runs never grow."""
-        C = seen.hi.shape[0]
-        if (int(seen.size) if size is None else size) <= C // 2:
+        if not self._seen_overloaded(seen, size):
             return seen
+        C = seen.hi.shape[0]
         hi, lo = fpset.to_host_keys(seen)
         self._grow_attempts = getattr(self, "_grow_attempts", 0) + 1
         try:

@@ -12,6 +12,10 @@ K compacted lanes.  engine/bfs.py (single chip) and parallel/mesh.py
 - the loop wrapper around the body (plain while_loop vs shard_map with
   psum-replicated stop conditions), which stays in each engine.
 
+Each stage of the body runs under a named scope (``STAGES``,
+``named_stage`` below), so every device operation of a pass says which
+stage it belongs to.
+
 Keeping the body in one place is load-bearing: the two engines must stay
 bit-identical per batch (same candidate order, same compaction, same
 trace layout) for checkpoints to be portable across engines and for the
@@ -38,13 +42,54 @@ obs/coverage.py is the host-side consumer.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from ..models.invariants import build_inv_id
 from ..models.schema import flatten_state, unflatten_state
 
 _I32 = jnp.int32
+
+# The stages of one pass, in order, as they are named in the compiled
+# program (a component ``<stage>`` of every operation's ``op_name``, so in
+# a profiler capture and in ``--xla_dump_to`` text).  ``front`` and
+# ``insert_enqueue`` stand for what the v4/v3 kernels fuse.  The engines
+# put ``prologue``/``epilogue`` around what sits outside the ``while``.
+STAGES = ("slice", "masks", "compact", "construct", "insert", "enqueue",
+          "record", "stats")
+FUSED_STAGES = ("front", "insert_enqueue")
+
+# Scope names are debug info, which jax strips before it hashes a module
+# for the persistent compile cache (``cache_key._canonicalize_ir``): a
+# cache filled before a name was added or changed hands back an
+# executable whose operations carry the old names.  The tag is in what
+# is hashed (``tag_stages``).  Change it with the names: the chunk and
+# ingest programs then compile once more.
+STAGES_TAG = "s1"
+
+
+def named_stage(name: str, fn):
+    """``fn`` traced under ``jax.named_scope(name)``: ``.../<name>/...``
+    in the ``op_name`` of every operation it emits, and nothing else."""
+    @functools.wraps(fn)
+    def call(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+    return call
+
+
+def tag_stages(count):
+    """``count``, an integer a program takes, through an ``add 0`` that
+    carries ``STAGES_TAG`` as a frontend attribute: an attribute of the
+    module jax hashes, on an operation XLA folds away.  (A named call
+    would do too, and costs 1.4 s of tracing and lowering per process on
+    the chip's host; a new name for the program itself is what others
+    know it by.)"""
+    with set_xla_metadata(stages_tag=STAGES_TAG):
+        return count + 0
 
 
 def build_chunk_body(*, dims, expand, fingerprint, pack_ok, inv_fns,
@@ -130,143 +175,152 @@ def build_chunk_body(*, dims, expand, fingerprint, pack_ok, inv_fns,
 
     fam_slices = tuple(zip(dims.family_offsets, dims.family_sizes))
 
-    def chunk_body(qcur, cur_count, carry):
-        (offset, steps, qnext, next_count, seen, tbuf, tcount,
-         gen, newc, ovfc, dead_any, drow, viol_any, vinv, vrow,
-         vhi, vlo, fail_any, fam_counts, fam_new, expanded,
-         fam_pruned) = carry
+    @functools.partial(named_stage, "slice")
+    def slice_(qcur, offset, cur_count):
         rows = jax.lax.dynamic_slice_in_dim(qcur, offset, B, axis=0)
         valid = (offset + jnp.arange(B, dtype=_I32)) < cur_count
+        # The v4 front kernel reads the packed rows itself.
+        states = (None if fused_front is not None else
+                  jax.vmap(unflatten_state, (0, None))(rows, dims))
+        return rows, valid, states
+
+    @functools.partial(named_stage, "front")
+    def front(rows, valid):
+        # v4: one Pallas megakernel runs masks -> POR -> compact ->
+        # delta fingerprints -> constraint/invariants on the
+        # VMEM-resident parent window.  en/ovf arrive already
+        # progress-limited; pruned is pre-limit (accounted in ``stats``
+        # like the split path); the per-lane parent fingerprints feed
+        # the trace recorder without re-reading the parents.
+        (en, ovf, pruned, P, total, lane_id, kvalid, kh, kl, krows,
+         cons_ok, inv, parent_hi, parent_lo) = fused_front(rows, valid)
+        if por_mask is None:
+            pruned = None
+        ptaken = jnp.arange(B, dtype=_I32) < P
+        return (en, ovf, pruned, P, total, lane_id, kvalid, ptaken, kh,
+                kl, krows, cons_ok, inv, parent_hi, parent_lo)
+
+    @functools.partial(named_stage, "masks")
+    def masks(states, valid):
+        if v2 is None:
+            cands, en, ovf = jax.vmap(expand)(states)
+            en = en & valid[:, None]
+            # A successor whose term/bag count outgrew the uint8 row
+            # is an overflow too (schema.build_pack_guard): stop,
+            # never alias.
+            ovf = (ovf | (en & ~jax.vmap(jax.vmap(pack_ok))(cands))) \
+                & valid[:, None]
+        else:
+            # Masks fold the pack guard in at the same lanes
+            # (actions2).
+            cands = None
+            en, ovf = jax.vmap(v2.masks)(states)
+            en = en & valid[:, None]
+            ovf = ovf & valid[:, None]
+
+        if por_mask is not None:
+            # Partial-order reduction (analysis/por.py table): keep
+            # ONE certified ample lane per state that has any,
+            # masking its siblings before compaction/fingerprinting
+            # — the reduction the coverage tables account as
+            # "pruned".  Rows with no certified enabled instance are
+            # untouched, so a state with an empty enabled set still
+            # reads as a deadlock.
+            amp = en & por_mask[None, :]
+            any_amp = jnp.any(amp, axis=1)
+            pri = jnp.where(amp, por_priority[None, :],
+                            jnp.int32(2147483647))
+            sel = jnp.argmin(pri, axis=1)
+            keep = jnp.where(
+                any_amp[:, None],
+                jnp.arange(G, dtype=_I32)[None, :] == sel[:, None],
+                jnp.ones((B, G), bool))
+            pruned = en & ~keep
+            en = en & keep
+            ovf = ovf & keep
+        else:
+            pruned = None
+        return cands, en, ovf, pruned
+
+    @functools.partial(named_stage, "compact")
+    def compact(en, ovf):
+        # Progress limiting + lane compaction (ops/compact.py): take
+        # the longest parent prefix whose fan-out fits K, compact
+        # the enabled lanes to K slots — nothing is ever dropped, a
+        # fan-out burst just advances fewer parents this step.
+        P, total, lane_id, kvalid = compactor(en)
+        ptaken = jnp.arange(B, dtype=_I32) < P
+        en = en & ptaken[:, None]
+        ovf = ovf & ptaken[:, None]
+        return en, ovf, P, total, lane_id, kvalid, ptaken
+
+    @functools.partial(named_stage, "construct")
+    def construct(states, cands, lane_id):
+        # Everything from here on — fingerprinting included — runs on
+        # the K compacted lanes only: gather the candidate structs
+        # first, hash after (identical to hashing the packed rows
+        # whenever pack_ok holds, and any overflow aborts the run).
+        # Hashing before compaction would read every field of all
+        # B*G lanes for the ~94% that are disabled.
+        if v2 is None:
+            cflat = jax.tree.map(
+                lambda a: a.reshape((BG,) + a.shape[2:]), cands)
+            kstates = jax.tree.map(lambda a: a[lane_id], cflat)
+            kh, kl = jax.vmap(fingerprint)(kstates)     # [K]
+        else:
+            # Gather K parent structs (from B parents, not B*G
+            # candidate lanes) and construct only those successors,
+            # with their fingerprints coming from the parents' hash
+            # sums + per-lane deltas (models/actions2.py).
+            ph = jax.vmap(v2.parent_hash)(states)
+            pidx = lane_id // G
+            kparents = jax.tree.map(lambda a: a[pidx], states)
+            kph = jax.tree.map(lambda a: a[pidx], ph)
+            kh, kl, kstates = jax.vmap(v2.lane_out)(
+                kparents, kph, lane_id % G)
+
+        if constraint is not None:
+            cons_ok = jax.vmap(constraint)(kstates)
+        else:
+            cons_ok = jnp.ones((K,), bool)
+        krows = jax.vmap(flatten_state, (0, None))(kstates, dims)
+        # Invariant dispatch depends only on the candidates, so it
+        # sits before the insert on both paths (the v4 kernel
+        # computes it in-kernel; values are insert-independent).
+        if inv_id is not None:
+            inv = jax.vmap(inv_id)(kstates)
+        else:
+            inv = jnp.full((K,), -1, _I32)
         parent_hi = parent_lo = None
-        if fused_front is not None:
-            # v4: one Pallas megakernel runs masks -> POR -> compact ->
-            # delta fingerprints -> constraint/invariants on the
-            # VMEM-resident parent window.  en/ovf arrive already
-            # progress-limited; pruned is pre-limit (accounted below
-            # like the split path); the per-lane parent fingerprints
-            # feed the trace recorder without re-reading the parents.
-            (en, ovf, pruned, P, total, lane_id, kvalid, kh, kl, krows,
-             cons_ok, inv, parent_hi, parent_lo) = fused_front(
-                 rows, valid)
-            if por_mask is None:
-                pruned = None
-            ptaken = jnp.arange(B, dtype=_I32) < P
-        else:
-            states = jax.vmap(unflatten_state, (0, None))(rows, dims)
+        if record_static:
             if v2 is None:
-                cands, en, ovf = jax.vmap(expand)(states)
-                en = en & valid[:, None]
-                # A successor whose term/bag count outgrew the uint8 row
-                # is an overflow too (schema.build_pack_guard): stop,
-                # never alias.
-                ovf = (ovf | (en & ~jax.vmap(jax.vmap(pack_ok))(cands))) \
-                    & valid[:, None]
+                php, plp = jax.vmap(fingerprint)(states)  # [B]
             else:
-                # Masks fold the pack guard in at the same lanes
-                # (actions2).
-                en, ovf = jax.vmap(v2.masks)(states)
-                en = en & valid[:, None]
-                ovf = ovf & valid[:, None]
+                php, plp = jax.vmap(v2.parent_fp)(ph)
+            parent_hi = php[lane_id // G]
+            parent_lo = plp[lane_id // G]
+        return kh, kl, krows, cons_ok, inv, parent_hi, parent_lo
 
-            if por_mask is not None:
-                # Partial-order reduction (analysis/por.py table): keep
-                # ONE certified ample lane per state that has any,
-                # masking its siblings before compaction/fingerprinting
-                # — the reduction the coverage tables account as
-                # "pruned".  Rows with no certified enabled instance are
-                # untouched, so a state with an empty enabled set still
-                # reads as a deadlock.
-                amp = en & por_mask[None, :]
-                any_amp = jnp.any(amp, axis=1)
-                pri = jnp.where(amp, por_priority[None, :],
-                                jnp.int32(2147483647))
-                sel = jnp.argmin(pri, axis=1)
-                keep = jnp.where(
-                    any_amp[:, None],
-                    jnp.arange(G, dtype=_I32)[None, :] == sel[:, None],
-                    jnp.ones((B, G), bool))
-                pruned = en & ~keep
-                en = en & keep
-                ovf = ovf & keep
-            else:
-                pruned = None
+    insert = named_stage("insert", insert_fn)
 
-            # Progress limiting + lane compaction (ops/compact.py): take
-            # the longest parent prefix whose fan-out fits K, compact
-            # the enabled lanes to K slots — nothing is ever dropped, a
-            # fan-out burst just advances fewer parents this step.
-            P, total, lane_id, kvalid = compactor(en)
-            ptaken = jnp.arange(B, dtype=_I32) < P
-            en = en & ptaken[:, None]
-            ovf = ovf & ptaken[:, None]
+    @functools.partial(named_stage, "insert_enqueue")
+    def insert_enqueue(seen, kh, kl, kvalid, krows, cons_ok, next_count,
+                       qnext):
+        # v3: one Pallas kernel probes/inserts the K keys and
+        # appends each novel constraint-passing row at the running
+        # cursor — the novelty bit never returns to HBM between the
+        # stages.  The constraint/rows moved BEFORE the insert (they
+        # depend only on the candidates), so every value is
+        # bit-identical to the split path.
+        seen, new, fail, qnext = fused_tail(
+            seen, kh, kl, kvalid, krows, cons_ok, next_count, qnext)
+        next_count = next_count + jnp.sum(new & cons_ok, dtype=_I32)
+        return seen, new, fail, qnext, next_count
 
-            # Everything below — fingerprinting included — runs on the K
-            # compacted lanes only: gather the candidate structs first,
-            # hash after (identical to hashing the packed rows whenever
-            # pack_ok holds, and any overflow aborts the run above).
-            # Hashing before compaction would read every field of all
-            # B*G lanes for the ~94% that are disabled.
-            if v2 is None:
-                cflat = jax.tree.map(
-                    lambda a: a.reshape((BG,) + a.shape[2:]), cands)
-                kstates = jax.tree.map(lambda a: a[lane_id], cflat)
-                kh, kl = jax.vmap(fingerprint)(kstates)     # [K]
-            else:
-                # Gather K parent structs (from B parents, not B*G
-                # candidate lanes) and construct only those successors,
-                # with their fingerprints coming from the parents' hash
-                # sums + per-lane deltas (models/actions2.py).
-                ph = jax.vmap(v2.parent_hash)(states)
-                pidx = lane_id // G
-                kparents = jax.tree.map(lambda a: a[pidx], states)
-                kph = jax.tree.map(lambda a: a[pidx], ph)
-                kh, kl, kstates = jax.vmap(v2.lane_out)(
-                    kparents, kph, lane_id % G)
-
-            if constraint is not None:
-                cons_ok = jax.vmap(constraint)(kstates)
-            else:
-                cons_ok = jnp.ones((K,), bool)
-            krows = jax.vmap(flatten_state, (0, None))(kstates, dims)
-            # Invariant dispatch depends only on the candidates, so it
-            # sits before the insert on both paths (the v4 kernel
-            # computes it in-kernel; values are insert-independent).
-            if inv_id is not None:
-                inv = jax.vmap(inv_id)(kstates)
-            else:
-                inv = jnp.full((K,), -1, _I32)
-            if record_static:
-                if v2 is None:
-                    php, plp = jax.vmap(fingerprint)(states)  # [B]
-                else:
-                    php, plp = jax.vmap(v2.parent_fp)(ph)
-                parent_hi = php[lane_id // G]
-                parent_lo = plp[lane_id // G]
-
-        dead_b = valid & ptaken & ~jnp.any(en, axis=1) \
-            & ~jnp.any(ovf, axis=1)
-        dead_any_b = jnp.any(dead_b)
-        drow_b = rows[jnp.argmax(dead_b)]
-
-        if fused_tail is not None:
-            # v3: one Pallas kernel probes/inserts the K keys and
-            # appends each novel constraint-passing row at the running
-            # cursor — the novelty bit never returns to HBM between the
-            # stages.  The constraint/rows above moved BEFORE the
-            # insert (they depend only on the candidates), so every
-            # value below is bit-identical to the split path.
-            seen, new, fail, qnext = fused_tail(
-                seen, kh, kl, kvalid, krows, cons_ok, next_count, qnext)
-        else:
-            seen, new, fail = insert_fn(seen, kh, kl, kvalid)
-        viol = new & (inv >= 0)
-        viol_any_b = jnp.any(viol)
-        vpos = jnp.argmax(viol)
-
+    @functools.partial(named_stage, "enqueue")
+    def enqueue(qnext, next_count, krows, new, cons_ok):
         enq = new & cons_ok
-        if fused_tail is not None:
-            pass                        # rows already placed in-kernel
-        elif enqueue_method == "scatter":
+        if enqueue_method == "scatter":
             epos = next_count + jnp.cumsum(enq.astype(_I32)) - 1
             epos = jnp.where(enq, epos, Q + jnp.arange(K, dtype=_I32))
             qnext = qnext.at[epos].set(krows)
@@ -297,33 +351,47 @@ def build_chunk_body(*, dims, expand, fingerprint, pack_ok, inv_fns,
             win = jnp.where((w < new_n)[:, None], krows[src], win)
             qnext = jax.lax.dynamic_update_slice(
                 qnext, win, (next_count, jnp.int32(0)))
-        next_count = next_count + jnp.sum(enq, dtype=_I32)
+        return qnext, next_count + jnp.sum(enq, dtype=_I32)
 
-        if record_static:
-            actions = lane_id % G
-            if enqueue_method == "scatter":
-                tpos = jnp.where(
-                    new, tcount + jnp.cumsum(new.astype(_I32)) - 1,
-                    TQ + jnp.arange(K, dtype=_I32))
-                tbuf = tuple(
-                    buf.at[tpos].set(col)
-                    for buf, col in zip(
-                        tbuf, (kh, kl, parent_hi, parent_lo, actions)))
-            else:
-                from ..ops.compact import inv_positions
-                tn = jnp.sum(new, dtype=_I32)
-                tw = jnp.arange(K, dtype=_I32)
-                tsrc = inv_positions(new, K)
-                out = []
+    @functools.partial(named_stage, "record")
+    def record(tbuf, tcount, new, kh, kl, parent_hi, parent_lo, lane_id):
+        actions = lane_id % G
+        if enqueue_method == "scatter":
+            tpos = jnp.where(
+                new, tcount + jnp.cumsum(new.astype(_I32)) - 1,
+                TQ + jnp.arange(K, dtype=_I32))
+            tbuf = tuple(
+                buf.at[tpos].set(col)
                 for buf, col in zip(
-                        tbuf, (kh, kl, parent_hi, parent_lo, actions)):
-                    twin = jax.lax.dynamic_slice(buf, (tcount,), (K,))
-                    twin = jnp.where(tw < tn, col[tsrc], twin)
-                    out.append(jax.lax.dynamic_update_slice(
-                        buf, twin, (tcount,)))
-                tbuf = tuple(out)
-            tcount = tcount + jnp.sum(new, dtype=_I32)
+                    tbuf, (kh, kl, parent_hi, parent_lo, actions)))
+        else:
+            from ..ops.compact import inv_positions
+            tn = jnp.sum(new, dtype=_I32)
+            tw = jnp.arange(K, dtype=_I32)
+            tsrc = inv_positions(new, K)
+            out = []
+            for buf, col in zip(
+                    tbuf, (kh, kl, parent_hi, parent_lo, actions)):
+                twin = jax.lax.dynamic_slice(buf, (tcount,), (K,))
+                twin = jnp.where(tw < tn, col[tsrc], twin)
+                out.append(jax.lax.dynamic_update_slice(
+                    buf, twin, (tcount,)))
+            tbuf = tuple(out)
+        return tbuf, tcount + jnp.sum(new, dtype=_I32)
 
+    @functools.partial(named_stage, "stats")
+    def stats(counters, P, rows, valid, ptaken, en, ovf, pruned, total,
+              lane_id, new, fail, inv, krows, kh, kl):
+        (offset, steps, gen, newc, ovfc, dead_any, drow, viol_any, vinv,
+         vrow, vhi, vlo, fail_any, fam_counts, fam_new, expanded,
+         fam_pruned) = counters
+        dead_b = valid & ptaken & ~jnp.any(en, axis=1) \
+            & ~jnp.any(ovf, axis=1)
+        dead_any_b = jnp.any(dead_b)
+        drow_b = rows[jnp.argmax(dead_b)]
+        viol = new & (inv >= 0)
+        viol_any_b = jnp.any(viol)
+        vpos = jnp.argmax(viol)
         take_v = ~viol_any & viol_any_b
         vinv = jnp.where(take_v, inv[vpos], vinv)
         vrow = jnp.where(take_v, krows[vpos], vrow)
@@ -348,13 +416,40 @@ def build_chunk_body(*, dims, expand, fingerprint, pack_ok, inv_fns,
             fam_pruned = fam_pruned + jnp.stack(
                 [jnp.sum(ptr[:, off:off + sz], dtype=_I32)
                  for off, sz in fam_slices])
-        return (offset + P, steps + 1, qnext, next_count, seen, tbuf,
-                tcount, gen + total,
+        return (offset + P, steps + 1, gen + total,
                 newc + jnp.sum(new, dtype=_I32),
                 ovfc + jnp.sum(ovf, dtype=_I32),
                 dead_any | dead_any_b, drow,
                 viol_any | viol_any_b, vinv, vrow, vhi, vlo,
                 fail_any | fail, fam_counts, fam_new, expanded,
                 fam_pruned)
+
+    def chunk_body(qcur, cur_count, carry):
+        (offset, steps, qnext, next_count, seen, tbuf, tcount,
+         *counters) = carry
+        rows, valid, states = slice_(qcur, offset, cur_count)
+        if fused_front is not None:
+            (en, ovf, pruned, P, total, lane_id, kvalid, ptaken, kh, kl,
+             krows, cons_ok, inv, parent_hi, parent_lo) = front(rows, valid)
+        else:
+            cands, en, ovf, pruned = masks(states, valid)
+            en, ovf, P, total, lane_id, kvalid, ptaken = compact(en, ovf)
+            (kh, kl, krows, cons_ok, inv, parent_hi,
+             parent_lo) = construct(states, cands, lane_id)
+        if fused_tail is not None:
+            seen, new, fail, qnext, next_count = insert_enqueue(
+                seen, kh, kl, kvalid, krows, cons_ok, next_count, qnext)
+        else:
+            seen, new, fail = insert(seen, kh, kl, kvalid)
+            qnext, next_count = enqueue(qnext, next_count, krows, new,
+                                        cons_ok)
+        if record_static:
+            tbuf, tcount = record(tbuf, tcount, new, kh, kl, parent_hi,
+                                  parent_lo, lane_id)
+        offset, steps, *counters = stats(
+            (offset, steps, *counters), P, rows, valid, ptaken, en, ovf,
+            pruned, total, lane_id, new, fail, inv, krows, kh, kl)
+        return (offset, steps, qnext, next_count, seen, tbuf, tcount,
+                *counters)
 
     return chunk_body

@@ -3,19 +3,23 @@
 One spine, several legs:
 
 - :mod:`.metrics` — a zero-dep, thread-safe :class:`MetricsRegistry`
-  (counters / gauges / histograms) with a :meth:`~MetricsRegistry.phase_timer`
-  context manager wrapping the host-side phases of the BFS chunk loop
-  and the simulate/mesh paths;
+  (counters / gauges / histograms) with the one span primitive:
+  :meth:`~MetricsRegistry.phase_timer` around the host-side phases of
+  the BFS chunk loop and the simulate/mesh paths,
+  :meth:`~MetricsRegistry.scope` around what holds them (``run``,
+  ``level``, ``replay``);
 - :mod:`.events` — the structured JSONL :class:`RunEventLog`
   (run_start, level_complete, fpset_resize, spill, checkpoint,
   violation, deadlock, chunk_profile, coverage, run_end) written next
   to the checkpoint dir and per-host under ``parallel/mesh.py``;
 - :mod:`.tracing` — :class:`SpanTracer`, nested spans serialized as
   Chrome trace-event JSON (``--trace-out``; opens in Perfetto).
-  Attached to a registry it mirrors every phase_timer block;
+  Attached to a registry it receives every span, and holds a
+  ``jax.profiler.TraceAnnotation`` open for each (``raft.<name>`` in
+  any profiler capture);
 - :mod:`.profile` — :class:`ChunkProfiler`, the per-stage chunk
   decomposition behind ``--profile-chunks`` (expand / fingerprint /
-  dedup-insert / enqueue histograms + the run-end stage-budget table);
+  dedup-insert / enqueue histograms + the run-end stage table);
 - :mod:`.coverage` — :class:`ActionCoverage`, TLC-style per-action
   generated/distinct/disabled counters and the run-end coverage table;
 - :mod:`.flight` — the always-on :class:`FlightRecorder` black box

@@ -15,6 +15,14 @@ Metric name convention: ``<layer>/<what>`` with ``/`` separators, e.g.
 Phase timers observe into histograms named ``phase/<name>`` whose
 ``total`` is the accumulated seconds — ``phase_seconds()`` projects just
 that view, which is what run events and bench reports embed.
+
+One span primitive: ``open_span`` (``phase_timer`` and ``scope`` are its
+``with`` forms).  A span has a name, a start, an end and keyword
+arguments; its parent is the span open on the same thread when it
+started.  Closing it feeds three sinks: the histogram here, and through
+the duck-typed ``registry.tracer`` hook (obs/tracing.py) the Chrome
+trace file and a ``jax.profiler.TraceAnnotation`` on the device
+profiler's own clock.
 """
 
 from __future__ import annotations
@@ -33,6 +41,55 @@ _DEFAULT_BOUNDS = tuple(
     m * 10.0 ** e for e in range(-6, 3) for m in (1.0, 2.0, 5.0))
 
 PHASE_PREFIX = "phase/"
+# Spans that contain other spans (``run``, ``level``, ``replay``) observe
+# under their own prefix: the ``phase/`` totals stay a partition of the
+# wall (``phase_seconds``, ``level_complete.unattributed_seconds``).
+SCOPE_PREFIX = "scope/"
+
+# The spans open on each thread, outermost first.  Process-wide because
+# what reads it is: jax's monitoring listeners are registered once per
+# process, and the engines' compile listener (engine/bfs.py) charges a
+# compile to the innermost span open on the compiling thread.
+_OPEN = threading.local()
+
+
+def innermost_span():
+    """The innermost span open on the calling thread, or None."""
+    stack = getattr(_OPEN, "stack", None)
+    return stack[-1] if stack else None
+
+
+class Span:
+    """One open span; ``close()`` ends it and feeds the sinks.  After
+    the close ``seconds`` holds its duration."""
+
+    __slots__ = ("registry", "name", "seconds", "_hist", "_t0", "_token")
+
+    def __init__(self, registry, hist: str, name: str, args: dict):
+        self.registry, self.name, self._hist = registry, name, hist
+        self.seconds = None
+        stack = getattr(_OPEN, "stack", None)
+        if stack is None:
+            stack = _OPEN.stack = []
+        stack.append(self)
+        tracer = registry.tracer
+        self._token = (tracer.begin(name, args) if tracer is not None
+                       else None)
+        self._t0 = time.perf_counter()
+
+    def close(self) -> None:
+        if self.seconds is not None:
+            return
+        self.seconds = time.perf_counter() - self._t0
+        self.registry.observe(self._hist + self.name, self.seconds)
+        if self._token is not None:
+            self.registry.tracer.end(self._token)
+        # A loop-shaped span (``level``) may be closed after an
+        # exception unwound past it: whatever was opened inside it and
+        # never closed goes with it.
+        stack = _OPEN.stack
+        if self in stack:
+            del stack[stack.index(self):]
 
 
 class Histogram:
@@ -88,9 +145,9 @@ class MetricsRegistry:
         self._gauges: Dict[str, float] = {}
         self._histograms: Dict[str, Histogram] = {}
         # Optional span tracer (obs/tracing.py SpanTracer, duck-typed —
-        # this module stays import-free): when attached and enabled,
-        # every phase_timer block is mirrored as a Chrome-trace span, so
-        # one attachment instruments every existing phase site.
+        # this module stays import-free): when attached, every span is
+        # handed to its ``begin``/``end``, so one attachment instruments
+        # every phase site.
         self.tracer = None
 
     # -- writers -------------------------------------------------------
@@ -109,26 +166,44 @@ class MetricsRegistry:
                 h = self._histograms[name] = Histogram()
             h.observe(value)
 
+    def open_span(self, name: str, prefix: str = PHASE_PREFIX,
+                  **args) -> Span:
+        """Open a span on this thread; the caller closes it.  For the
+        loop-shaped scopes a ``with`` block cannot bracket."""
+        return Span(self, prefix, name, args)
+
     @contextmanager
-    def phase_timer(self, name: str):
+    def _spanning(self, prefix: str, name: str, args: dict):
+        span = Span(self, prefix, name, args)
+        try:
+            yield span
+        finally:
+            span.close()
+
+    def phase_timer(self, name: str, **args):
         """Accumulate wall seconds into the ``phase/<name>`` histogram.
         Phases are the host-side stages of an engine loop (chunk dispatch,
         stats fetch, spill drain, checkpoint, ...): non-overlapping by
         construction at the call sites, so their totals partition the
-        loop's wall time."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.observe(PHASE_PREFIX + name, time.perf_counter() - t0)
-            tracer = self.tracer
-            if tracer is not None and tracer.enabled:
-                tracer.complete(name, t0)
+        loop's wall time.  The ``with`` block yields the :class:`Span`."""
+        return self._spanning(PHASE_PREFIX, name, args)
+
+    def scope(self, name: str, **args):
+        """A span that contains phases (``run``, ``replay``): the same
+        sinks, its seconds under ``scope/<name>``."""
+        return self._spanning(SCOPE_PREFIX, name, args)
 
     # -- readers -------------------------------------------------------
     def counter_value(self, name: str) -> float:
         with self._lock:
             return self._counters.get(name, 0)
+
+    def counters(self, prefix: str) -> Dict[str, float]:
+        """{name less ``prefix``: value} of the counters that start with
+        it."""
+        with self._lock:
+            return {k[len(prefix):]: v for k, v in self._counters.items()
+                    if k.startswith(prefix)}
 
     def gauge_value(self, name: str, default: float = 0.0) -> float:
         with self._lock:

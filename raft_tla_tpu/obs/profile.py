@@ -1,26 +1,24 @@
 """Per-stage chunk profiler — the instrument behind ``--profile-chunks``.
 
-NORTHSTAR.md's decision rule needs per-stage timings of the chunk
-pipeline (expand / fingerprint / dedup-insert / enqueue) on whatever
-hardware a run actually lands on, and until now the only way to get them
-was the ad-hoc ``scripts/profile_step.py`` path on a synthetic frontier.
-This module puts that decomposition behind one API and INSIDE the
-engine: every Nth chunk call, the profiler re-runs the sampled batch
-through separately-jitted stage programs with ``block_until_ready``
-fencing between stages, accumulates per-stage histograms into the
+Per-stage timings of the chunk pipeline (expand / fingerprint /
+dedup-insert / enqueue) on whatever hardware a run lands on: every Nth
+chunk call, the profiler re-runs the sampled batch through
+separately-jitted stage programs with ``block_until_ready`` fencing
+between stages, accumulates per-stage histograms into the
 MetricsRegistry (``chunk_stage/<stage>``), and emits one
-``chunk_profile`` run event plus a stderr stage-budget table keyed to
-NORTHSTAR's measured per-stage budget at run end.
+``chunk_profile`` run event plus a stderr stage table at run end.  (The
+time of each stage inside the REAL fused chunk program is read from a
+profiler capture instead: the program names its stages, engine/chunk.py
+``STAGES``.)
 
 The profiler is **observational**: the engine's real fused chunk program
 still does all the work, and the sampled batch is re-expanded on the
 side purely for measurement — so engine results are bit-identical with
 profiling on or off (the acceptance contract), at the cost of roughly
 ``1/N`` extra compute.  The staged decomposition measures the v1
-(classical) pipeline regardless of which pipeline the engine runs: the
-stages are the NORTHSTAR budget's row headings, and cross-pipeline
-comparability of the headings matters more than mirroring v2's fused
-deltas.  The separately-timed ``total`` program (all four stages in one
+(classical) pipeline regardless of which pipeline the engine runs:
+cross-pipeline comparability of the headings matters more than
+mirroring v2's fused deltas.  The separately-timed ``total`` program (all four stages in one
 jit, non-donating) is the fusion reference: ``sum(stages)`` vs
 ``total`` prices the inter-stage materialization XLA elides.
 
@@ -76,18 +74,6 @@ STAGES_V4 = ("front", "insert_enqueue")
 STAGES_SWARM = ("expand", "choose", "latch", "ring_probe")
 
 STAGE_PREFIX = "chunk_stage/"
-
-#: NORTHSTAR.md §c measured v1 budget (ms/batch, B=2048, TPU v5e chip),
-#: folded onto this profiler's stage granularity: expand includes the
-#: compact stage (36.6 + 21.4), enqueue includes row materialization
-#: (24.6 + 14.5).  Reference column of the run-end table — compare
-#: shapes, not absolutes, off that hardware/batch.
-NORTHSTAR_BUDGET_MS = {
-    "expand": 58.0,
-    "fingerprint": 6.7,
-    "dedup_insert": 5.3,
-    "enqueue": 39.1,
-}
 
 
 def build_stage_programs(dims, B: int, K: int,
@@ -664,37 +650,28 @@ class ChunkProfiler:
             "pipeline": self.pipeline,
             "stages": {s: {"mean_seconds": round(means[s], 6),
                            "total_seconds":
-                               round(self._stage_totals[s], 6),
-                           # v3 stage names have no NORTHSTAR v1 budget
-                           # row; null, never a KeyError.
-                           "budget_ms_b2048": NORTHSTAR_BUDGET_MS.get(s)}
+                               round(self._stage_totals[s], 6)}
                        for s in self.stages} if self.samples else {},
             "fused_total_mean_seconds": round(means.get("total", 0.0), 6),
             "staged_sum_mean_seconds": round(staged_sum, 6),
         }
 
     def render_table(self) -> str:
-        """Run-end stage-budget table: measured mean ms per stage next to
-        NORTHSTAR §c's measured v1 budget (B=2048, v5e) — the shape
-        comparison that names which stage to fuse next.  v3 runs render
-        their fused-stage rows ("-" in the budget column: the v1 budget
-        has no such row) — coherent per-granularity output instead of a
-        KeyError on the new stage names."""
+        """Run-end stage table: measured mean ms per stage and its share
+        of their sum, at the granularity profiled (v3/v4 runs render
+        their fused-stage rows)."""
         means = self.stage_means()
         if not means:
             return "chunk profile: no samples"
         lines = [f"chunk profile ({self.samples} sampled batches, "
                  f"B={self.B}, K={self.K}, every {self.every}th call, "
                  f"{self.pipeline} stages):",
-                 f"  {'stage':14s} {'mean ms':>10s} {'share':>7s} "
-                 f"{'NORTHSTAR ms@B=2048':>20s}"]
+                 f"  {'stage':14s} {'mean ms':>10s} {'share':>7s}"]
         staged_sum = sum(means[s] for s in self.stages)
         for s in self.stages:
             ms = means[s] * 1e3
             share = means[s] / staged_sum if staged_sum else 0.0
-            budget = NORTHSTAR_BUDGET_MS.get(s)
-            btxt = f"{budget:20.1f}" if budget is not None else f"{'-':>20s}"
-            lines.append(f"  {s:14s} {ms:10.2f} {share:6.1%} {btxt}")
+            lines.append(f"  {s:14s} {ms:10.2f} {share:6.1%}")
         lines.append(f"  {'sum(stages)':14s} {staged_sum * 1e3:10.2f}")
         lines.append(f"  {'fused total':14s} {means['total'] * 1e3:10.2f}"
                      f"  (inter-stage materialization the fused program "
@@ -703,7 +680,7 @@ class ChunkProfiler:
 
     def finish(self, evlog, stream=None) -> None:
         """Run-end hook: emit the ``chunk_profile`` event and print the
-        stage-budget table.  No-op when nothing was sampled."""
+        stage table.  No-op when nothing was sampled."""
         if not self.samples:
             return
         evlog.emit("chunk_profile", **self.summary())
@@ -722,11 +699,11 @@ class XlaProfileCapture:
     ``jax.profiler.start_trace`` captures it (XPlane protos + a
     Perfetto-openable trace under ``<logdir>/plugins/profile/...``).
 
-    Correlation contract: each captured chunk dispatch is bracketed in
-    a ``jax.profiler.StepTraceAnnotation("chunk", step_num=i)`` — the
-    SAME span name the SpanTracer's ``phase_timer("chunk")`` records in
-    the ``--trace-out`` Chrome trace — so the device-profiler timeline
-    and the host span timeline line up by name + step index.
+    Correlation: every engine span is in the capture itself, on the
+    host's ``python`` line, as ``raft.<name>`` with its arguments as
+    stats (obs/tracing.py) — ``raft.chunk`` with ``call=<i>`` is the
+    dispatch this window counts — so the device timeline and the
+    ``--trace-out`` Chrome trace line up by name and call index.
 
     Observational and fail-soft: the capture never changes what the
     engine computes, and a profiler that cannot start (unsupported
@@ -757,8 +734,8 @@ class XlaProfileCapture:
     def step(self):
         """Context manager bracketing ONE chunk dispatch.  Starts the
         trace lazily on the first call (so warm-up compilation never
-        pollutes the capture), annotates the step, and stops after
-        ``chunks`` calls.  A no-op once done."""
+        pollutes the capture) and stops after ``chunks`` calls.  A no-op
+        once done."""
         from contextlib import contextmanager
 
         @contextmanager
@@ -771,12 +748,9 @@ class XlaProfileCapture:
                 if self.done:           # start failed
                     yield
                     return
-            import jax
             self.steps += 1
             try:
-                with jax.profiler.StepTraceAnnotation(
-                        "chunk", step_num=self.steps):
-                    yield
+                yield
             finally:
                 if self.steps >= self.chunks:
                     self.stop()

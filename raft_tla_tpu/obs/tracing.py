@@ -15,12 +15,21 @@ here imports jax.  A ``SpanTracer(None)`` is a no-op sink (the
 
 Wiring: the engines attach their tracer to the
 :class:`~raft_tla_tpu.obs.metrics.MetricsRegistry` (``registry.tracer``),
-which mirrors every ``phase_timer`` block into a span — one attachment
-instruments every existing phase site (chunk dispatch, stats fetch,
-spill, checkpoint, sim_chunk, server request latencies, ...).  The
-engines add the scopes phases can't express: a ``run`` span, one
-``level`` span per BFS level, and the supervisor adds one ``attempt``
-span per child run plus ``restart`` instants.
+which hands every span it opens (``phase_timer``, ``scope``,
+``open_span``) to :meth:`SpanTracer.begin`/:meth:`SpanTracer.end` — one
+attachment instruments every phase site (chunk dispatch, stats fetch,
+spill, checkpoint, sim_chunk, ...) and the scopes around them (``run``,
+one ``level`` per BFS level, ``replay``).  The supervisor adds one
+``attempt`` span per child run plus ``restart`` instants.
+
+The same ``begin``/``end`` hold an annotation open for the span when
+the tracer was given an ``annotate`` factory: the engines pass
+``jax.profiler.TraceAnnotation``, so inside any ``jax.profiler`` capture
+(the benchmark's ``--trace 1`` window, ``--xla-profile``) every span is
+on the host's ``python`` line of the same ``.xplane.pb`` as the device's
+operations, as ``raft.<name>`` with its arguments as event stats.  With
+no capture active an annotation is a flag check.  Every span also
+carries ``run=<n>`` once the engine has set :attr:`SpanTracer.run`.
 
 Format notes (the subset Perfetto accepts without complaint): a JSON
 *array* of event objects; ``ph: "X"`` complete events carry ``ts`` and
@@ -50,9 +59,18 @@ class SpanTracer:
     never blocks on disk.
     """
 
+    #: What an annotation's name starts with in a profiler capture.
+    ANNOTATION_PREFIX = "raft."
+
     def __init__(self, path: Optional[str] = None,
-                 process_name: str = "raft_tla_tpu"):
+                 process_name: str = "raft_tla_tpu", annotate=None):
         self.path = path
+        # ``annotate(name, **args)`` -> context manager (the engines pass
+        # jax.profiler.TraceAnnotation; this module imports no jax).
+        self._annotate = annotate
+        # The engine's count of run() calls, shared by the spans of one
+        # verdict (its run and the replay of what it found).
+        self.run: Optional[int] = None
         self._process_name = process_name
         self._lock = threading.Lock()
         self._pid = os.getpid()
@@ -102,6 +120,23 @@ class SpanTracer:
 
     def _now_us(self) -> float:
         return (time.perf_counter() - self._t0) * 1e6
+
+    def begin(self, name: str, args: dict):
+        """Start of a registry span (obs/metrics.py ``Span``): open its
+        annotation; ``end`` takes what this returns."""
+        if self.run is not None:
+            args = {"run": self.run, **args}
+        note = None
+        if self._annotate is not None:
+            note = self._annotate(self.ANNOTATION_PREFIX + name, **args)
+            note.__enter__()
+        return name, time.perf_counter(), args, note
+
+    def end(self, token) -> None:
+        name, start, args, note = token
+        if note is not None:
+            note.__exit__(None, None, None)
+        self.complete(name, start, **args)
 
     @contextmanager
     def span(self, name: str, **args):

@@ -53,9 +53,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..engine.chunk import build_chunk_body
-from ..engine.bfs import (EngineConfig, EngineResult, TraceStore, Violation,
-                          _exit_condition_hit, _family_groups_meta,
+from ..engine.chunk import build_chunk_body, tag_stages
+from ..engine.bfs import (BFSEngine, EngineConfig, EngineResult, TraceStore,
+                          Violation, _exit_condition_hit, _family_groups_meta,
                           _progress_line, build_root_check,
                           find_root_violation, make_trace_store)
 from ..models.actions import build_expand
@@ -112,7 +112,8 @@ class MeshBFSEngine:
                 if pc > 1:
                     root, ext = os.path.splitext(trace_out)
                     trace_out = f"{root}.p{pi}of{pc}{ext or '.json'}"
-            self.tracer = SpanTracer(trace_out)
+            self.tracer = SpanTracer(
+                trace_out, annotate=jax.profiler.TraceAnnotation)
         self.metrics.tracer = self.tracer
         # The per-stage chunk profiler is a single-chip instrument
         # (EngineConfig.profile_chunks_every rationale); the mesh's
@@ -315,7 +316,7 @@ class MeshBFSEngine:
                           shi, slo, ssize, tbuf, tcount0, max_steps):
             # Shapes inside shard_map: leading device axis of size 1.
             qcur_l, qnext_l = qcur[0], qnext[0]
-            cnt_l, ncnt_l = cur_counts[0], next_counts[0]
+            cnt_l, ncnt_l = cur_counts[0], tag_stages(next_counts[0])
             # The level width is derived IN-program (pmax over chips), so
             # the host never needs a global view of the per-chip counts —
             # a multi-controller requirement (parallel/multihost.py).
@@ -404,7 +405,8 @@ class MeshBFSEngine:
             (qnext_l, ncnt_l, seen_l, tbuf_l, tcnt_l, n_new, fail,
              vinfo) = local_absorb(
                 rows_l, states, valid_l, sent, sent, acts,
-                qnext[0], next_counts[0], seen_l, tbuf_l, tcount0[0])
+                qnext[0], tag_stages(next_counts[0]), seen_l, tbuf_l,
+                tcount0[0])
             viol_any, vinv, vrow, vhi, vlo = vinfo
             # Replicated stats + lowest-flagged-chip violation broadcast
             # (sharded_chunk rationale): the host reads no per-chip values.
@@ -612,9 +614,17 @@ class MeshBFSEngine:
         from ..engine.bfs import BFSEngine
         return BFSEngine._xla_profile_dir(self)
 
-    def _emit_level_event(self, res, frontier_rows):
-        from ..engine.bfs import BFSEngine
-        BFSEngine._emit_level_event(self, res, frontier_rows)
+    # The level event, the run-end report, the level span, the loop's
+    # work counters and the replay are the single-chip engine's own
+    # (they touch nothing of its).
+    _emit_level_event = BFSEngine._emit_level_event
+    _end_run = BFSEngine._end_run
+    _level_event = BFSEngine._level_event
+    _open_level_span = BFSEngine._open_level_span
+    _close_level_span = BFSEngine._close_level_span
+    _count_chunk_call = BFSEngine._count_chunk_call
+    _replay = BFSEngine._replay
+    _replay_step = BFSEngine._replay_step
 
     def _sample_skew(self, res, next_counts, ssize) -> None:
         """Per-shard balance telemetry, sampled at each level boundary
@@ -965,6 +975,7 @@ class MeshBFSEngine:
             max_chunks = max((-(-len(p) // B) for p in per_chip), default=0)
             drained = 0       # next-level rows pushed to host pools (global)
             cur_sum = 0       # next-level rows on device (replicated psum)
+            self._open_level_span(0)
             for c in range(max_chunks):
                 # StopAfter covers ingest; the first wave always runs
                 # (engine/bfs.py rationale).  Clock decisions are agreed
@@ -1006,6 +1017,7 @@ class MeshBFSEngine:
                      istats, ivrow, ivfp) = out
                     ist = np.asarray(istats)
                 res.distinct += int(ist[0])
+                mt.counter("engine/ingest_calls")
                 mt.counter("engine/distinct", int(ist[0]))
                 cur_sum = int(ist[3])
                 if int(ist[1]):
@@ -1074,6 +1086,7 @@ class MeshBFSEngine:
                     and res.diameter >= cfg.max_diameter:
                 res.stop_reason = "diameter_budget"
                 break
+            self._open_level_span(res.diameter + 1)
             # Level loop over segments: device-resident rows first, then
             # host-pool segments (balanced re-uploads).  Budgeted runs
             # slow-start each level (engine/bfs.py rationale).  The level
@@ -1141,6 +1154,7 @@ class MeshBFSEngine:
                     # time.
                     with mt.phase_timer("stats_fetch"):
                         st = np.asarray(stats)
+                    self._count_chunk_call(int(st[1]), int(st[15]))
                     if self._perf is not None and int(st[1]):
                         # Launch accounting's dynamic half (obs/perf.py)
                         # — host arithmetic on the fetched stats only.
@@ -1401,7 +1415,7 @@ class MeshBFSEngine:
             self._growth_stalls.append(
                 (self.n_dev * self._CL, round(stall, 3)))
             from ..obs import PHASE_PREFIX, device_memory_stats
-            self.metrics.observe(PHASE_PREFIX + "fpset_grow", stall)
+            self.metrics.observe(PHASE_PREFIX + "grow", stall)
             self.metrics.counter("engine/fpset_resizes")
             self._evlog.emit("fpset_resize",
                              capacity=self.n_dev * self._CL,

@@ -288,9 +288,8 @@ def test_v3_mesh_matches_v2():
 def test_v3_profiler_fused_stage_granularity():
     """--profile-chunks on a v3 engine: the profiler samples the
     fused-stage decomposition (masks/compact/fingerprint/
-    insert_enqueue), renders a coherent table ('-' where the NORTHSTAR
-    v1 budget has no row), and EngineResult.chunk_stages carries the
-    v3 keys bench_diff folds."""
+    insert_enqueue), renders a coherent table, and
+    EngineResult.chunk_stages carries the v3 keys bench_diff folds."""
     from raft_tla_tpu.engine.check import initial_states, make_engine
     setup = load_config("configs/MCraft_bounded.cfg")
     eng = make_engine(setup, EngineConfig(
@@ -305,4 +304,5 @@ def test_v3_profiler_fused_stage_granularity():
     assert "insert_enqueue" in table and "v3 stages" in table
     summary = prof.summary()
     assert summary["pipeline"] == "v3"
-    assert summary["stages"]["insert_enqueue"]["budget_ms_b2048"] is None
+    assert set(summary["stages"]["insert_enqueue"]) == {
+        "mean_seconds", "total_seconds"}
