@@ -138,11 +138,22 @@ def build_v2(dims: RaftDims) -> V2Pipeline:
                      for ln in (0, 1))
 
     def dvec(start, olds, news, count):
-        """Delta for ``count`` consecutive positions from ``start``."""
+        """Delta for ``count`` consecutive positions from ``start``.
+
+        The window of multipliers comes out of ``c_ord`` by an
+        arange-mask select (the idiom of models/actions.py ``_set1``),
+        NOT a traced-start ``dynamic_slice``: under ``vmap`` that is a
+        gather of ``count``-wide slices, which the TPU compiler runs as
+        a sequential loop over the lanes (5 servers: 4 loops x K trips
+        a pass).
+        """
+        sel = (start + jnp.arange(count, dtype=jnp.int32))[:, None] \
+            == jnp.arange(d_ordered, dtype=jnp.int32)[None, :]
         out = []
         for ln in (0, 1):
             c_ord, _, seed = consts[ln]
-            cs = jax.lax.dynamic_slice(c_ord, (start,), (count,))
+            cs = jnp.sum(jnp.where(sel, c_ord[None, :], _U32(0)), axis=1,
+                         dtype=_U32)
             out.append(jnp.sum(fmix32(_u(news) * cs + seed)
                                - fmix32(_u(olds) * cs + seed), dtype=_U32))
         return tuple(out)

@@ -15,6 +15,7 @@ import pytest
 from raft_tla_tpu.models import oracle as orc
 from raft_tla_tpu.models.actions import build_expand
 from raft_tla_tpu.models.actions2 import build_v2
+from raft_tla_tpu.models.dims import CANDIDATE, LEADER
 from raft_tla_tpu.models.invariants import constraint_py
 from raft_tla_tpu.models.pystate import init_state
 from raft_tla_tpu.models.schema import build_pack_guard, encode_state
@@ -22,9 +23,7 @@ from raft_tla_tpu.ops.fingerprint import build_fingerprint
 from raft_tla_tpu.utils.cfg import load_config
 
 
-@pytest.fixture(scope="module")
-def rig():
-    setup = load_config("configs/MCraft_bounded.cfg")
+def _build_rig(setup):
     dims = setup.dims
     expand = build_expand(dims)
     fp = build_fingerprint(dims)
@@ -49,6 +48,19 @@ def rig():
         return succ, en, ovf, h, l, phi, plo
 
     return setup, dims, jax.jit(fp), v1_all, v2_all
+
+
+@pytest.fixture(scope="module")
+def rig():
+    return _build_rig(load_config("configs/MCraft_bounded.cfg"))
+
+
+@pytest.fixture(scope="module")
+def rig5():
+    """The north star's widths (5 servers, MaxLogLen 4) with a small
+    bag: where ``dvec``'s N-wide window was a per-lane loop on the TPU."""
+    return _build_rig(load_config("configs/raft5_bounded.cfg",
+                                  n_msg_slots=8))
 
 
 def _assert_state_matches(rig_, s, ctx=""):
@@ -91,8 +103,34 @@ def test_v2_matches_v1_on_reachable_states(rig):
         _assert_state_matches(rig, s, ctx=f"reachable[{i}]")
 
 
-def test_v2_matches_v1_on_leader_and_pack_edge_states(rig):
+def _row_delta_states(base, dims):
+    """Leaders and an electable candidate whose ``next_index`` /
+    ``match_index`` rows differ in every column, so Restart and
+    BecomeLeader change whole rows and each of the N multipliers of the
+    row's window weighs a different delta."""
+    n, top = dims.n_servers, dims.max_log
+    lead = base.role.index(LEADER)
+    ni = tuple(tuple(1 + (2 * i + j + 1) % (top + 1) for j in range(n))
+               for i in range(n))
+    mi = tuple(tuple((3 * i + 2 * j + 1) % (top + 1) for j in range(n))
+               for i in range(n))
+    rows = base.replace(next_index=ni, match_index=mi)
+    cand_log = ((1, 1), (base.current_term[lead], 2))
+    cand = rows.replace(
+        role=tuple(CANDIDATE if i == lead else r
+                   for i, r in enumerate(rows.role)),
+        votes_granted=tuple((1 << n) - 1 if i == lead else v
+                            for i, v in enumerate(rows.votes_granted)),
+        log=tuple(cand_log if i == lead else lg
+                  for i, lg in enumerate(rows.log)))
+    return [rows, cand]
+
+
+@pytest.mark.parametrize("rig_name", ["rig", "rig5"])
+def test_v2_matches_v1_on_leader_and_pack_edge_states(rig_name, request):
+    rig = request.getfixturevalue(rig_name)
     setup, dims = rig[0], rig[1]
+    n = dims.n_servers
     import sys
     sys.path.insert(0, "scripts")
     from leader_bench import leader_states
@@ -104,13 +142,13 @@ def test_v2_matches_v1_on_leader_and_pack_edge_states(rig):
         if s_cnt.messages else None
     crafted = [
         # term at the uint8 edge: Timeout must overflow-flag, not wrap.
-        base.replace(current_term=tuple(255 for _ in base.current_term)),
-        base.replace(current_term=(254, 255, 255)),
+        base.replace(current_term=(255,) * n),
+        base.replace(current_term=(254,) + (255,) * (n - 1)),
         # lastLogTerm > 127 breaks the signed msg column 4: RequestVote
         # sends must overflow-flag (schema.build_pack_guard).
-        base.replace(current_term=(200, 200, 200),
-                     log=(((200, 1),), ((200, 2),), ())),
-    ]
+        base.replace(current_term=(200,) * n,
+                     log=(((200, 1),), ((200, 2),)) + ((),) * (n - 2)),
+    ] + _row_delta_states(base, dims)
     if mm is not None:
         crafted.append(s_cnt.replace(messages=frozenset({(mm, 255)})))
         crafted.append(s_cnt.replace(messages=frozenset({(mm, 254)})))

@@ -3,10 +3,13 @@
 The TPU's compiler is installed here and compiles for a described
 ``v5e:2x2`` topology.  These tests keep the cheap ones at REAL widths —
 ``configs/MCraft_bounded.cfg``: 473-byte packed rows, 132 action
-instances — so every later PR is guarded at no chip time:
+instances; the whole chunk also at ``configs/TPUraft.cfg``: 951-byte
+rows, 224 instances, 48 slots — so every later PR is guarded at no chip
+time:
 
 - the XLA main path: ``ops/fpset.py insert`` over the bench's 2^25-key
-  table, the whole v2 chunk program of ``BFSEngine`` at a small batch,
+  table, the whole v2 chunk program of ``BFSEngine`` at a small batch
+  (and that it holds no gather expanded into a per-lane loop),
   the swarm walk chunk at 1,024 walks, the mesh chunk over the four
   described chips (with the owner-routed dedup's ``all-to-all``);
 - one case per Pallas kernel.  None of them compiles today (PR 24 moved
@@ -27,6 +30,7 @@ cannot be read back without one).
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -98,17 +102,35 @@ def test_fpset_insert_over_the_real_table(one_chip):
     assert c.memory_analysis().argument_size_in_bytes >= 8 * SEEN
 
 
-def test_v2_chunk_program_small_batch(setup, one_chip):
+@pytest.mark.parametrize("cfg, width, instances", [
+    ("configs/MCraft_bounded.cfg", 473, 132),
+    ("configs/TPUraft.cfg", 951, 224),
+], ids=["mcraft3", "raft5"])
+def test_v2_chunk_program_small_batch(cfg, width, instances, one_chip):
     """The whole BFSEngine chunk program (pipeline=auto -> v2, trace
-    recording on) at real widths; batch 64 compiles in seconds, the
-    bench's 2048 in about a minute (hand rehearsal, CHANGES.md)."""
+    recording on) at both benchmark models' real widths; batch 64
+    compiles in seconds, the bench's 2048 in a minute or two (hand
+    rehearsal, CHANGES.md).
+
+    No ``while`` of the optimised program may be a gather XLA expanded
+    into a loop over the lanes: the chunk's own loop and ``insert``'s
+    probe loop are the only two.  ``actions2.dvec`` as a traced-start
+    ``dynamic_slice`` was four of them at 5 servers (PR 28), each K trips
+    a pass."""
     from raft_tla_tpu.engine.bfs import EngineConfig
     from raft_tla_tpu.engine.check import make_engine
-    eng = make_engine(setup, EngineConfig(
+    from raft_tla_tpu.models.schema import state_width
+    from raft_tla_tpu.utils.cfg import load_config
+    s = load_config(os.path.join(REPO, cfg))
+    assert (state_width(s.dims), s.dims.n_instances) == (width, instances)
+    eng = make_engine(s, EngineConfig(
         batch=64, queue_capacity=1 << 14, seen_capacity=1 << 17))
     assert eng._v2 is not None
     c = compile_for(eng._chunk, lambda a: one_chip, *eng.chunk_avals())
     assert c.memory_analysis().generated_code_size_in_bytes > 0
+    loops = re.findall(r' while\(.*op_name="([^"]*)"', c.as_text())
+    assert len(loops) >= 2, loops       # else the pattern found nothing
+    assert not [name for name in loops if name.endswith("/gather")], loops
 
 
 def test_swarm_walk_chunk_1024_walks(one_chip):
