@@ -1349,6 +1349,7 @@ class BFSEngine:
                 # Counted in the loop (WORK_COUNTERS), and the compiles
                 # and cache loads of this run by the span they fell in.
                 **work_counts(mt, self._work_base),
+                **self._run_end_extra(),
                 compiles=compiles_by_span(mt, self._compile_base),
                 memory=device_memory_stats(),
                 # Peak host RSS + one probe per visible device; CPU-only
@@ -1360,6 +1361,11 @@ class BFSEngine:
             _FLIGHT.disarm()     # completed or already-dumped: no atexit dump
             evlog.close()
             self._evlog = RunEventLog(None)
+
+    def _run_end_extra(self) -> dict:
+        """Further fields of ``run_end`` (the mesh engine's exchange and
+        per-chip counts)."""
+        return {}
 
     def _events_path(self):
         """Single-controller resolution; the mesh engine overrides with

@@ -25,6 +25,7 @@ import json
 import os
 import pickle
 import re
+import zlib
 from typing import Dict, Optional, Tuple
 
 import numpy as np
@@ -48,7 +49,16 @@ from ..resilience import faults
 # v3 base-dims files still load; v3 *variant* files (written before the
 # class was recorded) are rejected with a clear message rather than
 # guessed at.
-FORMAT_VERSION = 4
+# v5: every array is stored as ``<name>__z`` (its bytes deflated in
+# ``_CHUNK`` pieces by a pool of threads, concatenated) + ``<name>__zoff``
+# (the pieces' offsets), its shape and dtype in the metadata
+# (``deflated``); a small array is one piece.  One deflate stream per
+# array made a level-12 MCraft snapshot (2.4 GB of rows, 158 MB of keys,
+# 396 MB of trace records) a minute of one core; in pieces it is seconds,
+# and the load inflates them side by side too.  v3 and v4 files (plain
+# ``savez_compressed`` members) still load.
+FORMAT_VERSION = 5
+_CHUNK = 8 << 20
 
 # Restorable dims classes.  An allowlist, not pickle: checkpoint metadata
 # is JSON and the class name in it must map to a known, audited schema.
@@ -108,6 +118,34 @@ def _level_of(path: str) -> Optional[int]:
     return None
 
 
+def _pool():
+    from concurrent.futures import ThreadPoolExecutor
+    return ThreadPoolExecutor(max(1, min(32, os.cpu_count() or 1)))
+
+
+def _deflate(ex, arr: np.ndarray):
+    """(bytes deflated piece by piece, the pieces' offsets) of one
+    array; zlib releases the GIL, so the pieces go side by side."""
+    buf = memoryview(np.ascontiguousarray(arr).reshape(-1).view(np.uint8))
+    parts = list(ex.map(lambda i: zlib.compress(buf[i:i + _CHUNK], 1),
+                        range(0, len(buf), _CHUNK)))
+    offs = np.zeros(len(parts) + 1, np.int64)
+    np.cumsum([len(p) for p in parts], out=offs[1:])
+    return np.frombuffer(b"".join(parts), np.uint8), offs
+
+
+def _inflate(ex, z, name: str, spec: dict) -> np.ndarray:
+    blob, offs = memoryview(z[name + "__z"]), z[name + "__zoff"]
+    out = np.empty(spec["shape"], np.dtype(spec["dtype"]))
+    flat = memoryview(out.reshape(-1).view(np.uint8))
+
+    def piece(i):
+        flat[i * _CHUNK:(i + 1) * _CHUNK] = zlib.decompress(
+            blob[offs[i]:offs[i + 1]], bufsize=_CHUNK)
+    list(ex.map(piece, range(len(offs) - 1)))
+    return out
+
+
 def save(path: str, ckpt: Checkpoint) -> None:
     """Atomically write ``ckpt`` to ``path`` (a ``.npz`` file)."""
     from ..models.schema import state_width
@@ -117,6 +155,14 @@ def save(path: str, ckpt: Checkpoint) -> None:
                        piece=int(m.group(2)) if m else 0, path=path):
             # Injected: this controller died before its piece landed.
             return
+    arrays = dict(
+        frontier=np.ascontiguousarray(ckpt.frontier).astype(
+            np.uint8, casting="safe", copy=False),
+        seen_hi=np.ascontiguousarray(ckpt.seen_hi, np.uint32),
+        seen_lo=np.ascontiguousarray(ckpt.seen_lo, np.uint32),
+        trace_fps=np.ascontiguousarray(ckpt.trace_fps, np.uint64),
+        trace_parents=np.ascontiguousarray(ckpt.trace_parents, np.uint64),
+        trace_actions=np.ascontiguousarray(ckpt.trace_actions, np.int32))
     check_dims_checkpointable(ckpt.dims)
     cls_name = type(ckpt.dims).__name__
     meta = {
@@ -131,20 +177,18 @@ def save(path: str, ckpt: Checkpoint) -> None:
         "action_counts": dict(ckpt.action_counts),
         "wall_seconds": ckpt.wall_seconds,
     }
+    meta["deflated"] = {k: {"shape": list(a.shape), "dtype": a.dtype.str}
+                        for k, a in arrays.items()}
+    members = {}
+    with _pool() as ex:
+        for k, a in arrays.items():
+            members[k + "__z"], members[k + "__zoff"] = _deflate(ex, a)
     os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
     tmp = path + ".tmp"
     with open(tmp, "wb") as f:
-        np.savez_compressed(
-            f,
-            meta=np.frombuffer(json.dumps(meta).encode(), np.uint8),
-            frontier=np.ascontiguousarray(ckpt.frontier).astype(
-                np.uint8, casting="safe"),
-            seen_hi=np.ascontiguousarray(ckpt.seen_hi, np.uint32),
-            seen_lo=np.ascontiguousarray(ckpt.seen_lo, np.uint32),
-            trace_fps=np.ascontiguousarray(ckpt.trace_fps, np.uint64),
-            trace_parents=np.ascontiguousarray(ckpt.trace_parents, np.uint64),
-            trace_actions=np.ascontiguousarray(ckpt.trace_actions, np.int32),
-            roots=np.frombuffer(pickle.dumps(ckpt.roots), np.uint8))
+        np.savez(f, meta=np.frombuffer(json.dumps(meta).encode(), np.uint8),
+                 roots=np.frombuffer(pickle.dumps(ckpt.roots), np.uint8),
+                 **members)
         f.flush()
         os.fsync(f.fileno())     # the rename must never land a torn file
     if faults.ACTIVE:
@@ -224,12 +268,12 @@ def load(path: str) -> Checkpoint:
 def _load_one(path: str) -> Checkpoint:
     with np.load(path) as z:
         meta = json.loads(bytes(z["meta"]).decode())
-        if meta["version"] not in (3, FORMAT_VERSION):
+        if meta["version"] not in (3, 4, FORMAT_VERSION):
             # Both loadable versions in the message: "!= v4" used to send
             # v3 holders hunting for a nonexistent problem (ADVICE r5).
             raise ValueError(
                 f"checkpoint format v{meta['version']} not in "
-                f"(v3, v{FORMAT_VERSION})")
+                f"(v3, v4, v{FORMAT_VERSION})")
         # v3 snapshots predate dims_class; a v3 file carrying variant-only
         # keys (e.g. 'targets') cannot be restored to the right class with
         # confidence, so it is rejected rather than guessed at.
@@ -262,20 +306,27 @@ def _load_one(path: str) -> Checkpoint:
                     f"checkpoint row width {meta['state_width']} != "
                     f"{state_width(dims)} for {cls.__name__}: the packed "
                     "layout changed since this snapshot was written")
+        deflated = meta.get("deflated", {})     # none before v5
+        with _pool() as ex:
+            got = {name: (_inflate(ex, z, name, deflated[name])
+                          if name in deflated else z[name])
+                   for name in ("frontier", "seen_hi", "seen_lo", "trace_fps",
+                                "trace_parents", "trace_actions")}
+        member = got.__getitem__
         return Checkpoint(
             dims=dims,
-            frontier=z["frontier"],
-            seen_hi=z["seen_hi"],
-            seen_lo=z["seen_lo"],
+            frontier=member("frontier"),
+            seen_hi=member("seen_hi"),
+            seen_lo=member("seen_lo"),
             distinct=meta["distinct"],
             generated=meta["generated"],
             diameter=meta["diameter"],
             levels=tuple(meta["levels"]),
             action_counts=dict(meta.get("action_counts", {})),
             wall_seconds=float(meta.get("wall_seconds", 0.0)),
-            trace_fps=z["trace_fps"],
-            trace_parents=z["trace_parents"],
-            trace_actions=z["trace_actions"],
+            trace_fps=member("trace_fps"),
+            trace_parents=member("trace_parents"),
+            trace_actions=member("trace_actions"),
             roots=pickle.loads(bytes(z["roots"])))
 
 

@@ -42,7 +42,11 @@ inline uint64_t mix(uint64_t x) {
     return x ^ (x >> 31);
 }
 
-void grow(Store* s);
+void rehash(Store* s, uint64_t new_capacity);
+
+inline bool crowded(uint64_t size, uint64_t capacity) {
+    return size * 10 >= capacity * 7;
+}
 
 inline void insert_one(Store* s, uint64_t fp, uint64_t parent,
                        int32_t action) {
@@ -54,19 +58,32 @@ inline void insert_one(Store* s, uint64_t fp, uint64_t parent,
     }
     s->slots[i] = Entry{fp, parent, action, 1};
     s->size++;
-    if (s->size * 10 >= s->capacity * 7) grow(s);
+    if (crowded(s->size, s->capacity)) rehash(s, s->capacity << 1);
 }
 
-void grow(Store* s) {
+void rehash(Store* s, uint64_t new_capacity) {
     Entry* old = s->slots;
     uint64_t old_cap = s->capacity;
-    s->capacity <<= 1;
+    s->capacity = new_capacity;
     s->slots = static_cast<Entry*>(calloc(s->capacity, sizeof(Entry)));
     s->size = 0;
     for (uint64_t i = 0; i < old_cap; i++)
         if (old[i].used)
             insert_one(s, old[i].fp, old[i].parent, old[i].action);
     free(old);
+}
+
+// Room for `n` more records before the first of them goes in.  A batch
+// that comes from another store's export arrives in THAT store's slot
+// order, i.e. sorted by the low bits of the same mix(): fed to a table
+// that doubles on the way, the records wrap onto slots the batch's own
+// head already filled and pile into one run that every later insert
+// walks (19.8 M records of a level-12 snapshot: over half an hour, where
+// a table sized first takes them in slot order, in seconds).
+void reserve(Store* s, uint64_t n) {
+    uint64_t cap = s->capacity;
+    while (crowded(s->size + n, cap)) cap <<= 1;
+    if (cap != s->capacity) rehash(s, cap);
 }
 
 }  // namespace
@@ -94,6 +111,7 @@ uint64_t ts_size(void* h) { return static_cast<Store*>(h)->size; }
 void ts_add_batch(void* h, const uint64_t* fps, const uint64_t* parents,
                   const int32_t* actions, uint64_t n) {
     Store* s = static_cast<Store*>(h);
+    reserve(s, n);
     for (uint64_t k = 0; k < n; k++)
         insert_one(s, fps[k], parents[k], actions[k]);
 }

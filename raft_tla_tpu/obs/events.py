@@ -59,6 +59,7 @@ KNOWN_EVENTS = (
     # (parallel/mesh.py skew telemetry).
     "perf",             # launch/roofline/advisor block; payload: "perf"
     "skew",             # shard imbalance warning; payload: "balance"
+    "rebalance",        # the mesh dealt an uneven frontier out evenly
     # Swarm tier (engine/swarm.py): periodic walker progress.  Swarm
     # runs also attach the same ``swarm`` payload object to their
     # ``run_end`` (exhaustive run_ends carry none, so only the

@@ -27,6 +27,10 @@ Runtime parity with the single-chip engine (engine/bfs.py):
   chunk exits and the host drains ALL chips' queues into one host pool
   (TLC's disk queue); pool segments re-upload *balanced* across chips, so
   spill doubles as load rebalancing;
+- **level-boundary deal-out**: rows enqueue where they were generated, so
+  from one root they would never leave its chip; a frontier whose fullest
+  chip holds over a batch more than its even share is drained and
+  re-uploaded balanced before the level is expanded (``_deal_out``);
 - **seen-set growth**: when any shard passes half load the host pulls its
   keys and rebuilds every shard at double capacity (owner = fp mod n is
   unchanged, so keys stay on their chips);
@@ -53,6 +57,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from jax.experimental.xla_metadata import set_xla_metadata
+
 from ..engine.chunk import build_chunk_body, tag_stages
 from ..engine.bfs import (BFSEngine, EngineConfig, EngineResult, TraceStore,
                           Violation, _exit_condition_hit, _family_groups_meta,
@@ -74,6 +80,29 @@ from ..resilience import faults as _faults
 
 _I32 = jnp.int32
 _U32 = jnp.uint32
+
+# What the mesh adds to a pass, as named scopes of its own inside the
+# shared body's ``insert`` stage (engine/chunk.py STAGES) and around the
+# loop: ``exchange`` (bucket by owner, the forward all_to_all of the two
+# fingerprint halves), ``owner_insert`` (the shard's insert on the union
+# of arriving queries), ``return`` (the reverse all_to_all of novelty
+# bits and the un-bucketing), ``agree`` (the psums of the loop condition
+# and of the statistics).  Scope names are not in the compile-cache key
+# (chunk.py STAGES_TAG rationale), so the mesh programs carry a tag of
+# their own beside the shared one: change it with these names, and the
+# one-chip programs keep their keys.
+MESH_STAGES = ("exchange", "owner_insert", "return", "agree")
+MESH_STAGES_TAG = "m1"
+
+# Rows of one frontier upload step.
+UPLOAD_ROWS = 1 << 16
+
+
+def tag_mesh_stages(count):
+    """``count`` through an ``add 0`` that carries both tags (chunk.py
+    ``tag_stages``): the shared stage names' and the mesh's own."""
+    with set_xla_metadata(mesh_stages_tag=MESH_STAGES_TAG):
+        return tag_stages(count) + 0
 
 
 class MeshBFSEngine:
@@ -204,25 +233,32 @@ class MeshBFSEngine:
             Exactly one copy of each globally-new key (across all chips)
             gets the bit."""
             k = fph.shape[0]
-            fph = jnp.where(valid, fph, SENTINEL)
-            fpl = jnp.where(valid, fpl, SENTINEL)
-            owner = (fph % _U32(n)).astype(_I32)
-            perm = jnp.argsort(owner, stable=True)
-            osort = owner[perm]
-            q_hi, q_lo = fph[perm], fpl[perm]
-            block_start = jnp.searchsorted(osort, jnp.arange(n, dtype=_I32))
-            rank = jnp.arange(k, dtype=_I32) - block_start[osort]
-            bh = jnp.full((n, k), SENTINEL, _U32).at[osort, rank].set(q_hi)
-            bl = jnp.full((n, k), SENTINEL, _U32).at[osort, rank].set(q_lo)
-            bh = jax.lax.all_to_all(bh, "x", 0, 0, tiled=True)
-            bl = jax.lax.all_to_all(bl, "x", 0, 0, tiled=True)
-            rh, rl = bh.reshape(-1), bl.reshape(-1)
-            rvalid = ~((rh == SENTINEL) & (rl == SENTINEL))
-            seen_local, qnew, fail = fpset.insert(seen_local, rh, rl, rvalid)
-            nov = jax.lax.all_to_all(qnew.reshape(n, k), "x", 0, 0,
-                                     tiled=True)
-            new_sortpos = nov[osort, rank]
-            new = jnp.zeros((k,), bool).at[perm].set(new_sortpos)
+            with jax.named_scope("exchange"):
+                fph = jnp.where(valid, fph, SENTINEL)
+                fpl = jnp.where(valid, fpl, SENTINEL)
+                owner = (fph % _U32(n)).astype(_I32)
+                perm = jnp.argsort(owner, stable=True)
+                osort = owner[perm]
+                q_hi, q_lo = fph[perm], fpl[perm]
+                block_start = jnp.searchsorted(
+                    osort, jnp.arange(n, dtype=_I32))
+                rank = jnp.arange(k, dtype=_I32) - block_start[osort]
+                bh = jnp.full((n, k), SENTINEL, _U32).at[
+                    osort, rank].set(q_hi)
+                bl = jnp.full((n, k), SENTINEL, _U32).at[
+                    osort, rank].set(q_lo)
+                bh = jax.lax.all_to_all(bh, "x", 0, 0, tiled=True)
+                bl = jax.lax.all_to_all(bl, "x", 0, 0, tiled=True)
+            with jax.named_scope("owner_insert"):
+                rh, rl = bh.reshape(-1), bl.reshape(-1)
+                rvalid = ~((rh == SENTINEL) & (rl == SENTINEL))
+                seen_local, qnew, fail = fpset.insert(
+                    seen_local, rh, rl, rvalid)
+            with jax.named_scope("return"):
+                nov = jax.lax.all_to_all(qnew.reshape(n, k), "x", 0, 0,
+                                         tiled=True)
+                new_sortpos = nov[osort, rank]
+                new = jnp.zeros((k,), bool).at[perm].set(new_sortpos)
             return seen_local, new, fail
 
         def local_absorb(crows, cands, en, parent_hi, parent_lo, actions,
@@ -312,15 +348,58 @@ class MeshBFSEngine:
             enqueue_method=enqueue_method,
             por_mask=por_mask, por_priority=por_priority)
 
+        def agreed_stats(offset, steps, gen, newc, ovfc, fail_any,
+                         max_count, ncnt_l, cnt_l, seen_l, viol_any, vinv,
+                         vrow, vhi, vlo, dead_any, drow, expanded,
+                         fam_counts, fam_new, fam_pruned):
+            """What a chunk call hands the host, the same on every chip:
+            the psum/pmax-combined statistics, the violation/deadlock
+            rows broadcast from the lowest-indexed flagged chip (no
+            per-chip inspection on the host side), and last each chip's
+            own (parents expanded, next-level rows, shard keys)."""
+            g_gen = jax.lax.psum(gen, "x")
+            g_new = jax.lax.psum(newc, "x")
+            g_ovf = jax.lax.psum(ovfc, "x")
+            g_fail = jax.lax.psum(fail_any.astype(_I32), "x")
+            from .multihost import bcast_lowest_flagged
+            v_any, vinv_g, vrow_g, vhi_g, vlo_g = bcast_lowest_flagged(
+                "x", viol_any, vinv, vrow, vhi, vlo)
+            d_any, drow_g = bcast_lowest_flagged("x", dead_any, drow)
+            # Packed replicated stats: one host fetch per call
+            # (engine/bfs.py contract).  Layout documented at the read
+            # site in run().
+            stats = jnp.concatenate([
+                jnp.stack([offset, steps, g_gen, g_new, g_ovf, g_fail,
+                           max_count,
+                           jax.lax.pmax(ncnt_l, "x"),
+                           jax.lax.psum(ncnt_l, "x"),
+                           jax.lax.psum(
+                               jnp.maximum(cnt_l - offset, 0), "x"),
+                           jax.lax.pmax(seen_l.size, "x"),
+                           v_any.astype(_I32),
+                           d_any.astype(_I32),
+                           vinv_g,
+                           jax.lax.psum(cnt_l, "x"),
+                           jax.lax.psum(expanded, "x")]),
+                jax.lax.psum(fam_counts, "x"),
+                jax.lax.psum(fam_new, "x"),
+                jax.lax.psum(fam_pruned, "x"),
+                jax.lax.all_gather(
+                    jnp.stack([expanded, ncnt_l, seen_l.size]),
+                    "x").T.reshape(-1)])
+            return stats, drow_g, vrow_g, vhi_g, vlo_g
+
         def sharded_chunk(qcur, cur_counts, offset0, qnext, next_counts,
                           shi, slo, ssize, tbuf, tcount0, max_steps):
             # Shapes inside shard_map: leading device axis of size 1.
             qcur_l, qnext_l = qcur[0], qnext[0]
-            cnt_l, ncnt_l = cur_counts[0], tag_stages(next_counts[0])
+            cnt_l = cur_counts[0]
+            ncnt_l = tag_mesh_stages(next_counts[0])
             # The level width is derived IN-program (pmax over chips), so
             # the host never needs a global view of the per-chip counts —
             # a multi-controller requirement (parallel/multihost.py).
-            max_count = jax.lax.pmax(cnt_l, "x")
+            with jax.named_scope("agree"):
+                max_count = jax.lax.pmax(cnt_l, "x")
             seen_l = fpset.FPSet(hi=shi[0], lo=slo[0], size=ssize[0])
             tbuf_l = tuple(t[0] for t in tbuf)
             init = (offset0, jnp.int32(0), qnext_l, ncnt_l, seen_l, tbuf_l,
@@ -349,7 +428,8 @@ class MeshBFSEngine:
                     stop = stop + dead_any.astype(_I32)
                 if record_static:
                     blocked = blocked + (tcnt_c > TQ - K).astype(_I32)
-                return more & (jax.lax.psum(blocked + stop, "x") == 0)
+                with jax.named_scope("agree"):
+                    return more & (jax.lax.psum(blocked + stop, "x") == 0)
 
             out = jax.lax.while_loop(
                 cond, lambda c: chunk_body(qcur_l, cnt_l, c), init)
@@ -357,37 +437,12 @@ class MeshBFSEngine:
              gen, newc, ovfc, dead_any, drow, viol_any, vinv, vrow,
              vhi, vlo, fail_any, fam_counts, fam_new, expanded,
              fam_pruned) = out
-            g_gen = jax.lax.psum(gen, "x")
-            g_new = jax.lax.psum(newc, "x")
-            g_ovf = jax.lax.psum(ovfc, "x")
-            g_fail = jax.lax.psum(fail_any.astype(_I32), "x")
-            # Violation/deadlock rows are broadcast from the lowest-indexed
-            # flagged chip so EVERY host reads identical replicated values
-            # — no per-chip inspection on the host side.
-            from .multihost import bcast_lowest_flagged
-            v_any, vinv_g, vrow_g, vhi_g, vlo_g = bcast_lowest_flagged(
-                "x", viol_any, vinv, vrow, vhi, vlo)
-            d_any, drow_g = bcast_lowest_flagged("x", dead_any, drow)
-
-            # Packed replicated stats: one host fetch per call
-            # (engine/bfs.py contract).  Layout documented at the read
-            # site in run().
-            stats = jnp.concatenate([
-                jnp.stack([offset, steps, g_gen, g_new, g_ovf, g_fail,
-                           max_count,
-                           jax.lax.pmax(ncnt_l, "x"),
-                           jax.lax.psum(ncnt_l, "x"),
-                           jax.lax.psum(
-                               jnp.maximum(cnt_l - offset, 0), "x"),
-                           jax.lax.pmax(seen_l.size, "x"),
-                           v_any.astype(_I32),
-                           d_any.astype(_I32),
-                           vinv_g,
-                           jax.lax.psum(cnt_l, "x"),
-                           jax.lax.psum(expanded, "x")]),
-                jax.lax.psum(fam_counts, "x"),
-                jax.lax.psum(fam_new, "x"),
-                jax.lax.psum(fam_pruned, "x")])
+            with jax.named_scope("agree"):
+                stats, drow_g, vrow_g, vhi_g, vlo_g = agreed_stats(
+                    offset, steps, gen, newc, ovfc, fail_any, max_count,
+                    ncnt_l, cnt_l, seen_l, viol_any, vinv, vrow, vhi, vlo,
+                    dead_any, drow, expanded, fam_counts, fam_new,
+                    fam_pruned)
             vfp_g = jnp.stack([vhi_g, vlo_g])
             return (qnext_l[None], ncnt_l[None], seen_l.hi[None],
                     seen_l.lo[None], seen_l.size[None],
@@ -405,22 +460,23 @@ class MeshBFSEngine:
             (qnext_l, ncnt_l, seen_l, tbuf_l, tcnt_l, n_new, fail,
              vinfo) = local_absorb(
                 rows_l, states, valid_l, sent, sent, acts,
-                qnext[0], tag_stages(next_counts[0]), seen_l, tbuf_l,
+                qnext[0], tag_mesh_stages(next_counts[0]), seen_l, tbuf_l,
                 tcount0[0])
             viol_any, vinv, vrow, vhi, vlo = vinfo
             # Replicated stats + lowest-flagged-chip violation broadcast
             # (sharded_chunk rationale): the host reads no per-chip values.
             from .multihost import bcast_lowest_flagged
-            v_any, vinv_g, vrow_g, vhi_g, vlo_g = bcast_lowest_flagged(
-                "x", viol_any, vinv, vrow, vhi, vlo)
-            stats = jnp.stack([
-                jax.lax.psum(n_new, "x"),
-                jax.lax.psum(fail.astype(_I32), "x"),
-                jax.lax.pmax(ncnt_l, "x"),
-                jax.lax.psum(ncnt_l, "x"),
-                v_any.astype(_I32),
-                vinv_g,
-                jax.lax.pmax(seen_l.size, "x")])
+            with jax.named_scope("agree"):
+                v_any, vinv_g, vrow_g, vhi_g, vlo_g = bcast_lowest_flagged(
+                    "x", viol_any, vinv, vrow, vhi, vlo)
+                stats = jnp.stack([
+                    jax.lax.psum(n_new, "x"),
+                    jax.lax.psum(fail.astype(_I32), "x"),
+                    jax.lax.pmax(ncnt_l, "x"),
+                    jax.lax.psum(ncnt_l, "x"),
+                    v_any.astype(_I32),
+                    vinv_g,
+                    jax.lax.pmax(seen_l.size, "x")])
             vfp = jnp.stack([vhi_g, vlo_g])
             return (qnext_l[None], ncnt_l[None], seen_l.hi[None],
                     seen_l.lo[None], seen_l.size[None],
@@ -470,6 +526,26 @@ class MeshBFSEngine:
                 jax.vmap(unflatten_state, (0, None))(rows, dims))
 
         self._fp_rows = jax.jit(fp_rows)
+
+        # The host loop's own small programs: a shard's keys inserted
+        # where the shard lies, every shard at once (_shards_from_keys);
+        # a step of frontier rows written into a queue (_upload_segment);
+        # the fills of _sharded_full, one per (shape, dtype, value).
+        self._full_fns = {}
+
+        def insert_keys(hi, lo, size, qh, ql, valid):
+            s, _new, fail = fpset.insert_unique(
+                fpset.FPSet(hi=hi[0], lo=lo[0], size=size[0]),
+                qh[0], ql[0], valid[0])
+            return s.hi[None], s.lo[None], s.size[None], fail[None]
+
+        self._insert_keys = jax.jit(
+            shard(insert_keys, in_specs=(sx,) * 6, out_specs=(sx,) * 4),
+            donate_argnums=(0, 1, 2))
+        self._write_rows = jax.jit(
+            lambda q, rows, at: jax.lax.dynamic_update_slice(
+                q, rows, (jnp.int32(0), at, jnp.int32(0))),
+            donate_argnums=(0,))
         self._expand1 = jax.jit(expand)
         self._fp_batch = jax.jit(jax.vmap(fingerprint))
         self._root_check = (build_root_check(inv_fns, fingerprint)
@@ -493,6 +569,18 @@ class MeshBFSEngine:
                 tbuf_av, i32s, scalar)
 
     # ------------------------------------------------------------------
+    def _sharded_full(self, shape, dtype, fill=0):
+        """An array of ``fill`` allocated ALREADY SHARDED over the mesh.
+        One program per (shape, dtype, fill), kept: the loop asks for a
+        zeroed trace count after every chunk call, and a new ``jit`` of
+        a new lambda each time was a compile each time."""
+        key = (tuple(shape), jnp.dtype(dtype).name, int(fill))
+        if key not in self._full_fns:
+            self._full_fns[key] = jax.jit(
+                lambda: jnp.full(shape, fill, dtype),
+                out_shardings=NamedSharding(self.mesh, P("x")))
+        return self._full_fns[key]()
+
     def _grow_seen(self, shi, slo, ssize, new_cl=None):
         """Rebuild this controller's shards at double (or given) capacity.
         Owner assignment (fp_hi mod n) is capacity-independent, so keys
@@ -500,57 +588,62 @@ class MeshBFSEngine:
         addressable shards and the arrays are reassembled shard-by-shard
         (multi-controller rule 3).  The chunk program recompiles for the
         new shape — identically everywhere."""
-        n = self.n_dev
-        new_cl = fpset._capacity(new_cl or 2 * self._CL)
-
-        def by_row(arr):
-            return {s.index[0].start: np.asarray(s.data)[0]
-                    for s in arr.addressable_shards}
-
-        his, los = by_row(shi), by_row(slo)
-        hi_b, lo_b, sz_b = {}, {}, {}
-        for d, hi_h in his.items():
-            lo_h = los[d]
-            real = ~((hi_h == SENTINEL) & (lo_h == SENTINEL))
-            s = fpset.from_host_keys(hi_h[real], lo_h[real], new_cl)
-            hi_b[d] = np.asarray(s.hi)[None]
-            lo_b[d] = np.asarray(s.lo)[None]
-            sz_b[d] = np.asarray(s.size, np.int32).reshape(1)
-        self._CL = new_cl
+        keys = self.shard_keys(shi, slo)
+        self._CL = fpset._capacity(new_cl or 2 * self._CL)
         self._rebuild_programs()
-        return self._assemble_sharded_fpset(hi_b, lo_b, sz_b)
-
-    def _assemble_sharded_fpset(self, hi_b, lo_b, sz_b):
-        """(shi, slo, ssize) sharded arrays from per-LOCAL-device host
-        shards ({global chip row -> [1, CL] / [1] arrays}); other
-        controllers supply their own rows via the same callbacks."""
-        n, cl = self.n_dev, self._CL
-        sh = NamedSharding(self.mesh, P("x"))
-        return (jax.make_array_from_callback(
-                    (n, cl), sh, lambda idx: hi_b[idx[0].start]),
-                jax.make_array_from_callback(
-                    (n, cl), sh, lambda idx: lo_b[idx[0].start]),
-                jax.make_array_from_callback(
-                    (n,), sh, lambda idx: sz_b[idx[0].start]))
+        return self._shards_from_keys(
+            np.concatenate([hi for hi, _lo in keys.values()]),
+            np.concatenate([lo for _hi, lo in keys.values()]))
 
     def _shards_from_keys(self, keys_hi, keys_lo):
         """Rebuild the sharded FPSet arrays from a global flat key set
-        (owner = fp_hi mod n); each controller materializes only its
-        addressable shards, shard-by-shard (never the whole n-chip table
-        on one device)."""
-        owner = (keys_hi % self.n_dev).astype(np.int64)
+        (owner = fp_hi mod n).  Each controller supplies only the keys
+        of its addressable shards; every shard is built ON the chip that
+        owns it, all of them side by side by one program over the mesh
+        (``insert_unique`` under ``shard_map``), a piece of keys a chip
+        at a time, and never comes to the host (a 2^25-slot shard is
+        268 MB; four of them built on chip 0, fetched and sent up again
+        were most of a deep resume)."""
+        n, cl = self.n_dev, self._CL
+        keys_hi = np.asarray(keys_hi).astype(np.uint32, copy=False)
+        keys_lo = np.asarray(keys_lo).astype(np.uint32, copy=False)
+        owner = keys_hi % np.uint32(n)
         me = jax.process_index()
-        hi_b, lo_b, sz_b = {}, {}, {}
-        for d in (i for i, dev in enumerate(self.mesh.devices.flat)
-                  if dev.process_index == me):
-            sel = owner == d
-            s = fpset.from_host_keys(keys_hi[sel].astype(np.uint32),
-                                     keys_lo[sel].astype(np.uint32),
-                                     self._CL)
-            hi_b[d] = np.asarray(s.hi)[None]
-            lo_b[d] = np.asarray(s.lo)[None]
-            sz_b[d] = np.asarray(s.size, np.int32).reshape(1)
-        return self._assemble_sharded_fpset(hi_b, lo_b, sz_b)
+        parts = {}
+        for d, dev in enumerate(self.mesh.devices.flat):
+            if dev.process_index == me:
+                sel = owner == d
+                parts[d] = (keys_hi[sel], keys_lo[sel])
+        most = max((len(h) for h, _l in parts.values()), default=0)
+        # Pieces of 2^15 keys (fpset.from_host_keys) up to 2^18: few
+        # round trips for a deep snapshot, the small program for a
+        # shallow one.
+        piece = min(max(fpset._capacity(most), 1 << 15), 1 << 18)
+        sh = NamedSharding(self.mesh, P("x"))
+
+        def piece_of(col, base):
+            def one(idx):
+                part = parts[idx[0].start][col][base:base + piece]
+                return np.pad(part, (0, piece - len(part)))[None]
+            return jax.make_array_from_callback((n, piece), sh, one)
+
+        shi, slo = (self._sharded_full((n, cl), _U32, SENTINEL)
+                    for _ in range(2))
+        ssize = self._sharded_full((n,), _I32)
+        fails = []
+        for base in range(0, most, piece):
+            valid = jax.make_array_from_callback(
+                (n, piece), sh,
+                lambda idx, base=base: (np.arange(base, base + piece)
+                                        < len(parts[idx[0].start][0]))[None])
+            shi, slo, ssize, fail = self._insert_keys(
+                shi, slo, ssize, piece_of(0, base), piece_of(1, base), valid)
+            fails.append(fail)
+        if any(bool(np.any(np.asarray(s.data)))
+               for f in fails for s in f.addressable_shards):
+            raise RuntimeError(
+                f"FPSet rebuild overflow: {most} keys into a shard of {cl}")
+        return shi, slo, ssize
 
     def _rebuild_programs(self):
         """Re-trace chunk/ingest for a changed seen-shard shape."""
@@ -787,6 +880,13 @@ class MeshBFSEngine:
             family_groups=_family_groups_meta(self.dims))
         self._cur_res = res     # run_end event reads it on error exits
         mt, evlog = self.metrics, self._evlog
+        # What run_end adds for the mesh (``_run_end_extra``), gathered
+        # from the chips by the chunk program itself: parents each chip
+        # expanded over the run; each chip's next-level rows and shard
+        # keys as the last chunk call left them.
+        self._mesh_counts = {
+            "chip_parents_expanded": [0] * n, "chip_next_count": [0] * n,
+            "chip_shard_keys": [0] * n}
         self._growth_stalls = res.growth_stalls
         # TLC-style per-action coverage (obs/coverage.py); stats are
         # psum-replicated, so every controller accumulates identical
@@ -800,9 +900,8 @@ class MeshBFSEngine:
 
         if resume is not None:
             # Shards must hold the checkpointed keys at <= half load.
-            per_owner = np.asarray(resume.seen_hi, np.uint64) % n
-            max_keys = max((int((per_owner == d).sum()) for d in range(n)),
-                           default=0)
+            max_keys = int(np.bincount(
+                np.asarray(resume.seen_hi) % n, minlength=n).max())
             while max_keys > self._CL // 2:
                 self._CL *= 2
                 self._rebuild_programs()
@@ -815,10 +914,7 @@ class MeshBFSEngine:
         # plain jnp.zeros would land the full n-chip array on one device
         # — invisible on the virtual CPU mesh, an instant OOM on a real
         # pod where per-chip capacities are sized to chip HBM.
-        def sharded_full(shape, dtype, fill=0):
-            sh = NamedSharding(self.mesh, P("x"))
-            return jax.jit(lambda: jnp.full(shape, fill, dtype),
-                           out_shardings=sh)()
+        sharded_full = self._sharded_full
 
         qcur = sharded_full((n, QLA, sw), jnp.uint8)
         qnext = sharded_full((n, QLA, sw), jnp.uint8)
@@ -923,11 +1019,12 @@ class MeshBFSEngine:
             # Each controller materializes only its addressable shards, so
             # a checkpoint written by M controllers (piece group, merged
             # by checkpoint.load) resumes on any process count.
-            shi, slo, ssize = self._shards_from_keys(
-                np.asarray(resume.seen_hi, np.uint64),
-                np.asarray(resume.seen_lo, np.uint64))
+            with mt.phase_timer("restore_keys"):
+                shi, slo, ssize = self._shards_from_keys(
+                    resume.seen_hi, resume.seen_lo)
+                ssize.block_until_ready()
             fr = np.ascontiguousarray(resume.frontier).astype(
-                ROW_DTYPE, casting="safe")
+                ROW_DTYPE, casting="safe", copy=False)
             level_rows = len(fr)
             if mp:
                 # Disjoint frontier slices per controller; the union is
@@ -943,7 +1040,11 @@ class MeshBFSEngine:
             # Pre-split into upload-sized segments (views).
             for i in range(0, len(fr), seg_cap):
                 pending.append(fr[i:i + seg_cap])
-            cur_counts_dev = zero_counts
+            # The first segment goes up here, balanced across the chips;
+            # the level loop uploads the others as it reaches them.
+            with mt.phase_timer("restore_frontier"):
+                qcur, cur_counts_dev = self._upload_segment(pending, qcur)
+                cur_counts_dev.block_until_ready()
             res.distinct = resume.distinct
             res.generated = resume.generated
             res.diameter = resume.diameter
@@ -959,9 +1060,10 @@ class MeshBFSEngine:
                         "checkpoint was written with trace recording "
                         "disabled; resume with record_trace=False or "
                         "restart from scratch")
-                trace.add_batch(resume.trace_fps, resume.trace_parents,
-                                resume.trace_actions)
-                trace.roots.update(resume.roots)
+                with mt.phase_timer("restore_trace"):
+                    trace.add_batch(resume.trace_fps, resume.trace_parents,
+                                    resume.trace_actions)
+                    trace.roots.update(resume.roots)
             elif resume.trace_fps.size > 0 and cfg.checkpoint_dir is not None:
                 raise ValueError(
                     "resuming a trace-carrying checkpoint with trace "
@@ -1142,7 +1244,10 @@ class MeshBFSEngine:
                     step_cm = (cap.step() if cap is not None
                                and not cap.done
                                else contextlib.nullcontext())
-                    with mt.phase_timer("chunk"), step_cm:
+                    # The registry's count pairs this span with the
+                    # ``account`` span of the same call (engine/bfs.py).
+                    call = int(mt.counter_value("engine/chunk_calls")) + 1
+                    with mt.phase_timer("chunk", call=call), step_cm:
                         out = self._chunk(
                             qcur, cur_counts_dev,
                             jnp.int32(offset), qnext, next_counts, shi,
@@ -1154,7 +1259,10 @@ class MeshBFSEngine:
                     # time.
                     with mt.phase_timer("stats_fetch"):
                         st = np.asarray(stats)
+                    account = mt.open_span("account", call=call,
+                                           passes=int(st[1]))
                     self._count_chunk_call(int(st[1]), int(st[15]))
+                    self._count_per_chip(st)
                     if self._perf is not None and int(st[1]):
                         # Launch accounting's dynamic half (obs/perf.py)
                         # — host arithmetic on the fetched stats only.
@@ -1201,6 +1309,7 @@ class MeshBFSEngine:
                         offset=offset, next_count=cur_sum,
                         seen_size=int(st[10]),
                         elapsed=round(time.time() - t0, 3))
+                    account.close()
                     if int(st[4]):
                         raise RuntimeError(
                             f"{int(st[4])} successors exceeded fixed-width "
@@ -1307,31 +1416,8 @@ class MeshBFSEngine:
                 # pool; the segment cap keeps any one upload within QL
                 # rows per chip).
                 with mt.phase_timer("upload"):
-                    my_rows = [i for i, d in
-                               enumerate(self.mesh.devices.flat)
-                               if d.process_index == jax.process_index()]
-                    cap = len(my_rows) * QL
-                    seg = pending.pop(0) if pending else \
-                        np.zeros((0, sw), ROW_DTYPE)
-                    while len(seg) > cap:
-                        pending.insert(0, seg[cap:])
-                        seg = seg[:cap]
-                    bufs = {}
-                    cnts = np.zeros((n,), np.int32)
-                    share = -(-len(seg) // len(my_rows)) if len(seg) else 0
-                    for k, di in enumerate(my_rows):
-                        part = seg[k * share:(k + 1) * share] if share \
-                            else seg[:0]
-                        b = np.zeros((QLA, sw), ROW_DTYPE)
-                        b[:len(part)] = part
-                        bufs[di] = b[None]
-                        cnts[di] = len(part)
-                    shq = NamedSharding(self.mesh, P("x"))
-                    qcur = jax.make_array_from_callback(
-                        (n, QLA, sw), shq, lambda idx: bufs[idx[0].start])
-                    cur_counts_dev = jax.make_array_from_callback(
-                        (n,), shq,
-                        lambda idx: cnts[idx[0].start:idx[0].stop])
+                    qcur, cur_counts_dev = self._upload_segment(pending,
+                                                                qcur)
             if res.stop_reason != "exhausted" or res.violation is not None:
                 break
             resolve_spill()      # level boundary: all drains must land
@@ -1344,6 +1430,8 @@ class MeshBFSEngine:
             cur_counts_dev = next_counts
             next_counts = sharded_full((n,), _I32)
             pending, spill_next = spill_next, pending
+            qcur, cur_counts_dev = self._deal_out(
+                qcur, cur_counts_dev, pending, res.diameter)
 
         res.wall_seconds = time.time() - t0
         if mp and cfg.record_trace:
@@ -1355,6 +1443,89 @@ class MeshBFSEngine:
         return res
 
     # ------------------------------------------------------------------
+    def _deal_out(self, qcur, cur_counts, pending, level):
+        """A row lies on the chip that generated it, so the rows of a
+        check that starts from one root would all lie on one chip, which
+        would then do every expansion (the others answering dedup
+        queries only) and hold the whole frontier.  At a level boundary
+        a frontier whose fullest chip holds over a batch more than its
+        even share is therefore drained and dealt out evenly, by the
+        drain and the balanced upload a spill uses.  Single-controller
+        runs only: under a process group the steps of an upload are
+        collective calls, and the controllers' shares differ.
+        Returns (qcur, per-chip counts)."""
+        cnts = self._local_counts(cur_counts)
+        rows = sum(cnts.values())
+        if (jax.process_count() > 1 or not cnts
+                or max(cnts.values()) - -(-rows // len(cnts)) <= self._B):
+            return qcur, cur_counts
+        with self.metrics.phase_timer("rebalance"):
+            pending.insert(0, self._drain(qcur, cnts))
+            qcur, cur_counts = self._upload_segment(pending, qcur)
+        self._evlog.emit("rebalance", level=level, rows=rows,
+                         fullest=max(cnts.values()))
+        return qcur, cur_counts
+
+    def _upload_segment(self, pending, qcur):
+        """The next host segment of the current level into ``qcur``,
+        balanced across this controller's chips (each controller
+        re-uploads its own pool; a segment is cut to QL rows a chip).
+        Only the rows go up, in steps of ``UPLOAD_ROWS`` a chip written
+        into the queue that is already there: rows past a chip's count
+        are never read (the slice stage masks them), so nothing is
+        zeroed and no queue-sized buffer is built on the host.
+        Returns (qcur, per-chip counts)."""
+        n, sw, QL = self.n_dev, self._sw, self._QL
+        QLA = QL + self._PAD
+        my_rows = [i for i, d in enumerate(self.mesh.devices.flat)
+                   if d.process_index == jax.process_index()]
+        cap = len(my_rows) * QL
+        seg = pending.pop(0) if pending else np.zeros((0, sw), ROW_DTYPE)
+        while len(seg) > cap:
+            pending.insert(0, seg[cap:])
+            seg = seg[:cap]
+        share = -(-len(seg) // len(my_rows)) if len(seg) else 0
+        parts = {di: seg[k * share:(k + 1) * share]
+                 for k, di in enumerate(my_rows)}
+        cnts = np.zeros((n,), np.int32)
+        for di, part in parts.items():
+            cnts[di] = len(part)
+        shq = NamedSharding(self.mesh, P("x"))
+        step = min(UPLOAD_ROWS, QLA)
+        for base in range(0, share, step):
+            # The last step is moved back to end with the queue, and
+            # writes some rows a second time.
+            at = min(base, QLA - step)
+
+            def rows_of(idx, at=at):
+                part = parts[idx[0].start][at:at + step]
+                if len(part) == step:
+                    return part[None]
+                buf = np.zeros((1, step, sw), ROW_DTYPE)
+                buf[0, :len(part)] = part
+                return buf
+            qcur = self._write_rows(
+                qcur, jax.make_array_from_callback((n, step, sw), shq,
+                                                   rows_of),
+                jnp.int32(at))
+        return qcur, jax.make_array_from_callback(
+            (n,), shq, lambda idx: cnts[idx[0].start:idx[0].stop])
+
+    def _count_per_chip(self, st) -> None:
+        """One chunk call's share of the per-chip counts: the tail of
+        the statistics just fetched is each chip's own (parents
+        expanded, next-level rows, shard keys)."""
+        n, mc = self.n_dev, self._mesh_counts
+        per_chip = np.asarray(st[len(st) - 3 * n:]).reshape(3, n)
+        mc["chip_parents_expanded"] = [
+            a + int(b)
+            for a, b in zip(mc["chip_parents_expanded"], per_chip[0])]
+        mc["chip_next_count"] = [int(v) for v in per_chip[1]]
+        mc["chip_shard_keys"] = [int(v) for v in per_chip[2]]
+
+    def _run_end_extra(self) -> dict:
+        return dict(getattr(self, "_mesh_counts", {}))
+
     def _local_counts(self, counts) -> dict:
         """{global chip row -> count} for THIS controller's addressable
         shards (single-controller: all chips — behavior unchanged)."""
@@ -1370,7 +1541,9 @@ class MeshBFSEngine:
                         key=lambda s: s.index[0].start):
             c = cnts.get(s.index[0].start, 0)
             if c:
-                segs.append(np.asarray(s.data)[0, :c])
+                # Sliced on the chip: a queue is QL rows long whatever
+                # it holds.
+                segs.append(np.asarray(s.data[:, :c])[0])
         return np.concatenate(segs) if segs else \
             np.zeros((0, self._sw), ROW_DTYPE)
 
@@ -1423,6 +1596,21 @@ class MeshBFSEngine:
                              memory=device_memory_stats())
         return shi, slo, ssize, qnext, next_counts, tbuf, t0
 
+    def shard_keys(self, shi, slo) -> dict:
+        """{chip: (hi, lo)} — the keys each of this controller's shards
+        holds, as they lie on it."""
+        out = {}
+        for s_hi, s_lo in zip(
+                sorted(shi.addressable_shards,
+                       key=lambda s: s.index[0].start),
+                sorted(slo.addressable_shards,
+                       key=lambda s: s.index[0].start)):
+            hi_h = np.asarray(s_hi.data)[0]
+            lo_h = np.asarray(s_lo.data)[0]
+            real = ~((hi_h == SENTINEL) & (lo_h == SENTINEL))
+            out[s_hi.index[0].start] = (hi_h[real], lo_h[real])
+        return out
+
     def _write_checkpoint(self, qcur, cur_counts, pending, shi, slo, res,
                           trace, wall):
         """Same snapshot format as the single-chip engine: flat frontier +
@@ -1442,24 +1630,16 @@ class MeshBFSEngine:
         # replicated counters in each); checkpoint.load merges the group.
         frontier, front_cleanup = pending.concat_with(
             self._drain(qcur, self._local_counts(cur_counts)))
-        keys_hi, keys_lo = [], []
-        for s_hi, s_lo in zip(
-                sorted(shi.addressable_shards,
-                       key=lambda s: s.index[0].start),
-                sorted(slo.addressable_shards,
-                       key=lambda s: s.index[0].start)):
-            hi_h = np.asarray(s_hi.data)[0]
-            lo_h = np.asarray(s_lo.data)[0]
-            real = ~((hi_h == SENTINEL) & (lo_h == SENTINEL))
-            keys_hi.append(hi_h[real])
-            keys_lo.append(lo_h[real])
-        keys_hi = np.concatenate(keys_hi) if keys_hi else np.empty(0)
-        keys_lo = np.concatenate(keys_lo) if keys_lo else np.empty(0)
-        order = np.lexsort((keys_lo, keys_hi))
+        shards = self.shard_keys(shi, slo)
+        # Sorted by (hi, lo), as one 64-bit key.
+        keys = np.concatenate(
+            [(h.astype(np.uint64) << np.uint64(32)) | l
+             for h, l in shards.values()] or [np.empty(0, np.uint64)])
+        keys.sort()
         ck = ckpt_mod.Checkpoint(
             dims=self.dims, frontier=frontier,
-            seen_hi=keys_hi[order].astype(np.uint32),
-            seen_lo=keys_lo[order].astype(np.uint32),
+            seen_hi=(keys >> np.uint64(32)).astype(np.uint32),
+            seen_lo=keys.astype(np.uint32),
             distinct=res.distinct, generated=res.generated,
             diameter=res.diameter, levels=tuple(res.levels),
             action_counts=dict(res.action_counts),

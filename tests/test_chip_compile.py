@@ -164,6 +164,36 @@ def test_mesh_chunk_over_four_described_chips(setup, topo):
     assert "all-to-all" in c.as_text()
 
 
+def test_mesh_chunk_at_the_four_chip_deployments_sizes(setup, topo):
+    """The mesh chunk as configuration ``mcraft3-mesh4`` runs it
+    (``benchmark/configs/mcraft3-mesh4.json``: batch 2,048 a chip,
+    16,777,216 queue rows and 2^27 keys over the four chips, trace
+    recording on) compiles for the described v5e:2x2; its arguments and
+    temporaries fit one chip's 16 GB with the host loop's third queue
+    beside them; the routed dedup is three ``all-to-all``s (the two
+    fingerprint halves out, the novelty bits back).  About 50 s here."""
+    from raft_tla_tpu.engine.bfs import EngineConfig
+    from raft_tla_tpu.engine.check import make_engine
+    from raft_tla_tpu.parallel.mesh import MeshBFSEngine
+    eng = make_engine(
+        setup, EngineConfig(batch=B, queue_capacity=1 << 24,
+                            seen_capacity=1 << 27, record_trace=True),
+        engine_cls=functools.partial(MeshBFSEngine,
+                                     devices=list(topo.devices)))
+    assert (eng.n_dev, eng._QL, eng._CL) == (4, 1 << 22, 1 << 25)
+    c = compile_for(
+        eng._chunk,
+        lambda a: NamedSharding(eng.mesh, P("x") if a.ndim else P()),
+        *eng.chunk_avals())
+    m = c.memory_analysis()
+    spare_queue = (eng._QL + eng._PAD) * 473
+    # Per chip: the compiler's figures are one partition's.
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + spare_queue) < 16e9, m
+    assert len(re.findall(r"= \S+ all-to-all(?:-start)?\(",
+                          c.as_text())) == 3
+
+
 # -- the Pallas kernels -----------------------------------------------------
 
 def _compact(setup):
