@@ -79,7 +79,7 @@ _I32 = jnp.int32
 # ``engine/<name>`` counters (process-cumulative, like every counter):
 # ``run_end`` carries a run's share, ``level_complete`` each level's.
 WORK_COUNTERS = ("chunk_calls", "passes", "ingest_calls",
-                 "parents_expanded")
+                 "parents_expanded", "flush_overlapped", "flush_drained")
 
 
 def work_counts(metrics, base: Optional[dict] = None) -> dict:
@@ -132,6 +132,69 @@ def compiles_by_span(metrics, base: Optional[dict] = None) -> dict:
             out[name] = [int(n - n0),
                          round(seconds.get(name, 0.0) - s0, 6)]
     return out
+
+
+# -- the trace flush, in two halves ------------------------------------------
+# Records one fetch program moves (``BFSEngine._fetch``: five slices of a
+# fixed length at a traced start, compiled in warm-up), smallest first.
+# A flush takes the smallest that holds its records in one piece, else
+# walks them in pieces of the largest: whatever a call admitted, its
+# flush compiles nothing and moves under one piece more than it needs.
+FLUSH_PIECES = (1 << 11, 1 << 14, 1 << 17)
+
+
+def flush_plan(n: int, lengths, size: int) -> List[Tuple[int, int, int, int]]:
+    """``[(start, length, lo, hi)]``: the fetches that bring entries
+    ``[0, n)`` of a ``size``-entry buffer, in order, and the part
+    ``[lo, hi)`` of each that is wanted.  The last piece starts early
+    rather than past ``size - length``, where a device slice would be
+    moved back without a word."""
+    length = next((x for x in lengths if x >= n), lengths[-1])
+    plan = []
+    for s in range(0, n, length):
+        start = min(s, size - length)
+        plan.append((start, length, s - start, min(s + length, n) - start))
+    return plan
+
+
+class _TraceFlush:
+    """One chunk call's trace records on their way to the host store, in
+    two halves, so that the device need not wait for the host's.
+    ``start`` enqueues the fetch programs on the call's trace buffer and
+    starts their copies to the host: device work, ahead in the stream of
+    whatever is dispatched next, which may donate that buffer.
+    ``finish`` waits for the copies and hands the records to the store.
+    The loop calls it behind the next chunk dispatch (counted as
+    ``flush_overlapped``), or with the device empty where something other
+    than a plain next call comes first: the end of a level, any stop
+    (``flush_drained``).  So at most one flush is owed, and records reach
+    the store in the order the calls ran.  A run that raises takes what
+    it owed with it: ``_run_degradable`` resumes into a new store."""
+
+    def __init__(self, engine, trace):
+        self._eng, self._trace, self._owed = engine, trace, None
+
+    def start(self, tbuf, n: int) -> None:
+        eng = self._eng
+        with eng.metrics.phase_timer("trace_flush"):
+            owed = []
+            for start, length, lo, hi in flush_plan(n, eng._fetch_lens,
+                                                    eng._TA):
+                cols = eng._fetch(tbuf, np.int32(start), length)
+                for col in cols:
+                    col.copy_to_host_async()
+                owed.append((cols, lo, hi))
+            self._owed = owed
+
+    def finish(self, counter: str) -> None:
+        if self._owed is None:
+            return
+        eng = self._eng
+        with eng.metrics.phase_timer("trace_flush"):
+            owed, self._owed = self._owed, None
+            for cols, lo, hi in owed:
+                eng._record(self._trace, cols, lo, hi)
+        eng.metrics.counter("engine/" + counter)
 
 
 @dataclasses.dataclass
@@ -1009,6 +1072,14 @@ class BFSEngine:
         # Allocated trace rows: live region + K trash slots for the
         # masked-off scatter lanes (stub when tracing is off).
         self._TA = TQ + K if record_static else 8
+        # The trace flush's fetch programs (``_TraceFlush``), one per
+        # length; ``_run_impl`` runs each once in warm-up.
+        self._fetch_lens = sorted({min(x, self._TA) for x in FLUSH_PIECES})
+        self._fetch = jax.jit(
+            lambda tbuf, start, length: tuple(
+                jax.lax.dynamic_slice(x, (start,), (length,))
+                for x in tbuf),
+            static_argnums=2)
         self._perf = None
         if cfg.perf:
             from ..obs import perf as perf_mod
@@ -1671,6 +1742,12 @@ class BFSEngine:
                               qnext, jnp.int32(0), seen, tbuf,
                               jnp.int32(0), jnp.int32(self._CH))
             qnext, seen, tbuf = out[0], out[1], out[2]
+            # The trace flush's programs, on the buffer as the chunk
+            # hands it back: no call's flush compiles, whatever it admitted.
+            if cfg.record_trace:
+                for length in self._fetch_lens:
+                    self._fetch(tbuf, np.int32(0), length)
+        flush = _TraceFlush(self, trace)
         t0 = time.time()
         last_progress = t0
         self._batch_ema = 0.0   # measured seconds per device batch
@@ -1767,11 +1844,13 @@ class BFSEngine:
                      vinfo) = self._ingest(
                         jnp.asarray(np.concatenate([chunk, pad])),
                         jnp.asarray(valid), qnext, next_count, seen)
-                    res.distinct += int(n_new)
+                    n_new = int(n_new)
+                    res.distinct += n_new
                 mt.counter("engine/ingest_calls")
-                mt.counter("engine/distinct", int(n_new))
-                with mt.phase_timer("trace_flush"):
-                    self._record(trace, tr, int(n_new))
+                mt.counter("engine/distinct", n_new)
+                if cfg.record_trace and n_new:
+                    with mt.phase_timer("trace_flush"):
+                        self._record(trace, tr, 0, n_new)
                 if bool(fail):
                     raise RuntimeError(
                         "seen-set probe failure during ingest; raise "
@@ -1910,19 +1989,26 @@ class BFSEngine:
                     # The registry's count: what pairs this span with
                     # the ``account`` span of the same call.
                     call = int(mt.counter_value("engine/chunk_calls")) + 1
-                    with mt.phase_timer("chunk", call=call) as dispatch, \
-                            step_cm:
+                    t_call = time.perf_counter()
+                    with mt.phase_timer("chunk", call=call), step_cm:
                         out = self._chunk(qcur, jnp.int32(cur_count),
                                           jnp.int32(offset), qnext,
                                           jnp.int32(next_count_h), seen,
                                           tbuf, jnp.int32(0),
                                           jnp.int32(allowed))
                         qnext, seen, tbuf = out[0], out[1], out[2]
+                    # The host half of the previous call's flush, while
+                    # the device runs this one.
+                    flush.finish("flush_overlapped")
                     # The packed-stats fetch is the loop's one blocking
                     # device sync — its phase time IS the device compute
                     # the dispatch above overlapped.
-                    with mt.phase_timer("stats_fetch") as fetch:
+                    with mt.phase_timer("stats_fetch"):
                         st = np.asarray(out[3])
+                    # What the call cost the loop, from its dispatch to
+                    # its statistics on the host: the flush that ran
+                    # under it is inside, where it outlasts the device.
+                    call_seconds = time.perf_counter() - t_call
                     passes = int(st[1])
                     # The host bookkeeping of one call, under the numbers
                     # the fetch brought: what a reader of a profiler
@@ -1930,10 +2016,9 @@ class BFSEngine:
                     account = mt.open_span("account", call=call,
                                            passes=passes)
                     self._count_chunk_call(passes, int(st[12]))
-                    call_seconds = dispatch.seconds + fetch.seconds
                     if self._perf is not None and passes:
                         # Launch accounting's dynamic half: the call's
-                        # passes and its seconds (dispatch + fetch).
+                        # passes and its seconds.
                         self._perf.add_chunk(passes, call_seconds)
                     if passes:           # st fetch synced: timing is real
                         per = call_seconds / passes
@@ -1987,8 +2072,9 @@ class BFSEngine:
                         elapsed=round(time.time() - t0, 3))
                     account.close()
                     if cfg.record_trace and tcount:
-                        with mt.phase_timer("trace_flush"):
-                            self._flush_trace(trace, tbuf, tcount)
+                        # The device half only: everything below may
+                        # raise, branch or dispatch with the flush owed.
+                        flush.start(tbuf, tcount)
                     if n_ovf:
                         raise RuntimeError(
                             f"{n_ovf} successors exceeded fixed-width "
@@ -2083,6 +2169,9 @@ class BFSEngine:
                     buf[:len(seg)] = seg
                     qcur = jax.device_put(buf, qcur.devices().pop())
                     cur_count = len(seg)
+            # The level is built, or the run stops: what follows reads
+            # the store (a snapshot, a replay) or closes the level's span.
+            flush.finish("flush_drained")
             if res.stop_reason != "exhausted" or res.violation is not None:
                 break  # aborted mid-level: diameter counts completed levels
             resolve_spill()      # level boundary: all drains must land
@@ -2174,7 +2263,13 @@ class BFSEngine:
         warm-up).  Returns (seen, qnext, tbuf, t0)."""
         if self._seen_overloaded(seen, size):
             with self.metrics.phase_timer("grow") as grow:
-                seen = self._maybe_grow_seen(seen, size)
+                # Committed, like every buffer the chunk takes (see
+                # ``run_init``): the rebuilt table comes out of jits of
+                # uncommitted inputs, the chunk hands it back committed,
+                # and the jit cache keys on that — the first real call
+                # after a growth compiled the chunk again, on the clock.
+                seen = jax.device_put(self._maybe_grow_seen(seen, size),
+                                      qcur.devices().pop())
                 out = self._chunk(qcur, jnp.int32(0), jnp.int32(0), qnext,
                                   jnp.int32(next_count), seen, tbuf,
                                   jnp.int32(0), jnp.int32(1))
@@ -2278,19 +2373,17 @@ class BFSEngine:
         if removed:
             self.metrics.counter("engine/checkpoints_gcd", removed)
 
-    def _record(self, trace, tr, n_new):
-        if n_new == 0 or not self.config.record_trace:
-            return
-        sh, sl, ph, pl, ac = (np.asarray(x[:n_new]) for x in tr)
+    @staticmethod
+    def _record(trace, cols, lo: int, hi: int) -> None:
+        """Entries ``[lo, hi)`` of five fetched trace columns into the
+        host store.  The columns come whole, at a shape fixed when the
+        engine was built, and are cut here: a device slice at every new
+        length is a compile of its own."""
+        sh, sl, ph, pl, ac = (np.asarray(x)[lo:hi] for x in cols)
         fps = (sh.astype(np.uint64) << np.uint64(32)) | sl.astype(np.uint64)
         parents = (ph.astype(np.uint64) << np.uint64(32)) \
             | pl.astype(np.uint64)
         trace.add_batch(fps, parents, ac)
-
-    def _flush_trace(self, trace, tbuf, tcount):
-        """Drain the device trace buffer (one chunk's records) to the host
-        store — one transfer per column slice."""
-        self._record(trace, tbuf, tcount)
 
     def _check_violation(self, res, vinfo) -> bool:
         viol_any, vinv, vrow, vhi, vlo = vinfo

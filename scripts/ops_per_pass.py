@@ -11,6 +11,11 @@ counts the same way in a checkout whose program names no stage and
 opens no span (copy it there, beside this PR's ``benchmark/`` files): the
 number to compare between two commits that must run the same program.
 
+Beside the counts it prints what the window's own ``run_end`` event
+counted (chunk calls, passes, trace flushes overlapped and drained,
+compiles by span), the pools' fill, the window's phases and the
+per-layer metrics as ``run.py --trace 1`` reduces them.
+
 Iterations are counted as ``benchmark/readers/xplane.py`` counts them
 (the most common number of times a direct child of the loop occurs);
 where the capture carries the engine's ``raft.account`` spans the exact
@@ -143,6 +148,23 @@ def main() -> int:
         tab = stages.stage_table(run)
         if tab:
             out["passes_by_raft_account"] = tab["passes"]
+        # What the window's own ``run_end`` counted (the loop's work
+        # counters, its compiles by span), the pools' fill, and the
+        # per-layer metrics as ``run.py --trace 1`` reduces them.
+        end = next((e for e in reversed(run.get("events", []))
+                    if e["event"] == "run_end"), {})
+        out["run_end"] = {k: end[k] for k in (
+            "chunk_calls", "passes", "ingest_calls", "flush_overlapped",
+            "flush_drained", "compiles") if k in end}
+        out["fill"] = run.get("counters")
+        out["phases"] = {k: round(v, 4) for k, v in run["phases"].items()}
+        import jax
+        import run as bench_run
+        run["device_kind"] = jax.devices()[0].device_kind
+        with open(os.path.join(ROOT, "BENCHMARK.json"),
+                  encoding="utf-8") as f:
+            metrics, _extra = bench_run.layer_metrics(json.load(f), run)
+        out["layer_metrics"] = {k: v["value"] for k, v in metrics.items()}
         print(json.dumps({"workload": args.workload, **out}), flush=True)
         return 0
     finally:

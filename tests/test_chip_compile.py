@@ -10,6 +10,7 @@ time:
 - the XLA main path: ``ops/fpset.py insert`` over the bench's 2^25-key
   table, the whole v2 chunk program of ``BFSEngine`` at a small batch
   (and that it holds no gather expanded into a per-lane loop),
+  the trace flush's fetch programs over the bench's trace buffers,
   the swarm walk chunk at 1,024 walks, the mesh chunk over the four
   described chips (with the owner-routed dedup's ``all-to-all``);
 - one case per Pallas kernel.  None of them compiles today (PR 24 moved
@@ -131,6 +132,25 @@ def test_v2_chunk_program_small_batch(cfg, width, instances, one_chip):
     loops = re.findall(r' while\(.*op_name="([^"]*)"', c.as_text())
     assert len(loops) >= 2, loops       # else the pattern found nothing
     assert not [name for name in loops if name.endswith("/gather")], loops
+
+
+def test_trace_flush_fetch_programs_at_the_bench_queue(setup, one_chip):
+    """The trace flush's three fetch programs (engine/bfs.py ``_fetch``:
+    five slices of a fixed length at a traced start) over trace buffers
+    as long as the bench's 2^21-row queue makes them."""
+    from raft_tla_tpu.engine.bfs import FLUSH_PIECES, EngineConfig
+    from raft_tla_tpu.engine.check import make_engine
+    eng = make_engine(setup, EngineConfig(
+        batch=64, queue_capacity=QUEUE, seen_capacity=1 << 17))
+    tbuf = eng.chunk_avals()[6]
+    assert tbuf[0].shape[0] > QUEUE
+    assert eng._fetch_lens == list(FLUSH_PIECES)
+    for length in eng._fetch_lens:
+        c = compile_for(
+            lambda t, start, n=length: eng._fetch(t, start, n),
+            lambda a: one_chip, tbuf, S((), jnp.int32))
+        assert c.memory_analysis().output_size_in_bytes >= 20 * length
+        assert " while(" not in c.as_text()
 
 
 def test_swarm_walk_chunk_1024_walks(one_chip):

@@ -221,9 +221,11 @@ def test_loop_counters_equal_a_hand_count(noleader, tmp_path):
         again = [json.loads(line) for line in f][-1]
     assert (again["chunk_calls"], again["passes"], again["ingest_calls"],
             again["parents_expanded"]) == (4, 4, 1, 71)
+    # One call a level: each flush is drained at its level's end.
     assert bfs_mod.work_counts(eng.metrics) == {
         "chunk_calls": 8, "passes": 8, "ingest_calls": 2,
-        "parents_expanded": 142}
+        "parents_expanded": 142, "flush_overlapped": 0,
+        "flush_drained": 8}
 
 
 def test_deeper_levels_take_the_passes_their_frontier_needs(verdict):
@@ -241,9 +243,11 @@ def test_deeper_levels_take_the_passes_their_frontier_needs(verdict):
 
 
 def test_compiles_are_charged_to_the_span_they_fell_in(noleader, tmp_path):
-    """The trace flush slices five device buffers at a new length after
-    every chunk call, each length a compile of its own.  Sizes no other
-    test uses, so that none of them is in this process's jit cache."""
+    """Every program the loop runs is compiled in ``warmup``: ingest,
+    chunk, and the trace flush's fetch programs, whose shapes are fixed
+    when the engine is built, so that a flush compiles nothing at
+    whatever length.  Sizes no other test uses, so that none of them is
+    in this process's jit cache."""
     ev = str(tmp_path / "ev.jsonl")
     eng = make_engine(noleader, EngineConfig(
         batch=48, queue_capacity=48 * 211, seen_capacity=1 << 17,
@@ -252,10 +256,12 @@ def test_compiles_are_charged_to_the_span_they_fell_in(noleader, tmp_path):
     with open(ev, encoding="utf-8") as f:
         end = [json.loads(line) for line in f][-1]
     compiles = end["compiles"]
-    n, seconds = compiles["trace_flush"]
-    assert n >= 5 and seconds > 0
-    assert compiles["warmup"][0] >= 2           # ingest and chunk
-    assert "stats_fetch" not in compiles and "account" not in compiles
+    n, seconds = compiles["warmup"]
+    # ingest, chunk and one fetch program a length
+    assert n >= 2 + len(eng._fetch_lens) and seconds > 0
+    assert end["flush_drained"] == 5            # five flushes, five lengths
+    for span in ("trace_flush", "stats_fetch", "account", "chunk"):
+        assert span not in compiles, compiles
     # The same sizes again: everything is in the jit cache, and a run
     # reports its own compiles, not the registry's running total.
     eng.run(initial_states(noleader))
