@@ -226,14 +226,8 @@ def main():
     # the coverage object's "pruned" column and the generated/distinct
     # headline — bench_diff.py then reports generated-state reduction
     # alongside the distinct/s regression gate.
-    # Successor pipeline (BENCH_PIPELINE=auto/v1/v2/v3/v4): v3 is the
-    # fused Pallas chunk (ops/pipeline_v3.py), v4 the whole-chunk VMEM
-    # megakernel (ops/pipeline_v4.py) — on TPU the real fused kernels,
-    # off-TPU interpret mode for the Pallas stages the platform policy
-    # keeps (the CI v2-vs-v3/v4 gates run this on CPU with
-    # fold-to-common stages in bench_diff.py).  The run's resolved pipeline + per-stage
-    # plan are embedded in the JSON so two benches are always
-    # attributable.
+    # Successor pipeline: BENCH_PIPELINE takes what EngineConfig.pipeline
+    # takes (auto/v1/v2); the resolved one is embedded in the JSON.
     # Device-profiler capture (obs/profile.py XlaProfileCapture;
     # BENCH_XLA_PROFILE=N traces the first N chunk calls): the
     # hardware-truth artifacts, landed under
@@ -403,12 +397,7 @@ def main():
         # BENCH_r* trajectories on.
         "chunk_stages": {k: round(v, 6)
                          for k, v in res.chunk_stages.items()},
-        # Which successor pipeline ran, and (v3) the per-stage lowering
-        # plan — bench_diff folds mismatched chunk_stages granularities
-        # across pipelines using this context.
         "pipeline": res.pipeline,
-        "fused_stages": dict(res.fused_stages),
-        "fused_reasons": dict(res.fused_reasons),
         "coverage": res.coverage,
         # Certified ample instances the run's POR table carried (0 = POR
         # off or an all-conservative certificate).

@@ -684,17 +684,10 @@ def main(argv=None):
                              "-workers / distributed TLC analog); auto = "
                              "mesh iff >1 accelerator device (default)")
         sp.add_argument("--pipeline",
-                        choices=("auto", "v1", "v2", "v3", "v4"),
-                        default=None,
+                        choices=("auto", "v1", "v2"), default=None,
                         help="successor pipeline: v1 = classical expand, "
                              "v2 = delta (guards-only masks + delta "
-                             "fingerprints), v3 = fused Pallas chunk "
-                             "(VMEM-resident compact + probe/insert->"
-                             "enqueue tail), v4 = whole-chunk VMEM "
-                             "megakernel (masks+POR+compact+fingerprint "
-                             "in ONE launch, then the v3 fused tail; "
-                             "per-stage XLA fallback, interpret mode "
-                             "off-TPU).  auto = v2 where it applies "
+                             "fingerprints).  auto = v2 where it applies "
                              "(default; flag > cfg PIPELINE directive "
                              "> auto)")
 
@@ -970,8 +963,7 @@ def main(argv=None):
     sb.add_argument("--seed", type=int, default=0)
     sb.add_argument("--engine", choices=("single", "mesh", "auto"),
                     default=None)
-    sb.add_argument("--pipeline",
-                    choices=("auto", "v1", "v2", "v3", "v4"),
+    sb.add_argument("--pipeline", choices=("auto", "v1", "v2"),
                     default=None)
     sb.add_argument("--trace", action="store_true",
                     help="record the counterexample trace (the server "

@@ -71,6 +71,7 @@ from ..resilience.faults import is_resource_exhausted
 from ..ops import compact as compact_mod
 from ..ops import fpset
 from ..ops.fingerprint import build_fingerprint
+from ..utils.cfg import check_pipeline
 from .chunk import build_chunk_body, named_stage, tag_stages
 
 _I32 = jnp.int32
@@ -219,51 +220,11 @@ class EngineConfig:
     # power of two (ops/compact.py choose_k).
     compact_lanes: Optional[int] = None
     # Successor pipeline: "auto" = the v2 delta pipeline (models/
-    # actions2.py — guards-only masks, delta fingerprints, K-lane sparse
-    # construction; the TPU-profile-driven rework) wherever it applies
-    # (base action alphabet), v1 expand for spec variants with
-    # extra_families.  "v1"/"v2" force one path (v2 raises on variants).
-    # "v3" = v2 semantics with the chunk stages progressively fused into
-    # Pallas kernels (ops/pipeline_v3.py: Pallas compact scan + the
-    # fused probe/insert->enqueue tail, VMEM-resident survivor window;
-    # interpret mode off-TPU).  Bit-identical to v2 by contract.  On the
-    # TPU a kernel the compiler refuses fails the build with the
-    # compiler's message; in interpret mode a stage that cannot build
-    # degrades to its XLA lowering, the resolved per-stage plan recorded
-    # on ``EngineResult.fused_stages``.  "v4" = v2 semantics with the WHOLE
-    # chunk body fused into two Pallas megakernels (ops/pipeline_v4.py:
-    # the masks->compact->fingerprint front over the VMEM-resident
-    # parent window, plus v3's probe/insert->enqueue tail); same
-    # bit-identity and build-failure contract.  Opt-in: "auto"
-    # never selects v3/v4.
+    # actions2.py: guards-only masks, delta fingerprints, K-lane sparse
+    # construction) wherever it applies (base action alphabet), v1 expand
+    # for a spec variant without v2 kernels.  "v1"/"v2" force one path
+    # (v2 raises on such a variant); the tests use v1 as v2's reference.
     pipeline: str = "auto"
-    # Per-stage override for the v3 plan ({"compact": "pallas"|"xla",
-    # "insert": "fused"|"xla", ...}) — tests force the full Pallas chain
-    # on CPU through this; None = the platform policy.
-    v3_force_stages: Optional[dict] = None
-    # Same for the v4 plan (ops/pipeline_v4.py _VALID; any front member
-    # forced off "fused" degrades the whole front group).  The
-    # RAFT_V4_FORCE env var merges over this, env winning per stage —
-    # the fallback-lattice tests' no-plumbing hook.
-    v4_force_stages: Optional[dict] = None
-    # Lane-compaction lowering (ops/compact.py): "scatter" (original) or
-    # "searchsorted" (binary-search inversion; identical outputs).  Kept
-    # switchable until a TPU profile picks the winner.
-    compact_method: str = "scatter"
-    # Enqueue/trace-record lowering (engine/chunk.py): "scatter" writes
-    # each compacted row at its cumsum position (+ per-lane trash for
-    # masked lanes); "window" rebuilds a K-row window at next_count with
-    # a searchsorted gather + one dynamic_update_slice; "pallas" issues
-    # run-coalesced HBM-to-HBM segment DMAs (ops/enqueue_pallas.py — the
-    # contiguous-append formulation; interpret mode off-TPU).  Live rows
-    # are bit-identical; switchable until a TPU profile picks the winner.
-    enqueue_method: str = "scatter"
-    # FPSet insert lowering: "xla" (ops/fpset.py sort + claim protocol) or
-    # "pallas" (ops/fpset_pallas.py single sequential-grid kernel, no sort,
-    # no claims; interpret mode off-TPU).  Engine results are bit-identical
-    # (is_new contract matches); switchable until a TPU profile decides
-    # the fused-chunk question.  Single-host engine only.
-    insert_method: str = "xla"
     # Statically-certified partial-order reduction (analysis/por.py).
     # ``por=True`` certifies in-process at engine construction (traces
     # the kernels once, proving the ample certificates against THIS
@@ -425,6 +386,9 @@ class EngineConfig:
     degrade_on_oom: bool = True
     min_batch: int = 32
 
+    def __post_init__(self):
+        check_pipeline(self.pipeline)
+
 
 @dataclasses.dataclass
 class Violation:
@@ -460,19 +424,9 @@ class EngineResult:
     # duration clock, recorded as evidence for up-front SEEN_CAPACITY
     # sizing (each is a rehash + retrace on the growing engine).
     growth_stalls: List = dataclasses.field(default_factory=list)
-    # Which successor pipeline actually ran ("v1"/"v2"/"v3"/"v4") —
+    # Which successor pipeline actually ran ("v1"/"v2") —
     # makes an ``auto`` fallback observable instead of a silent slowdown.
     pipeline: str = ""
-    # v3/v4 only: the resolved per-stage lowering plan ({stage: "xla"|
-    # "pallas"|"fused"}, ops/pipeline_v3.py / pipeline_v4.py) — a stage
-    # that fell back to XLA is visible here, never a silent
-    # degradation.  {} for v1/v2.
-    fused_stages: Dict[str, str] = dataclasses.field(default_factory=dict)
-    # ...and WHY each non-Pallas stage is what it is ({stage: reason}):
-    # distinguishes a policy choice / explicit force from a kernel that
-    # FAILED its build-probe ("... failed to build/probe: ...") — the
-    # operator-facing half of the no-silent-degradation contract.
-    fused_reasons: Dict[str, str] = dataclasses.field(default_factory=dict)
     # Certified ample instances the run's POR table carried (0 = POR off
     # or an all-conservative certificate — either way, full expansion).
     por_instances: int = 0
@@ -606,16 +560,6 @@ def _auto_capacities(sw: int, batch: int,
     return q, s
 
 
-def _resolve_insert(requested: str):
-    """EngineConfig.insert_method -> the FPSet insert function."""
-    if requested == "xla":
-        return fpset.insert
-    if requested == "pallas":
-        from ..ops import fpset_pallas
-        return fpset_pallas.insert
-    raise ValueError(f"insert_method must be xla/pallas, got {requested!r}")
-
-
 def resolve_por(cfg: EngineConfig, dims, invariants, constraint):
     """EngineConfig.por/por_table -> a verified analysis.por.PorTable or
     None (POR off).  Shared by the single-chip and mesh engines.
@@ -660,20 +604,13 @@ def _resolve_pipeline(requested: str, dims):
     variant genuinely lacks v2 kernels) selects v1 — any other error from
     kernel construction propagates, so a bug in a variant's
     ``build_extra_v2`` can never silently degrade to the slow path.  The
-    resolved choice is recorded on ``EngineResult.pipeline``.
-
-    "v3"/"v4" share v2's delta kernels (same semantics, hence the same
-    variant requirement and the same hard failure on one without v2
-    kernels); the fused-stage plan on top is the engines' business
-    (ops/pipeline_v3.py / ops/pipeline_v4.py)."""
+    resolved choice is recorded on ``EngineResult.pipeline``."""
     from ..models.actions2 import V2Unavailable, build_v2
+    check_pipeline(requested)
     if requested == "v1":
         return None
-    if requested in ("v2", "v3", "v4"):
+    if requested == "v2":
         return build_v2(dims)   # raises if a variant lacks v2 kernels
-    if requested != "auto":
-        raise ValueError(
-            f"pipeline must be auto/v1/v2/v3/v4, got {requested!r}")
     try:
         return build_v2(dims)
     except V2Unavailable:
@@ -766,15 +703,6 @@ class BFSEngine:
                 seen_capacity=max(
                     min(cfg.seen_capacity or (1 << 20), 1 << 22),
                     8 * prof_k),
-                compact_method=cfg.compact_method,
-                # v3/v4 runs are profiled at the fused-stage
-                # granularity (v3: masks / compact / fingerprint /
-                # insert_enqueue; v4: front / insert_enqueue); v1/v2
-                # keep the classical decomposition.
-                pipeline=(cfg.pipeline
-                          if cfg.pipeline in ("v3", "v4") else "v1"),
-                v3_force=(cfg.v4_force_stages if cfg.pipeline == "v4"
-                          else cfg.v3_force_stages),
                 every=prof_every, metrics=self.metrics)
         else:
             self._profiler = None
@@ -789,7 +717,7 @@ class BFSEngine:
         fingerprint = build_fingerprint(dims)
         pack_ok = build_pack_guard(dims)
         self._v2 = _resolve_pipeline(cfg.pipeline, dims)
-        insert_fn = _resolve_insert(cfg.insert_method)
+        self._pipeline_name = "v2" if self._v2 is not None else "v1"
         # Partial-order reduction table (analysis/por.py): verified
         # before any mask is applied; None = full expansion.  Survives
         # the re-entrant OOM-degrade __init__ (same rule as the registry
@@ -877,7 +805,7 @@ class BFSEngine:
             return jnp.sum(new, dtype=_I32), (
                 jnp.any(viol), inv[vpos], crows[vpos], fph[vpos], fpl[vpos])
 
-        insert = named_stage("insert", insert_fn)
+        insert = named_stage("insert", fpset.insert)
 
         def absorb(crows, en, parent_hi, parent_lo, actions,
                    qnext, next_count, seen):
@@ -931,58 +859,15 @@ class BFSEngine:
         # every batch triggers a spill — correct, just not fast.
         QTH = Q - K
         self._QTH = QTH
-        compactor = compact_mod.build_compactor(
-            B, G, K, method=cfg.compact_method)
-        # v3: resolve the fused-stage plan (ops/pipeline_v3.py) — Pallas
-        # compact + the fused insert->enqueue tail; on the TPU a refused
-        # kernel fails the build, in interpret mode it degrades per
-        # stage with recorded reasons.  The split stages below stay exactly the v2
-        # lowerings, so a fully-fallen-back v3 compiles the v2 program.
-        fused_tail = None
-        fused_front = None
-        enqueue_method = cfg.enqueue_method
-        if cfg.pipeline == "v3":
-            from ..ops import pipeline_v3
-            self._v3_plan = pipeline_v3.resolve_plan(
-                B, G, K, Q=Q, sw=sw, mesh=False,
-                enqueue_method=cfg.enqueue_method,
-                force=cfg.v3_force_stages)
-            if self._v3_plan.compactor is not None:
-                compactor = self._v3_plan.compactor
-            fused_tail = self._v3_plan.tail
-            enqueue_method = self._v3_plan.enqueue_method
-        elif cfg.pipeline == "v4":
-            # v4: the whole-chunk plan (ops/pipeline_v4.py) — the front
-            # megakernel needs the run's model context (v2 kernels,
-            # constraint, invariant list, POR arrays), which only this
-            # build site has.
-            from ..ops import pipeline_v4
-            self._v3_plan = pipeline_v4.resolve_plan(
-                B, G, K, Q=Q, sw=sw, mesh=False,
-                enqueue_method=cfg.enqueue_method,
-                force=cfg.v4_force_stages,
-                front_ctx={"dims": dims, "v2": self._v2,
-                           "constraint": constraint, "inv_fns": inv_fns,
-                           "por_mask": por_mask,
-                           "por_priority": por_priority})
-            if self._v3_plan.compactor is not None:
-                compactor = self._v3_plan.compactor
-            fused_front = self._v3_plan.front
-            fused_tail = self._v3_plan.tail
-            enqueue_method = self._v3_plan.enqueue_method
-        else:
-            self._v3_plan = None
-
+        compactor = compact_mod.build_compactor(B, G, K)
         # The per-batch pipeline body is shared with the mesh engine
         # (engine/chunk.py) — only the insert function differs.
         chunk_body = build_chunk_body(
             dims=dims, expand=expand, fingerprint=fingerprint,
             pack_ok=pack_ok, inv_fns=inv_fns, constraint=constraint,
             B=B, G=G, K=K, Q=Q, TQ=TQ, record_static=record_static,
-            compactor=compactor, insert_fn=insert_fn, v2=self._v2,
-            enqueue_method=enqueue_method,
-            por_mask=por_mask, por_priority=por_priority,
-            fused_tail=fused_tail, fused_front=fused_front)
+            compactor=compactor, insert_fn=fpset.insert, v2=self._v2,
+            por_mask=por_mask, por_priority=por_priority)
 
         # What sits outside the ``while``: the counters' start, and the
         # packing of what the host fetches.  The pools themselves go
@@ -1063,7 +948,7 @@ class BFSEngine:
         self._ingest = jax.jit(ingest, donate_argnums=(2, 4))
         # Performance observatory (obs/perf.py; EngineConfig.perf):
         # trace THE chunk program just built — the exact jaxpr the jit
-        # above compiles, v2/v3/POR/fused-tail included — for the
+        # above compiles, POR included — for the
         # static launch model, plus the shared stage programs for the
         # roofline traffic floors.  Fail-soft: a model that cannot be
         # built (exotic jaxpr the walk has no rule for) degrades to a
@@ -1084,17 +969,10 @@ class BFSEngine:
         if cfg.perf:
             from ..obs import perf as perf_mod
             self._perf = perf_mod.build_accounting(
-                pipeline=(cfg.pipeline
-                          if cfg.pipeline in ("v3", "v4")
-                          else "v2" if self._v2 is not None
-                          else "v1"),
+                pipeline=self._pipeline_name,
                 chunk_fn=chunk,
                 chunk_avals=self.chunk_avals(),
                 dims=dims, B=B, K=K,
-                compact_method=cfg.compact_method,
-                v3_force=(cfg.v4_force_stages if cfg.pipeline == "v4"
-                          else cfg.v3_force_stages),
-                plan=self._v3_plan,
                 metrics=self.metrics)
         self._fp_rows = jax.jit(fp_rows)
         self._expand1 = jax.jit(expand)
@@ -1240,20 +1118,13 @@ class BFSEngine:
         self._hbm_watermark = 0
         # Black box armed before the first event so run_start itself is
         # in the ring; the context snapshot is what the watch console
-        # shows as "what is running" (pipeline + resolved fused plan).
+        # shows as "what is running".
         _FLIGHT.arm(
             self._postmortem_path(), metrics=mt,
             context={
                 "engine": type(self).__name__, "dims": repr(self.dims),
                 "batch": cfg.batch, "resume": resume is not None,
-                "pipeline": (cfg.pipeline
-                             if getattr(self, "_v3_plan", None)
-                             is not None
-                             else "v2" if getattr(self, "_v2", None)
-                             is not None else "v1"),
-                "fused_stages": (dict(self._v3_plan.stages)
-                                 if getattr(self, "_v3_plan", None)
-                                 is not None else {}),
+                "pipeline": self._pipeline_name,
                 # Caller-attributed identity (job/tenant tags from the
                 # serving layer) rides the same context record.
                 **dict(cfg.run_context_extra or {})})
@@ -1594,12 +1465,7 @@ class BFSEngine:
         elif init_states is None:
             raise ValueError("need init_states or resume")
         res = EngineResult(
-            pipeline=(cfg.pipeline if self._v3_plan is not None
-                      else "v2" if self._v2 is not None else "v1"),
-            fused_stages=(dict(self._v3_plan.stages)
-                          if self._v3_plan is not None else {}),
-            fused_reasons=(dict(self._v3_plan.reasons)
-                           if self._v3_plan is not None else {}),
+            pipeline=self._pipeline_name,
             por_instances=(self._por_table.certified
                            if self._por_table is not None else 0),
             family_groups=_family_groups_meta(dims, self._v2))

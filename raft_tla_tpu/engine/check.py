@@ -208,16 +208,7 @@ def format_result(res: EngineResult) -> str:
             lines.append(f"widest level       {peak['level']} "
                          f"({peak['frontier']:,} states)")
     if res.pipeline:
-        line = f"pipeline           {res.pipeline}"
-        if res.fused_stages:
-            line += " (" + " ".join(
-                f"{s}={impl}" for s, impl in res.fused_stages.items()) + ")"
-        lines.append(line)
-        # A stage that FAILED its build-probe (vs a policy/forced XLA
-        # choice) is operator-actionable — say so in the result block.
-        for s, why in sorted(res.fused_reasons.items()):
-            if "failed to build/probe" in why:
-                lines.append(f"  {s} fell back: {why}")
+        lines.append(f"pipeline           {res.pipeline}")
     if res.action_counts:
         lines.append("generated by action family:")
         for name, c in sorted(res.action_counts.items(),

@@ -55,12 +55,10 @@ _I32 = jnp.int32
 
 # The stages of one pass, in order, as they are named in the compiled
 # program (a component ``<stage>`` of every operation's ``op_name``, so in
-# a profiler capture and in ``--xla_dump_to`` text).  ``front`` and
-# ``insert_enqueue`` stand for what the v4/v3 kernels fuse.  The engines
-# put ``prologue``/``epilogue`` around what sits outside the ``while``.
+# a profiler capture and in ``--xla_dump_to`` text).  The engines put
+# ``prologue``/``epilogue`` around what sits outside the ``while``.
 STAGES = ("slice", "masks", "compact", "construct", "insert", "enqueue",
           "record", "stats")
-FUSED_STAGES = ("front", "insert_enqueue")
 
 # Scope names are debug info, which jax strips before it hashes a module
 # for the persistent compile cache (``cache_key._canonicalize_ir``): a
@@ -94,9 +92,8 @@ def tag_stages(count):
 
 def build_chunk_body(*, dims, expand, fingerprint, pack_ok, inv_fns,
                      constraint, B, G, K, Q, TQ, record_static, compactor,
-                     insert_fn, v2=None, enqueue_method="scatter",
-                     por_mask=None, por_priority=None, fused_tail=None,
-                     fused_front=None):
+                     insert_fn, v2=None, por_mask=None,
+                     por_priority=None):
     """Returns ``chunk_body(qcur, cur_count, carry) -> carry'``.
 
     ``Q`` is the live next-queue capacity (per chip for the mesh); masked
@@ -121,30 +118,7 @@ def build_chunk_body(*, dims, expand, fingerprint, pack_ok, inv_fns,
     unaffected (masking only fires on non-empty enabled sets), and
     masked lanes' overflow flags are dropped with them (a pruned
     successor is never materialized, so its capacity overflow cannot
-    abort the reduced run).
-
-    ``fused_tail`` (the v3 pipeline, ops/pipeline_v3.py) replaces the
-    separate insert + enqueue stages with ONE fused Pallas kernel
-    ``(seen, kh, kl, kvalid, krows, cons_ok, next_count, qnext) ->
-    (seen, new, fail, qnext)`` (ops/fused_tail_pallas.py).  Requires
-    ``v2`` (the fused kernel consumes the delta fingerprints); the
-    constraint and row materialization move BEFORE the insert — they
-    depend only on the compacted candidates, so every carry field stays
-    bit-identical to the split path (the tests' contract).
-
-    ``fused_front`` (the v4 pipeline, ops/pipeline_v4.py) replaces the
-    masks -> POR -> compact -> fingerprint/constraint/invariant section
-    with ONE Pallas megakernel ``(rows, valid) -> (en, ovf, pruned, P,
-    total, lane_id, kvalid, kh, kl, krows, cons_ok, inv, parent_hi,
-    parent_lo)`` (ops/chunk_front_pallas.py) whose body runs the SAME
-    model functions on the VMEM-resident parent window; ``en``/``ovf``
-    arrive already progress-limited, ``pruned`` pre-limit (this body
-    applies ``& ptaken`` when accounting, like the split path).
-    Requires ``v2``; the kernel bakes in the POR arrays and the
-    constraint/invariant dispatch, so those arguments must describe the
-    same run."""
-    if enqueue_method not in ("scatter", "window", "pallas"):
-        raise ValueError(f"unknown enqueue method {enqueue_method!r}")
+    abort the reduced run)."""
     if (por_mask is None) != (por_priority is None):
         raise ValueError("por_mask and por_priority must be given together")
     if por_mask is not None:
@@ -166,10 +140,6 @@ def build_chunk_body(*, dims, expand, fingerprint, pack_ok, inv_fns,
             raise ValueError(
                 f"POR mask/priority must be bool/int32, got "
                 f"{por_mask.dtype} / {por_priority.dtype}")
-    if fused_tail is not None and v2 is None:
-        raise ValueError("fused_tail (v3) requires the v2 delta pipeline")
-    if fused_front is not None and v2 is None:
-        raise ValueError("fused_front (v4) requires the v2 delta pipeline")
     BG = B * G
     inv_id = build_inv_id(inv_fns) if inv_fns else None
 
@@ -179,26 +149,8 @@ def build_chunk_body(*, dims, expand, fingerprint, pack_ok, inv_fns,
     def slice_(qcur, offset, cur_count):
         rows = jax.lax.dynamic_slice_in_dim(qcur, offset, B, axis=0)
         valid = (offset + jnp.arange(B, dtype=_I32)) < cur_count
-        # The v4 front kernel reads the packed rows itself.
-        states = (None if fused_front is not None else
-                  jax.vmap(unflatten_state, (0, None))(rows, dims))
+        states = jax.vmap(unflatten_state, (0, None))(rows, dims)
         return rows, valid, states
-
-    @functools.partial(named_stage, "front")
-    def front(rows, valid):
-        # v4: one Pallas megakernel runs masks -> POR -> compact ->
-        # delta fingerprints -> constraint/invariants on the
-        # VMEM-resident parent window.  en/ovf arrive already
-        # progress-limited; pruned is pre-limit (accounted in ``stats``
-        # like the split path); the per-lane parent fingerprints feed
-        # the trace recorder without re-reading the parents.
-        (en, ovf, pruned, P, total, lane_id, kvalid, kh, kl, krows,
-         cons_ok, inv, parent_hi, parent_lo) = fused_front(rows, valid)
-        if por_mask is None:
-            pruned = None
-        ptaken = jnp.arange(B, dtype=_I32) < P
-        return (en, ovf, pruned, P, total, lane_id, kvalid, ptaken, kh,
-                kl, krows, cons_ok, inv, parent_hi, parent_lo)
 
     @functools.partial(named_stage, "masks")
     def masks(states, valid):
@@ -285,8 +237,7 @@ def build_chunk_body(*, dims, expand, fingerprint, pack_ok, inv_fns,
             cons_ok = jnp.ones((K,), bool)
         krows = jax.vmap(flatten_state, (0, None))(kstates, dims)
         # Invariant dispatch depends only on the candidates, so it
-        # sits before the insert on both paths (the v4 kernel
-        # computes it in-kernel; values are insert-independent).
+        # sits before the insert.
         if inv_id is not None:
             inv = jax.vmap(inv_id)(kstates)
         else:
@@ -303,80 +254,29 @@ def build_chunk_body(*, dims, expand, fingerprint, pack_ok, inv_fns,
 
     insert = named_stage("insert", insert_fn)
 
-    @functools.partial(named_stage, "insert_enqueue")
-    def insert_enqueue(seen, kh, kl, kvalid, krows, cons_ok, next_count,
-                       qnext):
-        # v3: one Pallas kernel probes/inserts the K keys and
-        # appends each novel constraint-passing row at the running
-        # cursor — the novelty bit never returns to HBM between the
-        # stages.  The constraint/rows moved BEFORE the insert (they
-        # depend only on the candidates), so every value is
-        # bit-identical to the split path.
-        seen, new, fail, qnext = fused_tail(
-            seen, kh, kl, kvalid, krows, cons_ok, next_count, qnext)
-        next_count = next_count + jnp.sum(new & cons_ok, dtype=_I32)
-        return seen, new, fail, qnext, next_count
-
     @functools.partial(named_stage, "enqueue")
     def enqueue(qnext, next_count, krows, new, cons_ok):
+        # Each row at its cumsum position; a masked lane writes its own
+        # trash slot past Q.  (Rebuilding a K-row window at next_count
+        # with a searchsorted gather and one dynamic_update_slice was
+        # the alternative until PR 31 timed it on the chip: 5.9 to 8.4 %
+        # slower end to end, PERF.md section 6.)
         enq = new & cons_ok
-        if enqueue_method == "scatter":
-            epos = next_count + jnp.cumsum(enq.astype(_I32)) - 1
-            epos = jnp.where(enq, epos, Q + jnp.arange(K, dtype=_I32))
-            qnext = qnext.at[epos].set(krows)
-        elif enqueue_method == "pallas":
-            # Run-coalesced DMA append (ops/enqueue_pallas.py): the enq
-            # destination is contiguous, so the rows go out as ~new_n/SEG
-            # HBM-to-HBM segment copies instead of K row-scatters.  Live
-            # rows bit-identical; trash region simply untouched (the
-            # "window" precedent).
-            from ..ops import enqueue_pallas
-            qnext = enqueue_pallas.enqueue(qnext, next_count, krows, enq)
-        else:
-            # "window": invert the placement instead of scattering 473-
-            # byte rows (the TPU profile's 14.5 ms enqueue stage).  The
-            # enq lanes land contiguously at [next_count, next_count +
-            # new_n); a K-row window at next_count is rebuilt with a
-            # searchsorted gather and written back with ONE
-            # dynamic_update_slice.  Live rows are bit-identical to the
-            # scatter path; the former trash region [Q, Q+K) is simply
-            # left untouched.  The batch watermark (next_count <= Q - K)
-            # plus PAD >= B keeps the window in-bounds.
-            from ..ops.compact import inv_positions
-            new_n = jnp.sum(enq, dtype=_I32)
-            w = jnp.arange(K, dtype=_I32)
-            src = inv_positions(enq, K)
-            win = jax.lax.dynamic_slice(
-                qnext, (next_count, jnp.int32(0)), (K, qnext.shape[1]))
-            win = jnp.where((w < new_n)[:, None], krows[src], win)
-            qnext = jax.lax.dynamic_update_slice(
-                qnext, win, (next_count, jnp.int32(0)))
+        epos = next_count + jnp.cumsum(enq.astype(_I32)) - 1
+        epos = jnp.where(enq, epos, Q + jnp.arange(K, dtype=_I32))
+        qnext = qnext.at[epos].set(krows)
         return qnext, next_count + jnp.sum(enq, dtype=_I32)
 
     @functools.partial(named_stage, "record")
     def record(tbuf, tcount, new, kh, kl, parent_hi, parent_lo, lane_id):
         actions = lane_id % G
-        if enqueue_method == "scatter":
-            tpos = jnp.where(
-                new, tcount + jnp.cumsum(new.astype(_I32)) - 1,
-                TQ + jnp.arange(K, dtype=_I32))
-            tbuf = tuple(
-                buf.at[tpos].set(col)
-                for buf, col in zip(
-                    tbuf, (kh, kl, parent_hi, parent_lo, actions)))
-        else:
-            from ..ops.compact import inv_positions
-            tn = jnp.sum(new, dtype=_I32)
-            tw = jnp.arange(K, dtype=_I32)
-            tsrc = inv_positions(new, K)
-            out = []
+        tpos = jnp.where(
+            new, tcount + jnp.cumsum(new.astype(_I32)) - 1,
+            TQ + jnp.arange(K, dtype=_I32))
+        tbuf = tuple(
+            buf.at[tpos].set(col)
             for buf, col in zip(
-                    tbuf, (kh, kl, parent_hi, parent_lo, actions)):
-                twin = jax.lax.dynamic_slice(buf, (tcount,), (K,))
-                twin = jnp.where(tw < tn, col[tsrc], twin)
-                out.append(jax.lax.dynamic_update_slice(
-                    buf, twin, (tcount,)))
-            tbuf = tuple(out)
+                tbuf, (kh, kl, parent_hi, parent_lo, actions)))
         return tbuf, tcount + jnp.sum(new, dtype=_I32)
 
     @functools.partial(named_stage, "stats")
@@ -428,21 +328,12 @@ def build_chunk_body(*, dims, expand, fingerprint, pack_ok, inv_fns,
         (offset, steps, qnext, next_count, seen, tbuf, tcount,
          *counters) = carry
         rows, valid, states = slice_(qcur, offset, cur_count)
-        if fused_front is not None:
-            (en, ovf, pruned, P, total, lane_id, kvalid, ptaken, kh, kl,
-             krows, cons_ok, inv, parent_hi, parent_lo) = front(rows, valid)
-        else:
-            cands, en, ovf, pruned = masks(states, valid)
-            en, ovf, P, total, lane_id, kvalid, ptaken = compact(en, ovf)
-            (kh, kl, krows, cons_ok, inv, parent_hi,
-             parent_lo) = construct(states, cands, lane_id)
-        if fused_tail is not None:
-            seen, new, fail, qnext, next_count = insert_enqueue(
-                seen, kh, kl, kvalid, krows, cons_ok, next_count, qnext)
-        else:
-            seen, new, fail = insert(seen, kh, kl, kvalid)
-            qnext, next_count = enqueue(qnext, next_count, krows, new,
-                                        cons_ok)
+        cands, en, ovf, pruned = masks(states, valid)
+        en, ovf, P, total, lane_id, kvalid, ptaken = compact(en, ovf)
+        (kh, kl, krows, cons_ok, inv, parent_hi,
+         parent_lo) = construct(states, cands, lane_id)
+        seen, new, fail = insert(seen, kh, kl, kvalid)
+        qnext, next_count = enqueue(qnext, next_count, krows, new, cons_ok)
         if record_static:
             tbuf, tcount = record(tbuf, tcount, new, kh, kl, parent_hi,
                                   parent_lo, lane_id)

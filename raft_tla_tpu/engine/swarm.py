@@ -75,7 +75,7 @@ _U32 = jnp.uint32
 class SwarmResult:
     """Swarm run outcome — swarm-native counters plus the EngineResult
     surface (stop_reason/distinct/generated/diameter/wall_seconds/
-    pipeline/fused_stages/report/violation/counterexample) the history
+    pipeline/report/violation/counterexample) the history
     ledger, serving layer, and explainer already consume.  The ledger
     dialect: ``distinct`` is accepted (ring-fresh) state visits,
     ``generated`` is lockstep walk-steps executed."""
@@ -90,7 +90,6 @@ class SwarmResult:
     stop_reason: str = "steps"
     wall_seconds: float = 0.0
     pipeline: str = ""
-    fused_stages: Dict[str, str] = dataclasses.field(default_factory=dict)
     phases: Dict[str, float] = dataclasses.field(default_factory=dict)
     report: Dict = dataclasses.field(default_factory=dict)
     violation: Optional[Violation] = None

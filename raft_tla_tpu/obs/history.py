@@ -12,7 +12,7 @@ append-only JSONL ledger closing that gap: one line per run, recording
   ``host_fingerprint`` (obs/flight.py) and its short ``host_key``;
 - outcome: verdict / stop_reason, distinct / generated / diameter /
   wall seconds, headline rates;
-- how it ran: pipeline + resolved fused-stage plan;
+- how it ran: the resolved pipeline;
 - the ``statespace`` report summary (obs/report.py ``summarize``);
 - for bench runs, the full bench JSON (``bench``) — which is what lets
   ``scripts/bench_diff.py --history`` resolve its baseline from the
@@ -74,7 +74,6 @@ def make_entry(kind: str, *, label: Optional[str] = None,
                distinct_per_sec: Optional[float] = None,
                generated_per_sec: Optional[float] = None,
                pipeline: Optional[str] = None,
-               fused_stages: Optional[dict] = None,
                report_summary: Optional[dict] = None,
                bench: Optional[dict] = None,
                ts: Optional[float] = None,
@@ -105,7 +104,6 @@ def make_entry(kind: str, *, label: Optional[str] = None,
         "distinct_per_sec": distinct_per_sec,
         "generated_per_sec": generated_per_sec,
         "pipeline": pipeline,
-        "fused_stages": dict(fused_stages or {}) or None,
         "report": dict(report_summary or {}) or None,
         "bench": bench,
     }
@@ -136,7 +134,6 @@ def entry_from_result(kind: str, res, *, cfg_text=None, dims=None,
         distinct_per_sec=round(res.distinct / wall, 1) if wall else None,
         generated_per_sec=round(res.generated / wall, 1) if wall else None,
         pipeline=res.pipeline or None,
-        fused_stages=res.fused_stages,
         report_summary=summarize(getattr(res, "report", None)),
         extra=extra)
 
@@ -158,7 +155,6 @@ def entry_from_bench(doc: dict, *, label=None, kind="bench",
         distinct_per_sec=doc.get("value"),
         generated_per_sec=doc.get("generated_per_sec"),
         pipeline=doc.get("pipeline"),
-        fused_stages=doc.get("fused_stages"),
         report_summary=summarize(doc.get("report")),
         bench=doc)
 
@@ -245,9 +241,9 @@ def perf_columns(entry: dict):
     entry's embedded bench perf block (obs/perf.py) — or the
     xplane_summary dialect, which embeds the same block shape.  The
     bandwidth fraction is the MAX across the profiled roofline stages
-    (the most saturated stage — what a v3/v4 fusion round is trying to
-    raise).  (None, None, None) for entries predating the metric, so
-    the trajectory renders '--' instead of guessing."""
+    (the most saturated stage).  (None, None, None) for entries
+    predating the metric, so the trajectory renders '--' instead of
+    guessing."""
     bench = entry.get("bench") or {}
     perf = bench.get("perf") or {}
     lpc = (perf.get("launch") or {}).get("launches_per_chunk")
@@ -282,10 +278,9 @@ def render_table(entries: List[dict], perf: bool = False,
     NOT rate-comparable (different or unknown host) — the r05 trap,
     rendered impossible to miss.  ``perf=True`` adds the performance-
     observatory columns (pipeline + launches/chunk + peak bandwidth
-    fraction + advisor pick) so the trajectory shows whether fusion
-    work (v3's fused tail, v4's megakernel) is actually RETIRING
-    launches and raising saturation across rounds, not just moving
-    wall-clock.  ``hunt=True`` adds the hunt-observatory columns
+    fraction + advisor pick) so the trajectory shows whether launches
+    are retired and saturation raised across rounds, not just
+    wall-clock moved.  ``hunt=True`` adds the hunt-observatory columns
     (coverage saturation + novelty rate + time-to-violation from
     obs/hunt.py summaries) so a swarm trajectory answers "is each
     round's hunt saturating sooner / latching faster" at a glance."""

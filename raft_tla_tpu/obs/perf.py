@@ -7,13 +7,11 @@ closes that gap with a **static launch model** plus a cheap dynamic
 feed:
 
 - *static*: walk the engine's REAL traced chunk program (the exact
-  jaxpr ``engine/bfs.py`` / ``parallel/mesh.py`` compile, v1/v2/v3,
-  POR mask and fused tail included) counting device ops — every
-  equation except pure layout prims, loop bodies once, ``pallas_call``
-  = one.  The count is a deterministic PRE-FUSION upper bound on kernel
-  launches (XLA fuses some neighbors; a Pallas stage is exactly one),
-  which makes fused-vs-unfused deltas first-class and CI-pinnable: a
-  stage silently un-fusing moves the pin.  The measured truth comes
+  jaxpr ``engine/bfs.py`` / ``parallel/mesh.py`` compile, v1/v2,
+  POR mask included) counting device ops — every equation except pure
+  layout prims, loop bodies once.  The count is a deterministic
+  PRE-FUSION upper bound on kernel launches (XLA fuses some
+  neighbors), CI-pinnable.  The measured truth comes
   from the device profiler (``scripts/xplane_summary.py`` over the
   stage-5b XPlane artifacts) — the static model is the gate, the
   XPlane number is the evidence.
@@ -113,17 +111,12 @@ class PerfAccounting:
     def __init__(self, *, pipeline: str, launch_model: Optional[dict],
                  stage_traffic: Optional[Dict[str, dict]],
                  peak: Optional[dict] = None,
-                 plan_launches: Optional[Dict[str, object]] = None,
                  metrics=None):
         from . import roofline as roofline_mod
         self.pipeline = pipeline
         self.launch_model = launch_model
         self.traffic = stage_traffic
         self.peak = peak or roofline_mod.peak_bandwidth()
-        #: v3 only — resolve_plan's expected launches per stage (a
-        #: Pallas/fused stage is exactly 1 kernel); the fused-vs-
-        #: unfused delta in its most legible form.
-        self.plan_launches = plan_launches
         self.metrics = metrics
         self.overhead_s = launch_overhead_seconds()
         self.reset()
@@ -217,8 +210,6 @@ class PerfAccounting:
                          "stages": rows},
             "advisor": advisor,
         }
-        if self.plan_launches is not None:
-            out["plan_launches"] = dict(self.plan_launches)
         if self.collective_probe_seconds is not None and lm is not None:
             probe = self.collective_probe_seconds
             coll = {"probe_seconds": round(probe, 6),
@@ -303,8 +294,7 @@ class PerfAccounting:
 def build_accounting(*, pipeline: str, chunk_fn, chunk_avals,
                      dims=None, B: Optional[int] = None,
                      K: Optional[int] = None,
-                     compact_method: str = "scatter", v3_force=None,
-                     plan=None, with_stages: bool = True,
+                     with_stages: bool = True,
                      metrics=None, engine: str = "engine",
                      ring: int = 16, swarm_pipeline: str = "v1"
                      ) -> PerfAccounting:
@@ -329,20 +319,14 @@ def build_accounting(*, pipeline: str, chunk_fn, chunk_avals,
         if with_stages and dims is not None:
             traffic = roofline_mod.stage_traffic(
                 dims, B, K,
-                pipeline=(pipeline if pipeline in ("v3", "v4", "swarm")
-                          else "v1"),
-                compact_method=compact_method, v3_force=v3_force,
+                pipeline="swarm" if pipeline == "swarm" else "v1",
                 ring=ring, swarm_pipeline=swarm_pipeline)
     except Exception as e:
         print(f"perf: {engine} launch/roofline model unavailable "
               f"({type(e).__name__}: {e}); continuing without",
               file=sys.stderr)
-    plan_launches = None
-    if plan is not None:
-        plan_launches = dict(getattr(plan, "launches", None) or {})
     return PerfAccounting(pipeline=pipeline, launch_model=launch_model,
-                          stage_traffic=traffic,
-                          plan_launches=plan_launches, metrics=metrics)
+                          stage_traffic=traffic, metrics=metrics)
 
 
 def timed_collective_probe(fn, *args, warm: bool = True) -> float:

@@ -29,22 +29,6 @@ Stage -> pipeline mapping (engine/chunk.py):
     dedup_insert  ops/fpset.py batched insert (in-batch dedup + probe)
     enqueue       materialize K uint8 rows + position scatter
 
-``pipeline="v3"`` switches to the FUSED-stage granularity of the v3
-chunk (ops/pipeline_v3.py) — the decomposition that actually runs
-there, so its table prices the fused kernels instead of a pipeline the
-engine is not executing:
-
-    masks           guards-only enabled/overflow masks (actions2)
-    compact         lane compaction (Pallas scan on TPU, XLA off it)
-    fingerprint     delta fingerprints + K-lane sparse rows
-    insert_enqueue  the fused probe/insert -> DMA-append tail
-
-``pipeline="v4"`` narrows further to the v4 megakernel granularity
-(ops/pipeline_v4.py) — two fused launches per chunk:
-
-    front           masks + POR + compact + fingerprint megakernel
-    insert_enqueue  the fused probe/insert -> DMA-append tail
-
 ``pipeline="swarm"`` profiles the walk-kernel decomposition of the
 swarm tier's lockstep scan body (engine/swarm.py) instead of a
 frontier chunk — same fencing discipline, swarm stage headings:
@@ -54,9 +38,6 @@ frontier chunk — same fencing discipline, swarm stage headings:
     choose        counter-PRNG draws + family-diversified choice
     latch         chosen-successor materialization + fingerprint
     ring_probe    per-walk ring dedup probe -> push -> restart reset
-
-``scripts/bench_diff.py`` folds the granularities onto common coarse
-stages when diffing across pipelines.
 
 jax is imported lazily (constructor), keeping ``obs`` importable in
 device-less tooling like the rest of the package.
@@ -69,15 +50,12 @@ import time
 from typing import Dict, Optional
 
 STAGES = ("expand", "fingerprint", "dedup_insert", "enqueue")
-STAGES_V3 = ("masks", "compact", "fingerprint", "insert_enqueue")
-STAGES_V4 = ("front", "insert_enqueue")
 STAGES_SWARM = ("expand", "choose", "latch", "ring_probe")
 
 STAGE_PREFIX = "chunk_stage/"
 
 
-def build_stage_programs(dims, B: int, K: int,
-                         compact_method: str = "scatter") -> dict:
+def build_stage_programs(dims, B: int, K: int) -> dict:
     """The jitted stage programs, shared by :class:`ChunkProfiler` and
     ``scripts/profile_step.py`` (which used to hand-roll the same
     decomposition).  Returns ``{stage_name: fn, "total": fn,
@@ -97,7 +75,7 @@ def build_stage_programs(dims, B: int, K: int,
     BG = B * G
     expand = build_expand(dims)
     fingerprint = build_fingerprint(dims)
-    compactor = build_compactor(B, G, K, method=compact_method)
+    compactor = build_compactor(B, G, K)
     # Profiler-local next-queue: K live rows + K per-lane trash slots
     # (the engine's trash-spread rule, ops/fpset.py design note 3).  The
     # scatter's cost scales with the rows written (K), not the target
@@ -145,180 +123,6 @@ def build_stage_programs(dims, B: int, K: int,
     }
 
 
-def build_stage_programs_v3(dims, B: int, K: int,
-                            compact_method: str = "scatter",
-                            force: Optional[dict] = None) -> dict:
-    """Stage programs at the v3 fused-chunk granularity (STAGES_V3).
-
-    The decomposition mirrors engine/chunk.py's v3 path exactly: v2
-    guards-only masks, the plan-resolved compactor (Pallas where it
-    lowers), delta fingerprints + sparse K-lane rows, then the fused
-    probe/insert->enqueue tail.  ``force`` must be the ENGINE'S
-    ``EngineConfig.v3_force_stages`` so the per-stage plan resolution
-    matches the engine's.  Caveat: when the fused tail itself fell back,
-    this profiler's split-tail stand-in is the DEFAULT XLA pair
-    (fpset.insert + scatter) regardless of insert_method/enqueue_method
-    overrides — the fallback engine's exotic-override combinations are
-    not mirrored here.  Same return shape as
-    ``build_stage_programs``."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..models.actions2 import build_v2
-    from ..models.schema import flatten_state, state_width, unflatten_state
-    from ..ops import fpset
-    from ..ops import pipeline_v3
-    from ..ops.compact import build_compactor
-
-    _I32 = jnp.int32
-    G = dims.n_instances
-    v2 = build_v2(dims)
-    QP = K
-    # Re-resolved here (not reused from the engine) because the fused
-    # tail binds the queue capacity statically and the profiler runs
-    # against its own QP-row scratch queue — but the INPUTS that decide
-    # each stage's lowering (force, compact_method, platform) are the
-    # engine's, so the resolved lowerings match the engine's plan.
-    plan = pipeline_v3.resolve_plan(B, G, K, Q=QP, sw=state_width(dims),
-                                    force=force)
-    compactor = plan.compactor or build_compactor(B, G, K,
-                                                  method=compact_method)
-
-    def s_masks(rows, valid):
-        states = jax.vmap(unflatten_state, (0, None))(rows, dims)
-        en, _ovf = jax.vmap(v2.masks)(states)
-        return states, en & valid[:, None]
-
-    def s_compact(en):
-        _P, _total, lane_id, kvalid = compactor(en)
-        return lane_id, kvalid
-
-    def s_fingerprint(states, lane_id):
-        ph = jax.vmap(v2.parent_hash)(states)
-        pidx = lane_id // G
-        kparents = jax.tree.map(lambda a: a[pidx], states)
-        kph = jax.tree.map(lambda a: a[pidx], ph)
-        kh, kl, kstates = jax.vmap(v2.lane_out)(kparents, kph, lane_id % G)
-        krows = jax.vmap(flatten_state, (0, None))(kstates, dims)
-        return kh, kl, krows
-
-    def s_tail(seen, kh, kl, kvalid, krows, qnext):
-        cons = jnp.ones((K,), bool)
-        if plan.tail is not None:
-            seen, new, fail, qnext = plan.tail(
-                seen, kh, kl, kvalid, krows, cons, jnp.int32(0), qnext)
-        else:
-            seen, new, fail = fpset.insert(seen, kh, kl, kvalid)
-            pos = jnp.cumsum(new.astype(_I32)) - 1
-            pos = jnp.where(new, pos, QP + jnp.arange(K, dtype=_I32))
-            qnext = qnext.at[pos].set(krows, mode="drop")
-        # fail rides out so the profiler's insert_fail saturation
-        # counter guards v3 sampling exactly as it guards v1's.
-        return seen, qnext, new, fail
-
-    def s_total(rows, valid, seen, qnext):
-        states, en = s_masks(rows, valid)
-        lane_id, kvalid = s_compact(en)
-        kh, kl, krows = s_fingerprint(states, lane_id)
-        seen, qnext, new, _fail = s_tail(seen, kh, kl, kvalid, krows,
-                                         qnext)
-        return seen, qnext, jnp.sum(new, dtype=_I32)
-
-    return {
-        "masks": jax.jit(s_masks),
-        "compact": jax.jit(s_compact),
-        "fingerprint": jax.jit(s_fingerprint),
-        "insert_enqueue": jax.jit(s_tail),
-        "total": jax.jit(s_total),
-        "queue_rows": 2 * QP,
-        "empty_seen": lambda cap: fpset.empty(cap),
-        "plan": plan,
-    }
-
-
-def build_stage_programs_v4(dims, B: int, K: int,
-                            compact_method: str = "scatter",
-                            force: Optional[dict] = None) -> dict:
-    """Stage programs at the v4 megakernel granularity (STAGES_V4).
-
-    ``front`` is the whole-chunk VMEM megakernel (masks + compact +
-    delta fingerprint in one Pallas launch); ``insert_enqueue`` is the
-    same fused tail v3 runs.  When the front group degraded (forced or
-    the kernel failed to build), the profiled ``front`` stand-in is the
-    v3-style split chain so its timing still covers the same work.
-    ``force`` must be the engine's ``EngineConfig.v4_force_stages``.
-    Constraint/invariant hooks are not mirrored (profiler scratch runs
-    have none), matching the v3 profiler's all-true ``cons``.  Same
-    return shape as ``build_stage_programs``."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..models.actions2 import build_v2
-    from ..models.schema import flatten_state, state_width, unflatten_state
-    from ..ops import fpset
-    from ..ops import pipeline_v4
-    from ..ops.compact import build_compactor
-
-    _I32 = jnp.int32
-    G = dims.n_instances
-    v2 = build_v2(dims)
-    QP = K
-    plan = pipeline_v4.resolve_plan(
-        B, G, K, Q=QP, sw=state_width(dims), force=force,
-        front_ctx={"dims": dims, "v2": v2, "constraint": None,
-                   "inv_fns": None, "por_mask": None,
-                   "por_priority": None})
-    compactor = plan.compactor or build_compactor(B, G, K,
-                                                  method=compact_method)
-
-    if plan.front is not None:
-        def s_front(rows, valid):
-            (_en, _ovf, _pruned, _P, _total, lane_id, kvalid, kh, kl,
-             krows, _cons, _inv, _phi, _plo) = plan.front(rows, valid)
-            return lane_id, kvalid, kh, kl, krows
-    else:
-        def s_front(rows, valid):
-            states = jax.vmap(unflatten_state, (0, None))(rows, dims)
-            en, _ovf = jax.vmap(v2.masks)(states)
-            en = en & valid[:, None]
-            _P, _total, lane_id, kvalid = compactor(en)
-            ph = jax.vmap(v2.parent_hash)(states)
-            pidx = lane_id // G
-            kparents = jax.tree.map(lambda a: a[pidx], states)
-            kph = jax.tree.map(lambda a: a[pidx], ph)
-            kh, kl, kstates = jax.vmap(v2.lane_out)(kparents, kph,
-                                                    lane_id % G)
-            krows = jax.vmap(flatten_state, (0, None))(kstates, dims)
-            return lane_id, kvalid, kh, kl, krows
-
-    def s_tail(seen, kh, kl, kvalid, krows, qnext):
-        cons = jnp.ones((K,), bool)
-        if plan.tail is not None:
-            seen, new, fail, qnext = plan.tail(
-                seen, kh, kl, kvalid, krows, cons, jnp.int32(0), qnext)
-        else:
-            seen, new, fail = fpset.insert(seen, kh, kl, kvalid)
-            pos = jnp.cumsum(new.astype(_I32)) - 1
-            pos = jnp.where(new, pos, QP + jnp.arange(K, dtype=_I32))
-            qnext = qnext.at[pos].set(krows, mode="drop")
-        return seen, qnext, new, fail
-
-    def s_total(rows, valid, seen, qnext):
-        _lane_id, kvalid, kh, kl, krows = s_front(rows, valid)
-        seen, qnext, new, _fail = s_tail(seen, kh, kl, kvalid, krows,
-                                         qnext)
-        return seen, qnext, jnp.sum(new, dtype=_I32)
-
-    return {
-        "front": jax.jit(s_front),
-        "insert_enqueue": jax.jit(s_tail),
-        "total": jax.jit(s_total),
-        "queue_rows": 2 * QP,
-        "empty_seen": lambda cap: fpset.empty(cap),
-        "plan": plan,
-    }
-
-
 def build_stage_programs_swarm(dims, B: int, R: int,
                                pipeline: str = "v1") -> dict:
     """Stage programs at the swarm walk-kernel granularity
@@ -329,8 +133,7 @@ def build_stage_programs_swarm(dims, B: int, R: int,
     profiled expand stage prices the masks the engine actually runs.
 
     The profiled step is the decision core only: invariant evaluation
-    and the violation latch are not mirrored (same rule as the v3/v4
-    profilers' all-true ``cons``), and the PRNG is keyed on a
+    and the violation latch are not mirrored, and the PRNG is keyed on a
     synthetic ``(seed=0, walk=lane, step=sample)`` tuple — timings
     need representative control flow, not the engine's draws.  The
     per-sample rings persist in the :class:`ChunkProfiler`, so probe
@@ -424,32 +227,24 @@ class ChunkProfiler:
     engine's own frontier rows."""
 
     def __init__(self, dims, *, batch: int, lanes: int,
-                 seen_capacity: int, compact_method: str = "scatter",
-                 pipeline: str = "v1", v3_force=None, every: int = 1,
+                 seen_capacity: int, pipeline: str = "v1", every: int = 1,
                  metrics=None, swarm_pipeline: str = "v1",
                  ring: int = 16):
         self.dims = dims
         self.B, self.K = int(batch), int(lanes)
         self.seen_capacity = int(seen_capacity)
-        self.compact_method = compact_method
-        # The engine's EngineConfig.v3_force_stages (or v4_force_stages
-        # when pipeline="v4"), so the profiled stage lowerings are
-        # exactly the ones the engine runs.
-        self.v3_force = v3_force
-        # "v1" = the classical NORTHSTAR-budget decomposition (default,
-        # cross-pipeline comparable); "v3"/"v4" = the fused-stage
-        # decomposition that chunk actually executes; "swarm" = the
-        # walk-kernel step of the swarm tier (swarm_pipeline names the
-        # engine's resolved expand pipeline, ring its dedup capacity).
-        if pipeline not in ("v1", "v3", "v4", "swarm"):
+        # "v1" = the classical four-stage decomposition (default);
+        # "swarm" = the walk-kernel step of the swarm tier
+        # (swarm_pipeline names the engine's resolved expand pipeline,
+        # ring its dedup capacity).
+        if pipeline not in ("v1", "swarm"):
             raise ValueError(f"profiler pipeline must be "
-                             f"v1/v3/v4/swarm, got {pipeline!r}")
+                             f"v1/swarm, got {pipeline!r}")
         self.pipeline = pipeline
         self.swarm_pipeline = swarm_pipeline
         self.ring_capacity = int(ring)
         self._swarm_k = 0
-        self.stages = {"v3": STAGES_V3, "v4": STAGES_V4,
-                       "swarm": STAGES_SWARM}.get(pipeline, STAGES)
+        self.stages = STAGES_SWARM if pipeline == "swarm" else STAGES
         self.every = max(1, int(every))
         self.metrics = metrics
         self.samples = 0
@@ -494,17 +289,7 @@ class ChunkProfiler:
             jax.block_until_ready((self._ring_s[0], rh, n))
             self._built = progs
             return progs
-        if self.pipeline == "v3":
-            progs = build_stage_programs_v3(self.dims, self.B, self.K,
-                                            self.compact_method,
-                                            force=self.v3_force)
-        elif self.pipeline == "v4":
-            progs = build_stage_programs_v4(self.dims, self.B, self.K,
-                                            self.compact_method,
-                                            force=self.v3_force)
-        else:
-            progs = build_stage_programs(self.dims, self.B, self.K,
-                                         self.compact_method)
+        progs = build_stage_programs(self.dims, self.B, self.K)
         from ..models.schema import state_width
         sw = state_width(self.dims)
         self._qnext = jnp.zeros((progs["queue_rows"], sw), jnp.uint8)
@@ -537,24 +322,6 @@ class ChunkProfiler:
                     *self._ring_s, fp_hi, fp_lo, en, ovf))
             self._ring_s = (rh, rl, rp)
             return None
-        if self.pipeline == "v4":
-            lane_id, kvalid, kh, kl, krows = fence(
-                "front", progs["front"](rows, valid))
-            self._seen_staged, self._qnext, new, fail = fence(
-                "insert_enqueue", progs["insert_enqueue"](
-                    self._seen_staged, kh, kl, kvalid, krows,
-                    self._qnext))
-            return fail
-        if self.pipeline == "v3":
-            states, en = fence("masks", progs["masks"](rows, valid))
-            lane_id, kvalid = fence("compact", progs["compact"](en))
-            kh, kl, krows = fence(
-                "fingerprint", progs["fingerprint"](states, lane_id))
-            self._seen_staged, self._qnext, new, fail = fence(
-                "insert_enqueue", progs["insert_enqueue"](
-                    self._seen_staged, kh, kl, kvalid, krows,
-                    self._qnext))
-            return fail
         cflat, lane_id, kvalid = fence(
             "expand", progs["expand"](rows, valid))
         kstates, kh, kl = fence(
@@ -629,9 +396,7 @@ class ChunkProfiler:
     # -- reporting -----------------------------------------------------
     def stage_means(self) -> Dict[str, float]:
         """{stage: mean seconds/sampled batch} (+ ``total`` for the fused
-        reference) — what bench JSON embeds as ``chunk_stages``.  Keys
-        follow the profiled granularity (STAGES or STAGES_V3);
-        bench_diff folds mismatched granularities when diffing."""
+        reference) — what bench JSON embeds as ``chunk_stages``."""
         if not self.samples:
             return {}
         out = {s: self._stage_totals[s] / self.samples
@@ -658,8 +423,7 @@ class ChunkProfiler:
 
     def render_table(self) -> str:
         """Run-end stage table: measured mean ms per stage and its share
-        of their sum, at the granularity profiled (v3/v4 runs render
-        their fused-stage rows)."""
+        of their sum."""
         means = self.stage_means()
         if not means:
             return "chunk profile: no samples"
@@ -693,10 +457,9 @@ class XlaProfileCapture:
     directive).
 
     The host-side chunk profiler above times WHOLE stage programs with
-    fences; it cannot see inside a program — which XLA/Mosaic kernels
-    run, their launch count, or HBM traffic.  That is exactly the
-    evidence NORTHSTAR §d's XLA-vs-Pallas decision needs, and
-    ``jax.profiler.start_trace`` captures it (XPlane protos + a
+    fences; it cannot see inside a program — which XLA kernels run,
+    their launch count, or HBM traffic.
+    ``jax.profiler.start_trace`` captures that (XPlane protos + a
     Perfetto-openable trace under ``<logdir>/plugins/profile/...``).
 
     Correlation: every engine span is in the capture itself, on the
@@ -787,9 +550,8 @@ class XlaProfileCapture:
 
 
 def profile_stages(dims, rows, valid=None, *, lanes: Optional[int] = None,
-                   seen_capacity: int = 1 << 20, n: int = 3,
-                   compact_method: str = "scatter",
-                   pipeline: str = "v1") -> Dict[str, float]:
+                   seen_capacity: int = 1 << 20,
+                   n: int = 3) -> Dict[str, float]:
     """One-shot stage profile of a frontier batch — the
     ``scripts/profile_step.py`` entry point, now on the shared programs.
     Returns {stage: mean seconds} over ``n`` fenced repetitions (first
@@ -803,8 +565,7 @@ def profile_stages(dims, rows, valid=None, *, lanes: Optional[int] = None,
     prof = ChunkProfiler(
         dims, batch=B,
         lanes=lanes or choose_k(B, dims.n_instances, None),
-        seen_capacity=seen_capacity, compact_method=compact_method,
-        pipeline=pipeline)
+        seen_capacity=seen_capacity)
     for _ in range(n):
         prof.sample(rows, valid)
     return prof.stage_means()

@@ -7,7 +7,7 @@ is small (tens of MB), so at HBM bandwidth the movement floor is
 kernel granularity, not physics.  Until now that floor was a hand
 calculation in a markdown file.  This module derives it mechanically,
 per stage, from the SAME stage programs the ChunkProfiler times
-(obs/profile.py build_stage_programs / _v3), so the model rows and the
+(obs/profile.py build_stage_programs), so the model rows and the
 measured rows share keys and can be joined into achieved-bandwidth
 fractions.
 
@@ -194,17 +194,6 @@ class TrafficDomain:
             return self._p_scan(eqn, invals)
         if name == "shard_map":
             return self._p_shard_map(eqn, invals)
-        if name == "pallas_call":
-            # One kernel by construction; block windows are invisible
-            # from the jaxpr, so operands count at full extent — an
-            # over-estimate that only ever UNDERSTATES an already-fused
-            # stage's headroom (it can't promote a fused stage to the
-            # advisor's top slot).
-            self._launch()
-            for v in invals:
-                self._read_full(v)
-            self.notes.add("pallas_call traffic at operand granularity")
-            return [_Src(None) for _ in range(nouts)]
         if name in _WINDOW_READ:
             self._read_win(invals[0], _aval_bytes(eqn.outvars[0].aval))
             for v in invals[1:]:
@@ -355,13 +344,11 @@ def jaxpr_traffic(closed, arg_avals) -> dict:
 
 
 def stage_traffic(dims, B: int, K: int, *, pipeline: str = "v1",
-                  compact_method: str = "scatter", v3_force=None,
                   seen_capacity: int = 1 << 14, ring: int = 16,
                   swarm_pipeline: str = "v1") -> Dict[str, dict]:
     """{stage: traffic dict} for the ChunkProfiler's stage programs —
-    v1 granularity (expand/fingerprint/dedup_insert/enqueue), the v3
-    fused-stage granularity, the v4 megakernel granularity
-    (front/insert_enqueue), or the swarm walk-kernel granularity
+    v1 granularity (expand/fingerprint/dedup_insert/enqueue) or the
+    swarm walk-kernel granularity
     (expand/choose/latch/ring_probe; ``ring``/``swarm_pipeline``
     mirror the swarm engine's dedup capacity and resolved expand
     pipeline) — matching ``chunk_stages`` keys so measured means and
@@ -380,15 +367,8 @@ def stage_traffic(dims, B: int, K: int, *, pipeline: str = "v1",
     if pipeline == "swarm":
         progs = profile_mod.build_stage_programs_swarm(
             dims, B, ring, pipeline=swarm_pipeline)
-    elif pipeline == "v3":
-        progs = profile_mod.build_stage_programs_v3(
-            dims, B, K, compact_method, force=v3_force)
-    elif pipeline == "v4":
-        progs = profile_mod.build_stage_programs_v4(
-            dims, B, K, compact_method, force=v3_force)
     else:
-        progs = profile_mod.build_stage_programs(dims, B, K,
-                                                 compact_method)
+        progs = profile_mod.build_stage_programs(dims, B, K)
 
     def traced(fn, *args):
         closed = jax.make_jaxpr(fn)(*args)
@@ -419,32 +399,15 @@ def stage_traffic(dims, B: int, K: int, *, pipeline: str = "v1",
         return out
     seen = jax.eval_shape(lambda: fpset.empty(seen_capacity))
     qnext = jax.ShapeDtypeStruct((progs["queue_rows"], sw), jnp.uint8)
-    if pipeline == "v4":
-        lane_id, kvalid, kh, kl, krows = jax.eval_shape(
-            progs["front"], rows, valid)
-        out["front"] = traced(progs["front"], rows, valid)
-        out["insert_enqueue"] = traced(progs["insert_enqueue"], seen, kh,
-                                       kl, kvalid, krows, qnext)
-    elif pipeline == "v3":
-        states, en = jax.eval_shape(progs["masks"], rows, valid)
-        out["masks"] = traced(progs["masks"], rows, valid)
-        lane_id, kvalid = jax.eval_shape(progs["compact"], en)
-        out["compact"] = traced(progs["compact"], en)
-        kh, kl, krows = jax.eval_shape(progs["fingerprint"], states,
-                                       lane_id)
-        out["fingerprint"] = traced(progs["fingerprint"], states, lane_id)
-        out["insert_enqueue"] = traced(progs["insert_enqueue"], seen, kh,
-                                       kl, kvalid, krows, qnext)
-    else:
-        cflat, lane_id, kvalid = jax.eval_shape(progs["expand"], rows,
-                                                valid)
-        out["expand"] = traced(progs["expand"], rows, valid)
-        kstates, kh, kl = jax.eval_shape(progs["fingerprint"], cflat,
-                                         lane_id)
-        out["fingerprint"] = traced(progs["fingerprint"], cflat, lane_id)
-        out["dedup_insert"] = traced(progs["dedup_insert"], seen, kh, kl,
-                                     kvalid)
-        out["enqueue"] = traced(progs["enqueue"], qnext, kstates, kvalid)
+    cflat, lane_id, kvalid = jax.eval_shape(progs["expand"], rows,
+                                            valid)
+    out["expand"] = traced(progs["expand"], rows, valid)
+    kstates, kh, kl = jax.eval_shape(progs["fingerprint"], cflat,
+                                     lane_id)
+    out["fingerprint"] = traced(progs["fingerprint"], cflat, lane_id)
+    out["dedup_insert"] = traced(progs["dedup_insert"], seen, kh, kl,
+                                 kvalid)
+    out["enqueue"] = traced(progs["enqueue"], qnext, kstates, kvalid)
     for t in out.values():
         t["bytes_total"] = t["bytes_read"] + t["bytes_written"]
     return out

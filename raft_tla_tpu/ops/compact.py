@@ -44,30 +44,13 @@ def choose_k(B: int, G: int, requested=None) -> int:
     return min(fpset._pow2(max(k, G, B)), fpset._pow2(B * G))
 
 
-def inv_positions(mask, out_len: int):
-    """Invert a boolean mask's compaction map: result[k] = index of the
-    (k+1)-th True lane, for k < sum(mask); clipped in-range otherwise
-    (callers gate dead slots themselves).  The searchsorted(side="left")
-    over the running count with +1 queries is the subtle core shared by
-    the searchsorted compactor and the window enqueue/trace lowerings —
-    keep it in ONE place."""
-    cum = jnp.cumsum(mask.astype(_I32))
-    q = jnp.arange(1, out_len + 1, dtype=_I32)
-    return jnp.clip(jnp.searchsorted(cum, q, side="left"),
-                    0, mask.shape[0] - 1).astype(_I32)
-
-
 def kspread(B: int, G: int, K: int):
-    """Hash-spread addresses for dead compacted slots — the ONE
-    definition shared by every compact lowering (both methods here and
-    ops/compact_pallas.py), because lane_id bit-identity across
-    lowerings depends on all of them initializing dead slots from the
-    identical vector."""
+    """Hash-spread addresses for dead compacted slots: each its own
+    cold address (a shared one serializes the gathers that follow)."""
     return jnp.asarray((np.arange(K) * 2654435761) % (B * G), _I32)
 
 
-def build_compactor(B: int, G: int, K: int, reduce_p=None,
-                    method: str = "scatter"):
+def build_compactor(B: int, G: int, K: int, reduce_p=None):
     """Returns ``compact(en) -> (P, total, lane_id, kvalid)`` for a
     [B, G] enabled mask:
 
@@ -89,16 +72,10 @@ def build_compactor(B: int, G: int, K: int, reduce_p=None,
     every chip advances its offset identically (the chunk body contains
     collectives, so trip counts must agree).
 
-    ``method`` selects the lowering, with IDENTICAL outputs (unit-tested):
-
-    - "scatter": the original formulation — a B*G-lane scatter of lane
-      indices into the K live + K trash slots;
-    - "searchsorted": invert the mapping instead — ``lane_id[k]`` is the
-      first flat lane whose running enabled-count reaches ``k+1``, i.e. a
-      binary search of ``arange(K)+1`` in the [B*G] cumsum.  ~log2(B*G)
-      gather rounds over K lanes replaces the B*G-lane scatter (the TPU
-      profile's 21 ms compact stage is that scatter); dead slots get the
-      same spread addresses as "scatter"."""
+    The lowering is a B*G-lane scatter of lane indices into the K live +
+    K trash slots.  (A ``searchsorted`` inversion of the running count
+    was the alternative until PR 31 timed it on the chip: 2.2 to 5.6 %
+    slower end to end in both deep cells, PERF.md section 6.)"""
     BG = B * G
     lane_f = jnp.arange(BG, dtype=_I32)
     kspr = kspread(B, G, K)
@@ -122,13 +99,4 @@ def build_compactor(B: int, G: int, K: int, reduce_p=None,
             .at[pos].set(lane_f)[:K]
         return P, total, lane_id, kvalid
 
-    def compact_searchsorted(en):
-        P, total, enf, kvalid = _prefix(en)
-        lane_id = jnp.where(kvalid, inv_positions(enf, K), kspr)
-        return P, total, lane_id, kvalid
-
-    if method == "scatter":
-        return compact_scatter
-    if method == "searchsorted":
-        return compact_searchsorted
-    raise ValueError(f"unknown compactor method {method!r}")
+    return compact_scatter

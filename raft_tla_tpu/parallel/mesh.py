@@ -152,12 +152,6 @@ class MeshBFSEngine:
             # Fail at construction, not at the first level-boundary write.
             from ..engine import checkpoint as _ckpt
             _ckpt.check_dims_checkpointable(dims)
-        if cfg.insert_method != "xla":
-            # The shard-local insert runs inside shard_map; the Pallas
-            # lowering is a single-host experiment and
-            # must not be silently ignored here.
-            raise NotImplementedError(
-                "MeshEngine supports insert_method='xla' only")
         devices = devices if devices is not None else jax.devices()
         self.n_dev = n = len(devices)
         self.mesh = Mesh(np.asarray(devices), ("x",))
@@ -170,6 +164,7 @@ class MeshBFSEngine:
         from ..engine.bfs import (_resolve_pipeline, por_device_arrays,
                                   resolve_por)
         self._v2 = _resolve_pipeline(cfg.pipeline, dims)
+        self._pipeline_name = "v2" if self._v2 is not None else "v1"
         # POR reduction table (analysis/por.py): resolved/verified once
         # on the host; the [G] mask/priority arrays are closed over by
         # the chunk body below, so shard_map replicates them to every
@@ -223,8 +218,7 @@ class MeshBFSEngine:
         # pmin keeps every chip's offset advance identical — the chunk
         # body contains collectives, so trip counts must agree.
         compactor = compact_mod.build_compactor(
-            B, G, K, reduce_p=lambda p: jax.lax.pmin(p, "x"),
-            method=cfg.compact_method)
+            B, G, K, reduce_p=lambda p: jax.lax.pmin(p, "x"))
 
         def route_insert(seen_local, fph, fpl, valid):
             """Cross-chip owner dedup: route each valid fingerprint to its
@@ -309,33 +303,6 @@ class MeshBFSEngine:
             return (qnext, next_count, seen_local, tbuf, tcount, n_new,
                     fail, vinfo)
 
-        # v3 on the mesh: the collective-coupled stages (pmin-replicated
-        # compact, owner-routed insert) stay XLA by design — the plan
-        # records why — and the enqueue stage rides the Pallas
-        # run-coalesced append inside shard_map.  Bit-identical either
-        # way (the engines' shared-body contract).
-        enqueue_method = cfg.enqueue_method
-        if cfg.pipeline == "v3":
-            from ..ops import pipeline_v3
-            self._v3_plan = pipeline_v3.resolve_plan(
-                B, G, K, Q=QL, sw=sw, mesh=True,
-                enqueue_method=cfg.enqueue_method,
-                force=cfg.v3_force_stages)
-            enqueue_method = self._v3_plan.enqueue_method
-        elif cfg.pipeline == "v4":
-            # v4 on the mesh degrades to the v3 arrangement (the plan
-            # records why: the front's compact P is pmin-replicated and
-            # the dedup is an all_to_all — collectives cannot live in
-            # the megakernels), so front/tail stay None here.
-            from ..ops import pipeline_v4
-            self._v3_plan = pipeline_v4.resolve_plan(
-                B, G, K, Q=QL, sw=sw, mesh=True,
-                enqueue_method=cfg.enqueue_method,
-                force=cfg.v4_force_stages)
-            enqueue_method = self._v3_plan.enqueue_method
-        else:
-            self._v3_plan = None
-
         # The per-batch pipeline body is shared with the single-chip
         # engine (engine/chunk.py); here the insert routes fingerprints
         # to their owner chips, and P is pmin-replicated via the
@@ -345,7 +312,6 @@ class MeshBFSEngine:
             pack_ok=pack_ok, inv_fns=inv_fns, constraint=constraint,
             B=B, G=G, K=K, Q=QL, TQ=TQ, record_static=record_static,
             compactor=compactor, insert_fn=route_insert, v2=self._v2,
-            enqueue_method=enqueue_method,
             por_mask=por_mask, por_priority=por_priority)
 
         def agreed_stats(offset, steps, gen, newc, ovfc, fail_any,
@@ -512,13 +478,10 @@ class MeshBFSEngine:
         if cfg.perf:
             from ..obs import perf as perf_mod
             self._perf = perf_mod.build_accounting(
-                pipeline=(cfg.pipeline
-                          if cfg.pipeline in ("v3", "v4")
-                          else "v2" if self._v2 is not None
-                          else "v1"),
+                pipeline=self._pipeline_name,
                 chunk_fn=self._chunk,
                 chunk_avals=self.chunk_avals(),
-                plan=self._v3_plan, with_stages=False,
+                with_stages=False,
                 metrics=self.metrics, engine="mesh")
 
         def fp_rows(rows):
@@ -869,12 +832,7 @@ class MeshBFSEngine:
             self._trace_run_id = mh.build_min(self.mesh)(
                 int(time.time() * 1000) & 0x7FFFFFFF)
         res = EngineResult(
-            pipeline=(cfg.pipeline if self._v3_plan is not None
-                      else "v2" if self._v2 is not None else "v1"),
-            fused_stages=(dict(self._v3_plan.stages)
-                          if self._v3_plan is not None else {}),
-            fused_reasons=(dict(self._v3_plan.reasons)
-                           if self._v3_plan is not None else {}),
+            pipeline=self._pipeline_name,
             por_instances=(self._por_table.certified
                            if self._por_table is not None else 0),
             family_groups=_family_groups_meta(self.dims))

@@ -292,8 +292,7 @@ def _do_check(req, telemetry=None):
         max_diameter=req.get("max_diameter"),
         record_trace=record_trace,
         check_deadlock=req.get("check_deadlock"),
-        # Successor pipeline (auto/v1/v2/v3/v4 — v3 is the fused
-        # Pallas chunk, v4 the whole-chunk megakernel); same
+        # Successor pipeline (utils/cfg.py PIPELINES); same
         # request-over-directive precedence as every key.
         pipeline=(req["pipeline"] if req.get("pipeline") is not None
                   else base.pipeline),
@@ -316,7 +315,7 @@ def _do_check(req, telemetry=None):
         else:
             por_key = cfg.por_table.fingerprint
     # pipeline keys the cache: the chunk program differs per pipeline,
-    # so a v3 request must never be served a warm v2 engine (or vice
+    # so a v1 request must never be served a warm v2 engine (or vice
     # versa).
     key = (ident, req.get("engine", "single"), cfg.batch,
            cfg.queue_capacity, cfg.seen_capacity, record_trace,
@@ -378,12 +377,9 @@ def _do_check(req, telemetry=None):
            "levels": list(res.levels), "stop_reason": res.stop_reason,
            "wall_seconds": round(res.wall_seconds, 3),
            "batch": engine.config.batch,      # resolved, for observability
-           # Which successor pipeline actually ran, and (v3) the
-           # resolved per-stage lowering plan — a stage that fell back
-           # to XLA is visible to the client, never silent.
+           # Which successor pipeline actually ran (an ``auto``
+           # fallback to v1 is visible to the client).
            "pipeline": res.pipeline,
-           "fused_stages": dict(res.fused_stages),
-           "fused_reasons": dict(res.fused_reasons),
            "action_counts": dict(res.action_counts),
            # (capacity-after, off-clock stall seconds) per seen-set
            # doubling — the SEEN_CAPACITY sizing evidence.
@@ -643,6 +639,9 @@ def _do_submit(req: dict, manager) -> dict:
         _METRICS.counter("server/rejected/bad_mode")
         raise ValueError(f"unknown mode {inner.get('mode')!r} "
                          f"(expected 'exhaustive' or 'swarm')")
+    if inner.get("pipeline") is not None:
+        from .utils.cfg import check_pipeline
+        check_pipeline(inner["pipeline"])
     label = _cfg_label(inner)
     if req.get("cache") and inner.get("cfg"):
         # Pin the cfg CONTENT at submit time: the cache key is

@@ -34,8 +34,7 @@ SPILL_DIR, TRACE_DIR, PROGRESS_SECONDS, EVENTS_OUT, KEEP_CHECKPOINTS,
 TRACE_OUT (Chrome-trace span file), PROFILE_CHUNKS (per-stage chunk
 profiling cadence), POR (statically-certified partial-order reduction),
 POR_TABLE (pre-certified reduction-table artifact path), PIPELINE
-(successor pipeline: auto / v1 / v2 / v3 / v4 — v3 is the fused Pallas
-chunk, v4 the whole-chunk VMEM megakernel; engine/bfs.py
+(successor pipeline: auto / v1 / v2, ``PIPELINES`` below; engine/bfs.py
 EngineConfig.pipeline), XLA_PROFILE (device-profiler
 capture: trace the first N chunk calls through jax.profiler,
 obs/profile.py XlaProfileCapture), METRICS_PORT (serve /metrics
@@ -104,6 +103,20 @@ _BACKEND_KEYS = {
 }
 
 
+#: The successor pipelines an engine can be asked for (engine/bfs.py
+#: EngineConfig.pipeline): ``auto`` is v2 wherever the spec variant has v2
+#: kernels, v1 elsewhere.
+PIPELINES = ("auto", "v1", "v2")
+
+
+def check_pipeline(value) -> None:
+    """Reject a pipeline name no engine builds — from a flag, a
+    directive, a request or a caller's EngineConfig alike."""
+    if value not in PIPELINES:
+        raise ValueError(f"pipeline must be one of "
+                         f"{'/'.join(PIPELINES)}, got {value!r}")
+
+
 def parse_backend_directives(text: str) -> Dict[str, object]:
     """``\\* TPU: KEY = VALUE`` comment directives (see module docstring)."""
     out: Dict[str, object] = {}
@@ -124,6 +137,8 @@ def parse_backend_directives(text: str) -> Dict[str, object]:
             out[key] = raw.upper() == "TRUE"
         else:
             out[key] = raw
+    if "PIPELINE" in out:
+        check_pipeline(out["PIPELINE"])
     return out
 
 

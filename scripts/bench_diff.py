@@ -29,13 +29,9 @@ BENCH_r04/r05 files predate chunk_stages/coverage and still diff):
   sub-percent phases); threshold ``--phase-max-regress``.
 - per-stage chunk means (``chunk_stages``): direct per-stage ratio,
   threshold ``--stage-max-regress``; the fused ``total`` row is
-  compared too (it is the engine-shaped number).  Runs profiled at
-  DIFFERENT stage granularities (the v1/v2 decomposition vs the v3
-  fused-stage keys, obs/profile.py STAGES vs STAGES_V3) are folded
-  onto common coarse stages — front (expand | masks+compact),
-  fingerprint, tail (dedup_insert+enqueue | insert_enqueue), total —
-  with a note, instead of silently comparing an empty intersection
-  (or refusing the diff).
+  compared too (it is the engine-shaped number).  Where the two sides'
+  stage keys differ, the keys both have are compared and the others
+  are named in a note.
 - performance observatory (``perf`` block, obs/perf.py — also the
   ``scripts/xplane_summary.py`` dialect): ``launches_per_chunk`` rising
   past ``--launch-drift`` regresses (a stage un-fusing is visible
@@ -308,54 +304,15 @@ def diff_phases(old: dict, new: dict, d: Diff, max_regress: float,
                       f"{nc:.2f} s/M-distinct")
 
 
-# chunk_stages key -> coarse common stage, across every profiler
-# granularity (obs/profile.py STAGES, STAGES_V3, STAGES_V4).  "front"
-# is everything up to and including the fingerprint (v1's expand row
-# already folds compaction in; v3 splits masks/compact; v4's megakernel
-# row covers the whole trio — folding the fingerprint into "front"
-# everywhere keeps all three granularities comparable), "tail" is
-# everything after it.
-STAGE_FOLD = {
-    "expand": "front", "masks": "front", "compact": "front",
-    "fingerprint": "front", "front": "front",
-    "dedup_insert": "tail", "enqueue": "tail", "insert_enqueue": "tail",
-    "total": "total",
-}
-
-
-def fold_stages(stages: dict):
-    """Sum a chunk_stages dict onto the coarse common stages; unknown
-    keys are returned separately (reported, never silently dropped)."""
-    out, unknown = {}, []
-    for key, val in stages.items():
-        coarse = STAGE_FOLD.get(key)
-        if coarse is None:
-            unknown.append(key)
-        else:
-            out[coarse] = out.get(coarse, 0.0) + val
-    return out, unknown
-
-
 def diff_stages(old: dict, new: dict, d: Diff, max_regress: float):
     os_, ns = old.get("chunk_stages") or {}, new.get("chunk_stages") or {}
     if not os_ or not ns:
         return
-    if set(os_) != set(ns):
-        # Mismatched granularities (e.g. a v2 bench vs a v3 bench, whose
-        # profiler emits the fused-stage keys): fold both sides onto the
-        # common coarse stages and diff those — a cross-pipeline diff
-        # stays a diff, not a refusal.
-        os_, o_unk = fold_stages(os_)
-        ns, n_unk = fold_stages(ns)
-        d.note("chunk_stages granularities differ "
-               f"(old: {old.get('pipeline') or 'v1/v2'} keys, "
-               f"new: {new.get('pipeline') or 'v1/v2'} keys); folded to "
-               "common stages front(expand|masks+compact) / fingerprint "
-               "/ tail(insert+enqueue)")
-        for side, unk in (("old", o_unk), ("new", n_unk)):
-            if unk:
-                d.note(f"  unrecognized {side} stage keys not folded: "
-                       f"{', '.join(sorted(unk))}")
+    for side, only in (("old", set(os_) - set(ns)),
+                       ("new", set(ns) - set(os_))):
+        if only:
+            d.note(f"chunk stages on the {side} side only, not compared: "
+                   f"{', '.join(sorted(only))}")
     for stage in sorted(set(os_) & set(ns)):
         oc, nc = os_[stage], ns[stage]
         pct = (nc - oc) / oc * 100.0 if oc else 0.0
@@ -605,10 +562,9 @@ def main(argv=None) -> int:
     if om != nm:
         # Cross-dialect diff: an exhaustive distinct/s headline and a
         # swarm steps/s headline measure different things — folding
-        # them into one regression ratio would gate noise.  The
-        # STAGE_FOLD rule applies: the diff stays a diff (both
-        # headlines reported, host guard above still live), nothing is
-        # gated.
+        # them into one regression ratio would gate noise.  The diff
+        # stays a diff (both headlines reported, host guard above still
+        # live), nothing is gated.
         d.note(f"bench modes differ (baseline: {om}, candidate: {nm}) "
                f"— dialect rates are not comparable; reported, not "
                f"gated")

@@ -10,17 +10,9 @@ inside a live engine run — so this script's numbers and an engine
 run's ``chunk_profile`` event are the same instrument.  On top of
 that, this script times what the in-engine profiler can't:
 
-  compact[searchsorted]  the alternate compaction lowering
-  fpset_pallas.insert    Mosaic sequential-probe insert (TPU only)
-  enqueue pallas         run-coalesced DMA append (TPU only)
   CHUNK                  the engine's real fused chunk program
   CHUNK x8               ditto, 8 batches per call (sync_every)
-  CHUNK v2 / v2+ss+win   the delta pipeline + full candidate config
-  v3 staged + CHUNK v3   the fused Pallas pipeline (ops/pipeline_v3.py):
-                         per-stage masks/compact/fingerprint/
-                         insert_enqueue timings and the whole v3 chunk —
-                         the measurement row the fused-chunk decision
-                         needs
+  CHUNK v2 / v2 x8       the delta pipeline (what ``auto`` runs)
 
 Run:  python scripts/profile_step.py [batch]
 
@@ -40,8 +32,6 @@ import numpy as np
 
 from raft_tla_tpu.engine.bfs import EngineConfig
 from raft_tla_tpu.engine.check import initial_states, make_engine
-from raft_tla_tpu.models.actions import build_expand
-from raft_tla_tpu.models.schema import flatten_state, unflatten_state
 from raft_tla_tpu.ops import fpset
 from raft_tla_tpu.utils.cfg import load_config
 
@@ -94,8 +84,7 @@ def main():
     # The staged decomposition — the SAME programs --profile-chunks runs
     # inside a live engine (obs/profile.py), so a number printed here
     # and a chunk_profile event disagree only if the hardware does.
-    from raft_tla_tpu.obs.profile import (STAGES, build_stage_programs,
-                                          profile_stages)
+    from raft_tla_tpu.obs.profile import STAGES, profile_stages
     rows = qcur[:B]
     means = profile_stages(dims, np.asarray(rows), lanes=K,
                            seen_capacity=cfg.seen_capacity, n=10)
@@ -106,52 +95,8 @@ def main():
     print(f"{'staged total (one jit, non-donating)':42s} "
           f"{means['total'] * 1e3:9.2f} ms")
 
-    # Beyond the shared stages: the alternate compaction lowering...
-    expand = build_expand(dims)
-    from raft_tla_tpu.ops.compact import build_compactor
-    compactor_ss = build_compactor(B, G, K, method="searchsorted")
-
-    @jax.jit
-    def part_compact_ss(rows):
-        states = jax.vmap(unflatten_state, (0, None))(rows, dims)
-        cands, en, ovf = jax.vmap(expand)(states)
-        cflat = jax.tree.map(
-            lambda a: a.reshape((BG,) + a.shape[2:]), cands)
-        _P, _total, lane_id, kvalid = compactor_ss(en)
-        return (cflat, lane_id, kvalid)
-
-    bench("expand + compact[searchsorted]", part_compact_ss, rows)
-
-    # ...and the Pallas lowerings, fed from the shared stage programs'
-    # own intermediates (no re-derived pipeline).
-    progs = build_stage_programs(dims, B, K)
-    valid = jnp.ones((B,), bool)
-    cflat, lane_id, kvalid = progs["expand"](rows, valid)
-    kstates, kh, kl = progs["fingerprint"](cflat, lane_id)
     seen = fpset.empty(cfg.seen_capacity)
-    # Pallas sequential-grid insert (ops/fpset_pallas.py): same contract,
-    # no sort/claims; prices Mosaic scalar-DMA probing — the datum for
-    # NORTHSTAR.md §d's fused-chunk decision.  Tolerant of a Mosaic
-    # lowering failure (unmeasured until a window runs it on real TPU).
-    try:
-        from raft_tla_tpu.ops import fpset_pallas
-        seen_p = fpset.empty(cfg.seen_capacity)
-        bench("fpset_pallas.insert (sequential kernel)",
-              fpset_pallas.insert, seen_p, kh, kl, kvalid)
-    except Exception as e:  # noqa: BLE001 — report, keep profiling
-        print(f"fpset_pallas.insert                        FAILED: {e!r}")
-    krows = jax.vmap(flatten_state, (0, None))(kstates, dims)
     qnext = jnp.zeros((QA, SW), jnp.uint8)
-    # Pallas run-coalesced enqueue (ops/enqueue_pallas.py): the
-    # contiguous-append formulation of the 14.5 ms scatter stage —
-    # the other half of NORTHSTAR §d's fused-chunk pricing.
-    try:
-        from raft_tla_tpu.ops import enqueue_pallas
-        qnext2 = jnp.zeros((QA, SW), jnp.uint8)
-        bench("enqueue pallas (run-coalesced DMA)", enqueue_pallas.enqueue,
-              qnext2, jnp.int32(0), krows, kvalid)
-    except Exception as e:  # noqa: BLE001 — report, keep profiling
-        print(f"enqueue_pallas                             FAILED: {e!r}")
 
     # The engine's own fused chunk program (qnext/seen/tbuf are donated:
     # thread the outputs back through).
@@ -225,88 +170,6 @@ def main():
     jax.block_until_ready(out2)
     print(f"{'CHUNK v2 x8 (8 batches per call)':42s} "
           f"{(time.time() - t0) / n / 8 * 1e3:9.2f} ms/batch")
-
-    # Full candidate config: v2 + searchsorted compaction + window
-    # enqueue — the three profile-justified lowerings together.
-    eng3 = make_engine(setup, EngineConfig(
-        batch=B, queue_capacity=1 << 20, seen_capacity=1 << 23,
-        record_trace=False, check_deadlock=False, pipeline="v2",
-        compact_method="searchsorted", enqueue_method="window"))
-    qnext3 = jnp.zeros((QA, SW), jnp.uint8)
-    seen3 = fpset.empty(cfg.seen_capacity)
-    tbuf3 = tuple(jnp.zeros((eng3._TA,), d) for d in
-                  (jnp.uint32, jnp.uint32, jnp.uint32, jnp.uint32,
-                   jnp.int32))
-
-    def chunk8_v3(qnext, seen, tbuf, nb):
-        return eng3._chunk(qcur, jnp.int32(nb * B), jnp.int32(0), qnext,
-                           jnp.int32(0), seen, tbuf, jnp.int32(0),
-                           jnp.int32(nb))
-
-    out3 = chunk8_v3(qnext3, seen3, tbuf3, 1)
-    jax.block_until_ready(out3)
-    out3 = chunk8_v3(out3[0], out3[1], out3[2], 8)
-    jax.block_until_ready(out3)
-    t0 = time.time()
-    for _ in range(n):
-        out3 = chunk8_v3(out3[0], out3[1], out3[2], 8)
-    jax.block_until_ready(out3)
-    print(f"{'CHUNK v2+ss+win x8 (full candidate)':42s} "
-          f"{(time.time() - t0) / n / 8 * 1e3:9.2f} ms/batch")
-
-    # The v3 fused Pallas pipeline (NORTHSTAR §d decision row): the
-    # fused-stage decomposition, then the engine's whole v3 chunk.  On
-    # TPU this prices the real Mosaic kernels (Pallas compact + fused
-    # probe/insert->enqueue tail); off-TPU it runs interpret mode — a
-    # correctness instrument, not a perf number.  Tolerant of a Mosaic
-    # lowering failure: the plan's per-stage fallback is part of what
-    # this row measures, so a fallen-back stage prints as such instead
-    # of aborting the session.
-    try:
-        from raft_tla_tpu.obs.profile import STAGES_V3
-        means3 = profile_stages(dims, np.asarray(rows), lanes=K,
-                                seen_capacity=cfg.seen_capacity, n=10,
-                                pipeline="v3")
-        for s in STAGES_V3:
-            print(f"{'v3 ' + s + ' (staged, fenced)':42s} "
-                  f"{means3[s] * 1e3:9.2f} ms")
-        print(f"{'v3 staged total (one jit)':42s} "
-              f"{means3['total'] * 1e3:9.2f} ms")
-        engv3 = make_engine(setup, EngineConfig(
-            batch=B, queue_capacity=1 << 20, seen_capacity=1 << 23,
-            record_trace=False, check_deadlock=False, pipeline="v3"))
-        from raft_tla_tpu.ops.pipeline_v3 import describe
-        print(f"{'v3 plan':42s} {describe(engv3._v3_plan)}")
-        qnextf = jnp.zeros((QA, SW), jnp.uint8)
-        seenf = fpset.empty(cfg.seen_capacity)
-        tbuff = tuple(jnp.zeros((engv3._TA,), d) for d in
-                      (jnp.uint32, jnp.uint32, jnp.uint32, jnp.uint32,
-                       jnp.int32))
-
-        def chunk_f(qnext, seen, tbuf, nb):
-            return engv3._chunk(qcur, jnp.int32(nb * B), jnp.int32(0),
-                                qnext, jnp.int32(0), seen, tbuf,
-                                jnp.int32(0), jnp.int32(nb))
-
-        outf = chunk_f(qnextf, seenf, tbuff, 1)
-        jax.block_until_ready(outf)
-        t0 = time.time()
-        for _ in range(n):
-            outf = chunk_f(outf[0], outf[1], outf[2], 1)
-        jax.block_until_ready(outf)
-        print(f"{'CHUNK v3 (1 batch, fused pipeline)':42s} "
-              f"{(time.time() - t0) / n * 1e3:9.2f} ms")
-        outf = chunk_f(outf[0], outf[1], outf[2], 8)
-        jax.block_until_ready(outf)
-        t0 = time.time()
-        for _ in range(n):
-            outf = chunk_f(outf[0], outf[1], outf[2], 8)
-        jax.block_until_ready(outf)
-        print(f"{'CHUNK v3 x8 (8 batches per call)':42s} "
-              f"{(time.time() - t0) / n / 8 * 1e3:9.2f} ms/batch")
-    except Exception as e:  # noqa: BLE001 — report, keep the session
-        print(f"v3 pipeline                                FAILED: {e!r}")
-
 
 if __name__ == "__main__":
     main()

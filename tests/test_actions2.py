@@ -92,12 +92,20 @@ def _assert_state_matches(rig_, s, ctx=""):
                 f"succ field {name} {ctx} {dims.describe_instance(gi)}"
 
 
-def test_v2_matches_v1_on_reachable_states(rig):
+@pytest.mark.parametrize("cfg, levels", [
+    ("MCraft_bounded.cfg", 5), ("MCraft_noleader.cfg", 5),
+    ("TPUraft.cfg", 3)], ids=["mcraft3", "noleader", "raft5"])
+def test_v2_matches_v1_on_reachable_states(cfg, levels, rig):
+    """v1 is v2's reference at every benchmark configuration's own dims
+    and bounds: 473-, 403- and 951-byte rows (the last with the cfg's 48
+    message slots and 224 instances)."""
+    if cfg != "MCraft_bounded.cfg":
+        rig = _build_rig(load_config("configs/" + cfg))
     setup, dims = rig[0], rig[1]
     res = orc.bfs([init_state(dims)], dims,
                   constraint=constraint_py(setup.bounds),
-                  check_deadlock=False, max_levels=5)
-    states = list(res.parent)[:120]
+                  check_deadlock=False, max_levels=levels)
+    states = list(res.parent)[-120:]
     assert len(states) >= 100
     for i, s in enumerate(states):
         _assert_state_matches(rig, s, ctx=f"reachable[{i}]")
@@ -303,26 +311,6 @@ def test_auto_pipeline_propagates_accidental_errors():
                                 n_msg_slots=8))
 
 
-def test_compactor_methods_identical():
-    """ops/compact.py: the searchsorted lowering must produce the exact
-    (P, total, lane_id, kvalid) of the scatter lowering — including the
-    spread addresses in dead slots."""
-    from raft_tla_tpu.ops.compact import build_compactor
-    rng = np.random.RandomState(7)
-    for B, G, K, p in ((8, 12, 16, 0.1), (16, 33, 64, 0.5),
-                       (4, 5, 8, 0.0), (8, 7, 8, 1.0)):
-        c1 = build_compactor(B, G, K, method="scatter")
-        c2 = build_compactor(B, G, K, method="searchsorted")
-        for _ in range(5):
-            en = jnp.asarray(rng.rand(B, G) < p)
-            r1 = c1(en)
-            r2 = c2(en)
-            for a, b, nm in zip(r1, r2, ("P", "total", "lane_id",
-                                         "kvalid")):
-                assert (np.asarray(a) == np.asarray(b)).all(), \
-                    f"{nm} differs at B={B} G={G} K={K} p={p}"
-
-
 def test_simulator_pipelines_agree_seeded():
     """engine/simulate.py: v1 and v2 walker fleets draw identical actions
     (masks are bit-identical), so a seeded run's step/trace/violation
@@ -342,80 +330,20 @@ def test_simulator_pipelines_agree_seeded():
         == (r2.steps, r2.traces, r2.violation_invariant)
 
 
-def test_enqueue_methods_identical_results():
-    """engine/chunk.py 'window' enqueue vs 'scatter': identical distinct
-    counts and level profile, AND identical replayed counterexample
-    paths — the windowed trace buffer must record the same (parent,
-    action) links, not just the same counts."""
-    from raft_tla_tpu.engine.bfs import BFSEngine, EngineConfig
-    from raft_tla_tpu.models.invariants import build_constraint
-    setup = load_config("configs/MCraft_bounded.cfg")
-    dims = setup.dims
-    # Fingerprint of a concrete depth-5 reachable state to replay in both
-    # engines: the recorded trace content, not only counts, must agree.
-    res5 = orc.bfs([init_state(dims)], dims,
-                   constraint=constraint_py(setup.bounds),
-                   check_deadlock=False, max_levels=5)
-    target = sorted(res5.parent, key=lambda s: (len(s.messages),
-                                                s.current_term))[-1]
-    fp1 = build_fingerprint(dims)
-    h, l = jax.jit(fp1)(jax.tree.map(jnp.asarray,
-                                     encode_state(target, dims)))
-    target_fp = (int(h) << 32) | int(l)
-    results, paths = {}, {}
-    for meth in ("scatter", "window", "pallas"):
-        eng = BFSEngine(
-            dims, constraint=build_constraint(dims, setup.bounds),
-            config=EngineConfig(batch=128, queue_capacity=1 << 14,
-                                seen_capacity=1 << 16, record_trace=True,
-                                check_deadlock=False, max_diameter=6,
-                                enqueue_method=meth,
-                                compact_method="searchsorted"))
-        res = eng.run([init_state(dims)])
-        results[meth] = (res.distinct, res.generated, res.levels,
-                         res.diameter)
-        assert res.distinct == 9457    # pinned oracle L6 cumulative
-        trace = eng.replay(target_fp)
-        assert trace and trace[-1][1] == target
-        paths[meth] = [g for g, _s in trace]
-    assert results["scatter"] == results["window"] == results["pallas"]
-    assert paths["scatter"] == paths["window"] == paths["pallas"]
-    assert len(paths["scatter"]) >= 5
-
-
-def test_insert_methods_identical_results():
-    """engine/bfs.py insert_method='pallas' (ops/fpset_pallas.py,
-    interpret mode on CPU) vs 'xla': identical distinct/generated/level
-    profile and identical replayed counterexample path — the whole
-    engine is bit-identical because the insert contract (is_new flags)
-    is."""
-    from raft_tla_tpu.engine.bfs import BFSEngine, EngineConfig
-    from raft_tla_tpu.models.invariants import build_constraint
-    setup = load_config("configs/MCraft_bounded.cfg")
-    dims = setup.dims
-    res4 = orc.bfs([init_state(dims)], dims,
-                   constraint=constraint_py(setup.bounds),
-                   check_deadlock=False, max_levels=4)
-    target = sorted(res4.parent, key=lambda s: (len(s.messages),
-                                                s.current_term))[-1]
-    fp1 = build_fingerprint(dims)
-    h, l = jax.jit(fp1)(jax.tree.map(jnp.asarray,
-                                     encode_state(target, dims)))
-    target_fp = (int(h) << 32) | int(l)
-    results, paths = {}, {}
-    for meth in ("xla", "pallas"):
-        eng = BFSEngine(
-            dims, constraint=build_constraint(dims, setup.bounds),
-            config=EngineConfig(batch=64, queue_capacity=1 << 13,
-                                seen_capacity=1 << 14, record_trace=True,
-                                check_deadlock=False, max_diameter=5,
-                                insert_method=meth))
-        res = eng.run([init_state(dims)])
-        results[meth] = (res.distinct, res.generated, res.levels,
-                         res.diameter)
-        assert res.distinct == 2300    # pinned oracle L5 cumulative
-        trace = eng.replay(target_fp)
-        assert trace and trace[-1][1] == target
-        paths[meth] = [g for g, _s in trace]
-    assert results["xla"] == results["pallas"]
-    assert paths["xla"] == paths["pallas"] and len(paths["xla"]) >= 4
+@pytest.mark.parametrize("cfg", [
+    "MCraft_bounded.cfg", "MCraft_noleader.cfg", "MCraft_safety.cfg",
+    "TPUraft.cfg", "raft5_bounded.cfg"])
+def test_auto_runs_v2_on_every_base_alphabet_cfg(cfg):
+    """``EngineResult.pipeline`` — what the benchmark's ``correct`` reads —
+    is ``v2`` under ``auto`` for every cfg in ``configs/`` with the base
+    action alphabet, whatever its widths and invariant set."""
+    from raft_tla_tpu.engine.bfs import EngineConfig
+    from raft_tla_tpu.engine.check import initial_states, make_engine
+    setup = load_config("configs/" + cfg)
+    assert not setup.dims.extra_families
+    eng = make_engine(setup, EngineConfig(
+        batch=32, queue_capacity=1 << 12, seen_capacity=1 << 14,
+        record_trace=False, max_diameter=2))
+    assert eng.config.pipeline == "auto"
+    res = eng.run(initial_states(setup))
+    assert res.pipeline == "v2" and res.diameter == 2

@@ -254,3 +254,78 @@ def test_progress_seconds_backend_directive(tmp_path):
                     "CONSTANT Server = {r1}\nCONSTANT Value = {v1}\n")
     s = load_config(str(cfgf))
     assert s.backend["PROGRESS_SECONDS"] == 300
+
+
+# -- the pipelines that were deleted (PR 31): rejected by name -------------
+
+VALID = r"auto/v1/v2"
+
+
+@pytest.mark.parametrize("dead", ["v3", "v4"])
+def test_directive_for_a_deleted_pipeline_is_rejected(dead, tmp_path):
+    """``\\* TPU: PIPELINE = v4`` fails at load with the three valid
+    values named; it is not mapped to v2."""
+    cfgf = tmp_path / "dead.cfg"
+    cfgf.write_text(f"\\* TPU: PIPELINE = {dead}\n"
+                    "CONSTANT Server = {r1}\nCONSTANT Value = {v1}\n")
+    with pytest.raises(ValueError, match=f"{VALID}.*{dead}"):
+        load_config(str(cfgf))
+    cfgf.write_text("\\* TPU: PIPELINE = v2\n"
+                    "CONSTANT Server = {r1}\nCONSTANT Value = {v1}\n")
+    assert load_config(str(cfgf)).backend == {"PIPELINE": "v2"}
+
+
+@pytest.mark.parametrize("dead", ["v3", "v4"])
+def test_cli_flag_for_a_deleted_pipeline_is_rejected(dead, capsys):
+    from raft_tla_tpu.cli import main
+    with pytest.raises(SystemExit) as exc:
+        main(["check", os.path.join(REPO, "configs/MCraft_bounded.cfg"),
+              "--pipeline", dead])
+    assert exc.value.code == 2
+    err = capsys.readouterr().err
+    assert f"invalid choice: '{dead}'" in err
+    assert "auto, v1, v2" in err
+
+
+# -- README.md names only what exists --------------------------------------
+
+def _readme():
+    with open(os.path.join(REPO, "README.md"), encoding="utf-8") as f:
+        return f.read()
+
+
+def test_readme_names_only_flags_and_directives_that_exist():
+    """Every ``--flag`` the README names is an option of some entry point
+    (the CLI, the server, bench.py, the smoke, the benchmark, a script;
+    XLA's own ``--xla_*`` flags aside), and every ``\\* TPU:`` directive is
+    in ``utils/cfg.py``'s set.  The README has described removed options
+    before."""
+    import glob
+    import re
+    from raft_tla_tpu.utils.cfg import _BACKEND_KEYS
+    text = _readme()
+    sources = [os.path.join(REPO, p) for p in (
+        "raft_tla_tpu/cli.py", "raft_tla_tpu/server.py", "bench.py",
+        "chip_smoke.py", "benchmark/run.py")]
+    sources += glob.glob(os.path.join(REPO, "scripts", "*.py"))
+    known = set()
+    for path in sources:
+        with open(path, encoding="utf-8") as f:
+            known |= set(re.findall(r'"(--[a-z][a-z0-9-]+)"', f.read()))
+    named = set(re.findall(r"(?<![\w-])(--[a-z][a-z0-9_-]+)", text))
+    named = {flag for flag in named if not flag.startswith("--xla_")}
+    assert len(named) > 40 and not named - known, sorted(named - known)
+    directives = set(re.findall(r"TPU:\s*([A-Z_]+)", text))
+    assert len(directives) > 5 and not directives - _BACKEND_KEYS, \
+        sorted(directives - _BACKEND_KEYS)
+
+
+def test_readme_names_only_engine_config_fields_that_exist():
+    import dataclasses
+    import re
+    from raft_tla_tpu.engine.bfs import EngineConfig, EngineResult
+    text = _readme()
+    for cls in (EngineConfig, EngineResult):
+        fields = {f.name for f in dataclasses.fields(cls)}
+        named = set(re.findall(cls.__name__ + r"\s*[.(]\s*(\w+)", text))
+        assert named and not named - fields, sorted(named - fields)

@@ -3,23 +3,18 @@
 The TPU's compiler is installed here and compiles for a described
 ``v5e:2x2`` topology.  These tests keep the cheap ones at REAL widths —
 ``configs/MCraft_bounded.cfg``: 473-byte packed rows, 132 action
-instances; the whole chunk also at ``configs/TPUraft.cfg``: 951-byte
-rows, 224 instances, 48 slots — so every later PR is guarded at no chip
-time:
-
-- the XLA main path: ``ops/fpset.py insert`` over the bench's 2^25-key
-  table, the whole v2 chunk program of ``BFSEngine`` at a small batch
-  (and that it holds no gather expanded into a per-lane loop),
-  the trace flush's fetch programs over the bench's trace buffers,
-  the swarm walk chunk at 1,024 walks, the mesh chunk over the four
-  described chips (with the owner-routed dedup's ``all-to-all``);
-- one case per Pallas kernel.  None of them compiles today (PR 24 moved
-  their scalars to SMEM; what remains is each kernel's design against the
-  chip's tiling).  The contract is that the compiler's refusal PROPAGATES:
-  asked for on the TPU, a refused kernel is an error carrying the
-  compiler's words, never a substituted XLA stage.  When a kernel is
-  repaired its case here fails with DID NOT RAISE: turn it into a plain
-  ``compile_for`` call then.
+instances; the whole chunk also at ``configs/TPUraft.cfg`` (951-byte
+rows, 224 instances, 48 slots), ``configs/MCraft_noleader.cfg`` (403-byte
+rows: the verdict cell), ``configs/MCraft_safety.cfg`` (ten invariants on
+the K lanes) and ``configs/reconfig3.cfg`` (the extra action families) —
+so every later PR is guarded at no chip time: ``ops/fpset.py insert``
+over the bench's 2^25-key table, the whole chunk program of ``BFSEngine``
+at a small batch (and that it holds no gather expanded into a per-lane
+loop), its ``ingest`` program, the two programs a seen-set growth
+dispatches at the verdict cell's sizes, the trace flush's fetch programs
+over the bench's trace buffers, the swarm walk chunk at 1,024 walks, the
+mesh chunk and ingest over the four described chips (with the
+owner-routed dedup's ``all-to-all``).
 
 A compile that passes is not a chip run; ``chip_smoke.py`` is.
 
@@ -103,35 +98,82 @@ def test_fpset_insert_over_the_real_table(one_chip):
     assert c.memory_analysis().argument_size_in_bytes >= 8 * SEEN
 
 
-@pytest.mark.parametrize("cfg, width, instances", [
-    ("configs/MCraft_bounded.cfg", 473, 132),
-    ("configs/TPUraft.cfg", 951, 224),
-], ids=["mcraft3", "raft5"])
-def test_v2_chunk_program_small_batch(cfg, width, instances, one_chip):
-    """The whole BFSEngine chunk program (pipeline=auto -> v2, trace
-    recording on) at both benchmark models' real widths; batch 64
-    compiles in seconds, the bench's 2048 in a minute or two (hand
-    rehearsal, CHANGES.md).
-
-    No ``while`` of the optimised program may be a gather XLA expanded
-    into a loop over the lanes: the chunk's own loop and ``insert``'s
-    probe loop are the only two.  ``actions2.dvec`` as a traced-start
-    ``dynamic_slice`` was four of them at 5 servers (PR 28), each K trips
-    a pass."""
+def small_engine(cfg, width, instances, **kw):
+    """``cfg``'s engine as ``auto`` resolves it, at a batch that compiles
+    in seconds (the bench's 2048 takes a minute or two)."""
     from raft_tla_tpu.engine.bfs import EngineConfig
     from raft_tla_tpu.engine.check import make_engine
     from raft_tla_tpu.models.schema import state_width
     from raft_tla_tpu.utils.cfg import load_config
     s = load_config(os.path.join(REPO, cfg))
     assert (state_width(s.dims), s.dims.n_instances) == (width, instances)
-    eng = make_engine(s, EngineConfig(
-        batch=64, queue_capacity=1 << 14, seen_capacity=1 << 17))
+    size = dict(batch=64, queue_capacity=1 << 14, seen_capacity=1 << 17)
+    eng = make_engine(s, EngineConfig(**{**size, **kw}))
     assert eng._v2 is not None
+    return eng
+
+
+@pytest.mark.parametrize("cfg, width, instances, invariants", [
+    ("configs/MCraft_bounded.cfg", 473, 132, 1),
+    ("configs/TPUraft.cfg", 951, 224, 1),
+    ("configs/MCraft_noleader.cfg", 403, 132, 1),
+    ("configs/MCraft_safety.cfg", 473, 132, 10),
+    ("configs/reconfig3.cfg", 474, 114, 1),
+], ids=["mcraft3", "raft5", "noleader", "safety", "reconfig3"])
+def test_v2_chunk_program_small_batch(cfg, width, instances, invariants,
+                                      one_chip):
+    """The whole BFSEngine chunk program (pipeline=auto -> v2, trace
+    recording on) at every benchmark model's real widths, with the
+    whole invariant suite on the K lanes, and with the reconfiguration
+    variant's extra families.
+
+    No ``while`` of the optimised program may be a gather XLA expanded
+    into a loop over the lanes: the chunk's own loop and ``insert``'s
+    probe loop are the only two.  ``actions2.dvec`` as a traced-start
+    ``dynamic_slice`` was four of them at 5 servers (PR 28), each K trips
+    a pass."""
+    eng = small_engine(cfg, width, instances)
+    assert len(eng.inv_names) == invariants
     c = compile_for(eng._chunk, lambda a: one_chip, *eng.chunk_avals())
     assert c.memory_analysis().generated_code_size_in_bytes > 0
     loops = re.findall(r' while\(.*op_name="([^"]*)"', c.as_text())
     assert len(loops) >= 2, loops       # else the pattern found nothing
     assert not [name for name in loops if name.endswith("/gather")], loops
+
+
+@pytest.mark.parametrize("cfg, width, instances", [
+    ("configs/MCraft_bounded.cfg", 473, 132),
+    ("configs/TPUraft.cfg", 951, 224),
+], ids=["mcraft3", "raft5"])
+def test_ingest_program_small_batch(cfg, width, instances, one_chip):
+    """``BFSEngine``'s ingest program (roots and spilled rows into the
+    next queue): ``correct`` forbids its compile inside a window, and a
+    window never runs it, so only this compiles it for the chip."""
+    eng = small_engine(cfg, width, instances)
+    _q, i32, _, qnext, _, seen, _tbuf, _, _ = eng.chunk_avals()
+    c = compile_for(eng._ingest, lambda a: one_chip,
+                    S((eng._B, width), jnp.uint8), S((eng._B,), jnp.bool_),
+                    qnext, i32, seen)
+    assert c.memory_analysis().generated_code_size_in_bytes > 0
+    assert "/insert/" in c.as_text()    # the chunk's stage names
+
+
+@pytest.mark.parametrize("grown", [1 << 17, 1 << 18])
+def test_seen_set_growth_programs_at_the_verdict_cells_sizes(grown,
+                                                             one_chip):
+    """What ``_grow_precompiled`` dispatches, as the verdict cell runs it
+    (``MCraft_noleader.cfg``: batch 256, 16,384-row queues, a 65,536-key
+    table that doubles twice a check): the rehash's ``insert_unique`` of
+    2^15 keys into the grown table, then the chunk at that table."""
+    from raft_tla_tpu.ops import fpset
+    table = jax.eval_shape(lambda: fpset.empty(grown))
+    k = 1 << 15                         # fpset.from_host_keys' piece
+    compile_for(fpset.insert_unique, lambda a: one_chip, table,
+                S((k,), jnp.uint32), S((k,), jnp.uint32), S((k,), jnp.bool_))
+    eng = small_engine("configs/MCraft_noleader.cfg", 403, 132, batch=256,
+                       seen_capacity=grown)
+    assert (eng._B, eng._Q, eng._seen_cap) == (256, 1 << 14, grown)
+    compile_for(eng._chunk, lambda a: one_chip, *eng.chunk_avals())
 
 
 def test_trace_flush_fetch_programs_at_the_bench_queue(setup, one_chip):
@@ -165,22 +207,46 @@ def test_swarm_walk_chunk_1024_walks(one_chip):
     compile_for(eng._chunk, lambda a: one_chip, *eng.chunk_avals(1))
 
 
-def test_mesh_chunk_over_four_described_chips(setup, topo):
-    """MeshBFSEngine's chunk for a Mesh of the topology's four devices:
-    it partitions, and the owner-routed dedup is an all-to-all."""
+def small_mesh_engine(cfg, topo, **kw):
     from raft_tla_tpu.engine.bfs import EngineConfig
     from raft_tla_tpu.engine.check import make_engine
     from raft_tla_tpu.parallel.mesh import MeshBFSEngine
+    from raft_tla_tpu.utils.cfg import load_config
     eng = make_engine(
-        setup, EngineConfig(batch=64, queue_capacity=1 << 14,
-                            seen_capacity=1 << 17, record_trace=False),
+        load_config(os.path.join(REPO, cfg)),
+        EngineConfig(batch=64, queue_capacity=1 << 14,
+                     seen_capacity=1 << 17, **kw),
         engine_cls=functools.partial(MeshBFSEngine,
                                      devices=list(topo.devices)))
     assert eng.n_dev == 4
-    c = compile_for(
-        eng._chunk,
-        lambda a: NamedSharding(eng.mesh, P("x") if a.ndim else P()),
-        *eng.chunk_avals())
+    return eng, (lambda a: NamedSharding(eng.mesh,
+                                         P("x") if a.ndim else P()))
+
+
+@pytest.mark.parametrize("cfg, width", [
+    ("configs/MCraft_bounded.cfg", 473), ("configs/TPUraft.cfg", 951),
+], ids=["mcraft3", "raft5"])
+def test_mesh_chunk_over_four_described_chips(cfg, width, topo):
+    """MeshBFSEngine's chunk for a Mesh of the topology's four devices,
+    at both models' widths: it partitions under ``shard_map``, and the
+    owner-routed dedup is an all-to-all."""
+    eng, over_mesh = small_mesh_engine(cfg, topo, record_trace=False)
+    assert eng._sw == width
+    c = compile_for(eng._chunk, over_mesh, *eng.chunk_avals())
+    assert "all-to-all" in c.as_text()
+
+
+def test_mesh_ingest_over_four_described_chips(topo):
+    """``sharded_ingest`` (the roots, and a resume's spilled rows) at
+    473-byte rows with trace recording on: its insert is owner-routed
+    too."""
+    eng, over_mesh = small_mesh_engine("configs/MCraft_bounded.cfg", topo)
+    (qav, counts, _, _, _, shi, slo, ssize, tbuf, tcount,
+     _) = eng.chunk_avals()
+    c = compile_for(eng._ingest, over_mesh,
+                    S((4, eng._B, 473), jnp.uint8),
+                    S((4, eng._B), jnp.bool_),
+                    qav, counts, shi, slo, ssize, tbuf, tcount)
     assert "all-to-all" in c.as_text()
 
 
@@ -212,108 +278,3 @@ def test_mesh_chunk_at_the_four_chip_deployments_sizes(setup, topo):
             + spare_queue) < 16e9, m
     assert len(re.findall(r"= \S+ all-to-all(?:-start)?\(",
                           c.as_text())) == 3
-
-
-# -- the Pallas kernels -----------------------------------------------------
-
-def _compact(setup):
-    from raft_tla_tpu.ops import compact_pallas
-    g = setup.dims.n_instances
-    return (lambda en, ks: compact_pallas._compact_jit(en, ks, K, False),
-            (S((B, g), jnp.bool_), S((K,), jnp.int32)))
-
-
-def _enqueue(setup):
-    from raft_tla_tpu.ops import enqueue_pallas
-    return (lambda q, nc, kr, enq: enqueue_pallas._enqueue_jit(
-                q, nc, kr, enq, False),
-            (S((QUEUE + K, 473), jnp.uint8), S((), jnp.int32),
-             S((K, 473), jnp.uint8), S((K,), jnp.bool_)))
-
-
-def _table():
-    from raft_tla_tpu.ops import fpset
-    return jax.eval_shape(lambda: fpset.empty(SEEN))
-
-
-def _insert(setup):
-    from raft_tla_tpu.ops import fpset_pallas
-    return (lambda s, h, l, v: fpset_pallas._insert_padded(
-                s, h, l, v, False),
-            (_table(), S((K,), jnp.uint32), S((K,), jnp.uint32),
-             S((K,), jnp.bool_)))
-
-
-def _fused_tail(setup):
-    from raft_tla_tpu.ops import fused_tail_pallas
-    return (lambda s, h, l, v, e, kr, q, nc:
-            fused_tail_pallas._tail_padded(s, h, l, v, e, kr, q, nc,
-                                           QUEUE, False),
-            (_table(), S((K,), jnp.uint32), S((K,), jnp.uint32),
-             S((K,), jnp.bool_), S((K,), jnp.bool_),
-             S((K, 473), jnp.uint8), S((QUEUE + K, 473), jnp.uint8),
-             S((), jnp.int32)))
-
-
-def _v4_front(setup):
-    from raft_tla_tpu.engine.check import (resolve_constraint,
-                                           resolve_invariants)
-    from raft_tla_tpu.models.actions2 import build_v2
-    from raft_tla_tpu.ops import chunk_front_pallas
-    front = chunk_front_pallas.build_front(
-        dims=setup.dims, v2=build_v2(setup.dims),
-        constraint=resolve_constraint(setup),
-        inv_fns=list(resolve_invariants(setup).values()),
-        B=B, G=setup.dims.n_instances, K=K, por_mask=None,
-        por_priority=None, interpret=False)
-    return front, (S((B, 473), jnp.uint8), S((B,), jnp.bool_))
-
-
-#: kernel -> (builder, the compiler's refusal at the bench's shapes).
-#: CHANGES.md (PR 24) quotes each in full.
-REFUSED = {
-    "compact": (_compact, "Unimplemented primitive in Pallas TPU "
-                          "lowering for KernelType.TC: cumsum"),
-    "enqueue": (_enqueue, "Failed to prove that a tile index in "
-                          "dimension 0 is divisible by the tiling (8)"),
-    "insert": (_insert, "Slice shape along dimension 0 must be aligned "
-                        "to tiling (1024), but is 1"),
-    "fused_tail": (_fused_tail, "Slice shape along dimension 0 must be "
-                                "aligned to tiling (1024), but is 1"),
-    "v4_front": (_v4_front, "Shape mismatch in input, indices and "
-                            "output"),
-}
-
-
-@pytest.mark.parametrize("kernel", sorted(REFUSED))
-def test_pallas_kernel_refusal_propagates(kernel, setup, one_chip):
-    build, words = REFUSED[kernel]
-    fn, avals = build(setup)
-    with pytest.raises(Exception) as exc:
-        compile_for(fn, lambda a: one_chip, *avals)
-    assert words in str(exc.value), str(exc.value)[:600]
-
-
-@pytest.mark.parametrize("pipeline", ["v3", "v4"])
-def test_plan_on_the_chip_raises_instead_of_substituting(pipeline,
-                                                         monkeypatch):
-    """resolve_plan with ``interpret=False`` (what it resolves to when
-    the platform is the TPU): a Pallas stage that cannot be built is an
-    error with the compiler's message, not an XLA stage and a reason
-    string.  The same failure in interpret mode still degrades."""
-    from raft_tla_tpu.ops import (compact_pallas, fused_tail_pallas,
-                                  pipeline_v3, pipeline_v4)
-
-    def refuse(*a, **k):
-        raise NotImplementedError("mosaic says no")
-
-    monkeypatch.setattr(compact_pallas, "build_compactor", refuse)
-    monkeypatch.setattr(fused_tail_pallas, "insert_enqueue", refuse)
-    resolve = (pipeline_v3 if pipeline == "v3"
-               else pipeline_v4).resolve_plan
-    kw = dict(Q=1 << 10, sw=473, force={"compact": "pallas"})
-    with pytest.raises(NotImplementedError, match="mosaic says no"):
-        resolve(32, 132, 512, interpret=False, **kw)
-    plan = resolve(32, 132, 512, interpret=True, **kw)
-    assert plan.stages["compact"] == "xla"
-    assert "mosaic says no" in plan.reasons["compact"]

@@ -7,6 +7,9 @@ primary observable statistics (SURVEY §4 differential oracle).  Fingerprint
 collisions would show up here as count mismatches.
 """
 
+import dataclasses
+import functools
+
 import numpy as np
 import pytest
 
@@ -581,3 +584,199 @@ def test_path_to_state_edge_cases():
         DIMS, target, constraint=build_constraint(DIMS, BOUNDS),
         config=small_config(record_trace=False, check_deadlock=True))
     assert steps[-1][1] == target and len(steps) - 1 == 2
+
+
+@pytest.mark.parametrize("dead", ["v3", "v4"])
+def test_engine_config_rejects_a_deleted_pipeline(dead):
+    """``EngineConfig(pipeline="v4")`` fails where it is written, with
+    the valid values named, and so does the resolver the swarm and the
+    simulator hand a bare name to; ``auto``/``v1``/``v2`` build."""
+    from raft_tla_tpu.engine.bfs import _resolve_pipeline
+    with pytest.raises(ValueError, match=f"auto/v1/v2.*{dead}"):
+        EngineConfig(pipeline=dead)
+    with pytest.raises(ValueError, match=f"auto/v1/v2.*{dead}"):
+        _resolve_pipeline(dead, DIMS)
+    for ok, is_v2 in (("auto", True), ("v1", False), ("v2", True)):
+        assert (_resolve_pipeline(EngineConfig(pipeline=ok).pipeline, DIMS)
+                is not None) == is_v2
+
+
+# -- one chunk call against the oracle --------------------------------------
+#
+# The chunk program's eight stages, held to a reference at once: ONE call
+# of ``eng._chunk`` on the oracle's level-3 frontier (the seen-set holding
+# levels 0-3) must enqueue exactly the oracle's new constraint-passing
+# successors, and record exactly one (child, parent, action) link per new
+# successor, each a transition the oracle makes.  ``tests/test_mesh.py``
+# runs the same comparison over four chips.
+
+class Level3:
+    """The oracle's side: ``cfg``'s level-3 frontier in BFS order, every
+    state through level 3, and each NEW successor of the frontier with
+    the first frontier state that generates it."""
+
+    def __init__(self, cfg):
+        import os
+        from raft_tla_tpu.utils.cfg import load_config
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        self.setup = load_config(os.path.join(repo, "configs", cfg))
+        self.dims = dims = self.setup.dims
+        cons = constraint_py(self.setup.bounds)
+        res = orc.bfs([init_state(dims)], dims, constraint=cons,
+                      check_deadlock=False, max_levels=3)
+        depth = {}
+        for t, (parent, _act) in res.parent.items():
+            depth[t] = 0 if parent is None else depth[parent] + 1
+        self.frontier = [t for t, d in depth.items()
+                         if d == 3 and cons(t, dims)]
+        self.seen = list(res.parent)
+        self.first_parent = {}
+        for s in self.frontier:
+            for _act, t in orc.successors(s, dims):
+                if t not in res.parent:
+                    self.first_parent.setdefault(t, s)
+        self.enqueued = [t for t in self.first_parent if cons(t, dims)]
+
+    def rows(self):
+        from raft_tla_tpu.models.schema import (encode_state,
+                                                flatten_state)
+        return np.stack([np.asarray(flatten_state(
+            encode_state(s, self.dims), self.dims)) for s in self.frontier])
+
+    def fingerprints(self, eng, states):
+        from raft_tla_tpu.models.schema import encode_state, stack_states
+        hi, lo = eng._fp_batch(stack_states(
+            [encode_state(s, self.dims) for s in states]))
+        return [(int(h) << 32) | int(l)
+                for h, l in zip(np.asarray(hi), np.asarray(lo))]
+
+    def seen_keys(self, eng):
+        fps = self.fingerprints(eng, self.seen)
+        return (np.array([f >> 32 for f in fps], np.uint32),
+                np.array([f & 0xFFFFFFFF for f in fps], np.uint32))
+
+    def check_rows(self, rows):
+        """``rows``: the packed rows the call enqueued, all chips'."""
+        import collections
+        from raft_tla_tpu.models.schema import (decode_state,
+                                                unflatten_state)
+        got = [decode_state(unflatten_state(r, self.dims), self.dims)
+               for r in rows]
+        assert collections.Counter(got) \
+            == collections.Counter(self.enqueued)
+        assert len(got) == len(set(got)) > 0
+
+    def check_records(self, eng, cols, first_parent_wins):
+        """``cols``: the five trace columns' written entries.  One link
+        per new successor (constraint-failing ones too: TLC counts them
+        distinct); every link a transition the oracle makes from a
+        frontier state, under the action the link names."""
+        dims = self.dims
+        child_of = dict(zip(self.fingerprints(eng, list(self.first_parent)),
+                            self.first_parent))
+        parent_of = dict(zip(self.fingerprints(eng, self.frontier),
+                             self.frontier))
+        fp = [[(int(h) << 32) | int(l) for h, l in zip(hi, lo)]
+              for hi, lo in (cols[0:2], cols[2:4])]
+        assert sorted(fp[0]) == sorted(child_of) and fp[0]
+        for c, p, g in zip(fp[0], fp[1], (int(a) for a in cols[4])):
+            child, parent = child_of[c], parent_of[p]
+            if first_parent_wins:   # lanes are parent-major, dedup stable
+                assert parent is self.first_parent[child]
+            fam, params = dims.instance_info(g)
+            if "slot" in params:
+                params = (sorted(parent.messages)[params["slot"]][0],)
+            else:
+                params = tuple(params.values())
+            assert ((fam, params), child) in orc.successors(parent, dims), \
+                dims.describe_instance(g)
+
+
+@functools.lru_cache(maxsize=None)
+def one_chunk_call(cfg):
+    """(oracle side, engine, enqueued rows, trace columns) of one
+    ``BFSEngine`` chunk call on ``cfg``'s level-3 frontier."""
+    from raft_tla_tpu.engine.check import make_engine
+    from raft_tla_tpu.ops import fpset
+    want = Level3(cfg)
+    eng = make_engine(want.setup, EngineConfig(
+        batch=32, queue_capacity=1 << 12, seen_capacity=1 << 14))
+    (qav, _, _, _, _, _seen, tbuf_av, _, _) = eng.chunk_avals()
+    qcur = np.zeros(qav.shape, np.uint8)
+    qcur[:len(want.frontier)] = want.rows()
+    out = eng._chunk(
+        jnp.asarray(qcur), jnp.int32(len(want.frontier)), jnp.int32(0),
+        jnp.zeros(qav.shape, jnp.uint8), jnp.int32(0),
+        fpset.from_host_keys(*want.seen_keys(eng), eng._seen_cap),
+        tuple(jnp.zeros(a.shape, a.dtype) for a in tbuf_av),
+        jnp.int32(0), jnp.int32(eng._CH))
+    qnext, _seen, tbuf, stats = out[:4]
+    _offset, _steps, next_count, _size, tcount = (
+        int(x) for x in np.asarray(stats)[:5])
+    assert int(np.asarray(stats)[12]) == len(want.frontier)    # expanded
+    return (want, eng, np.asarray(qnext[:next_count]),
+            [np.asarray(c[:tcount]) for c in tbuf])
+
+
+CHUNK_CFGS = ["MCraft_bounded.cfg", "MCraft_noleader.cfg", "TPUraft.cfg"]
+
+
+@pytest.mark.parametrize("cfg", CHUNK_CFGS)
+def test_one_chunk_call_enqueues_the_oracles_new_successors(cfg):
+    want, _eng, rows, _cols = one_chunk_call(cfg)
+    want.check_rows(rows)
+
+
+@pytest.mark.parametrize("cfg", CHUNK_CFGS)
+def test_one_chunk_call_records_the_oracles_transitions(cfg):
+    want, eng, _rows, cols = one_chunk_call(cfg)
+    want.check_records(eng, cols, first_parent_wins=True)
+
+
+@pytest.mark.parametrize("cfg, pins, through, violation_depth", [
+    ("configs/TPUraft.cfg", "artifacts/tpuraft_L9_oracle.jsonl", 5, None),
+    ("configs/MCraft_noleader.cfg",
+     "benchmark/pinned/mcraft3-noleader.jsonl", 8, 9),
+], ids=["raft5", "noleader"])
+def test_every_level_matches_the_pinned_profile(cfg, pins, through,
+                                                violation_depth, tmp_path):
+    """Frontier, cumulative distinct and cumulative generated of EVERY
+    level against the oracle's pinned profile (the benchmark's ``correct``
+    compares the same three a level): the 5-server model through level 5
+    (17,852 distinct), and the verdict cell's whole check under its cfg's
+    own small pools — levels 0-8 (37,452 distinct), then the violation in
+    level 9."""
+    import json
+    import os
+    from raft_tla_tpu.engine.check import (engine_config_from_backend,
+                                           initial_states, make_engine)
+    from raft_tla_tpu.obs import validate_run_events
+    from raft_tla_tpu.utils.cfg import load_config
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, pins), encoding="utf-8") as f:
+        want = [json.loads(line) for line in f][:through + 1]
+    assert [w["level"] for w in want] == list(range(through + 1))
+    setup = load_config(os.path.join(here, cfg))
+    ev = str(tmp_path / "events.jsonl")
+    if violation_depth is None:
+        config = small_config(batch=256, queue_capacity=1 << 14,
+                              seen_capacity=1 << 16, max_diameter=through,
+                              record_trace=False, events_out=ev)
+    else:
+        config = dataclasses.replace(engine_config_from_backend(setup),
+                                     events_out=ev)
+    eng = make_engine(setup, config)
+    res = eng.run(initial_states(setup))
+    got = [e for e in validate_run_events(ev)
+           if e["event"] == "level_complete"][:through + 1]
+    assert [(e["frontier_rows"], e["distinct"], e["generated"])
+            for e in got] == [(w["frontier"], w["distinct"], w["generated"])
+                              for w in want]
+    if violation_depth is None:
+        assert res.violation is None and res.distinct == want[-1]["distinct"]
+    else:
+        assert res.stop_reason == "violation"
+        assert res.violation.invariant == "NoLeaderElected"
+        path = eng.replay(res.violation.fingerprint)
+        assert len(path) == violation_depth + 1
+        assert LEADER in path[-1][1].role

@@ -66,89 +66,3 @@ def test_insert_reports_fail_when_genuinely_full():
     lo = jnp.asarray(np.arange(512, dtype=np.uint32) * 7 + 1)
     s, new, fail = fp.insert(s, hi, lo, jnp.ones((512,), bool))
     assert bool(fail)
-
-
-def test_pallas_insert_matches_xla_insert():
-    """ops/fpset_pallas.py: the sequential-grid Pallas insert must match
-    the XLA claim-protocol insert on the observable contract — is_new
-    (exactly one query per distinct new key, same index), size, fail,
-    stored key set, and subsequent `contains` — across duplicate-heavy
-    batches on BOTH tables as they fill.  Raw slot layout may differ
-    (documented in the module header), so tables are compared as sorted
-    key sets, not arrays."""
-    from raft_tla_tpu.ops import fpset_pallas
-
-    rng = np.random.RandomState(11)
-    s_x = fpset.empty(1 << 12)
-    s_p = fpset.empty(1 << 12)
-    ins_x = jax.jit(fp.insert)
-    ref = set()
-    for it in range(6):
-        keys = rng.randint(0, 1 << 10, size=512).astype(np.uint64)
-        hi = jnp.asarray((keys >> 5).astype(np.uint32) + np.uint32(it * 131))
-        lo = jnp.asarray(keys.astype(np.uint32))
-        valid = jnp.asarray(rng.rand(512) < 0.75)
-        s_x, new_x, fail_x = ins_x(s_x, hi, lo, valid)
-        s_p, new_p, fail_p = fpset_pallas.insert(s_p, hi, lo, valid)
-        assert (np.asarray(new_x) == np.asarray(new_p)).all(), f"iter {it}"
-        assert bool(fail_x) == bool(fail_p) == False  # noqa: E712
-        assert int(s_x.size) == int(s_p.size)
-        ref |= {(int(h), int(l))
-                for h, l, v in zip(np.asarray(hi), np.asarray(lo),
-                                   np.asarray(valid)) if v}
-        assert int(s_p.size) == len(ref)
-    kx = fpset.to_host_keys(s_x)
-    kp = fpset.to_host_keys(s_p)
-    assert (kx[0] == kp[0]).all() and (kx[1] == kp[1]).all()
-    # Cross-membership: keys inserted by the Pallas path are found by the
-    # XLA probe over the Pallas-laid-out table (the chain invariant holds
-    # for sequential layouts too).
-    hi = jnp.asarray(np.array([h for h, _ in sorted(ref)], np.uint32))
-    lo = jnp.asarray(np.array([l for _, l in sorted(ref)], np.uint32))
-    assert bool(fp.contains(s_p, hi, lo).all())
-    assert not bool(fp.contains(s_p, hi ^ jnp.uint32(1 << 30), lo).any())
-
-
-def test_pallas_enqueue_matches_scatter_reference():
-    """ops/enqueue_pallas.py: live queue rows [0, next_count') identical
-    to the scatter lowering for adversarial masks — empty, full, single
-    lanes, runs ending at K-1, run lengths straddling the SEG quantum —
-    and the overhang never lands outside [next_count'+0, +SEG)."""
-    from raft_tla_tpu.ops import enqueue_pallas as ep
-
-    rng = np.random.RandomState(3)
-    K, SW, QA = 256, 37, 1024
-    masks = [
-        np.zeros(K, bool),
-        np.ones(K, bool),
-        np.eye(1, K, 0, dtype=bool)[0],           # single first lane
-        np.eye(1, K, K - 1, dtype=bool)[0],       # single last lane
-    ]
-    m = np.zeros(K, bool)
-    m[5:5 + ep.SEG + 3] = True                    # one run straddling SEG
-    masks.append(m)
-    for _ in range(6):
-        masks.append(rng.rand(K) < rng.choice([0.1, 0.5, 0.9]))
-    for t, mask in enumerate(masks):
-        krows = jnp.asarray(rng.randint(0, 255, (K, SW)), jnp.uint8)
-        base = jnp.asarray(rng.randint(0, 255, (QA, SW)), jnp.uint8)
-        nc = int(rng.randint(0, QA - 2 * K))
-        enq = jnp.asarray(mask)
-        got = np.asarray(ep.enqueue(base, jnp.int32(nc), krows, enq))
-        # scatter reference (chunk.py semantics, live region only)
-        want = np.asarray(base).copy()
-        want[nc:nc + int(mask.sum())] = np.asarray(krows)[mask]
-        end = nc + int(mask.sum())
-        assert (got[:end] == want[:end]).all(), f"mask {t}: live rows"
-        # overhang confined to < SEG rows past the live region
-        assert (got[end + ep.SEG:] == want[end + ep.SEG:]).all(), \
-            f"mask {t}: wrote beyond the overhang window"
-
-
-def test_pallas_insert_reports_fail_when_genuinely_full():
-    s = fpset.empty(1 << 8)
-    from raft_tla_tpu.ops import fpset_pallas
-    hi = jnp.asarray(np.arange(512, dtype=np.uint32))
-    lo = jnp.asarray(np.arange(512, dtype=np.uint32) * 7 + 1)
-    _s, _new, fail = fpset_pallas.insert(s, hi, lo, jnp.ones((512,), bool))
-    assert bool(fail)

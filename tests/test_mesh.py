@@ -255,3 +255,65 @@ def test_mesh_progress_lines_emitted(capfd):
     eng.run([init_state(DIMS)])
     err = capfd.readouterr().err
     assert "progress:" in err and "queue" in err
+
+
+# -- one chunk call against the oracle, over four chips ---------------------
+
+@pytest.fixture(scope="module")
+def one_mesh_chunk_call():
+    """tests/test_engine.py's comparison for ``MeshBFSEngine``: the
+    level-3 frontier of ``MCraft_bounded.cfg`` dealt over four chips, the
+    seen-set sharded by owner, one ``sharded_chunk`` call."""
+    import functools
+    import numpy as np
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from raft_tla_tpu.engine.check import make_engine
+    from raft_tla_tpu.parallel import multihost as mh
+    from tests.test_engine import Level3
+    want = Level3("MCraft_bounded.cfg")
+    eng = make_engine(
+        want.setup, EngineConfig(batch=32, queue_capacity=1 << 12,
+                                 seen_capacity=1 << 14),
+        engine_cls=functools.partial(MeshBFSEngine,
+                                     devices=jax.devices()[:4]))
+    n = eng.n_dev
+    (qav, counts_av, _, _, _, _shi, _slo, _size, tbuf_av, _,
+     _) = eng.chunk_avals()
+    qcur = np.zeros(qav.shape, np.uint8)
+    counts = np.zeros(counts_av.shape, np.int32)
+    for d in range(n):
+        mine = want.rows()[d::n]
+        qcur[d, :len(mine)], counts[d] = mine, len(mine)
+
+    def put(a):
+        return mh.put_global(a, eng.mesh, P("x"))
+
+    zeros = np.zeros(counts_av.shape, np.int32)
+    out = eng._chunk(
+        put(qcur), put(counts), jnp.int32(0),
+        put(np.zeros(qav.shape, np.uint8)), put(zeros),
+        *eng._shards_from_keys(*want.seen_keys(eng)),
+        tuple(put(np.zeros(a.shape, a.dtype)) for a in tbuf_av),
+        put(zeros), jnp.int32(eng._CH))
+    qnext, ncnt, _hi, _lo, _size, tbuf, tcnt = (
+        jax.tree.map(np.asarray, out[:7]))
+    assert all(0 < c < len(want.enqueued) for c in ncnt)    # every chip
+    rows = np.concatenate([qnext[d, :ncnt[d]] for d in range(n)])
+    cols = [np.concatenate([c[d, :tcnt[d]] for d in range(n)])
+            for c in tbuf]
+    return want, eng, rows, cols
+
+
+def test_one_mesh_chunk_call_enqueues_the_oracles_new_successors(
+        one_mesh_chunk_call):
+    want, _eng, rows, _cols = one_mesh_chunk_call
+    want.check_rows(rows)
+
+
+def test_one_mesh_chunk_call_records_the_oracles_transitions(
+        one_mesh_chunk_call):
+    """The owner decides which of two chips' equal candidates is new, so
+    the first parent in frontier order need not win here."""
+    want, eng, _rows, cols = one_mesh_chunk_call
+    want.check_records(eng, cols, first_parent_wins=False)

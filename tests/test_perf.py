@@ -3,16 +3,15 @@
 Three contracts from the ISSUE acceptance criteria:
 
 - the static roofline's byte model is cross-checked against HAND-
-  COMPUTED traffic for the fingerprint (v1) and compact (v3) stages on
+  COMPUTED traffic for the fingerprint stage and the compactor on
   the seed dims — the walk's windowed-gather/full-read rules are pinned
   to arithmetic a reviewer can redo on paper;
-- launch counts are PINNED per pipeline (v1/v2/v3) on the tiny model:
+- launch counts are PINNED per pipeline (v1/v2) on the tiny model:
   the counts are deterministic jaxpr device-op totals, so a chunk-body
-  change that un-fuses a stage (e.g. the v3 fused tail silently falling
-  back to the split insert+enqueue, +128 ops here) moves the pin and
-  fails CI instead of landing as an invisible slowdown.  Re-pin ONLY
-  after confirming the delta is intentional (a jax upgrade that
-  re-lowers primitives also legitimately moves these);
+  change that adds device ops moves the pin and fails CI instead of
+  landing as an invisible slowdown.  Re-pin ONLY after confirming the
+  delta is intentional (a jax upgrade that re-lowers primitives also
+  legitimately moves these);
 - engine counts are bit-identical with the perf surfaces on or off,
   single-chip and mesh (the observational contract every obs leg
   keeps).
@@ -104,11 +103,15 @@ def test_fingerprint_stage_bytes_match_hand_computed():
 
 
 def test_compact_stage_bytes_match_hand_computed():
-    """v3 compact stage: reads the [B, G] bool enabled mask (1 byte per
-    lane), writes the [K] int32 lane ids + [K] bool validity."""
+    """The chunk's compact stage (ops/compact.py): reads the [B, G] bool
+    enabled mask (1 byte per lane), writes the [K] int32 lane ids + [K]
+    bool validity."""
+    from raft_tla_tpu.ops.compact import build_compactor
     roofline = _roofline()
-    traffic = roofline.stage_traffic(DIMS, B, K, pipeline="v3")
-    got = traffic["compact"]
+    compactor = build_compactor(B, DIMS.n_instances, K)
+    en = jax.ShapeDtypeStruct((B, DIMS.n_instances), jnp.bool_)
+    got = roofline.jaxpr_traffic(
+        jax.make_jaxpr(lambda e: compactor(e)[2:])(en), [en])
     assert got["bytes_read"] == B * DIMS.n_instances
     assert got["bytes_written"] == K * 4 + K
 
@@ -152,29 +155,24 @@ def test_roofline_rows_and_advisor():
 
 
 # ---------------------------------------------------------------------------
-# Pinned launch counts (the CI un-fusing gate)
+# Pinned launch counts
 
 #: Deterministic jaxpr device-op counts of the REAL chunk programs on
 #: the tiny model above (batch=32, trace on, deadlock off).  These move
 #: only when the chunk body (or a jax upgrade's lowering) changes — an
 #: intentional change re-pins with the delta explained in its PR.  The
-#: v3 pin sits BELOW v2 by the fused tail's retired split-path ops: the
-#: fused probe/insert->enqueue kernel replacing the XLA insert + row
-#: scatter is directly visible here.  The v1 pin moved 1948 -> 2119
-#: with the BLEST family grouping (models/actions.py): the stacked
-#: group kernels add where-cascade selects to the PRE-fusion eqn count
-#: while cutting the per-family launch fan-out XLA must schedule.  The
-#: v4 pin is the megakernel story: the whole front (masks + compact +
-#: fingerprint) plus the fused tail collapse ~2900 device ops into two
-#: Pallas launches + the fixed chunk scaffolding.
-#: Re-pinned for jax 0.9.0 (PR 24; was 2119 / 3178 / 3050 / 257 under
+#: v1 pin moved 1948 -> 2119 with the BLEST family grouping
+#: (models/actions.py): the stacked group kernels add where-cascade
+#: selects to the PRE-fusion eqn count while cutting the per-family
+#: launch fan-out XLA must schedule.
+#: Re-pinned for jax 0.9.0 (PR 24; was 2119 / 3178 under
 #: 0.4.36) with no change to any chunk body.  Two causes: 0.9.0 names
 #: the nested-jit primitive ``jit`` (was ``pjit``), which the walk did
 #: not recurse into until analysis/interp.py learned the name (v2 read
 #: 2993 in between); and with the recursion restored 0.9.0 still
 #: traces the same bodies to fewer pre-fusion equations (v1 -84,
-#: v2/v3 -38, v4 -8) — not attributable per primitive without the old
-#: installation.  The v2 - v3 = 128 and v2 / v4 relations are unchanged.
+#: v2 -38) — not attributable per primitive without the old
+#: installation.
 #: ``launches_fixed`` 6 -> 7 (PR 27): the ``add 0`` outside the loop that
 #: carries the stage names' tag into the compile-cache key
 #: (engine/chunk.py tag_stages).  This model counts equations before
@@ -184,12 +182,10 @@ def test_roofline_rows_and_advisor():
 LAUNCH_PINS = {
     "v1": {"launches_per_batch": 2035, "launches_fixed": 7},
     "v2": {"launches_per_batch": 3140, "launches_fixed": 7},
-    "v3": {"launches_per_batch": 3012, "launches_fixed": 7},
-    "v4": {"launches_per_batch": 249, "launches_fixed": 7},
 }
 
 
-@pytest.mark.parametrize("pipe", ["v1", "v2", "v3", "v4"])
+@pytest.mark.parametrize("pipe", ["v1", "v2"])
 def test_launch_counts_pinned_per_pipeline(pipe):
     eng = BFSEngine(DIMS, constraint=build_constraint(DIMS, BOUNDS),
                     config=small_config(perf=True, pipeline=pipe))
@@ -200,7 +196,7 @@ def test_launch_counts_pinned_per_pipeline(pipe):
         f"{pipe} chunk-program launch count moved: {got} != pinned "
         f"{LAUNCH_PINS[pipe]}.  If the chunk body changed "
         f"intentionally (or jax re-lowered primitives), re-pin WITH "
-        f"the delta explained; otherwise a stage just un-fused.")
+        f"the delta explained; otherwise the chunk grew device ops.")
 
 
 #: Swarm chunk program on the same tiny model (walks=batch=32,
@@ -208,7 +204,7 @@ def test_launch_counts_pinned_per_pipeline(pipe):
 #: by the hunt flag: the +147-op delta IS the observatory's whole
 #: static footprint (bloom probes/pushes + the O(B^2) same-fingerprint
 #: prior + depth/family tallies), pinned so analytics creep into the
-#: walk hot loop fails CI the same way an un-fused stage does.  Only 3
+#: walk hot loop fails CI the same way a grown chunk body does.  Only 3
 #: fixed ops (vs the BFS engines' 6): the swarm scaffolding is the
 #: scan wrapper alone — no queue/frontier plumbing.  Re-pinned for jax
 #: 0.9.0 (PR 24; was 3104 / 3251): the same -36 on both, same cause as
@@ -243,72 +239,6 @@ def test_swarm_launch_counts_pinned(hunt):
     # the scan body but never an order of magnitude.
     assert SWARM_LAUNCH_PINS[True]["launches_per_batch"] \
         <= 1.10 * SWARM_LAUNCH_PINS[False]["launches_per_batch"]
-
-
-def test_v3_fused_tail_retires_launches():
-    """The relation (not just the absolute pins): v3's fused tail must
-    count FEWER device ops than v2's split insert+enqueue — the
-    fused-vs-unfused delta as a first-class assertion."""
-    assert LAUNCH_PINS["v3"]["launches_per_batch"] \
-        < LAUNCH_PINS["v2"]["launches_per_batch"]
-
-
-def test_v4_megakernel_quarter_of_v2():
-    """ISSUE 15 acceptance criterion as an assertion: v4's static
-    per-chunk device-op count must be at MOST 25% of v2's — the
-    megakernel's whole point.  (Measured: ~8%.)"""
-    assert LAUNCH_PINS["v4"]["launches_per_batch"] \
-        <= 0.25 * LAUNCH_PINS["v2"]["launches_per_batch"]
-
-
-def test_v3_plan_reports_stage_launches():
-    from raft_tla_tpu.models.schema import state_width
-    from raft_tla_tpu.ops import pipeline_v3
-    G = DIMS.n_instances
-    plan = pipeline_v3.resolve_plan(B, G, K, Q=4096,
-                                    sw=state_width(DIMS))
-    # CPU policy: fused tail (interpret), XLA compact.
-    assert plan.stages["insert"] == "fused"
-    assert plan.launches["insert"] == 1
-    assert plan.launches["enqueue"] == 0       # shares the fused kernel
-    assert plan.launches["compact"] is None    # XLA: the walk's to count
-    forced = pipeline_v3.resolve_plan(B, G, K, Q=4096,
-                                      sw=state_width(DIMS),
-                                      force={"insert": "xla"})
-    assert forced.launches["insert"] is None
-
-
-def test_v4_plan_reports_stage_launches():
-    """v4 plan launch accounting: a built front is ONE launch covering
-    masks/compact/fingerprint (the grouped stages count 0), the fused
-    tail one more; degrading any front member nulls the whole group
-    (XLA ops counted by the jaxpr walk instead)."""
-    from raft_tla_tpu.models.actions2 import build_v2
-    from raft_tla_tpu.models.schema import state_width
-    from raft_tla_tpu.ops import pipeline_v4
-    G = DIMS.n_instances
-    ctx = {"dims": DIMS, "v2": build_v2(DIMS), "constraint": None,
-           "inv_fns": None, "por_mask": None, "por_priority": None}
-    plan = pipeline_v4.resolve_plan(B, G, K, Q=4096,
-                                    sw=state_width(DIMS), front_ctx=ctx)
-    assert plan.stages == {"masks": "fused", "compact": "fused",
-                           "fingerprint": "fused", "insert": "fused",
-                           "enqueue": "fused"}
-    assert plan.launches["masks"] == 1
-    assert plan.launches["compact"] == 0
-    assert plan.launches["fingerprint"] == 0
-    assert plan.launches["insert"] == 1
-    assert plan.launches["enqueue"] == 0
-    degraded = pipeline_v4.resolve_plan(B, G, K, Q=4096,
-                                        sw=state_width(DIMS),
-                                        front_ctx=ctx,
-                                        force={"compact": "xla"})
-    assert degraded.front is None
-    assert degraded.launches["masks"] is None
-    # shape-only resolve (no build context) degrades with a reason
-    shp = pipeline_v4.resolve_plan(B, G, K, Q=4096, sw=state_width(DIMS))
-    assert shp.front is None
-    assert any("front" in r for r in shp.reasons.values())
 
 
 # ---------------------------------------------------------------------------

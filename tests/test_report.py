@@ -201,7 +201,7 @@ def _bench_doc(value=1000.0, fp=FP_A):
             "unit": "states/s", "generated_per_sec": 4 * value,
             "distinct_states": 50000, "generated_states": 200000,
             "diameter": 8, "wall_s": 50.0, "stop_reason":
-            "duration_budget", "pipeline": "v2", "fused_stages": {},
+            "duration_budget", "pipeline": "v2",
             "host_fingerprint": fp,
             "phases": {"chunk": 30.0}, "coverage": {},
             "report": {"collision": {"calculated": 1e-12,
@@ -276,6 +276,46 @@ def test_history_entry_from_engine_result(tmp_path):
     led = str(tmp_path / "led.jsonl")
     history_mod.append_entry(led, entry)
     assert history_mod.read_history(led)[0]["label"] == "unit"
+
+
+def test_family_groups_metadata_and_ledger(tmp_path):
+    """models/actions.py family_groups: the base alphabet stacks into
+    the four parameter-shape groups (10 families -> 4 launches), and the
+    grouping rides EngineResult -> statespace report -> history-ledger
+    summary."""
+    from raft_tla_tpu.models.actions import family_groups
+    from raft_tla_tpu.utils.cfg import load_config
+    setup = load_config(os.path.join(REPO, "configs/MCraft_bounded.cfg"))
+    dims = setup.dims
+
+    groups = family_groups(dims)
+    by_name = {g["group"]: g for g in groups}
+    assert set(by_name) == {"server", "server_pair", "server_value",
+                            "slot"}
+    assert by_name["server"]["kernels"] == 4
+    assert by_name["server"]["families"] == ["Restart", "Timeout",
+                                             "BecomeLeader",
+                                             "AdvanceCommitIndex"]
+    assert sum(g["lanes"] for g in groups) == dims.n_instances
+
+    eng = BFSEngine(dims, constraint=build_constraint(dims, setup.bounds),
+                    config=EngineConfig(batch=64, queue_capacity=1 << 12,
+                                        seen_capacity=1 << 14,
+                                        check_deadlock=False,
+                                        max_diameter=2))
+    res = eng.run([init_state(dims)])
+    assert res.family_groups == groups
+    assert res.report.get("family_groups") == groups
+    summ = report_mod.summarize(res.report)
+    assert summ["family_groups"] == {"server": 4, "server_pair": 2,
+                                     "server_value": 1, "slot": 3}
+
+    ledger = str(tmp_path / "ledger.jsonl")
+    history_mod.append_entry(
+        ledger, history_mod.entry_from_result("check", res,
+                                              label="family_groups"))
+    entry = history_mod.read_history(ledger)[0]
+    assert entry["report"]["family_groups"]["server"] == 4
 
 
 def test_history_rejects_corrupt_ledger(tmp_path):

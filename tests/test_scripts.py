@@ -179,31 +179,28 @@ def test_bench_diff_pruned_fraction_is_gated(tmp_path, capsys):
     assert "POR pruned" not in capsys.readouterr().out
 
 
-def test_bench_diff_folds_mismatched_stage_granularities(tmp_path, capsys):
-    """A v2 bench (classical stage keys) vs a v3 bench (fused-stage
-    keys) must still diff: both sides fold to the common coarse stages
-    (front / fingerprint / tail / total) with a note — a cross-pipeline
-    comparison is a diff, not a refusal, and a genuine folded-stage
-    blow-up still gates."""
+def test_bench_diff_compares_the_stage_keys_both_sides_have(tmp_path,
+                                                            capsys):
+    """Two documents whose ``chunk_stages`` keys differ: the keys both
+    have are compared (and still gate), the keys only one side has are
+    named in a note, nothing is folded onto a coarser stage."""
     main = _bench_diff_main()
-    old, new = tmp_path / "v2.json", tmp_path / "v3.json"
+    old, new = tmp_path / "old.json", tmp_path / "new.json"
     old.write_text(json.dumps(_fake_bench()))
-    v3_stages = {"masks": 0.030, "compact": 0.018, "fingerprint": 0.011,
-                 "insert_enqueue": 0.037, "total": 0.058}
-    new.write_text(json.dumps(_fake_bench(
-        chunk_stages=v3_stages, pipeline="v3",
-        fused_stages={"insert": "fused", "enqueue": "fused"})))
+    other = {"expand": 0.050, "fingerprint": 0.011, "tail": 0.037,
+             "total": 0.058}
+    new.write_text(json.dumps(_fake_bench(chunk_stages=other)))
     assert main([str(old), str(new)]) == 0
     out = capsys.readouterr().out
-    assert "granularities differ" in out and "folded to common" in out
-    assert "chunk stage front" in out and "chunk stage tail" in out
-    # The folded comparison still gates: a fused tail 10x the old
-    # insert+enqueue sum regresses.
-    v3_bad = dict(v3_stages, insert_enqueue=0.350)
-    new.write_text(json.dumps(_fake_bench(chunk_stages=v3_bad,
-                                          pipeline="v3")))
+    assert "old side only, not compared: dedup_insert, enqueue" in out
+    assert "new side only, not compared: tail" in out
+    assert "chunk stage expand" in out and "chunk stage total" in out
+    assert "chunk stage tail" not in out and "folded" not in out
+    # A shared key still gates, exit code as for any other diff.
+    new.write_text(json.dumps(_fake_bench(
+        chunk_stages=dict(other, fingerprint=0.100))))
     assert main([str(old), str(new)]) == 1
-    assert "chunk stage 'tail'" in capsys.readouterr().out
+    assert "chunk stage 'fingerprint'" in capsys.readouterr().out
 
 
 def test_bench_diff_malformed_inputs_exit_2(tmp_path, capsys):

@@ -254,3 +254,17 @@ def test_stats_request_reports_requests_and_cache_counters(server):
         "count"] >= 2
     # The stats op never takes the engine lock, and counts itself.
     assert counters["server/requests/stats"] >= 1
+
+
+@pytest.mark.parametrize("dead", ["v3", "v4"])
+def test_request_for_a_deleted_pipeline_is_rejected(dead, server):
+    """A check with ``"pipeline": "v3"`` answers ``ok: false`` naming the
+    valid values and runs nothing; a submit is refused at admission."""
+    job = {"op": "check",
+           "cfg": os.path.join(REPO, "configs/MCraft_bounded.cfg"),
+           "batch": 128, "max_diameter": 1, "pipeline": dead}
+    resp = roundtrip(server, job)
+    assert resp["ok"] is False
+    assert "auto/v1/v2" in resp["error"] and dead in resp["error"]
+    with pytest.raises(ValueError, match=f"auto/v1/v2.*{dead}"):
+        srv_mod._do_submit({"op": "submit", "job": job}, manager=None)
