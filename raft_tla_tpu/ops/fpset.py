@@ -237,6 +237,56 @@ def insert(s: FPSet, qhi, qlo, valid) -> Tuple["FPSet", jnp.ndarray,
     return s, is_new[:k], fail
 
 
+def insert_windowed(s: FPSet, qhi, qlo, valid, window: int):
+    """``insert`` for a batch that is mostly padding (the mesh owner's
+    arrivals: n blocks of ``window`` lanes, each padded with SENTINEL,
+    about one block's worth of queries in all).  A gather or a scatter
+    costs by the lane whatever the lane holds (design notes 1-3: a masked
+    lane does full work at its own spread address), so the probe rounds
+    run only on lanes that hold a query.  One stable sort is the
+    compaction — SENTINEL sorts last, so the valid queries are the sorted
+    prefix, equal keys adjacent with the lowest index first — and
+    ``insert`` runs on windows of ``window`` sorted lanes (rounded up to
+    a power of two), as many as the valid queries reach: one where they
+    fit a block, ``kp / window`` where every lane holds one.  A key on
+    both sides of a cut is new in the earlier window, where its lowest
+    index lies, and found in the table by the later one.  Each window's
+    novelty goes back to the caller's order by a scatter of its own
+    lanes.
+
+    Returns ``(table', is_new, fail, windows)``.  ``is_new`` and ``fail``
+    are ``insert``'s on the whole batch, lane for lane, and the table
+    holds the same key set; only the slot a key lands in may differ
+    (claims resolve window by window).  ``windows`` is how many ran.
+    Every window goes through ``insert`` itself: the one door by which
+    any engine's candidates reach the set."""
+    import jax
+
+    (qhi, qlo, valid), k = _pad_pow2(
+        (qhi, qlo, jnp.asarray(valid, bool)),
+        (SENTINEL, SENTINEL, False))
+    kp = qhi.shape[0]
+    m = min(_pow2(window), kp)
+    (sh, sl), order, _first = dedup_batch(qhi, qlo, valid)
+    windows = (jnp.sum(valid, dtype=_I32) + (m - 1)) // m
+
+    def window_body(carry):
+        s, is_new, fail, j = carry
+        wh, wl, worder = (jax.lax.dynamic_slice_in_dim(a, j * m, m)
+                          for a in (sh, sl, order))
+        s, wnew, wfail = insert(
+            s, wh, wl, ~((wh == SENTINEL) & (wl == SENTINEL)))
+        # ``order`` is a permutation: every lane of the window, a padded
+        # one too, writes an address of its own.
+        is_new = is_new.at[worder].set(wnew, unique_indices=True)
+        return s, is_new, fail | wfail, j + 1
+
+    s, is_new, fail, _j = jax.lax.while_loop(
+        lambda carry: carry[3] < windows, window_body,
+        (s, jnp.zeros((kp,), bool), jnp.bool_(False), _I32(0)))
+    return s, is_new[:k], fail, windows
+
+
 def contains(s: FPSet, qhi, qlo):
     """Membership for a batch of keys.  [K] bool.  Sentinel-keyed (invalid)
     lanes report False."""

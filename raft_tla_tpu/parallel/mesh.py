@@ -201,10 +201,13 @@ class MeshBFSEngine:
         per_chip = -(-qreq // n)
         QL = max(-(-per_chip // B) * B, K)
         PAD = max(B, K)
-        # Seen shard: each chip receives up to n*K owner-routed queries
-        # per batch in the worst case, but only ~K on average; the same
-        # 8-batch floor as the single-chip engine keeps the growth
-        # threshold (half load) safely ahead of probe failure.
+        # Seen shard: each chip receives n blocks of K owner-routed
+        # lanes per batch — up to n*K queries in the worst case, ~K on
+        # average — and its insert pays for the queries, a window of K
+        # lanes at a time (``route_insert``), not for the blocks'
+        # padding; the same 8-batch floor as the single-chip engine
+        # keeps the growth threshold (half load) safely ahead of probe
+        # failure.
         CL = fpset._capacity(max(-(-sreq // n), 8 * K))
         self._sw, self._B, self._G, self._QL, self._CL = sw, B, G, QL, CL
         self._K, self._PAD = K, PAD
@@ -220,12 +223,20 @@ class MeshBFSEngine:
         compactor = compact_mod.build_compactor(
             B, G, K, reduce_p=lambda p: jax.lax.pmin(p, "x"))
 
-        def route_insert(seen_local, fph, fpl, valid):
+        def route_insert(seen_windows, fph, fpl, valid):
             """Cross-chip owner dedup: route each valid fingerprint to its
             owner chip (fp_hi mod n) with one all_to_all, insert the union
             of arrivals into the local shard, route the novelty bits back.
             Exactly one copy of each globally-new key (across all chips)
-            gets the bit."""
+            gets the bit.  ``seen_windows`` is the shard and, beside it,
+            the count of insert windows run so far (the shared body passes
+            both through as its ``seen``): the n blocks of k lanes that
+            arrive hold about k queries between them, and the owner's
+            insert runs on windows of k lanes over the queries
+            (``fpset.insert_windowed``), one nearly always, never on the
+            blocks' padding.  The trip count is this chip's own: no
+            collective runs inside ``owner_insert``."""
+            seen_local, windows = seen_windows
             k = fph.shape[0]
             with jax.named_scope("exchange"):
                 fph = jnp.where(valid, fph, SENTINEL)
@@ -246,14 +257,14 @@ class MeshBFSEngine:
             with jax.named_scope("owner_insert"):
                 rh, rl = bh.reshape(-1), bl.reshape(-1)
                 rvalid = ~((rh == SENTINEL) & (rl == SENTINEL))
-                seen_local, qnew, fail = fpset.insert(
-                    seen_local, rh, rl, rvalid)
+                seen_local, qnew, fail, ran = fpset.insert_windowed(
+                    seen_local, rh, rl, rvalid, k)
             with jax.named_scope("return"):
                 nov = jax.lax.all_to_all(qnew.reshape(n, k), "x", 0, 0,
                                          tiled=True)
                 new_sortpos = nov[osort, rank]
                 new = jnp.zeros((k,), bool).at[perm].set(new_sortpos)
-            return seen_local, new, fail
+            return (seen_local, windows + ran), new, fail
 
         def local_absorb(crows, cands, en, parent_hi, parent_lo, actions,
                          qnext, next_count, seen_local, tbuf, tcount):
@@ -262,7 +273,8 @@ class MeshBFSEngine:
             <= B); the chunk path below compacts first."""
             k = crows.shape[0]
             fph, fpl = jax.vmap(fingerprint)(cands)
-            seen_local, new, fail = route_insert(seen_local, fph, fpl, en)
+            (seen_local, _windows), new, fail = route_insert(
+                (seen_local, jnp.int32(0)), fph, fpl, en)
             fph = jnp.where(en, fph, SENTINEL)
             fpl = jnp.where(en, fpl, SENTINEL)
 
@@ -315,14 +327,15 @@ class MeshBFSEngine:
             por_mask=por_mask, por_priority=por_priority)
 
         def agreed_stats(offset, steps, gen, newc, ovfc, fail_any,
-                         max_count, ncnt_l, cnt_l, seen_l, viol_any, vinv,
-                         vrow, vhi, vlo, dead_any, drow, expanded,
+                         max_count, ncnt_l, cnt_l, seen_l, windows, viol_any,
+                         vinv, vrow, vhi, vlo, dead_any, drow, expanded,
                          fam_counts, fam_new, fam_pruned):
             """What a chunk call hands the host, the same on every chip:
             the psum/pmax-combined statistics, the violation/deadlock
             rows broadcast from the lowest-indexed flagged chip (no
             per-chip inspection on the host side), and last each chip's
-            own (parents expanded, next-level rows, shard keys)."""
+            own (parents expanded, next-level rows, shard keys, insert
+            windows run)."""
             g_gen = jax.lax.psum(gen, "x")
             g_new = jax.lax.psum(newc, "x")
             g_ovf = jax.lax.psum(ovfc, "x")
@@ -351,7 +364,7 @@ class MeshBFSEngine:
                 jax.lax.psum(fam_new, "x"),
                 jax.lax.psum(fam_pruned, "x"),
                 jax.lax.all_gather(
-                    jnp.stack([expanded, ncnt_l, seen_l.size]),
+                    jnp.stack([expanded, ncnt_l, seen_l.size, windows]),
                     "x").T.reshape(-1)])
             return stats, drow_g, vrow_g, vhi_g, vlo_g
 
@@ -368,7 +381,8 @@ class MeshBFSEngine:
                 max_count = jax.lax.pmax(cnt_l, "x")
             seen_l = fpset.FPSet(hi=shi[0], lo=slo[0], size=ssize[0])
             tbuf_l = tuple(t[0] for t in tbuf)
-            init = (offset0, jnp.int32(0), qnext_l, ncnt_l, seen_l, tbuf_l,
+            init = (offset0, jnp.int32(0), qnext_l, ncnt_l,
+                    (seen_l, jnp.int32(0)), tbuf_l,
                     tcount0[0], jnp.int32(0), jnp.int32(0), jnp.int32(0),
                     jnp.bool_(False), jnp.zeros((sw,), jnp.uint8),
                     jnp.bool_(False), jnp.int32(-1),
@@ -380,7 +394,7 @@ class MeshBFSEngine:
                     jnp.zeros((len(dims.family_sizes),), _I32))
 
             def cond(c):
-                (offset, steps, _qn, ncnt_c, seen_c, _tb, tcnt_c,
+                (offset, steps, _qn, ncnt_c, (seen_c, _win), _tb, tcnt_c,
                  _g, _n, ovfc, dead_any, _dr, viol_any, _vi, _vr, _vh,
                  _vl, fail_any, _fam, _famn, _exp, _famp) = c
                 # Every term is reduced to a REPLICATED bool so all chips
@@ -399,15 +413,15 @@ class MeshBFSEngine:
 
             out = jax.lax.while_loop(
                 cond, lambda c: chunk_body(qcur_l, cnt_l, c), init)
-            (offset, steps, qnext_l, ncnt_l, seen_l, tbuf_l, tcnt_l,
-             gen, newc, ovfc, dead_any, drow, viol_any, vinv, vrow,
+            (offset, steps, qnext_l, ncnt_l, (seen_l, windows), tbuf_l,
+             tcnt_l, gen, newc, ovfc, dead_any, drow, viol_any, vinv, vrow,
              vhi, vlo, fail_any, fam_counts, fam_new, expanded,
              fam_pruned) = out
             with jax.named_scope("agree"):
                 stats, drow_g, vrow_g, vhi_g, vlo_g = agreed_stats(
                     offset, steps, gen, newc, ovfc, fail_any, max_count,
-                    ncnt_l, cnt_l, seen_l, viol_any, vinv, vrow, vhi, vlo,
-                    dead_any, drow, expanded, fam_counts, fam_new,
+                    ncnt_l, cnt_l, seen_l, windows, viol_any, vinv, vrow, vhi,
+                    vlo, dead_any, drow, expanded, fam_counts, fam_new,
                     fam_pruned)
             vfp_g = jnp.stack([vhi_g, vlo_g])
             return (qnext_l[None], ncnt_l[None], seen_l.hi[None],
@@ -840,11 +854,13 @@ class MeshBFSEngine:
         mt, evlog = self.metrics, self._evlog
         # What run_end adds for the mesh (``_run_end_extra``), gathered
         # from the chips by the chunk program itself: parents each chip
-        # expanded over the run; each chip's next-level rows and shard
-        # keys as the last chunk call left them.
+        # expanded and windows its owner insert ran (``route_insert``:
+        # one a pass where K lanes hold a pass's arrivals) over the run;
+        # each chip's next-level rows and shard keys as the last chunk
+        # call left them.
         self._mesh_counts = {
             "chip_parents_expanded": [0] * n, "chip_next_count": [0] * n,
-            "chip_shard_keys": [0] * n}
+            "chip_shard_keys": [0] * n, "chip_insert_windows": [0] * n}
         self._growth_stalls = res.growth_stalls
         # TLC-style per-action coverage (obs/coverage.py); stats are
         # psum-replicated, so every controller accumulates identical
@@ -1472,12 +1488,12 @@ class MeshBFSEngine:
     def _count_per_chip(self, st) -> None:
         """One chunk call's share of the per-chip counts: the tail of
         the statistics just fetched is each chip's own (parents
-        expanded, next-level rows, shard keys)."""
+        expanded, next-level rows, shard keys, insert windows run)."""
         n, mc = self.n_dev, self._mesh_counts
-        per_chip = np.asarray(st[len(st) - 3 * n:]).reshape(3, n)
-        mc["chip_parents_expanded"] = [
-            a + int(b)
-            for a, b in zip(mc["chip_parents_expanded"], per_chip[0])]
+        per_chip = np.asarray(st[len(st) - 4 * n:]).reshape(4, n)
+        for key, row in (("chip_parents_expanded", per_chip[0]),
+                         ("chip_insert_windows", per_chip[3])):
+            mc[key] = [a + int(b) for a, b in zip(mc[key], row)]
         mc["chip_next_count"] = [int(v) for v in per_chip[1]]
         mc["chip_shard_keys"] = [int(v) for v in per_chip[2]]
 

@@ -317,3 +317,176 @@ def test_one_mesh_chunk_call_records_the_oracles_transitions(
     the first parent in frontier order need not win here."""
     want, eng, _rows, cols = one_mesh_chunk_call
     want.check_records(eng, cols, first_parent_wins=False)
+
+
+# -- the owner's windowed insert against fpset.insert -----------------------
+
+OWNER_K = 64             # lanes a source chip sends an owner: the window
+OWNER_SLOTS = 1 << 12    # the owner's shard
+
+
+def _owner_cases(n):
+    """name -> (arrivals [n, OWNER_K] as (hi, lo) with SENTINEL padding,
+    keys the shard holds before, lanes that must carry the bit or None).
+    A block is rank-packed at its front, as ``route_insert`` builds it."""
+    import numpy as np
+    from raft_tla_tpu.ops.fingerprint import SENTINEL
+    rng = np.random.default_rng(32)
+    k = OWNER_K
+
+    def keys(count):
+        return (rng.integers(0, 1 << 32, count, dtype=np.uint64),
+                rng.integers(0, 1 << 31, count, dtype=np.uint64))
+
+    def blocks(per_block, khi=None, klo=None):
+        hi = np.full((n, k), SENTINEL, np.uint64)
+        lo = np.full((n, k), SENTINEL, np.uint64)
+        if khi is None:
+            khi, klo = keys(sum(per_block))
+        at = 0
+        for b, c in enumerate(per_block):
+            hi[b, :c], lo[b, :c] = khi[at:at + c], klo[at:at + c]
+            at += c
+        return hi.astype(np.uint32), lo.astype(np.uint32)
+
+    def spread(total):
+        return [total // n + (b < total % n) for b in range(n)]
+
+    none = (np.zeros(0, np.uint32),) * 2
+    cases = {
+        "no_arrival": (blocks([0] * n), none, None),
+        "typical_pass": (blocks(spread(k - 7)), none, None),
+        "exactly_m": (blocks(spread(k)), none, None),
+        "m_plus_one": (blocks(spread(k + 1)), none, None),
+        "every_lane_valid": (blocks([k] * n), none, None),
+    }
+    # One key from three source chips, twice from the lowest of them.
+    hi, lo = blocks(spread(k - 7))
+    for b, lane in ((1, 0), (1, 3), (4, 2), (6, 5)):
+        hi[b, lane], lo[b, lane] = 0xC0FFEE, 0xFACADE
+    cases["one_key_from_three_chips"] = ((hi, lo), none, [1 * k + 0])
+    # A shard 45 % full that holds half of what arrives.
+    phi, plo = keys(int(0.45 * OWNER_SLOTS))
+    ahi, alo = keys(k - 7)
+    ahi[::2], alo[::2] = phi[:len(ahi[::2])], plo[:len(ahi[::2])]
+    pre = (phi.astype(np.uint32), plo.astype(np.uint32))
+    cases["table_45_percent_full"] = (
+        blocks(spread(k - 7), ahi, alo), pre, None)
+    # Two windows over a loaded shard, a key on both sides of the cut.
+    ahi, alo = keys(2 * k)
+    ahi[k:k + 9], alo[k:k + 9] = ahi[:9], alo[:9]
+    ahi[9:30], alo[9:30] = phi[9:30], plo[9:30]
+    cases["two_windows_table_loaded"] = (
+        blocks(spread(2 * k), ahi, alo), pre, None)
+    return cases
+
+
+@pytest.fixture(scope="module")
+def owner_inserts():
+    """Every case on a chip of its own, in ONE program over the 8
+    devices: ``fpset.insert_windowed`` as ``route_insert``'s
+    ``owner_insert`` calls it (the flattened blocks, window = a block's
+    lanes), each chip at its own trip count, beside ``fpset.insert`` on
+    the same arrivals into the same shard."""
+    import numpy as np
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, PartitionSpec as P
+    from raft_tla_tpu.ops import fpset
+    from raft_tla_tpu.ops.fingerprint import SENTINEL
+    devices = jax.devices()
+    n = len(devices)
+    cases = _owner_cases(n)
+    assert len(cases) == n
+
+    def preloaded(khi, klo):
+        s = fpset.from_host_keys(khi, klo, OWNER_SLOTS, chunk=OWNER_SLOTS)
+        assert int(s.size) == len(khi)
+        return np.asarray(s.hi), np.asarray(s.lo), np.int32(len(khi))
+
+    tables = [preloaded(*pre) for _arr, pre, _bit in cases.values()]
+    thi, tlo, tsize = (np.stack(col) for col in zip(*tables))
+    qhi = np.stack([arr[0].reshape(-1) for arr, _p, _b in cases.values()])
+    qlo = np.stack([arr[1].reshape(-1) for arr, _p, _b in cases.values()])
+
+    def both(thi, tlo, tsize, qhi, qlo):
+        s = fpset.FPSet(hi=thi[0], lo=tlo[0], size=tsize[0])
+        rh, rl = qhi[0], qlo[0]
+        rvalid = ~((rh == SENTINEL) & (rl == SENTINEL))
+        ws, wnew, wfail, windows = fpset.insert_windowed(
+            s, rh, rl, rvalid, OWNER_K)
+        ps, pnew, pfail = fpset.insert(s, rh, rl, rvalid)
+        return tuple(x[None] for x in (
+            ws.hi, ws.lo, ws.size, wnew, wfail, windows,
+            ps.hi, ps.lo, ps.size, pnew, pfail))
+
+    sx = P("x")
+    out = jax.jit(jax.shard_map(
+        both, mesh=Mesh(np.asarray(devices), ("x",)), in_specs=(sx,) * 5,
+        out_specs=(sx,) * 11, check_vma=False))(
+            *(jnp.asarray(a) for a in (thi, tlo, tsize, qhi, qlo)))
+    out = [np.asarray(a) for a in out]
+    return {name: (case, [a[d] for a in out])
+            for d, (name, case) in enumerate(cases.items())}
+
+
+@pytest.mark.parametrize("name", [
+    "no_arrival", "typical_pass", "exactly_m", "m_plus_one",
+    "every_lane_valid", "one_key_from_three_chips",
+    "table_45_percent_full", "two_windows_table_loaded"])
+def test_owner_windowed_insert_equals_insert_on_the_padded_block(
+        owner_inserts, name):
+    """Novelty bits lane for lane, the shard's key set, ``size`` and
+    ``fail`` are ``fpset.insert``'s on all n x K lanes; the windows run
+    are the arrived queries' share of K-lane windows, rounded up."""
+    import numpy as np
+    from raft_tla_tpu.ops.fingerprint import SENTINEL
+    ((ahi, alo), pre, bit_lanes), got = owner_inserts[name]
+    (whi, wlo, wsize, wnew, wfail, windows,
+     phi, plo, psize, pnew, pfail) = got
+
+    def key_set(hi, lo):
+        real = ~((hi == SENTINEL) & (lo == SENTINEL))
+        return set(zip(hi[real].tolist(), lo[real].tolist()))
+
+    assert np.array_equal(wnew, pnew)
+    assert key_set(whi, wlo) == key_set(phi, plo)
+    assert len(key_set(whi, wlo)) == wsize == psize
+    assert not wfail and not pfail
+    valid = int(np.sum(~((ahi == SENTINEL) & (alo == SENTINEL))))
+    assert windows == -(-valid // OWNER_K)
+    # What insert itself is held to, so that equal is not equally wrong.
+    arrived = key_set(ahi.reshape(-1), alo.reshape(-1))
+    held = key_set(*pre)
+    assert int(wnew.sum()) == len(arrived - held) == wsize - len(held)
+    assert key_set(whi, wlo) == arrived | held
+    if bit_lanes is not None:
+        dup = (ahi.reshape(-1) == 0xC0FFEE) & (alo.reshape(-1) == 0xFACADE)
+        assert np.flatnonzero(dup & wnew).tolist() == bit_lanes
+        assert int(dup.sum()) == 4
+
+
+def _run_end(engine_cls, tmp_path, **kw):
+    import json
+    events = str(tmp_path / f"{engine_cls.__name__}.jsonl")
+    eng = engine_cls(
+        DIMS, constraint=build_constraint(DIMS, BOUNDS),
+        config=small_mesh_config(max_diameter=4, events_out=events), **kw)
+    eng.run([init_state(DIMS)])
+    with open(events, encoding="utf-8") as f:
+        end = [json.loads(line) for line in f][-1]
+    assert end["event"] == "run_end"
+    return end
+
+
+def test_run_end_carries_the_insert_windows_of_every_chip(tmp_path):
+    """``chip_insert_windows``: one entry a chip, the windows its owner
+    dedup ran over the run's passes — one in every pass that brought it a
+    query (a pass of this run sends a chip far fewer than K), none in
+    the others; the one-chip engine has no such count."""
+    end = _run_end(MeshBFSEngine, tmp_path, devices=jax.devices()[:4])
+    windows = end["chip_insert_windows"]
+    assert len(windows) == 4 == len(end["chip_shard_keys"])
+    assert all(0 < w <= end["passes"] for w in windows)
+    # Every pass generated a candidate, and some chip owns it.
+    assert sum(windows) >= end["passes"] > 0
+    assert "chip_insert_windows" not in _run_end(BFSEngine, tmp_path)
