@@ -60,18 +60,13 @@ def _swarm_bench(setup, platform: str) -> None:
     import jax
 
     from raft_tla_tpu.engine.check import (initial_states,
-                                           resolve_constraint,
-                                           resolve_invariants)
-    from raft_tla_tpu.engine.swarm import SwarmEngine
+                                           make_swarm_engine)
 
-    walks = int(os.environ.get("BENCH_WALKS", "1024"))
-    max_depth = int(os.environ.get("BENCH_MAX_DEPTH", "64"))
-    ring = int(os.environ.get("BENCH_RING", "16"))
-    chunk = int(os.environ.get("BENCH_CHUNK", "32"))
+    def env_int(name):
+        return int(os.environ[name]) if os.environ.get(name) else None
+
     seed = int(os.environ.get("BENCH_SEED", "0"))
-    num_steps = (int(os.environ["BENCH_NUM_STEPS"])
-                 if os.environ.get("BENCH_NUM_STEPS") else None)
-    batch = int(os.environ.get("BENCH_BATCH", str(walks)))
+    num_steps = env_int("BENCH_NUM_STEPS")
     events_file = os.environ.get("BENCH_EVENTS_OUT")
     scratch_dir = None
     if events_file is None:
@@ -82,14 +77,20 @@ def _swarm_bench(setup, platform: str) -> None:
     # walk-kernel stage-sampling cadence (0 = off).
     perf_on = bool(int(os.environ.get("BENCH_PERF", "1")))
     profile_every = int(os.environ.get("BENCH_PROFILE_CHUNKS", "64"))
-    eng = SwarmEngine(setup.dims,
-                      invariants=resolve_invariants(setup),
-                      constraint=resolve_constraint(setup),
-                      walks=walks, max_depth=max_depth,
-                      batch=min(batch, walks), chunk=chunk, ring=ring,
-                      pipeline=os.environ.get("BENCH_PIPELINE", "auto"),
-                      events_out=events_file, perf=perf_on,
-                      profile_chunks_every=profile_every)
+    # Walks, depth, slice width and pipeline as ``check --mode swarm``
+    # resolves them (engine/check.py make_swarm_engine); a knob left
+    # unset is the cfg's or the engine's own default.
+    shape = {k: v for k, v in (("ring", env_int("BENCH_RING")),
+                               ("chunk", env_int("BENCH_CHUNK")))
+             if v is not None}
+    eng = make_swarm_engine(
+        setup, walks=env_int("BENCH_WALKS"),
+        max_depth=env_int("BENCH_MAX_DEPTH"),
+        batch=env_int("BENCH_BATCH"),
+        pipeline=os.environ.get("BENCH_PIPELINE"),
+        events_out=events_file, perf=perf_on,
+        profile_chunks_every=profile_every, **shape)
+    walks, max_depth, ring = eng.walks, eng.max_depth, eng.ring
     _mark(f"swarm engine built (walks={walks}, depth={max_depth}, "
           f"ring={ring}); compiling + running "
           + (f"{num_steps} steps" if num_steps is not None

@@ -272,20 +272,15 @@ def phase_counterexample() -> None:
 
 def phase_swarm() -> None:
     from raft_tla_tpu.engine.check import (initial_states,
-                                           resolve_constraint,
-                                           resolve_invariants)
-    from raft_tla_tpu.engine.swarm import SwarmEngine
+                                           make_swarm_engine)
     from raft_tla_tpu.models.dims import LEADER
     from raft_tla_tpu.utils.cfg import load_config
     setup = load_config(NOLEADER_CFG)
     seed, walks = 7, 1024
     t0 = time.perf_counter()
     # check --mode swarm --walks 1024 --max-depth 64, as cli._run_swarm
-    # builds it (batch: the cfg's BATCH directive capped at the walks).
-    eng = SwarmEngine(setup.dims, invariants=resolve_invariants(setup),
-                      constraint=resolve_constraint(setup), walks=walks,
-                      max_depth=64,
-                      batch=min(setup.backend.get("BATCH", 1024), walks))
+    # builds it (the slice width: the cfg's BATCH directive).
+    eng = make_swarm_engine(setup, walks=walks, max_depth=64)
     res = eng.run(initial_states(setup, seed=seed), seed=seed,
                   max_seconds=300.0)
     assert res.violation is not None, (

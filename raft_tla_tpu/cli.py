@@ -286,26 +286,18 @@ def _client_call(target: str, req: dict, timeout: float) -> dict:
     return json.loads(line)
 
 
-def _run_swarm(args, setup, resolve, batch) -> int:
+def _run_swarm(args, setup, resolve) -> int:
     """``check --mode swarm``: the randomized-walk tier
     (engine/swarm.py).  Same surface contract as the exhaustive
     branch: a summary line, an optional history-ledger entry
     (``kind=swarm``), and on a violation the rendered TLC-style
     counterexample plus exit 1."""
-    from .engine.check import (initial_states, resolve_constraint,
-                               resolve_invariants)
-    from .engine.swarm import SwarmEngine
+    from .engine.check import initial_states, make_swarm_engine
 
-    walks = int(resolve(args.walks, "WALKS", 1024))
     ckpt = resolve(args.checkpoint_dir, "CHECKPOINT_DIR", None)
-    engine = SwarmEngine(
-        setup.dims,
-        invariants=resolve_invariants(setup),
-        constraint=resolve_constraint(setup),
-        walks=walks,
-        max_depth=args.max_depth or setup.max_diameter or 128,
-        batch=min(batch, walks),
-        pipeline=resolve(args.pipeline, "PIPELINE", "auto"),
+    engine = make_swarm_engine(
+        setup, walks=args.walks, max_depth=args.max_depth,
+        batch=args.batch, pipeline=args.pipeline,
         events_out=resolve(args.events_out, "EVENTS_OUT", None),
         checkpoint_dir=ckpt,
         counterexample_dir=(
@@ -1260,7 +1252,7 @@ def main(argv=None):
         if mode not in ("exhaustive", "swarm"):
             p.error(f"MODE must be exhaustive or swarm, got {mode!r}")
         if mode == "swarm":
-            return _run_swarm(args, setup, resolve, batch)
+            return _run_swarm(args, setup, resolve)
         cfgobj = EngineConfig(
             batch=batch,
             queue_capacity=resolve(args.queue_capacity,

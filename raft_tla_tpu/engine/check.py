@@ -122,6 +122,50 @@ def make_engine(setup: CheckSetup,
                constraint=resolve_constraint(setup), config=cfg)
 
 
+def swarm_slice_width(walks: int, hunt: bool = True) -> int:
+    """Lanes of one dispatch of the walk chunk where neither the caller
+    nor the cfg's ``BATCH`` says: all ``walks`` in one slice, but with
+    the hunt observatory on no more than keeps its (lanes x lanes)
+    same-fingerprint prior, four bytes a cell with its temporaries,
+    inside a sixteenth of the device's memory (16,384 lanes on a 16 GB
+    chip).  A power of two, so the last slice is the only narrow one."""
+    import jax
+    if not hunt:
+        return walks
+    stats = jax.devices()[0].memory_stats() or {}
+    budget = int(stats.get("bytes_limit", 16 << 30)) // 16
+    lanes = 1 << (max(int((budget // 4) ** 0.5), 1).bit_length() - 1)
+    return min(walks, lanes)
+
+
+def make_swarm_engine(setup: CheckSetup, *, walks: Optional[int] = None,
+                      max_depth: Optional[int] = None,
+                      batch: Optional[int] = None,
+                      pipeline: Optional[str] = None, **engine_kwargs):
+    """Build the swarm tier's engine (engine/swarm.py) with the cfg-file
+    fallbacks applied, so every entry point — ``check --mode swarm``,
+    the server's swarm branch, ``bench.py``'s ``BENCH_MODE=swarm``, the
+    benchmark — resolves it identically: each of ``walks`` (else the
+    cfg's ``WALKS``, else 1024), ``max_depth`` (else the companion
+    module's depth budget, else 128), ``batch`` (the slice width: else
+    the cfg's ``BATCH``, else ``swarm_slice_width``) and ``pipeline``
+    (else ``PIPELINE``, else auto) is the caller's where given.
+    ``engine_kwargs`` go to ``SwarmEngine`` as they are."""
+    from .swarm import SwarmEngine
+    be = setup.backend
+    walks = int(walks if walks is not None else be.get("WALKS", 1024))
+    if batch is None:
+        batch = be.get("BATCH")
+    if batch is None:
+        batch = swarm_slice_width(walks, engine_kwargs.get("hunt", True))
+    return SwarmEngine(
+        setup.dims, invariants=resolve_invariants(setup),
+        constraint=resolve_constraint(setup), walks=walks,
+        max_depth=int(max_depth or setup.max_diameter or 128),
+        batch=min(int(batch), walks),
+        pipeline=pipeline or be.get("PIPELINE", "auto"), **engine_kwargs)
+
+
 def initial_states(setup: CheckSetup, seed: int = 0) -> List[PyState]:
     if setup.smoke:
         return smoke.smoke_init_states(setup.dims, k=setup.smoke_k,

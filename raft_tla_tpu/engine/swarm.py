@@ -48,6 +48,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from ..models.actions import build_expand
 from ..models.dims import RaftDims
@@ -56,19 +57,47 @@ from ..models.pystate import PyState
 from ..models.schema import (StateBatch, build_pack_guard, check_packable,
                              decode_state, encode_state, flatten_state,
                              stack_states, unflatten_state)
-from ..obs import (MetricsRegistry, RunEventLog, device_memory_stats,
-                   events_path, phase_delta)
+from ..obs import (MetricsRegistry, RunEventLog, SpanTracer,
+                   device_memory_stats, events_path, phase_delta)
 from ..obs.flight import RECORDER as _FLIGHT
+from ..obs.metrics import SCOPE_PREFIX
 from ..ops.fingerprint import build_fingerprint
 from ..ops.walk_kernels import (CHOICE_STREAM, FAMILY_STREAM, INIT_STREAM,
                                 ROOT_STREAM, bloom_init, bloom_probe,
                                 bloom_push, family_subset, preferred_choice,
                                 ring_init, ring_probe, ring_push, ring_reset,
                                 walk_bits)
-from .bfs import Violation, _resolve_pipeline
+from .bfs import (Violation, _resolve_pipeline, compiles_by_span,
+                  watch_compiles)
 
 _I32 = jnp.int32
 _U32 = jnp.uint32
+
+# The stages of one lockstep step, in order, as they are named in the
+# compiled walk chunk (named scopes, as engine/chunk.py STAGES): the
+# guards of every instance (``masks``), the counter-hash draw among the
+# enabled ones (``choose``), the chosen successor and its packed row
+# (``lane_out``), its key (``fingerprint``), the invariants and the
+# first-violation latch (``latch``), the constraint, the ring probe and
+# push, the action record and the restart (``ring``), the observatory's
+# tallies (``hunt``).  Scope names are not in the compile-cache key
+# (chunk.py STAGES_TAG rationale), so the walk chunk carries a tag of its
+# own: change it with these names, and the BFS and mesh programs keep
+# their keys.
+WALK_STAGES = ("masks", "choose", "lane_out", "fingerprint", "latch",
+               "ring", "hunt")
+WALK_STAGES_TAG = "w1"
+
+# What the host loop counts where it does the work: ``run_end`` carries a
+# run's own, the registry ``swarm/<name>`` the process's sum.
+# ``chunk_calls`` dispatches of the walk chunk (one a slice a round),
+# ``slices`` the run's slices, ``fetches`` blocking device-to-host copies
+# the loop made, ``steps`` lockstep walk-steps, ``latch_step`` the global
+# step of the reported violation (-1: none), ``steps_past_latch`` the
+# walk-steps computed at or after it, ``restarts`` traces begun after the
+# first W, ``reconstruct_steps`` expand round trips of the replay.
+SWARM_COUNTERS = ("chunk_calls", "slices", "fetches", "steps", "latch_step",
+                  "steps_past_latch", "restarts", "reconstruct_steps")
 
 
 @dataclasses.dataclass
@@ -97,6 +126,10 @@ class SwarmResult:
     #: Wall-clock seconds into the run when the violation latched — the
     #: swarm's headline "time to first counterexample" metric.
     violation_at_seconds: Optional[float] = None
+    #: The latched violation's lockstep step and walker: the first in
+    #: (step, walk) order over all W walkers, whatever the slicing.
+    violation_step: Optional[int] = None
+    violation_walk: Optional[int] = None
     counterexample: Dict = dataclasses.field(default_factory=dict)
     #: Performance observatory block (obs/perf.py; ``perf=True``) —
     #: same shape as ``EngineResult.perf``.
@@ -135,7 +168,8 @@ def build_swarm_chunk(dims: RaftDims, inv_fns, constraint, D: int, R: int,
     Steps at or past ``k_limit`` are frozen no-ops (carry unchanged,
     nothing accepted, nothing latched): the host can run an exact
     ``num_steps`` budget in chunk-sized dispatches without a remainder
-    recompile.
+    recompile.  Every operation of a step runs under one of the named
+    scopes ``WALK_STAGES``, which change no bit.
 
     With ``hunt=True`` (the hunt observatory, obs/hunt.py) the
     signature grows two trailing args ``(bloom1, bloom2)`` — the
@@ -182,135 +216,149 @@ def build_swarm_chunk(dims: RaftDims, inv_fns, constraint, D: int, R: int,
             (rows, tstep, cur_root, abuf, rh, rl, rp, epoch, restarts,
              visited, depth_max, latch) = carry[:12]
             act = k < k_limit
-            states = jax.vmap(unflatten_state, (0, None))(rows, dims)
-            if v2 is None:
-                cands, en, ovf = jax.vmap(expand)(states)
-                # uint8-row wrap counts as overflow (simulator rule):
-                # restart rather than step through an aliased row.
-                ovf = ovf | (en & ~jax.vmap(jax.vmap(pack_ok))(cands))
-            else:
-                en, ovf = jax.vmap(v2.masks)(states)  # pack guard folded
-            bits = walk_bits(seed, walk_ids, k, CHOICE_STREAM)
-            mbits = walk_bits(seed, walk_ids, epoch, FAMILY_STREAM)
-            choice = preferred_choice(bits, en, family_subset(mbits, fam))
-            can_step = jnp.any(en, axis=1) & act
-            if v2 is None:
-                nxt = jax.tree.map(lambda a: a[lanes, choice], cands)
-            else:
-                ph = jax.vmap(v2.parent_hash)(states)  # DCE'd: unused
-                _h, _l, nxt = jax.vmap(v2.lane_out)(states, ph,
-                                                    choice.astype(_I32))
-            nrows = jax.vmap(flatten_state, (0, None))(nxt, dims)
-            fp_hi, fp_lo = jax.vmap(fingerprint)(nxt)
+            with jax.named_scope("masks"):
+                states = jax.vmap(unflatten_state, (0, None))(rows, dims)
+                if v2 is None:
+                    cands, en, ovf = jax.vmap(expand)(states)
+                    # uint8-row wrap counts as overflow (simulator rule):
+                    # restart rather than step through an aliased row.
+                    ovf = ovf | (en & ~jax.vmap(jax.vmap(pack_ok))(cands))
+                else:
+                    en, ovf = jax.vmap(v2.masks)(states)  # pack guard folded
+            with jax.named_scope("choose"):
+                bits = walk_bits(seed, walk_ids, k, CHOICE_STREAM)
+                mbits = walk_bits(seed, walk_ids, epoch, FAMILY_STREAM)
+                choice = preferred_choice(bits, en,
+                                          family_subset(mbits, fam))
+                can_step = jnp.any(en, axis=1) & act
+            with jax.named_scope("lane_out"):
+                if v2 is None:
+                    nxt = jax.tree.map(lambda a: a[lanes, choice], cands)
+                else:
+                    ph = jax.vmap(v2.parent_hash)(states)  # DCE'd: unused
+                    _h, _l, nxt = jax.vmap(v2.lane_out)(
+                        states, ph, choice.astype(_I32))
+                nrows = jax.vmap(flatten_state, (0, None))(nxt, dims)
+            with jax.named_scope("fingerprint"):
+                fp_hi, fp_lo = jax.vmap(fingerprint)(nxt)
 
-            if inv_fns:
-                inv = jax.vmap(inv_id)(nxt)
-            else:
-                inv = jnp.full((B,), -1, _I32)
-            bad = can_step & (inv >= 0)
-            # Latch the slice's FIRST violation: first step with any bad
-            # lane, lowest lane at that step.  The step index rides
-            # along so the host can pick the global (step, walk) minimum
-            # across slices — the partition-invariant verdict.
-            (vf, vinv, vroot, vlen, vacts, vchoice,
-             vwalk, vstep, vhi, vlo) = latch
-            any_new = jnp.any(bad) & ~vf
-            w = jnp.argmax(bad)
-            latch = (vf | jnp.any(bad),
-                     jnp.where(any_new, inv[w], vinv),
-                     jnp.where(any_new, cur_root[w], vroot),
-                     jnp.where(any_new, tstep[w], vlen),
-                     jnp.where(any_new, abuf[w], vacts),
-                     jnp.where(any_new, choice[w].astype(_I32), vchoice),
-                     jnp.where(any_new, walk_ids[w].astype(_I32), vwalk),
-                     jnp.where(any_new, k.astype(_I32), vstep),
-                     jnp.where(any_new, fp_hi[w], vhi),
-                     jnp.where(any_new, fp_lo[w], vlo))
+            with jax.named_scope("latch"):
+                if inv_fns:
+                    inv = jax.vmap(inv_id)(nxt)
+                else:
+                    inv = jnp.full((B,), -1, _I32)
+                bad = can_step & (inv >= 0)
+                # Latch the slice's FIRST violation: first step with any
+                # bad lane, lowest lane at that step.  The step index
+                # rides along so the host can pick the global (step,
+                # walk) minimum across slices — the partition-invariant
+                # verdict.
+                (vf, vinv, vroot, vlen, vacts, vchoice,
+                 vwalk, vstep, vhi, vlo) = latch
+                any_new = jnp.any(bad) & ~vf
+                w = jnp.argmax(bad)
+                latch = (vf | jnp.any(bad),
+                         jnp.where(any_new, inv[w], vinv),
+                         jnp.where(any_new, cur_root[w], vroot),
+                         jnp.where(any_new, tstep[w], vlen),
+                         jnp.where(any_new, abuf[w], vacts),
+                         jnp.where(any_new, choice[w].astype(_I32),
+                                   vchoice),
+                         jnp.where(any_new, walk_ids[w].astype(_I32),
+                                   vwalk),
+                         jnp.where(any_new, k.astype(_I32), vstep),
+                         jnp.where(any_new, fp_hi[w], vhi),
+                         jnp.where(any_new, fp_lo[w], vlo))
 
-            if constraint is not None:
-                cons_ok = jax.vmap(constraint)(nxt)
-            else:
-                cons_ok = jnp.ones((B,), bool)
-            seen = ring_probe(rh, rl, fp_hi, fp_lo)
-            accept = (can_step & ~jnp.any(ovf, axis=1) & cons_ok & ~seen)
-            # Record the action taken since the last restart (before the
-            # restart decision, mirroring the simulator's abuf contract).
-            abuf = abuf.at[lanes, jnp.clip(tstep, 0, D - 1)].set(
-                jnp.where(can_step, choice.astype(_I32), -1))
-            rh, rl, rp = ring_push(rh, rl, rp, fp_hi, fp_lo, accept)
-            # Restart on: dead end, overflow, constraint stop, ring
-            # revisit (all folded into ~accept) or the depth bound.
-            restart = (~accept | (tstep + 1 >= D)) & act
+            with jax.named_scope("ring"):
+                if constraint is not None:
+                    cons_ok = jax.vmap(constraint)(nxt)
+                else:
+                    cons_ok = jnp.ones((B,), bool)
+                seen = ring_probe(rh, rl, fp_hi, fp_lo)
+                accept = (can_step & ~jnp.any(ovf, axis=1) & cons_ok
+                          & ~seen)
+                # Record the action taken since the last restart (before
+                # the restart decision, mirroring the simulator's abuf
+                # contract).
+                abuf = abuf.at[lanes, jnp.clip(tstep, 0, D - 1)].set(
+                    jnp.where(can_step, choice.astype(_I32), -1))
+                rh, rl, rp = ring_push(rh, rl, rp, fp_hi, fp_lo, accept)
+                # Restart on: dead end, overflow, constraint stop, ring
+                # revisit (all folded into ~accept) or the depth bound.
+                restart = (~accept | (tstep + 1 >= D)) & act
 
             if hunt:
-                # Hunt observatory tallies — every value below is
-                # derived from the decisions already made above and
-                # feeds NOTHING back into them (the on/off bit-identity
-                # contract).  Species accounting: the two persistent
-                # Bloom filters give each accepted visit's prior
-                # observation count (capped at 2), exact within this
-                # dispatch via the same-fingerprint prior count over
-                # earlier lanes of the same step.
-                (b1, b2, fresh_t, promote_t, revisit_t, dead_t, povf_t,
-                 cons_t, dbound_t, dhist, fch, fac, ffr) = carry[12]
-                in1 = bloom_probe(b1, fp_hi, fp_lo)
-                in2 = bloom_probe(b2, fp_hi, fp_lo)
-                eqm = ((fp_hi[:, None] == fp_hi[None, :])
-                       & (fp_lo[:, None] == fp_lo[None, :])
-                       & accept[None, :])
-                prior = jnp.sum(jnp.tril(eqm, -1), axis=1, dtype=_I32)
-                nobs = in1.astype(_I32) + in2.astype(_I32) + prior
-                fresh = accept & (nobs == 0)
-                promote = accept & (nobs == 1)
-                b1 = bloom_push(b1, fp_hi, fp_lo, accept)
-                b2 = bloom_push(b2, fp_hi, fp_lo, accept & (nobs >= 1))
-                # Restart-reason census, in the engine's decision order
-                # (the first failing rule owns the restart): together
-                # with the depth bound these partition ``restart``.
-                anyovf = jnp.any(ovf, axis=1)
-                deadend = ~can_step & act
-                ovfstop = can_step & anyovf
-                consstop = can_step & ~anyovf & ~cons_ok
-                revisit = can_step & ~anyovf & cons_ok & seen
-                dbound = accept & (tstep + 1 >= D)
-                # Final depth of each completed trace (masked lanes
-                # contribute an add of 0 — scatter-add, never a branch).
-                dfin = jnp.clip(jnp.where(accept, tstep + 1, tstep),
-                                0, D)
-                dhist = dhist.at[dfin].add(restart.astype(_I32))
-                # Per-family efficacy: which diversification families
-                # get chosen, land accepted states, and find FRESH ones.
-                fidx = fam[choice]
-                fch = fch.at[fidx].add(can_step.astype(_I32))
-                fac = fac.at[fidx].add(accept.astype(_I32))
-                ffr = ffr.at[fidx].add(fresh.astype(_I32))
-                hcarry = (b1, b2,
-                          fresh_t + jnp.sum(fresh, dtype=_I32),
-                          promote_t + jnp.sum(promote, dtype=_I32),
-                          revisit_t + jnp.sum(revisit, dtype=_I32),
-                          dead_t + jnp.sum(deadend, dtype=_I32),
-                          povf_t + jnp.sum(ovfstop, dtype=_I32),
-                          cons_t + jnp.sum(consstop, dtype=_I32),
-                          dbound_t + jnp.sum(dbound, dtype=_I32),
-                          dhist, fch, fac, ffr)
-                hys = (jnp.sum(fresh, dtype=_I32),
-                       jnp.sum(accept, dtype=_I32))
-            root_idx = (walk_bits(seed, walk_ids, k, ROOT_STREAM)
-                        % _U32(roots.shape[0])).astype(_I32)
-            rows = jnp.where(restart[:, None], roots[root_idx],
-                             jnp.where(accept[:, None], nrows, rows))
-            cur_root = jnp.where(restart, root_idx, cur_root)
-            rh, rl, rp = ring_reset(rh, rl, rp, restart)
-            depth_max = jnp.maximum(
-                depth_max, jnp.max(jnp.where(accept, tstep + 1, 0)))
-            tstep = jnp.where(restart, 0,
-                              jnp.where(accept, tstep + 1, tstep))
-            # A restart begins the walk's next trace: bump its epoch so
-            # the FAMILY_STREAM mask re-draws — every trace hunts a
-            # fresh random sub-model.
-            epoch = epoch + restart.astype(_I32)
-            restarts = restarts + jnp.sum(restart, dtype=_I32)
-            visited = visited + jnp.sum(accept, dtype=_I32)
+                with jax.named_scope("hunt"):
+                    # Hunt observatory tallies — every value below is
+                    # derived from the decisions already made above and
+                    # feeds NOTHING back into them (the on/off bit-identity
+                    # contract).  Species accounting: the two persistent
+                    # Bloom filters give each accepted visit's prior
+                    # observation count (capped at 2), exact within this
+                    # dispatch via the same-fingerprint prior count over
+                    # earlier lanes of the same step.
+                    (b1, b2, fresh_t, promote_t, revisit_t, dead_t, povf_t,
+                     cons_t, dbound_t, dhist, fch, fac, ffr) = carry[12]
+                    in1 = bloom_probe(b1, fp_hi, fp_lo)
+                    in2 = bloom_probe(b2, fp_hi, fp_lo)
+                    eqm = ((fp_hi[:, None] == fp_hi[None, :])
+                           & (fp_lo[:, None] == fp_lo[None, :])
+                           & accept[None, :])
+                    prior = jnp.sum(jnp.tril(eqm, -1), axis=1, dtype=_I32)
+                    nobs = in1.astype(_I32) + in2.astype(_I32) + prior
+                    fresh = accept & (nobs == 0)
+                    promote = accept & (nobs == 1)
+                    b1 = bloom_push(b1, fp_hi, fp_lo, accept)
+                    b2 = bloom_push(b2, fp_hi, fp_lo, accept & (nobs >= 1))
+                    # Restart-reason census, in the engine's decision order
+                    # (the first failing rule owns the restart): together
+                    # with the depth bound these partition ``restart``.
+                    anyovf = jnp.any(ovf, axis=1)
+                    deadend = ~can_step & act
+                    ovfstop = can_step & anyovf
+                    consstop = can_step & ~anyovf & ~cons_ok
+                    revisit = can_step & ~anyovf & cons_ok & seen
+                    dbound = accept & (tstep + 1 >= D)
+                    # Final depth of each completed trace (masked lanes
+                    # contribute an add of 0 — scatter-add, never a branch).
+                    dfin = jnp.clip(jnp.where(accept, tstep + 1, tstep),
+                                    0, D)
+                    dhist = dhist.at[dfin].add(restart.astype(_I32))
+                    # Per-family efficacy: which diversification families
+                    # get chosen, land accepted states, and find FRESH ones.
+                    fidx = fam[choice]
+                    fch = fch.at[fidx].add(can_step.astype(_I32))
+                    fac = fac.at[fidx].add(accept.astype(_I32))
+                    ffr = ffr.at[fidx].add(fresh.astype(_I32))
+                    hcarry = (b1, b2,
+                              fresh_t + jnp.sum(fresh, dtype=_I32),
+                              promote_t + jnp.sum(promote, dtype=_I32),
+                              revisit_t + jnp.sum(revisit, dtype=_I32),
+                              dead_t + jnp.sum(deadend, dtype=_I32),
+                              povf_t + jnp.sum(ovfstop, dtype=_I32),
+                              cons_t + jnp.sum(consstop, dtype=_I32),
+                              dbound_t + jnp.sum(dbound, dtype=_I32),
+                              dhist, fch, fac, ffr)
+                    hys = (jnp.sum(fresh, dtype=_I32),
+                           jnp.sum(accept, dtype=_I32))
+            with jax.named_scope("ring"):
+                root_idx = (walk_bits(seed, walk_ids, k, ROOT_STREAM)
+                            % _U32(roots.shape[0])).astype(_I32)
+                rows = jnp.where(restart[:, None], roots[root_idx],
+                                 jnp.where(accept[:, None], nrows, rows))
+                cur_root = jnp.where(restart, root_idx, cur_root)
+                rh, rl, rp = ring_reset(rh, rl, rp, restart)
+                depth_max = jnp.maximum(
+                    depth_max, jnp.max(jnp.where(accept, tstep + 1, 0)))
+                tstep = jnp.where(restart, 0,
+                                  jnp.where(accept, tstep + 1, tstep))
+                # A restart begins the walk's next trace: bump its epoch
+                # so the FAMILY_STREAM mask re-draws — every trace hunts
+                # a fresh random sub-model.
+                epoch = epoch + restart.astype(_I32)
+                restarts = restarts + jnp.sum(restart, dtype=_I32)
+                visited = visited + jnp.sum(accept, dtype=_I32)
             out = (rows, tstep, cur_root, abuf, rh, rl, rp, epoch,
                    restarts, visited, depth_max, latch)
             if hunt:
@@ -330,6 +378,10 @@ def build_swarm_chunk(dims: RaftDims, inv_fns, constraint, D: int, R: int,
                                 jnp.zeros((n_fam,), _I32),
                                 jnp.zeros((n_fam,), _I32),
                                 jnp.zeros((n_fam,), _I32)),)
+        # WALK_STAGES_TAG on an ``add 0`` XLA folds away: in what jax
+        # hashes for the compile cache, as the stage names are not.
+        with set_xla_metadata(walk_stages_tag=WALK_STAGES_TAG):
+            k0 = k0 + 0
         ks = k0 + jnp.arange(chunk, dtype=_I32)
         return jax.lax.scan(body, carry0, ks)
 
@@ -370,6 +422,15 @@ class SwarmEngine:
             raise ValueError(f"max_depth must be >= 1, got {max_depth}")
         self.dims = dims
         self.metrics = metrics or MetricsRegistry()
+        # Spans, as the BFS engines attach theirs (obs/tracing.py): every
+        # phase_timer and scope of a run is ``raft.<name>`` on the host's
+        # line of a profiler capture; a registry shared with another
+        # engine keeps the tracer it has.
+        if self.metrics.tracer is None:
+            self.metrics.tracer = SpanTracer(
+                None, annotate=jax.profiler.TraceAnnotation)
+        self.tracer = self.metrics.tracer
+        watch_compiles()
         self.inv_names = list((invariants or {}).keys())
         inv_fns = list((invariants or {}).values())
         self.walks, self.max_depth, self.ring = walks, max_depth, ring
@@ -442,6 +503,10 @@ class SwarmEngine:
         self._expand1 = jax.jit(build_expand(dims))
         self._fp1 = jax.jit(build_fingerprint(dims))
         self._last_trace: Optional[List[Tuple[int, PyState]]] = None
+        # The last run's walkers, kept on the device for
+        # ``walk_transcripts``: (roots as given, slices).
+        self._walkers = None
+        self._counts = dict.fromkeys(SWARM_COUNTERS, 0)
 
     def chunk_avals(self, n_roots: int = 2) -> tuple:
         """The walk chunk program's arguments as shapes (one full-width
@@ -504,6 +569,14 @@ class SwarmEngine:
                     self.checkpoint_dir or ".", "xla_profile"),
                 self._xla_chunks)
         t0 = time.time()
+        # One identifier for the spans of one verdict, as the BFS
+        # engines': every span below carries ``run=<n>``.
+        self.tracer.run = self._run_id = getattr(self, "_run_id", 0) + 1
+        run_span = mt.open_span("run", SCOPE_PREFIX, mode="swarm")
+        self._counts = dict.fromkeys(SWARM_COUNTERS, 0)
+        self._counts["latch_step"] = -1
+        self._walkers = None
+        compile_base = compiles_by_span(mt)
         evlog = RunEventLog(events_path(self.events_out,
                                         self.checkpoint_dir))
         phase_base = mt.phase_seconds()
@@ -528,84 +601,107 @@ class SwarmEngine:
             err = e
             raise
         finally:
-            res.wall_seconds = time.time() - t0
-            res.distinct, res.generated = res.visited, res.steps
-            res.phases = phase_delta(mt.phase_seconds(), phase_base)
-            ce_path = None
-            ce_dir = self.counterexample_dir or self.checkpoint_dir
-            if err is None and res.violation is not None and ce_dir:
-                try:
-                    from .explain import write_counterexample
-                    res.counterexample = write_counterexample(
-                        self, res, ce_dir)
-                    ce_path = res.counterexample["txt"]
-                except Exception as e:
-                    import sys as _sys
-                    print(f"counterexample render failed: "
-                          f"{type(e).__name__}: {e}", file=_sys.stderr)
-            # Profiler / perf / device-capture run-end hooks, the BFS
-            # engine's order: the profiler lands its means first (the
-            # roofline's measured half), perf prices them, the capture
-            # window closes whether the run lived or died.
-            if self._profiler is not None:
-                res.chunk_stages = self._profiler.stage_means()
-                self._profiler.finish(evlog)
-            if self._perf is not None and err is None:
-                try:
-                    res.perf = self._perf.finish(
-                        evlog, chunk_stages=res.chunk_stages)
-                except Exception as e:
-                    import sys as _sys
-                    print(f"perf: block assembly failed "
-                          f"({type(e).__name__}: {e})", file=_sys.stderr)
-            if self._xla_capture is not None:
-                self._xla_capture.finish(evlog)
-            # The hunt report (obs/hunt.py): the swarm sibling of the
-            # statespace report, riding the same surfaces — its own
-            # ``hunt`` run event, the report dict, gauges, flight ring.
-            hunt_report = None
-            if self._hunt_acc is not None and err is None:
-                from ..obs import hunt as hunt_mod
-                hunt_report = hunt_mod.build_report(
-                    self._hunt_acc,
-                    violation_at_seconds=res.violation_at_seconds,
-                    wall_seconds=res.wall_seconds)
-                evlog.emit("hunt", hunt=hunt_report)
-                hunt_mod.feed_metrics(hunt_report, mt)
-                _FLIGHT.record("hunt", **self._hunt_acc.snapshot())
-            swarm_block = self._swarm_block(res)
-            if err is None:
-                res.report = {
-                    "collision": {"calculated": 0.0},
-                    "diameter": res.diameter,
-                    "verdict": ("violation" if res.violation is not None
-                                else "ok"),
-                    "levels": [],
-                    "mode": "swarm",
-                    "swarm": swarm_block,
-                }
-                if hunt_report is not None:
-                    res.report["hunt"] = hunt_report
-                evlog.emit("statespace", report=res.report)
-            pm_path = None
-            if err is not None:
-                pm_path = _FLIGHT.dump(
-                    f"swarm run error: {type(err).__name__}: {err}")
-            evlog.emit(
-                "run_end",
-                stop_reason=(res.stop_reason if err is None else "error"),
-                error=(f"{type(err).__name__}: {err}"
-                       if err is not None else None),
-                postmortem_path=pm_path,
-                counterexample_path=ce_path,
-                distinct=res.distinct, generated=res.generated,
-                diameter=res.diameter, levels=[],
-                wall_seconds=res.wall_seconds,
-                phase_seconds=res.phases, swarm=swarm_block,
-                memory=device_memory_stats())
-            _FLIGHT.set_live_evlog(None)
-            _FLIGHT.disarm()
-            evlog.close()
+            try:
+                with mt.phase_timer("run_end"):
+                    self._run_end(res, err, evlog, t0, phase_base,
+                                  compile_base)
+            finally:
+                run_span.close()
+
+    def _run_end(self, res, err, evlog, t0, phase_base, compile_base):
+        """What every run pays after its loop: the counterexample files,
+        the profiler's and the observatory's reports, ``run_end``."""
+        mt = self.metrics
+        res.wall_seconds = time.time() - t0
+        res.distinct, res.generated = res.visited, res.steps
+        res.phases = phase_delta(mt.phase_seconds(), phase_base)
+        ce_path = None
+        ce_dir = self.counterexample_dir or self.checkpoint_dir
+        if err is None and res.violation is not None and ce_dir:
+            try:
+                from .explain import write_counterexample
+                res.counterexample = write_counterexample(
+                    self, res, ce_dir)
+                ce_path = res.counterexample["txt"]
+            except Exception as e:
+                import sys as _sys
+                print(f"counterexample render failed: "
+                      f"{type(e).__name__}: {e}", file=_sys.stderr)
+        # Profiler / perf / device-capture run-end hooks, the BFS
+        # engine's order: the profiler lands its means first (the
+        # roofline's measured half), perf prices them, the capture
+        # window closes whether the run lived or died.
+        if self._profiler is not None:
+            res.chunk_stages = self._profiler.stage_means()
+            self._profiler.finish(evlog)
+        if self._perf is not None and err is None:
+            try:
+                res.perf = self._perf.finish(
+                    evlog, chunk_stages=res.chunk_stages)
+            except Exception as e:
+                import sys as _sys
+                print(f"perf: block assembly failed "
+                      f"({type(e).__name__}: {e})", file=_sys.stderr)
+        if self._xla_capture is not None:
+            self._xla_capture.finish(evlog)
+        # The hunt report (obs/hunt.py): the swarm sibling of the
+        # statespace report, riding the same surfaces — its own
+        # ``hunt`` run event, the report dict, gauges, flight ring.
+        hunt_report = None
+        if self._hunt_acc is not None and err is None:
+            from ..obs import hunt as hunt_mod
+            hunt_report = hunt_mod.build_report(
+                self._hunt_acc,
+                violation_at_seconds=res.violation_at_seconds,
+                wall_seconds=res.wall_seconds)
+            evlog.emit("hunt", hunt=hunt_report)
+            hunt_mod.feed_metrics(hunt_report, mt)
+            _FLIGHT.record("hunt", **self._hunt_acc.snapshot())
+        swarm_block = self._swarm_block(res)
+        if err is None:
+            res.report = {
+                "collision": {"calculated": 0.0},
+                "diameter": res.diameter,
+                "verdict": ("violation" if res.violation is not None
+                            else "ok"),
+                "levels": [],
+                "mode": "swarm",
+                "swarm": swarm_block,
+            }
+            if hunt_report is not None:
+                res.report["hunt"] = hunt_report
+            evlog.emit("statespace", report=res.report)
+        pm_path = None
+        if err is not None:
+            pm_path = _FLIGHT.dump(
+                f"swarm run error: {type(err).__name__}: {err}")
+        counts = self._counts
+        counts["steps"] = res.steps
+        # ``swarm/steps`` is counted in the loop; the latch's step is a
+        # position, not a sum.
+        mt.gauge("swarm/latch_step", counts["latch_step"])
+        for name in SWARM_COUNTERS:
+            if name not in ("steps", "latch_step"):
+                mt.counter("swarm/" + name, counts[name])
+        evlog.emit(
+            "run_end",
+            stop_reason=(res.stop_reason if err is None else "error"),
+            error=(f"{type(err).__name__}: {err}"
+                   if err is not None else None),
+            postmortem_path=pm_path,
+            counterexample_path=ce_path,
+            distinct=res.distinct, generated=res.generated,
+            diameter=res.diameter, levels=[],
+            wall_seconds=res.wall_seconds,
+            phase_seconds=res.phases, swarm=swarm_block,
+            # Counted in the loop (SWARM_COUNTERS), and the compiles and
+            # cache loads of this run by the span they fell in.
+            **counts,
+            compiles=compiles_by_span(mt, compile_base),
+            memory=device_memory_stats())
+        _FLIGHT.set_live_evlog(None)
+        _FLIGHT.disarm()
+        evlog.close()
 
     def _swarm_block(self, res: SwarmResult) -> dict:
         """The ``swarm`` payload object shared by ``swarm_progress``,
@@ -646,23 +742,18 @@ class SwarmEngine:
             check_packable(e, self.dims)
         return np.stack([flatten_state(e, dims) for e in encoded])
 
-    def _run_impl(self, roots, res, seed, num_steps, max_seconds,
-                  evlog, t0):
+    def _init_slices(self, roots, res, seed_j, dev):
+        """(roots on the device, slices, the hunt's two filters), or
+        None where a root already violates an invariant.  Walk slices:
+        global walk ids 0..W-1 in ``batch``-lane device dispatches.
+        Everything per-walk depends only on (seed, walk_id, step), so
+        the slicing is invisible to the walks."""
         W, D, B = self.walks, self.max_depth, self.batch
-        mt = self.metrics
         roots_np = self._prepare_roots(roots, res)
         if roots_np is None:
-            return
-        dev = jax.devices()[0]
+            return None
         roots_j = jax.device_put(jnp.asarray(roots_np), dev)
         n_roots = roots_np.shape[0]
-        k_limit = jnp.int32(num_steps if num_steps is not None
-                            else np.iinfo(np.int32).max)
-        seed_j = _U32(np.uint32(seed & 0xFFFFFFFF))
-
-        # Walk slices: global walk ids 0..W-1 in ``batch``-lane device
-        # dispatches.  Everything per-walk depends only on (seed,
-        # walk_id, step), so the slicing is invisible to the walks.
         slices = []
         for off in range(0, W, B):
             ids = np.arange(off, min(off + B, W), dtype=np.int32)
@@ -684,10 +775,6 @@ class SwarmEngine:
                 "epoch": jax.device_put(jnp.zeros((lanes,), _I32), dev),
                 "visited": 0, "latch": None, "ys": None,
             })
-        res.traces = W
-        mt.counter("swarm/walks", W)
-        mt.gauge("swarm/active_walks", W)
-
         hunt_args = ()
         if self.hunt:
             from ..obs import hunt as hunt_mod
@@ -700,9 +787,34 @@ class SwarmEngine:
             # walks were sliced (only the per-step series reorders).
             hunt_args = (jax.device_put(bloom_init(self.hunt_cells), dev),
                          jax.device_put(bloom_init(self.hunt_cells), dev))
+        return roots_j, slices, hunt_args
+
+    def _run_impl(self, roots, res, seed, num_steps, max_seconds,
+                  evlog, t0):
+        W = self.walks
+        mt, counts = self.metrics, self._counts
+        dev = jax.devices()[0]
+        k_limit = jnp.int32(num_steps if num_steps is not None
+                            else np.iinfo(np.int32).max)
+        seed_j = _U32(np.uint32(seed & 0xFFFFFFFF))
+        with mt.phase_timer("swarm_init"):
+            made = self._init_slices(roots, res, seed_j, dev)
+        if made is None:
+            return
+        roots_j, slices, hunt_args = made
+        self._walkers = (list(roots), slices)
+        counts["slices"] = len(slices)
+        res.traces = W
+        mt.counter("swarm/walks", W)
+        mt.gauge("swarm/active_walks", W)
         hacc = self._hunt_acc
         prof = self._profiler
         cap = self._xla_capture
+
+        def fetch(a):
+            """One blocking device-to-host copy, counted."""
+            counts["fetches"] += 1
+            return np.asarray(a)
 
         fps_acc: List[np.ndarray] = []
         k0 = 0
@@ -716,7 +828,7 @@ class SwarmEngine:
                 prof.sample(slices[0]["rows"],
                             np.ones((self.batch,), bool))
             tc0 = time.perf_counter()
-            with mt.phase_timer("swarm_chunk"):
+            with mt.phase_timer("swarm_chunk", step=k0):
                 step_cm = cap.step() if cap is not None else None
                 if step_cm is not None:
                     step_cm.__enter__()
@@ -739,6 +851,7 @@ class SwarmEngine:
                 finally:
                     if step_cm is not None:
                         step_cm.__exit__(None, None, None)
+            counts["chunk_calls"] += len(slices)
             stepped = min(self.chunk,
                           max(0, int(k_limit) - k0)) if num_steps \
                 else self.chunk
@@ -750,42 +863,48 @@ class SwarmEngine:
             res.steps += W * stepped
             fired = []
             novel_steps = accept_steps = None
-            with mt.phase_timer("swarm_fetch"):
+            with mt.phase_timer("swarm_fetch", step=k_start):
                 for s in slices:
-                    res.traces += int(s["restarts"])
-                    mt.counter("swarm/walks", int(s["restarts"]))
-                    v = int(s["visited_d"])
+                    restarts = int(fetch(s["restarts"]))
+                    res.traces += restarts
+                    counts["restarts"] += restarts
+                    mt.counter("swarm/walks", restarts)
+                    v = int(fetch(s["visited_d"]))
                     res.visited += v
                     mt.counter("swarm/visited", v)
-                    depth_max = max(depth_max, int(s["depth_d"]))
-                    vf = bool(s["latch"][0])
+                    depth_max = max(depth_max, int(fetch(s["depth_d"])))
+                    vf = bool(fetch(s["latch"][0]))
                     if vf:
                         fired.append(s["latch"])
                     if hacc is not None:
                         hc = s["hunt"]
                         hacc.add_slice(
-                            fresh=int(hc[2]), promote=int(hc[3]),
+                            fresh=int(fetch(hc[2])),
+                            promote=int(fetch(hc[3])),
                             # RESTART_REASONS order: deadend, overflow,
                             # constraint, revisit, depth_bound.
-                            reasons=(int(hc[5]), int(hc[6]), int(hc[7]),
-                                     int(hc[4]), int(hc[8])),
-                            depth_hist=np.asarray(hc[9]),
-                            fam_chosen=np.asarray(hc[10]),
-                            fam_accept=np.asarray(hc[11]),
-                            fam_fresh=np.asarray(hc[12]))
-                        nv = np.asarray(s["ys"][3])
-                        av = np.asarray(s["ys"][4])
+                            reasons=tuple(int(fetch(hc[i]))
+                                          for i in (5, 6, 7, 4, 8)),
+                            depth_hist=fetch(hc[9]),
+                            fam_chosen=fetch(hc[10]),
+                            fam_accept=fetch(hc[11]),
+                            fam_fresh=fetch(hc[12]))
+                        nv = fetch(s["ys"][3])
+                        av = fetch(s["ys"][4])
                         novel_steps = (nv if novel_steps is None
                                        else novel_steps + nv)
                         accept_steps = (av if accept_steps is None
                                         else accept_steps + av)
                     if self.collect_fingerprints:
-                        hi, lo, acc = (np.asarray(a)
-                                       for a in s["ys"][:3])
+                        hi, lo, acc = (fetch(a) for a in s["ys"][:3])
                         m = acc.reshape(-1)
                         fps_acc.append(np.stack(
                             [hi.reshape(-1)[m], lo.reshape(-1)[m]],
                             axis=1))
+                # Globally first violation in (step, walk) order — the
+                # partition-invariant pick across slices.
+                latched = [(int(fetch(lt[7])), int(fetch(lt[6])), lt)
+                           for lt in fired]
             if hacc is not None and stepped:
                 hacc.add_steps(k_start + stepped, W * stepped,
                                novel_steps[:stepped],
@@ -814,12 +933,14 @@ class SwarmEngine:
                 _FLIGHT.progress(mode="swarm", steps=res.steps,
                                  visited=res.visited, traces=res.traces,
                                  **flight_extra)
-            if fired:
-                # Globally first violation in (step, walk) order — the
-                # partition-invariant pick across slices.
-                latch = min(fired, key=lambda lt: (int(lt[7]),
-                                                   int(lt[6])))
-                self._reconstruct(res, roots, latch)
+            if latched:
+                vstep, vwalk, latch = min(latched, key=lambda f: f[:2])
+                counts["latch_step"] = res.violation_step = vstep
+                res.violation_walk = vwalk
+                counts["steps_past_latch"] = W * (k_start + stepped
+                                                  - vstep)
+                with mt.scope("reconstruct", step=vstep, walk=vwalk):
+                    self._reconstruct(res, roots, latch)
                 res.stop_reason = "violation"
                 res.violation_at_seconds = round(time.time() - t0, 6)
                 evlog.emit("violation",
@@ -827,7 +948,7 @@ class SwarmEngine:
                                       if res.violation else "?"),
                            fingerprint=(hex(res.violation.fingerprint)
                                         if res.violation else None),
-                           walk=int(latch[6]), step=int(latch[7]),
+                           walk=vwalk, step=vstep,
                            at_seconds=res.violation_at_seconds)
                 break
             if max_seconds is not None and time.time() - t0 > max_seconds:
@@ -844,33 +965,62 @@ class SwarmEngine:
                 np.concatenate(fps_acc, axis=0) if fps_acc
                 else np.zeros((0, 2), np.uint32))
 
+    def replay_actions(self, root: PyState, actions) -> list:
+        """``[(action, PyState), ...]`` from ``root`` (action -1) through
+        the instance ids ``actions``, one expand round trip a step; stops
+        at an id that is negative or not enabled.  Threads the ENCODED
+        candidate row, never a re-encoded state (the simulator's
+        slot-aliasing rule: re-encoding reassigns message slots, and
+        slot-indexed action ids would then address the wrong message)."""
+        st = encode_state(root, self.dims)
+        trace = [(-1, root)]
+        for g in actions:
+            g = int(g)
+            with self.metrics.phase_timer("reconstruct_step"):
+                cands, en, _ovf = self._expand1(st)
+                self._counts["reconstruct_steps"] += 1
+                if g < 0 or not bool(np.asarray(en)[g]):
+                    break
+                row = jax.tree.map(lambda a: np.asarray(a)[g], cands)
+            st = StateBatch(*row)
+            trace.append((g, decode_state(st, self.dims)))
+        return trace
+
     def _reconstruct(self, res: SwarmResult, roots, latch):
         """Replay the latched (root, action sequence) through the expand
-        kernel — the simulator's reconstruction, including its
-        slot-aliasing rule: thread the ENCODED candidate row, never
-        re-encode the decoded state (re-encoding reassigns message
-        slots and slot-indexed action ids would then address the wrong
-        message mid-replay)."""
+        kernel — the simulator's reconstruction."""
         (_vf, vinv, vroot, vlen, vacts, vchoice, _vwalk, _vstep,
          vhi, vlo) = latch
         vinv, vroot, vlen = int(vinv), int(vroot), int(vlen)
-        vacts = np.asarray(vacts)
-        state = roots[vroot]
-        st = encode_state(state, self.dims)
-        trace = [(-1, state)]
-        for g in list(vacts[:vlen]) + [int(vchoice)]:
-            g = int(g)
-            cands, en, _ovf = self._expand1(st)
-            if g < 0 or not bool(np.asarray(en)[g]):
-                break
-            row = jax.tree.map(lambda a: np.asarray(a)[g], cands)
-            st = StateBatch(*row)
-            state = decode_state(st, self.dims)
-            trace.append((g, state))
+        trace = self.replay_actions(
+            roots[vroot], list(np.asarray(vacts)[:vlen]) + [int(vchoice)])
         fp = (int(vhi) << 32) | int(vlo)
         res.violation = Violation(
             invariant=(self.inv_names[vinv]
                        if 0 <= vinv < len(self.inv_names) else "?"),
-            state=state, fingerprint=fp)
+            state=trace[-1][1], fingerprint=fp)
         res.violation_trace = trace
         self._last_trace = trace
+
+    def walk_transcripts(self, ids) -> list:
+        """``[(root, actions, row), ...]`` of the walkers with the global
+        ids ``ids`` as the last run's last chunk left them: the root of
+        the walker's current trace (a ``PyState`` of the run's roots),
+        the instance ids it took since that restart, and its packed row
+        on the device.  ``replay_actions(root, actions)`` must end in the
+        state ``row`` decodes to."""
+        if self._walkers is None:
+            raise RuntimeError("no finished run to read walkers from")
+        roots, slices = self._walkers
+        host = {}       # slice index -> its four arrays, copied once
+        out = []
+        for w in ids:
+            i, lane = int(w) // self.batch, int(w) % self.batch
+            if i not in host:
+                host[i] = [np.asarray(slices[i][k]) for k in
+                           ("tstep", "cur_root", "abuf", "rows")]
+            tstep, cur_root, abuf, rows = host[i]
+            out.append((roots[int(cur_root[lane])],
+                        [int(g) for g in abuf[lane, :int(tstep[lane])]],
+                        rows[lane]))
+        return out

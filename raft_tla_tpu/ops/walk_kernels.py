@@ -28,9 +28,8 @@ ring is initialized to the FPSet's reserved all-ones sentinel pair
 (ops/fingerprint.py remaps real fingerprints off it), so empty slots
 can never alias a real state.
 
-Plain jnp ops throughout: the swarm's profitable platform today is
-the CPU CI host and the vmap'd expand kernels it calls into are
-already the BLEST-grouped family kernels.
+Plain jnp ops throughout; the vmap'd expand kernels the swarm calls
+into are already the BLEST-grouped family kernels.
 """
 
 from __future__ import annotations

@@ -415,31 +415,16 @@ def _do_swarm(req, telemetry=None, _loaded=None):
     ``events_out`` / ``postmortem_dir`` / ``run_context`` are
     (re)assigned on EVERY request so a cached engine never leaks one
     job's paths into the next."""
-    from .engine.check import (initial_states, resolve_constraint,
-                               resolve_invariants)
-    from .engine.swarm import SwarmEngine
+    from .engine.check import initial_states, make_swarm_engine
 
     setup, ident, cfg_text = (_loaded if _loaded is not None
                               else _load_setup(req))
-    backend = setup.backend
-    walks = (int(req["walks"]) if req.get("walks") is not None
-             else int(backend.get("WALKS", 1024)))
-    max_depth = (int(req["max_depth"])
-                 if req.get("max_depth") is not None
-                 else int(setup.max_diameter or 128))
-    batch = (int(req["batch"]) if req.get("batch") is not None
-             else int(backend.get("BATCH", walks)))
-    pipeline = (req["pipeline"] if req.get("pipeline") is not None
-                else backend.get("PIPELINE", "auto"))
-    key = (ident, "swarm", walks, max_depth, min(batch, walks), pipeline)
+    shape = {k: req.get(k)
+             for k in ("walks", "max_depth", "batch", "pipeline")}
+    key = (ident, "swarm") + tuple(shape.values())
     eng = _cache_get(_SWARMS, key, "swarm_cache")
     if eng is None:
-        eng = SwarmEngine(setup.dims,
-                          invariants=resolve_invariants(setup),
-                          constraint=resolve_constraint(setup),
-                          walks=walks, max_depth=max_depth,
-                          batch=min(batch, walks), pipeline=pipeline,
-                          metrics=_METRICS)
+        eng = make_swarm_engine(setup, metrics=_METRICS, **shape)
         _cache_put(_SWARMS, key, eng, "swarm_cache")
     tel = telemetry or {}
     eng.events_out = tel.get("events_out")

@@ -13,8 +13,9 @@ for the checkout itself, or a git-ignored copy of another commit such as
 (``$ROOT`` stands for this checkout).  ``how`` = ``"ops"`` runs
 ``scripts/ops_per_pass.py`` instead, ``"probe"`` ``scripts/setup_probe.py``,
 ``"record"`` ``scripts/record_capture.py``, ``"control:<name>"``
-``benchmark/tests/controls_mesh.py <name>`` around the same run (a result
-of ``correct: false`` is what it must give).  After ``how``: ``"stop"``
+``benchmark/tests/controls_mesh.py <name>`` around the same run, and
+``"control:swarm:<name>"`` ``benchmark/tests/controls_swarm.py <name>`` (a
+result of ``correct: false`` is what either must give).  After ``how``: ``"stop"``
 ends the plan when that run did not give what it should, and
 ``"limit:<seconds>"`` kills the run at that age (rc -9 in the results).
 ``--within`` is the plan's own limit: a run gets no more than what is
@@ -40,7 +41,8 @@ OUT = os.path.join(ROOT, "chiprun_out")
 SHOWN = ("stages:", "idle ", "window compiles", "trace:", "trace reduced",
          "setup:", "window:", "fill:", "ROOT", "   ", "capture_small",
          "traced check", "kept snapshot", "mesh:", "enqueued:", "at ",
-         "memory", "replay:", "sample:", "window phases")
+         "memory", "replay:", "sample:", "window phases", "walk:",
+         "window counters")
 
 
 def command(root: str, workload: str, seed: int, trace: int, how: str):
@@ -55,8 +57,10 @@ def command(root: str, workload: str, seed: int, trace: int, how: str):
     run = ["--workload", workload, "--seed", str(seed), "--seconds", "20",
            "--trace", str(trace)]
     if how.startswith("control:"):
-        return [sys.executable, "benchmark/tests/controls_mesh.py",
-                how.split(":", 1)[1], "--", *run]
+        script, _, name = how.split(":", 1)[1].rpartition(":")
+        return [sys.executable,
+                f"benchmark/tests/controls_{script or 'mesh'}.py", name,
+                "--", *run]
     return [sys.executable, "benchmark/run.py", *run]
 
 

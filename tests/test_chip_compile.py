@@ -12,7 +12,8 @@ over the bench's 2^25-key table, the whole chunk program of ``BFSEngine``
 at a small batch (and that it holds no gather expanded into a per-lane
 loop), its ``ingest`` program, the two programs a seen-set growth
 dispatches at the verdict cell's sizes, the trace flush's fetch programs
-over the bench's trace buffers, the swarm walk chunk at 1,024 walks, the
+over the bench's trace buffers, the swarm walk chunk at 1,024 walks and
+at the size of the benchmark's ``mcraft3-hunt`` cell, the
 mesh chunk and ingest over the four described chips (with the
 owner-routed dedup's ``all-to-all``).
 
@@ -205,6 +206,32 @@ def test_swarm_walk_chunk_1024_walks(one_chip):
                       constraint=resolve_constraint(s), walks=1024,
                       max_depth=64, batch=1024)
     compile_for(eng._chunk, lambda a: one_chip, *eng.chunk_avals(1))
+
+
+def test_swarm_walk_chunk_at_the_hunt_cells_size(one_chip, tmp_path):
+    """The walk chunk as the benchmark's ``mcraft3-hunt`` cell builds it
+    (``benchmark/configs/mcraft3-swarm.json``: its cfg text, its depth,
+    ``make_swarm_engine``): one slice of the cell's width, the hunt
+    observatory's lanes x lanes prior included, fits a v5e."""
+    import json
+    from raft_tla_tpu.engine.check import make_swarm_engine
+    from raft_tla_tpu.utils.cfg import load_config
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "mcraft3-swarm.json"), encoding="utf-8") as f:
+        config = json.load(f)
+    cfg = tmp_path / config["cfg_name"]
+    cfg.write_text("\n".join(config["cfg_text"]) + "\n")
+    eng = make_swarm_engine(load_config(str(cfg)),
+                            max_depth=config["max_depth"])
+    assert (eng.walks, eng.batch, eng.hunt) == (
+        config["walks"], config["batch"], True)
+    c = compile_for(eng._chunk, lambda a: one_chip, *eng.chunk_avals(1))
+    m = c.memory_analysis()
+    print(f"walk chunk, {eng.batch} lanes of {eng.walks} walks, depth "
+          f"{eng.max_depth}: {m}")
+    slices = -(-eng.walks // eng.batch)
+    assert (slices * m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes) < 16e9
 
 
 def small_mesh_engine(cfg, topo, **kw):
