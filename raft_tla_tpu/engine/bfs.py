@@ -136,12 +136,18 @@ def compiles_by_span(metrics, base: Optional[dict] = None) -> dict:
 
 
 # -- the trace flush, in two halves ------------------------------------------
-# Records one fetch program moves (``BFSEngine._fetch``: five slices of a
-# fixed length at a traced start, compiled in warm-up), smallest first.
+# Records one fetch program moves (an engine's ``_fetch``: five slices of
+# a fixed length at a traced start, compiled in warm-up), smallest first.
 # A flush takes the smallest that holds its records in one piece, else
 # walks them in pieces of the largest: whatever a call admitted, its
 # flush compiles nothing and moves under one piece more than it needs.
 FLUSH_PIECES = (1 << 11, 1 << 14, 1 << 17)
+
+
+def fetch_lengths(size: int) -> List[int]:
+    """The lengths an engine warms its fetch program up at, for trace
+    buffers of ``size`` entries: no piece is longer than the buffer."""
+    return sorted({min(x, size) for x in FLUSH_PIECES})
 
 
 def flush_plan(n: int, lengths, size: int) -> List[Tuple[int, int, int, int]]:
@@ -159,33 +165,43 @@ def flush_plan(n: int, lengths, size: int) -> List[Tuple[int, int, int, int]]:
 
 
 class _TraceFlush:
-    """One chunk call's trace records on their way to the host store, in
-    two halves, so that the device need not wait for the host's.
-    ``start`` enqueues the fetch programs on the call's trace buffer and
-    starts their copies to the host: device work, ahead in the stream of
-    whatever is dispatched next, which may donate that buffer.
+    """One call's trace records on their way to the host store, in two
+    halves, so that the device need not wait for the host's.  ``start``
+    enqueues the fetch programs on the call's trace buffers and starts
+    their copies to the host: device work, ahead in the stream of
+    whatever is dispatched next, which may donate those buffers.
     ``finish`` waits for the copies and hands the records to the store.
     The loop calls it behind the next chunk dispatch (counted as
     ``flush_overlapped``), or with the device empty where something other
     than a plain next call comes first: the end of a level, any stop
     (``flush_drained``).  So at most one flush is owed, and records reach
     the store in the order the calls ran.  A run that raises takes what
-    it owed with it: ``_run_degradable`` resumes into a new store."""
+    it owed with it: ``_run_degradable`` resumes into a new store.
+
+    One class for both host loops.  What differs comes from the engine
+    that runs: its ``_fetch(buffer, start, length)`` (five columns of
+    ``length`` entries of one buffer), the lengths that program was
+    warmed up at (``_fetch_lens``) and the buffer's size (``_TA``), its
+    ``_record``; and the loop gives ``start`` one ``(buffer, records)``
+    a plan: ``BFSEngine`` its trace buffer, ``MeshBFSEngine`` one for
+    each chip of this controller, in chip order (``_trace_parts``)."""
 
     def __init__(self, engine, trace):
         self._eng, self._trace, self._owed = engine, trace, None
 
-    def start(self, tbuf, n: int) -> None:
+    def start(self, parts) -> None:
         eng = self._eng
+        assert self._owed is None, "a flush is owed already"
         with eng.metrics.phase_timer("trace_flush"):
             owed = []
-            for start, length, lo, hi in flush_plan(n, eng._fetch_lens,
-                                                    eng._TA):
-                cols = eng._fetch(tbuf, np.int32(start), length)
-                for col in cols:
-                    col.copy_to_host_async()
-                owed.append((cols, lo, hi))
-            self._owed = owed
+            for buf, n in parts:
+                for start, length, lo, hi in flush_plan(
+                        n, eng._fetch_lens, eng._TA):
+                    cols = eng._fetch(buf, np.int32(start), length)
+                    for col in cols:
+                        col.copy_to_host_async()
+                    owed.append((cols, lo, hi))
+            self._owed = owed or None
 
     def finish(self, counter: str) -> None:
         if self._owed is None:
@@ -959,7 +975,7 @@ class BFSEngine:
         self._TA = TQ + K if record_static else 8
         # The trace flush's fetch programs (``_TraceFlush``), one per
         # length; ``_run_impl`` runs each once in warm-up.
-        self._fetch_lens = sorted({min(x, self._TA) for x in FLUSH_PIECES})
+        self._fetch_lens = fetch_lengths(self._TA)
         self._fetch = jax.jit(
             lambda tbuf, start, length: tuple(
                 jax.lax.dynamic_slice(x, (start,), (length,))
@@ -1940,7 +1956,7 @@ class BFSEngine:
                     if cfg.record_trace and tcount:
                         # The device half only: everything below may
                         # raise, branch or dispatch with the flush owed.
-                        flush.start(tbuf, tcount)
+                        flush.start(((tbuf, tcount),))
                     if n_ovf:
                         raise RuntimeError(
                             f"{n_ovf} successors exceeded fixed-width "

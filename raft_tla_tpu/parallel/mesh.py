@@ -62,8 +62,9 @@ from jax.experimental.xla_metadata import set_xla_metadata
 from ..engine.chunk import build_chunk_body, tag_stages
 from ..engine.bfs import (BFSEngine, EngineConfig, EngineResult, TraceStore,
                           Violation, _exit_condition_hit, _family_groups_meta,
-                          _progress_line, build_root_check,
-                          find_root_violation, make_trace_store)
+                          _progress_line, _TraceFlush, build_root_check,
+                          fetch_lengths, find_root_violation,
+                          make_trace_store, watch_compiles)
 from ..models.actions import build_expand
 from ..models.dims import RaftDims
 from ..models.invariants import build_inv_id
@@ -96,6 +97,25 @@ MESH_STAGES_TAG = "m1"
 
 # Rows of one frontier upload step.
 UPLOAD_ROWS = 1 << 16
+
+
+def _chip(shard) -> int:
+    """The chip (row of the mesh) an addressable shard of a ``P("x")``
+    array lies on.  A one-device mesh's only shard spans the axis, and
+    its slice has no start."""
+    return shard.index[0].start or 0
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _fetch_shard(cols, start, length):
+    """The trace flush's fetch program (engine/bfs.py ``_TraceFlush``):
+    ``length`` entries from ``start`` of one chip's five trace columns,
+    each that chip's own ``[1, TA]`` shard.  A one-device program on the
+    chip the shard lies on, so no controller waits for another's; one
+    ``jit`` for every engine of the process, so a seen-set growth's
+    rebuilt programs find it compiled."""
+    return tuple(jax.lax.dynamic_slice(x, (0, start), (1, length))[0]
+                 for x in cols)
 
 
 def tag_mesh_stages(count):
@@ -144,6 +164,7 @@ class MeshBFSEngine:
             self.tracer = SpanTracer(
                 trace_out, annotate=jax.profiler.TraceAnnotation)
         self.metrics.tracer = self.tracer
+        watch_compiles()    # ``run_end.compiles`` (engine/bfs.py)
         # The per-stage chunk profiler is a single-chip instrument
         # (EngineConfig.profile_chunks_every rationale); the mesh's
         # observability is spans + phases + coverage.
@@ -217,6 +238,9 @@ class MeshBFSEngine:
         TQ = QL + K if record_static else 8
         self._TQ = TQ
         self._TA = TQ + K if record_static else 8
+        # The trace flush's fetch lengths (``_TraceFlush``); ``_run_impl``
+        # runs ``_fetch`` at each on every chip once in warm-up.
+        self._fetch_lens = fetch_lengths(self._TA)
         check_deadlock_static = self._check_deadlock
         # pmin keeps every chip's offset advance identical — the chunk
         # body contains collectives, so trip counts must agree.
@@ -326,8 +350,8 @@ class MeshBFSEngine:
             compactor=compactor, insert_fn=route_insert, v2=self._v2,
             por_mask=por_mask, por_priority=por_priority)
 
-        def agreed_stats(offset, steps, gen, newc, ovfc, fail_any,
-                         max_count, ncnt_l, cnt_l, seen_l, windows, viol_any,
+        def agreed_stats(offset, steps, gen, newc, ovfc, fail_any, max_count,
+                         ncnt_l, cnt_l, seen_l, windows, tcnt_l, viol_any,
                          vinv, vrow, vhi, vlo, dead_any, drow, expanded,
                          fam_counts, fam_new, fam_pruned):
             """What a chunk call hands the host, the same on every chip:
@@ -335,7 +359,7 @@ class MeshBFSEngine:
             rows broadcast from the lowest-indexed flagged chip (no
             per-chip inspection on the host side), and last each chip's
             own (parents expanded, next-level rows, shard keys, insert
-            windows run)."""
+            windows run, trace records written)."""
             g_gen = jax.lax.psum(gen, "x")
             g_new = jax.lax.psum(newc, "x")
             g_ovf = jax.lax.psum(ovfc, "x")
@@ -364,7 +388,8 @@ class MeshBFSEngine:
                 jax.lax.psum(fam_new, "x"),
                 jax.lax.psum(fam_pruned, "x"),
                 jax.lax.all_gather(
-                    jnp.stack([expanded, ncnt_l, seen_l.size, windows]),
+                    jnp.stack([expanded, ncnt_l, seen_l.size, windows,
+                               tcnt_l]),
                     "x").T.reshape(-1)])
             return stats, drow_g, vrow_g, vhi_g, vlo_g
 
@@ -420,9 +445,9 @@ class MeshBFSEngine:
             with jax.named_scope("agree"):
                 stats, drow_g, vrow_g, vhi_g, vlo_g = agreed_stats(
                     offset, steps, gen, newc, ovfc, fail_any, max_count,
-                    ncnt_l, cnt_l, seen_l, windows, viol_any, vinv, vrow, vhi,
-                    vlo, dead_any, drow, expanded, fam_counts, fam_new,
-                    fam_pruned)
+                    ncnt_l, cnt_l, seen_l, windows, tcnt_l, viol_any, vinv,
+                    vrow, vhi, vlo, dead_any, drow, expanded, fam_counts,
+                    fam_new, fam_pruned)
             vfp_g = jnp.stack([vhi_g, vlo_g])
             return (qnext_l[None], ncnt_l[None], seen_l.hi[None],
                     seen_l.lo[None], seen_l.size[None],
@@ -808,7 +833,7 @@ class MeshBFSEngine:
         mp = mh.is_multiprocess()
         if mp:
             # Multi-controller trace recording: each controller's store
-            # accumulates its own chips' records (_flush_trace) and the
+            # accumulates its own chips' records (_trace_parts) and the
             # stores are exchanged as per-controller piece files on the
             # shared filesystem (same R8 assumption as multi-host
             # checkpoints), merged lazily at replay().  That exchange
@@ -984,6 +1009,13 @@ class MeshBFSEngine:
                               qnext, next_counts, shi, slo, ssize, tbuf,
                               tcount, jnp.int32(self._CH))
             qnext, next_counts, shi, slo, ssize, tbuf = out[:6]
+            # The trace flush's programs, on every chip's buffers as the
+            # chunk hands them back (engine/bfs.py warm-up rationale).
+            if cfg.record_trace:
+                for cols, _m in self._trace_parts(tbuf, [0] * n):
+                    for length in self._fetch_lens:
+                        self._fetch(cols, np.int32(0), length)
+        flush = _TraceFlush(self, trace)
         t0 = time.time()
         last_progress = t0
         self._batch_ema = 0.0
@@ -1099,8 +1131,11 @@ class MeshBFSEngine:
                 if int(ist[1]):
                     raise RuntimeError("seen-set probe failure during "
                                        "ingest; raise seen_capacity")
-                with mt.phase_timer("trace_flush"):
-                    self._flush_trace(trace, tbuf, tcount)
+                if cfg.record_trace and int(ist[0]):
+                    # Roots, outside every window: at once.
+                    flush.start(self._trace_parts(
+                        tbuf, self._local_counts(tcount)))
+                    flush.finish("flush_drained")
                 tcount = sharded_full((n,), _I32)
                 (shi, slo, ssize, qnext, next_counts, tbuf,
                  t0) = self._grow_precompiled(shi, slo, ssize, qcur, qnext,
@@ -1228,6 +1263,9 @@ class MeshBFSEngine:
                             slo, ssize, tbuf, tcount, jnp.int32(allowed))
                         (qnext, next_counts, shi, slo, ssize, tbuf,
                          tcount, stats, drow_g, vrow_g, vfp_g) = out
+                    # The host half of the previous call's flush, while
+                    # the chips run this one.
+                    flush.finish("flush_overlapped")
                     # One blocking sync per chunk call (engine/bfs.py):
                     # this phase is the mesh's device compute + collective
                     # time.
@@ -1236,7 +1274,7 @@ class MeshBFSEngine:
                     account = mt.open_span("account", call=call,
                                            passes=int(st[1]))
                     self._count_chunk_call(int(st[1]), int(st[15]))
-                    self._count_per_chip(st)
+                    written = self._count_per_chip(st)
                     if self._perf is not None and int(st[1]):
                         # Launch accounting's dynamic half (obs/perf.py)
                         # — host arithmetic on the fetched stats only.
@@ -1284,6 +1322,11 @@ class MeshBFSEngine:
                         seen_size=int(st[10]),
                         elapsed=round(time.time() - t0, 3))
                     account.close()
+                    if cfg.record_trace and written.any():
+                        # The device half only (engine/bfs.py): ahead in
+                        # every chip's stream of the next call, which
+                        # donates ``tbuf``.
+                        flush.start(self._trace_parts(tbuf, written))
                     if int(st[4]):
                         raise RuntimeError(
                             f"{int(st[4])} successors exceeded fixed-width "
@@ -1295,9 +1338,11 @@ class MeshBFSEngine:
                             "seen-set probe failure (load spiked within "
                             "one chunk); raise seen_capacity or lower "
                             "sync_every")
-                    with mt.phase_timer("trace_flush"):
-                        self._flush_trace(trace, tbuf, tcount)
                     tcount = sharded_full((n,), _I32)
+                    if int(st[10]) > self._CL // 2:
+                        # The shards grow: a rehash on the host, then
+                        # rebuilt programs that hand back another ``tbuf``.
+                        flush.finish("flush_drained")
                     (shi, slo, ssize, qnext, next_counts, tbuf,
                      t0) = self._grow_precompiled(
                         shi, slo, ssize, qcur, qnext, next_counts, tbuf,
@@ -1313,6 +1358,7 @@ class MeshBFSEngine:
                                          if any_flag is not None
                                          else bool(pending))
                         if more_here:
+                            flush.finish("flush_drained")
                             resolve_spill()
                             with mt.phase_timer("spill"):
                                 cnts = self._local_counts(next_counts)
@@ -1392,6 +1438,9 @@ class MeshBFSEngine:
                 with mt.phase_timer("upload"):
                     qcur, cur_counts_dev = self._upload_segment(pending,
                                                                 qcur)
+            # The level is built, or the run stops: what follows reads
+            # the store (a snapshot, a replay, a trace piece).
+            flush.finish("flush_drained")
             if res.stop_reason != "exhausted" or res.violation is not None:
                 break
             resolve_spill()      # level boundary: all drains must land
@@ -1485,17 +1534,20 @@ class MeshBFSEngine:
         return qcur, jax.make_array_from_callback(
             (n,), shq, lambda idx: cnts[idx[0].start:idx[0].stop])
 
-    def _count_per_chip(self, st) -> None:
+    def _count_per_chip(self, st) -> np.ndarray:
         """One chunk call's share of the per-chip counts: the tail of
         the statistics just fetched is each chip's own (parents
-        expanded, next-level rows, shard keys, insert windows run)."""
+        expanded, next-level rows, shard keys, insert windows run, trace
+        records written).  Returns the last, by chip: what the call's
+        flush fetches, with no further copy from any chip."""
         n, mc = self.n_dev, self._mesh_counts
-        per_chip = np.asarray(st[len(st) - 4 * n:]).reshape(4, n)
+        per_chip = np.asarray(st[len(st) - 5 * n:]).reshape(5, n)
         for key, row in (("chip_parents_expanded", per_chip[0]),
                          ("chip_insert_windows", per_chip[3])):
             mc[key] = [a + int(b) for a, b in zip(mc[key], row)]
         mc["chip_next_count"] = [int(v) for v in per_chip[1]]
         mc["chip_shard_keys"] = [int(v) for v in per_chip[2]]
+        return per_chip[4]
 
     def _run_end_extra(self) -> dict:
         return dict(getattr(self, "_mesh_counts", {}))
@@ -1503,7 +1555,7 @@ class MeshBFSEngine:
     def _local_counts(self, counts) -> dict:
         """{global chip row -> count} for THIS controller's addressable
         shards (single-controller: all chips — behavior unchanged)."""
-        return {s.index[0].start: int(np.asarray(s.data)[0])
+        return {_chip(s): int(np.asarray(s.data)[0])
                 for s in counts.addressable_shards}
 
     def _drain(self, qnext, cnts: dict) -> np.ndarray:
@@ -1511,9 +1563,8 @@ class MeshBFSEngine:
         controller drains only its addressable shards; the union across
         controllers is the global queue (multi-controller rule 2)."""
         segs = []
-        for s in sorted(qnext.addressable_shards,
-                        key=lambda s: s.index[0].start):
-            c = cnts.get(s.index[0].start, 0)
+        for s in sorted(qnext.addressable_shards, key=_chip):
+            c = cnts.get(_chip(s), 0)
             if c:
                 # Sliced on the chip: a queue is QL rows long whatever
                 # it holds.
@@ -1575,14 +1626,12 @@ class MeshBFSEngine:
         holds, as they lie on it."""
         out = {}
         for s_hi, s_lo in zip(
-                sorted(shi.addressable_shards,
-                       key=lambda s: s.index[0].start),
-                sorted(slo.addressable_shards,
-                       key=lambda s: s.index[0].start)):
+                sorted(shi.addressable_shards, key=_chip),
+                sorted(slo.addressable_shards, key=_chip)):
             hi_h = np.asarray(s_hi.data)[0]
             lo_h = np.asarray(s_lo.data)[0]
             real = ~((hi_h == SENTINEL) & (lo_h == SENTINEL))
-            out[s_hi.index[0].start] = (hi_h[real], lo_h[real])
+            out[_chip(s_hi)] = (hi_h[real], lo_h[real])
         return out
 
     def _write_checkpoint(self, qcur, cur_counts, pending, shi, slo, res,
@@ -1639,32 +1688,25 @@ class MeshBFSEngine:
         if removed:
             self.metrics.counter("engine/checkpoints_gcd", removed)
 
-    def _flush_trace(self, trace, tbuf, tcount):
-        """Harvest trace records from this controller's ADDRESSABLE chip
-        buffers only (single-controller: all chips — behavior unchanged).
-        Under a process group, fetching the global arrays would be a
-        cross-host gather; instead each controller's store accumulates
-        the records its own chips produced, and the stores are merged
-        through per-controller piece files at replay time
-        (:meth:`_merge_trace_pieces`)."""
-        if not self.config.record_trace:
-            return
-        counts = self._local_counts(tcount)
-        if not any(counts.values()):
-            return
-        comps = [sorted(x.addressable_shards,
-                        key=lambda s: s.index[0].start) for x in tbuf]
-        for shard_set in zip(*comps):
-            d = shard_set[0].index[0].start
-            m = counts.get(d, 0)
-            if m == 0:
-                continue
-            sh, sl, ph, pl, ac = (np.asarray(s.data)[0] for s in shard_set)
-            fps = ((sh[:m].astype(np.uint64) << np.uint64(32))
-                   | sl[:m].astype(np.uint64))
-            parents = ((ph[:m].astype(np.uint64) << np.uint64(32))
-                       | pl[:m].astype(np.uint64))
-            trace.add_batch(fps, parents, ac[:m])
+    # What ``_TraceFlush`` (engine/bfs.py) takes from this engine: the
+    # fetch program, the one-chip engine's way of recording a fetched
+    # piece, and one plan for each chip.
+    _fetch = staticmethod(_fetch_shard)
+    _record = staticmethod(BFSEngine._record)
+
+    def _trace_parts(self, tbuf, counts) -> list:
+        """``[(a chip's five trace columns, its records)]`` for this
+        controller's ADDRESSABLE chips only, in chip order; ``counts`` is
+        indexed by chip.  Under a process group, fetching the global
+        arrays would be a cross-host gather; instead each controller's
+        store accumulates the records its own chips produced, fetched by
+        one-device programs no other controller takes part in, and the
+        stores are merged through per-controller piece files at replay
+        time (:meth:`_merge_trace_pieces`)."""
+        comps = [sorted(x.addressable_shards, key=_chip) for x in tbuf]
+        return [(tuple(s.data for s in cols),
+                 int(counts[_chip(cols[0])]))
+                for cols in zip(*comps)]
 
     # -- multi-host trace exchange (shared filesystem, like R8) ---------
     @property

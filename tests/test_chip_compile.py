@@ -12,8 +12,10 @@ over the bench's 2^25-key table, the whole chunk program of ``BFSEngine``
 at a small batch (and that it holds no gather expanded into a per-lane
 loop), its ``ingest`` program, the two programs a seen-set growth
 dispatches at the verdict cell's sizes, the trace flush's fetch programs
-over the bench's trace buffers, the swarm walk chunk at 1,024 walks and
-at the size of the benchmark's ``mcraft3-hunt`` cell, the
+over the bench's trace buffers (the one-chip engine's, and the mesh's
+over one chip's shards of the four-chip deployment), the swarm walk
+chunk at 1,024 walks and at the size of the benchmark's
+``mcraft3-hunt`` cell, the
 mesh chunk and ingest over the four described chips (with the
 owner-routed dedup's ``all-to-all``).
 
@@ -277,14 +279,11 @@ def test_mesh_ingest_over_four_described_chips(topo):
     assert "all-to-all" in c.as_text()
 
 
-def test_mesh_chunk_at_the_four_chip_deployments_sizes(setup, topo):
-    """The mesh chunk as configuration ``mcraft3-mesh4`` runs it
+def deployment_mesh_engine(setup, topo):
+    """The engine of configuration ``mcraft3-mesh4``
     (``benchmark/configs/mcraft3-mesh4.json``: batch 2,048 a chip,
     16,777,216 queue rows and 2^27 keys over the four chips, trace
-    recording on) compiles for the described v5e:2x2; its arguments and
-    temporaries fit one chip's 16 GB with the host loop's third queue
-    beside them; the routed dedup is three ``all-to-all``s (the two
-    fingerprint halves out, the novelty bits back).  About 50 s here."""
+    recording on) over the described chips."""
     from raft_tla_tpu.engine.bfs import EngineConfig
     from raft_tla_tpu.engine.check import make_engine
     from raft_tla_tpu.parallel.mesh import MeshBFSEngine
@@ -294,6 +293,16 @@ def test_mesh_chunk_at_the_four_chip_deployments_sizes(setup, topo):
         engine_cls=functools.partial(MeshBFSEngine,
                                      devices=list(topo.devices)))
     assert (eng.n_dev, eng._QL, eng._CL) == (4, 1 << 22, 1 << 25)
+    return eng
+
+
+def test_mesh_chunk_at_the_four_chip_deployments_sizes(setup, topo):
+    """The mesh chunk as configuration ``mcraft3-mesh4`` runs it compiles
+    for the described v5e:2x2; its arguments and
+    temporaries fit one chip's 16 GB with the host loop's third queue
+    beside them; the routed dedup is three ``all-to-all``s (the two
+    fingerprint halves out, the novelty bits back).  About 50 s here."""
+    eng = deployment_mesh_engine(setup, topo)
     c = compile_for(
         eng._chunk,
         lambda a: NamedSharding(eng.mesh, P("x") if a.ndim else P()),
@@ -305,3 +314,25 @@ def test_mesh_chunk_at_the_four_chip_deployments_sizes(setup, topo):
             + spare_queue) < 16e9, m
     assert len(re.findall(r"= \S+ all-to-all(?:-start)?\(",
                           c.as_text())) == 3
+
+
+def test_mesh_trace_flush_fetch_programs_at_the_deployments_buffers(
+        setup, topo, one_chip):
+    """The mesh's trace flush (parallel/mesh.py ``_fetch_shard``: five
+    slices of a fixed length at a traced start of ONE chip's ``[1, TA]``
+    shards, a one-device program) at the three lengths, over a chip's
+    4.26 M-entry trace buffers of ``mcraft3-mesh4``: no collective, no
+    loop, and a piece is what it moves."""
+    from raft_tla_tpu.engine.bfs import FLUSH_PIECES
+    eng = deployment_mesh_engine(setup, topo)
+    shard = tuple(S((1,) + t.shape[1:], t.dtype)
+                  for t in eng.chunk_avals()[8])
+    assert shard[0].shape == (1, (1 << 22) + 2 * K)
+    assert eng._fetch_lens == list(FLUSH_PIECES)
+    for length in eng._fetch_lens:
+        c = compile_for(
+            lambda t, start, n=length: eng._fetch(t, start, n),
+            lambda a: one_chip, shard, S((), jnp.int32))
+        assert 20 * length <= c.memory_analysis().output_size_in_bytes \
+            < 40 * length
+        assert not re.search(r" while\(|all-|collective", c.as_text())

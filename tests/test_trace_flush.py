@@ -1,16 +1,24 @@
-"""The trace flush of the one-chip host loop (engine/bfs.py ``_TraceFlush``,
-``flush_plan``): its fetch programs have shapes fixed at the engine's
-build and compile in warm-up, its host half runs behind the next chunk
-dispatch, and it is drained wherever something other than a plain next
-call reads the store or leaves the loop.  Every run here takes one batch
-a call (``sync_every=1``), so that levels hold several calls and every
-call admits another number of states.
+"""The trace flush of both host loops (engine/bfs.py ``_TraceFlush``,
+``flush_plan``; ``BFSEngine._run_impl`` and ``MeshBFSEngine._run_impl``):
+its fetch programs have shapes fixed at the engine's build and compile in
+warm-up, its host half runs behind the next chunk dispatch, and it is
+drained wherever something other than a plain next call reads the store
+or leaves the loop.  Every run here takes one batch a call
+(``sync_every=1``), so that levels hold several calls and every call
+admits another number of states.  The mesh runs over four of the suite's
+virtual devices, four parents a chip a call: from one root everything
+lies on chip 0 until level 2's 15 rows are dealt out, so its chips'
+counts differ and some are 0; its roots' records go through the flush
+too, drained at once (the one-chip ingest hands its own back itself).
 """
 
+import functools
 import json
 import time
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from raft_tla_tpu.engine import bfs as bfs_mod
@@ -21,6 +29,7 @@ from raft_tla_tpu.models.dims import LEADER, RaftDims
 from raft_tla_tpu.models.invariants import (Bounds, build_constraint,
                                             build_type_ok)
 from raft_tla_tpu.models.pystate import init_state
+from raft_tla_tpu.parallel.mesh import MeshBFSEngine
 
 DIMS = RaftDims(n_servers=3, n_values=2, max_log=4, n_msg_slots=32)
 BOUNDS = Bounds(max_term=2, max_log_len=1, max_msg_count=1)
@@ -30,13 +39,26 @@ LEVELS = [1, 3, 15, 52, 162, 486]
 DISTINCT = 1313     # the states the constraint keeps out of a level among them
 
 
-def make_engine(invariants=None, **kw):
+ENGINES = ["one_chip", "mesh"]
+
+
+@pytest.fixture(params=ENGINES)
+def kind(request):
+    return request.param
+
+
+def make_engine(kind="one_chip", invariants=None, chips=4, **kw):
     base = dict(batch=16, queue_capacity=1 << 12, seen_capacity=1 << 15,
                 check_deadlock=False, sync_every=1, max_diameter=5)
+    cls = BFSEngine
+    if kind == "mesh":
+        base["batch"] = 4       # a chip: level 4's 162 rows are 11 calls
+        cls = functools.partial(MeshBFSEngine,
+                                devices=jax.devices()[:chips])
     base.update(kw)
-    return BFSEngine(DIMS, invariants=invariants,
-                     constraint=build_constraint(DIMS, BOUNDS),
-                     config=EngineConfig(**base))
+    return cls(DIMS, invariants=invariants,
+               constraint=build_constraint(DIMS, BOUNDS),
+               config=EngineConfig(**base))
 
 
 def run_end(path):
@@ -48,13 +70,15 @@ def run_end(path):
 
 @pytest.fixture
 def started(monkeypatch):
-    """The number of records of every flush the loop starts."""
+    """The records of every flush the loop starts, a number a plan (the
+    one-chip loop's one, the mesh's one a chip)."""
     lengths = []
     start = bfs_mod._TraceFlush.start
 
-    def noting(self, tbuf, n):
-        lengths.append(n)
-        start(self, tbuf, n)
+    def noting(self, parts):
+        parts = list(parts)
+        lengths.append(tuple(n for _buf, n in parts))
+        start(self, parts)
 
     monkeypatch.setattr(bfs_mod._TraceFlush, "start", noting)
     return lengths
@@ -107,17 +131,28 @@ def test_the_plan_covers_the_records_once_and_in_order(n, lengths, size,
 
 # -- no compile after warm-up -----------------------------------------------------
 
-def test_no_flush_compiles_whatever_its_length(started, tmp_path):
+def test_no_flush_compiles_whatever_its_length(kind, started, tmp_path,
+                                               monkeypatch):
     ev = str(tmp_path / "ev.jsonl")
     # Sizes of its own: nothing here is in the process's jit cache.
-    eng = make_engine(batch=18, queue_capacity=18 * 233, events_out=ev)
+    if kind == "mesh":
+        # Two lengths under a chip's buffer, and flushes of several pieces.
+        monkeypatch.setattr(bfs_mod, "FLUSH_PIECES", (8, 32))
+        eng = make_engine(kind, batch=5, queue_capacity=4 * 5 * 233,
+                          events_out=ev)
+        assert eng._fetch_lens == [8, 32]
+        # The fills before warm-up, and a deal-out's own programs.
+        outside = {"run", "root_check", "warmup", "rebalance"}
+    else:
+        eng = make_engine(kind, batch=18, queue_capacity=18 * 233,
+                          events_out=ev)
+        outside = {"root_check", "run_init", "warmup", "frontier_fetch"}
     res = eng.run([init_state(DIMS)])
     assert res.levels == LEVELS
     assert len(set(started)) >= 10, started
     end = run_end(ev)
-    assert end["chunk_calls"] == len(started)
-    in_the_loop = set(end["compiles"]) - {"root_check", "run_init",
-                                          "warmup", "frontier_fetch"}
+    assert end["chunk_calls"] == len(started) - (kind == "mesh")
+    in_the_loop = set(end["compiles"]) - outside
     assert not in_the_loop, end["compiles"]
     assert end["compiles"]["warmup"][0] >= 2 + len(eng._fetch_lens)
 
@@ -126,26 +161,31 @@ def test_no_flush_compiles_whatever_its_length(started, tmp_path):
 
 @pytest.mark.parametrize("pieces", [bfs_mod.FLUSH_PIECES, (4, 16)],
                          ids=["one_piece", "several_pieces"])
-def test_the_store_equals_a_synchronous_flush(pieces, monkeypatch):
-    """The deferred flush against ``_record`` applied before anything
-    else happens, on the same run; with pieces of 4 and 16 records a
-    flush takes up to eight fetches."""
+def test_the_store_equals_a_synchronous_flush(kind, pieces, started,
+                                              monkeypatch):
+    """The deferred flush against every buffer copied whole to the host
+    and cut there, before anything else happens, on the same run (what
+    the mesh loop did until PR 34); with pieces of 4 and 16 records a
+    flush takes up to eight fetches a buffer."""
     monkeypatch.setattr(bfs_mod, "FLUSH_PIECES", pieces)
-    eng = make_engine()
+    eng = make_engine(kind)
     res = eng.run([init_state(DIMS)])
     assert (res.levels, res.distinct) == (LEVELS, DISTINCT)
     # One record a distinct state, the root's among them.
     assert len(eng.trace.export()[0]) == res.distinct
     assert_every_chain_reaches_a_root(eng.trace)
+    if kind == "mesh":
+        # Chips whose counts differ, one of them 0; and all four at work.
+        assert any(0 in c and max(c) > 0 for c in started), started
+        assert any(len(set(c)) > 1 and min(c) > 0 for c in started)
 
-    start = bfs_mod._TraceFlush.start
+    def whole_buffers_at_once(self, parts):
+        for buf, n in parts:
+            cols = [np.asarray(x).reshape(-1) for x in buf]
+            self._eng._record(self._trace, cols, 0, n)
 
-    def at_once(self, tbuf, n):
-        start(self, tbuf, n)
-        self.finish("flush_drained")
-
-    monkeypatch.setattr(bfs_mod._TraceFlush, "start", at_once)
-    sync = make_engine()
+    monkeypatch.setattr(bfs_mod._TraceFlush, "start", whole_buffers_at_once)
+    sync = make_engine(kind)
     assert sync.run([init_state(DIMS)]).distinct == res.distinct
     assert sync.metrics.counter_value("engine/flush_overlapped") == 0
     assert records(eng.trace) == records(sync.trace)
@@ -153,18 +193,18 @@ def test_the_store_equals_a_synchronous_flush(pieces, monkeypatch):
 
 # -- drain points -----------------------------------------------------------------
 
-def drained_before_a_checkpoint(tmp_path):
+def drained_before_a_checkpoint(kind, tmp_path):
     """Level 4 is eleven calls; its snapshot holds every record of it,
     and the resumed run ends where an uninterrupted one does."""
     ck = str(tmp_path / "ck")
-    first = make_engine(max_diameter=4, checkpoint_dir=ck)
+    first = make_engine(kind, max_diameter=4, checkpoint_dir=ck)
     first.run([init_state(DIMS)])
     path = ckpt_mod.latest(ck)
     assert path.endswith("level_00004.npz")
     kept = ckpt_mod.load(path)
     assert kept.levels == tuple(LEVELS[:5])
     assert kept.trace_fps.size == kept.distinct
-    second = make_engine()
+    second = make_engine(kind)
     got = second.run(resume=path)
     assert (got.levels, got.distinct) == (LEVELS, DISTINCT)
     assert len(second.trace.export()[0]) == got.distinct
@@ -177,7 +217,7 @@ def drained_before_a_checkpoint(tmp_path):
     return [first, second]
 
 
-def drained_before_a_replay(tmp_path):
+def drained_before_a_replay(kind, tmp_path):
     """The violation lies in a call dispatched with its predecessor's
     flush still owed; the replay needs both calls' records.  (The root
     of tests/test_engine.py: a candidate one vote short of quorum.)"""
@@ -187,9 +227,9 @@ def drained_before_a_replay(tmp_path):
         messages=frozenset({((1, 1, 0, 2, 1, ()), 1)}))
     ev = str(tmp_path / "ev.jsonl")
     eng = make_engine(
-        invariants={"TypeOK": build_type_ok(DIMS),
-                    "NoLeader": lambda st: jnp.all(st.role != LEADER)},
-        batch=2, max_diameter=None, events_out=ev)
+        kind, invariants={"TypeOK": build_type_ok(DIMS),
+                          "NoLeader": lambda st: jnp.all(st.role != LEADER)},
+        batch=1 if kind == "mesh" else 2, max_diameter=None, events_out=ev)
     res = eng.run([s0])
     assert res.stop_reason == "violation"
     with open(ev, encoding="utf-8") as f:
@@ -203,9 +243,9 @@ def drained_before_a_replay(tmp_path):
     return [eng]
 
 
-def drained_at_a_duration_stop(tmp_path):
+def drained_at_a_duration_stop(kind, tmp_path):
     """Whatever call the deadline falls after, nothing stays owed."""
-    eng = make_engine(max_diameter=None, max_seconds=1.0)
+    eng = make_engine(kind, max_diameter=None, max_seconds=1.0)
     res = eng.run([init_state(DIMS)])
     assert res.stop_reason == "duration_budget"
     assert len(eng.trace.export()[0]) == res.distinct
@@ -213,12 +253,30 @@ def drained_at_a_duration_stop(tmp_path):
     return [eng]
 
 
+def drained_where_the_seen_set_grows(kind, tmp_path):
+    """A growth inside a level: the rebuilt programs hand the loop
+    another trace buffer, and every record of the calls on either side
+    of it is in the store.  A table of the least capacity is 2,048
+    slots (a chip) and grows past 1,024 keys: in level 5 on one chip, in
+    level 6 on each of two."""
+    depth = 6 if kind == "mesh" else 5
+    eng = make_engine(kind, chips=2, batch=16, seen_capacity=1 << 11,
+                      max_diameter=depth)
+    res = eng.run([init_state(DIMS)])
+    assert res.growth_stalls
+    assert res.levels == (LEVELS + [1378])[:depth + 1]
+    assert len(eng.trace.export()[0]) == res.distinct
+    assert_every_chain_reaches_a_root(eng.trace)
+    return [eng]
+
+
 @pytest.mark.parametrize("case", [drained_before_a_checkpoint,
                                   drained_before_a_replay,
-                                  drained_at_a_duration_stop],
+                                  drained_at_a_duration_stop,
+                                  drained_where_the_seen_set_grows],
                          ids=lambda f: f.__name__)
-def test_nothing_is_owed_at_a_drain_point(case, tmp_path, started):
-    engines = case(tmp_path)
+def test_nothing_is_owed_at_a_drain_point(case, kind, tmp_path, started):
+    engines = case(kind, tmp_path)
     counts = [bfs_mod.work_counts(eng.metrics) for eng in engines]
     assert sum(c["flush_overlapped"] for c in counts) > 0
     # Every flush started was finished, one way or the other.
@@ -226,18 +284,20 @@ def test_nothing_is_owed_at_a_drain_point(case, tmp_path, started):
                for c in counts) == len(started)
 
 
-def test_a_degraded_resume_takes_no_record_of_the_run_that_died(tmp_path):
+def test_a_degraded_resume_takes_no_record_of_the_run_that_died(kind,
+                                                                tmp_path):
     """``_run_degradable`` resumes from the snapshot into a new store:
     what the dead attempt still owed must not reach it."""
     from raft_tla_tpu.resilience import faults
     ck = str(tmp_path / "ck")
+    half = 2 if kind == "mesh" else 8
     faults.install("oom@level=4;chunk=5", hard=False)
     try:
-        eng = make_engine(checkpoint_dir=ck, min_batch=8)
+        eng = make_engine(kind, checkpoint_dir=ck, min_batch=half)
         res = eng.run([init_state(DIMS)])
     finally:
         faults.clear()
-    assert eng.config.batch == 8 and res.levels == LEVELS
+    assert eng.config.batch == half and res.levels == LEVELS
     assert eng.metrics.counter_value("engine/degraded") == 1
     assert len(eng.trace.export()[0]) == res.distinct
     assert_every_chain_reaches_a_root(eng.trace)
@@ -245,27 +305,30 @@ def test_a_degraded_resume_takes_no_record_of_the_run_that_died(tmp_path):
 
 # -- the counters -----------------------------------------------------------------
 
-def test_run_end_counts_every_flush_once(started, tmp_path):
+def test_run_end_counts_every_flush_once(kind, started, tmp_path):
     ev = str(tmp_path / "ev.jsonl")
-    eng = make_engine(events_out=ev)
+    eng = make_engine(kind, events_out=ev)
     res = eng.run([init_state(DIMS)])
     end = run_end(ev)
     assert end["flush_overlapped"] + end["flush_drained"] == len(started)
     # One drain a level; every other call of a level is overlapped.
-    assert end["flush_drained"] == res.diameter == 5
+    assert end["flush_drained"] - (kind == "mesh") == res.diameter == 5
     assert end["flush_overlapped"] == end["chunk_calls"] - 5 > 0
     with open(ev, encoding="utf-8") as f:
         levels = [json.loads(line) for line in f]
     per_level = [(e["chunk_calls"], e["flush_overlapped"],
                   e["flush_drained"]) for e in levels
                  if e["event"] == "level_complete" and e["level"] >= 1]
-    assert per_level == [(-(-n // 16), -(-n // 16) - 1, 1)
-                         for n in LEVELS[:5]]
+    # The mesh expands a chip's share of a level, 4 rows a call.
+    calls = ([1, 1, 1, 4, 12] if kind == "mesh"
+             else [-(-n // 16) for n in LEVELS[:5]])
+    assert per_level == [(c, c - 1, 1) for c in calls]
 
 
 # -- the cost of a call -----------------------------------------------------------
 
-def test_a_calls_cost_runs_from_its_dispatch_to_its_statistics(monkeypatch):
+def test_a_calls_cost_runs_from_its_dispatch_to_its_statistics(kind,
+                                                                monkeypatch):
     """The deadline sizing divides what is left by ``_batch_ema``: a host
     half of a flush that outlasts the device is part of the call it ran
     under (dispatch + fetch seconds alone would leave it out)."""
@@ -277,7 +340,24 @@ def test_a_calls_cost_runs_from_its_dispatch_to_its_statistics(monkeypatch):
         finish(self, counter)
 
     monkeypatch.setattr(bfs_mod._TraceFlush, "finish", slow)
-    eng = make_engine(max_diameter=4)       # level 3's 52 parents: 4 calls
+    # Level 3's 52 parents: 4 calls, of 16 or of four chips' 4.
+    eng = make_engine(kind, max_diameter=4)
     eng.run([init_state(DIMS)])
     assert eng.metrics.counter_value("engine/flush_overlapped") == 3
     assert eng._batch_ema >= 0.25
+
+
+# -- trace recording off --------------------------------------------------------
+
+def test_without_trace_recording_no_fetch_is_dispatched(kind, started,
+                                                        monkeypatch):
+    eng = make_engine(kind, record_trace=False)
+    fetched = []
+    monkeypatch.setattr(eng, "_fetch",
+                        lambda *a: fetched.append(a), raising=True)
+    res = eng.run([init_state(DIMS)])
+    assert (res.levels, res.distinct) == (LEVELS, DISTINCT)
+    assert not fetched and not started
+    counts = bfs_mod.work_counts(eng.metrics)
+    assert counts["flush_overlapped"] == counts["flush_drained"] == 0
+    assert counts["chunk_calls"] > 5
