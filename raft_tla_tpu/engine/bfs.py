@@ -79,7 +79,10 @@ _I32 = jnp.int32
 # What the host loop counts where it does the work, as the registry's
 # ``engine/<name>`` counters (process-cumulative, like every counter):
 # ``run_end`` carries a run's share, ``level_complete`` each level's.
-WORK_COUNTERS = ("chunk_calls", "passes", "ingest_calls",
+# ``inv_lanes``: compacted lanes the chunk evaluated the invariants on,
+# K a pass on every chip (duplicates and empty lanes included; TLC
+# evaluates an invariant once a new distinct state).
+WORK_COUNTERS = ("chunk_calls", "passes", "inv_lanes", "ingest_calls",
                  "parents_expanded", "flush_overlapped", "flush_drained")
 
 
@@ -773,6 +776,7 @@ class BFSEngine:
         PAD = max(B, K)
         self._sw, self._B, self._G, self._Q = sw, B, G, Q
         self._K, self._PAD = K, PAD
+        self._inv_lanes_a_pass = K if inv_fns else 0
 
         # The ingest program's stages carry the chunk's names
         # (engine/chunk.py STAGES), so one reduction reads both.
@@ -990,6 +994,11 @@ class BFSEngine:
                 chunk_avals=self.chunk_avals(),
                 dims=dims, B=B, K=K,
                 metrics=self.metrics)
+        # The last frontier's fetch (``_fetch_frontier``).
+        self._frontier_rows = min(1 << 16, Q + PAD)
+        self._frontier_piece = jax.jit(
+            lambda q, start: jax.lax.dynamic_slice_in_dim(
+                q, start, self._frontier_rows, axis=0))
         self._fp_rows = jax.jit(fp_rows)
         self._expand1 = jax.jit(expand)
         self._fp_batch = jax.jit(jax.vmap(fingerprint))
@@ -1389,6 +1398,7 @@ class BFSEngine:
         mt = self.metrics
         mt.counter("engine/chunk_calls")
         mt.counter("engine/passes", passes)
+        mt.counter("engine/inv_lanes", passes * self._inv_lanes_a_pass)
         mt.counter("engine/parents_expanded", parents)
 
     def _level_event(self, res, frontier_rows):
@@ -1629,6 +1639,7 @@ class BFSEngine:
             if cfg.record_trace:
                 for length in self._fetch_lens:
                     self._fetch(tbuf, np.int32(0), length)
+            self._frontier_piece(qcur, np.int32(0))
         flush = _TraceFlush(self, trace)
         t0 = time.time()
         last_progress = t0
@@ -2069,10 +2080,26 @@ class BFSEngine:
         # Final frontier snapshot (empty when exhausted): profiling tools
         # use it as a representative mid-level workload.
         with mt.phase_timer("frontier_fetch"):
-            self._last_frontier = (np.asarray(qcur[:cur_count])
-                                   if cur_count
-                                   else np.zeros((0, sw), ROW_DTYPE))
+            self._last_frontier = self._fetch_frontier(qcur, cur_count)
         return res
+
+    def _fetch_frontier(self, qcur, count: int) -> np.ndarray:
+        """Rows ``[0, count)`` of a level queue on the host, in pieces of
+        one fixed shape (``_frontier_piece``, compiled in warm-up): a
+        slice at the frontier's own length is a compile of its own at
+        every new length, inside the duration budget."""
+        rows, qa = self._frontier_rows, qcur.shape[0]
+        pieces = []
+        for start in range(0, count, rows):
+            # A piece that would pass the queue's end starts earlier.
+            at = min(start, qa - rows)
+            piece = self._frontier_piece(qcur, np.int32(at))
+            piece.copy_to_host_async()
+            pieces.append((piece, start - at, min(rows, count - start)))
+        if not pieces:
+            return np.zeros((0, self._sw), ROW_DTYPE)
+        return np.concatenate([np.asarray(piece)[lo:lo + n]
+                               for piece, lo, n in pieces])
 
     # ------------------------------------------------------------------
     def replay(self, fp: int) -> List[Tuple[int, PyState]]:

@@ -19,6 +19,7 @@ from ..models.invariants import (Bounds, build_constraint,
 from ..models.pystate import PyState, init_state
 from ..utils.cfg import CheckSetup, load_config
 from .bfs import BFSEngine, EngineConfig, EngineResult
+from .chunk import named_stage
 
 # name -> builder(dims) -> kernel(state)->bool.  TypeOK (raft.tla:482-492)
 # plus the whole dead-region safety suite (raft.tla:896-1180; SURVEY §2.3),
@@ -34,13 +35,16 @@ CONSTRAINT_REGISTRY: Dict[str, Callable[[RaftDims, Bounds], Callable]] = {
 
 
 def resolve_invariants(setup: CheckSetup) -> Dict[str, Callable]:
+    """The cfg's INVARIANTS in cfg order, each kernel traced under a
+    scope of its own name (a capture then says which predicate an
+    operation of any engine's program belongs to)."""
     invs = {}
     for name in setup.invariants:
         if name not in INVARIANT_REGISTRY:
             raise ValueError(
                 f"unknown INVARIANT {name!r}; registered: "
                 f"{sorted(INVARIANT_REGISTRY)}")
-        invs[name] = INVARIANT_REGISTRY[name](setup.dims)
+        invs[name] = named_stage(name, INVARIANT_REGISTRY[name](setup.dims))
     return invs
 
 

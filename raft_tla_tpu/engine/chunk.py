@@ -66,7 +66,17 @@ STAGES = ("slice", "masks", "compact", "construct", "insert", "enqueue",
 # executable whose operations carry the old names.  The tag is in what
 # is hashed (``tag_stages``).  Change it with the names: the chunk and
 # ingest programs then compile once more.
-STAGES_TAG = "s1"
+STAGES_TAG = "s2"
+
+# The parts of ``construct``, as scopes nested in the stage's own
+# (``.../construct/<part>/...``): the parents' hash sums and the K-lane
+# gather of the parents, the successors' construction, the state
+# constraint, the rows' packing, and the invariants (each predicate
+# under its cfg name where ``engine/check.py`` resolved it).  The
+# parents' fingerprints for the trace records stay under the stage's
+# name alone.
+CONSTRUCT_PARTS = ("parents", "lane_out", "constraint", "flatten",
+                   "invariants")
 
 
 def named_stage(name: str, fn):
@@ -213,33 +223,42 @@ def build_chunk_body(*, dims, expand, fingerprint, pack_ok, inv_fns,
         # first, hash after (identical to hashing the packed rows
         # whenever pack_ok holds, and any overflow aborts the run).
         # Hashing before compaction would read every field of all
-        # B*G lanes for the ~94% that are disabled.
+        # B*G lanes for the ~94% that are disabled.  Its parts run
+        # under scopes of their own (``CONSTRUCT_PARTS``).
         if v2 is None:
-            cflat = jax.tree.map(
-                lambda a: a.reshape((BG,) + a.shape[2:]), cands)
-            kstates = jax.tree.map(lambda a: a[lane_id], cflat)
+            with jax.named_scope("parents"):
+                cflat = jax.tree.map(
+                    lambda a: a.reshape((BG,) + a.shape[2:]), cands)
+                kstates = jax.tree.map(lambda a: a[lane_id], cflat)
             kh, kl = jax.vmap(fingerprint)(kstates)     # [K]
         else:
             # Gather K parent structs (from B parents, not B*G
             # candidate lanes) and construct only those successors,
             # with their fingerprints coming from the parents' hash
             # sums + per-lane deltas (models/actions2.py).
-            ph = jax.vmap(v2.parent_hash)(states)
-            pidx = lane_id // G
-            kparents = jax.tree.map(lambda a: a[pidx], states)
-            kph = jax.tree.map(lambda a: a[pidx], ph)
-            kh, kl, kstates = jax.vmap(v2.lane_out)(
-                kparents, kph, lane_id % G)
+            with jax.named_scope("parents"):
+                ph = jax.vmap(v2.parent_hash)(states)
+                pidx = lane_id // G
+                kparents = jax.tree.map(lambda a: a[pidx], states)
+                kph = jax.tree.map(lambda a: a[pidx], ph)
+            with jax.named_scope("lane_out"):
+                kh, kl, kstates = jax.vmap(v2.lane_out)(
+                    kparents, kph, lane_id % G)
 
         if constraint is not None:
-            cons_ok = jax.vmap(constraint)(kstates)
+            with jax.named_scope("constraint"):
+                cons_ok = jax.vmap(constraint)(kstates)
         else:
             cons_ok = jnp.ones((K,), bool)
-        krows = jax.vmap(flatten_state, (0, None))(kstates, dims)
+        with jax.named_scope("flatten"):
+            krows = jax.vmap(flatten_state, (0, None))(kstates, dims)
         # Invariant dispatch depends only on the candidates, so it
-        # sits before the insert.
+        # sits before the insert: every invariant on all K lanes of
+        # the pass, duplicates and empty lanes included (the engines
+        # count them, ``inv_lanes``).
         if inv_id is not None:
-            inv = jax.vmap(inv_id)(kstates)
+            with jax.named_scope("invariants"):
+                inv = jax.vmap(inv_id)(kstates)
         else:
             inv = jnp.full((K,), -1, _I32)
         parent_hi = parent_lo = None

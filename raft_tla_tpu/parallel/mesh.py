@@ -232,6 +232,9 @@ class MeshBFSEngine:
         CL = fpset._capacity(max(-(-sreq // n), 8 * K))
         self._sw, self._B, self._G, self._QL, self._CL = sw, B, G, QL, CL
         self._K, self._PAD = K, PAD
+        # Every chip runs the body on its own K lanes (engine/bfs.py
+        # WORK_COUNTERS ``inv_lanes``).
+        self._inv_lanes_a_pass = n * K if inv_fns else 0
         self._QTH = QL - K
         CH = self._CH = max(1, cfg.sync_every)
         record_static = cfg.record_trace

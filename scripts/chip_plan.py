@@ -12,10 +12,13 @@ for the checkout itself, or a git-ignored copy of another commit such as
 ``_archive_check/parent``), with ``env`` added to the environment
 (``$ROOT`` stands for this checkout).  ``how`` = ``"ops"`` runs
 ``scripts/ops_per_pass.py`` instead, ``"probe"`` ``scripts/setup_probe.py``,
-``"record"`` ``scripts/record_capture.py``, ``"control:<name>"``
+``"record"`` ``scripts/record_capture.py``, ``"check"`` the CLI's ``check``
+with ``workload`` as its arguments (one string, split at spaces; no result
+line: what it printed of the run's totals is shown), ``"control:<name>"``
 ``benchmark/tests/controls_mesh.py <name>`` around the same run, and
-``"control:swarm:<name>"`` ``benchmark/tests/controls_swarm.py <name>`` (a
-result of ``correct: false`` is what either must give).  After ``how``: ``"stop"``
+``"control:swarm:<name>"`` ``benchmark/tests/controls_swarm.py <name>``
+(``"control:safety:<name>"`` likewise; a result of ``correct: false`` is
+what each must give).  After ``how``: ``"stop"``
 ends the plan when that run did not give what it should, and
 ``"limit:<seconds>"`` kills the run at that age (rc -9 in the results).
 ``--within`` is the plan's own limit: a run gets no more than what is
@@ -42,7 +45,9 @@ SHOWN = ("stages:", "idle ", "window compiles", "trace:", "trace reduced",
          "setup:", "window:", "fill:", "ROOT", "   ", "capture_small",
          "traced check", "kept snapshot", "mesh:", "enqueued:", "at ",
          "memory", "replay:", "sample:", "window phases", "walk:",
-         "window counters")
+         "window counters", "construct:", "suite:", "witness ",
+         "distinct states", "states generated", "diameter", "stop reason",
+         "wall seconds", "states/sec", "VIOLATION", "pipeline")
 
 
 def command(root: str, workload: str, seed: int, trace: int, how: str):
@@ -54,6 +59,9 @@ def command(root: str, workload: str, seed: int, trace: int, how: str):
                                              "setup_probe.py"), root]
     if how == "record":
         return [sys.executable, "scripts/record_capture.py"]
+    if how == "check":
+        return [sys.executable, "-m", "raft_tla_tpu", "check",
+                *workload.split()]
     run = ["--workload", workload, "--seed", str(seed), "--seconds", "20",
            "--trace", str(trace)]
     if how.startswith("control:"):

@@ -223,7 +223,8 @@ def test_loop_counters_equal_a_hand_count(noleader, tmp_path):
             again["parents_expanded"]) == (4, 4, 1, 71)
     # One call a level: each flush is drained at its level's end.
     assert bfs_mod.work_counts(eng.metrics) == {
-        "chunk_calls": 8, "passes": 8, "ingest_calls": 2,
+        "chunk_calls": 8, "passes": 8, "inv_lanes": 8 * eng._K,
+        "ingest_calls": 2,
         "parents_expanded": 142, "flush_overlapped": 0,
         "flush_drained": 8}
 
