@@ -40,6 +40,11 @@ Transcribed semantics, with reference citations:
 - ``LeaderCompleteness`` — raft.tla:1176-1180.
 
 Every kernel returns a scalar bool: True = invariant holds in this state.
+
+No predicate indexes a table by a traced value (``st.term[src]``): under
+``vmap`` that is a gather, 8-12 ns an element on the TPU and 62 % of a pass
+of the benchmark's ``safety9`` until PR 36.  ``_pick`` compares the position
+with the table's static axis, selects and sums (``actions2.py dvec``'s idiom).
 """
 
 from __future__ import annotations
@@ -55,11 +60,22 @@ from .pystate import PyState
 # Shared tensor helpers (single state, no batch axis).
 
 
+def _is_at(at, size):
+    """One-hot rows of in-range positions: ``[len(at), size]`` bools."""
+    return at[:, None] == jnp.arange(size)[None, :]
+
+
+def _pick(is_, table):
+    """``table`` at the one position each row of ``is_`` marks along axis 1
+    (``table`` broadcasts against ``is_``): the suite's one way to read at
+    a traced position (module docstring)."""
+    return jnp.sum(jnp.where(is_, table, 0), axis=1)
+
+
 def _last_terms(st, L):
     """LastTerm(log[i]) for all i — raft.tla:84.  [N]."""
-    n = st.log_len.shape[0]
     at = jnp.clip(st.log_len - 1, 0, L - 1)
-    return jnp.where(st.log_len > 0, st.log_term[jnp.arange(n), at], 0)
+    return jnp.where(st.log_len > 0, _pick(_is_at(at, L), st.log_term), 0)
 
 
 def _entry_eq(st):
@@ -97,10 +113,15 @@ def build_messages_inv(dims: RaftDims):
         src = jnp.clip(st.msg[:, 1] - 1, 0, N - 1)
         dst = jnp.clip(st.msg[:, 2] - 1, 0, N - 1)
         mterm = st.msg[:, 3]
+        src_is, dst_is = _is_at(src, N), _is_at(dst, N)   # [M, N]
         lt = _last_terms(st, L)                           # [N]
-        len_src, len_dst = st.log_len[src], st.log_len[dst]
-        lt_src, lt_dst = lt[src], lt[dst]
-        t_src, t_dst = st.term[src], st.term[dst]
+        len_src, len_dst = _pick(src_is, st.log_len), \
+            _pick(dst_is, st.log_len)
+        lt_src, lt_dst = _pick(src_is, lt), _pick(dst_is, lt)
+        t_src, t_dst = _pick(src_is, st.term), _pick(dst_is, st.term)
+        # log[src], whole rows; a position is picked out of them below.
+        log_term_src = _pick(src_is[:, :, None], st.log_term)   # [M, L]
+        log_val_src = _pick(src_is[:, :, None], st.log_val)
 
         # MessageTermsLtCurrentTerm — raft.tla:934-935 (all message types).
         terms_ok = mterm <= t_src
@@ -113,7 +134,7 @@ def build_messages_inv(dims: RaftDims):
         rvr_ok = ~rvr_ante | rvr_cons
 
         # RequestVoteRequestInv — raft.tla:915-920.
-        rvq_ante = (mt == RVQ) & (st.role[src] == CANDIDATE) \
+        rvq_ante = (mt == RVQ) & (_pick(src_is, st.role) == CANDIDATE) \
             & (t_src == mterm)
         rvq_cons = (st.msg[:, 5] == len_src) & (st.msg[:, 4] == lt_src)
         rvq_ok = ~rvq_ante | rvq_cons
@@ -122,13 +143,13 @@ def build_messages_inv(dims: RaftDims):
         prev, pterm = st.msg[:, 4], st.msg[:, 5]
         n_ent, eterm, eval_ = st.msg[:, 6], st.msg[:, 7], st.msg[:, 8]
         aeq_ante = (mt == AEQ) & (n_ent > 0) & (mterm == t_src)
-        at1 = jnp.clip(prev, 0, L - 1)                    # prev+1, 0-based
+        at1 = _is_at(jnp.clip(prev, 0, L - 1), L)         # prev+1, 0-based
         entry1_ok = (prev + 1 >= 1) & (prev + 1 <= len_src) \
-            & (st.log_term[src, at1] == eterm) \
-            & (st.log_val[src, at1] == eval_)
-        atp = jnp.clip(prev - 1, 0, L - 1)
+            & (_pick(at1, log_term_src) == eterm) \
+            & (_pick(at1, log_val_src) == eval_)
+        atp = _is_at(jnp.clip(prev - 1, 0, L - 1), L)
         prev_in = (prev > 0) & (prev <= len_src)
-        pterm_ok = ~prev_in | (st.log_term[src, atp] == pterm)
+        pterm_ok = ~prev_in | (_pick(atp, log_term_src) == pterm)
         aeq_ok = ~aeq_ante | (entry1_ok & pterm_ok)
 
         return jnp.all(~occ | (terms_ok & rvr_ok & rvq_ok & aeq_ok))

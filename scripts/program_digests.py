@@ -3,13 +3,15 @@
 
     python3 scripts/program_digests.py [<checkout>]
 
-Lowers ``BFSEngine``'s ``chunk`` and ``ingest`` for three cfgs and
+Lowers ``BFSEngine``'s ``chunk`` and ``ingest`` for four cfgs
+(``MCraft_safety`` is the one that traces ``models/safety.py``),
 ``MeshBFSEngine``'s two programs over four virtual devices (trace
-recording on and off) at a small batch, and prints sha256 of each
-``.lower(...).as_text()``.  jax's persistent-cache key strips debug
-metadata, so where two checkouts print the same digests the cache hands
-the second the first one's executables: a PR that must not change the
-program (ISSUE 31) shows it by running this on its parent and on itself.
+recording on and off) and ``SwarmEngine``'s walk chunk at a small batch,
+and prints sha256 of each ``.lower(...).as_text()``.  jax's
+persistent-cache key strips debug metadata, so where two checkouts print
+the same digests the cache hands the second the first one's executables:
+a PR that must not change the program (ISSUE 31), or only one cfg's
+(ISSUE 36), shows it by running this on its parent and on itself.
 """
 
 import functools
@@ -28,7 +30,8 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from raft_tla_tpu.engine.bfs import EngineConfig  # noqa: E402
-from raft_tla_tpu.engine.check import make_engine  # noqa: E402
+from raft_tla_tpu.engine.check import (make_engine,  # noqa: E402
+                                       make_swarm_engine)
 from raft_tla_tpu.parallel.mesh import MeshBFSEngine  # noqa: E402
 from raft_tla_tpu.utils.cfg import load_config  # noqa: E402
 
@@ -46,7 +49,8 @@ def small(**kw) -> EngineConfig:
 
 def main() -> None:
     i32 = S((), jnp.int32)
-    for cfg in ("MCraft_bounded", "TPUraft", "MCraft_noleader"):
+    for cfg in ("MCraft_bounded", "TPUraft", "MCraft_noleader",
+                "MCraft_safety"):
         eng = make_engine(
             load_config(os.path.join(ROOT, f"configs/{cfg}.cfg")), small())
         av = eng.chunk_avals()
@@ -73,6 +77,11 @@ def main() -> None:
             eng._ingest.lower(over_mesh(S((n, B, sw), jnp.uint8)),
                               over_mesh(S((n, B), jnp.bool_)), qav, counts,
                               keys, keys, counts, tbuf, counts)))
+    eng = make_swarm_engine(
+        load_config(os.path.join(ROOT, "configs/MCraft_noleader.cfg")),
+        walks=256, max_depth=64, batch=256)
+    print("walk chunk  MCraft_noleader "
+          + digest(eng._chunk.lower(*eng.chunk_avals(1))))
 
 
 if __name__ == "__main__":

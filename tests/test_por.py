@@ -182,6 +182,33 @@ def test_predicate_read_sets(pass_result):
     assert set(reads["CONSTRAINT"]) == {"term", "log_len", "msg_cnt"}
 
 
+@pytest.mark.parametrize("name, fields, msg_columns", [
+    ("MessagesInv", ("term", "role", "log_len", "log_term", "log_val",
+                     "msg_cnt", "msg"), 9),
+    ("MoreUpToDateCorrect", ("commit", "log_len", "log_term", "log_val"),
+     None),
+])
+def test_suite_read_sets_without_traced_index_reads(name, fields,
+                                                    msg_columns):
+    """The suite reads its tables at a traced position by compare, select
+    and sum (models/safety.py ``_pick``), primitives the taint domain
+    carries: the read sets are what the gathers gave (a gather at a
+    traced index tainted the whole table too), element for element, and
+    the domain falls back on no conservative rule."""
+    from raft_tla_tpu.analysis.lane_map import field_shapes
+    from raft_tla_tpu.models.safety import SAFETY_INVARIANTS
+    reads, notes = por.predicate_read_sets(
+        DIMS, [(name, SAFETY_INVARIANTS[name](DIMS))])
+    assert notes == []
+    want = {f: np.ones(field_shapes(DIMS)[f], bool) for f in fields}
+    if msg_columns:     # header, mterm and the payload the four read
+        want["msg"][:, msg_columns:] = False
+    got = reads[name]
+    assert set(got) == set(want)
+    for f, mask in want.items():
+        assert np.array_equal(np.asarray(got[f], bool), mask), f
+
+
 def test_self_disabling_proof():
     """C3: a guard proved false on the kernel's own successor envelope.
     A one-shot toy action (guard ``role[0] == 0``, write ``role[0] = 1``)
