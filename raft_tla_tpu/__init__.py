@@ -28,4 +28,12 @@ The reference's ``MCraft.cfg``/``Smokeraft.cfg`` remain the source of truth:
 the cfg parser consumes them verbatim (they are *read*, never copied).
 """
 
+import time as _time
+
+# The ``package`` mark of the process record (obs/metrics.py
+# ProcessRecord), taken here so that importing the package stays what it
+# was: nothing else is imported, and the record itself is made when
+# ``obs`` first is.
+IMPORT_STAMP = _time.perf_counter()
+
 __version__ = "0.1.0"

@@ -1135,8 +1135,14 @@ def main(argv=None):
     # what makes supervised crash-resume restarts cheap (each restart is
     # a fresh process re-running the same programs).  Enabled below the
     # supervise branch: the supervisor parent only spawns children and
-    # must not pay the jax import itself.
+    # must not pay the jax import itself.  Around it, the two marks of
+    # the process record (obs/metrics.py) that split what comes before
+    # ``make_engine`` into the import of jax and the backend's start-up.
+    import jax
+
+    from .obs.metrics import process_record
     from .utils.platform import enable_persistent_cache
+    process_record().mark("jax_imported")
     enable_persistent_cache()
 
     # Multi-host launch contract (parallel/multihost.py): export
@@ -1158,6 +1164,11 @@ def main(argv=None):
             # anyway — say it in CLI terms.
             p.error("multi-host check requires --no-trace "
                     "(counterexample traces are not multi-host yet)")
+
+    # The first look at the devices starts the backend (on a chip,
+    # seconds): here, stamped, and not somewhere inside ``make_engine``.
+    jax.devices()
+    process_record().mark("backend_ready")
 
     from .engine.bfs import EngineConfig
     from .engine.check import (format_result, initial_states, make_engine)

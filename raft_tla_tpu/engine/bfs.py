@@ -64,7 +64,7 @@ from ..obs import (ActionCoverage, MetricsRegistry, RunEventLog,
                    SpanTracer, all_device_memory_stats,
                    device_memory_stats, events_path, peak_host_rss_bytes,
                    phase_delta)
-from ..obs.metrics import SCOPE_PREFIX, innermost_span
+from ..obs.metrics import SCOPE_PREFIX, process_record, watch_compiles
 from ..obs.flight import RECORDER as _FLIGHT
 from ..resilience import faults as _faults
 from ..resilience.faults import is_resource_exhausted
@@ -94,33 +94,15 @@ def work_counts(metrics, base: Optional[dict] = None) -> dict:
 
 
 # -- compiles by span ------------------------------------------------------
-# jax reports every backend compile, and every load from the persistent
-# cache, through one process-wide monitoring hook.  The listener charges
-# each to the innermost span open on the compiling thread, in that
-# span's registry: ``compile/<span>`` (how many) and
-# ``compile_seconds/<span>``.  ``run_end.compiles`` is a run's share.
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_compile_watch_installed = False
-
-
-def _on_compile(event: str, duration: float, **_kw) -> None:
-    if event != _COMPILE_EVENT:
-        return
-    span = innermost_span()
-    if span is not None:
-        span.registry.counter("compile/" + span.name)
-        span.registry.counter("compile_seconds/" + span.name,
-                              float(duration))
-
-
-def watch_compiles() -> None:
-    """Register the listener, once per process (jax keeps listeners for
-    the life of the process)."""
-    global _compile_watch_installed
-    if not _compile_watch_installed:
-        import jax.monitoring
-        jax.monitoring.register_event_duration_secs_listener(_on_compile)
-        _compile_watch_installed = True
+# jax reports every trace, lowering and backend compile (or load from
+# the persistent cache) through process-wide monitoring hooks.  The
+# listeners (obs/metrics.py ``watch_compiles``) count each in the
+# process record and charge it to the innermost span open on the
+# compiling thread, in that span's registry: ``compile/<span>`` (how
+# many backend compiles and cache loads) and ``compile_seconds/<span>``,
+# with ``trace_seconds/<span>`` and ``lower_seconds/<span>`` beside them.
+# ``run_end.compiles`` is a run's share of the first two, ``run_end.jit``
+# its share of all stages by program.
 
 
 def compiles_by_span(metrics, base: Optional[dict] = None) -> dict:
@@ -1125,6 +1107,7 @@ class BFSEngine:
         self._evlog = evlog = RunEventLog(self._events_path())
         self._phase_base = mt.phase_seconds()
         self._compile_base = compiles_by_span(mt)
+        self._jit_base = process_record().jit_reading()
         self._work_base = self._work_level_base = work_counts(mt)
         # Observed-collision base: the counter is process-cumulative
         # (shared registries — server, warm engines), the report's
@@ -1166,7 +1149,8 @@ class BFSEngine:
             "run_start", engine=type(self).__name__, dims=repr(self.dims),
             batch=cfg.batch, sync_every=cfg.sync_every,
             record_trace=cfg.record_trace, resume=resume is not None,
-            memory=device_memory_stats())
+            memory=device_memory_stats(),
+            process=process_record().run_start())
         self._cur_res = None
         err = None
         try:
@@ -1318,6 +1302,7 @@ class BFSEngine:
                 **work_counts(mt, self._work_base),
                 **self._run_end_extra(),
                 compiles=compiles_by_span(mt, self._compile_base),
+                jit=process_record().jit_since(self._jit_base),
                 memory=device_memory_stats(),
                 # Peak host RSS + one probe per visible device; CPU-only
                 # platforms report {} per device rather than omitting

@@ -17,6 +17,7 @@ from ..models.dims import RaftDims
 from ..models.invariants import (Bounds, build_constraint,
                                  invariant_registry)
 from ..models.pystate import PyState, init_state
+from ..obs.metrics import process_span
 from ..utils.cfg import CheckSetup, load_config
 from .bfs import BFSEngine, EngineConfig, EngineResult
 from .chunk import named_stage
@@ -92,6 +93,7 @@ def engine_config_from_backend(setup: CheckSetup) -> EngineConfig:
         counterexample_dir=be.get("COUNTEREXAMPLE_DIR"))
 
 
+@process_span("make_engine", "engine_begin", "engine_built", kind="bfs")
 def make_engine(setup: CheckSetup,
                 engine_config: Optional[EngineConfig] = None,
                 engine_cls=None):
@@ -142,6 +144,7 @@ def swarm_slice_width(walks: int, hunt: bool = True) -> int:
     return min(walks, lanes)
 
 
+@process_span("make_engine", "engine_begin", "engine_built", kind="swarm")
 def make_swarm_engine(setup: CheckSetup, *, walks: Optional[int] = None,
                       max_depth: Optional[int] = None,
                       batch: Optional[int] = None,

@@ -60,7 +60,7 @@ from ..models.schema import (StateBatch, build_pack_guard, check_packable,
 from ..obs import (MetricsRegistry, RunEventLog, SpanTracer,
                    device_memory_stats, events_path, phase_delta)
 from ..obs.flight import RECORDER as _FLIGHT
-from ..obs.metrics import SCOPE_PREFIX
+from ..obs.metrics import SCOPE_PREFIX, process_record
 from ..ops.fingerprint import build_fingerprint
 from ..ops.walk_kernels import (CHOICE_STREAM, FAMILY_STREAM, INIT_STREAM,
                                 ROOT_STREAM, bloom_init, bloom_probe,
@@ -577,6 +577,7 @@ class SwarmEngine:
         self._counts["latch_step"] = -1
         self._walkers = None
         compile_base = compiles_by_span(mt)
+        jit_base = process_record().jit_reading()
         evlog = RunEventLog(events_path(self.events_out,
                                         self.checkpoint_dir))
         phase_base = mt.phase_seconds()
@@ -591,7 +592,8 @@ class SwarmEngine:
                    dims=repr(self.dims), walks=self.walks,
                    max_depth=self.max_depth, batch=self.batch,
                    ring=self.ring, seed=seed, num_steps=num_steps,
-                   memory=device_memory_stats())
+                   memory=device_memory_stats(),
+                   process=process_record().run_start())
         err = None
         try:
             self._run_impl(roots, res, seed, num_steps, max_seconds,
@@ -604,11 +606,12 @@ class SwarmEngine:
             try:
                 with mt.phase_timer("run_end"):
                     self._run_end(res, err, evlog, t0, phase_base,
-                                  compile_base)
+                                  compile_base, jit_base)
             finally:
                 run_span.close()
 
-    def _run_end(self, res, err, evlog, t0, phase_base, compile_base):
+    def _run_end(self, res, err, evlog, t0, phase_base, compile_base,
+                 jit_base):
         """What every run pays after its loop: the counterexample files,
         the profiler's and the observatory's reports, ``run_end``."""
         mt = self.metrics
@@ -698,6 +701,7 @@ class SwarmEngine:
             # cache loads of this run by the span they fell in.
             **counts,
             compiles=compiles_by_span(mt, compile_base),
+            jit=process_record().jit_since(jit_base),
             memory=device_memory_stats())
         _FLIGHT.set_live_evlog(None)
         _FLIGHT.disarm()

@@ -7,7 +7,10 @@ One spine, several legs:
   :meth:`~MetricsRegistry.phase_timer` around the host-side phases of
   the BFS chunk loop and the simulate/mesh paths,
   :meth:`~MetricsRegistry.scope` around what holds them (``run``,
-  ``level``, ``replay``);
+  ``level``, ``replay``); and the one **process record**
+  (:class:`~.metrics.ProcessRecord`): marks, span totals and jax's
+  trace / lower / load / compile stages by program since the package's
+  import, carried by every ``run_start`` as ``process``;
 - :mod:`.events` — the structured JSONL :class:`RunEventLog`
   (run_start, level_complete, fpset_resize, spill, checkpoint,
   violation, deadlock, chunk_profile, coverage, run_end) written next

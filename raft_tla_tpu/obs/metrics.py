@@ -6,9 +6,9 @@ one registry every layer (engine, mesh, server, CLI, bench) writes into.
 Zero-dependency and thread-safe: the checker service handles requests on
 multiple threads against one process-global registry, and the engines'
 host loops update theirs thousands of times per second — so every
-operation is a few dict ops under one lock, and nothing here ever
-imports jax (the registry must be importable in tooling that never
-touches a device).
+operation is a few dict ops under one lock, and importing this module
+never imports jax (the registry must be importable in tooling that never
+touches a device; ``watch_compiles`` alone does, when it is called).
 
 Metric name convention: ``<layer>/<what>`` with ``/`` separators, e.g.
 ``engine/generated``, ``server/requests/check``, ``phase/stats_fetch``.
@@ -23,15 +23,26 @@ started.  Closing it feeds three sinks: the histogram here, and through
 the duck-typed ``registry.tracer`` hook (obs/tracing.py) the Chrome
 trace file and a ``jax.profiler.TraceAnnotation`` on the device
 profiler's own clock.
+
+One process record (``ProcessRecord``, the module-level ``PROCESS``):
+what the process did before its runs and between them, kept from the
+package's import on.  Marks, every closed span's seconds by name
+whichever registry opened it, and jax's trace / lower / compile-or-load
+events by program, each counted whether or not a span is open.  Every
+``run_start`` carries it (``ProcessRecord.run_start``).
 """
 
 from __future__ import annotations
 
 import math
+import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
 from typing import Dict, Optional
+
+from .. import IMPORT_STAMP
 
 # Histogram bucket upper bounds: geometric decades with a 1-2-5 ladder,
 # 1 us .. 100 s — wide enough for both kernel dispatches and whole
@@ -46,10 +57,14 @@ PHASE_PREFIX = "phase/"
 # wall (``phase_seconds``, ``level_complete.unattributed_seconds``).
 SCOPE_PREFIX = "scope/"
 
-# The spans open on each thread, outermost first.  Process-wide because
-# what reads it is: jax's monitoring listeners are registered once per
-# process, and the engines' compile listener (engine/bfs.py) charges a
-# compile to the innermost span open on the compiling thread.
+# What an annotation's name starts with in a profiler capture.
+ANNOTATION_PREFIX = "raft."
+
+# The spans open on each thread, outermost first (``stack``), and the
+# jit stages jax has begun on it and not ended (``jit``).  Process-wide
+# because what reads it is: jax's monitoring listeners are registered
+# once per process, and the compile listener below charges a compile to
+# the innermost span open on the compiling thread.
 _OPEN = threading.local()
 
 
@@ -63,11 +78,15 @@ class Span:
     """One open span; ``close()`` ends it and feeds the sinks.  After
     the close ``seconds`` holds its duration."""
 
-    __slots__ = ("registry", "name", "seconds", "_hist", "_t0", "_token")
+    __slots__ = ("registry", "name", "seconds", "jit", "_hist", "_t0",
+                 "_token")
 
     def __init__(self, registry, hist: str, name: str, args: dict):
         self.registry, self.name, self._hist = registry, name, hist
         self.seconds = None
+        # Seconds jax spent tracing, lowering, compiling or loading
+        # inside this span (``_jit_end``): ``seconds - jit`` is its own.
+        self.jit = 0.0
         stack = getattr(_OPEN, "stack", None)
         if stack is None:
             stack = _OPEN.stack = []
@@ -82,6 +101,7 @@ class Span:
             return
         self.seconds = time.perf_counter() - self._t0
         self.registry.observe(self._hist + self.name, self.seconds)
+        PROCESS.span_closed(self._hist + self.name, self.seconds, self.jit)
         if self._token is not None:
             self.registry.tracer.end(self._token)
         # A loop-shaped span (``level``) may be closed after an
@@ -238,3 +258,395 @@ def phase_delta(now: Dict[str, float],
         return dict(now)
     return {k: v - base.get(k, 0.0) for k, v in now.items()
             if v - base.get(k, 0.0) > 0.0}
+
+
+# -- the process record ----------------------------------------------------
+# jax reports a jitted function's way to an executable in three timed
+# stages, each through its monitoring hooks as a scalar when it begins
+# and a duration when it ends, both with the function's name: the trace
+# to a jaxpr, the lowering to a module, and the backend's compile, which
+# is a load from the persistent cache when the cache's own ``cache_hits``
+# event fell inside its bracket.  A program that compiles in under
+# ``jax_persistent_cache_min_compile_time_secs`` is never stored, so it
+# is a ``compile`` in every process.
+JIT_STAGES = ("trace", "lower", "load", "compile")
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_JIT_EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+               "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+               _BACKEND_EVENT: "backend"}
+_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_STORED_EVENT = "/jax/compilation_cache/cache_misses"
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def _process_age() -> Optional[float]:
+    """Seconds since the OS started this process, where ``/proc`` says
+    (to a clock tick), else None."""
+    try:
+        with open("/proc/self/stat", encoding="ascii") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime", encoding="ascii") as f:
+            up = float(f.read().split()[0])
+        return max(up - ticks / os.sysconf("SC_CLK_TCK"), 0.0)
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def _net(entry) -> float:
+    """A span total's own seconds: all of them less the jit's."""
+    return round(max(entry[1] - entry[2], 0.0), 4)
+
+
+class _Program:
+    """One jitted function's jit seconds in the process, by stage; its
+    ``events`` (stages of its own, a trace inside a trace is none), what
+    its last backend stage was (``cache``: "hit" or "miss"), the span
+    its last stage fell in, and the backend stages that were compiles."""
+
+    __slots__ = ("trace_s", "lower_s", "backend_s", "events", "cache",
+                 "span", "compiles", "compile_s")
+
+    def __init__(self):
+        self.trace_s = self.lower_s = self.backend_s = self.compile_s = 0.0
+        self.events = self.compiles = 0
+        self.cache = self.span = None
+
+    @property
+    def seconds(self) -> float:
+        return self.trace_s + self.lower_s + self.backend_s
+
+    def row(self, name: str, base=(0.0, 0.0, 0.0, 0)) -> dict:
+        return {"name": name, "trace_s": round(self.trace_s - base[0], 4),
+                "lower_s": round(self.lower_s - base[1], 4),
+                "backend_s": round(self.backend_s - base[2], 4),
+                "cache": self.cache, "span": self.span}
+
+
+class ProcessRecord:
+    """What one process did before its runs and between them.
+
+    - ``marks``: {name: seconds since the process started}, the first
+      occurrence of each (``mark``).  The start is the OS's own where
+      ``/proc/self/stat`` gives it, else the package's import.
+    - every closed span's seconds by histogram name, whichever registry
+      opened it, beside the jit seconds that fell inside it
+      (``span_closed``, from ``Span.close``);
+    - jax's jit stages (``JIT_STAGES``) as [events, seconds] and by
+      program (``jit_event``, from the listeners ``watch_compiles``
+      registers), in SELF time: a stage that ran inside another on the
+      same thread is taken out of it, so the four never sum past the wall;
+    - ``metrics``: a registry of its own for the spans no engine's
+      registry exists for yet (``load_config``, ``make_engine``).  The
+      record is that registry's tracer: such a span is ``raft.<name>`` in
+      any profiler capture that is open, and those closed before the
+      process's first run are kept for that run's ``--trace-out``
+      (``early_spans``, obs/tracing.py ``SpanTracer.reset``).
+
+    A few dict operations a call under one lock of its own, and nothing
+    that grows with the events: names come from the code, and a program
+    past ``MAX_PROGRAMS`` is counted under ``(others)``.  One instance a
+    process (``PROCESS``); ``process_record()`` is how other modules
+    reach it, so a test can put its own in its place."""
+
+    PROGRAMS = 8            # ``run_start.process.programs`` holds so many
+    PHASES = 12             # ... and ``runs.phases`` so many
+    EARLY_SPANS = 32
+    MAX_PROGRAMS = 512
+
+    def __init__(self, package_stamp: Optional[float] = None):
+        now = time.perf_counter()
+        age = _process_age()
+        if age is not None:
+            self.t0 = now - age
+        else:
+            self.t0 = package_stamp if package_stamp is not None else now
+        self._lock = threading.Lock()
+        self.marks: Dict[str, float] = {}
+        if package_stamp is not None:
+            self.marks["package"] = max(package_stamp - self.t0, 0.0)
+        self._jit = {stage: [0, 0.0] for stage in JIT_STAGES}
+        self._retrieval_s = 0.0     # of ``load``: the cache's own read
+        self._stored = 0            # compiles the persistent cache kept
+        self._jit_before_engine = 0.0
+        self._programs: Dict[str, _Program] = {}
+        # histogram name -> [count, seconds, jit seconds inside]
+        self._spans: Dict[str, list] = {}
+        self._early: list = []
+        self.metrics = MetricsRegistry()
+        self.metrics.tracer = self
+
+    # -- writers -------------------------------------------------------
+    def mark(self, name: str) -> None:
+        """Stamp ``name`` now; the first stamp of a name stands."""
+        t = time.perf_counter() - self.t0
+        with self._lock:
+            if name not in self.marks:
+                self.marks[name] = t
+                if name == "engine_begin":
+                    self._jit_before_engine = sum(
+                        s for _n, s in self._jit.values())
+
+    def span_closed(self, hist_name: str, seconds: float,
+                    jit_seconds: float) -> None:
+        with self._lock:
+            e = self._spans.get(hist_name)
+            if e is None:
+                e = self._spans[hist_name] = [0, 0.0, 0.0]
+            e[0] += 1
+            e[1] += seconds
+            e[2] += jit_seconds
+
+    def jit_event(self, stage: str, program: str, seconds: float,
+                  own: bool, span: Optional[str]) -> None:
+        """One ended stage: ``seconds`` of self time of ``program``;
+        ``own`` is False for a trace inside a trace (its caller's
+        tracing, no event of its own)."""
+        with self._lock:
+            total = self._jit[stage]
+            total[0] += own
+            total[1] += seconds
+            p = self._programs.get(program)
+            if p is None:
+                if len(self._programs) >= self.MAX_PROGRAMS:
+                    program = "(others)"
+                p = self._programs.setdefault(program, _Program())
+            p.events += own
+            p.span = span
+            if stage == "trace":
+                p.trace_s += seconds
+            elif stage == "lower":
+                p.lower_s += seconds
+            else:
+                p.backend_s += seconds
+                p.cache = "hit" if stage == "load" else "miss"
+                if stage == "compile":
+                    p.compiles += 1
+                    p.compile_s += seconds
+
+    def cache_event(self, retrieval_s: float = 0.0, stored: int = 0) -> None:
+        with self._lock:
+            self._retrieval_s += retrieval_s
+            self._stored += stored
+
+    # -- the record's registry hands it its spans (``registry.tracer``) --
+    def begin(self, name: str, args: dict):
+        note = None
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            note = profiler.TraceAnnotation(ANNOTATION_PREFIX + name, **args)
+            note.__enter__()
+        return name, time.perf_counter(), args, note
+
+    def end(self, token) -> None:
+        name, start, args, note = token
+        if note is not None:
+            note.__exit__(None, None, None)
+        with self._lock:
+            if ("first_run" not in self.marks
+                    and len(self._early) < self.EARLY_SPANS):
+                self._early.append((name, start, time.perf_counter(), args))
+
+    # -- readers -------------------------------------------------------
+    def early_spans(self) -> list:
+        """``[(name, start, end, args)]`` (``perf_counter`` stamps) of
+        the record's own spans closed so far, while no run of the
+        process has started; ``[]`` from then on."""
+        with self._lock:
+            return [] if "first_run" in self.marks else list(self._early)
+
+    def _top(self, rows: list) -> list:
+        """The ``PROGRAMS`` rows (``_Program.row``) with most seconds."""
+        rows.sort(key=lambda r: -(r["trace_s"] + r["lower_s"]
+                                  + r["backend_s"]))
+        return rows[:self.PROGRAMS]
+
+    def run_start(self) -> dict:
+        """The ``process`` field of a ``run_start`` event, and the stamp
+        of the process's first run.  ``age_s`` is now; ``marks`` as they
+        stand; ``jit`` {stage: [events, self seconds]} with, beside it,
+        what of them fell before ``engine_begin`` and the cache's own
+        counts; ``programs`` the ``PROGRAMS`` with most jit seconds and
+        ``compiled`` [name, compiles, seconds] of those the backend
+        compiled rather than loaded (in a warm process: the programs the
+        persistent cache never keeps); ``runs`` the process's closed
+        ``run`` scopes, its ``make_engine`` spans and the ``PHASES``
+        largest phases of any engine so far, each net of the jit seconds
+        inside it."""
+        self.mark("first_run")
+        with self._lock:
+            age = time.perf_counter() - self.t0
+            compiled = sorted(
+                ([name, p.compiles, round(p.compile_s, 4)]
+                 for name, p in self._programs.items() if p.compiles),
+                key=lambda row: -row[2])[:self.PROGRAMS]
+            spans = self._spans
+            run = spans.get(SCOPE_PREFIX + "run", (0, 0.0, 0.0))
+            phases = sorted(
+                ((k[len(PHASE_PREFIX):], _net(e)) for k, e in spans.items()
+                 if k.startswith(PHASE_PREFIX)), key=lambda kv: -kv[1])
+            return {
+                "age_s": round(age, 4),
+                "marks": {k: round(v, 4) for k, v in self.marks.items()},
+                "jit": {s: [n, round(sec, 4)]
+                        for s, (n, sec) in self._jit.items()},
+                "jit_before_engine_s": round(self._jit_before_engine, 4),
+                "cache": {"retrieval_s": round(self._retrieval_s, 4),
+                          "stored": self._stored},
+                "programs": self._top(
+                    [p.row(name) for name, p in self._programs.items()]),
+                "compiled": compiled,
+                "runs": {
+                    "count": run[0], "run_s": _net(run),
+                    "make_engine_s": _net(spans.get(
+                        SCOPE_PREFIX + "make_engine", (0, 0.0, 0.0))),
+                    "phases": dict(phases[:self.PHASES])},
+            }
+
+    def jit_reading(self):
+        """The jit totals as they stand, for ``jit_since``."""
+        with self._lock:
+            return ({s: tuple(v) for s, v in self._jit.items()},
+                    {k: (p.trace_s, p.lower_s, p.backend_s, p.events)
+                     for k, p in self._programs.items()})
+
+    def jit_since(self, base) -> dict:
+        """What ``run_end.jit`` carries: the stages that moved since
+        ``base`` (a ``jit_reading``) as {stage: [events, seconds]} and,
+        under ``programs``, the programs they belong to, each with the
+        span its last stage fell in; ``{}`` when jax did nothing."""
+        stages0, programs0 = base
+        with self._lock:
+            out = {}
+            for s, (n, sec) in self._jit.items():
+                n0, sec0 = stages0[s]
+                if n > n0 or sec > sec0:
+                    out[s] = [n - n0, round(sec - sec0, 4)]
+            if not out:
+                return out
+            moved = []
+            for name, p in self._programs.items():
+                b = programs0.get(name, (0.0, 0.0, 0.0, 0))
+                if p.events > b[3] or p.seconds > b[0] + b[1] + b[2]:
+                    moved.append(p.row(name, b))
+            out["programs"] = self._top(moved)
+            return out
+
+
+PROCESS = ProcessRecord(IMPORT_STAMP)
+
+
+def process_record() -> ProcessRecord:
+    """The process's record (looked up at every call)."""
+    return PROCESS
+
+
+@contextmanager
+def process_span(name: str, begin: Optional[str] = None,
+                 end: Optional[str] = None, **args):
+    """A span on the process record's own registry, between two of its
+    marks; a ``with`` block or a decorator.  For what runs before any
+    engine's registry exists: ``load_config``, ``make_engine``."""
+    record = process_record()
+    if begin is not None:
+        record.mark(begin)
+    span = record.metrics.open_span(name, SCOPE_PREFIX, **args)
+    try:
+        yield span
+    finally:
+        span.close()
+        if end is not None:
+            record.mark(end)
+
+
+def _program_name(fun_name) -> str:
+    """``chunk`` of jax's ``jit(chunk)`` (the lowering and the backend
+    name the module, the trace the function)."""
+    name = str(fun_name or "?")
+    if name.endswith(")") and "(" in name:
+        name = name[name.index("(") + 1:-1]
+    return name
+
+
+def _jit_begin(event: str, _value, fun_name=None, **_kw) -> None:
+    stage = _JIT_EVENTS.get(event)
+    if stage is None:
+        return
+    frames = getattr(_OPEN, "jit", None)
+    if frames is None:
+        frames = _OPEN.jit = []
+    # A trace inside a trace is its caller's tracing: jax traces every
+    # jitted function the chunk calls, thousands of them, inside the
+    # chunk's own trace.
+    if stage == "trace" and frames and frames[-1][0] == "trace":
+        frames.append([stage, frames[-1][1], 0.0, False, False])
+    else:
+        frames.append([stage, _program_name(fun_name), 0.0, True, False])
+
+
+def _jit_mark(event: str, **_kw) -> None:
+    # Neither carries a name: a hit falls inside the backend bracket of
+    # the program it belongs to, which is open on this thread and
+    # reports after it.
+    if event == _HIT_EVENT:
+        frames = getattr(_OPEN, "jit", None)
+        if frames:
+            frames[-1][4] = True
+    elif event == _STORED_EVENT:
+        PROCESS.cache_event(stored=1)
+
+
+def _jit_end(event: str, duration: float, fun_name=None, **_kw) -> None:
+    """The listener for jax's duration events.  Every stage that ends is
+    counted in the process record, in self time (its duration less the
+    stages that ran inside it on this thread), and charged to every span
+    open on the thread (``Span.jit``); the innermost one's registry
+    counts it too: ``compile/<span>`` and ``compile_seconds/<span>`` a
+    backend compile or cache load, as they always have,
+    ``trace_seconds/<span>`` and ``lower_seconds/<span>`` the rest."""
+    stage = _JIT_EVENTS.get(event)
+    if stage is None:
+        if event == _RETRIEVAL_EVENT:
+            PROCESS.cache_event(retrieval_s=float(duration))
+        return
+    duration = float(duration)
+    frames = getattr(_OPEN, "jit", None) or []
+    frame = None
+    while frames and frame is None:
+        top = frames.pop()
+        if top[0] == stage:
+            frame = top
+    if frame is None:       # begun before the listeners were registered
+        frame = [stage, _program_name(fun_name), 0.0, True, False]
+    _stage, program, inside, own, hit = frame
+    seconds = max(duration - inside, 0.0)
+    if frames:
+        frames[-1][2] += duration
+    if stage == "backend":
+        stage = "load" if hit else "compile"
+    stack = getattr(_OPEN, "stack", None)
+    span = stack[-1] if stack else None
+    if span is not None:
+        for s in stack:
+            s.jit += seconds
+        if event == _BACKEND_EVENT:
+            span.registry.counter("compile/" + span.name)
+            span.registry.counter("compile_seconds/" + span.name, duration)
+        else:
+            span.registry.counter(f"{stage}_seconds/{span.name}", seconds)
+    PROCESS.jit_event(stage, program, seconds, own,
+                      span.name if span is not None else None)
+
+
+_watching = False
+
+
+def watch_compiles() -> None:
+    """Register the listeners, once per process (jax keeps listeners for
+    the life of the process)."""
+    global _watching
+    if not _watching:
+        import jax.monitoring as monitoring
+        monitoring.register_scalar_listener(_jit_begin)
+        monitoring.register_event_listener(_jit_mark)
+        monitoring.register_event_duration_secs_listener(_jit_end)
+        _watching = True

@@ -48,6 +48,8 @@ import time
 from contextlib import contextmanager
 from typing import Optional
 
+from . import metrics as _metrics
+
 
 class SpanTracer:
     """Thread-safe span recorder; ``SpanTracer(None)`` discards.
@@ -60,7 +62,7 @@ class SpanTracer:
     """
 
     #: What an annotation's name starts with in a profiler capture.
-    ANNOTATION_PREFIX = "raft."
+    ANNOTATION_PREFIX = _metrics.ANNOTATION_PREFIX
 
     def __init__(self, path: Optional[str] = None,
                  process_name: str = "raft_tla_tpu", annotate=None):
@@ -74,19 +76,15 @@ class SpanTracer:
         self._process_name = process_name
         self._lock = threading.Lock()
         self._pid = os.getpid()
-        self.reset()
+        self._clear(time.perf_counter())
 
-    def reset(self) -> None:
-        """Drop everything recorded and restart the clock — one trace
-        file describes ONE run, so warm/reused engines call this at
-        every run start (``_telemetry_run``) instead of appending a
-        second ``run`` span to the first run's events.  The supervisor's
-        own tracer is deliberately never reset: its attempt/restart
-        timeline spans the whole supervision episode."""
+    def _clear(self, t0: float) -> None:
+        """Drop everything recorded; ``ts`` counts from ``t0`` (a
+        ``perf_counter`` stamp, now or earlier)."""
         with self._lock:
             self._events = []
             self._named_tids = set()
-        self._t0 = time.perf_counter()
+        self._t0 = t0
         if self.path is not None:
             # Process metadata + the epoch anchor for cross-process merge.
             self._append({"name": "process_name", "ph": "M",
@@ -94,7 +92,24 @@ class SpanTracer:
                           "args": {"name": self._process_name}})
             self._append({"name": "trace_start_unix", "ph": "M",
                           "pid": self._pid, "tid": 0,
-                          "args": {"unix_seconds": round(time.time(), 6)}})
+                          "args": {"unix_seconds": round(
+                              time.time() - (time.perf_counter() - t0), 6)}})
+
+    def reset(self) -> None:
+        """Drop everything recorded and restart the clock — one trace
+        file describes ONE run, so warm/reused engines call this at
+        every run start (``_telemetry_run``) instead of appending a
+        second ``run`` span to the first run's events.  The FIRST run
+        of a process keeps what the process recorded before it (the
+        ``load_config`` and ``make_engine`` spans of obs/metrics.py
+        ``ProcessRecord``), on a clock that starts with the process.
+        The supervisor's own tracer is deliberately never reset: its
+        attempt/restart timeline spans the whole supervision episode."""
+        record = _metrics.process_record()
+        early = record.early_spans() if self.path is not None else []
+        self._clear(record.t0 if early else time.perf_counter())
+        for name, start, end, args in early:
+            self._span(name, start, end, args)
 
     @property
     def enabled(self) -> bool:
@@ -155,13 +170,14 @@ class SpanTracer:
         """Record a span from an earlier ``time.perf_counter()`` stamp to
         now — the loop-shaped-scope form (level boundaries, supervisor
         attempts), where a ``with`` block can't bracket the region."""
-        if self.path is None:
-            return
-        end = time.perf_counter()
+        if self.path is not None:
+            self._span(name, start_perf_counter, time.perf_counter(), args)
+
+    def _span(self, name: str, start: float, end: float, args: dict) -> None:
         rec = {"name": name, "ph": "X", "pid": self._pid,
                "tid": self._tid(),
-               "ts": round((start_perf_counter - self._t0) * 1e6, 3),
-               "dur": round((end - start_perf_counter) * 1e6, 3)}
+               "ts": round((start - self._t0) * 1e6, 3),
+               "dur": round((end - start) * 1e6, 3)}
         if args:
             rec["args"] = args
         self._append(rec)

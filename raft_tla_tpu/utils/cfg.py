@@ -61,6 +61,7 @@ from typing import Dict, List, Optional, Tuple
 
 from ..models.dims import RaftDims
 from ..models.invariants import Bounds
+from ..obs.metrics import process_span
 
 _KEYWORDS = {
     "CONSTANT", "CONSTANTS", "SPECIFICATION", "INVARIANT", "INVARIANTS",
@@ -321,6 +322,7 @@ class CheckSetup:
     backend: Dict[str, object] = dataclasses.field(default_factory=dict)
 
 
+@process_span("load_config", end="cfg_loaded")
 def load_config(cfg_path: str, max_log: Optional[int] = None,
                 n_msg_slots: Optional[int] = None) -> CheckSetup:
     """Parse cfg + companion module, intern model values, derive dims.

@@ -36,9 +36,16 @@ def enable_persistent_cache() -> None:
     program takes about two minutes to compile cold on a v5e).  Where
     ``JAX_COMPILATION_CACHE_DIR`` is set jax already reads it, and no
     directory is set in code.  Safe to call more than once, before or
-    after backend init."""
+    after backend init.  Every entry point calls this first, so it also
+    turns on the process record's jax listeners (obs/metrics.py: what
+    is traced, compiled or loaded from here on is counted) and stamps
+    the record's ``cache_enabled`` mark."""
     import jax
+
+    from ..obs.metrics import process_record, watch_compiles
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", cache_dir())
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    watch_compiles()
+    process_record().mark("cache_enabled")

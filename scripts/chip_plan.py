@@ -47,7 +47,9 @@ SHOWN = ("stages:", "idle ", "window compiles", "trace:", "trace reduced",
          "memory", "replay:", "sample:", "window phases", "walk:",
          "window counters", "construct:", "suite:", "witness ",
          "distinct states", "states generated", "diameter", "stop reason",
-         "wall seconds", "states/sec", "VIOLATION", "pipeline")
+         "wall seconds", "states/sec", "VIOLATION", "pipeline",
+         "setup by", "  marks", "  partition", "  jit", "  compiled",
+         "  runs so far")
 
 
 def command(root: str, workload: str, seed: int, trace: int, how: str):

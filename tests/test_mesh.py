@@ -465,28 +465,61 @@ def test_owner_windowed_insert_equals_insert_on_the_padded_block(
         assert int(dup.sum()) == 4
 
 
-def _run_end(engine_cls, tmp_path, **kw):
+@pytest.fixture(scope="module")
+def short_runs(tmp_path_factory):
+    """{engine class: the events of its run to level 4}, each the first
+    run of a process by a record of its own (obs/metrics.py), the engine
+    built inside the bracket ``make_engine`` puts around any engine."""
     import json
-    events = str(tmp_path / f"{engine_cls.__name__}.jsonl")
-    eng = engine_cls(
-        DIMS, constraint=build_constraint(DIMS, BOUNDS),
-        config=small_mesh_config(max_diameter=4, events_out=events), **kw)
-    eng.run([init_state(DIMS)])
-    with open(events, encoding="utf-8") as f:
-        end = [json.loads(line) for line in f][-1]
-    assert end["event"] == "run_end"
-    return end
+    from raft_tla_tpu.obs import metrics as metrics_mod
+    tmp = tmp_path_factory.mktemp("short_runs")
+    out = {}
+    for engine_cls, kw in ((MeshBFSEngine, {"devices": jax.devices()[:4]}),
+                           (BFSEngine, {})):
+        events = str(tmp / f"{engine_cls.__name__}.jsonl")
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(metrics_mod, "PROCESS", metrics_mod.ProcessRecord())
+            with metrics_mod.process_span("make_engine", "engine_begin",
+                                          "engine_built"):
+                eng = engine_cls(
+                    DIMS, constraint=build_constraint(DIMS, BOUNDS),
+                    config=small_mesh_config(max_diameter=4,
+                                             events_out=events), **kw)
+            eng.run([init_state(DIMS)])
+        with open(events, encoding="utf-8") as f:
+            out[engine_cls] = [json.loads(line) for line in f]
+        assert out[engine_cls][-1]["event"] == "run_end"
+    return out
 
 
-def test_run_end_carries_the_insert_windows_of_every_chip(tmp_path):
+def test_run_end_carries_the_insert_windows_of_every_chip(short_runs):
     """``chip_insert_windows``: one entry a chip, the windows its owner
     dedup ran over the run's passes — one in every pass that brought it a
     query (a pass of this run sends a chip far fewer than K), none in
     the others; the one-chip engine has no such count."""
-    end = _run_end(MeshBFSEngine, tmp_path, devices=jax.devices()[:4])
+    end = short_runs[MeshBFSEngine][-1]
     windows = end["chip_insert_windows"]
     assert len(windows) == 4 == len(end["chip_shard_keys"])
     assert all(0 < w <= end["passes"] for w in windows)
     # Every pass generated a candidate, and some chip owns it.
     assert sum(windows) >= end["passes"] > 0
-    assert "chip_insert_windows" not in _run_end(BFSEngine, tmp_path)
+    assert "chip_insert_windows" not in short_runs[BFSEngine][-1]
+
+
+def test_the_mesh_run_start_carries_the_process_record(short_runs):
+    """The one helper, through ``BFSEngine._telemetry_run``: marks in
+    order, eight parts that sum to the age, and in ``run_end`` the mesh
+    programs the run compiled or loaded, by name."""
+    from tests.test_setup_record import check_process
+    start, end = short_runs[MeshBFSEngine][0], short_runs[MeshBFSEngine][-1]
+    assert (start["event"], start["engine"]) == ("run_start", "MeshBFSEngine")
+    parts = check_process(start["process"])
+    assert set(start["process"]["marks"]) == {"engine_begin", "engine_built",
+                                              "first_run"}
+    assert start["process"]["runs"]["count"] == 0 and parts["runs_s"] == 0.0
+    named = {p["name"]: p for p in end["jit"]["programs"]}
+    assert {"sharded_chunk", "sharded_ingest"} <= set(named), sorted(named)
+    assert named["sharded_chunk"]["cache"] in ("hit", "miss")
+    # The backend stage of ``jit`` is what ``compiles`` has always counted.
+    assert (end["jit"].get("load", [0])[0] + end["jit"].get("compile", [0])[0]
+            == sum(n for n, _s in end["compiles"].values()))

@@ -20,6 +20,7 @@ from raft_tla_tpu.engine import chunk as chunk_mod
 from raft_tla_tpu.engine.bfs import EngineConfig
 from raft_tla_tpu.engine.check import initial_states, make_engine
 from raft_tla_tpu.obs import MetricsRegistry, SpanTracer
+from raft_tla_tpu.obs import metrics as metrics_mod
 from raft_tla_tpu.obs.metrics import innermost_span
 from raft_tla_tpu.utils.cfg import load_config
 
@@ -53,7 +54,11 @@ def verdict(noleader, tmp_path_factory):
         events = [json.loads(line) for line in f]
     eng.tracer.write()          # the replay's spans came after run end
     with open(d / "tr.json", encoding="utf-8") as f:
-        chrome = [e for e in json.load(f) if e["ph"] == "X"]
+        # Less what the process recorded before its first run, which
+        # this may be (``load_config``, ``make_engine``: they carry no
+        # ``run``; tests/test_setup_record.py holds them).
+        chrome = [e for e in json.load(f) if e["ph"] == "X"
+                  and "run" in e.get("args", {})]
     from jax.profiler import ProfileData
     pb = glob.glob(str(d / "xplane" / "**" / "*.xplane.pb"),
                    recursive=True)[0]
@@ -269,6 +274,100 @@ def test_compiles_are_charged_to_the_span_they_fell_in(noleader, tmp_path):
     with open(ev, encoding="utf-8") as f:
         again = [json.loads(line) for line in f][-1]["compiles"]
     assert "trace_flush" not in again and "warmup" not in again
+
+
+# -- the process record on run_start and run_end --------------------------------
+
+@pytest.fixture(scope="module")
+def three_runs(tmp_path_factory):
+    """The first three runs of a process as its record (obs/metrics.py,
+    one of this fixture's own) saw them: the canary cfg loaded, an engine
+    made (``test_loop_counters``' sizes), run to level 4 twice, then once
+    more after a fetch length no warm-up has seen was put on the engine.
+    (record, the events of each run, the Chrome events of each run)"""
+    d = tmp_path_factory.mktemp("record")
+    events, chrome = [], []
+    with pytest.MonkeyPatch.context() as mp:
+        record = metrics_mod.ProcessRecord()
+        mp.setattr(metrics_mod, "PROCESS", record)
+        setup = load_config(os.path.join(REPO, "configs/MCraft_noleader.cfg"))
+        eng = make_engine(setup, EngineConfig(
+            batch=64, queue_capacity=1 << 14, seen_capacity=1 << 17,
+            max_diameter=4, trace_out=str(d / "tr.json")))
+        for i in range(3):
+            if i == 2:
+                eng._fetch_lens = [7] + eng._fetch_lens
+            eng.config.events_out = str(d / f"ev{i}.jsonl")
+            eng.run(initial_states(setup))
+            with open(eng.config.events_out, encoding="utf-8") as f:
+                events.append([json.loads(line) for line in f])
+            with open(d / "tr.json", encoding="utf-8") as f:
+                chrome.append([e for e in json.load(f) if e["ph"] == "X"])
+    return record, events, chrome
+
+
+def test_run_start_carries_the_process_record(three_runs):
+    from tests.test_setup_record import check_process
+    record, events, _chrome = three_runs
+    starts = [evs[0]["process"] for evs in events]
+    assert [evs[0]["event"] for evs in events] == ["run_start"] * 3
+    parts = [check_process(p) for p in starts]
+    # One first run a process; every later run_start says when it was.
+    assert len({p["marks"]["first_run"] for p in starts}) == 1
+    assert set(starts[0]["marks"]) == {"cfg_loaded", "engine_begin",
+                                       "engine_built", "first_run"}
+    # Before the first run: nothing has run, and what make_engine took is
+    # its own seconds and the jit's (constants made eagerly as it builds).
+    first = starts[0]
+    assert first["runs"]["count"] == 0 and first["runs"]["phases"] == {}
+    built = first["marks"]["engine_built"] - first["marks"]["engine_begin"]
+    jit_s = sum(sec for _n, sec in first["jit"].values())
+    assert parts[0]["make_engine_s"] + jit_s == pytest.approx(
+        built + first["jit_before_engine_s"], abs=0.01)
+    assert parts[0]["runs_s"] == 0.0 and abs(parts[0]["outside_s"]) < 0.05
+    # Before the second: the first run, its phases and what it compiled.
+    second = starts[1]
+    assert second["runs"]["count"] == 1 and parts[1]["runs_s"] > 0
+    assert {"warmup", "stats_fetch"} <= set(second["runs"]["phases"])
+    assert "chunk" in [p["name"] for p in second["programs"]]
+    assert parts[1]["trace_s"] > parts[0]["trace_s"]
+    assert starts[2]["runs"]["count"] == 2
+
+
+def test_a_warm_run_compiles_nothing_and_a_new_shape_is_named(three_runs):
+    _record, events, _chrome = three_runs
+    first, warm, reshaped = (evs[-1] for evs in events)
+    assert first["event"] == "run_end"
+    assert first["jit"]["trace"][0] >= 2 and "warmup" in first["compiles"]
+    assert "chunk" in [p["name"] for p in first["jit"]["programs"]]
+    assert warm["jit"] == {} and warm["compiles"] == {}
+    # The third run's warm-up met a fetch length it had not compiled:
+    # one program, named, under the span it fell in, in ``compiles`` too.
+    assert reshaped["compiles"] == {"warmup": [1, pytest.approx(
+        reshaped["compiles"]["warmup"][1])]}
+    assert [(p["name"], p["span"]) for p in reshaped["jit"]["programs"]] == [
+        ("<lambda>", "warmup")]
+    assert reshaped["jit"]["trace"][0] == reshaped["jit"]["lower"][0] == 1
+    assert (reshaped["jit"].get("load", [0])[0]
+            + reshaped["jit"].get("compile", [0])[0]) == 1
+
+
+def test_the_first_runs_trace_file_holds_make_engine_and_load_config(
+        three_runs):
+    record, _events, chrome = three_runs
+    first = [e["name"] for e in chrome[0]]
+    assert first[:2] == ["load_config", "make_engine"]
+    make = chrome[0][1]
+    assert make["args"] == {"kind": "bfs"}
+    assert make["ts"] == pytest.approx(
+        record.marks["engine_begin"] * 1e6, abs=5e3)
+    run = next(e for e in chrome[0] if e["name"] == "run")
+    assert make["ts"] + make["dur"] <= run["ts"]
+    for later in chrome[1:]:
+        names = {e["name"] for e in later}
+        assert "run" in names and not names & {"make_engine", "load_config"}
+        # ... on its own clock again, from the run's start.
+        assert next(e for e in later if e["name"] == "run")["ts"] < 1e5
 
 
 # -- stage names in the programs -----------------------------------------------

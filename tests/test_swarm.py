@@ -217,6 +217,34 @@ def test_swarm_events_validate_and_carry_the_swarm_payload(violation_run):
     assert viol["at_seconds"] == res.violation_at_seconds
 
 
+def test_swarm_run_start_carries_the_process_record(violation_run, tmp_path,
+                                                    monkeypatch):
+    """The one helper at ``SwarmEngine``'s emit site: the warm engine's
+    next hunt is the first run of a process by a record of this test's
+    own, and compiles nothing."""
+    from raft_tla_tpu.obs import metrics as metrics_mod
+    from tests.test_setup_record import check_process
+    eng = violation_run[0]
+    record = metrics_mod.ProcessRecord()
+    monkeypatch.setattr(metrics_mod, "PROCESS", record)
+    monkeypatch.setattr(eng, "events_out", str(tmp_path / "again.jsonl"))
+    monkeypatch.setattr(eng, "counterexample_dir", str(tmp_path))
+    for _ in range(2):
+        eng.run([seeded_root()], seed=1, num_steps=64)
+    events = validate_run_events(eng.events_out)
+    starts = [e for e in events if e["event"] == "run_start"]
+    ends = [e for e in events if e["event"] == "run_end"]
+    assert [e["engine"] for e in starts] == ["SwarmEngine"] * 2
+    parts = [check_process(e["process"]) for e in starts]
+    assert starts[0]["process"]["marks"].keys() == {"first_run"}
+    assert (starts[0]["process"]["marks"]["first_run"]
+            == starts[1]["process"]["marks"]["first_run"])
+    assert parts[0]["runs_s"] == 0.0 < parts[1]["runs_s"]
+    assert starts[1]["process"]["runs"]["count"] == 1
+    assert "swarm_fetch" in starts[1]["process"]["runs"]["phases"]
+    assert [e["jit"] for e in ends] == [{}, {}]
+
+
 def test_swarm_progress_without_payload_object_is_rejected(tmp_path):
     p = tmp_path / "ev.jsonl"
     lines = [{"event": "run_start", "ts": 0.0},
