@@ -10,7 +10,8 @@ the K lanes) and ``configs/reconfig3.cfg`` (the extra action families) —
 so every later PR is guarded at no chip time: ``ops/fpset.py insert``
 over the bench's 2^25-key table, the whole chunk program of ``BFSEngine``
 at a small batch (and that it holds no gather expanded into a per-lane
-loop), its ``ingest`` program, the two programs a seen-set growth
+loop, and that what an invariant's operations write has the lanes
+minor-most), its ``ingest`` program, the two programs a seen-set growth
 dispatches at the verdict cell's sizes, the trace flush's fetch programs
 over the bench's trace buffers (the one-chip engine's, and the mesh's
 over one chip's shards of the four-chip deployment), the swarm walk
@@ -116,6 +117,23 @@ def small_engine(cfg, width, instances, **kw):
     return eng
 
 
+def invariant_results_over_the_lanes(text, lanes):
+    """The results of rank 3 and more, over the ``lanes`` of a pass,
+    of the fusions named for an invariant, each held to the lanes as
+    its minor-most axis: ``pred[K,3,3]{2,1,0}`` (a row a lane, an axis
+    of 3 padded to a 128-wide vector) was 3.9 ms a pass of ``TypeOK``
+    alone until PR 38 made its reductions integer sums (PERF.md section
+    6)."""
+    named = [line.split(" fusion(")[0] for line in text.splitlines()
+             if " fusion(" in line and re.search(
+                 r'op_name="[^"]*/invariants/', line)]
+    assert named, "no fusion is named for an invariant"
+    wide = [shape for result in named for shape in re.findall(
+        rf"\[{lanes},\d+(?:,\d+)+\]\{{\d+(?:,\d+)+", result)]
+    assert all(shape.split("{")[1].startswith("0,") for shape in wide), wide
+    return wide
+
+
 @pytest.mark.parametrize("cfg, width, instances, invariants", [
     ("configs/MCraft_bounded.cfg", 473, 132, 1),
     ("configs/TPUraft.cfg", 951, 224, 1),
@@ -142,6 +160,8 @@ def test_v2_chunk_program_small_batch(cfg, width, instances, invariants,
     loops = re.findall(r' while\(.*op_name="([^"]*)"', c.as_text())
     assert len(loops) >= 2, loops       # else the pattern found nothing
     assert not [name for name in loops if name.endswith("/gather")], loops
+    wide = invariant_results_over_the_lanes(c.as_text(), eng._K)
+    assert wide or invariants == 1      # the suite writes such tensors
 
 
 @pytest.mark.parametrize("cfg, width, instances", [
@@ -314,6 +334,7 @@ def test_mesh_chunk_at_the_four_chip_deployments_sizes(setup, topo):
             + spare_queue) < 16e9, m
     assert len(re.findall(r"= \S+ all-to-all(?:-start)?\(",
                           c.as_text())) == 3
+    invariant_results_over_the_lanes(c.as_text(), K)
 
 
 def test_mesh_trace_flush_fetch_programs_at_the_deployments_buffers(
