@@ -1109,6 +1109,10 @@ class BFSEngine:
         self._compile_base = compiles_by_span(mt)
         self._jit_base = process_record().jit_reading()
         self._work_base = self._work_level_base = work_counts(mt)
+        # ``generated_by_family`` of this run's events is the run's own
+        # share: what a resumed snapshot carried is taken where
+        # ``_run_impl`` loads it (``_note_family_base``).
+        self._family_base = None if resume is not None else {}
         # Observed-collision base: the counter is process-cumulative
         # (shared registries — server, warm engines), the report's
         # "observed dual-key collisions" is per-run.
@@ -1300,6 +1304,7 @@ class BFSEngine:
                 # Counted in the loop (WORK_COUNTERS), and the compiles
                 # and cache loads of this run by the span they fell in.
                 **work_counts(mt, self._work_base),
+                generated_by_family=self._generated_by_family(res),
                 **self._run_end_extra(),
                 compiles=compiles_by_span(mt, self._compile_base),
                 jit=process_record().jit_since(self._jit_base),
@@ -1313,6 +1318,21 @@ class BFSEngine:
             _FLIGHT.disarm()     # completed or already-dumped: no atexit dump
             evlog.close()
             self._evlog = RunEventLog(None)
+
+    def _generated_by_family(self, res) -> dict:
+        """Successors this run generated, by action family: the packed
+        statistics' per-family counts (``res.action_counts``) less what
+        a resumed snapshot carried.  Sums to the run's own generated."""
+        counts = getattr(res, "action_counts", None) or {}
+        base = self._family_base or {}
+        return {name: int(counts.get(name, 0)) - int(base.get(name, 0))
+                for name in self.dims.family_names}
+
+    def _note_family_base(self, resume) -> None:
+        """The snapshot the USER resumed gives the base; one the run
+        itself resumes after a degradation holds the run's own counts."""
+        if self._family_base is None:
+            self._family_base = dict(resume.action_counts)
 
     def _run_end_extra(self) -> dict:
         """Further fields of ``run_end`` (the mesh engine's exchange and
@@ -1457,7 +1477,9 @@ class BFSEngine:
         evlog.emit(
             "level_complete", level=res.diameter,
             frontier_rows=frontier_rows, distinct=res.distinct,
-            generated=res.generated, phase_seconds=phases,
+            generated=res.generated,
+            generated_by_family=self._generated_by_family(res),
+            phase_seconds=phases,
             unattributed_seconds=round(
                 elapsed - sum(phases.values()), 6),
             memory=mem, **work, **extra)
@@ -1661,6 +1683,7 @@ class BFSEngine:
                 res.diameter = resume.diameter
                 res.levels = list(resume.levels)
                 res.action_counts = dict(resume.action_counts)
+                self._note_family_base(resume)
                 # Coverage resumes its generated series from the checkpoint
                 # so the run-end table still matches generated_by_action
                 # (distinct/expanded are not checkpointed; see

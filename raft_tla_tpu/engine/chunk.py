@@ -66,7 +66,7 @@ STAGES = ("slice", "masks", "compact", "construct", "insert", "enqueue",
 # executable whose operations carry the old names.  The tag is in what
 # is hashed (``tag_stages``).  Change it with the names: the chunk and
 # ingest programs then compile once more.
-STAGES_TAG = "s2"
+STAGES_TAG = "s3"
 
 # The parts of ``construct``, as scopes nested in the stage's own
 # (``.../construct/<part>/...``): the parents' hash sums and the K-lane

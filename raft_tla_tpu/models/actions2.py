@@ -93,7 +93,16 @@ class V2Pipeline(NamedTuple):
 def build_v2(dims: RaftDims) -> V2Pipeline:
     N, V, L, M, W = (dims.n_servers, dims.n_values, dims.max_log,
                      dims.n_msg_slots, dims.msg_width)
-    quorum = dims.build_quorum()
+    # The quorum rule and the variant's families run under scopes of
+    # their own, ``quorum`` and ``extra``, wherever ``masks`` and
+    # ``lane_out`` use them: nested in the chunk's ``masks`` stage and in
+    # ``construct/lane_out`` (engine/chunk.py), so a capture says what a
+    # variant's hooks cost there.
+    quorum_fn = dims.build_quorum()
+
+    def quorum(st, i, member):
+        with jax.named_scope("quorum"):
+            return quorum_fn(st, i, member)
 
     # Fingerprint constants — MUST match ops/fingerprint.py exactly (same
     # fixed seed, same draw order) for bit-identical fingerprints.
@@ -477,21 +486,23 @@ def build_v2(dims: RaftDims) -> V2Pipeline:
         # ovf |= en & ~pack_ok) — enforced generically so a future
         # variant whose extras touch a packed-bound field cannot
         # silently diverge between pipelines.
-        if extra_masks is not None and extra_v1:
-            pk_parent = pack_ok_fn(st)
-            for (params, _kern), mask_fn in zip(extra_v1, extra_masks):
-                in_axes = (None, None) + (0,) * len(params)
-                en_e, ovf_e = jax.vmap(mask_fn, in_axes)(
-                    st, pk_parent, *params)
-                en_parts.append(en_e)
-                ovf_parts.append(ovf_e)
-        else:
-            for params, kern in extra_v1:
-                in_axes = (None,) + (0,) * len(params)
-                en_e, ovf_e, succ_e = jax.vmap(kern, in_axes)(st, *params)
-                pk_e = jax.vmap(pack_ok_fn)(succ_e)
-                en_parts.append(en_e)
-                ovf_parts.append(ovf_e | (en_e & ~pk_e))
+        with jax.named_scope("extra"):
+            if extra_masks is not None and extra_v1:
+                pk_parent = pack_ok_fn(st)
+                for (params, _kern), mask_fn in zip(extra_v1, extra_masks):
+                    in_axes = (None, None) + (0,) * len(params)
+                    en_e, ovf_e = jax.vmap(mask_fn, in_axes)(
+                        st, pk_parent, *params)
+                    en_parts.append(en_e)
+                    ovf_parts.append(ovf_e)
+            else:
+                for params, kern in extra_v1:
+                    in_axes = (None,) + (0,) * len(params)
+                    en_e, ovf_e, succ_e = jax.vmap(kern, in_axes)(
+                        st, *params)
+                    pk_e = jax.vmap(pack_ok_fn)(succ_e)
+                    en_parts.append(en_e)
+                    ovf_parts.append(ovf_e | (en_e & ~pk_e))
         return jnp.concatenate(en_parts), jnp.concatenate(ovf_parts)
 
     # -- per-lane delta fingerprint + sparse successor --------------------
@@ -731,18 +742,19 @@ def build_v2(dims: RaftDims) -> V2Pipeline:
         # family id.
         db0, db1 = d_base
         extra_folds = []
-        for e, ((params_e, _k1), lane_fn) in enumerate(
-                zip(extra_v1, extra_v2)):
-            is_e = fam == 10 + e
-            off_e, size_e = offs[10 + e], sizes[10 + e]
-            local = jnp.clip(g - off_e, 0, size_e - 1)
-            pe = tuple(arr[local] for arr in params_e)
-            dbe, dme, succ_e = lane_fn(st, *pe)
-            db0 = db0 + jnp.where(is_e, dbe[0], _U32(0))
-            db1 = db1 + jnp.where(is_e, dbe[1], _U32(0))
-            dm0 = dm0 + jnp.where(is_e, dme[0], _U32(0))
-            dm1 = dm1 + jnp.where(is_e, dme[1], _U32(0))
-            extra_folds.append((is_e, succ_e))
+        with jax.named_scope("extra"):
+            for e, ((params_e, _k1), lane_fn) in enumerate(
+                    zip(extra_v1, extra_v2)):
+                is_e = fam == 10 + e
+                off_e, size_e = offs[10 + e], sizes[10 + e]
+                local = jnp.clip(g - off_e, 0, size_e - 1)
+                pe = tuple(arr[local] for arr in params_e)
+                dbe, dme, succ_e = lane_fn(st, *pe)
+                db0 = db0 + jnp.where(is_e, dbe[0], _U32(0))
+                db1 = db1 + jnp.where(is_e, dbe[1], _U32(0))
+                dm0 = dm0 + jnp.where(is_e, dme[0], _U32(0))
+                dm1 = dm1 + jnp.where(is_e, dme[1], _U32(0))
+                extra_folds.append((is_e, succ_e))
         d_base = (db0, db1)
 
         hi = finalize(ph.base0 + d_base[0], ph.msum0 + dm0, 0)
@@ -796,8 +808,9 @@ def build_v2(dims: RaftDims) -> V2Pipeline:
                           commit=ci_o, votes_resp=vr_o, votes_gran=vg_o,
                           next_idx=ni_o, match_idx=mi_o,
                           msg=msg_o, msg_cnt=cnt_o)
-        for is_e, succ_e in extra_folds:
-            succ = _sel(is_e, succ_e, succ)
+        with jax.named_scope("extra"):
+            for is_e, succ_e in extra_folds:
+                succ = _sel(is_e, succ_e, succ)
         return hi, lo, succ
 
     return V2Pipeline(masks=masks, parent_hash=parent_hash,

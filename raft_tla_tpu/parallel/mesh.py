@@ -721,6 +721,8 @@ class MeshBFSEngine:
     _open_level_span = BFSEngine._open_level_span
     _close_level_span = BFSEngine._close_level_span
     _count_chunk_call = BFSEngine._count_chunk_call
+    _generated_by_family = BFSEngine._generated_by_family
+    _note_family_base = BFSEngine._note_family_base
     _replay = BFSEngine._replay
     _replay_step = BFSEngine._replay_step
 
@@ -1059,6 +1061,7 @@ class MeshBFSEngine:
             res.diameter = resume.diameter
             res.levels = list(resume.levels)
             res.action_counts = dict(resume.action_counts)
+            self._note_family_base(resume)
             # Coverage-only resume seeding (engine/bfs.py rule: registry
             # counters are process-cumulative and must not be re-seeded).
             coverage.seed_generated(resume.action_counts)

@@ -49,7 +49,7 @@ SHOWN = ("stages:", "idle ", "window compiles", "trace:", "trace reduced",
          "distinct states", "states generated", "diameter", "stop reason",
          "wall seconds", "states/sec", "VIOLATION", "pipeline",
          "setup by", "  marks", "  partition", "  jit", "  compiled",
-         "  runs so far")
+         "  runs so far", "variant:", "window generated")
 
 
 def command(root: str, workload: str, seed: int, trace: int, how: str):

@@ -164,6 +164,53 @@ def test_v2_chunk_program_small_batch(cfg, width, instances, invariants,
     assert wide or invariants == 1      # the suite writes such tensors
 
 
+def test_reconfig3_chunk_at_the_cells_sizes(one_chip, capsys):
+    """The chunk of ``configs/reconfig3.cfg`` as cell ``reconfig3`` runs
+    it (benchmark/configs/reconfig3.json: B = 2048, queues of 4,194,304
+    rows of 474 bytes, 2^25 keys, trace recording on) compiles for the
+    described v5e, v2 with the variant's extra families; its arguments
+    and temporaries, with the host loop's third queue beside them, fit
+    the chip's 16.9 GB.  The joint rule's scan reads a log value at a
+    traced index: it may not have become a loop over the lanes.  About
+    a minute here."""
+    import json
+    with open(os.path.join(REPO, "benchmark/configs/reconfig3.json"),
+              encoding="utf-8") as f:
+        config = json.load(f)
+    width = config["shapes"]["row_bytes"]
+    eng = small_engine(
+        "configs/reconfig3.cfg", width, 114, batch=config["batch"],
+        queue_capacity=config["queue_capacity"],
+        seen_capacity=config["seen_capacity"], record_trace=True)
+    assert (eng._B, eng._K) == (B, K)
+    c = compile_for(eng._chunk, lambda a: one_chip, *eng.chunk_avals())
+    m = c.memory_analysis()
+    spare_queue = (eng._Q + eng._PAD) * width
+    with capsys.disabled():
+        print(f"\nreconfig3 chunk at B={eng._B}, K={eng._K}: arguments "
+              f"{m.argument_size_in_bytes} bytes, temporaries "
+              f"{m.temp_size_in_bytes} bytes, spare queue {spare_queue} "
+              f"bytes")
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + spare_queue) < 16.9e9, m
+    text = c.as_text()
+    loops = re.findall(r' while\(.*op_name="([^"]*)"', text)
+    assert len(loops) >= 2, loops
+    # PR 39's finding, at this size only (the small batch above has
+    # none): ``flatten_state``'s read of the message value columns for
+    # the high-byte plane (``st.msg[:, cols]``, 2-byte values) is a
+    # gather XLA runs as a loop over the K lanes, twice a pass.  Queued
+    # in PERF.md section 7, not repaired there; nothing else may be one,
+    # the joint rule's scan least of all.
+    per_lane = [name for name in loops if name.endswith("/gather")]
+    assert len(per_lane) <= 2 and all(
+        "/construct/flatten/" in name for name in per_lane), loops
+    for site in ("masks", "construct/lane_out"):
+        for scope in ("quorum", "extra"):
+            assert re.search(rf"/while/body/{site}/[^\"]*\({scope}\)+/",
+                             text), (site, scope)
+
+
 @pytest.mark.parametrize("cfg, width, instances", [
     ("configs/MCraft_bounded.cfg", 473, 132),
     ("configs/TPUraft.cfg", 951, 224),
