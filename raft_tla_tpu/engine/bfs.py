@@ -30,8 +30,9 @@ TLC-semantics notes:
   ``check_deadlock=False``, Smokeraft.cfg:48);
 - the run stops at the first invariant violation, like TLC; counterexamples
   are reconstructed by fingerprint walk-back plus *kernel replay* (the trace
-  stores (parent fp, action instance id); re-running the expand kernel on the
-  replayed parent yields each next state bit-exactly);
+  stores (parent fp, action instance id); running the recorded instances
+  forward from the root, in one device call, yields each next state
+  bit-exactly: ``replay``, engine/replay.py);
 - ``generated`` counts every enabled successor evaluation (TLC's "states
   generated"), ``distinct`` counts FPSet insertions.
 
@@ -58,8 +59,9 @@ from ..models.actions import build_expand
 from ..models.invariants import build_inv_id
 from ..models.pystate import PyState
 from ..models.schema import (ROW_DTYPE, StateBatch, build_pack_guard,
-                             check_packable, decode_state, encode_state,
-                             flatten_state, state_width, unflatten_state)
+                             check_packable, decode_message, decode_state,
+                             encode_state, flatten_state, state_width,
+                             unflatten_state)
 from ..obs import (ActionCoverage, MetricsRegistry, RunEventLog,
                    SpanTracer, all_device_memory_stats,
                    device_memory_stats, events_path, peak_host_rss_bytes,
@@ -73,6 +75,7 @@ from ..ops import fpset
 from ..ops.fingerprint import build_fingerprint
 from ..utils.cfg import check_pipeline
 from .chunk import build_chunk_body, named_stage, tag_stages
+from .replay import ReplayScan, leading_true
 
 _I32 = jnp.int32
 
@@ -982,6 +985,9 @@ class BFSEngine:
             lambda q, start: jax.lax.dynamic_slice_in_dim(
                 q, start, self._frontier_rows, axis=0))
         self._fp_rows = jax.jit(fp_rows)
+        # The replay: a whole trace in one call (engine/replay.py), and
+        # the per-step matcher's two programs for what it cannot hold.
+        self._replay_scan = ReplayScan(dims, self.metrics)
         self._expand1 = jax.jit(expand)
         self._fp_batch = jax.jit(jax.vmap(fingerprint))
         self._root_check = (build_root_check(inv_fns, fingerprint)
@@ -2112,16 +2118,24 @@ class BFSEngine:
     # ------------------------------------------------------------------
     def replay(self, fp: int) -> List[Tuple[int, PyState]]:
         """Counterexample reconstruction: walk the trace back to a root,
-        then re-run the expand kernel forward, selecting at each step the
-        candidate whose fingerprint matches the recorded child fingerprint.
-        Returns [(action_id, state)] root-first (root action = -1).
+        then run the recorded action instances forward from it in ONE
+        device call (engine/replay.py: the successor's row threaded from
+        step to step, as the chunk program built it), and hold every step
+        to its record: the instance enabled, the successor's 64-bit key
+        the recorded child's.  Returns [(action_id, state)] root-first
+        (root action = -1).
 
-        Matching by fingerprint (not by recorded action id alone) matters:
-        queue rows keep the kernel's message-slot arrangement, while replay
-        re-encodes states canonically (sorted slots, schema.encode_state),
-        so a recorded slot-indexed action (Receive/Duplicate/Drop) may map
-        to a different slot of the canonical parent.  The recorded id is
-        preferred when it still matches, so labels stay stable."""
+        The recorded id of a slot-indexed action (Receive/Duplicate/Drop)
+        addresses the message slot of the kernel's arrangement, which
+        queue rows keep; the id returned addresses the same message in
+        the canonical parent (sorted slots, schema.encode_state: the
+        order a rendered state lists its messages in).
+
+        From the first step that fails either test (a chain whose rows
+        were re-encoded: a resumed snapshot's frontier, a witness) the
+        rest goes step by step through ``_replay_step``, which re-encodes
+        the parent canonically and matches the child by its key among
+        all enabled candidates, preferring the recorded id."""
         with self.metrics.scope("replay"):
             return self._replay(fp)
 
@@ -2140,11 +2154,35 @@ class BFSEngine:
             raise KeyError("trace chain does not reach a root")
         state = self.trace.roots[root_fp]
         out = [(-1, state)]
-        for step, (child_fp, g_rec) in enumerate(chain[1:], 1):
+        steps = chain[1:]
+        rows, keys, _calls = self._replay_scan(state,
+                                               [g for _fp, g in steps])
+        want = np.fromiter((f for f, _g in steps), np.uint64, len(steps))
+        held = leading_true(keys == want[:len(keys)])
+        parent = None       # the parent as the kernel arranged it
+        for row, (_fp, g_rec) in zip(rows[:held], steps):
+            succ = unflatten_state(row, self.dims)
+            out.append((self._canonical_instance(g_rec, parent, state),
+                        decode_state(succ, self.dims)))
+            parent, state = succ, out[-1][1]
+        for step, (child_fp, g_rec) in enumerate(steps[held:], held + 1):
+            mt.counter("engine/replay_fallback_steps")
             with mt.phase_timer("replay_step", step=step):
                 state, g = self._replay_step(state, child_fp, g_rec)
             out.append((g, state))
         return out
+
+    def _canonical_instance(self, g: int, parent, state: PyState) -> int:
+        """Instance ``g`` of ``parent`` (a ``StateBatch`` in the kernel's
+        slot arrangement; None: the root, encoded canonically) as the
+        instance of ``state``, the same parent decoded, under
+        ``encode_state``'s sorted slots: a slot-indexed instance moves to
+        its message's rank in the sorted bag, any other is itself."""
+        slot = self.dims.instance_info(g)[1].get("slot")
+        if slot is None or parent is None:
+            return g
+        message = decode_message(np.asarray(parent.msg[slot]), self.dims)
+        return g - slot + sorted(m for m, _c in state.messages).index(message)
 
     def _replay_step(self, state, child_fp: int, g_rec: int):
         """The successor of ``state`` whose fingerprint is ``child_fp``,

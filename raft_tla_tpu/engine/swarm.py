@@ -54,7 +54,7 @@ from ..models.actions import build_expand
 from ..models.dims import RaftDims
 from ..models.invariants import build_inv_id
 from ..models.pystate import PyState
-from ..models.schema import (StateBatch, build_pack_guard, check_packable,
+from ..models.schema import (build_pack_guard, check_packable,
                              decode_state, encode_state, flatten_state,
                              stack_states, unflatten_state)
 from ..obs import (MetricsRegistry, RunEventLog, SpanTracer,
@@ -67,6 +67,7 @@ from ..ops.walk_kernels import (CHOICE_STREAM, FAMILY_STREAM, INIT_STREAM,
                                 bloom_push, family_subset, preferred_choice,
                                 ring_init, ring_probe, ring_push, ring_reset,
                                 walk_bits)
+from .replay import ReplayScan
 from .bfs import (Violation, _resolve_pipeline, compiles_by_span,
                   watch_compiles)
 
@@ -95,9 +96,13 @@ WALK_STAGES_TAG = "w1"
 # the loop made, ``steps`` lockstep walk-steps, ``latch_step`` the global
 # step of the reported violation (-1: none), ``steps_past_latch`` the
 # walk-steps computed at or after it, ``restarts`` traces begun after the
-# first W, ``reconstruct_steps`` expand round trips of the replay.
+# first W, ``reconstruct_scans`` calls of the replay's fused program
+# (engine/replay.py), ``reconstruct_steps`` expand round trips of the
+# replay: one a step before that program, none since, the key kept for
+# who reads ``run_end`` by it.
 SWARM_COUNTERS = ("chunk_calls", "slices", "fetches", "steps", "latch_step",
-                  "steps_past_latch", "restarts", "reconstruct_steps")
+                  "steps_past_latch", "restarts", "reconstruct_steps",
+                  "reconstruct_scans")
 
 
 @dataclasses.dataclass
@@ -500,7 +505,7 @@ class SwarmEngine:
             return jnp.full(batch.term.shape[:1], -1, _I32)
 
         self._roots_inv = jax.jit(roots_inv)
-        self._expand1 = jax.jit(build_expand(dims))
+        self._replay_scan = ReplayScan(dims, self.metrics)
         self._fp1 = jax.jit(build_fingerprint(dims))
         self._last_trace: Optional[List[Tuple[int, PyState]]] = None
         # The last run's walkers, kept on the device for
@@ -971,24 +976,19 @@ class SwarmEngine:
 
     def replay_actions(self, root: PyState, actions) -> list:
         """``[(action, PyState), ...]`` from ``root`` (action -1) through
-        the instance ids ``actions``, one expand round trip a step; stops
-        at an id that is negative or not enabled.  Threads the ENCODED
-        candidate row, never a re-encoded state (the simulator's
-        slot-aliasing rule: re-encoding reassigns message slots, and
-        slot-indexed action ids would then address the wrong message)."""
-        st = encode_state(root, self.dims)
-        trace = [(-1, root)]
-        for g in actions:
-            g = int(g)
-            with self.metrics.phase_timer("reconstruct_step"):
-                cands, en, _ovf = self._expand1(st)
-                self._counts["reconstruct_steps"] += 1
-                if g < 0 or not bool(np.asarray(en)[g]):
-                    break
-                row = jax.tree.map(lambda a: np.asarray(a)[g], cands)
-            st = StateBatch(*row)
-            trace.append((g, decode_state(st, self.dims)))
-        return trace
+        the instance ids ``actions``, in one device call (engine/
+        replay.py; ``ceil(len / capacity)`` for a trace longer than its
+        buffer); stops at an id that is negative or not enabled.  The
+        program threads the ENCODED successor row, never a re-encoded
+        state (the simulator's slot-aliasing rule: re-encoding reassigns
+        message slots, and slot-indexed action ids would then address
+        the wrong message)."""
+        rows, _keys, calls = self._replay_scan(root, actions)
+        self._counts["reconstruct_scans"] += calls
+        return [(-1, root)] + [
+            (int(g), decode_state(unflatten_state(row, self.dims),
+                                  self.dims))
+            for g, row in zip(actions, rows)]
 
     def _reconstruct(self, res: SwarmResult, roots, latch):
         """Replay the latched (root, action sequence) through the expand

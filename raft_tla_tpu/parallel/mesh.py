@@ -60,6 +60,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.experimental.xla_metadata import set_xla_metadata
 
 from ..engine.chunk import build_chunk_body, tag_stages
+from ..engine.replay import ReplayScan
 from ..engine.bfs import (BFSEngine, EngineConfig, EngineResult, TraceStore,
                           Violation, _exit_condition_hit, _family_groups_meta,
                           _progress_line, _TraceFlush, build_root_check,
@@ -551,6 +552,7 @@ class MeshBFSEngine:
             lambda q, rows, at: jax.lax.dynamic_update_slice(
                 q, rows, (jnp.int32(0), at, jnp.int32(0))),
             donate_argnums=(0,))
+        self._replay_scan = ReplayScan(dims, self.metrics)
         self._expand1 = jax.jit(expand)
         self._fp_batch = jax.jit(jax.vmap(fingerprint))
         self._root_check = (build_root_check(inv_fns, fingerprint)
@@ -725,6 +727,7 @@ class MeshBFSEngine:
     _note_family_base = BFSEngine._note_family_base
     _replay = BFSEngine._replay
     _replay_step = BFSEngine._replay_step
+    _canonical_instance = BFSEngine._canonical_instance
 
     def _sample_skew(self, res, next_counts, ssize) -> None:
         """Per-shard balance telemetry, sampled at each level boundary

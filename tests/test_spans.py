@@ -132,11 +132,14 @@ def test_run_level_replay_reach_all_three_sinks(verdict):
     # ... and so does every leaf, under the same name in each.
     for name in ("run_init", "warmup", "ingest", "chunk", "stats_fetch",
                  "account", "trace_flush", "grow", "level_end",
-                 "frontier_fetch", "run_end", "trace_chain", "replay_step"):
+                 "frontier_fetch", "run_end", "trace_chain", "replay_scan"):
         assert hist[f"phase/{name}"]["count"] == sum(
             e["name"] == name for e in chrome) == sum(
             nm == f"raft.{name}" for nm, _s in noted) > 0, name
-    assert hist["phase/replay_step"]["count"] == len(steps) - 1 == 9
+    # The whole trace in one call of the fused program, no step through
+    # the per-step matcher (tests/test_replay_scan.py holds its spans).
+    assert hist["phase/replay_scan"]["count"] == 1 and len(steps) - 1 == 9
+    assert "phase/replay_step" not in hist
 
 
 def test_spans_nest_and_share_the_run_id(verdict):
@@ -150,7 +153,7 @@ def test_spans_nest_and_share_the_run_id(verdict):
     inside = lambda a, b: (b["ts"] <= a["ts"] + 1e-3 and  # noqa: E731
                            a["ts"] + a["dur"] <= b["ts"] + b["dur"] + 1e-3)
     for e in chrome:
-        if e["name"] in ("trace_chain", "replay_step"):
+        if e["name"] in ("trace_chain", "replay_scan"):
             assert inside(e, replay), e
         elif e["name"] not in ("run", "replay"):
             assert inside(e, run), e
@@ -170,7 +173,7 @@ def test_spans_nest_and_share_the_run_id(verdict):
     assert stats["raft.account"] == {"run", "call", "passes"}
     assert stats["raft.chunk"] == {"run", "call"}
     assert stats["raft.trace_flush"] == {"run"}
-    assert stats["raft.replay_step"] == {"run", "step"}
+    assert stats["raft.replay_scan"] == {"run", "steps"}
 
 
 def test_the_span_tree_closes_over_the_run(verdict):
@@ -194,7 +197,7 @@ def test_the_span_tree_closes_over_the_run(verdict):
     # The histograms say the same as the Chrome file.
     phases = eng.metrics.phase_seconds()
     in_run = sum(v for k, v in phases.items()
-                 if k not in ("trace_chain", "replay_step"))
+                 if k not in ("trace_chain", "replay_scan"))
     assert in_run * 1e6 == pytest.approx(covered, rel=0.02)
 
 

@@ -255,8 +255,11 @@ def test_run_end_carries_the_counters_and_they_conserve(hunted):
         rounds * eng.chunk - end["latch_step"])
     # 17 copies a slice a round, two more for each slice that latched
     assert end["fetches"] >= 17 * end["chunk_calls"] + 2
-    assert end["reconstruct_steps"] == len(eng.replay(
-        res.violation.fingerprint)) - 1
+    # the latched trace rebuilt in one call of the fused program
+    # (engine/replay.py), no expand round trip a step
+    assert (end["reconstruct_scans"], end["reconstruct_steps"]) == (1, 0)
+    assert eng.metrics.counter_value("engine/replay_scan_steps") >= len(
+        eng.replay(res.violation.fingerprint)) - 1
     # the registry's sums, for a process that serves many runs
     assert eng.metrics.counter_value("swarm/chunk_calls") \
         == end["chunk_calls"]
@@ -268,10 +271,11 @@ def test_the_spans_of_a_run(hunted):
     hist = eng.metrics.snapshot()["histograms"]
     for name in ("scope/run", "scope/reconstruct", "phase/swarm_init",
                  "phase/swarm_chunk", "phase/swarm_fetch",
-                 "phase/reconstruct_step", "phase/run_end"):
+                 "phase/replay_scan", "phase/run_end"):
         assert hist[name]["count"] >= 1, name
-    assert hist["phase/reconstruct_step"]["count"] \
-        == eng._counts["reconstruct_steps"]
+    assert hist["phase/replay_scan"]["count"] \
+        == eng.metrics.counter_value("engine/replay_scans")
+    assert "phase/reconstruct_step" not in hist
 
 
 def test_the_walk_chunk_names_its_stages(setup):
