@@ -1159,6 +1159,9 @@ class BFSEngine:
             "run_start", engine=type(self).__name__, dims=repr(self.dims),
             batch=cfg.batch, sync_every=cfg.sync_every,
             record_trace=cfg.record_trace, resume=resume is not None,
+            # States the run was given: 1 from Init, more from roots past
+            # it; 0 on a resume, whose snapshot brings its own.
+            roots=len(init_states or ()),
             memory=device_memory_stats(),
             process=process_record().run_start())
         self._cur_res = None

@@ -3,8 +3,10 @@
 
     python3 scripts/program_digests.py [<checkout>]
 
-Lowers ``BFSEngine``'s ``chunk`` and ``ingest`` for four cfgs
-(``MCraft_safety`` is the one that traces ``models/safety.py``),
+Lowers ``BFSEngine``'s ``chunk`` and ``ingest`` for six cfgs
+(``MCraft_safety`` and ``reconfig3_safety`` are the ones that trace
+``models/safety.py``, the second under ``ReconfigDims``; a cfg a checkout
+lacks is left out),
 ``MeshBFSEngine``'s two programs over four virtual devices (trace
 recording on and off) and ``SwarmEngine``'s walk chunk at a small batch,
 and prints sha256 of each ``.lower(...).as_text()``.  jax's
@@ -50,7 +52,9 @@ def small(**kw) -> EngineConfig:
 def main() -> None:
     i32 = S((), jnp.int32)
     for cfg in ("MCraft_bounded", "TPUraft", "MCraft_noleader",
-                "MCraft_safety"):
+                "MCraft_safety", "reconfig3", "reconfig3_safety"):
+        if not os.path.exists(os.path.join(ROOT, f"configs/{cfg}.cfg")):
+            continue
         eng = make_engine(
             load_config(os.path.join(ROOT, f"configs/{cfg}.cfg")), small())
         av = eng.chunk_avals()

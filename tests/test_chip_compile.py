@@ -140,7 +140,9 @@ def invariant_results_over_the_lanes(text, lanes):
     ("configs/MCraft_noleader.cfg", 403, 132, 1),
     ("configs/MCraft_safety.cfg", 473, 132, 10),
     ("configs/reconfig3.cfg", 474, 114, 1),
-], ids=["mcraft3", "raft5", "noleader", "safety", "reconfig3"])
+    ("configs/reconfig3_safety.cfg", 474, 114, 10),
+], ids=["mcraft3", "raft5", "noleader", "safety", "reconfig3",
+        "reconfig3-safety"])
 def test_v2_chunk_program_small_batch(cfg, width, instances, invariants,
                                       one_chip):
     """The whole BFSEngine chunk program (pipeline=auto -> v2, trace
@@ -164,33 +166,42 @@ def test_v2_chunk_program_small_batch(cfg, width, instances, invariants,
     assert wide or invariants == 1      # the suite writes such tensors
 
 
-def test_reconfig3_chunk_at_the_cells_sizes(one_chip, capsys):
+@pytest.mark.parametrize("name, invariants", [
+    ("reconfig3", 1), ("reconfig3-safety", 10)])
+def test_reconfig3_chunk_at_the_cells_sizes(name, invariants, one_chip,
+                                            capsys):
     """The chunk of ``configs/reconfig3.cfg`` as cell ``reconfig3`` runs
     it (benchmark/configs/reconfig3.json: B = 2048, queues of 4,194,304
     rows of 474 bytes, 2^25 keys, trace recording on) compiles for the
     described v5e, v2 with the variant's extra families; its arguments
     and temporaries, with the host loop's third queue beside them, fit
     the chip's 16.9 GB.  The joint rule's scan reads a log value at a
-    traced index: it may not have become a loop over the lanes.  About
-    a minute here."""
+    traced index: it may not have become a loop over the lanes.  And
+    the chunk of ``configs/reconfig3_safety.cfg`` as ``reconfig3-safety``
+    runs it: the same pools under the ten invariants, whose results over
+    the K lanes lie lanes-minor under ``ReconfigDims`` too.  About a
+    minute each here."""
     import json
-    with open(os.path.join(REPO, "benchmark/configs/reconfig3.json"),
+    with open(os.path.join(REPO, f"benchmark/configs/{name}.json"),
               encoding="utf-8") as f:
         config = json.load(f)
     width = config["shapes"]["row_bytes"]
     eng = small_engine(
-        "configs/reconfig3.cfg", width, 114, batch=config["batch"],
+        "configs/" + config["cfg_name"], width, 114, batch=config["batch"],
         queue_capacity=config["queue_capacity"],
         seen_capacity=config["seen_capacity"], record_trace=True)
     assert (eng._B, eng._K) == (B, K)
+    assert len(eng.inv_names) == invariants
     c = compile_for(eng._chunk, lambda a: one_chip, *eng.chunk_avals())
     m = c.memory_analysis()
     spare_queue = (eng._Q + eng._PAD) * width
     with capsys.disabled():
-        print(f"\nreconfig3 chunk at B={eng._B}, K={eng._K}: arguments "
+        print(f"\n{name} chunk at B={eng._B}, K={eng._K}: arguments "
               f"{m.argument_size_in_bytes} bytes, temporaries "
               f"{m.temp_size_in_bytes} bytes, spare queue {spare_queue} "
               f"bytes")
+    assert invariant_results_over_the_lanes(c.as_text(), eng._K) \
+        or invariants == 1
     assert (m.argument_size_in_bytes + m.temp_size_in_bytes
             + spare_queue) < 16.9e9, m
     text = c.as_text()
