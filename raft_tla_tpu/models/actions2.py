@@ -34,8 +34,11 @@ same deliberate bug replications: the AppendEntriesAlreadyDone hidden
 guard raft.tla:309+:317, UpdateTerm leaving the message in flight :378,
 one-entry truncation :323-324).  Spec variants with ``extra_families``
 ride the same pipeline when they implement ``dims.build_extra_v2``
-(models/reconfig.py does), and the extra families' deltas/successors fold
-into ``lane_out`` by family id.  Extra-family MASKS come from the
+(models/reconfig.py does).  A family declared a :class:`~.dims.LogAppend`
+is written through ``lane_out``'s one log-append write, ClientRequest's,
+and runs nothing of its own there but its value; a family given as a
+general ``lane_fn`` runs on every lane and has its deltas/successors
+folded into ``lane_out`` by family id.  Extra-family MASKS come from the
 variant's guards-only ``build_extra_masks_v2`` kernels when provided
 (one ``pack_ok(parent)`` per parent, no per-lane successors); absent
 that, the masks pass falls back to running the variant's full v1 kernels
@@ -55,7 +58,7 @@ import numpy as np
 
 from ..ops.fingerprint import SENTINEL, fmix32
 from .dims import (AEQ, AER, CANDIDATE, FOLLOWER, LEADER, NIL, RVQ, RVR,
-                   RaftDims)
+                   LogAppend, RaftDims)
 from .actions import _add1, _sel, _set1, _set2, _setrow
 from .schema import StateBatch
 
@@ -197,6 +200,16 @@ def build_v2(dims: RaftDims) -> V2Pipeline:
             f"dims {type(dims).__name__} does not provide v2 kernels for "
             "its extra families (build_extra_v2); use the v1 pipeline")
     extra_v1 = dims.build_extra_kernels()
+    # The two forms an entry may take (dims.build_extra_v2), each with its
+    # family id and the family's parameter arrays: declared appends ride
+    # lane_out's own log write, general lane_fns are folded in after it.
+    appends = [(10 + e, entry.value_fn, [np.asarray(a) for a in params])
+               for e, (entry, (params, _k1)) in enumerate(
+                   zip(extra_v2, extra_v1)) if isinstance(entry, LogAppend)]
+    generals = [(10 + e, params, entry)
+                for e, (entry, (params, _k1)) in enumerate(
+                    zip(extra_v2, extra_v1))
+                if not isinstance(entry, LogAppend)]
     extra_masks = dims.build_extra_masks_v2()
     if extra_masks is not None and len(extra_masks) != len(extra_v1):
         raise ValueError(
@@ -525,6 +538,17 @@ def build_v2(dims: RaftDims) -> V2Pipeline:
                 p1_np[g], p2_np[g] = k // V, k % V + 1
             else:                              # slot families
                 p1_np[g] = k
+    # A declared append's instances decode to the family's own
+    # parameters, (i) or (i, one more), as ClientRequest's do to (i, v).
+    for fam, _value_fn, params in appends:
+        if not 1 <= len(params) <= 2:
+            raise ValueError(
+                f"{dims.family_names[fam]}: a LogAppend family takes "
+                f"(i) or (i, one more), not {len(params)} parameters")
+        grid = slice(offs[fam], offs[fam] + sizes[fam])
+        p1_np[grid] = params[0]
+        if len(params) == 2:
+            p2_np[grid] = params[1]
     fam_t = jnp.asarray(fam_np)
     p1_t = jnp.asarray(p1_np)
     p2_t = jnp.asarray(p2_np)
@@ -590,15 +614,25 @@ def build_v2(dims: RaftDims) -> V2Pipeline:
         noc_k = jnp.clip(rc["ln"], 0, L - 1)
         conf_fire = is_recv & rc["en_conf"]
         noc_fire = is_recv & rc["en_noc"]
-        log_tgt_i = jnp.where(is_cr, i, ri)
-        log_k = jnp.where(is_cr, cr_k, jnp.where(conf_fire, conf_k, noc_k))
-        log_t_new = jnp.where(is_cr, term_i,
+        # A variant's declared appends (dims.LogAppend) are this same
+        # write with another value: ``is_app`` and ``app_v`` take them
+        # in, and everything below covers them with ClientRequest.
+        is_app, app_v = is_cr, jv
+        with jax.named_scope("extra"):
+            for fam_e, value_fn, params in appends:
+                is_e = fam == fam_e
+                app_v = jnp.where(
+                    is_e, value_fn(st, i, *(jv,) * (len(params) - 1)), app_v)
+                is_app = is_app | is_e
+        log_tgt_i = jnp.where(is_app, i, ri)
+        log_k = jnp.where(is_app, cr_k, jnp.where(conf_fire, conf_k, noc_k))
+        log_t_new = jnp.where(is_app, term_i,
                               jnp.where(conf_fire, 0, rc["eterm"]))
-        log_v_new = jnp.where(is_cr, jv,
+        log_v_new = jnp.where(is_app, app_v,
                               jnp.where(conf_fire, 0, rc["eval_"]))
         ll_new = jnp.where(conf_fire, rc["ln"] - 1,
-                           jnp.where(is_cr, ln_i + 1, rc["ln"] + 1))
-        log_wr = is_cr | conf_fire | noc_fire
+                           jnp.where(is_app, ln_i + 1, rc["ln"] + 1))
+        log_wr = is_app | conf_fire | noc_fire
 
         # commit: Restart -> 0; AdvanceCommitIndex -> rule; Done -> mcommit.
         idxs = jnp.arange(1, L + 1, dtype=_I32)
@@ -736,17 +770,17 @@ def build_v2(dims: RaftDims) -> V2Pipeline:
             + jnp.where(do_send & sctx["ok"], d_send[1], _U32(0)) \
             + jnp.where(is_dup, d_dup[1], _U32(0))
 
-        # Extra-family lanes: on base-family lanes every *_wr gate above
-        # is False, so the base deltas are zero and the base successor is
-        # the parent — fold the variant kernels' deltas/successors in by
-        # family id.
+        # General extra-family lanes (an entry that is a ``lane_fn``): on
+        # base-family lanes every *_wr gate above is False, so the base
+        # deltas are zero and the base successor is the parent — fold the
+        # variant kernels' deltas/successors in by family id.  A declared
+        # append has nothing to do here: the log write above was its.
         db0, db1 = d_base
         extra_folds = []
         with jax.named_scope("extra"):
-            for e, ((params_e, _k1), lane_fn) in enumerate(
-                    zip(extra_v1, extra_v2)):
-                is_e = fam == 10 + e
-                off_e, size_e = offs[10 + e], sizes[10 + e]
+            for fam_e, params_e, lane_fn in generals:
+                is_e = fam == fam_e
+                off_e, size_e = offs[fam_e], sizes[fam_e]
                 local = jnp.clip(g - off_e, 0, size_e - 1)
                 pe = tuple(arr[local] for arr in params_e)
                 dbe, dme, succ_e = lane_fn(st, *pe)

@@ -52,6 +52,7 @@ the audit's static table.
 from __future__ import annotations
 
 import dataclasses
+from typing import Callable
 
 # Role codes.
 FOLLOWER, CANDIDATE, LEADER = 0, 1, 2
@@ -77,6 +78,19 @@ A_DROP = 9           # \E m : DropMessage(m)        raft.tla:430 -> :415
 FAMILY_NAMES = ("Restart", "Timeout", "RequestVote", "BecomeLeader",
                 "ClientRequest", "AdvanceCommitIndex", "AppendEntries",
                 "Receive", "DuplicateMessage", "DropMessage")
+
+
+@dataclasses.dataclass(frozen=True)
+class LogAppend:
+    """A ``build_extra_v2`` entry for a family whose whole footprint is ONE
+    log append: instance ``(i, *rest)`` (the family's
+    ``build_extra_kernels`` parameters: the appending server first, at
+    most one more) writes ``(term[i], value_fn(state, i, *rest))`` at
+    ``(i, Len(log[i]))``, ``ClientRequest``'s footprint (raft.tla:206-213)
+    with another value.  ``lane_out`` then writes the lane through the
+    append it already has; ``value_fn`` is all the family runs there."""
+
+    value_fn: Callable
 
 
 @dataclasses.dataclass(frozen=True)
@@ -198,16 +212,30 @@ class RaftDims:
         """Delta-pipeline kernels for the extra families (models/
         actions2.py), in ``extra_families`` order, or ``None`` if the
         variant does not support the v2 pipeline (engines then fall back
-        to v1).  Each entry is one ``lane_fn(state, *params) ->
-        ((d_base0, d_base1), (d_msum0, d_msum1), successor)`` — the
-        fingerprint-sum deltas plus the sparsely-constructed successor
-        for ONE instance.  The parameter arrays are NOT duplicated here:
-        actions2 feeds each lane_fn the ``build_extra_kernels`` param
-        arrays of the same family (single source of truth for the grid
-        order).  ``fp_helpers`` is actions2's delta toolkit
-        (dpos/dvec/dsum/offsets...).  Masks and the pack guard come for
-        free from ``build_extra_kernels`` (actions2 evaluates the v1
-        kernel's guards and folds ``enabled & ~pack_ok(successor)``
+        to v1).  An entry takes one of two forms:
+
+        - a :class:`LogAppend` declaration, for a family that appends one
+          entry to ``log[i]``.  It costs a lane nothing beyond its value:
+          ``lane_out`` folds the family into ``ClientRequest``'s write
+          (position, term, length, the three fingerprint deltas and the
+          three successor fields are computed once for all of them), and
+          reads the instance's parameters from its static decode tables,
+          which it fills from ``build_extra_kernels``' arrays at build
+          time;
+        - a general ``lane_fn(state, *params) -> ((d_base0, d_base1),
+          (d_msum0, d_msum1), successor)`` — the fingerprint-sum deltas
+          plus the sparsely-constructed successor for ONE instance, for
+          any other footprint.  It runs on every lane, its parameters
+          read at the lane's traced position, and the successor is
+          selected in over the whole state: what a declaration saves.
+
+        The parameter arrays are NOT duplicated here: both forms get the
+        ``build_extra_kernels`` arrays of the same family (single source
+        of truth for the grid order).  ``fp_helpers`` is actions2's
+        delta toolkit (dpos/dvec/dsum/offsets...), for the general form.
+        Masks and the pack guard come from ``build_extra_masks_v2`` or,
+        without it, from ``build_extra_kernels`` (actions2 evaluates the
+        v1 kernel's guards and folds ``enabled & ~pack_ok(successor)``
         exactly as the v1 chunk does).  Base spec: no extras."""
         return []
 

@@ -51,7 +51,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Tuple
 
-from .dims import LEADER, RaftDims
+from .dims import LEADER, LogAppend, RaftDims
 
 # Log-entry values >= CFG_BASE are configuration entries; below are client
 # values.  Layout: CFG_BASE + (old_mask << 8) + new_mask, old_mask == 0
@@ -183,8 +183,8 @@ class ReconfigDims(RaftDims):
 
     # -- new actions ------------------------------------------------------
     def _append_entry(self, st, i, val):
-        """Shared log-append used by BOTH pipelines' extra kernels:
-        (fits, successor) for appending ``(term[i], val)`` to log[i]."""
+        """The v1 extra kernels' log-append: (fits, successor) for
+        appending ``(term[i], val)`` to log[i]."""
         import jax.numpy as jnp
 
         from .actions import _add1, _set2
@@ -197,43 +197,53 @@ class ReconfigDims(RaftDims):
             log_len=_add1(st.log_len, i, 1))
 
     def _build_guards(self):
-        """Shared (enabled, appended-value) closures for the two extra
-        actions — the ONE source of the guard expressions, used by all
-        three kernel builders (v1 kernels, v2 lanes, v2 guards-only
-        masks) so the pipelines cannot drift."""
+        """``((enabled, value), (enabled, value))`` closures for
+        InitiateReconfig and FinalizeReconfig — the ONE source of the
+        guard and appended-value expressions, used by all three kernel
+        builders (v1 kernels, v2 declarations, v2 guards-only masks) so
+        the pipelines cannot drift.  Apart, so that a caller traces only
+        the half it uses: the masks pass no value, ``lane_out`` no
+        guard."""
         config_scan = _build_config_scan(self)
 
-        def initiate(st, i, c):
-            """Leader with a final config appends C_current,c."""
+        def initiate_en(st, i, c):
+            """Leader with a final config (no change in progress)."""
             old, new, _idx = config_scan(st, i)
-            en = (st.role[i] == LEADER) & (old == 0) & (c != new)
-            return en, CFG_BASE + (new << 8) + c
+            return (st.role[i] == LEADER) & (old == 0) & (c != new)
 
-        def finalize(st, i):
-            """Leader whose committed joint config C_old,new appends
-            C_new."""
-            old, new, idx = config_scan(st, i)
-            en = ((st.role[i] == LEADER) & (old > 0)
-                  & (st.commit[i] >= idx))
-            return en, CFG_BASE + new
+        def initiate_val(st, i, c):
+            """The joint entry C_current,c it appends."""
+            _old, new, _idx = config_scan(st, i)
+            return CFG_BASE + (new << 8) + c
 
-        return initiate, finalize
+        def finalize_en(st, i):
+            """Leader whose joint config C_old,new is committed."""
+            old, _new, idx = config_scan(st, i)
+            return ((st.role[i] == LEADER) & (old > 0)
+                    & (st.commit[i] >= idx))
+
+        def finalize_val(st, i):
+            """The final entry C_new it appends."""
+            _old, new, _idx = config_scan(st, i)
+            return CFG_BASE + new
+
+        return (initiate_en, initiate_val), (finalize_en, finalize_val)
 
     def build_extra_kernels(self):
         import jax.numpy as jnp
 
-        init_g, fin_g = self._build_guards()
+        (init_en, init_val), (fin_en, fin_val) = self._build_guards()
         N = self.n_servers
         i32 = jnp.int32
 
         def initiate(st, i, c):
-            en, val = init_g(st, i, c)
-            fits, new_st = self._append_entry(st, i, val)
+            en = init_en(st, i, c)
+            fits, new_st = self._append_entry(st, i, init_val(st, i, c))
             return en & fits, en & ~fits, new_st
 
         def finalize(st, i):
-            en, val = fin_g(st, i)
-            fits, new_st = self._append_entry(st, i, val)
+            en = fin_en(st, i)
+            fits, new_st = self._append_entry(st, i, fin_val(st, i))
             return en & fits, en & ~fits, new_st
 
         targets = jnp.asarray(self.targets, i32)
@@ -244,39 +254,16 @@ class ReconfigDims(RaftDims):
         return [((ii, cc), initiate), ((servers,), finalize)]
 
     def build_extra_v2(self, fp):
-        """Delta-pipeline kernels (models/actions2.py contract: one
-        lane_fn per extra family; param arrays come from
-        ``build_extra_kernels``): both extra actions append ONE log entry
-        at (i, Len(log[i])) — the same footprint as ClientRequest — so
-        the fingerprint delta is three ordered-position shifts and the
-        bag is untouched.  The successor comes from the SAME
-        ``_append_entry`` the v1 kernels use (no drift between
-        pipelines)."""
-        import jax.numpy as jnp
-
-        init_g, fin_g = self._build_guards()
-        L = self.max_log
-
-        def append_delta_succ(st, i, val):
-            ln = st.log_len[i]
-            k = jnp.clip(ln, 0, L - 1)
-            d_base = fp.dsum(
-                fp.dpos(fp.O_LT + i * L + k, st.log_term[i, k],
-                        st.term[i]),
-                fp.dpos(fp.O_LV + i * L + k, st.log_val[i, k], val),
-                fp.dpos(fp.O_LL + i, ln, ln + 1))
-            _fits, succ = self._append_entry(st, i, val)
-            return d_base, fp.ZD, succ
-
-        def initiate(st, i, c):
-            _en, val = init_g(st, i, c)
-            return append_delta_succ(st, i, val)
-
-        def finalize(st, i):
-            _en, val = fin_g(st, i)
-            return append_delta_succ(st, i, val)
-
-        return [initiate, finalize]
+        """Delta-pipeline entries (models/dims.py ``build_extra_v2``
+        contract): both extra actions append ONE log entry at
+        (i, Len(log[i])) — the same footprint as ClientRequest — so each
+        is a :class:`LogAppend` declaration and ``lane_out`` writes the
+        lane through ClientRequest's append: the same three
+        ordered-position shifts, the bag untouched.  What a lane runs for
+        them is the appended value, ``_build_guards``' (``fp``, the delta
+        toolkit, is not needed)."""
+        (_en0, init_val), (_en1, fin_val) = self._build_guards()
+        return [LogAppend(init_val), LogAppend(fin_val)]
 
     def build_extra_masks_v2(self):
         """Guards-only masks (dims.build_extra_masks_v2 contract): both
@@ -288,7 +275,7 @@ class ReconfigDims(RaftDims):
         pack_ok(parent)`` exactly and the per-lane successor + pack-guard
         evaluation of the v1 fallback is pure overhead.  Bit-identity
         with that fallback is property-tested (tests/test_actions2.py)."""
-        init_g, fin_g = self._build_guards()
+        (init_en, _val0), (fin_en, _val1) = self._build_guards()
         L = self.max_log
 
         def _append_masks(en, st, i, pk_parent):
@@ -296,12 +283,10 @@ class ReconfigDims(RaftDims):
             return en & fits, (en & ~fits) | (en & fits & ~pk_parent)
 
         def initiate(st, pk_parent, i, c):
-            en, _val = init_g(st, i, c)
-            return _append_masks(en, st, i, pk_parent)
+            return _append_masks(init_en(st, i, c), st, i, pk_parent)
 
         def finalize(st, pk_parent, i):
-            en, _val = fin_g(st, i)
-            return _append_masks(en, st, i, pk_parent)
+            return _append_masks(fin_en(st, i), st, i, pk_parent)
 
         return [initiate, finalize]
 
@@ -365,12 +350,18 @@ def _build_config_scan(dims: "ReconfigDims"):
     full = (1 << N) - 1
 
     def config_scan(st, i):
-        vals = st.log_val[i]
+        # Row i and position k are read by compare, select and sum (the
+        # idiom of models/safety.py ``_pick``), not ``table[traced]``:
+        # under ``vmap`` that is a gather, which ``lane_out`` would pay
+        # on every one of the K lanes.
+        row = jnp.arange(N, dtype=i32) == i
+        vals = jnp.sum(jnp.where(row[:, None], st.log_val, 0), axis=0)
+        ln = jnp.sum(jnp.where(row, st.log_len, 0))
         lanes = jnp.arange(L, dtype=i32)
-        is_cfg = (lanes < st.log_len[i]) & (vals >= CFG_BASE)
+        is_cfg = (lanes < ln) & (vals >= CFG_BASE)
         has = jnp.any(is_cfg)
         k = jnp.max(jnp.where(is_cfg, lanes, -1))
-        enc = vals[jnp.clip(k, 0, L - 1)] - CFG_BASE
+        enc = jnp.sum(jnp.where(lanes == k, vals, 0)) - CFG_BASE
         old = jnp.where(has, (enc >> 8) & 0xFF, 0)
         new = jnp.where(has, enc & 0xFF, full)
         return old, new, jnp.where(has, k + 1, 0)

@@ -7,6 +7,8 @@ of every enabled successor — because the engines treat the two paths as
 interchangeable (shared checkpoints, shared differential baselines).
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -347,3 +349,168 @@ def test_auto_runs_v2_on_every_base_alphabet_cfg(cfg):
     assert eng.config.pipeline == "auto"
     res = eng.run(initial_states(setup))
     assert res.pipeline == "v2" and res.diameter == 2
+
+
+# -- the variant's declared appends, written through lane_out's one log
+# -- append (models/dims.py LogAppend) ----------------------------------------
+
+def _general_form_dims(dims):
+    """``dims``' two families as general ``lane_fn`` entries, the other
+    form ``build_extra_v2`` may return: the kernels ``ReconfigDims`` gave
+    until it declared both a ``LogAppend``."""
+    from raft_tla_tpu.models.reconfig import ReconfigDims
+
+    class GeneralForm(ReconfigDims):
+        def build_extra_v2(self, fp):
+            (_e0, init_val), (_e1, fin_val) = self._build_guards()
+            top = self.max_log
+
+            def append_delta_succ(st, i, val):
+                ln = st.log_len[i]
+                k = jnp.clip(ln, 0, top - 1)
+                d_base = fp.dsum(
+                    fp.dpos(fp.O_LT + i * top + k, st.log_term[i, k],
+                            st.term[i]),
+                    fp.dpos(fp.O_LV + i * top + k, st.log_val[i, k], val),
+                    fp.dpos(fp.O_LL + i, ln, ln + 1))
+                _fits, succ = self._append_entry(st, i, val)
+                return d_base, fp.ZD, succ
+
+            return [lambda st, i, c: append_delta_succ(
+                        st, i, init_val(st, i, c)),
+                    lambda st, i: append_delta_succ(st, i, fin_val(st, i))]
+
+    return GeneralForm(**{f.name: getattr(dims, f.name)
+                          for f in dataclasses.fields(dims)})
+
+
+def _fold_states(dims):
+    """name -> encoded parent.  The nine canonical roots of the
+    benchmark's ``reconfig3`` cell (``E_i`` elected, ``J_i`` joint entry
+    committed, ``F_i`` finalized) by the recipe on the program's own
+    oracle, and the corners of the append: a log at ``MaxLogLen`` and a
+    full one (``log_len == max_log``: the lane is disabled and flagged),
+    parents past the row's packing, and a log whose truncated
+    configuration entry still lies beyond its length."""
+    from raft_tla_tpu.models.reconfig import final_value, joint_value
+    from tests.test_reconfig_deployment import recipe_on_the_programs_oracle
+
+    named = {}
+    for i in range(dims.n_servers):
+        for name, path in recipe_on_the_programs_oracle(dims, i).items():
+            named[name] = path[-1]
+    e0, j0, j1 = named["E_0"], named["J_0"], named["J_1"]
+    t0 = e0.current_term[0]
+
+    def with_log0(s, log):
+        return s.replace(log=(tuple(log),) + s.log[1:])
+
+    client = (t0, 1)
+    named["at_max_log_len"] = with_log0(e0, [client] * 2)
+    named["full_log_final"] = with_log0(e0, [client] * dims.max_log)
+    named["full_log_joint"] = with_log0(
+        j0, [j0.log[0][0]] + [client] * (dims.max_log - 1))
+    named["pack_edge_final"] = e0.replace(
+        current_term=(256,) + e0.current_term[1:])
+    named["pack_edge_joint"] = j1.replace(
+        current_term=j1.current_term[:1] + (256,) + j1.current_term[2:])
+    states = {k: jax.tree.map(jnp.asarray, encode_state(s, dims))
+              for k, s in named.items()}
+    # C_3 at index 1; the joint entry C_3,7 that followed it was
+    # truncated away and its value left in the tensor past the length.
+    st = jax.tree.map(np.array, encode_state(
+        with_log0(e0, [(t0, final_value(3))]), dims))
+    st.log_term[0, 1], st.log_val[0, 1] = t0, joint_value(3, 7)
+    states["truncated_cfg"] = jax.tree.map(jnp.asarray, st)
+    return states
+
+
+FOLD_STATES = [f"{kind}_{i}" for i in range(3) for kind in "EJF"] + [
+    "at_max_log_len", "full_log_final", "full_log_joint",
+    "pack_edge_final", "pack_edge_joint", "truncated_cfg"]
+
+
+@pytest.fixture(scope="module")
+def fold_rig():
+    """(dims, states, v1 over the grid, v2 with the declared appends, v2
+    with the same families as general lane_fns) on ``reconfig3.cfg``."""
+    setup = load_config("configs/reconfig3.cfg")
+    dims = setup.dims
+    _s, _d, _fp, v1_all, v2_all = _build_rig(setup)
+    general = _build_rig(dataclasses.replace(
+        setup, dims=_general_form_dims(dims)))[4]
+    states = _fold_states(dims)
+    assert sorted(states) == sorted(FOLD_STATES)
+    return dims, states, v1_all, v2_all, general
+
+
+@pytest.mark.parametrize("name", FOLD_STATES)
+@pytest.mark.parametrize("family", ["InitiateReconfig", "FinalizeReconfig"])
+def test_a_declared_append_is_the_v1_lane_and_the_oracles(fold_rig, family,
+                                                          name):
+    """On every lane of the family: masks as v1's; where enabled,
+    ``lane_out``'s successor field for field and its delta key equal to
+    the v1 kernel's successor and that successor's full fingerprint, and
+    the decoded successor the one ``extra_successors_py`` gives for the
+    instance."""
+    from raft_tla_tpu.models.schema import StateBatch, decode_state
+    dims, states, v1_all, v2_all, _general = fold_rig
+    st = states[name]
+    parent = decode_state(jax.tree.map(np.asarray, st), dims)
+    c1, en1, ovf1, h1, l1 = jax.device_get(v1_all(st))
+    c2, en2, ovf2, h2, l2, _phi, _plo = jax.device_get(v2_all(st))
+    fam = dims.family_names.index(family)
+    off, size = dims.family_offsets[fam], dims.family_sizes[fam]
+    want = {a: t for a, t in dims.extra_successors_py(parent)
+            if a[0] == fam}
+    for g in range(off, off + size):
+        where = f"{name} {dims.describe_instance(g)}"
+        assert (en1[g], ovf1[g]) == (en2[g], ovf2[g]), where
+        code, params = dims.instance_info(g)
+        key = (code, tuple(params.values()))
+        fits = len(parent.log[params["i"]]) < dims.max_log
+        assert bool(en2[g]) == (key in want and fits), where
+        assert bool(ovf2[g]) >= (key in want and not fits), where
+        if not en2[g]:
+            continue
+        assert (h1[g], l1[g]) == (h2[g], l2[g]), where
+        for field, a, b in zip(c1._fields, c1, c2):
+            assert (a[g] == b[g]).all(), f"{field} {where}"
+        succ = decode_state(StateBatch(*(a[g] for a in c2)), dims)
+        assert succ == want[key], where
+
+
+@pytest.mark.parametrize("name", FOLD_STATES)
+def test_the_general_lane_fn_form_gives_the_same_lanes(fold_rig, name):
+    """``build_extra_v2``'s two forms of the same two families: the same
+    masks, and on every enabled lane of the whole grid the same key and
+    successor.  The general form stays a supported path."""
+    dims, states, _v1, v2_all, general = fold_rig
+    got = jax.device_get(v2_all(states[name]))
+    ref = jax.device_get(general(states[name]))
+    for a, b in zip(got[1:3] + got[5:], ref[1:3] + ref[5:]):
+        assert (np.asarray(a) == np.asarray(b)).all()
+    extra = dims.family_offsets[10]
+    assert got[1][extra:].any() or name.startswith("full_log")
+    for g in np.nonzero(got[1])[0]:
+        where = f"{name} {dims.describe_instance(int(g))}"
+        assert (got[3][g], got[4][g]) == (ref[3][g], ref[4][g]), where
+        for field, a, b in zip(got[0]._fields, got[0], ref[0]):
+            assert (a[g] == b[g]).all(), f"{field} {where}"
+
+
+def test_a_declared_append_takes_a_server_and_at_most_one_more():
+    """The decode tables hold (i, one more) an instance: a declaration
+    over three parameter arrays is refused when the pipeline is built."""
+    from raft_tla_tpu.models.reconfig import ReconfigDims
+
+    class ThreeParams(ReconfigDims):
+        def build_extra_kernels(self):
+            (params, kern), fin = super().build_extra_kernels()
+            return [(params + params[:1], kern), fin]
+
+    d = load_config("configs/reconfig3.cfg").dims
+    with pytest.raises(ValueError, match="LogAppend"):
+        build_v2(ThreeParams(n_servers=d.n_servers, n_values=d.n_values,
+                             max_log=d.max_log, n_msg_slots=d.n_msg_slots,
+                             targets=d.targets))

@@ -175,8 +175,9 @@ def test_reconfig3_chunk_at_the_cells_sizes(name, invariants, one_chip,
     rows of 474 bytes, 2^25 keys, trace recording on) compiles for the
     described v5e, v2 with the variant's extra families; its arguments
     and temporaries, with the host loop's third queue beside them, fit
-    the chip's 16.9 GB.  The joint rule's scan reads a log value at a
-    traced index: it may not have become a loop over the lanes.  And
+    the chip's 16.9 GB.  No gather but ``flatten``'s may have become a
+    loop over the lanes, and what the two families cost ``lane_out``
+    under ``extra`` stays their value's few operations.  And
     the chunk of ``configs/reconfig3_safety.cfg`` as ``reconfig3-safety``
     runs it: the same pools under the ten invariants, whose results over
     the K lanes lie lanes-minor under ``ReconfigDims`` too.  About a
@@ -220,6 +221,20 @@ def test_reconfig3_chunk_at_the_cells_sizes(name, invariants, one_chip,
         for scope in ("quorum", "extra"):
             assert re.search(rf"/while/body/{site}/[^\"]*\({scope}\)+/",
                              text), (site, scope)
+    # PR 44: both families are ``LogAppend`` declarations, so under
+    # ``extra`` ``lane_out`` runs their value alone (one scan of log[i]
+    # by compare, select and sum).  By the compiler's own estimate that
+    # was 17.7 % of ``lane_out`` in 55 operations, 12 of them writing
+    # lanes-major for ``table[traced]`` reads; now a few operations,
+    # none of either kind.
+    import sys
+    sys.path.insert(0, os.path.join(REPO, "scripts"))
+    import hlo_parts
+    ops, cycles, rows = hlo_parts.tally(text, eng._K, "lane_out", "extra")
+    assert 0 < ops["scope", "all"] <= 16, rows
+    assert cycles["scope", "all"] < 0.02 * cycles["lane_out", "all"], rows
+    assert ops["scope", "lanes-major"] == 0, rows
+    assert not [r for r in rows if r[-1].endswith("/gather")], rows
 
 
 @pytest.mark.parametrize("cfg, width, instances", [

@@ -84,6 +84,59 @@ def test_value_ok_accepts_config_entries():
     assert not DIMS.value_ok_py(CFG_BASE)       # new_mask must be nonempty
 
 
+# The kernel's scan reads row i and the latest entry's position by compare,
+# select and sum, no ``table[traced]``: held to ``config_of_py`` on logs of
+# every shape, with what a truncation leaves past the length still in the
+# tensor.
+SCAN_LOGS = {
+    "empty": (), "client": ((2, 1),),
+    "joint": ((2, joint_value(7, 3)),),
+    "joint_then_client": ((2, joint_value(7, 3)), (2, 1)),
+    "final_after_joint": ((2, joint_value(7, 3)), (2, 1),
+                          (2, final_value(3))),
+    "joint_after_final": ((2, final_value(3)), (2, joint_value(3, 7))),
+    "full_of_config": ((1, final_value(5)), (2, joint_value(5, 6)),
+                       (2, final_value(6)), (3, joint_value(6, 7)),
+                       (3, final_value(7))),
+}
+
+
+@pytest.mark.parametrize("server", range(3))
+@pytest.mark.parametrize("name", sorted(SCAN_LOGS))
+def test_config_scan_is_config_of_on_every_prefix(name, server):
+    from raft_tla_tpu.models.reconfig import _build_config_scan
+    scan = jax.jit(_build_config_scan(DIMS))
+    log = SCAN_LOGS[name]
+    s = init_state(DIMS)
+    others = ((2, joint_value(1, 2)),)      # another server's: not read
+    st = encode_state(s.replace(log=tuple(
+        log if j == server else others for j in range(3))), DIMS)
+    for keep in range(len(log) + 1):
+        # entries keep.. stay in the tensor, as no engine state has them
+        # (a truncation zeroes its cell) and as the scan must not care.
+        lens = st.log_len.copy()
+        lens[server] = keep
+        cut = st._replace(log_len=lens)
+        got = tuple(int(x) for x in scan(cut, server))
+        assert got == config_of_py(log[:keep], 3), (name, keep)
+
+
+def test_both_families_declare_a_log_append_of_the_guards_value():
+    """``build_extra_v2``: two ``LogAppend`` declarations whose values are
+    the entries the oracle appends."""
+    from raft_tla_tpu.models.dims import LogAppend
+    initiate, finalize = DIMS.build_extra_v2(None)
+    assert isinstance(initiate, LogAppend) and isinstance(finalize, LogAppend)
+    st = encode_state(leader_state(log=((2, final_value(3)),)), DIMS)
+    assert int(initiate.value_fn(st, 0, 7)) == joint_value(3, 7)
+    st = encode_state(leader_state(log=((2, joint_value(7, 3)),), commit=1),
+                      DIMS)
+    assert int(finalize.value_fn(st, 0)) == final_value(3)
+    (after,) = [t for _a, t in DIMS.extra_successors_py(
+        leader_state(log=((2, final_value(3)),)))]
+    assert after.log[0][-1] == (2, joint_value(3, 7))
+
+
 # ---------------------------------------------------------------------------
 # action semantics (oracle side)
 

@@ -13,6 +13,7 @@ CPU, small sizes.  The reference is ``benchmark/reference`` (``reconfig.py``,
 import dataclasses
 import os
 import random
+import re
 import sys
 
 import jax
@@ -381,6 +382,37 @@ def test_the_chunk_names_the_scope_under_the_site(op_names, site, scope):
     stage = {"masks": "masks", "lane_out": "construct"}[site]
     assert all(stages.stage_of(n) == stage
                for n, o in zip(names, of) if o == (site, scope))
+
+
+def test_lane_out_runs_only_the_appended_value_under_extra(setup, op_names):
+    """The two families are ``LogAppend`` declarations (``models/dims.py``)
+    and ride ``ClientRequest``'s write, so what ``lane_out`` does under
+    ``extra`` is their value: one scan of ``log[i]`` by compare, select
+    and sum.  No read or write at a traced position (under ``vmap`` a
+    gather on every one of the K lanes: 6.8 ms of a 73.8 ms pass on the
+    chip until PR 44) and no select over the message table, which a
+    general ``lane_fn``'s successor costs once a family."""
+    variant = lib.load_module("readers", "variant")
+    text, _names = op_names
+    named = dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', text, re.M))
+    dims = setup.dims
+    table = f"x{dims.n_msg_slots}x{dims.msg_width}x"
+    traced = re.compile(
+        r"stablehlo\.(gather|scatter|dynamic_slice|dynamic_update_slice)\b")
+    by_scope = {}
+    for line in text.splitlines():
+        at = re.search(r"loc\((#loc\d+)\)$", line)
+        if at and " = " in line and at.group(1) in named:
+            of = variant.scope_of(named[at.group(1)] + "/x:")
+            by_scope.setdefault(of, []).append(line)
+    extra = by_scope[("lane_out", "extra")]
+    assert any("stablehlo.reduce" in line for line in extra)
+    assert not [line for line in extra if traced.search(line)]
+    assert not [line for line in extra if table in line]
+    # ... which the rest of ``lane_out`` has, found by the same words.
+    rest = by_scope[("lane_out", None)]
+    assert any(traced.search(line) for line in rest)
+    assert any(table in line for line in rest)
 
 
 def test_scope_of_reads_only_masks_and_lane_out():

@@ -56,6 +56,51 @@ def test_leader_bench_runs():
     assert rec["seeds"] > 0
 
 
+def _hlo_line(name, result, path, cycles):
+    return (f'  %{name} = {result} fusion(%p.1), kind=kLoop, '
+            f'metadata={{op_name="jit(chunk)/jit(chunk)/while/body/{path}" '
+            f'stack_frame_id=4}}, backend_config={{"window_config":'
+            f'{{"estimated_cycles":"{cycles}","is_mask":false}}}}')
+
+
+def test_hlo_parts_reads_a_scope_apart():
+    """``scripts/hlo_parts.py --scope extra``: beside the per-part table,
+    the operations of ``--part`` whose path names the scope after the
+    part, ``vmap(extra)`` unwrapped, as ``benchmark/readers/variant.py
+    scope_of`` reads a capture; lanes-major is any axis but the K lanes
+    minor-most.  On lines shaped as the TPU compiler writes them (the
+    compile itself is ``tests/test_chip_compile.py``'s, in the one
+    process that may load the compiler)."""
+    sys.path.insert(0, os.path.join(REPO, "scripts"))
+    import hlo_parts
+    lane = "construct/lane_out/vmap(lane_out)/"
+    text = "\n".join([
+        _hlo_line("fusion.1", "s32[1024,3]{0,1:T(4,128)}",
+                  lane + "vmap(extra)/reduce_sum", 700),
+        _hlo_line("fusion.2", "(pred[1024,1]{1,0:T(8,128)}, s32[1024]{0})",
+                  lane + "vmap(extra)/gather", 5000),
+        _hlo_line("fusion.3", "u32[1024]{0:T(1024)}",
+                  lane + "vmap(vmap(quorum))/gt", 30),
+        _hlo_line("fusion.4", "s32[1024,3,3]{2,1,0:T(4,128)}",
+                  lane + "select_n", 11),
+        _hlo_line("fusion.5", "pred[1024]{0}",
+                  "construct/invariants/vmap(TypeOK)/extra/and", 3),
+        _hlo_line("fusion.6", "pred[1024]{0}", "masks/vmap(extra)/and", 90),
+        "  %bare = s32[] add(%a, %b)"])
+    ops, cycles, rows = hlo_parts.tally(text, 1024, "lane_out", "extra")
+    assert (ops["lane_out", "all"], cycles["lane_out", "all"]) == (4, 5741)
+    assert (ops["lane_out", "lanes-major"],
+            cycles["lane_out", "lanes-major"]) == (2, 5011)
+    assert (ops["scope", "all"], cycles["scope", "all"]) == (2, 5700)
+    assert (ops["scope", "lanes-major"],
+            cycles["scope", "lanes-major"]) == (1, 5000)
+    assert (ops["invariants", "all"], ops["scope", "all"]) == (1, 2)
+    assert [r[1] for r in sorted(rows, reverse=True)] == ["fusion.2",
+                                                          "fusion.1"]
+    _ops, _cycles, rows = hlo_parts.tally(text, 1024, "lane_out")
+    assert len(rows) == 4
+
+
 def test_oracle_exhaust_level_capped(tmp_path):
     out = run_script(["scripts/oracle_exhaust.py",
                       "configs/MCraft_bounded.cfg",
