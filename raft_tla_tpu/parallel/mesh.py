@@ -99,6 +99,12 @@ MESH_STAGES_TAG = "m1"
 # Rows of one frontier upload step.
 UPLOAD_ROWS = 1 << 16
 
+# Keys a chip of one piece of a shard rebuild (``_shards_from_keys``), a
+# power of two of lanes (``fpset`` design note 2): from
+# ``fpset.from_host_keys``' 2^15, the small program for a shallow
+# snapshot, up to 2^18, few round trips for a deep one.
+KEY_PIECE_MIN, KEY_PIECE_MAX = 1 << 15, 1 << 18
+
 
 def _chip(shard) -> int:
     """The chip (row of the mesh) an addressable shard of a ``P("x")``
@@ -117,6 +123,32 @@ def _fetch_shard(cols, start, length):
     rebuilt programs find it compiled."""
     return tuple(jax.lax.dynamic_slice(x, (0, start), (1, length))[0]
                  for x in cols)
+
+
+class _KeyInserts:
+    """The pieces one ``_shards_from_keys`` call dispatched and nobody
+    has waited for yet: their count, when the first went out, and each
+    one's per-chip fail flag, still on the chips."""
+
+    def __init__(self, overflow: str):
+        self._overflow, self._fails = overflow, []
+        self.since = None       # time.time() at the first dispatch's return
+
+    @property
+    def pieces(self) -> int:
+        return len(self._fails)
+
+    def dispatched(self, fail) -> None:
+        self._fails.append(fail)
+        if self.since is None:
+            self.since = time.time()
+
+    def wait(self) -> None:
+        """Block until the last piece is in its shard; a shard that
+        could not take its keys raises."""
+        if any(bool(np.any(np.asarray(s.data)))
+               for f in self._fails for s in f.addressable_shards):
+            raise RuntimeError(self._overflow)
 
 
 def tag_mesh_stages(count):
@@ -588,69 +620,109 @@ class MeshBFSEngine:
                 out_shardings=NamedSharding(self.mesh, P("x")))
         return self._full_fns[key]()
 
-    def _grow_seen(self, shi, slo, ssize, new_cl=None):
+    def _grow_seen(self, shi, slo, most, new_cl=None):
         """Rebuild this controller's shards at double (or given) capacity.
         Owner assignment (fp_hi mod n) is capacity-independent, so keys
         stay on their chips; every controller rehashes only its
         addressable shards and the arrays are reassembled shard-by-shard
-        (multi-controller rule 3).  The chunk program recompiles for the
-        new shape — identically everywhere."""
+        (multi-controller rule 3).  ``most`` is the fullest shard's size
+        as every controller read it (the statistics' pmax).  The chunk
+        program recompiles for the new shape — identically everywhere."""
         keys = self.shard_keys(shi, slo)
         self._CL = fpset._capacity(new_cl or 2 * self._CL)
         self._rebuild_programs()
-        return self._shards_from_keys(
+        shi, slo, ssize, inserts = self._shards_from_keys(
             np.concatenate([hi for hi, _lo in keys.values()]),
-            np.concatenate([lo for _hi, lo in keys.values()]))
+            np.concatenate([lo for _hi, lo in keys.values()]), most)
+        inserts.wait()
+        return shi, slo, ssize
 
-    def _shards_from_keys(self, keys_hi, keys_lo):
-        """Rebuild the sharded FPSet arrays from a global flat key set
-        (owner = fp_hi mod n).  Each controller supplies only the keys
-        of its addressable shards; every shard is built ON the chip that
-        owns it, all of them side by side by one program over the mesh
-        (``insert_unique`` under ``shard_map``), a piece of keys a chip
-        at a time, and never comes to the host (a 2^25-slot shard is
-        268 MB; four of them built on chip 0, fetched and sent up again
-        were most of a deep resume)."""
+    def _shards_from_keys(self, keys_hi, keys_lo, most):
+        """Rebuild the sharded FPSet arrays from a flat key set (owner =
+        fp_hi mod n) and DO NOT WAIT for them: returns ``(shi, slo,
+        ssize, inserts)`` with the pieces dispatched, and the caller does
+        what else it has for the host before ``inserts.wait()``, which
+        blocks on the last piece and raises where a shard overflowed.
+
+        Each controller supplies only the keys of its addressable
+        shards; every shard is built ON the chip that owns it, all of
+        them side by side by one program over the mesh (``insert_unique``
+        under ``shard_map``), a piece of keys a chip at a time, and never
+        comes to the host (a 2^25-slot shard is 268 MB; four of them
+        built on chip 0, fetched and sent up again were most of a deep
+        resume).  The host buckets the keys by owner a SLAB at a time (a
+        little over one piece an owner) and dispatches a piece as soon as
+        every owner here has one: slab k+1 is bucketed while the chips
+        insert piece k, so they wait for one slab's host work, not for
+        the whole set's (1.0 s of idle chips a level-12 resume).  What a
+        slab holds past an owner's piece is carried over, in order, so
+        owner ``d``'s ``k``-th piece is keys ``[k*piece, (k+1)*piece)`` of
+        its own, however the slabs fell.
+
+        ``most`` is the fullest shard's key count over ALL the mesh's
+        shards, as every controller knows it (the resume counts the
+        checkpoint's owners, a growth reads the statistics' pmax): it
+        alone sets the piece's length and the number of dispatches, so
+        controllers that hold different keys still make the same calls.
+        An owner here with keys left after them was not counted in it:
+        an error."""
         n, cl = self.n_dev, self._CL
         keys_hi = np.asarray(keys_hi).astype(np.uint32, copy=False)
         keys_lo = np.asarray(keys_lo).astype(np.uint32, copy=False)
-        owner = keys_hi % np.uint32(n)
+        piece = min(max(fpset._capacity(most), KEY_PIECE_MIN), KEY_PIECE_MAX)
+        # A sixteenth over a piece an owner: hashed owners fall within a
+        # hundredth of even, so one slab feeds one dispatch.
+        slab = n * (piece + piece // 16)
         me = jax.process_index()
-        parts = {}
-        for d, dev in enumerate(self.mesh.devices.flat):
-            if dev.process_index == me:
-                sel = owner == d
-                parts[d] = (keys_hi[sel], keys_lo[sel])
-        most = max((len(h) for h, _l in parts.values()), default=0)
-        # Pieces of 2^15 keys (fpset.from_host_keys) up to 2^18: few
-        # round trips for a deep snapshot, the small program for a
-        # shallow one.
-        piece = min(max(fpset._capacity(most), 1 << 15), 1 << 18)
+        no_keys = np.zeros((0,), np.uint32)
+        held = {d: (no_keys, no_keys)
+                for d, dev in enumerate(self.mesh.devices.flat)
+                if dev.process_index == me}
+        read = 0
+
+        def bucket_slab():
+            nonlocal read
+            hi, lo = keys_hi[read:read + slab], keys_lo[read:read + slab]
+            read += len(hi)
+            owner = hi % np.uint32(n)
+            for d, (h, l) in held.items():
+                at = np.flatnonzero(owner == d)
+                held[d] = (np.concatenate([h, hi.take(at)]),
+                           np.concatenate([l, lo.take(at)]))
+
         sh = NamedSharding(self.mesh, P("x"))
-
-        def piece_of(col, base):
-            def one(idx):
-                part = parts[idx[0].start][col][base:base + piece]
-                return np.pad(part, (0, piece - len(part)))[None]
-            return jax.make_array_from_callback((n, piece), sh, one)
-
         shi, slo = (self._sharded_full((n, cl), _U32, SENTINEL)
                     for _ in range(2))
         ssize = self._sharded_full((n,), _I32)
-        fails = []
-        for base in range(0, most, piece):
+        inserts = _KeyInserts(
+            f"FPSet rebuild overflow: {most} keys into a shard of {cl}")
+        for _ in range(-(-most // piece)):
+            while read < len(keys_hi) and min(
+                    len(h) for h, _l in held.values()) < piece:
+                bucket_slab()
+            part = {d: (h[:piece], l[:piece]) for d, (h, l) in held.items()}
+            held = {d: (h[piece:], l[piece:]) for d, (h, l) in held.items()}
+
+            def column(col, part=part):
+                def one(idx):
+                    keys = part[idx[0].start or 0][col]
+                    return np.pad(keys, (0, piece - len(keys)))[None]
+                return jax.make_array_from_callback((n, piece), sh, one)
+
             valid = jax.make_array_from_callback(
-                (n, piece), sh,
-                lambda idx, base=base: (np.arange(base, base + piece)
-                                        < len(parts[idx[0].start][0]))[None])
+                (n, piece), sh, lambda idx, part=part: (
+                    np.arange(piece) < len(part[idx[0].start or 0][0]))[None])
             shi, slo, ssize, fail = self._insert_keys(
-                shi, slo, ssize, piece_of(0, base), piece_of(1, base), valid)
-            fails.append(fail)
-        if any(bool(np.any(np.asarray(s.data)))
-               for f in fails for s in f.addressable_shards):
+                shi, slo, ssize, column(0), column(1), valid)
+            inserts.dispatched(fail)
+        while read < len(keys_hi):
+            bucket_slab()
+        left = {d: len(h) for d, (h, _l) in held.items() if len(h)}
+        if left:
             raise RuntimeError(
-                f"FPSet rebuild overflow: {most} keys into a shard of {cl}")
-        return shi, slo, ssize
+                f"FPSet rebuild: keys left after the pieces that a fullest "
+                f"shard of {most} keys asks for, by chip: {left}")
+        return shi, slo, ssize, inserts
 
     def _rebuild_programs(self):
         """Re-trace chunk/ingest for a changed seen-shard shape."""
@@ -907,9 +979,14 @@ class MeshBFSEngine:
 
         if resume is not None:
             # Shards must hold the checkpointed keys at <= half load.
-            max_keys = int(np.bincount(
-                np.asarray(resume.seen_hi) % n, minlength=n).max())
-            while max_keys > self._CL // 2:
+            # The fullest owner's count is the same on every controller
+            # (each loaded the whole key set): the restore's pieces go
+            # by it.
+            owner = np.asarray(resume.seen_hi, np.uint32) % np.uint32(n)
+            most_keys = max(int(np.count_nonzero(owner == d))
+                            for d in range(n))
+            del owner
+            while most_keys > self._CL // 2:
                 self._CL *= 2
                 self._rebuild_programs()
 
@@ -1029,14 +1106,31 @@ class MeshBFSEngine:
         self._batch_ema = 0.0
 
         if resume is not None:
-            # Rebuild shards from the flat key set: owner = fp_hi mod n.
-            # Each controller materializes only its addressable shards, so
-            # a checkpoint written by M controllers (piece group, merged
-            # by checkpoint.load) resumes on any process count.
-            with mt.phase_timer("restore_keys"):
-                shi, slo, ssize = self._shards_from_keys(
-                    resume.seen_hi, resume.seen_lo)
-                ssize.block_until_ready()
+            if cfg.record_trace:
+                if resume.distinct > 0 and resume.trace_fps.size == 0:
+                    raise ValueError(
+                        "checkpoint was written with trace recording "
+                        "disabled; resume with record_trace=False or "
+                        "restart from scratch")
+            elif resume.trace_fps.size > 0 and cfg.checkpoint_dir is not None:
+                raise ValueError(
+                    "resuming a trace-carrying checkpoint with trace "
+                    "recording disabled would write trace-less snapshots "
+                    "into the same directory, shadowing the intact ones "
+                    "for any later trace-on resume; use a different "
+                    "checkpoint_dir or keep tracing enabled")
+            # The resume never holds the chips for host work they can
+            # be given something to do under.  The frontier's first
+            # segment goes up FIRST, into ``qcur``, which no insert
+            # touches, and is not waited for: dispatched behind inserts
+            # in flight, its ``_write_rows`` steps are held back by the
+            # runtime one by one until the inserts have drained (a chip
+            # measured 3.0 s in that upload against 0.05 s here).  Then
+            # the key inserts are dispatched, each piece bucketed while
+            # the chips insert the one before; the trace store's refill
+            # (the host's alone) runs while they are in flight, and the
+            # one wait comes last.  Every key is in its shard before the
+            # first chunk call, and an overflow raises there.
             fr = np.ascontiguousarray(resume.frontier).astype(
                 ROW_DTYPE, casting="safe", copy=False)
             level_rows = len(fr)
@@ -1058,7 +1152,13 @@ class MeshBFSEngine:
             # the level loop uploads the others as it reaches them.
             with mt.phase_timer("restore_frontier"):
                 qcur, cur_counts_dev = self._upload_segment(pending, qcur)
-                cur_counts_dev.block_until_ready()
+            # Rebuild shards from the flat key set: owner = fp_hi mod n.
+            # Each controller materializes only its addressable shards, so
+            # a checkpoint written by M controllers (piece group, merged
+            # by checkpoint.load) resumes on any process count.
+            with mt.phase_timer("restore_keys"):
+                shi, slo, ssize, inserts = self._shards_from_keys(
+                    resume.seen_hi, resume.seen_lo, most_keys)
             res.distinct = resume.distinct
             res.generated = resume.generated
             res.diameter = resume.diameter
@@ -1070,22 +1170,24 @@ class MeshBFSEngine:
             coverage.seed_generated(resume.action_counts)
             t0 -= resume.wall_seconds
             if cfg.record_trace:
-                if resume.distinct > 0 and resume.trace_fps.size == 0:
-                    raise ValueError(
-                        "checkpoint was written with trace recording "
-                        "disabled; resume with record_trace=False or "
-                        "restart from scratch")
                 with mt.phase_timer("restore_trace"):
                     trace.add_batch(resume.trace_fps, resume.trace_parents,
                                     resume.trace_actions)
                     trace.roots.update(resume.roots)
-            elif resume.trace_fps.size > 0 and cfg.checkpoint_dir is not None:
-                raise ValueError(
-                    "resuming a trace-carrying checkpoint with trace "
-                    "recording disabled would write trace-less snapshots "
-                    "into the same directory, shadowing the intact ones "
-                    "for any later trace-on resume; use a different "
-                    "checkpoint_dir or keep tracing enabled")
+            # ``restore_keys`` a second time (a phase entered twice
+            # accumulates): what of the inserts the host's work did not
+            # cover.
+            t_wait = time.time()
+            with mt.phase_timer("restore_keys"):
+                inserts.wait()
+                cur_counts_dev.block_until_ready()
+            # How much of the resume the chips paced (run_end): a wait
+            # near nothing says the host still is the pace.
+            self._mesh_counts.update(
+                restore_pieces=inserts.pieces,
+                restore_host_s=round(
+                    t_wait - (inserts.since or t_wait), 3),
+                restore_wait_s=round(time.time() - t_wait, 3))
         else:
             # Ingest roots round-robin across chips in B-sized waves.
             per_chip = [rows_np[i::n] for i in range(n)]
@@ -1530,7 +1632,7 @@ class MeshBFSEngine:
             at = min(base, QLA - step)
 
             def rows_of(idx, at=at):
-                part = parts[idx[0].start][at:at + step]
+                part = parts[idx[0].start or 0][at:at + step]
                 if len(part) == step:
                     return part[None]
                 buf = np.zeros((1, step, sw), ROW_DTYPE)
@@ -1592,7 +1694,7 @@ class MeshBFSEngine:
             # wrapper (halve batch + resume); the per-shard rebuild has
             # no safe mid-way retry point, unlike the single-chip table.
             _faults.fire("oom", grow=self._grow_attempts)
-        return self._grow_seen(shi, slo, ssize)
+        return self._grow_seen(shi, slo, max_ssize)
 
     def _grow_precompiled(self, shi, slo, ssize, qcur, qnext, next_counts,
                           tbuf, tcount, t0, max_ssize):

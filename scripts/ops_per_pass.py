@@ -13,7 +13,8 @@ number to compare between two commits that must run the same program.
 
 Beside the counts it prints what the window's own ``run_end`` event
 counted (chunk calls, passes, trace flushes overlapped and drained,
-the mesh's insert windows by chip, compiles by span), the pools' fill,
+the mesh's insert windows by chip and what its resume overlapped,
+compiles by span), the pools' fill,
 the window's phases and the per-layer metrics as ``run.py --trace 1``
 reduces them.
 
@@ -156,7 +157,8 @@ def main() -> int:
                     if e["event"] == "run_end"), {})
         out["run_end"] = {k: end[k] for k in (
             "chunk_calls", "passes", "ingest_calls", "flush_overlapped",
-            "flush_drained", "chip_insert_windows", "compiles") if k in end}
+            "flush_drained", "chip_insert_windows", "restore_pieces",
+            "restore_host_s", "restore_wait_s", "compiles") if k in end}
         out["fill"] = run.get("counters")
         out["phases"] = {k: round(v, 4) for k, v in run["phases"].items()}
         import jax

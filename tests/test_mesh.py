@@ -259,6 +259,18 @@ def test_mesh_progress_lines_emitted(capfd):
 
 # -- one chunk call against the oracle, over four chips ---------------------
 
+def rebuilt_shards(eng, keys_hi, keys_lo, most=None):
+    """``_shards_from_keys`` of a whole key set, waited for: (shi, slo,
+    ssize).  ``most`` as the resume computes it where none is given."""
+    import numpy as np
+    if most is None:
+        most = int(np.bincount(keys_hi % eng.n_dev,
+                               minlength=eng.n_dev).max())
+    shi, slo, ssize, inserts = eng._shards_from_keys(keys_hi, keys_lo, most)
+    inserts.wait()
+    return shi, slo, ssize
+
+
 @pytest.fixture(scope="module")
 def one_mesh_chunk_call():
     """tests/test_engine.py's comparison for ``MeshBFSEngine``: the
@@ -293,7 +305,7 @@ def one_mesh_chunk_call():
     out = eng._chunk(
         put(qcur), put(counts), jnp.int32(0),
         put(np.zeros(qav.shape, np.uint8)), put(zeros),
-        *eng._shards_from_keys(*want.seen_keys(eng)),
+        *rebuilt_shards(eng, *want.seen_keys(eng)),
         tuple(put(np.zeros(a.shape, a.dtype)) for a in tbuf_av),
         put(zeros), jnp.int32(eng._CH))
     qnext, ncnt, _hi, _lo, _size, tbuf, tcnt = (
@@ -504,6 +516,8 @@ def test_run_end_carries_the_insert_windows_of_every_chip(short_runs):
     # Every pass generated a candidate, and some chip owns it.
     assert sum(windows) >= end["passes"] > 0
     assert "chip_insert_windows" not in short_runs[BFSEngine][-1]
+    # A run from the root restored nothing, and says nothing of it.
+    assert "restore_pieces" not in end
 
 
 def test_the_mesh_run_start_carries_the_process_record(short_runs):
@@ -523,3 +537,114 @@ def test_the_mesh_run_start_carries_the_process_record(short_runs):
     # The backend stage of ``jit`` is what ``compiles`` has always counted.
     assert (end["jit"].get("load", [0])[0] + end["jit"].get("compile", [0])[0]
             == sum(n for n, _s in end["compiles"].values()))
+
+
+# -- the shard rebuild, a slab at a time (PR 45) ---------------------------
+
+def key_set(kind: str, n: int, piece: int):
+    """(keys_hi, keys_lo) for ``n`` chips where a piece holds ``piece``
+    keys a chip: ``several_slabs`` is five pieces a chip of hashed keys,
+    ``skewed`` the same with owner 0's keys all in front (its first slab
+    holds a slab of them and none of any other owner: the carry-over, and
+    the others' pieces filled from later slabs), ``small`` under one
+    piece, ``empty`` none."""
+    import numpy as np
+    count = {"several_slabs": 5 * piece * n - 37, "skewed": 5 * piece * n,
+             "small": n * piece // 3, "empty": 0}[kind]
+    rng = np.random.default_rng(45 + n)
+    hi = rng.integers(0, 1 << 32, count, dtype=np.uint32)
+    lo = rng.integers(0, 1 << 32, count, dtype=np.uint32)
+    if kind == "skewed":
+        order = np.argsort(hi % n != 0, kind="stable")
+        hi, lo = hi[order], lo[order]
+    return hi, lo
+
+
+@pytest.fixture(scope="module")
+def rebuild_engines():
+    """{n: a mesh engine on n chips}, shards of 2^14 slots: only their
+    rebuild programs are ever compiled."""
+    return {n: MeshBFSEngine(
+        DIMS, config=EngineConfig(batch=32, queue_capacity=1 << 12,
+                                  seen_capacity=n << 14),
+        devices=jax.devices()[:n]) for n in (1, 2, 4)}
+
+
+@pytest.mark.parametrize("kind", ["several_slabs", "skewed", "small",
+                                  "empty"])
+@pytest.mark.parametrize("n", [1, 2, 4])
+def test_shards_from_keys_puts_every_key_on_its_owner_once(
+        rebuild_engines, monkeypatch, n, kind):
+    """Bucketed a slab at a time, pieces of 256 to 1,024 keys a chip:
+    chip ``d`` holds exactly the keys with ``fp_hi mod n == d``, ``ssize``
+    counts them, and the dispatches are what the fullest owner asks for
+    (the number every controller computes alike), however the slabs
+    fell."""
+    import numpy as np
+    from raft_tla_tpu.parallel import mesh as mesh_mod
+    monkeypatch.setattr(mesh_mod, "KEY_PIECE_MIN", 256)
+    monkeypatch.setattr(mesh_mod, "KEY_PIECE_MAX", 1024)
+    eng = rebuild_engines[n]
+    hi, lo = key_set(kind, n, 1024)
+    counts = np.bincount(hi % n, minlength=n)
+    most = int(counts.max())
+    calls = []
+    insert = eng._insert_keys
+    monkeypatch.setattr(eng, "_insert_keys",
+                        lambda *a: calls.append(a[3].shape) or insert(*a))
+    shi, slo, ssize, inserts = eng._shards_from_keys(hi, lo, most)
+    inserts.wait()
+    piece = {"small": 512, "empty": 256}.get(kind, 1024)
+    assert calls == [(n, piece)] * -(-most // piece)
+    assert inserts.pieces == len(calls)
+    assert (inserts.since is None) == (kind == "empty")
+    assert np.asarray(ssize).tolist() == counts.tolist()
+    got = eng.shard_keys(shi, slo)
+    assert sorted(got) == list(range(n))
+    for d, (h, l) in got.items():
+        mine = hi % n == d
+        assert sorted(zip(h.tolist(), l.tolist())) \
+            == sorted(zip(hi[mine].tolist(), lo[mine].tolist()))
+
+
+def test_shards_from_keys_raises_where_a_shard_overflows(rebuild_engines,
+                                                         monkeypatch):
+    """More keys for chip 1 than its shard has slots: the pieces are
+    dispatched, and the wait raises as the rebuild always has.  Keys past
+    the pieces that ``most`` asks for are an error too, not a silent
+    loss."""
+    import numpy as np
+    eng = rebuild_engines[2]
+    monkeypatch.setattr(eng, "_CL", 1 << 9)
+    rng = np.random.default_rng(4545)
+    hi = rng.integers(0, 1 << 31, 700, dtype=np.uint32) * 2 + 1
+    lo = rng.integers(0, 1 << 32, 700, dtype=np.uint32)
+    _shi, _slo, _ssize, inserts = eng._shards_from_keys(hi, lo, 700)
+    assert inserts.pieces == 1
+    with pytest.raises(RuntimeError, match="FPSet rebuild overflow: 700 "
+                                           "keys into a shard of 512"):
+        inserts.wait()
+    monkeypatch.setattr(eng, "_CL", 1 << 14)
+    with pytest.raises(RuntimeError, match="keys left after the pieces"):
+        eng._shards_from_keys(np.tile(hi, 60), np.tile(lo, 60), 700)
+
+
+def test_grow_seen_keeps_every_shards_keys():
+    """``_grow_seen``, the rebuild's other caller: the same key set a
+    shard at twice the slots, ``ssize`` equal, waited for."""
+    import numpy as np
+    eng = MeshBFSEngine(
+        DIMS, config=EngineConfig(batch=32, queue_capacity=1 << 12,
+                                  seen_capacity=4 << 10),
+        devices=jax.devices()[:4])
+    hi, lo = key_set("small", 4, 1024)
+    shi, slo, ssize = rebuilt_shards(eng, hi, lo)
+    before, sizes = eng.shard_keys(shi, slo), np.asarray(ssize).tolist()
+    slots = eng._CL
+    shi, slo, ssize = eng._grow_seen(shi, slo, max(sizes))
+    assert eng._CL == 2 * slots and shi.shape == (4, 2 * slots)
+    assert np.asarray(ssize).tolist() == sizes
+    after = eng.shard_keys(shi, slo)
+    for d in range(4):
+        assert sorted(zip(*map(np.ndarray.tolist, after[d]))) \
+            == sorted(zip(*map(np.ndarray.tolist, before[d])))

@@ -173,6 +173,54 @@ def test_snapshot_crosses_engines(snapshots, mesh, single, writer, reader):
                 "restore_trace"} <= set(res.phases)
 
 
+def test_a_resumed_run_reports_what_its_restore_overlapped(
+        snapshots, mesh, tmp_path):
+    """The mesh resume sends the frontier up, dispatches the key inserts,
+    refills the trace store under them, and waits last: the three
+    ``restore_*`` phases stay disjoint on the host clock (their sum lies
+    within the run's wall), ``run_end`` says how many pieces went out and
+    how the host's seconds under them stand to the final wait, and the
+    run's counts are the ones ``test_snapshot_crosses_engines`` pins."""
+    events = str(tmp_path / "resume.jsonl")
+    ck = dataclasses.replace(snapshots["single"], wall_seconds=0.0)
+    res = run(mesh, None, ck, max_diameter=7, events_out=events)
+    assert (res.diameter, res.distinct) == (7, LEVEL7)
+    assert res.levels[-1] == reference_levels()[7][0]
+    restore = [res.phases[k] for k in ("restore_keys", "restore_frontier",
+                                       "restore_trace")]
+    assert all(s > 0 for s in restore)
+    assert sum(restore) < res.wall_seconds
+    with open(events, encoding="utf-8") as f:
+        end = [json.loads(line) for line in f][-1]
+    assert end["event"] == "run_end"
+    # One piece of 2^15 keys a chip holds level 5's keys.
+    assert end["restore_pieces"] == 1
+    assert end["restore_host_s"] >= 0 and end["restore_wait_s"] >= 0
+    assert end["restore_host_s"] + end["restore_wait_s"] <= sum(restore)
+
+
+@pytest.mark.parametrize("record_trace", [True, False])
+def test_a_resume_that_cannot_keep_its_trace_raises_before_any_insert(
+        snapshots, mesh, monkeypatch, tmp_path, record_trace):
+    """A trace-less checkpoint resumed with the trace on (and a traced
+    one resumed without it into a checkpoint directory) is refused
+    before a piece of keys is dispatched."""
+    ck = snapshots["mesh"]
+    if record_trace:
+        ck = dataclasses.replace(
+            ck, trace_fps=np.empty(0, np.uint64),
+            trace_parents=np.empty(0, np.uint64),
+            trace_actions=np.empty(0, np.int32), roots={})
+    calls = []
+    insert = mesh._insert_keys
+    monkeypatch.setattr(mesh, "_insert_keys",
+                        lambda *a: calls.append(1) or insert(*a))
+    with pytest.raises(ValueError, match="trace recording disabled"):
+        run(mesh, None, ck, max_diameter=6, record_trace=record_trace,
+            checkpoint_dir=None if record_trace else str(tmp_path))
+    assert not calls
+
+
 def test_the_shares_add_up(setup, snapshots, mesh, single, tmp_path):
     """One level from 256 seeded roots: the four shards are disjoint,
     every key lies on chip ``fp_hi mod 4``, and their union is the
