@@ -72,11 +72,6 @@ def _swarm_bench(setup, platform: str) -> None:
     if events_file is None:
         scratch_dir = tempfile.mkdtemp(prefix="bench_obs_")
         events_file = os.path.join(scratch_dir, "events.jsonl")
-    # Same perf/profiler knobs as the exhaustive bench: BENCH_PERF=0
-    # disables launch accounting, BENCH_PROFILE_CHUNKS sets the
-    # walk-kernel stage-sampling cadence (0 = off).
-    perf_on = bool(int(os.environ.get("BENCH_PERF", "1")))
-    profile_every = int(os.environ.get("BENCH_PROFILE_CHUNKS", "64"))
     # Walks, depth, slice width and pipeline as ``check --mode swarm``
     # resolves them (engine/check.py make_swarm_engine); a knob left
     # unset is the cfg's or the engine's own default.
@@ -88,8 +83,7 @@ def _swarm_bench(setup, platform: str) -> None:
         max_depth=env_int("BENCH_MAX_DEPTH"),
         batch=env_int("BENCH_BATCH"),
         pipeline=os.environ.get("BENCH_PIPELINE"),
-        events_out=events_file, perf=perf_on,
-        profile_chunks_every=profile_every, **shape)
+        events_out=events_file, **shape)
     walks, max_depth, ring = eng.walks, eng.max_depth, eng.ring
     _mark(f"swarm engine built (walks={walks}, depth={max_depth}, "
           f"ring={ring}); compiling + running "
@@ -148,9 +142,6 @@ def _swarm_bench(setup, platform: str) -> None:
         "phases": {k: round(v, 4) for k, v in res.phases.items()},
         "pipeline": res.pipeline,
         "report": res.report,
-        "perf": res.perf,
-        "chunk_stages": {k: round(v, 6)
-                         for k, v in res.chunk_stages.items()},
     }
     if res.report.get("hunt"):
         from raft_tla_tpu.obs import hunt as hunt_mod
@@ -215,12 +206,6 @@ def main():
     if events_file is None:
         scratch_dir = tempfile.mkdtemp(prefix="bench_obs_")
         events_file = os.path.join(scratch_dir, "events.jsonl")
-    # Per-stage chunk profiling (obs/profile.py): sampled sparsely enough
-    # (default every 64th chunk call) that the headline states/s stays a
-    # throughput number while every bench JSON still carries the stage
-    # decomposition bench_diff.py gates on.  BENCH_PROFILE_CHUNKS=0
-    # disables; engine results are bit-identical either way.
-    profile_every = int(os.environ.get("BENCH_PROFILE_CHUNKS", "64"))
     # Partial-order reduction (analysis/por.py): BENCH_POR=1 certifies
     # in-process at engine build, BENCH_POR_TABLE applies a pre-built
     # artifact.  The reduction (if any certificate proves) shows up in
@@ -235,13 +220,6 @@ def main():
     # BENCH_XLA_PROFILE_DIR (default artifacts/xla_profile).
     # Observational — the headline number is unaffected.
     xla_profile = int(os.environ.get("BENCH_XLA_PROFILE", "0"))
-    # Performance observatory (obs/perf.py; BENCH_PERF=0 disables):
-    # launch accounting + static roofline + fusion-advisor verdict,
-    # embedded as the bench JSON's "perf" block — what bench_diff.py
-    # gates with --launch-drift and bench_history.py renders with
-    # --perf.  Observational: the headline number is unaffected (the
-    # one-time jaxpr walk happens at engine build, before the clock).
-    perf_on = bool(int(os.environ.get("BENCH_PERF", "1")))
     cfg = EngineConfig(
         batch=int(os.environ.get("BENCH_BATCH",
                                  str(2048 if on_accel else 512))),
@@ -252,16 +230,12 @@ def main():
         max_seconds=BENCH_SECONDS,   # host-side; C++ store tracked separately)
         events_out=events_file,
         trace_out=os.environ.get("BENCH_TRACE_OUT"),
-        # 0 passes through as explicitly-off so BENCH_PERF=1 cannot
-        # re-enable a profiler BENCH_PROFILE_CHUNKS=0 turned off.
-        profile_chunks_every=profile_every,
         xla_profile_chunks=xla_profile or None,
         xla_profile_dir=os.environ.get("BENCH_XLA_PROFILE_DIR",
                                        "artifacts/xla_profile"),
         pipeline=os.environ.get("BENCH_PIPELINE", "auto"),
         por=bool(int(os.environ.get("BENCH_POR", "0"))),
-        por_table=os.environ.get("BENCH_POR_TABLE"),
-        perf=perf_on)
+        por_table=os.environ.get("BENCH_POR_TABLE"))
     # "auto": on a multi-accelerator slice (e.g. v5e-8) the run shards
     # over all devices — the mesh engine is the product's scaling path
     # and the north-star target is defined on the full slice.
@@ -392,13 +366,9 @@ def main():
         # accounting BENCH_r06+ carries so hot-path work can be targeted
         # at the phase that actually dominates.
         "phases": {k: round(v, 4) for k, v in res.phases.items()},
-        # Per-stage chunk decomposition (obs/profile.py; mean seconds per
-        # sampled batch + the fused "total" reference) and the TLC-style
-        # coverage object — the two new axes scripts/bench_diff.py gates
-        # BENCH_r* trajectories on.
-        "chunk_stages": {k: round(v, 6)
-                         for k, v in res.chunk_stages.items()},
         "pipeline": res.pipeline,
+        # The TLC-style coverage object — an axis scripts/bench_diff.py
+        # gates BENCH_r* trajectories on.
         "coverage": res.coverage,
         # Certified ample instances the run's POR table carried (0 = POR
         # off or an all-conservative certificate).
@@ -407,12 +377,6 @@ def main():
         # probability, per-level table, out-degree, seen-set load —
         # the semantic half of the trajectory the run ledger records.
         "report": res.report,
-        # Performance observatory (obs/perf.py): launch accounting,
-        # roofline rows with achieved-bandwidth fractions, and the
-        # fusion advisor's verdict — bench_diff.py gates
-        # launches_per_chunk (--launch-drift) and bandwidth drift on
-        # this block; {} when BENCH_PERF=0.
-        "perf": res.perf,
         "baseline_states_per_sec": round(base_rate, 1),
         "baseline_distinct": ores.distinct_states,
         "baseline_wall_s": round(base_wall, 2),

@@ -133,7 +133,6 @@ def phase_device(chips: int) -> dict:
 
     import jax
 
-    from raft_tla_tpu.obs import roofline
     from raft_tla_tpu.utils.platform import (cache_dir,
                                              enable_persistent_cache)
     devs = jax.devices()
@@ -146,14 +145,10 @@ def phase_device(chips: int) -> dict:
         f"asked for {chips} chip(s), jax.devices() has {len(devs)}")
     enable_persistent_cache()       # the program's rule, not this script's
     limit = d0.memory_stats()["bytes_limit"]
-    # The roofline table must know this chip: a device_kind it does not
-    # list is an error there, and this is the string it has to match.
-    bw = roofline.peak_bandwidth_for_kind(d0.device_kind)
     say(f"[1 device] platform={d0.platform} device_kind={d0.device_kind!r} "
         f"count={len(devs)} bytes_limit={limit}")
     say(f"[1 device] jax={jax.__version__} "
-        f"jaxlib={md.version('jaxlib')} libtpu={md.version('libtpu')} "
-        f"peak_bw_table={bw / 1e9:.0f}GB/s")
+        f"jaxlib={md.version('jaxlib')} libtpu={md.version('libtpu')}")
     say(f"[1 device] compile cache: {cache_dir()} "
         f"(JAX_COMPILATION_CACHE_DIR "
         f"{'set' if os.environ.get('JAX_COMPILATION_CACHE_DIR') else 'unset'}"
@@ -190,7 +185,7 @@ def phase_exhaustive(depth: int, device: dict, tmp: str,
                      sizes: Sizes = REAL) -> None:
     ev = os.path.join(tmp, "exhaustive.jsonl")
     t0 = time.perf_counter()
-    rc, out = run_cli(check_argv(sizes, depth, ev, "--perf"))
+    rc, out = run_cli(check_argv(sizes, depth, ev))
     wall = time.perf_counter() - t0
     assert rc == 0, f"check exited {rc}:\n{out[-2000:]}"
     events = read_events(ev)
@@ -198,10 +193,6 @@ def phase_exhaustive(depth: int, device: dict, tmp: str,
     end = assert_clean_run(events, sizes.batch, "exhaustive")
     assert "pipeline           v2" in out, (
         f"pipeline=auto did not resolve to v2:\n{out[-1500:]}")
-    perf = [e for e in events if e["event"] == "perf"]
-    assert len(perf) == 1 and perf[0]["perf"], "no perf block (--perf)"
-    assert perf[0]["perf"]["pipeline"] == "v2", perf[0]["perf"]["pipeline"]
-    assert perf[0]["perf"]["launch"]["launches_per_batch"] > 0
     mem = end["memory"]
     if device["platform"] == "tpu":     # the CPU backend reports nothing
         # The memory block is the device's own report, not an assumed
@@ -224,10 +215,6 @@ def phase_exhaustive(depth: int, device: dict, tmp: str,
     phases = sorted(end["phase_seconds"].items(), key=lambda kv: -kv[1])
     say("[2 exhaustive] host phases (s, smoke timings): "
         + ", ".join(f"{k} {v:.1f}" for k, v in phases if v >= 0.05))
-    say(f"[2 exhaustive] perf: "
-        f"{perf[0]['perf']['launch']['launches_per_batch']} device "
-        f"ops/batch (pre-fusion), "
-        f"{perf[0]['perf']['launch']['batches']} batches")
 
 
 # -- phase 3 ---------------------------------------------------------------

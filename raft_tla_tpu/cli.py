@@ -305,12 +305,6 @@ def _run_swarm(args, setup, resolve) -> int:
             or ("." if args.render_trace and not ckpt else None)),
         progress_seconds=float(
             resolve(args.progress_interval, "PROGRESS_SECONDS", 5.0)),
-        # The BFS branch's observability knobs, swarm dialect: --perf
-        # prices the scan-chunk launches, --profile-chunks samples the
-        # walk-kernel stages, --xla-profile captures device truth.
-        perf=bool(resolve(args.perf or None, "PERF", False)),
-        profile_chunks_every=resolve(args.profile_chunks,
-                                     "PROFILE_CHUNKS", None),
         xla_profile_chunks=resolve(args.xla_profile, "XLA_PROFILE",
                                    None),
         xla_profile_dir=args.xla_profile_dir)
@@ -778,27 +772,6 @@ def main(argv=None):
                         "(`analyze --passes por --por-artifact FILE`); "
                         "fingerprint/model/predicate-coverage checked "
                         "before any mask is applied")
-    c.add_argument("--profile-chunks", nargs="?", const=1, type=int,
-                   default=None, metavar="N",
-                   help="sample every Nth chunk call (default 1 = every "
-                        "call) through per-stage programs with device "
-                        "fencing: expand / fingerprint / dedup-insert / "
-                        "enqueue histograms land in --metrics-out, a "
-                        "chunk_profile event in --events-out, and a "
-                        "stage table on stderr at run end.  "
-                        "Observational: engine results are bit-identical "
-                        "with profiling on or off")
-    c.add_argument("--perf", action="store_true",
-                   help="performance observatory (obs/perf.py): launch "
-                        "accounting over the real traced chunk program, "
-                        "static roofline with achieved-bandwidth "
-                        "fractions per chunk stage, and the fusion "
-                        "advisor naming the next fusion target — a "
-                        "'perf' event in --events-out, perf/* gauges, "
-                        "and a run-end table.  Implies --profile-chunks "
-                        "16 when no cadence is set.  Observational: "
-                        "engine results are bit-identical with perf on "
-                        "or off.  PERF directive is the cfg fallback")
     c.add_argument("--metrics-port", type=int, default=None,
                    metavar="PORT",
                    help="serve live telemetry over HTTP on 127.0.0.1:"
@@ -1012,7 +985,7 @@ def main(argv=None):
     w = sub.add_parser(
         "watch",
         help="attach a live console to a running check (run attach): "
-             "stream progress/coverage/fused-stage snapshots from a "
+             "stream progress/coverage snapshots from a "
              "checker service's watch op, or poll a --metrics-port "
              "listener's /flight endpoint; --job scopes the stream to "
              "one async job")
@@ -1285,15 +1258,12 @@ def main(argv=None):
             trace_dir=resolve(args.trace_dir, "TRACE_DIR", None),
             events_out=resolve(args.events_out, "EVENTS_OUT", None),
             trace_out=resolve(args.trace_out, "TRACE_OUT", None),
-            profile_chunks_every=resolve(args.profile_chunks,
-                                         "PROFILE_CHUNKS", None),
             xla_profile_chunks=resolve(args.xla_profile,
                                        "XLA_PROFILE", None),
             xla_profile_dir=args.xla_profile_dir,
             pipeline=resolve(args.pipeline, "PIPELINE", "auto"),
             por=bool(resolve(args.por or None, "POR", False)),
             por_table=resolve(args.por_table, "POR_TABLE", None),
-            perf=bool(resolve(args.perf or None, "PERF", False)),
             degrade_on_oom=not args.no_degrade,
             statespace_report=(False if args.no_report
                                else bool(resolve(None, "REPORT", True))),

@@ -295,40 +295,12 @@ class EngineConfig:
     # file at run end — opens directly in Perfetto/chrome://tracing.
     # None disables (zero overhead: the tracer no-ops).
     trace_out: Optional[str] = None
-    # Per-stage chunk profiling (obs/profile.py): sample every Nth chunk
-    # call through separately-fenced expand/fingerprint/dedup-insert/
-    # enqueue stage programs, accumulating chunk_stage/* histograms and
-    # a run-end chunk_profile event + stage table.  Observational
-    # (the real fused chunk still does all the work — results are
-    # bit-identical profiling on or off); None = unset (a --perf run
-    # then samples every 16th call), 0 = explicitly disabled (perf will
-    # not re-enable it).  Single-chip engine only; the mesh ignores it
-    # (its per-chip stages interleave collectives that a staged
-    # decomposition cannot fence honestly).
-    profile_chunks_every: Optional[int] = None
-    # -- performance observatory (obs/perf.py, obs/roofline.py) --------
-    # ``perf=True`` builds the launch-accounting + static-roofline
-    # layer: the engine's REAL chunk program is traced once at build
-    # for the static launch model (device ops per batch, a pre-fusion
-    # upper bound — CI pins it per pipeline so a stage un-fusing can
-    # never land silently), the shared stage programs are traced for
-    # per-stage HBM-traffic floors, and the host loop feeds (batches,
-    # seconds) per chunk call.  At run end the ``perf`` event /
-    # ``EngineResult.perf`` / ``perf/*`` gauges carry launches-per-
-    # chunk, the launch tax priced against measured chunk time,
-    # achieved-bandwidth fractions per stage, and the fusion advisor's
-    # top candidate.  Observational: engine counts are bit-identical
-    # with perf on or off (tested).  Implies chunk profiling (the
-    # roofline's measured half): when profile_chunks_every is unset, a
-    # --perf run samples every 16th chunk call.
-    perf: bool = False
     # Mesh skew telemetry (parallel/mesh.py): emit a ``skew`` warning
     # event when the per-shard frontier imbalance (max/mean of this
     # controller's shard next-level counts) reaches this ratio at a
     # level boundary.  The balance gauges + level_complete fields are
     # always on (a handful of host ints per level); only the warning
-    # threshold is configurable.  The collective-latency probe rides
-    # the ``perf`` flag instead (it costs a compile + a collective).
+    # threshold is configurable.
     skew_warn_ratio: float = 2.0
     # Deadline for collecting sibling controllers' trace piece files at
     # replay (parallel/mesh.py _merge_trace_pieces).  None = auto: a 30 s
@@ -417,9 +389,6 @@ class EngineResult:
     # evaluations.  Populated by the engines at run end.
     coverage: Dict[str, Dict[str, int]] = dataclasses.field(
         default_factory=dict)
-    # Mean seconds per sampled chunk stage ({stage: s} + "total"), when
-    # --profile-chunks ran (obs/profile.py); {} otherwise.
-    chunk_stages: Dict[str, float] = dataclasses.field(default_factory=dict)
     violation: Optional[Violation] = None
     deadlock: Optional[PyState] = None
     stop_reason: str = "exhausted"
@@ -459,12 +428,6 @@ class EngineResult:
     # (engine/explain.py write_counterexample): {"txt": ..., "json":
     # ..., "depth": n}, {} when no traced violation was rendered.
     counterexample: Dict = dataclasses.field(default_factory=dict)
-    # Performance observatory block (obs/perf.py; EngineConfig.perf):
-    # launch accounting, static roofline rows with achieved-bandwidth
-    # fractions, and the fusion advisor's verdict.  {} when perf is
-    # off; embedded in bench JSON and gated by scripts/bench_diff.py
-    # --launch-drift.
-    perf: Dict = dataclasses.field(default_factory=dict)
 
     @property
     def states_per_second(self) -> float:
@@ -685,31 +648,6 @@ class BFSEngine:
         # the chunk loop can always read it.
         if not hasattr(self, "_xla_capture"):
             self._xla_capture = None
-        # Per-stage chunk profiler (obs/profile.py; --profile-chunks).
-        # Rebuilt on re-entrant init: its stage programs are shaped by
-        # the (possibly halved) batch.  --perf implies sparse profiling
-        # (every 16th call) when no cadence was chosen: the roofline's
-        # achieved-bandwidth fractions need measured stage means.
-        # None = unset (perf may imply a cadence); 0 = explicitly OFF
-        # (BENCH_PROFILE_CHUNKS=0) — perf must not re-enable it.
-        prof_every = (cfg.profile_chunks_every
-                      if cfg.profile_chunks_every is not None
-                      else (16 if cfg.perf else None))
-        if prof_every:
-            from ..obs import ChunkProfiler
-            prof_k = compact_mod.choose_k(cfg.batch, dims.n_instances,
-                                          cfg.compact_lanes)
-            self._profiler = ChunkProfiler(
-                dims, batch=cfg.batch, lanes=prof_k,
-                # Same 8*K floor the engine's own table gets (below):
-                # a table smaller than one sample's K keys would saturate
-                # from the first insert and time a pathological probe.
-                seen_capacity=max(
-                    min(cfg.seen_capacity or (1 << 20), 1 << 22),
-                    8 * prof_k),
-                every=prof_every, metrics=self.metrics)
-        else:
-            self._profiler = None
         if cfg.checkpoint_dir:
             # Fail at construction, not at the first level-boundary write.
             from . import checkpoint as _ckpt
@@ -951,13 +889,6 @@ class BFSEngine:
 
         self._chunk = jax.jit(chunk, donate_argnums=(3, 5, 6))
         self._ingest = jax.jit(ingest, donate_argnums=(2, 4))
-        # Performance observatory (obs/perf.py; EngineConfig.perf):
-        # trace THE chunk program just built — the exact jaxpr the jit
-        # above compiles, POR included — for the
-        # static launch model, plus the shared stage programs for the
-        # roofline traffic floors.  Fail-soft: a model that cannot be
-        # built (exotic jaxpr the walk has no rule for) degrades to a
-        # null perf block at run end, never a failed engine build.
         self._TQ = TQ
         # Allocated trace rows: live region + K trash slots for the
         # masked-off scatter lanes (stub when tracing is off).
@@ -970,15 +901,6 @@ class BFSEngine:
                 jax.lax.dynamic_slice(x, (start,), (length,))
                 for x in tbuf),
             static_argnums=2)
-        self._perf = None
-        if cfg.perf:
-            from ..obs import perf as perf_mod
-            self._perf = perf_mod.build_accounting(
-                pipeline=self._pipeline_name,
-                chunk_fn=chunk,
-                chunk_avals=self.chunk_avals(),
-                dims=dims, B=B, K=K,
-                metrics=self.metrics)
         # The last frontier's fetch (``_fetch_frontier``).
         self._frontier_rows = min(1 << 16, Q + PAD)
         self._frontier_piece = jax.jit(
@@ -1096,7 +1018,7 @@ class BFSEngine:
 
     def _telemetry_run(self, impl, init_states, resume=None):
         """Shared run_start/run_end bracketing (single-chip and mesh):
-        event log, run/level spans, coverage + chunk-profile run-end
+        event log, run/level spans, coverage run-end
         reporting, the Chrome-trace write-out — and the flight
         recorder's arm/disarm cycle: the black box is armed for the
         whole run (postmortem on any abnormal death), and disarmed on
@@ -1124,12 +1046,6 @@ class BFSEngine:
         # "observed dual-key collisions" is per-run.
         self._collision_base = mt.counter_value("engine/fp_collisions")
         self.coverage = None        # _run_impl installs this run's own
-        prof = getattr(self, "_profiler", None)
-        if prof is not None:
-            prof.reset()            # warm engines: samples are per-run
-        pf = getattr(self, "_perf", None)
-        if pf is not None:
-            pf.reset()              # launch/level accumulators per run
         # Device-HBM watermark (level-correlated OOM evidence): per-run
         # high-water mark, re-armed here so a warm shared registry
         # never carries a previous run's peak into this run's levels.
@@ -1250,29 +1166,6 @@ class BFSEngine:
                     import sys as _sys
                     print(report_mod.render_report(res.report),
                           file=_sys.stderr)
-            # Re-read the profiler: OOM degradation re-enters __init__,
-            # which rebuilds it for the halved batch — the run-end
-            # report must come from the object that took the most
-            # recent samples, not the pre-degrade one captured above.
-            prof = getattr(self, "_profiler", None)
-            if prof is not None:
-                if res is not None:
-                    res.chunk_stages = prof.stage_means()
-                prof.finish(evlog)
-            # Performance observatory (obs/perf.py): assemble the perf
-            # block AFTER the profiler lands its means (the roofline's
-            # measured half), emit the ``perf`` event + gauges, print
-            # the run-end table.  Skipped on error exits — a crashed
-            # run's perf numbers would price a partial loop.
-            pf = getattr(self, "_perf", None)
-            if pf is not None and err is None and res is not None:
-                try:
-                    res.perf = pf.finish(evlog,
-                                         chunk_stages=res.chunk_stages)
-                except Exception as e:
-                    import sys as _sys
-                    print(f"perf: block assembly failed "
-                          f"({type(e).__name__}: {e})", file=_sys.stderr)
             # Device-profiler window: close it (early-exit runs) and
             # land the xla_profile event whether the run lived or died.
             cap = getattr(self, "_xla_capture", None)
@@ -1417,12 +1310,6 @@ class BFSEngine:
 
     def _level_event(self, res, frontier_rows):
         evlog = self._evlog
-        # Launch accounting level boundary (obs/perf.py): snapshot this
-        # level's launch total so OOM/skew events correlate with launch
-        # pressure per level.
-        pf = getattr(self, "_perf", None)
-        if pf is not None:
-            pf.end_level(res.diameter)
         # Per-level device-HBM watermark: run_end's one-shot
         # devices_memory probe cannot say WHICH level drove the peak —
         # sampling here lets an OOM-degradation event be correlated
@@ -1868,16 +1755,6 @@ class BFSEngine:
                             # by a whole sync_every chunk.
                             allowed = 1
                     calls_in_level += 1
-                    prof = self._profiler
-                    if prof is not None and prof.want():
-                        # Observational per-stage sample of the batch
-                        # this call will expand first (obs/profile.py):
-                        # the real fused chunk below still does all the
-                        # work — results stay bit-identical.
-                        with mt.phase_timer("profile"):
-                            prof.sample(
-                                qcur[offset:offset + B],
-                                (offset + np.arange(B)) < cur_count)
                     if _faults.ACTIVE:
                         # Deterministic injection sites (resilience/):
                         # "kill" dies here (mid-level, past the level's
@@ -1926,10 +1803,6 @@ class BFSEngine:
                     account = mt.open_span("account", call=call,
                                            passes=passes)
                     self._count_chunk_call(passes, int(st[12]))
-                    if self._perf is not None and passes:
-                        # Launch accounting's dynamic half: the call's
-                        # passes and its seconds.
-                        self._perf.add_chunk(passes, call_seconds)
                     if passes:           # st fetch synced: timing is real
                         per = call_seconds / passes
                         # Conservative estimator: jumps up to the latest

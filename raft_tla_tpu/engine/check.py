@@ -83,12 +83,10 @@ def engine_config_from_backend(setup: CheckSetup) -> EngineConfig:
         trace_dir=be.get("TRACE_DIR"),
         events_out=be.get("EVENTS_OUT"),
         trace_out=be.get("TRACE_OUT"),
-        profile_chunks_every=be.get("PROFILE_CHUNKS"),
         xla_profile_chunks=be.get("XLA_PROFILE"),
         pipeline=be.get("PIPELINE", EngineConfig.pipeline),
         por=bool(be.get("POR", False)),
         por_table=be.get("POR_TABLE"),
-        perf=bool(be.get("PERF", False)),
         statespace_report=bool(be.get("REPORT", True)),
         counterexample_dir=be.get("COUNTEREXAMPLE_DIR"))
 

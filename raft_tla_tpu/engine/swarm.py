@@ -136,12 +136,6 @@ class SwarmResult:
     violation_step: Optional[int] = None
     violation_walk: Optional[int] = None
     counterexample: Dict = dataclasses.field(default_factory=dict)
-    #: Performance observatory block (obs/perf.py; ``perf=True``) —
-    #: same shape as ``EngineResult.perf``.
-    perf: Dict = dataclasses.field(default_factory=dict)
-    #: ChunkProfiler stage means (``profile_chunks_every``) at the
-    #: swarm granularity (choose/expand/ring_probe/latch).
-    chunk_stages: Dict[str, float] = dataclasses.field(default_factory=dict)
     #: The visited-fingerprint multiset as an [N, 2] uint32 (hi, lo)
     #: array, ONLY when the engine was built with
     #: ``collect_fingerprints=True`` (the determinism tests) — a
@@ -417,8 +411,6 @@ class SwarmEngine:
                  progress_seconds: float = 5.0,
                  run_context_extra: Optional[dict] = None,
                  hunt: bool = True, hunt_cells: int = 1 << 20,
-                 perf: bool = False,
-                 profile_chunks_every: Optional[int] = None,
                  xla_profile_chunks: Optional[int] = None,
                  xla_profile_dir: Optional[str] = None):
         if walks < 1:
@@ -462,37 +454,6 @@ class SwarmEngine:
         self._chunk = jax.jit(build_swarm_chunk(
             dims, inv_fns, constraint, max_depth, ring, chunk,
             pipeline=pipeline, hunt=hunt))
-        # Per-stage chunk profiler at the swarm granularity
-        # (choose/expand/ring_probe/latch; obs/profile.py).  Same
-        # cadence contract as the BFS engine: --perf implies sparse
-        # sampling (every 16th) when no cadence was chosen; an explicit
-        # 0 keeps it off.
-        prof_every = (profile_chunks_every
-                      if profile_chunks_every is not None
-                      else (16 if perf else None))
-        self._profiler = None
-        if prof_every:
-            from ..obs import ChunkProfiler
-            self._profiler = ChunkProfiler(
-                dims, batch=self.batch, lanes=dims.n_instances,
-                seen_capacity=1 << 10, pipeline="swarm",
-                swarm_pipeline=self.pipeline_name, ring=ring,
-                every=prof_every, metrics=self.metrics)
-        # Performance observatory (obs/perf.py): trace THE jitted chunk
-        # program above — scan body, hunt tallies and all — for the
-        # CI-pinned static launch model, plus the walk-kernel stage
-        # traffic floors for the roofline.  Fail-soft like the BFS
-        # engine's: a failed model degrades to nulls, never a failed
-        # engine build.
-        self._perf = None
-        if perf:
-            from ..obs import perf as perf_mod
-            self._perf = perf_mod.build_accounting(
-                pipeline="swarm", chunk_fn=self._chunk,
-                chunk_avals=self.chunk_avals(), dims=dims,
-                B=self.batch, K=dims.n_instances,
-                ring=ring, swarm_pipeline=self.pipeline_name,
-                metrics=self.metrics, engine="swarm")
         self._xla_chunks = xla_profile_chunks
         self._xla_dir = xla_profile_dir
         self._xla_capture = None
@@ -515,8 +476,8 @@ class SwarmEngine:
 
     def chunk_avals(self, n_roots: int = 2) -> tuple:
         """The walk chunk program's arguments as shapes (one full-width
-        slice of ``self.batch`` lanes): what the launch model traces and
-        what a compile for a described chip lowers."""
+        slice of ``self.batch`` lanes): what a compile for a described
+        chip lowers."""
         from ..models.schema import state_width
         B, sw = self.batch, state_width(self.dims)
         i32 = jax.ShapeDtypeStruct((), jnp.int32)
@@ -559,13 +520,9 @@ class SwarmEngine:
         mt = self.metrics
         if num_steps is None and max_seconds is None:
             num_steps = self.max_depth
-        # Per-run telemetry state (warm engines reuse the static
-        # halves: compiled programs, launch model, stage programs).
+        # Per-run telemetry state (warm engines reuse the compiled
+        # programs).
         self._hunt_acc = None
-        if self._profiler is not None:
-            self._profiler.reset()
-        if self._perf is not None:
-            self._perf.reset()
         self._xla_capture = None
         if self._xla_chunks:
             from ..obs import XlaProfileCapture
@@ -618,7 +575,7 @@ class SwarmEngine:
     def _run_end(self, res, err, evlog, t0, phase_base, compile_base,
                  jit_base):
         """What every run pays after its loop: the counterexample files,
-        the profiler's and the observatory's reports, ``run_end``."""
+        the capture's and the observatory's reports, ``run_end``."""
         mt = self.metrics
         res.wall_seconds = time.time() - t0
         res.distinct, res.generated = res.visited, res.steps
@@ -635,21 +592,7 @@ class SwarmEngine:
                 import sys as _sys
                 print(f"counterexample render failed: "
                       f"{type(e).__name__}: {e}", file=_sys.stderr)
-        # Profiler / perf / device-capture run-end hooks, the BFS
-        # engine's order: the profiler lands its means first (the
-        # roofline's measured half), perf prices them, the capture
-        # window closes whether the run lived or died.
-        if self._profiler is not None:
-            res.chunk_stages = self._profiler.stage_means()
-            self._profiler.finish(evlog)
-        if self._perf is not None and err is None:
-            try:
-                res.perf = self._perf.finish(
-                    evlog, chunk_stages=res.chunk_stages)
-            except Exception as e:
-                import sys as _sys
-                print(f"perf: block assembly failed "
-                      f"({type(e).__name__}: {e})", file=_sys.stderr)
+        # The capture window closes whether the run lived or died.
         if self._xla_capture is not None:
             self._xla_capture.finish(evlog)
         # The hunt report (obs/hunt.py): the swarm sibling of the
@@ -817,7 +760,6 @@ class SwarmEngine:
         mt.counter("swarm/walks", W)
         mt.gauge("swarm/active_walks", W)
         hacc = self._hunt_acc
-        prof = self._profiler
         cap = self._xla_capture
 
         def fetch(a):
@@ -830,13 +772,6 @@ class SwarmEngine:
         depth_max = 0
         last_progress = t0
         while True:
-            if prof is not None and prof.want():
-                # Observational side-channel: re-run the first (always
-                # full-width) slice's current rows through the staged
-                # walk-kernel programs for per-stage timings.
-                prof.sample(slices[0]["rows"],
-                            np.ones((self.batch,), bool))
-            tc0 = time.perf_counter()
             with mt.phase_timer("swarm_chunk", step=k0):
                 step_cm = cap.step() if cap is not None else None
                 if step_cm is not None:
@@ -864,9 +799,6 @@ class SwarmEngine:
             stepped = min(self.chunk,
                           max(0, int(k_limit) - k0)) if num_steps \
                 else self.chunk
-            if self._perf is not None:
-                self._perf.add_chunk(len(slices),
-                                     time.perf_counter() - tc0)
             k_start = k0
             k0 += self.chunk
             res.steps += W * stepped

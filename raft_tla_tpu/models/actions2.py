@@ -1,7 +1,6 @@
 """The v2 (delta) successor pipeline — guards first, construction last.
 
-The first TPU profile of the v1 chunk (artifacts/profile_step_tpu.txt,
-2026-07-31, B=2048) showed 85% of the 89 ms/batch in three stages that all
+The first TPU profile of the v1 chunk (2026-07-31, B=2048) showed 85% of the 89 ms/batch in three stages that all
 scale with *full candidate-state construction over every B*G lane*:
 
     expand (36.6 ms)       builds a complete ~473-field successor struct

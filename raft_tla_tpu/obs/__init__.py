@@ -13,20 +13,19 @@ One spine, several legs:
   import, carried by every ``run_start`` as ``process``;
 - :mod:`.events` — the structured JSONL :class:`RunEventLog`
   (run_start, level_complete, fpset_resize, spill, checkpoint,
-  violation, deadlock, chunk_profile, coverage, run_end) written next
+  violation, deadlock, coverage, run_end) written next
   to the checkpoint dir and per-host under ``parallel/mesh.py``;
 - :mod:`.tracing` — :class:`SpanTracer`, nested spans serialized as
   Chrome trace-event JSON (``--trace-out``; opens in Perfetto).
   Attached to a registry it receives every span, and holds a
   ``jax.profiler.TraceAnnotation`` open for each (``raft.<name>`` in
   any profiler capture);
-- :mod:`.profile` — :class:`ChunkProfiler`, the per-stage chunk
-  decomposition behind ``--profile-chunks`` (expand / fingerprint /
-  dedup-insert / enqueue histograms + the run-end stage table);
+- :mod:`.profile` — :class:`XlaProfileCapture`, the ``jax.profiler``
+  window over N of the real chunk dispatches behind ``--xla-profile``;
 - :mod:`.coverage` — :class:`ActionCoverage`, TLC-style per-action
   generated/distinct/disabled counters and the run-end coverage table;
 - :mod:`.flight` — the always-on :class:`FlightRecorder` black box
-  (bounded ring of recent events/progress/stage samples) with the
+  (bounded ring of recent events/progress snapshots) with the
   crash/SIGTERM/fault-kill **postmortem dump** and the process-global
   :data:`~.flight.RECORDER` the live-introspection consumers read;
 - :mod:`.expose` — Prometheus text exposition of the registry
@@ -37,12 +36,6 @@ One spine, several legs:
   probability, per-level frontier table, out-degree, seen-set load)
   assembled host-side at run end: the ``statespace`` event,
   ``EngineResult.report``, and the TLC-style stderr block;
-- :mod:`.perf` / :mod:`.roofline` — the **performance observatory**
-  (``--perf``): static launch accounting over the engines' real traced
-  chunk programs, per-stage HBM-traffic floors joined with the
-  ChunkProfiler's measured means into achieved-bandwidth fractions,
-  and the fusion advisor naming the next fusion target (the ``perf``
-  run event, ``EngineResult.perf``, ``perf/*`` gauges);
 - :mod:`.history` — the append-only JSONL **run-history ledger**
   (``check --history`` / ``HISTORY`` directive / ``BENCH_HISTORY``):
   per-run cfg/model/host fingerprints, verdict, rates, and report
@@ -50,11 +43,17 @@ One spine, several legs:
   ``scripts/bench_diff.py --history`` resolves baselines from it.
 
 The CLI exposes them via ``--metrics-out`` / ``--events-out`` /
-``--trace-out`` / ``--profile-chunks`` / ``--metrics-port`` /
-``--xla-profile``, the checker service via the ``stats`` / ``metrics``
-/ ``watch`` requests, and ``bench.py`` embeds the phase breakdown,
-chunk stage means, and coverage in its JSON (``scripts/bench_diff.py``
-gates on all three).  See README.md "Observability" for the schemas.
+``--trace-out`` / ``--metrics-port`` / ``--xla-profile``, the checker
+service via the ``stats`` / ``metrics`` / ``watch`` requests, and
+``bench.py`` embeds the phase breakdown and coverage in its JSON
+(``scripts/bench_diff.py`` gates on both).  See README.md
+"Observability" for the schemas.
+
+Nothing here models a performance number: how fast the program is
+comes from the chip, through ``benchmark/`` (trace, spans, counters) or
+``--xla-profile`` / ``--trace-out`` / ``--events-out``.  And ``obs`` is
+a leaf: ``utils/`` imports it, so it imports none of its sibling
+packages (tests/test_layers.py).
 """
 
 from .metrics import (Histogram, MetricsRegistry, PHASE_PREFIX,  # noqa: F401
@@ -72,16 +71,4 @@ from .expose import (parse_prometheus, render_prometheus,        # noqa: F401
 from .report import (build_report, collision_probability,        # noqa: F401
                      render_report)
 from . import history                                            # noqa: F401
-# NOTE deliberately NOT imported here: obs.perf / obs.roofline (the
-# performance observatory).  Importing them at package init would put
-# two new modules into the import-time heap history of EVERY test and
-# tool that touches obs — and jaxlib's CPU client is heap-layout
-# fragile under the big mesh tests (the tests/conftest.py reorder
-# rationale), so new modules stay off the default import path as a
-# precaution.  Consumers import them lazily:
-# ``from raft_tla_tpu.obs import perf`` /
-# ``from raft_tla_tpu.obs import roofline`` at use sites.
-# .profile imports jax lazily but pulls model/ops modules at call time;
-# import the classes here for the one-stop namespace (still jax-free at
-# import).
-from .profile import ChunkProfiler, XlaProfileCapture            # noqa: F401
+from .profile import XlaProfileCapture                           # noqa: F401

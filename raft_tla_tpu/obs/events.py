@@ -34,14 +34,13 @@ REQUIRED_EVENTS = ("run_start", "run_end")
 #: Every event type the engines/tooling emit (documentation + the
 #: validator's schema table).  Unknown types still validate — forward
 #: compatibility — but known STRUCTURED types must carry their payload
-#: field, so a half-written profiler/coverage emitter fails the bench
+#: field, so a half-written coverage emitter fails the bench
 #: gate instead of shipping empty records.
 KNOWN_EVENTS = (
     "run_start", "level_complete", "fpset_resize", "spill", "checkpoint",
     "violation", "deadlock", "run_end", "restart", "supervised_done",
     "supervise_giveup", "degraded", "analysis",
-    # Deep-profiling layer (obs/profile.py, obs/coverage.py):
-    "chunk_profile",    # per-stage chunk timings; payload: "stages"
+    # Deep-profiling layer (obs/coverage.py):
     "coverage",         # TLC-style per-action counters; payload: "actions"
     # Flight-recorder / live-introspection layer (obs/flight.py,
     # obs/expose.py):
@@ -53,11 +52,8 @@ KNOWN_EVENTS = (
     # ``counterexample_path`` when a traced violation was rendered
     # (engine/explain.py).
     "statespace",       # TLC-parity run report; payload: "report"
-    # Performance observatory (obs/perf.py, obs/roofline.py): launch
-    # accounting + static roofline + fusion-advisor verdict, one per
-    # completed --perf run; and the mesh's per-shard balance warning
-    # (parallel/mesh.py skew telemetry).
-    "perf",             # launch/roofline/advisor block; payload: "perf"
+    # The mesh's per-shard balance warning (parallel/mesh.py skew
+    # telemetry).
     "skew",             # shard imbalance warning; payload: "balance"
     "rebalance",        # the mesh dealt an uneven frontier out evenly
     # Swarm tier (engine/swarm.py): periodic walker progress.  Swarm
@@ -72,10 +68,10 @@ KNOWN_EVENTS = (
 )
 
 #: Structured payload field each new event type must carry.
-_EVENT_PAYLOAD_FIELDS = {"chunk_profile": "stages", "coverage": "actions",
+_EVENT_PAYLOAD_FIELDS = {"coverage": "actions",
                          "postmortem": "dump", "watch_attach": "client",
                          "xla_profile": "capture", "statespace": "report",
-                         "perf": "perf", "skew": "balance",
+                         "skew": "balance",
                          "swarm_progress": "swarm", "hunt": "hunt"}
 
 
@@ -225,8 +221,7 @@ def validate_and_cleanup(events_file: str, scratch_dir: Optional[str],
     """Bench-harness gate: validate a run's event log, removing
     ``scratch_dir`` whether validation succeeds or raises (a failing CI
     run must not orphan its scratch directory either).  Returns the
-    event count; raises like :func:`validate_run_events`.  One shared
-    copy for ``bench.py`` and ``scripts/true_bench.py``."""
+    event count; raises like :func:`validate_run_events`."""
     import shutil
     try:
         return len(validate_run_events(events_file, required=required))

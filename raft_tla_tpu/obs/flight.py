@@ -1,13 +1,13 @@
 """Flight recorder — always-on black-box telemetry + postmortem dumps.
 
-Every other observability leg (JSONL events, Chrome traces, the chunk
-profiler, coverage) is post-hoc and file-based: a run that dies with a
+Every other observability leg (JSONL events, Chrome traces,
+coverage) is post-hoc and file-based: a run that dies with a
 hung device, a SIGTERM'd supervised child, or a fault-injected
 ``os._exit`` leaves nothing but whatever already hit disk.  This module
 is the black box: a bounded in-memory ring of recent telemetry records
 — run events (mirrored automatically from every :class:`RunEventLog`,
-file-backed or not), rate-limited per-chunk progress snapshots,
-chunk-stage profiler samples, and run-context/registry deltas — always
+file-backed or not), rate-limited per-chunk progress snapshots, and
+run-context/registry deltas — always
 on at near-zero overhead (a deque append under a lock per record, a few
 records per second at most), plus a **postmortem dump**: when the
 recorder is armed for a run and the process dies abnormally, the ring

@@ -236,25 +236,6 @@ def resolve_baseline(path: str, host_fp: Optional[dict],
     return None
 
 
-def perf_columns(entry: dict):
-    """(launches/chunk, advisor-top, peak bandwidth fraction) from an
-    entry's embedded bench perf block (obs/perf.py) — or the
-    xplane_summary dialect, which embeds the same block shape.  The
-    bandwidth fraction is the MAX across the profiled roofline stages
-    (the most saturated stage).  (None, None, None) for entries
-    predating the metric, so the trajectory renders '--' instead of
-    guessing."""
-    bench = entry.get("bench") or {}
-    perf = bench.get("perf") or {}
-    lpc = (perf.get("launch") or {}).get("launches_per_chunk")
-    top = (perf.get("advisor") or {}).get("top")
-    stages = ((perf.get("roofline") or {}).get("stages") or {})
-    fracs = [r.get("bandwidth_fraction") for r in stages.values()
-             if isinstance(r, dict)
-             and r.get("bandwidth_fraction") is not None]
-    return lpc, top, (max(fracs) if fracs else None)
-
-
 def hunt_columns(entry: dict):
     """(saturation, novel rate, time-to-violation seconds) from a swarm
     entry's hunt summary (obs/hunt.py summarize) — carried either as
@@ -271,26 +252,20 @@ def hunt_columns(entry: dict):
             hunt.get("time_to_violation_seconds"))
 
 
-def render_table(entries: List[dict], perf: bool = False,
-                 hunt: bool = False) -> str:
+def render_table(entries: List[dict], hunt: bool = False) -> str:
     """The trajectory table (scripts/bench_history.py): one row per
     entry, host-key column + explicit flags where adjacent entries are
     NOT rate-comparable (different or unknown host) — the r05 trap,
-    rendered impossible to miss.  ``perf=True`` adds the performance-
-    observatory columns (pipeline + launches/chunk + peak bandwidth
-    fraction + advisor pick) so the trajectory shows whether launches
-    are retired and saturation raised across rounds, not just
-    wall-clock moved.  ``hunt=True`` adds the hunt-observatory columns
-    (coverage saturation + novelty rate + time-to-violation from
-    obs/hunt.py summaries) so a swarm trajectory answers "is each
-    round's hunt saturating sooner / latching faster" at a glance."""
-    pcols = (f" {'pipe':>4s} {'launch/chunk':>12s} {'bw-frac':>8s} "
-             f"{'advisor':14s}") if perf else ""
+    rendered impossible to miss.  ``hunt=True`` adds the
+    hunt-observatory columns (coverage saturation + novelty rate +
+    time-to-violation from obs/hunt.py summaries) so a swarm
+    trajectory answers "is each round's hunt saturating sooner /
+    latching faster" at a glance."""
     hcols = (f" {'satur':>7s} {'novel':>7s} {'t-viol':>7s}") if hunt \
         else ""
     lines = [f"{'#':>3s} {'label':20s} {'kind':9s} {'host':10s} "
              f"{'distinct/s':>12s} {'distinct':>12s} {'diam':>5s} "
-             f"{'verdict':10s}{pcols}{hcols} flags"]
+             f"{'verdict':10s}{hcols} flags"]
     first = object()
     prev_key = first              # sentinel: first row never flags
     warnings = []
@@ -331,14 +306,6 @@ def render_table(entries: List[dict], perf: bool = False,
                + (f" {dia:5d}" if isinstance(dia, int)
                   else f" {'--':>5s}")
                + f" {str(e.get('verdict') or '?'):10s}")
-        if perf:
-            lpc, top, bw = perf_columns(e)
-            row += (f" {str(e.get('pipeline') or '--'):>4s}"
-                    + (f" {lpc:12,.0f}" if isinstance(lpc, (int, float))
-                       else f" {'--':>12s}")
-                    + (f" {bw:8.1%}" if isinstance(bw, (int, float))
-                       else f" {'--':>8s}")
-                    + f" {str(top or '--'):14s}")
         if hunt:
             sat, novel, ttv = hunt_columns(e)
             row += ((f" {sat:7.1%}" if isinstance(sat, (int, float))

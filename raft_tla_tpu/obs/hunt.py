@@ -38,8 +38,9 @@ Surfaces: the ``hunt`` run event (payload ``hunt``) and the enriched
 ["hunt"]``, bench JSON, the server ``check`` response, ``hunt/*``
 registry gauges (Prometheus: ``raft_hunt_*``), flight-recorder ``hunt``
 snapshots, and the history ledger.  Zero-dep and jax-free like all of
-``obs/``; keep it OFF the eager ``obs/__init__`` import path (same
-heap-layout precaution as obs/perf.py).
+``obs/``; keep it OFF the eager ``obs/__init__`` import path (jaxlib's
+CPU client is heap-layout fragile under the big mesh tests, the
+tests/conftest.py reorder rationale).
 """
 
 from __future__ import annotations

@@ -5,8 +5,7 @@ typically well under 10% (measured fan-out ~6% of G on MCraft_bounded), so
 everything downstream of expand — fingerprint insert, row materialization,
 invariant/constraint evaluation, enqueue — runs on K << B*G compacted
 lanes.  This module is the single implementation both engines (engine/
-bfs.py, parallel/mesh.py) and the profiling instrument (scripts/
-profile_step.py) share; its invariants are load-bearing:
+bfs.py, parallel/mesh.py) share; its invariants are load-bearing:
 
 - ``K`` is a power of two and ``K >= G``, so one parent's worst-case
   fan-out always fits and a batch always makes progress (``P >= 1``);

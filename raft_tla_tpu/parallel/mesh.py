@@ -198,10 +198,6 @@ class MeshBFSEngine:
                 trace_out, annotate=jax.profiler.TraceAnnotation)
         self.metrics.tracer = self.tracer
         watch_compiles()    # ``run_end.compiles`` (engine/bfs.py)
-        # The per-stage chunk profiler is a single-chip instrument
-        # (EngineConfig.profile_chunks_every rationale); the mesh's
-        # observability is spans + phases + coverage.
-        self._profiler = None
         if cfg.checkpoint_dir:
             # Fail at construction, not at the first level-boundary write.
             from ..engine import checkpoint as _ckpt
@@ -539,25 +535,7 @@ class MeshBFSEngine:
             in_specs=(sx, sx, sx, sx, sx, sx, sx, sx, sx),
             out_specs=(sx, sx, sx, sx, sx, (sx,) * 5, sx, rep, rep, rep)),
             donate_argnums=(2, 4, 5, 6, 7))
-        # Performance observatory (obs/perf.py; EngineConfig.perf):
-        # launch model from THE sharded chunk program just built — the
-        # walk recurses through shard_map, so collectives (all_to_all
-        # owner routing, psum'd stats) are counted per batch alongside
-        # the device ops.  The roofline's per-stage measured half is a
-        # single-chip instrument (the profiler rationale above), so the
-        # mesh block carries launch accounting + the modeled collective
-        # share, not stage fractions.  Fail-soft like the single-chip
-        # engine.
         self._last_skew = None
-        self._perf = None
-        if cfg.perf:
-            from ..obs import perf as perf_mod
-            self._perf = perf_mod.build_accounting(
-                pipeline=self._pipeline_name,
-                chunk_fn=self._chunk,
-                chunk_avals=self.chunk_avals(),
-                with_stages=False,
-                metrics=self.metrics, engine="mesh")
 
         def fp_rows(rows):
             return jax.vmap(fingerprint)(
@@ -810,16 +788,12 @@ class MeshBFSEngine:
         ``skew`` WARNING event when max/mean frontier imbalance reaches
         ``EngineConfig.skew_warn_ratio``.  Host-side reads of a handful
         of addressable-shard ints per level — observational by
-        construction (bit-identity asserted in tests/test_perf.py).
+        construction (the telemetry itself is held by
+        tests/test_mesh.py).
         Caveats: under a process group each controller samples its own
         shards (the union is the global picture, one event log piece
         each); a level whose rows were already drained to the host pool
-        samples the device-resident remainder only.
-
-        With ``--perf`` on, also times one psum agreement round (the
-        collective-latency probe behind the perf block's modeled
-        collective share) — that half is gated: it costs a compile +
-        a collective round, unlike the free shard reads."""
+        samples the device-resident remainder only."""
         try:
             fr = self._local_counts(next_counts)
             sz = self._local_counts(ssize)
@@ -853,18 +827,6 @@ class MeshBFSEngine:
                 "level": res.diameter, "frontier_skew": fsk,
                 "seen_skew": ssk, "shard_frontier": vals,
                 "threshold": thr})
-        if self._perf is not None:
-            try:
-                if not hasattr(self, "_psum_probe"):
-                    from . import multihost as mh
-                    self._psum_probe = mh.build_sum(self.mesh)
-                    self._psum_probe(1)   # warm once: compile off the
-                from ..obs import perf as perf_mod  # timed samples
-                self._perf.note_collective_probe(
-                    perf_mod.timed_collective_probe(self._psum_probe, 1,
-                                                    warm=False))
-            except Exception:
-                pass                 # the probe is a nicety, never fatal
 
     def _counterexample_base(self) -> str:
         """Per-controller counterexample file stem (the event-log piece
@@ -1386,11 +1348,6 @@ class MeshBFSEngine:
                                            passes=int(st[1]))
                     self._count_chunk_call(int(st[1]), int(st[15]))
                     written = self._count_per_chip(st)
-                    if self._perf is not None and int(st[1]):
-                        # Launch accounting's dynamic half (obs/perf.py)
-                        # — host arithmetic on the fetched stats only.
-                        self._perf.add_chunk(int(st[1]),
-                                             time.time() - t_call)
                     if int(st[1]):
                         per = (time.time() - t_call) / int(st[1])
                         # Conservative: jump up instantly, decay slowly

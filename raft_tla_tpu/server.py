@@ -60,7 +60,7 @@ response per line.  Requests:
     {"op": "watch", "interval": 1.0, "count": 0}
         -> a STREAM of lines (the one multi-line-response op): one
            {"ok": true, "watch": {run, progress, level, coverage,
-            chunk_stage, seq, armed}} snapshot per interval, closed by
+            hunt, seq, armed}} snapshot per interval, closed by
            {"ok": true, "done": true, ...} when the watched run ends
            (or after "count" snapshots; count 0 = until run end).
        Run attach (obs/flight.py): snapshots come from the in-memory
@@ -823,7 +823,6 @@ class _Handler(socketserver.StreamRequestHandler):
                 "progress": RECORDER.last_record("progress"),
                 "level": RECORDER.last_event("level_complete"),
                 "coverage": RECORDER.last_event("coverage"),
-                "chunk_stage": RECORDER.last_record("chunk_stage"),
                 "hunt": RECORDER.last_record("hunt"),
             }
             if not self._try_respond({"ok": True, "watch": snapshot}):

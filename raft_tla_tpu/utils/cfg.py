@@ -31,8 +31,8 @@ unchanged — the cfg stays the single source of truth for both engines.
 Recognized keys: BATCH, QUEUE_CAPACITY, SEEN_CAPACITY, N_MSG_SLOTS,
 MAX_LOG, PLATFORM, CHECKPOINT_DIR, CHECKPOINT_EVERY, CHECKPOINT_INTERVAL,
 SPILL_DIR, TRACE_DIR, PROGRESS_SECONDS, EVENTS_OUT, KEEP_CHECKPOINTS,
-TRACE_OUT (Chrome-trace span file), PROFILE_CHUNKS (per-stage chunk
-profiling cadence), POR (statically-certified partial-order reduction),
+TRACE_OUT (Chrome-trace span file), POR (statically-certified
+partial-order reduction),
 POR_TABLE (pre-certified reduction-table artifact path), PIPELINE
 (successor pipeline: auto / v1 / v2, ``PIPELINES`` below; engine/bfs.py
 EngineConfig.pipeline), XLA_PROFILE (device-profiler
@@ -44,9 +44,7 @@ obs/report.py; TRUE by default — FALSE drops every report surface),
 COUNTEREXAMPLE_DIR (where a traced violation's rendered counterexample
 lands, engine/explain.py; defaults next to CHECKPOINT_DIR), HISTORY
 (append one run-history ledger entry per run to this JSONL file,
-obs/history.py), PERF (the performance observatory: launch accounting,
-static roofline + fusion advisor, obs/perf.py — observational, implies
-sparse chunk profiling), MODE (checking engine tier: ``exhaustive``
+obs/history.py), MODE (checking engine tier: ``exhaustive``
 (default) or ``swarm`` — the vmap'd randomized-walk engine,
 engine/swarm.py), WALKS (swarm mode: concurrent walks per device).
 Precedence everywhere: CLI flag > cfg backend key > built-in default.
@@ -98,9 +96,9 @@ _BACKEND_KEYS = {
     "BATCH", "QUEUE_CAPACITY", "SEEN_CAPACITY", "N_MSG_SLOTS", "MAX_LOG",
     "PLATFORM", "CHECKPOINT_DIR", "CHECKPOINT_EVERY", "CHECKPOINT_INTERVAL",
     "SPILL_DIR", "TRACE_DIR", "PROGRESS_SECONDS", "EVENTS_OUT",
-    "KEEP_CHECKPOINTS", "TRACE_OUT", "PROFILE_CHUNKS", "POR", "POR_TABLE",
+    "KEEP_CHECKPOINTS", "TRACE_OUT", "POR", "POR_TABLE",
     "PIPELINE", "XLA_PROFILE", "METRICS_PORT", "REPORT",
-    "COUNTEREXAMPLE_DIR", "HISTORY", "PERF", "MODE", "WALKS",
+    "COUNTEREXAMPLE_DIR", "HISTORY", "MODE", "WALKS",
 }
 
 

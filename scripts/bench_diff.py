@@ -3,10 +3,9 @@ of the BENCH_r* trajectory.
 
 ``bench.py`` (and the round-note harness that wraps it into
 ``BENCH_rNN.json``) emits one JSON object per run: headline
-states/s, per-phase host seconds, per-stage chunk means
-(``chunk_stages``, obs/profile.py), and the TLC-style ``coverage``
+states/s, per-phase host seconds, and the TLC-style ``coverage``
 object (obs/coverage.py).  This script compares OLD vs NEW along all
-four axes and exits nonzero when NEW regresses past the thresholds —
+three axes and exits nonzero when NEW regresses past the thresholds —
 so CI (and a human mid-perf-PR) gets a yes/no instead of two JSON
 blobs to eyeball.
 
@@ -19,7 +18,7 @@ is used; a null ``parsed`` — a bench run that never emitted JSON — is
 malformed input, exit 2).
 
 Comparison rules (each axis only when BOTH runs carry it — early
-BENCH_r04/r05 files predate chunk_stages/coverage and still diff):
+BENCH_r04/r05 files predate coverage and still diff):
 
 - headline ``value`` (distinct states/s) and ``generated_per_sec``:
   regression when NEW < OLD * (1 - max_regress).
@@ -27,17 +26,6 @@ BENCH_r04/r05 files predate chunk_stages/coverage and still diff):
   (budget-length independence), compared per phase when the OLD phase
   is at least ``--phase-floor`` of total phase time (noise floor for
   sub-percent phases); threshold ``--phase-max-regress``.
-- per-stage chunk means (``chunk_stages``): direct per-stage ratio,
-  threshold ``--stage-max-regress``; the fused ``total`` row is
-  compared too (it is the engine-shaped number).  Where the two sides'
-  stage keys differ, the keys both have are compared and the others
-  are named in a note.
-- performance observatory (``perf`` block, obs/perf.py — also the
-  ``scripts/xplane_summary.py`` dialect): ``launches_per_chunk`` rising
-  past ``--launch-drift`` regresses (a stage un-fusing is visible
-  before any wall-clock moves), as does a stage's achieved-bandwidth
-  fraction falling by the same margin; one side predating the block
-  folds to a note.
 - POR pruned fraction (``pruned / (pruned + generated)`` from the
   coverage object): compared whenever either side pruned anything; a
   candidate whose fraction falls more than ``--pruned-drift`` points
@@ -78,8 +66,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-PHASE_PREFIX_SKIP = ("profile",)   # measurement overhead, not engine work
-
 
 def load_bench(path: str) -> dict:
     """Load a bench JSON in either accepted form; raise ValueError on
@@ -91,14 +77,9 @@ def load_bench(path: str) -> dict:
         raise ValueError(f"{path}: cannot load bench JSON: {e}")
     if isinstance(data, dict) and "parsed" in data:
         data = data["parsed"]           # BENCH_rNN wrapper
-    # "value" is the classic bench headline; a perf-only document (the
-    # scripts/xplane_summary.py dialect: measured launch counts from
-    # device-profiler artifacts, no states/s headline) diffs too — the
-    # headline axis simply has nothing to compare.
-    if not isinstance(data, dict) or ("value" not in data
-                                      and "perf" not in data):
+    if not isinstance(data, dict) or "value" not in data:
         raise ValueError(
-            f"{path}: not a bench result (no 'value' or 'perf' field; a "
+            f"{path}: not a bench result (no 'value' field; a "
             f"BENCH_r* wrapper whose run emitted no JSON has "
             f"parsed=null)")
     return data
@@ -166,11 +147,7 @@ def diff_host(old: dict, new: dict, d: Diff):
 
 
 def diff_headline(old: dict, new: dict, d: Diff, max_regress: float):
-    # The headline's direction follows its unit: rates (".../s",
-    # bench.py) regress downward, costs ("ms/iter", true_bench.py TB_JSON)
-    # regress upward.
     unit = old.get("unit", "states/s")
-    higher_is_better = not unit.startswith("ms")
     for key, label in (("value", f"headline ({unit})"),
                        ("generated_per_sec", "generated states/s")):
         ov, nv = old.get(key), new.get(key)
@@ -178,9 +155,7 @@ def diff_headline(old: dict, new: dict, d: Diff, max_regress: float):
             continue
         pct = (nv - ov) / ov * 100.0 if ov else 0.0
         d.note(f"{label}: {ov:,.1f} -> {nv:,.1f} ({pct:+.1f}%)")
-        worse = (_ratio_regress(ov, nv, max_regress) if higher_is_better
-                 else ov > 0 and nv > ov * (1.0 + max_regress))
-        if worse:
+        if _ratio_regress(ov, nv, max_regress):
             d.regress(f"{label} moved {pct:+.1f}% "
                       f"(> {max_regress:.0%} allowed): {ov:,.1f} -> "
                       f"{nv:,.1f}")
@@ -287,8 +262,6 @@ def diff_phases(old: dict, new: dict, d: Diff, max_regress: float,
         return
     ototal = sum(op.values()) or 1.0
     for phase in sorted(set(op) & set(np_)):
-        if phase in PHASE_PREFIX_SKIP:
-            continue
         if op[phase] / ototal < floor:
             continue        # sub-floor phases are timer noise
         # Seconds per 1M distinct states: compares runs of different
@@ -302,76 +275,6 @@ def diff_phases(old: dict, new: dict, d: Diff, max_regress: float,
             d.regress(f"phase '{phase}' cost rose {pct:.1f}% "
                       f"(> {max_regress:.0%} allowed): {oc:.2f} -> "
                       f"{nc:.2f} s/M-distinct")
-
-
-def diff_stages(old: dict, new: dict, d: Diff, max_regress: float):
-    os_, ns = old.get("chunk_stages") or {}, new.get("chunk_stages") or {}
-    if not os_ or not ns:
-        return
-    for side, only in (("old", set(os_) - set(ns)),
-                       ("new", set(ns) - set(os_))):
-        if only:
-            d.note(f"chunk stages on the {side} side only, not compared: "
-                   f"{', '.join(sorted(only))}")
-    for stage in sorted(set(os_) & set(ns)):
-        oc, nc = os_[stage], ns[stage]
-        pct = (nc - oc) / oc * 100.0 if oc else 0.0
-        d.note(f"chunk stage {stage}: {oc * 1e3:.2f} -> {nc * 1e3:.2f} "
-               f"ms/batch ({pct:+.1f}%)")
-        if oc > 0 and nc > oc * (1.0 + max_regress):
-            d.regress(f"chunk stage '{stage}' rose {pct:.1f}% "
-                      f"(> {max_regress:.0%} allowed): {oc * 1e3:.2f} -> "
-                      f"{nc * 1e3:.2f} ms/batch")
-
-
-def diff_perf(old: dict, new: dict, d: Diff, launch_drift: float):
-    """Performance-observatory axis (obs/perf.py ``perf`` block, also
-    the scripts/xplane_summary.py dialect): launches_per_chunk rising
-    more than ``--launch-drift`` (fractional) regresses — a stage
-    un-fusing shows up here before any wall-clock number moves — and a
-    stage's achieved-bandwidth fraction falling by more than the same
-    fraction regresses too.  Folds gracefully when one side predates
-    the metric (legacy BENCH_r* files): reported, never gated."""
-    op, np_ = old.get("perf") or {}, new.get("perf") or {}
-    if not op and not np_:
-        return
-    if not op or not np_:
-        side = "baseline" if not op else "candidate"
-        have = np_ if np_ else op
-        lpc = (have.get("launch") or {}).get("launches_per_chunk")
-        d.note(f"perf block present on one side only ({side} predates "
-               f"it); launches/chunk "
-               + (f"{lpc:,.0f}" if lpc is not None else "unknown")
-               + " not gated")
-        return
-    ol = (op.get("launch") or {}).get("launches_per_chunk")
-    nl = (np_.get("launch") or {}).get("launches_per_chunk")
-    if ol is not None and nl is not None:
-        pct = (nl - ol) / ol * 100.0 if ol else 0.0
-        d.note(f"launches/chunk: {ol:,.0f} -> {nl:,.0f} ({pct:+.1f}%)")
-        if ol > 0 and nl > ol * (1.0 + launch_drift):
-            d.regress(f"launches_per_chunk rose {pct:.1f}% "
-                      f"(> {launch_drift:.0%} allowed): {ol:,.0f} -> "
-                      f"{nl:,.0f} — a stage un-fused or the chunk "
-                      f"program grew kernels")
-    osr = ((op.get("roofline") or {}).get("stages")) or {}
-    nsr = ((np_.get("roofline") or {}).get("stages")) or {}
-    for stage in sorted(set(osr) & set(nsr)):
-        of = osr[stage].get("bandwidth_fraction")
-        nf = nsr[stage].get("bandwidth_fraction")
-        if of is None or nf is None:
-            continue
-        d.note(f"achieved bandwidth {stage}: {of:.2%} -> {nf:.2%} "
-               f"of peak")
-        if of > 0 and nf < of * (1.0 - launch_drift):
-            d.regress(f"achieved-bandwidth fraction of '{stage}' fell "
-                      f"{(of - nf) / of:.0%} (> {launch_drift:.0%} "
-                      f"allowed): {of:.2%} -> {nf:.2%} of peak")
-    oa = (op.get("advisor") or {}).get("top")
-    na = (np_.get("advisor") or {}).get("top")
-    if oa or na:
-        d.note(f"fusion advisor top candidate: {oa or '-'} -> "
-               f"{na or '-'}")
 
 
 def pruned_fraction(cov: dict):
@@ -499,9 +402,6 @@ def main(argv=None) -> int:
                    help="allowed fractional rise in per-phase "
                         "s/M-distinct (noisier than the headline; "
                         "default 0.35)")
-    p.add_argument("--stage-max-regress", type=float, default=0.35,
-                   help="allowed fractional rise in per-stage chunk "
-                        "means (default 0.35)")
     p.add_argument("--phase-floor", type=float, default=0.02,
                    help="ignore phases below this fraction of total "
                         "phase time in the baseline (default 0.02)")
@@ -509,12 +409,6 @@ def main(argv=None) -> int:
                    help="allowed absolute drift (percentage points) in "
                         "any action's share of generated states "
                         "(default 5.0)")
-    p.add_argument("--launch-drift", type=float, default=0.25,
-                   help="allowed fractional rise in launches_per_chunk "
-                        "(and fall in per-stage achieved-bandwidth "
-                        "fraction) from the perf block (obs/perf.py; "
-                        "default 0.25).  Only gated when BOTH benches "
-                        "carry the block — legacy files fold to a note")
     p.add_argument("--pruned-drift", type=float, default=1.0,
                    help="allowed drop (percentage points) in the POR "
                         "pruned fraction (pruned/(pruned+generated)) "
@@ -577,18 +471,11 @@ def main(argv=None) -> int:
     diff_headline(old, new, d, args.max_regress)
     diff_phases(old, new, d, args.phase_max_regress, args.phase_floor)
     if om == "swarm":
-        # Swarm-dialect axes, then the shared perf/stage axes (swarm
-        # docs now embed a perf block and walk-kernel chunk_stages —
-        # the launch-drift and stage gates apply unchanged); the
-        # exhaustive pruned/coverage axes have no meaning for a walker
-        # and fall through as silent no-ops anyway.
+        # Swarm-dialect axes; the exhaustive pruned/coverage axes
+        # have no meaning for a walker.
         diff_swarm(old, new, d, args.max_regress)
         diff_hunt(old, new, d, args.hunt_drift)
-        diff_stages(old, new, d, args.stage_max_regress)
-        diff_perf(old, new, d, args.launch_drift)
         return d.render()
-    diff_stages(old, new, d, args.stage_max_regress)
-    diff_perf(old, new, d, args.launch_drift)
     diff_pruned(old, new, d, args.pruned_drift)
     diff_coverage(old, new, d, args.coverage_drift)
     return d.render()

@@ -93,13 +93,6 @@ def main(argv=None) -> int:
                    help="seed from the committed BENCH_r*/MULTICHIP_r* "
                         "files in DIR (default: repo root) before "
                         "rendering; idempotent by label")
-    p.add_argument("--perf", action="store_true",
-                   help="add the performance-observatory columns "
-                        "(launches/chunk + fusion-advisor pick, from "
-                        "each bench entry's embedded perf block) — the "
-                        "trajectory view of whether fusion work is "
-                        "retiring launches across rounds; entries "
-                        "predating the metric render '--'")
     p.add_argument("--hunt", action="store_true",
                    help="add the hunt-observatory columns (coverage "
                         "saturation + novelty rate + time-to-violation "
@@ -124,8 +117,7 @@ def main(argv=None) -> int:
     except (OSError, ValueError) as e:
         print(f"bench_history: {e}", file=sys.stderr)
         return 2
-    print(history_mod.render_table(entries, perf=args.perf,
-                                   hunt=args.hunt))
+    print(history_mod.render_table(entries, hunt=args.hunt))
     return 0
 
 

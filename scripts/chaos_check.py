@@ -87,10 +87,6 @@ def main(argv=None):
             "--queue-capacity", str(1 << 12),
             "--seen-capacity", str(1 << 15),
             "--max-diameter", str(args.max_diameter),
-            # Sparse chunk-stage sampling (observational, bit-identical
-            # on/off — tested): the killed child's postmortem must
-            # carry chunk-stage samples, not just progress.
-            "--profile-chunks", "4",
             "--progress-interval", "0"]
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)       # single-device children
@@ -155,9 +151,8 @@ def main(argv=None):
 
         # Flight-recorder gate (obs/flight.py): a hard-killed child must
         # leave its black box behind — postmortem.json next to the
-        # checkpoints, holding the last progress snapshot AND
-        # chunk-stage samples (ISSUE 9 acceptance), surfaced by the
-        # supervisor as a 'postmortem' event.
+        # checkpoints, holding the last progress snapshot, surfaced by
+        # the supervisor as a 'postmortem' event.
         if any(f.split("@")[0] == "kill" for f in args.faults.split(",")):
             pm_path = os.path.join(sup_dir, "postmortem.json")
             if not os.path.exists(pm_path):
@@ -169,7 +164,6 @@ def main(argv=None):
                     pm = json.load(f)
                 recs = pm.get("records") or {}
                 prog = recs.get("progress") or []
-                stages = recs.get("chunk_stage") or []
                 if not pm.get("reason", "").startswith("fault_kill"):
                     print(f"FAIL: postmortem reason {pm.get('reason')!r} "
                           f"is not the injected kill")
@@ -177,13 +171,9 @@ def main(argv=None):
                 elif not prog:
                     print("FAIL: postmortem has no progress snapshots")
                     ok = False
-                elif not stages:
-                    print("FAIL: postmortem has no chunk-stage samples")
-                    ok = False
                 else:
                     print(f"chaos: postmortem ok ({pm['reason']!r}, "
-                          f"{len(prog)} progress snapshot(s), "
-                          f"{len(stages)} chunk-stage sample(s), last "
+                          f"{len(prog)} progress snapshot(s), last "
                           f"distinct={prog[-1].get('distinct')})")
             if count_events(sup_log, "postmortem") < 1:
                 print("FAIL: supervisor surfaced no 'postmortem' event")

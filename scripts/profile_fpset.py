@@ -5,9 +5,8 @@ rounds vs while_loop); the old sorted-merge (full lax.sort) and
 binary-search probe — to decide which dedup design the TPU actually wants.
 
 These are per-call host-clock timings around ``block_until_ready``; for
-device timings that amortize dispatch use scripts/true_bench.py
-(fori_loop-chained iterations, host scalar fetch as the barrier) or an
-end-to-end engine run.
+device timings read a profiler capture of an end-to-end engine run
+(``--xla-profile``, ``benchmark/run.py --trace 1``).
 """
 
 import sys

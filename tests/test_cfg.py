@@ -287,6 +287,28 @@ def test_cli_flag_for_a_deleted_pipeline_is_rejected(dead, capsys):
     assert "auto, v1, v2" in err
 
 
+# -- the modelled-performance options (PR 46): refused by name ------------
+
+@pytest.mark.parametrize("dead", ["PERF", "PROFILE_CHUNKS"])
+def test_directive_for_a_deleted_option_is_rejected(dead, tmp_path):
+    cfgf = tmp_path / "dead.cfg"
+    cfgf.write_text(f"\\* TPU: {dead} = 1\n"
+                    "CONSTANT Server = {r1}\nCONSTANT Value = {v1}\n")
+    with pytest.raises(ValueError,
+                       match=f"unknown TPU backend key '{dead}'"):
+        load_config(str(cfgf))
+
+
+@pytest.mark.parametrize("dead", ["--perf", "--profile-chunks"])
+def test_cli_flag_for_a_deleted_option_is_rejected(dead, capsys):
+    from raft_tla_tpu.cli import main
+    with pytest.raises(SystemExit) as exc:
+        main(["check", os.path.join(REPO, "configs/MCraft_bounded.cfg"),
+              dead])
+    assert exc.value.code == 2
+    assert f"unrecognized arguments: {dead}" in capsys.readouterr().err
+
+
 # -- README.md names only what exists --------------------------------------
 
 def _readme():

@@ -225,12 +225,12 @@ def test_file_less_evlog_mirrors_into_flight():
 # ---------------------------------------------------------------------------
 # Engine integration
 
-def test_error_exit_writes_postmortem_with_progress_and_stages(tmp_path):
+def test_error_exit_writes_postmortem_with_progress(tmp_path):
     """The in-process half of the crash contract (the hard-kill half is
     scripts/chaos_check.py in CI, via the same dump machinery in
     faults._die): a run dying on an exception leaves postmortem.json
-    with the last progress snapshots and chunk-stage samples, and its
-    run_end event carries postmortem_path."""
+    with the last progress snapshots, and its run_end event carries
+    postmortem_path."""
     from raft_tla_tpu.resilience import faults
     ck = tmp_path / "states"
     ev = tmp_path / "e.jsonl"
@@ -238,7 +238,6 @@ def test_error_exit_writes_postmortem_with_progress_and_stages(tmp_path):
                     config=small_config(
                         checkpoint_dir=str(ck), events_out=str(ev),
                         checkpoint_interval_seconds=0.0,
-                        profile_chunks_every=1,
                         degrade_on_oom=False, max_diameter=6))
     faults.install("oom@level=2", hard=False)
     try:
@@ -251,7 +250,6 @@ def test_error_exit_writes_postmortem_with_progress_and_stages(tmp_path):
     doc = json.loads(open(pm_path).read())
     assert doc["reason"].startswith("run error:")
     assert doc["records"]["progress"], "no progress snapshots in dump"
-    assert doc["records"]["chunk_stage"], "no chunk-stage samples in dump"
     assert doc["context"]["engine"] == "BFSEngine"
     # run_end points at the dump; a postmortem event precedes it.
     events = validate_run_events(str(ev))

@@ -257,6 +257,30 @@ def test_mesh_progress_lines_emitted(capfd):
     assert "progress:" in err and "queue" in err
 
 
+def test_mesh_skew_telemetry_is_always_on(tmp_path):
+    """Per-shard balance telemetry (``_sample_skew``) needs no option:
+    balance gauges, skew fields on ``level_complete``, and (with a 1.0
+    threshold — any imbalance) ``skew`` warning events whose payload the
+    event validator holds."""
+    from raft_tla_tpu.obs import validate_run_events
+    ev = str(tmp_path / "mesh_events.jsonl")
+    eng = MeshBFSEngine(
+        DIMS, constraint=build_constraint(DIMS, BOUNDS),
+        config=small_mesh_config(max_diameter=2, events_out=ev,
+                                 skew_warn_ratio=1.0))
+    eng.run([init_state(DIMS)])
+    recs = validate_run_events(ev)
+    levels = [e for e in recs if e["event"] == "level_complete"]
+    assert any(e.get("frontier_skew") is not None for e in levels)
+    assert any(isinstance(e.get("shard_frontier"), list) for e in levels)
+    skews = [e for e in recs if e["event"] == "skew"]
+    assert skews, "threshold 1.0 must warn on any imbalance"
+    bal = skews[0]["balance"]
+    assert bal["frontier_skew"] >= 1.0
+    assert len(bal["shard_frontier"]) == eng.n_dev
+    assert "mesh/frontier_skew" in eng.metrics.snapshot()["gauges"]
+
+
 # -- one chunk call against the oracle, over four chips ---------------------
 
 def rebuilt_shards(eng, keys_hi, keys_lo, most=None):

@@ -390,11 +390,11 @@ def test_validate_chrome_trace_rejects(tmp_path):
 
 def test_validate_run_events_new_event_payloads(tmp_path):
     from raft_tla_tpu.obs import KNOWN_EVENTS
-    assert {"chunk_profile", "coverage"} <= set(KNOWN_EVENTS)
+    assert {"xla_profile", "coverage"} <= set(KNOWN_EVENTS)
     p = tmp_path / "ev.jsonl"
     ok = [{"event": "run_start", "ts": 0.0},
           {"event": "coverage", "ts": 1.0, "actions": {"Timeout": {}}},
-          {"event": "chunk_profile", "ts": 2.0, "stages": {}},
+          {"event": "xla_profile", "ts": 2.0, "capture": {}},
           {"event": "run_end", "ts": 3.0}]
     p.write_text("".join(json.dumps(e) + "\n" for e in ok))
     assert len(validate_run_events(str(p))) == 4
@@ -405,15 +405,31 @@ def test_validate_run_events_new_event_payloads(tmp_path):
     with pytest.raises(ValueError, match="actions"):
         validate_run_events(str(p))
     bad = list(ok)
-    bad[2] = {"event": "chunk_profile", "ts": 2.0, "stages": 7}
+    bad[2] = {"event": "xla_profile", "ts": 2.0, "capture": 7}
     p.write_text("".join(json.dumps(e) + "\n" for e in bad))
-    with pytest.raises(ValueError, match="stages"):
+    with pytest.raises(ValueError, match="capture"):
         validate_run_events(str(p))
 
 
+def test_skew_event_requires_its_payload(tmp_path):
+    """The validator's schema table knows the mesh's ``skew`` warning: a
+    record without its ``balance`` object is a malformed log."""
+    def log(skew):
+        p = tmp_path / "ev.jsonl"
+        p.write_text("".join(json.dumps(e) + "\n" for e in (
+            {"event": "run_start", "ts": 1.0}, skew,
+            {"event": "run_end", "ts": 3.0})))
+        return str(p)
+    with pytest.raises(ValueError, match="balance"):
+        validate_run_events(log({"event": "skew", "ts": 2.0}))
+    assert len(validate_run_events(log(
+        {"event": "skew", "ts": 2.0,
+         "balance": {"frontier_skew": 3.0}}))) == 3
+
+
 # ---------------------------------------------------------------------------
-# Deep-profiling integration: --trace-out spans + --profile-chunks stage
-# accounting + coverage, through a real (small) engine run.
+# Deep-profiling integration: --trace-out spans + coverage, through a
+# real (small) engine run.
 
 def test_engine_trace_profile_coverage_end_to_end(tmp_path):
     from raft_tla_tpu.obs import validate_chrome_trace
@@ -423,7 +439,7 @@ def test_engine_trace_profile_coverage_end_to_end(tmp_path):
     eng = BFSEngine(DIMS, constraint=build_constraint(DIMS, BOUNDS),
                     config=small_config(
                         max_diameter=3, events_out=ev, trace_out=trace,
-                        profile_chunks_every=1, metrics=mt))
+                        metrics=mt))
     res = eng.run([init_state(DIMS)])
 
     # -- Chrome trace: valid array, a span per level, >=1 chunk span,
@@ -435,27 +451,9 @@ def test_engine_trace_profile_coverage_end_to_end(tmp_path):
     runs = [e for e in events if e["name"] == "run"]
     assert len(runs) == 1 and runs[0]["ph"] == "X"
 
-    # -- Profiler: per-stage histograms in the registry, consistent
-    #    with the result's stage means (the wall-time-closure claim has
-    #    its own post-compile test below — phase/profile here includes
-    #    the stage programs' compile).
-    snap = mt.snapshot()
-    from raft_tla_tpu.obs.profile import STAGES
-    hists = snap["histograms"]
-    samples = hists["chunk_stage/total"]["count"]
-    assert samples >= 1
-    for s in STAGES:
-        assert hists[f"chunk_stage/{s}"]["count"] == samples
-        assert abs(hists[f"chunk_stage/{s}"]["total"] / samples
-                   - res.chunk_stages[s]) < 1e-9
-    assert set(res.chunk_stages) == set(STAGES) | {"total"}
-    assert hists["phase/profile"]["total"] > 0
-
-    # -- chunk_profile event with its stages payload.
+    # -- The spans' phases land in the shared registry too.
+    assert mt.snapshot()["histograms"]["phase/chunk"]["count"] >= 1
     recs = validate_run_events(ev)
-    prof_evs = [e for e in recs if e["event"] == "chunk_profile"]
-    assert len(prof_evs) == 1
-    assert set(prof_evs[0]["stages"]) == set(STAGES)
 
     # -- Coverage: per-family generated matches action_counts EXACTLY
     #    (one packed-stats source), distinct partitions distinct minus
@@ -475,23 +473,15 @@ def test_engine_trace_profile_coverage_end_to_end(tmp_path):
     assert len(end["devices_memory"]) >= 1
 
 
-def test_profiling_is_observational(tmp_path):
-    """Engine results are bit-identical with profiling on or off (the
-    acceptance contract: the profiler re-expands samples on the side)."""
-    plain = BFSEngine(DIMS, constraint=build_constraint(DIMS, BOUNDS),
-                      config=small_config(max_diameter=3))
-    res0 = plain.run([init_state(DIMS)])
-    prof = BFSEngine(DIMS, constraint=build_constraint(DIMS, BOUNDS),
-                     config=small_config(
-                         max_diameter=3,
-                         trace_out=str(tmp_path / "t.json"),
-                         profile_chunks_every=1))
-    res1 = prof.run([init_state(DIMS)])
-    assert (res0.distinct, res0.generated, res0.levels) \
-        == (res1.distinct, res1.generated, res1.levels)
-    assert res0.action_counts == res1.action_counts
-    assert res0.coverage == res1.coverage
-    assert res1.chunk_stages and not res0.chunk_stages
+def test_every_level_row_carries_the_hbm_watermark():
+    """The per-level device-HBM watermark needs no option: the field is
+    on every ``level_stats`` row (None on CPU devices, which report no
+    memory stats — present either way)."""
+    eng = BFSEngine(DIMS, constraint=build_constraint(DIMS, BOUNDS),
+                    config=small_config(max_diameter=2))
+    res = eng.run([init_state(DIMS)])
+    assert res.level_stats
+    assert all("hbm_peak_bytes" in row for row in res.level_stats)
 
 
 def test_coverage_events_on_progress_interval(tmp_path, capsys):
@@ -514,66 +504,6 @@ def test_coverage_events_on_progress_interval(tmp_path, capsys):
     assert "fpset load" in err                # enriched progress line
 
 
-def test_stage_sum_accounts_for_staged_wall(monkeypatch):
-    """The fencing does not distort the decomposition: the fenced
-    per-stage intervals PARTITION the staged wall — every interval from
-    the first dispatch to the last stage's fence lands in exactly one
-    stage, none dropped and none counted twice, and each fence blocks
-    once.  Counted on a tick clock, not timed: a wall-clock comparison
-    of two separately timed loops does not hold under six test workers
-    (the fused ``total`` row legitimately differs anyway: XLA elides
-    inter-stage materialization)."""
-    import types
-
-    import jax
-    import numpy as np
-
-    from raft_tla_tpu.obs import profile as profile_mod
-    from raft_tla_tpu.obs.profile import STAGES, ChunkProfiler
-    from raft_tla_tpu.models.schema import encode_state, flatten_state
-
-    B, K, CAP, N = 64, 1024, 1 << 12, 3
-    root = np.asarray(
-        flatten_state(encode_state(init_state(DIMS), DIMS), DIMS))
-    rows = np.tile(root, (B, 1))
-    valid = np.ones((B,), bool)
-
-    ticks = {"clock": 0, "fences": 0}
-
-    def tick():
-        ticks["clock"] += 1
-        return float(ticks["clock"])
-
-    real_block = jax.block_until_ready
-
-    def counting_block(x):
-        ticks["fences"] += 1
-        return real_block(x)
-
-    monkeypatch.setattr(profile_mod, "time",
-                        types.SimpleNamespace(perf_counter=tick))
-    monkeypatch.setattr(jax, "block_until_ready", counting_block)
-
-    prof = ChunkProfiler(DIMS, batch=B, lanes=K, seen_capacity=CAP)
-    prof.sample(rows, valid)      # builds + compiles (its own fences)
-    prof.reset()
-    ticks.update(clock=0, fences=0)
-    for _ in range(N):
-        prof.sample(rows, valid)
-    assert prof.samples == N
-    # One clock read to start a sample, then one per stage fence and
-    # one for the fused total: nothing else reads the clock, so the
-    # stage intervals tile [start, last stage fence] with no gap.
-    per_sample = 1 + len(STAGES) + 1
-    assert ticks["clock"] == N * per_sample
-    assert ticks["fences"] == N * (len(STAGES) + 1)
-    means = prof.stage_means()
-    assert set(means) == set(STAGES) | {"total"}
-    assert all(means[s] == 1.0 for s in STAGES)
-    assert sum(means[s] for s in STAGES) == float(len(STAGES))
-    assert means["total"] == 1.0
-
-
 def test_warm_engine_trace_resets_per_run(tmp_path):
     """A reused engine's second run rewrites the trace as ONE run —
     tracer.reset() at run start, not append (one trace file = one run)."""
@@ -586,3 +516,43 @@ def test_warm_engine_trace_resets_per_run(tmp_path):
     events = validate_chrome_trace(trace)
     assert sum(1 for e in events if e["name"] == "run") == 1
     assert sum(1 for e in events if e["name"] == "trace_start_unix") == 1
+
+
+# ---------------------------------------------------------------------------
+# The observational options do not reach the program: the static form of
+# "bit-identical with X on or off".  A run-twice test holds it for the
+# states one small run visits; the lowered text holds it for the program.
+
+def chunk_text(**option) -> str:
+    eng = BFSEngine(DIMS, constraint=build_constraint(DIMS, BOUNDS),
+                    config=small_config(**option))
+    return eng._chunk.lower(*eng.chunk_avals()).as_text()
+
+
+@pytest.fixture(scope="module")
+def default_chunk_text():
+    return chunk_text()
+
+
+@pytest.mark.parametrize("option", [
+    lambda tmp: {"events_out": str(tmp / "e.jsonl")},
+    lambda tmp: {"trace_out": str(tmp / "t.json")},
+    lambda tmp: {"metrics": MetricsRegistry()},
+    lambda tmp: {"xla_profile_chunks": 2,
+                 "xla_profile_dir": str(tmp / "xla")},
+    lambda tmp: {"statespace_report": False},
+    lambda tmp: {"postmortem_dir": str(tmp)},
+    lambda tmp: {"run_context_extra": {"job_id": "j1", "tenant": "t"}},
+    lambda tmp: {"progress_interval_seconds": 1e-6},
+    lambda tmp: {"counterexample_dir": str(tmp)},
+    lambda tmp: {"keep_checkpoints": 2},
+    lambda tmp: {"skew_warn_ratio": 1.0},
+    lambda tmp: {"trace_merge_timeout_seconds": 5.0},
+], ids=["events_out", "trace_out", "metrics", "xla_profile",
+        "statespace_report_off", "postmortem_dir", "run_context_extra",
+        "progress_interval_seconds", "counterexample_dir",
+        "keep_checkpoints", "skew_warn_ratio",
+        "trace_merge_timeout_seconds"])
+def test_an_observational_option_leaves_the_chunk_program_as_it_is(
+        option, tmp_path, default_chunk_text):
+    assert chunk_text(**option(tmp_path)) == default_chunk_text

@@ -116,8 +116,6 @@ def test_cli_server_and_bench_build_the_same_engine(tmp_path, monkeypatch,
                  "BENCH_RING", "BENCH_CHUNK", "BENCH_PIPELINE"):
         monkeypatch.delenv(name, raising=False)
     monkeypatch.setenv("BENCH_NUM_STEPS", "32")
-    monkeypatch.setenv("BENCH_PERF", "0")
-    monkeypatch.setenv("BENCH_PROFILE_CHUNKS", "0")
     monkeypatch.setenv("BENCH_EVENTS_OUT", str(tmp_path / "bench.jsonl"))
     bench._swarm_bench(load_config(str(cfg)), "cpu")
     capsys.readouterr()

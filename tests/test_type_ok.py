@@ -46,6 +46,19 @@ def _kernel(cfg):
 
 
 @functools.lru_cache(maxsize=None)
+def _named(cfg):
+    """``build_inv_id`` over a batch, jitted as every engine runs it.  Run
+    eagerly under ``vmap``, its ``jnp.int32(...)`` constants reach the
+    process-wide ``convert_element_type`` callable from inside a batch
+    trace, jax keeps no fast path for a call made there, and where that
+    is the process's FIRST such call the signature stays on pjit's
+    Python path for good: every later ``jnp.int32(0)`` of any engine in
+    this worker then counts as a trace (tests/test_spans.py's warm run
+    read 33 of them whenever this file ran before it)."""
+    return jax.jit(jax.vmap(build_inv_id([_kernel(cfg)])))
+
+
+@functools.lru_cache(maxsize=None)
 def _walk(dims, steps=40, seeds=(1, 2, 3)):
     """States of a few random walks from the initial state, kept inside
     what a row can hold (term and bag under the pack guard's limits)."""
@@ -159,7 +172,7 @@ def test_a_state_that_breaks_one_check_reads_false(cfg, name, check, field,
     batch = stack_states([*sound[:3], bad, *sound[3:], base])
     want = [True] * 3 + [False] + [True] * (len(sound) - 3) + [True]
     assert np.asarray(jax.vmap(type_ok)(batch)).tolist() == want
-    named = jax.vmap(build_inv_id([type_ok]))(batch)
+    named = _named(cfg)(batch)
     assert np.asarray(named).tolist() == [-1 if ok else 0 for ok in want]
 
 
