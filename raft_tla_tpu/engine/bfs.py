@@ -84,9 +84,13 @@ _I32 = jnp.int32
 # ``run_end`` carries a run's share, ``level_complete`` each level's.
 # ``inv_lanes``: compacted lanes the chunk evaluated the invariants on,
 # K a pass on every chip (duplicates and empty lanes included; TLC
-# evaluates an invariant once a new distinct state).
+# evaluates an invariant once a new distinct state).  ``deadline_calls``
+# and ``probe_calls``: chunk calls of a budgeted run whose size the
+# time left set, and those of one batch made for want of an estimate
+# (``budget_call_size``).
 WORK_COUNTERS = ("chunk_calls", "passes", "inv_lanes", "ingest_calls",
-                 "parents_expanded", "flush_overlapped", "flush_drained")
+                 "parents_expanded", "flush_overlapped", "flush_drained",
+                 "deadline_calls", "probe_calls")
 
 
 def work_counts(metrics, base: Optional[dict] = None) -> dict:
@@ -94,6 +98,26 @@ def work_counts(metrics, base: Optional[dict] = None) -> dict:
     base = base or {}
     return {k: int(metrics.counter_value("engine/" + k)) - base.get(k, 0)
             for k in WORK_COUNTERS}
+
+
+def budget_call_size(ch: int, remaining: float, batch_ema: float,
+                     calls_in_level: int) -> Tuple[int, str]:
+    """Batches the next chunk call of a duration-budgeted run may take,
+    and the rule that set them.  With no cost estimate yet, ``probe``:
+    one batch, so the first call cannot blow the deadline by a whole
+    ``sync_every`` chunk.  Else half the time left at the measured cost
+    a batch (``deadline``), under the per-level slow-start ramp, which
+    starts at 2 batches so that the call's host round trip amortizes
+    over the probe and does not lock the (jump-up, decay-slow) estimator
+    at a cost the round trip dominates (``ramp``, which also names a
+    call that ``sync_every`` capped)."""
+    if not batch_ema:
+        return 1, "probe"
+    ramp = min(ch, 2 << min(calls_in_level, 9))
+    deadline = int(remaining / (2 * batch_ema))
+    if deadline < ramp:
+        return max(1, deadline), "deadline"
+    return ramp, "ramp"
 
 
 # -- compiles by span ------------------------------------------------------
@@ -1071,13 +1095,14 @@ class BFSEngine:
                 self._xla_profile_dir(), cfg.xla_profile_chunks)
         else:
             self._xla_capture = None
+        self._run_roots = len(init_states or ())
         evlog.emit(
             "run_start", engine=type(self).__name__, dims=repr(self.dims),
             batch=cfg.batch, sync_every=cfg.sync_every,
             record_trace=cfg.record_trace, resume=resume is not None,
             # States the run was given: 1 from Init, more from roots past
             # it; 0 on a resume, whose snapshot brings its own.
-            roots=len(init_states or ()),
+            roots=self._run_roots,
             memory=device_memory_stats(),
             process=process_record().run_start())
         self._cur_res = None
@@ -1201,6 +1226,7 @@ class BFSEngine:
                 # supervised vs. uninterrupted runs on this field.
                 levels=list(getattr(res, "levels", None) or []),
                 wall_seconds=getattr(res, "wall_seconds", None),
+                **self._budget_fields(res),
                 growth_stalls=len(getattr(res, "growth_stalls", ())),
                 phase_seconds=phases,
                 # Counted in the loop (WORK_COUNTERS), and the compiles
@@ -1220,6 +1246,18 @@ class BFSEngine:
             _FLIGHT.disarm()     # completed or already-dumped: no atexit dump
             evlog.close()
             self._evlog = RunEventLog(None)
+
+    def _budget_fields(self, res) -> dict:
+        """``run_end``'s ``roots`` (the states the run was given, as
+        ``run_start`` has them), ``budget_s`` (the duration budget it ran
+        under, None without one) and ``budget_overshoot_s`` (the wall
+        past that budget of a run the budget stopped, else None)."""
+        budget = self.config.max_seconds
+        over = None
+        if getattr(res, "stop_reason", None) == "duration_budget":
+            over = round(res.wall_seconds - budget, 6)
+        return {"roots": self._run_roots, "budget_s": budget,
+                "budget_overshoot_s": over}
 
     def _generated_by_family(self, res) -> dict:
         """Successors this run generated, by action family: the packed
@@ -1738,22 +1776,16 @@ class BFSEngine:
                         if remaining <= 0:
                             res.stop_reason = "duration_budget"
                             break
-                        if self._batch_ema:
-                            # Half the remaining budget per call, capped
-                            # by the per-level slow-start ramp.  The ramp
-                            # starts at 2 batches so the per-call host
-                            # round-trip amortizes over the probe and
-                            # does not lock the (jump-up, decay-slow)
-                            # estimator at RTT-dominated cost.
-                            allowed = max(1, min(
-                                self._CH,
-                                int(remaining / (2 * self._batch_ema)),
-                                2 << min(calls_in_level, 9)))
-                        else:
-                            # No cost estimate yet: probe with one batch
-                            # so the first call can't blow the deadline
-                            # by a whole sync_every chunk.
-                            allowed = 1
+                        allowed, rule = budget_call_size(
+                            self._CH, remaining, self._batch_ema,
+                            calls_in_level)
+                        if rule == "probe":
+                            mt.counter("engine/probe_calls")
+                        elif rule == "deadline" and allowed * B \
+                                < cur_count - offset:
+                            # The time left, and not the level's end,
+                            # cut this call short.
+                            mt.counter("engine/deadline_calls")
                     calls_in_level += 1
                     if _faults.ACTIVE:
                         # Deterministic injection sites (resilience/):

@@ -173,8 +173,11 @@ def make_swarm_engine(setup: CheckSetup, *, walks: Optional[int] = None,
 
 def initial_states(setup: CheckSetup, seed: int = 0) -> List[PyState]:
     if setup.smoke:
-        return smoke.smoke_init_states(setup.dims, k=setup.smoke_k,
-                                       seed=seed)
+        # The draw of the nine k-subsets and the bag, and their product:
+        # what a smoke check pays on the host before its first state.
+        with process_span("smoke_roots", k=setup.smoke_k, seed=seed):
+            return smoke.smoke_init_states(setup.dims, k=setup.smoke_k,
+                                           seed=seed)
     return [init_state(setup.dims)]
 
 

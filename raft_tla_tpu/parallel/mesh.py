@@ -63,8 +63,8 @@ from ..engine.chunk import build_chunk_body, tag_stages
 from ..engine.replay import ReplayScan
 from ..engine.bfs import (BFSEngine, EngineConfig, EngineResult, TraceStore,
                           Violation, _exit_condition_hit, _family_groups_meta,
-                          _progress_line, _TraceFlush, build_root_check,
-                          fetch_lengths, find_root_violation,
+                          _progress_line, _TraceFlush, budget_call_size,
+                          build_root_check, fetch_lengths, find_root_violation,
                           make_trace_store, watch_compiles)
 from ..models.actions import build_expand
 from ..models.dims import RaftDims
@@ -774,6 +774,7 @@ class MeshBFSEngine:
     _close_level_span = BFSEngine._close_level_span
     _count_chunk_call = BFSEngine._count_chunk_call
     _generated_by_family = BFSEngine._generated_by_family
+    _budget_fields = BFSEngine._budget_fields
     _note_family_base = BFSEngine._note_family_base
     _replay = BFSEngine._replay
     _replay_step = BFSEngine._replay_step
@@ -1287,16 +1288,11 @@ class MeshBFSEngine:
                     if cfg.max_seconds is not None:
                         remaining = cfg.max_seconds - (time.time() - t0)
                         over = remaining <= 0
-                        if self._batch_ema:
-                            # Half-window sizing + per-level slow-start
-                            # (engine/bfs.py rationale)
-                            allowed = max(1, min(
-                                self._CH,
-                                int(remaining / (2 * self._batch_ema)),
-                                2 << min(calls_in_level, 9)))
-                        else:
-                            allowed = 1    # no estimate yet: probe batch
-                                           # (engine/bfs.py rationale)
+                        # Half-window sizing + per-level slow-start
+                        # (engine/bfs.py budget_call_size)
+                        allowed, rule = budget_call_size(
+                            self._CH, remaining, self._batch_ema,
+                            calls_in_level)
                         if budget_agree is not None:
                             # allowed is an input to a collective program:
                             # all controllers must pass the same value —
@@ -1307,6 +1303,11 @@ class MeshBFSEngine:
                         if over:
                             res.stop_reason = "duration_budget"
                             break
+                        if rule != "ramp":
+                            # The level's width is the program's to
+                            # know: a deadline call here may be one the
+                            # level's end cut shorter still.
+                            mt.counter(f"engine/{rule}_calls")
                     calls_in_level += 1
                     if _faults.ACTIVE:
                         # Same deterministic sites as the single-chip

@@ -17,7 +17,7 @@ def reference():
 
     The reference (lemmy/raft.tla + TLC harness configs) is mounted at
     /root/reference on the primary dev host but absent in plain CI /
-    test containers; the four tests that parse the REAL reference files
+    test containers; the tests that parse the REAL reference files
     skip there with this reason instead of failing tier-1.  Everything
     those tests cover structurally is still exercised against the
     committed configs/ copies by the rest of this module."""
@@ -38,9 +38,17 @@ def test_parse_mcraft_cfg(reference):
     assert s.bounds.max_term is None   # MCraft.cfg is unbounded
 
 
-def test_parse_smokeraft_cfg(reference):
-    s = load_config(f"{reference}/Smokeraft.cfg")
+SMOKERAFT = os.path.join(REPO, "configs/Smokeraft.cfg")
+
+
+def test_parse_smokeraft_cfg():
+    """The repository's copy of upstream's smoke test (the text is this
+    repo's, from SURVEY.md's account: the file says so)."""
+    s = load_config(SMOKERAFT)
     assert s.dims.n_servers == 3 and s.dims.n_values == 2
+    assert s.invariants == ["TypeOK"]
+    assert s.bounds.max_term is None and s.bounds.max_log_len is None
+    assert s.dims.max_log == 12     # no MaxLogLen: the loader's own
     assert s.smoke and s.smoke_k == 2          # Smokeraft.tla:17-19
     assert s.max_seconds == 1.0                # TLCGet("duration") > 1
     assert s.max_diameter == 100               # TLCGet("diameter") > 100
@@ -240,8 +248,8 @@ def test_distinct_budget_constraint_loads(tmp_path):
     assert s.max_seconds is None and s.max_diameter is None
 
 
-def test_smokeraft_stopafter_still_routes_to_native_budgets(reference):
-    s = load_config(f"{reference}/Smokeraft.cfg")
+def test_smokeraft_stopafter_still_routes_to_native_budgets():
+    s = load_config(SMOKERAFT)
     assert s.max_seconds == 1.0 and s.max_diameter == 100
     assert s.exit_conditions == ()
 

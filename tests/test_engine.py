@@ -297,21 +297,16 @@ def test_order_independence_of_exploration():
 
 
 def test_smokeraft_cfg_end_to_end():
-    """The reference Smokeraft.cfg (randomized init, StopAfter budgets,
-    CHECK_DEADLOCK FALSE) runs unmodified through the cfg front-end and the
-    engine: budget stop (or exhaustion of the random slice) with nonzero
-    distinct states and no violation."""
+    """The repository's copy of upstream's smoke test, configs/Smokeraft.cfg
+    (randomized init, StopAfter budgets, CHECK_DEADLOCK FALSE), runs as it
+    stands through the cfg front-end and the engine: budget stop (or
+    exhaustion of the random slice) with nonzero distinct states and no
+    violation."""
     import os
-    import pytest
-    if not os.path.isdir("/root/reference"):
-        # Same rule as tests/test_cfg.py's ``reference`` fixture: the
-        # reference checkout is absent in plain containers — skip with
-        # the reason, don't fail tier-1.
-        pytest.skip("reference specs not mounted (/root/reference absent "
-                    "in this container)")
     from raft_tla_tpu.engine.check import run_check
-    res = run_check("/root/reference/Smokeraft.cfg",
-                    engine_config=small_config(batch=128))
+    cfg = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "configs", "Smokeraft.cfg")
+    res = run_check(cfg, engine_config=small_config(batch=128))
     assert res.violation is None
     assert res.distinct > 0
     assert res.stop_reason in ("duration_budget", "diameter_budget",

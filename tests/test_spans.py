@@ -234,7 +234,9 @@ def test_loop_counters_equal_a_hand_count(noleader, tmp_path):
         "chunk_calls": 8, "passes": 8, "inv_lanes": 8 * eng._K,
         "ingest_calls": 2,
         "parents_expanded": 142, "flush_overlapped": 0,
-        "flush_drained": 8}
+        "flush_drained": 8,
+        # No duration budget: no call is a probe or sized by a deadline.
+        "deadline_calls": 0, "probe_calls": 0}
 
 
 def test_deeper_levels_take_the_passes_their_frontier_needs(verdict):
