@@ -60,8 +60,8 @@ from ..models.invariants import build_inv_id
 from ..models.pystate import PyState
 from ..models.schema import (ROW_DTYPE, StateBatch, build_pack_guard,
                              check_packable, decode_message, decode_state,
-                             encode_state, flatten_state, state_width,
-                             unflatten_state)
+                             encode_state, flatten_states, stack_states,
+                             state_width, unflatten_state)
 from ..obs import (ActionCoverage, MetricsRegistry, RunEventLog,
                    SpanTracer, all_device_memory_stats,
                    device_memory_stats, events_path, peak_host_rss_bytes,
@@ -620,17 +620,17 @@ def _family_groups_meta(dims, _v2=None):
         return []
 
 
-def find_root_violation(root_check, encoded, init_states, batch_size,
+def find_root_violation(root_check, roots, init_states, batch_size,
                         inv_names) -> Optional[Violation]:
-    """Run ``build_root_check``'s program over the encoded roots in
-    fixed-size chunks (padding by repeating the last root so one program
-    shape serves any root count); first violation wins, like TLC."""
-    from ..models.schema import stack_states
-    for base in range(0, len(encoded), batch_size):
-        chunk = encoded[base:base + batch_size]
-        pad = [chunk[-1]] * (batch_size - len(chunk))
-        inv, fph, fpl = root_check(stack_states(chunk + pad))
-        inv = np.asarray(inv)[:len(chunk)]
+    """Run ``build_root_check``'s program over the stacked encoded roots
+    (``stack_states``) in fixed-size chunks (padding by repeating the
+    last root so one program shape serves any root count); first
+    violation wins, like TLC."""
+    n = len(init_states)
+    for base in range(0, n, batch_size):
+        at = np.minimum(np.arange(base, base + batch_size), n - 1)
+        inv, fph, fpl = root_check(StateBatch(*(x[at] for x in roots)))
+        inv = np.asarray(inv)[:n - base]
         if (inv >= 0).any():
             i = int(np.argmax(inv >= 0))
             fp = (int(np.asarray(fph)[i]) << 32) | int(np.asarray(fpl)[i])
@@ -925,11 +925,6 @@ class BFSEngine:
                 jax.lax.dynamic_slice(x, (start,), (length,))
                 for x in tbuf),
             static_argnums=2)
-        # The last frontier's fetch (``_fetch_frontier``).
-        self._frontier_rows = min(1 << 16, Q + PAD)
-        self._frontier_piece = jax.jit(
-            lambda q, start: jax.lax.dynamic_slice_in_dim(
-                q, start, self._frontier_rows, axis=0))
         self._fp_rows = jax.jit(fp_rows)
         # The replay: a whole trace in one call (engine/replay.py), and
         # the per-step matcher's two programs for what it cannot hold.
@@ -1454,10 +1449,12 @@ class BFSEngine:
             # Root handling before warm-up: neither the root check's XLA
             # compile nor a violating root charges the duration budget (TLC
             # reports an init-state violation without starting the clock).
-            encoded = [encode_state(s, dims) for s in init_states]
+            with mt.phase_timer("roots_encode"):
+                roots = stack_states(
+                    [encode_state(s, dims) for s in init_states])
             if self._root_check is not None:
                 with mt.phase_timer("root_check"):
-                    v = find_root_violation(self._root_check, encoded,
+                    v = find_root_violation(self._root_check, roots,
                                             init_states, B, self.inv_names)
                 if v is not None:
                     if cfg.record_trace:
@@ -1475,9 +1472,9 @@ class BFSEngine:
                     return res
             # Only now reject unpackable roots (see schema.check_packable:
             # an invariant-flagged root is a violation, not an error).
-            for e in encoded:
-                check_packable(e, self.dims)
-            rows_np = np.stack([flatten_state(e, dims) for e in encoded])
+            with mt.phase_timer("roots_encode"):
+                check_packable(roots, dims)
+                rows_np = flatten_states(roots, dims)
             # Root fingerprints for the trace store — computed (and their
             # program compiled) BEFORE the duration clock starts; root
             # registration is setup, like the warm-up below.
@@ -1580,7 +1577,6 @@ class BFSEngine:
             if cfg.record_trace:
                 for length in self._fetch_lens:
                     self._fetch(tbuf, np.int32(0), length)
-            self._frontier_piece(qcur, np.int32(0))
         flush = _TraceFlush(self, trace)
         t0 = time.time()
         last_progress = t0
@@ -1999,29 +1995,7 @@ class BFSEngine:
             pending, spill_next = spill_next, pending
 
         res.wall_seconds = time.time() - t0
-        # Final frontier snapshot (empty when exhausted): profiling tools
-        # use it as a representative mid-level workload.
-        with mt.phase_timer("frontier_fetch"):
-            self._last_frontier = self._fetch_frontier(qcur, cur_count)
         return res
-
-    def _fetch_frontier(self, qcur, count: int) -> np.ndarray:
-        """Rows ``[0, count)`` of a level queue on the host, in pieces of
-        one fixed shape (``_frontier_piece``, compiled in warm-up): a
-        slice at the frontier's own length is a compile of its own at
-        every new length, inside the duration budget."""
-        rows, qa = self._frontier_rows, qcur.shape[0]
-        pieces = []
-        for start in range(0, count, rows):
-            # A piece that would pass the queue's end starts earlier.
-            at = min(start, qa - rows)
-            piece = self._frontier_piece(qcur, np.int32(at))
-            piece.copy_to_host_async()
-            pieces.append((piece, start - at, min(rows, count - start)))
-        if not pieces:
-            return np.zeros((0, self._sw), ROW_DTYPE)
-        return np.concatenate([np.asarray(piece)[lo:lo + n]
-                               for piece, lo, n in pieces])
 
     # ------------------------------------------------------------------
     def replay(self, fp: int) -> List[Tuple[int, PyState]]:

@@ -36,7 +36,8 @@ from ..models.pystate import PyState
 from ..obs import MetricsRegistry
 from ..models.schema import (StateBatch, build_pack_guard, check_packable,
                              decode_state, encode_state, flatten_state,
-                             stack_states, state_width, unflatten_state)
+                             flatten_states, stack_states, state_width,
+                             unflatten_state)
 
 _I32 = jnp.int32
 
@@ -182,8 +183,8 @@ class Simulator:
         immediately; otherwise reject silently-aliasing roots and return
         the packed root rows."""
         dims = self.dims
-        encoded = [encode_state(s, dims) for s in roots]
-        rinv = np.asarray(self._roots_inv(stack_states(encoded)))
+        stacked = stack_states([encode_state(s, dims) for s in roots])
+        rinv = np.asarray(self._roots_inv(stacked))
         if (rinv >= 0).any():
             idx = int(np.argmax(rinv >= 0))
             res.violation_state = roots[idx]
@@ -191,9 +192,8 @@ class Simulator:
             res.violation_invariant = self.inv_names[int(rinv[idx])]
             res.wall_seconds = time.time() - t0
             return None
-        for e in encoded:
-            check_packable(e, self.dims)
-        return np.stack([flatten_state(e, dims) for e in encoded])
+        check_packable(stacked, dims)
+        return flatten_states(stacked, dims)
 
     def run(self, roots: List[PyState], num_steps: int, seed: int = 0,
             max_seconds: Optional[float] = None) -> SimResult:

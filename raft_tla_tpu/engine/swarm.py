@@ -56,7 +56,7 @@ from ..models.invariants import build_inv_id
 from ..models.pystate import PyState
 from ..models.schema import (build_pack_guard, check_packable,
                              decode_state, encode_state, flatten_state,
-                             stack_states, unflatten_state)
+                             flatten_states, stack_states, unflatten_state)
 from ..obs import (MetricsRegistry, RunEventLog, SpanTracer,
                    device_memory_stats, events_path, phase_delta)
 from ..obs.flight import RECORDER as _FLIGHT
@@ -677,7 +677,8 @@ class SwarmEngine:
         root ends the run immediately with a length-1 trace."""
         dims = self.dims
         encoded = [encode_state(s, dims) for s in roots]
-        rinv = np.asarray(self._roots_inv(stack_states(encoded)))
+        stacked = stack_states(encoded)
+        rinv = np.asarray(self._roots_inv(stacked))
         if (rinv >= 0).any():
             idx = int(np.argmax(rinv >= 0))
             hi, lo = self._fp1(encoded[idx])
@@ -690,9 +691,8 @@ class SwarmEngine:
             res.stop_reason = "violation"
             res.violation_at_seconds = 0.0
             return None
-        for e in encoded:
-            check_packable(e, self.dims)
-        return np.stack([flatten_state(e, dims) for e in encoded])
+        check_packable(stacked, dims)
+        return flatten_states(stacked, dims)
 
     def _init_slices(self, roots, res, seed_j, dev):
         """(roots on the device, slices, the hunt's two filters), or

@@ -135,6 +135,9 @@ def decode_message(w: np.ndarray, dims: RaftDims) -> tuple:
 
 def check_packable(st: "StateBatch", dims: "RaftDims") -> None:
     """Raise if any field value cannot round-trip the uint8 row packing.
+    ``st`` is one state or a batch of roots (``stack_states``: one
+    leading axis); of a batch the error names the first root at fault
+    by its number, then what it names of a single state.
 
     Host-side, roots only; kernel-produced successors are guarded by
     ``build_pack_guard``.  Engines call this *after* the pre-pack root
@@ -152,23 +155,32 @@ def check_packable(st: "StateBatch", dims: "RaftDims") -> None:
     # just a raw lane index.  Import-light by design (no jax, no cycle).
     from ..analysis import lane_map
     caps = lane_map.lane_capacities(dims)
-    for name, arr in zip(StateBatch._fields, st):
-        a = np.asarray(arr)
-        if a.size == 0:
-            continue
-        lo_col, hi_col = caps[name]     # 'msg': per-column [W] arrays
-        bad = (a < lo_col) | (a > hi_col)
-        if bad.any():
-            idx = tuple(int(i) for i in np.argwhere(bad)[0])
-            if name == "msg":
-                lo_b, hi_b = int(lo_col[idx[-1]]), int(hi_col[idx[-1]])
-            else:
-                lo_b, hi_b = int(lo_col), int(hi_col)
-            raise ValueError(
-                f"value {int(a[idx])} at {lane_map.describe_lane(name, idx, dims)} "
-                f"is outside the packable range [{lo_b}, {hi_b}] "
-                f"(uint8 row packing would alias it silently; "
-                f"{int(bad.sum())} offending element(s) total)")
+    st = StateBatch(*(np.asarray(x) for x in st))
+    # 'msg': per-column [W] bounds, broadcast over the slots.
+    bad = StateBatch(*((a < caps[f][0]) | (a > caps[f][1])
+                       for f, a in zip(StateBatch._fields, st)))
+    if not any(b.any() for b in bad):
+        return
+    where = ""
+    if st.term.ndim == 2:
+        root = int(np.argmax(np.any(
+            [b.reshape(len(b), -1).any(axis=1) for b in bad], axis=0)))
+        where = f"root {root}: "
+        st, bad = (StateBatch(*(x[root] for x in t)) for t in (st, bad))
+    name, a, b = next(t for t in zip(StateBatch._fields, st, bad)
+                      if t[2].any())
+    lo_col, hi_col = caps[name]
+    idx = tuple(int(i) for i in np.argwhere(b)[0])
+    if name == "msg":
+        lo_b, hi_b = int(lo_col[idx[-1]]), int(hi_col[idx[-1]])
+    else:
+        lo_b, hi_b = int(lo_col), int(hi_col)
+    raise ValueError(
+        f"{where}value {int(a[idx])} at "
+        f"{lane_map.describe_lane(name, idx, dims)} "
+        f"is outside the packable range [{lo_b}, {hi_b}] "
+        f"(uint8 row packing would alias it silently; "
+        f"{int(b.sum())} offending element(s) total)")
 
 
 def encode_state(s: PyState, dims: RaftDims) -> StateBatch:
@@ -322,6 +334,19 @@ def flatten_state(st: StateBatch, dims: RaftDims):
                                for p in parts]).astype(ROW_DTYPE)
     import jax.numpy as jnp  # jax arrays and tracers
     return jnp.concatenate(parts).astype(jnp.uint8)
+
+
+def flatten_states(st: StateBatch, dims: RaftDims) -> np.ndarray:
+    """A batch of states (``stack_states``: numpy, one leading axis) ->
+    ``[n, state_width]`` uint8 rows, each what ``flatten_state`` makes of
+    that state, in one concatenation."""
+    n = len(st.term)
+    parts = list(st)        # the row's order is the fields' own
+    if dims.value_bytes == 2:
+        parts += [st.log_val >> 8,
+                  st.msg[:, :, list(_msg_value_cols(dims))] >> 8]
+    return np.concatenate([np.asarray(p, np.int32).reshape(n, -1)
+                           for p in parts], axis=1).astype(ROW_DTYPE)
 
 
 def unflatten_state(row, dims: RaftDims) -> StateBatch:

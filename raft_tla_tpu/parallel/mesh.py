@@ -71,8 +71,8 @@ from ..models.dims import RaftDims
 from ..models.invariants import build_inv_id
 from ..models.pystate import PyState
 from ..models.schema import (ROW_DTYPE, build_pack_guard, check_packable,
-                             decode_state, encode_state, flatten_state,
-                             state_width, unflatten_state)
+                             decode_state, encode_state, flatten_states,
+                             stack_states, state_width, unflatten_state)
 from ..obs import MetricsRegistry, RunEventLog, events_path
 from ..obs.flight import RECORDER as _flight_rec
 from ..ops import compact as compact_mod
@@ -995,10 +995,12 @@ class MeshBFSEngine:
                     free_q.append(arr)
 
         if resume is None:
-            encoded = [encode_state(s, dims) for s in init_states]
+            with mt.phase_timer("roots_encode"):
+                roots = stack_states(
+                    [encode_state(s, dims) for s in init_states])
             if self._root_check is not None:
                 with mt.phase_timer("root_check"):
-                    v = find_root_violation(self._root_check, encoded,
+                    v = find_root_violation(self._root_check, roots,
                                             init_states, B, self.inv_names)
                 if v is not None:   # before warm-up: no checking time spent
                     if cfg.record_trace:
@@ -1022,9 +1024,9 @@ class MeshBFSEngine:
                     evlog.emit("violation", invariant=v.invariant,
                                fingerprint=hex(v.fingerprint), level=0)
                     return res
-            for e in encoded:       # reject silently-aliasing roots
-                check_packable(e, self.dims)
-            rows_np = np.stack([flatten_state(e, dims) for e in encoded])
+            with mt.phase_timer("roots_encode"):
+                check_packable(roots, dims)   # silently-aliasing roots
+                rows_np = flatten_states(roots, dims)
             if cfg.record_trace:
                 with mt.phase_timer("root_check"):
                     rhi, rlo = (np.asarray(x) for x in

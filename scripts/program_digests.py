@@ -3,10 +3,10 @@
 
     python3 scripts/program_digests.py [<checkout>]
 
-Lowers ``BFSEngine``'s ``chunk`` and ``ingest`` for six cfgs
+Lowers ``BFSEngine``'s ``chunk`` and ``ingest`` for seven cfgs
 (``MCraft_safety`` and ``reconfig3_safety`` are the ones that trace
-``models/safety.py``, the second under ``ReconfigDims``; a cfg a checkout
-lacks is left out),
+``models/safety.py``, the second under ``ReconfigDims``; ``Smokeraft`` is
+the smoke tier's, 1,103-byte rows; a cfg a checkout lacks is left out),
 ``MeshBFSEngine``'s two programs over four virtual devices (trace
 recording on and off) and ``SwarmEngine``'s walk chunk at a small batch,
 and prints sha256 of each ``.lower(...).as_text()``.  jax's
@@ -52,7 +52,8 @@ def small(**kw) -> EngineConfig:
 def main() -> None:
     i32 = S((), jnp.int32)
     for cfg in ("MCraft_bounded", "TPUraft", "MCraft_noleader",
-                "MCraft_safety", "reconfig3", "reconfig3_safety"):
+                "MCraft_safety", "reconfig3", "reconfig3_safety",
+                "Smokeraft"):
         if not os.path.exists(os.path.join(ROOT, f"configs/{cfg}.cfg")):
             continue
         eng = make_engine(

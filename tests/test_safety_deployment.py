@@ -267,26 +267,34 @@ def test_the_stage_tag_moved_with_the_names():
     assert f'stages_tag = "{chunk_mod.STAGES_TAG}"' in text
 
 
-# -- the last frontier's fetch ------------------------------------------------
+# -- a run ends where its wall is taken ---------------------------------------
 
-def test_the_last_frontier_comes_in_pieces_of_one_shape(setup):
-    """``run()`` ends by copying the frontier it stopped on to the host
-    (profiling scripts read it).  A slice at that frontier's own length
-    compiled anew at every length, inside the duration budget, 0.3-0.7 s
-    of a 20 s window on the chip and cached or not by chance: `safety9`'s
-    six seeds spread by 2.3 % for it.  Pieces of one shape, compiled in
-    warm-up, whatever the length."""
-    whole = make_engine(setup, small(max_diameter=5))
-    whole.run(initial_states(setup))
-    pieces = make_engine(setup, small(max_diameter=5))
-    pieces._frontier_rows = 96          # read when the piece is traced
-    pieces.run(initial_states(setup))
-    assert whole._last_frontier.shape == (1218, whole._sw)
-    assert (pieces._last_frontier == whole._last_frontier).all()
-    # A piece that would pass the queue's end starts earlier.
-    rng = np.random.default_rng(0)
-    qa = pieces._Q + pieces._PAD
-    q = rng.integers(0, 256, (qa, pieces._sw), dtype=np.uint8)
-    for count in (0, 1, 96, 97, qa - 5, qa):
-        got = pieces._fetch_frontier(jax.device_put(q), count)
-        assert got.shape == (count, pieces._sw) and (got == q[:count]).all()
+@pytest.mark.parametrize("stop,levels,distinct,generated", [
+    ("diameter_budget", [1, 3, 18, 79, 318, 1218], 2300, 5616),
+    ("exhausted", [1, 0], 4, 6),
+])
+def test_a_run_copies_no_frontier_at_its_end(setup, stop, levels, distinct,
+                                             generated):
+    """Until PR 48 ``run()`` ended by copying the frontier it stopped on
+    to the host (phase ``frontier_fetch``, into an attribute: 1,218 rows
+    here, 15-17 k a smoke check, 260-397 MB a deep window) for profiling
+    scripts PR 46 deleted.  Stopped on a budget or exhausted (from a root
+    whose every successor, a ``Timeout`` into term 4, the constraint
+    prunes), the run leaves no such phase, and counts what the parent's
+    run counted."""
+    (root,) = initial_states(setup)
+    if stop == "exhausted":
+        eng = make_engine(setup, small())
+        res = eng.run([dataclasses.replace(root, current_term=(3, 3, 3))])
+    else:
+        eng = make_engine(setup, small(max_diameter=5))
+        res = eng.run([root])
+    assert (res.stop_reason, res.levels, res.distinct, res.generated) == (
+        stop, levels, distinct, generated)
+    assert res.violation is None
+    assert "frontier_fetch" not in res.phases
+    assert "phase/frontier_fetch" not in eng.metrics.snapshot()["histograms"]
+    assert not [name for name in vars(eng) if "frontier" in name]
+    # What the run's ends are made of now.
+    assert {"roots_encode", "root_check", "run_init", "warmup"} <= set(
+        res.phases)

@@ -130,9 +130,9 @@ def test_run_level_replay_reach_all_three_sinks(verdict):
         assert sum(e["name"] == name for e in chrome) == n, name
         assert sum(nm == f"raft.{name}" for nm, _s in noted) == n, name
     # ... and so does every leaf, under the same name in each.
-    for name in ("run_init", "warmup", "ingest", "chunk", "stats_fetch",
-                 "account", "trace_flush", "grow", "level_end",
-                 "frontier_fetch", "run_end", "trace_chain", "replay_scan"):
+    for name in ("roots_encode", "run_init", "warmup", "ingest", "chunk",
+                 "stats_fetch", "account", "trace_flush", "grow", "level_end",
+                 "run_end", "trace_chain", "replay_scan"):
         assert hist[f"phase/{name}"]["count"] == sum(
             e["name"] == name for e in chrome) == sum(
             nm == f"raft.{name}" for nm, _s in noted) > 0, name

@@ -146,7 +146,7 @@ def test_no_flush_compiles_whatever_its_length(kind, started, tmp_path,
     else:
         eng = make_engine(kind, batch=18, queue_capacity=18 * 233,
                           events_out=ev)
-        outside = {"root_check", "run_init", "warmup", "frontier_fetch"}
+        outside = {"root_check", "run_init", "warmup"}
     res = eng.run([init_state(DIMS)])
     assert res.levels == LEVELS
     assert len(set(started)) >= 10, started
