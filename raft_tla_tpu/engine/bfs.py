@@ -1091,6 +1091,7 @@ class BFSEngine:
         else:
             self._xla_capture = None
         self._run_roots = len(init_states or ())
+        self._restore_counts = {}   # a resume's rebuild fills it
         evlog.emit(
             "run_start", engine=type(self).__name__, dims=repr(self.dims),
             batch=cfg.batch, sync_every=cfg.sync_every,
@@ -1270,9 +1271,11 @@ class BFSEngine:
             self._family_base = dict(resume.action_counts)
 
     def _run_end_extra(self) -> dict:
-        """Further fields of ``run_end`` (the mesh engine's exchange and
-        per-chip counts)."""
-        return {}
+        """Further fields of ``run_end``: what a resume's seen-set
+        rebuild ran (``fpset.from_host_keys``: probe rounds, and the
+        lanes they ran on); the mesh engine's are its exchange and
+        per-chip counts and its own rebuild's."""
+        return dict(self._restore_counts)
 
     def _events_path(self):
         """Single-controller resolution; the mesh engine overrides with
@@ -1591,8 +1594,10 @@ class BFSEngine:
                 cap = self._seen_cap
                 while n_keys > fpset._capacity(cap) // 2:
                     cap *= 2
-                seen = fpset.from_host_keys(resume.seen_hi, resume.seen_lo,
-                                            cap)
+                seen, rounds, lane_rounds = fpset.from_host_keys(
+                    resume.seen_hi, resume.seen_lo, cap)
+                self._restore_counts = {"restore_rounds": rounds,
+                                        "restore_lane_rounds": lane_rounds}
                 fr = np.ascontiguousarray(resume.frontier).astype(
                     ROW_DTYPE, casting="safe")
                 # A frontier larger than the device queue resumes as device
@@ -2121,8 +2126,11 @@ class BFSEngine:
             self.metrics.counter("engine/fpset_resizes")
             # The growth_stall event BENCH_r05 had to infer from outside:
             # capacity after, off-clock stall, live memory.
+            rounds, lane_rounds = self._rebuild_counts
             self._evlog.emit("fpset_resize", capacity=len(seen.hi),
                              stall_seconds=round(stall, 3),
+                             rebuild_rounds=rounds,
+                             rebuild_lane_rounds=lane_rounds,
                              memory=device_memory_stats())
         return seen, qnext, tbuf, t0
 
@@ -2148,7 +2156,7 @@ class BFSEngine:
         try:
             if _faults.ACTIVE:
                 _faults.fire("oom", grow=self._grow_attempts)
-            return fpset.from_host_keys(hi, lo, 2 * C)
+            return self._rebuilt(hi, lo, 2 * C)
         except Exception as e:
             if not (self.config.degrade_on_oom
                     and is_resource_exhausted(e)):
@@ -2172,7 +2180,13 @@ class BFSEngine:
                     arr.delete()
                 except Exception:
                     pass
-            return fpset.from_host_keys(hi, lo, 2 * C)
+            return self._rebuilt(hi, lo, 2 * C)
+
+    def _rebuilt(self, hi, lo, capacity):
+        """The grown table, its rebuild's rounds and lane-rounds kept for
+        the ``fpset_resize`` event."""
+        seen, *self._rebuild_counts = fpset.from_host_keys(hi, lo, capacity)
+        return seen
 
     def _write_checkpoint(self, qcur, cur_count, pending, seen, res, trace,
                           wall):

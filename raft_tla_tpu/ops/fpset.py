@@ -30,6 +30,19 @@ rounds sets the ``fail`` flag (table effectively full for that
 neighborhood) — the engine raises rather than ever silently dropping a
 state.  Keep load below ~0.7 · capacity; the engines' capacity checks
 enforce a margin.
+
+Two doors lead into a table.  A chunk program's candidates go through
+``insert`` (``insert_windowed`` on the mesh owner): most of them ARE in
+the table already, the caller needs ``is_new``, and the benchmark's
+key-width controls patch that one function.  A REBUILD (a resume's
+checkpointed keys, a growth's rehash: ``from_host_keys``,
+``MeshBFSEngine._shards_from_keys``) feeds keys that came out of a
+table, pairwise distinct and none of them in the new one, and goes
+through ``rebuild_unique``: the same probing and the same chain
+invariant, but no match against the table, no ``is_new``, and after the
+first probe the rounds run only on the lanes still pending.  The two
+stay apart on purpose: a branch inside ``insert_unique`` would change
+every cell's chunk program for a gain only a rebuild can show.
 """
 
 from __future__ import annotations
@@ -220,6 +233,122 @@ def insert_unique(s: FPSet, qhi, qlo, valid) -> Tuple["FPSet", jnp.ndarray,
             is_new[:k], jnp.any(pending))
 
 
+# Lanes of a rebuild's narrower rounds, as divisors of the piece: a
+# round costs by the lane, so after the first probe the rounds run on a
+# quarter of the piece and then on a thirty-second of it, as soon as the
+# lanes still pending fit.  A width under REBUILD_MIN_LANES is not worth
+# a compaction of its own.
+REBUILD_NARROWINGS = (4, 32)
+REBUILD_MIN_LANES = 128
+
+
+def rebuild_unique(s: FPSet, qhi, qlo, valid):
+    """Insert a batch of keys into a table that is being REBUILT.
+    Returns ``(table', fail, rounds, lane_rounds)``.
+
+    PRECONDITION: valid keys are pairwise distinct AND none of them is in
+    ``s`` (a checkpoint's key dump, a growth's rehash: keys that came out
+    of a table).  So a rebuild needs neither ``insert_unique``'s
+    ``match`` nor its ``is_new``: every valid lane is new, and a key fed
+    twice would be stored twice.
+
+    ``insert_unique`` runs every round on all ``kp`` lanes while any is
+    pending, and a settled lane gathers and scatters at its spread
+    address like a working one (design note 3).  After one probe the
+    pending share is about the table's load, so here the rounds are
+    staged over static widths ``kp``, ``kp/4``, ``kp/32``
+    (``REBUILD_NARROWINGS``): a stage runs rounds while more lanes are
+    pending than the next width holds, then they are brought to the
+    front with their keys and chain steps (a prefix sum, one scatter of
+    lane numbers, a gather at the next width: a ``lax.sort`` runs 2.5 ms
+    a piece faster on a v5e and takes 40 s to compile where this takes
+    4), and the next stage runs on that prefix alone.  The width follows
+    from the pending count the program itself holds: a rehash into a
+    table a quarter full stays wide for as many rounds as it needs, and
+    a piece that is mostly padding starts narrow.
+
+    What every probing reader depends on is kept exactly as
+    ``insert_unique`` states it: a lane advances its chain only after it
+    saw its slot held by another key, a claim loser retries the same
+    slot, the first empty slot of a key's chain ends a search.  The one
+    claim table is carried through the stages and its tags stay
+    monotone across them (round x ``kp`` + the lane's place in the
+    current width).  ``fail``: a valid lane still pending after
+    ``PROBE_ROUNDS`` rounds in all.  ``rounds`` counts the rounds run,
+    ``lane_rounds`` the lanes they ran on (int32 both)."""
+    import jax
+
+    c = s.hi.shape[0]
+    (qhi, qlo, valid), _k = _pad_pow2(
+        (qhi, qlo, jnp.asarray(valid, bool)),
+        (SENTINEL, SENTINEL, False))
+    kp = qhi.shape[0]
+    widths = [kp] + [kp // d for d in REBUILD_NARROWINGS
+                     if kp // d >= REBUILD_MIN_LANES]
+    cm = min(c, CLAIM_CAP) - 1
+    assert (PROBE_ROUNDS + 1) * kp < 2**31, "claim tag overflow"
+
+    hi, lo = s.hi, s.lo
+    claim = jnp.full((cm + 1,), -1, _I32)
+    step = jnp.zeros((kp,), _U32)
+    pending = valid
+    qhi, qlo = (jnp.where(valid, q, SENTINEL) for q in (qhi, qlo))
+    count = placed = jnp.sum(valid, dtype=_I32)
+    r = lane_rounds = _I32(0)
+    for w, floor in zip(widths, widths[1:] + [0]):
+        if w < qhi.shape[0]:
+            # The pending lanes to the front, in their order (at most
+            # ``w`` of them unless the rounds ran out, and then ``count``
+            # says so): a prefix sum gives every lane its place, the
+            # settled ones behind the pending, one scatter of the lanes'
+            # own numbers inverts that, and the front ``w`` gather theirs.
+            ahead = jnp.cumsum(pending, dtype=_I32)
+            lane = jnp.arange(qhi.shape[0], dtype=_I32)
+            place = jnp.where(pending, ahead - 1, count + lane - ahead)
+            src = jnp.zeros_like(lane).at[place].set(
+                lane, unique_indices=True)[:w]
+            qhi, qlo, step = qhi[src], qlo[src], step[src]
+            pending = jnp.arange(w, dtype=_I32) < count
+        h1, h2 = _probe_base(qhi, qlo, c)
+        arange = jnp.arange(w, dtype=_I32)
+        spread = arange & (c - 1)               # cold per-lane addresses
+        # A settled lane stays at the slot that holds its key, an address
+        # as scattered as a probing lane's (and a round of lanes at
+        # CONSECUTIVE addresses costs a v5e 1.6 times one of lanes at
+        # hashed ones); only the lanes without a key, which would all
+        # hash alike, go to their spread address.
+        keyed = ~((qhi == SENTINEL) & (qlo == SENTINEL))
+
+        def round_body(carry):
+            hi, lo, claim, step, pending, _count, r = carry
+            probe = ((h1 + step * h2) & _U32(c - 1)).astype(_I32)
+            idx = jnp.where(keyed, probe, spread)
+            cur_hi, cur_lo = hi[idx], lo[idx]
+            occupied = pending & ~((cur_hi == SENTINEL)
+                                   & (cur_lo == SENTINEL))
+            attempt = pending & ~occupied
+            # Identity-element scatters at the lane's own address, as in
+            # ``insert_unique``.
+            tag = r * _I32(kp) + arange
+            claim = claim.at[idx & cm].max(jnp.where(attempt, tag, -1))
+            win = attempt & (claim[idx & cm] == tag)
+            hi = hi.at[idx].min(jnp.where(win, qhi, SENTINEL))
+            lo = lo.at[idx].min(jnp.where(win, qlo, SENTINEL))
+            pending = pending & ~win
+            step = step + occupied.astype(_U32)
+            return (hi, lo, claim, step, pending,
+                    jnp.sum(pending, dtype=_I32), r + 1)
+
+        # Rounds on ``w`` lanes while more than ``floor`` are pending.
+        r0 = r
+        hi, lo, claim, step, pending, count, r = jax.lax.while_loop(
+            lambda carry: (carry[5] > floor) & (carry[6] < PROBE_ROUNDS),
+            round_body, (hi, lo, claim, step, pending, count, r))
+        lane_rounds = lane_rounds + (r - r0) * _I32(w)
+    return (FPSet(hi=hi, lo=lo, size=s.size + placed - count),
+            count > 0, r, lane_rounds)
+
+
 def insert(s: FPSet, qhi, qlo, valid) -> Tuple["FPSet", jnp.ndarray,
                                                jnp.ndarray]:
     """Full-batch insert: dedup pre-pass + unique insert.  Returns
@@ -325,30 +454,47 @@ def to_host_keys(s: FPSet) -> Tuple[np.ndarray, np.ndarray]:
     return hi[order], lo[order]
 
 
-def from_host_keys(keys_hi: np.ndarray, keys_lo: np.ndarray,
-                   capacity: int, chunk: int = 1 << 15) -> FPSet:
-    """Rebuild a table from checkpointed/rehashed keys.
+def rebuild_piece(s: FPSet, status, qhi, qlo, valid):
+    """One piece of a rebuild and its place in the rebuild's status,
+    ``[pieces that failed, rounds, lane_rounds]`` (int32, on the device
+    beside the table): ``(table', status')``.  A rebuild carries the
+    status through its pieces and reads it once, after the last."""
+    s, fail, rounds, lane_rounds = rebuild_unique(s, qhi, qlo, valid)
+    return s, status + jnp.stack([fail.astype(_I32), rounds, lane_rounds])
 
-    Every caller feeds keys that are ALREADY pairwise distinct — they
-    come out of a hash table (growth rehash) or a checkpointed key dump
-    (`to_host_keys` output) — so the per-chunk dedup sort that dominates
-    `insert` is pure overhead here: `insert_unique` is used directly.
-    That halves the growth-rehash stall the engines record in
-    ``EngineResult.growth_stalls``."""
+
+def from_host_keys(keys_hi: np.ndarray, keys_lo: np.ndarray,
+                   capacity: int, chunk: int = 1 << 15):
+    """Rebuild a table from checkpointed/rehashed keys.  Returns
+    ``(table, rounds, lane_rounds)``: the probe rounds the pieces ran and
+    the lanes they ran on.
+
+    Every caller feeds keys that are ALREADY pairwise distinct and goes
+    into an empty table — they come out of a hash table (growth rehash)
+    or a checkpointed key dump (`to_host_keys` output) — so neither the
+    per-chunk dedup sort that dominates `insert` nor `insert_unique`'s
+    match against the table has anything to find: a rebuild goes through
+    `rebuild_unique`, the door of its own whose rounds run, after the
+    first, on the lanes still pending.  (The chunk programs keep
+    `insert`: their candidates ARE mostly in the table, and they need
+    `is_new`.)  The pieces' fail flags and counts stay on the device and
+    are read once, after the last piece; an overflow raises then."""
     import jax
 
     s = empty(capacity)
-    ins = jax.jit(insert_unique, donate_argnums=(0,))
+    status = jnp.zeros((3,), _I32)
+    piece = jax.jit(rebuild_piece, donate_argnums=(0, 1))
     n = len(keys_hi)
     for base in range(0, n, chunk):
         h = np.asarray(keys_hi[base:base + chunk], np.uint32)
         l = np.asarray(keys_lo[base:base + chunk], np.uint32)
         pad = chunk - len(h)
         valid = np.arange(chunk) < len(h)
-        s, _new, fail = ins(
-            s, jnp.asarray(np.pad(h, (0, pad))),
+        s, status = piece(
+            s, status, jnp.asarray(np.pad(h, (0, pad))),
             jnp.asarray(np.pad(l, (0, pad))), jnp.asarray(valid))
-        if bool(fail):
-            raise RuntimeError(
-                f"FPSet rebuild overflow: {n} keys into capacity {capacity}")
-    return s
+    failed, rounds, lane_rounds = (int(x) for x in np.asarray(status))
+    if failed:
+        raise RuntimeError(
+            f"FPSet rebuild overflow: {n} keys into capacity {capacity}")
+    return s, rounds, lane_rounds

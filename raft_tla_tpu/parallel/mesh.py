@@ -127,27 +127,31 @@ def _fetch_shard(cols, start, length):
 
 class _KeyInserts:
     """The pieces one ``_shards_from_keys`` call dispatched and nobody
-    has waited for yet: their count, when the first went out, and each
-    one's per-chip fail flag, still on the chips."""
+    has waited for yet: their count, when the first went out, and the
+    rebuild's status (``fpset.rebuild_piece``: a row a chip), carried
+    through the pieces and still on the chips."""
 
-    def __init__(self, overflow: str):
-        self._overflow, self._fails = overflow, []
+    def __init__(self, overflow: str, status):
+        self._overflow, self._status = overflow, status
+        self.pieces = 0
         self.since = None       # time.time() at the first dispatch's return
+        self.rounds = self.lane_rounds = None    # known after ``wait``
 
-    @property
-    def pieces(self) -> int:
-        return len(self._fails)
-
-    def dispatched(self, fail) -> None:
-        self._fails.append(fail)
+    def dispatched(self, status) -> None:
+        self._status, self.pieces = status, self.pieces + 1
         if self.since is None:
             self.since = time.time()
 
     def wait(self) -> None:
-        """Block until the last piece is in its shard; a shard that
+        """Block until the last piece is in its shard and read the
+        status, once: ``rounds`` is the most probe rounds a chip here
+        ran, ``lane_rounds`` the lanes all of them ran on; a shard that
         could not take its keys raises."""
-        if any(bool(np.any(np.asarray(s.data)))
-               for f in self._fails for s in f.addressable_shards):
+        failed, rounds, lane_rounds = np.concatenate(
+            [np.asarray(s.data) for s in self._status.addressable_shards]).T
+        self.rounds, self.lane_rounds = int(rounds.max()), int(
+            lane_rounds.sum())
+        if failed.any():
             raise RuntimeError(self._overflow)
 
 
@@ -549,15 +553,15 @@ class MeshBFSEngine:
         # the fills of _sharded_full, one per (shape, dtype, value).
         self._full_fns = {}
 
-        def insert_keys(hi, lo, size, qh, ql, valid):
-            s, _new, fail = fpset.insert_unique(
-                fpset.FPSet(hi=hi[0], lo=lo[0], size=size[0]),
+        def insert_keys(hi, lo, size, qh, ql, valid, status):
+            s, status = fpset.rebuild_piece(
+                fpset.FPSet(hi=hi[0], lo=lo[0], size=size[0]), status[0],
                 qh[0], ql[0], valid[0])
-            return s.hi[None], s.lo[None], s.size[None], fail[None]
+            return s.hi[None], s.lo[None], s.size[None], status[None]
 
         self._insert_keys = jax.jit(
-            shard(insert_keys, in_specs=(sx,) * 6, out_specs=(sx,) * 4),
-            donate_argnums=(0, 1, 2))
+            shard(insert_keys, in_specs=(sx,) * 7, out_specs=(sx,) * 4),
+            donate_argnums=(0, 1, 2, 6))
         self._write_rows = jax.jit(
             lambda q, rows, at: jax.lax.dynamic_update_slice(
                 q, rows, (jnp.int32(0), at, jnp.int32(0))),
@@ -613,6 +617,7 @@ class MeshBFSEngine:
             np.concatenate([hi for hi, _lo in keys.values()]),
             np.concatenate([lo for _hi, lo in keys.values()]), most)
         inserts.wait()
+        self._rebuild_counts = (inserts.rounds, inserts.lane_rounds)
         return shi, slo, ssize
 
     def _shards_from_keys(self, keys_hi, keys_lo, most):
@@ -624,7 +629,7 @@ class MeshBFSEngine:
 
         Each controller supplies only the keys of its addressable
         shards; every shard is built ON the chip that owns it, all of
-        them side by side by one program over the mesh (``insert_unique``
+        them side by side by one program over the mesh (``fpset.rebuild_piece``
         under ``shard_map``), a piece of keys a chip at a time, and never
         comes to the host (a 2^25-slot shard is 268 MB; four of them
         built on chip 0, fetched and sent up again were most of a deep
@@ -672,8 +677,10 @@ class MeshBFSEngine:
         shi, slo = (self._sharded_full((n, cl), _U32, SENTINEL)
                     for _ in range(2))
         ssize = self._sharded_full((n,), _I32)
+        status = self._sharded_full((n, 3), _I32)
         inserts = _KeyInserts(
-            f"FPSet rebuild overflow: {most} keys into a shard of {cl}")
+            f"FPSet rebuild overflow: {most} keys into a shard of {cl}",
+            status)
         for _ in range(-(-most // piece)):
             while read < len(keys_hi) and min(
                     len(h) for h, _l in held.values()) < piece:
@@ -690,9 +697,9 @@ class MeshBFSEngine:
             valid = jax.make_array_from_callback(
                 (n, piece), sh, lambda idx, part=part: (
                     np.arange(piece) < len(part[idx[0].start or 0][0]))[None])
-            shi, slo, ssize, fail = self._insert_keys(
-                shi, slo, ssize, column(0), column(1), valid)
-            inserts.dispatched(fail)
+            shi, slo, ssize, status = self._insert_keys(
+                shi, slo, ssize, column(0), column(1), valid, status)
+            inserts.dispatched(status)
         while read < len(keys_hi):
             bucket_slab()
         left = {d: len(h) for d, (h, _l) in held.items() if len(h)}
@@ -1147,9 +1154,13 @@ class MeshBFSEngine:
                 inserts.wait()
                 cur_counts_dev.block_until_ready()
             # How much of the resume the chips paced (run_end): a wait
-            # near nothing says the host still is the pace.
+            # near nothing says the host still is the pace.  And what
+            # the chips ran: the most probe rounds of any, and the lanes
+            # of all (over the checkpoint's keys: 1.1 is the floor).
             self._mesh_counts.update(
                 restore_pieces=inserts.pieces,
+                restore_rounds=inserts.rounds,
+                restore_lane_rounds=inserts.lane_rounds,
                 restore_host_s=round(
                     t_wait - (inserts.since or t_wait), 3),
                 restore_wait_s=round(time.time() - t_wait, 3))
@@ -1686,9 +1697,12 @@ class MeshBFSEngine:
             from ..obs import PHASE_PREFIX, device_memory_stats
             self.metrics.observe(PHASE_PREFIX + "grow", stall)
             self.metrics.counter("engine/fpset_resizes")
+            rounds, lane_rounds = self._rebuild_counts
             self._evlog.emit("fpset_resize",
                              capacity=self.n_dev * self._CL,
                              stall_seconds=round(stall, 3),
+                             rebuild_rounds=rounds,
+                             rebuild_lane_rounds=lane_rounds,
                              memory=device_memory_stats())
         return shi, slo, ssize, qnext, next_counts, tbuf, t0
 

@@ -158,7 +158,8 @@ def main() -> int:
         out["run_end"] = {k: end[k] for k in (
             "chunk_calls", "passes", "ingest_calls", "flush_overlapped",
             "flush_drained", "chip_insert_windows", "restore_pieces",
-            "restore_host_s", "restore_wait_s", "compiles") if k in end}
+            "restore_rounds", "restore_lane_rounds", "restore_host_s",
+            "restore_wait_s", "compiles") if k in end}
         out["fill"] = run.get("counters")
         out["phases"] = {k: round(v, 4) for k, v in run["phases"].items()}
         import jax

@@ -259,13 +259,17 @@ def test_seen_set_growth_programs_at_the_verdict_cells_sizes(grown,
                                                              one_chip):
     """What ``_grow_precompiled`` dispatches, as the verdict cell runs it
     (``MCraft_noleader.cfg``: batch 256, 16,384-row queues, a 65,536-key
-    table that doubles twice a check): the rehash's ``insert_unique`` of
+    table that doubles twice a check): the rehash's ``rebuild_piece`` of
     2^15 keys into the grown table, then the chunk at that table."""
     from raft_tla_tpu.ops import fpset
     table = jax.eval_shape(lambda: fpset.empty(grown))
     k = 1 << 15                         # fpset.from_host_keys' piece
-    compile_for(fpset.insert_unique, lambda a: one_chip, table,
-                S((k,), jnp.uint32), S((k,), jnp.uint32), S((k,), jnp.bool_))
+    c = compile_for(fpset.rebuild_piece, lambda a: one_chip, table,
+                    S((3,), jnp.int32), S((k,), jnp.uint32),
+                    S((k,), jnp.uint32), S((k,), jnp.bool_))
+    # The staged rounds: a loop a width, the piece's, a quarter of it
+    # and a thirty-second.
+    assert c.as_text().count(" while(") >= 3
     eng = small_engine("configs/MCraft_noleader.cfg", 403, 132, batch=256,
                        seen_capacity=grown)
     assert (eng._B, eng._Q, eng._seen_cap) == (256, 1 << 14, grown)
@@ -370,6 +374,24 @@ def test_mesh_ingest_over_four_described_chips(topo):
                     S((4, eng._B), jnp.bool_),
                     qav, counts, shi, slo, ssize, tbuf, tcount)
     assert "all-to-all" in c.as_text()
+
+
+def test_mesh_key_inserts_over_four_described_chips(topo):
+    """The shard rebuild's program (``_insert_keys``:
+    ``fpset.rebuild_piece`` under ``shard_map``, a resume's and a
+    growth's) at the smallest piece: every chip fills its own shard, so
+    no collective, and the rounds are staged over three widths."""
+    from raft_tla_tpu.parallel.mesh import KEY_PIECE_MIN
+    eng, over_mesh = small_mesh_engine("configs/MCraft_bounded.cfg", topo,
+                                       record_trace=False)
+    shard = S((4, eng._CL), jnp.uint32)
+    keys = S((4, KEY_PIECE_MIN), jnp.uint32)
+    text = compile_for(
+        eng._insert_keys, over_mesh, shard, shard, S((4,), jnp.int32),
+        keys, keys, S((4, KEY_PIECE_MIN), jnp.bool_),
+        S((4, 3), jnp.int32)).as_text()
+    assert not re.search(r"all-|collective", text)
+    assert text.count(" while(") >= 3
 
 
 def deployment_mesh_engine(setup, topo):

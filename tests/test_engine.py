@@ -702,7 +702,7 @@ def one_chunk_call(cfg):
     out = eng._chunk(
         jnp.asarray(qcur), jnp.int32(len(want.frontier)), jnp.int32(0),
         jnp.zeros(qav.shape, jnp.uint8), jnp.int32(0),
-        fpset.from_host_keys(*want.seen_keys(eng), eng._seen_cap),
+        fpset.from_host_keys(*want.seen_keys(eng), eng._seen_cap)[0],
         tuple(jnp.zeros(a.shape, a.dtype) for a in tbuf_av),
         jnp.int32(0), jnp.int32(eng._CH))
     qnext, _seen, tbuf, stats = out[:4]

@@ -435,7 +435,8 @@ def owner_inserts():
     assert len(cases) == n
 
     def preloaded(khi, klo):
-        s = fpset.from_host_keys(khi, klo, OWNER_SLOTS, chunk=OWNER_SLOTS)
+        s, _rounds, _lane_rounds = fpset.from_host_keys(
+            khi, klo, OWNER_SLOTS, chunk=OWNER_SLOTS)
         assert int(s.size) == len(khi)
         return np.asarray(s.hi), np.asarray(s.lo), np.int32(len(khi))
 
@@ -541,7 +542,8 @@ def test_run_end_carries_the_insert_windows_of_every_chip(short_runs):
     assert sum(windows) >= end["passes"] > 0
     assert "chip_insert_windows" not in short_runs[BFSEngine][-1]
     # A run from the root restored nothing, and says nothing of it.
-    assert "restore_pieces" not in end
+    assert not {"restore_pieces", "restore_rounds",
+                "restore_lane_rounds"} & set(end)
 
 
 def test_the_mesh_run_start_carries_the_process_record(short_runs):

@@ -197,6 +197,35 @@ def test_a_resumed_run_reports_what_its_restore_overlapped(
     assert end["restore_pieces"] == 1
     assert end["restore_host_s"] >= 0 and end["restore_wait_s"] >= 0
     assert end["restore_host_s"] + end["restore_wait_s"] <= sum(restore)
+    # What the chips' rebuild ran (PR 49): every piece a round at least,
+    # every key a lane-round at least, and far fewer lane-rounds than
+    # rounds on all of a piece's 2^15 lanes a chip would be.
+    keys = len(ck.seen_hi)
+    assert end["restore_rounds"] >= end["restore_pieces"]
+    assert keys <= end["restore_lane_rounds"] \
+        < end["restore_rounds"] * CHIPS * (1 << 15)
+
+
+def test_a_one_chip_resume_reports_its_rebuilds_rounds(setup, snapshots,
+                                                       single, tmp_path):
+    """``BFSEngine``'s resume rebuilds through ``fpset.from_host_keys``:
+    ``run_end`` carries the rounds and lane-rounds it ran, and a run from
+    the root carries neither."""
+    ends = {}
+    for name, args in (("resume", (None, snapshots["single"])),
+                       ("root", (initial_states(setup),))):
+        events = str(tmp_path / f"{name}.jsonl")
+        run(single, *args, max_diameter=6 if name == "resume" else 2,
+            events_out=events)
+        with open(events, encoding="utf-8") as f:
+            ends[name] = [json.loads(line) for line in f][-1]
+        assert ends[name]["event"] == "run_end"
+    end, keys = ends["resume"], len(snapshots["single"].seen_hi)
+    pieces = -(-keys // (1 << 15))
+    assert end["restore_rounds"] >= pieces
+    assert keys <= end["restore_lane_rounds"] \
+        < end["restore_rounds"] * (1 << 15)
+    assert not {"restore_rounds", "restore_lane_rounds"} & set(ends["root"])
 
 
 @pytest.mark.parametrize("record_trace", [True, False])

@@ -219,7 +219,7 @@ def test_grow_oom_retries_after_releasing_old_table():
     n = 700                                  # past half of a 1024 table
     hi = np.arange(1, n + 1, dtype=np.uint32)
     lo = np.arange(1, n + 1, dtype=np.uint32)
-    seen = fpset.from_host_keys(hi, lo, 1 << 10)
+    seen = fpset.from_host_keys(hi, lo, 1 << 10)[0]
     faults.install("oom@grow=1", hard=False)
     grown = eng._maybe_grow_seen(seen)
     assert grown.hi.shape[0] == 1 << 11      # doubled despite the OOM

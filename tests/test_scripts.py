@@ -32,6 +32,26 @@ def test_profile_fpset_runs():
     assert "hash insert" in out
 
 
+def test_rebuild_rounds_runs(tmp_path):
+    """``scripts/rebuild_rounds.py`` at a tiny size: one line a
+    measurement, and the rebuild's own insert runs the same rounds as
+    ``insert_unique`` on under half the lanes."""
+    out = run_script(["scripts/rebuild_rounds.py", "--slots", "16",
+                      "--piece", "10", "--pieces", "4", "--loads", "0.14",
+                      "--out", str(tmp_path / "rounds.jsonl")])
+    lines = [json.loads(text) for text in out.splitlines()]
+    assert [line["what"] for line in lines] == [
+        "device", "insert_unique", "round", "round", "round", "compact",
+        "rebuild_unique", "whole", "whole", "whole"]
+    with open(tmp_path / "rounds.jsonl", encoding="utf-8") as f:
+        assert [json.loads(text) for text in f] == lines
+    old, new = lines[-2], lines[-1]
+    assert (old["door"], new["door"]) == ("insert_unique", "rebuild_unique")
+    assert old["keys"] == new["keys"] == 4 << 10
+    assert old["rounds_by_piece"] == new["rounds_by_piece"]
+    assert 1 <= new["lane_rounds_a_key"] < old["lane_rounds_a_key"] / 2
+
+
 @pytest.mark.slow   # ~2 min CPU
 def test_leader_bench_runs():
     """The leader-rich bench must actually exercise the log-machinery

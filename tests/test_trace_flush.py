@@ -260,10 +260,20 @@ def drained_where_the_seen_set_grows(kind, tmp_path):
     slots (a chip) and grows past 1,024 keys: in level 5 on one chip, in
     level 6 on each of two."""
     depth = 6 if kind == "mesh" else 5
+    ev = str(tmp_path / "grow.jsonl")
     eng = make_engine(kind, chips=2, batch=16, seen_capacity=1 << 11,
-                      max_diameter=depth)
+                      max_diameter=depth, events_out=ev)
     res = eng.run([init_state(DIMS)])
     assert res.growth_stalls
+    # Each growth says what its rehash ran (``fpset.rebuild_unique``): a
+    # round at least, and a lane-round a key of the 1,025 or more it
+    # moved.
+    with open(ev, encoding="utf-8") as f:
+        grown = [e for e in map(json.loads, f)
+                 if e["event"] == "fpset_resize"]
+    assert len(grown) == len(res.growth_stalls)
+    assert all(e["rebuild_rounds"] >= 1 and e["rebuild_lane_rounds"] > 1024
+               for e in grown)
     assert res.levels == (LEVELS + [1378])[:depth + 1]
     assert len(eng.trace.export()[0]) == res.distinct
     assert_every_chain_reaches_a_root(eng.trace)
