@@ -307,7 +307,8 @@ def phase_server(platform: str, sizes: Sizes = REAL) -> None:
     try:
         addr = srv.server_address
         ping = roundtrip(addr, {"op": "ping"})
-        assert ping == {"ok": True, "platform": platform}, ping
+        assert ping == {"ok": True, "platform": platform,
+                        "wait": True}, ping
         req = {"op": "check", "cfg_text": cfg_text, "batch": 512,
                "max_diameter": depth, "queue_capacity": 1 << 16,
                "seen_capacity": 1 << 19}

@@ -384,13 +384,72 @@ def _run_swarm(args, setup, resolve) -> int:
     return 0
 
 
+#: Seconds one blocking ``result`` asks the server to hold: short of
+#: the server's own cap, long enough that a queued job costs a request
+#: a minute.
+_RESULT_WAIT_SECONDS = 60.0
+
+
+def _wait_for_job(args, job: dict):
+    """``submit --wait``: until the job is terminal.  Returns ``(job
+    summary, result document or None)``, or ``(None, None)`` after
+    saying why.  Against a server whose ``ping`` answers ``"wait"`` the
+    ``result`` op blocks until the job ends (no poll, no sleep: the
+    answer leaves when the executor journals the terminal state, and
+    brings the result with it); against one that does not, ``status``
+    is polled every ``--poll-interval``.  Both tolerate transient
+    network errors (a server mid-restart replays its journal and the
+    job resumes): a few failed asks print a note and retry; persistent
+    failure gives up cleanly instead of a traceback."""
+    import time
+    try:
+        blocking = bool(_client_call(args.server, {"op": "ping"},
+                                     args.timeout).get("wait"))
+    except (OSError, ValueError):
+        blocking = False        # the poll loop below reports a lost server
+    misses = 0
+    while True:
+        if not blocking:
+            time.sleep(args.poll_interval)
+        try:
+            if blocking:
+                st = _client_call(
+                    args.server,
+                    {"op": "result", "job_id": job["id"],
+                     "wait": _RESULT_WAIT_SECONDS},
+                    args.timeout + _RESULT_WAIT_SECONDS)
+            else:
+                st = _client_call(args.server,
+                                  {"op": "status", "job_id": job["id"]},
+                                  args.timeout)
+        except (OSError, ValueError) as e:
+            misses += 1
+            if misses >= 10:
+                print(f"submit: lost the server while waiting ({e}); "
+                      f"job {job['id']} may still run — poll with "
+                      f"'jobs' or 'watch --job'", file=sys.stderr)
+                return None, None
+            print(f"submit: poll failed ({e}); retrying",
+                  file=sys.stderr)
+            if blocking:
+                time.sleep(args.poll_interval)
+            continue
+        misses = 0
+        if not st.get("ok"):
+            print(f"submit: {st.get('error')}", file=sys.stderr)
+            return None, None
+        job = st["job"]
+        if job["state"] in ("done", "failed", "cancelled"):
+            return job, st.get("result")
+        print(f"job {job['id']} {job['state']}...", file=sys.stderr)
+
+
 def _run_submit(args) -> int:
     """``submit``: queue a check on a checker service as an async job
     (serving/).  Sends cfg CONTENT (cfg_text), so the service need not
-    share a filesystem with the client.  --wait polls until the job is
-    terminal and renders the result."""
+    share a filesystem with the client.  --wait waits until the job is
+    terminal (``_wait_for_job``) and renders the result."""
     import json
-    import time
     try:
         with open(args.cfg, encoding="utf-8") as f:
             cfg_text = f.read()
@@ -438,35 +497,9 @@ def _run_submit(args) -> int:
           file=status_out)
     if not args.wait:
         return 0
-    # The poll loop tolerates transient network errors (a server mid-
-    # restart replays its journal and the job resumes): a few failed
-    # polls print a note and retry; persistent failure exits cleanly
-    # instead of a traceback.
-    misses = 0
-    while True:
-        time.sleep(args.poll_interval)
-        try:
-            st = _client_call(args.server,
-                              {"op": "status", "job_id": job["id"]},
-                              args.timeout)
-        except (OSError, ValueError) as e:
-            misses += 1
-            if misses >= 10:
-                print(f"submit: lost the server while waiting ({e}); "
-                      f"job {job['id']} may still run — poll with "
-                      f"'jobs' or 'watch --job'", file=sys.stderr)
-                return 1
-            print(f"submit: poll failed ({e}); retrying",
-                  file=sys.stderr)
-            continue
-        misses = 0
-        if not st.get("ok"):
-            print(f"submit: {st.get('error')}", file=sys.stderr)
-            return 1
-        job = st["job"]
-        if job["state"] in ("done", "failed", "cancelled"):
-            break
-        print(f"job {job['id']} {job['state']}...", file=sys.stderr)
+    job, doc = _wait_for_job(args, job)
+    if job is None:
+        return 1
     print(f"job {job['id']} {job['state']} "
           f"(queue_wait {job.get('queue_wait_seconds')}s, run "
           f"{job.get('run_seconds')}s, turnaround "
@@ -480,18 +513,20 @@ def _run_submit(args) -> int:
               + (f": {job['error']}" if job.get("error") else ""),
               file=sys.stderr)
         return 1
-    try:
-        res = _client_call(args.server,
-                           {"op": "result", "job_id": job["id"]},
-                           args.timeout)
-    except (OSError, ValueError) as e:
-        print(f"submit: cannot fetch result ({e}); job {job['id']} is "
-              f"done — retry with the 'result' op", file=sys.stderr)
-        return 1
-    if not res.get("ok"):
-        print(f"submit: {res.get('error')}", file=sys.stderr)
-        return 1
-    doc = res["result"]
+    if doc is None:
+        try:
+            res = _client_call(args.server,
+                               {"op": "result", "job_id": job["id"]},
+                               args.timeout)
+        except (OSError, ValueError) as e:
+            print(f"submit: cannot fetch result ({e}); job {job['id']} "
+                  f"is done — retry with the 'result' op",
+                  file=sys.stderr)
+            return 1
+        if not res.get("ok"):
+            print(f"submit: {res.get('error')}", file=sys.stderr)
+            return 1
+        doc = res["result"]
     if args.json:
         print(json.dumps(doc, indent=2, sort_keys=True, default=str))
     else:

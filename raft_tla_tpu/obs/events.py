@@ -65,6 +65,11 @@ KNOWN_EVENTS = (
     # walk-analytics report for swarm runs — the probabilistic sibling
     # of ``statespace``.
     "hunt",             # swarm coverage report; payload: "hunt"
+    # Serving layer (serving/manager.py): one per job that reached a
+    # terminal state through the executor, in the service's own log
+    # ``<job-dir>/events.jsonl``: ``queue_wait_s``, ``run_s``,
+    # ``engine_wall_s``, ``turnaround_s``, ``cached``, ``result_bytes``.
+    "job_end",
 )
 
 #: Structured payload field each new event type must carry.

@@ -56,6 +56,11 @@ PHASE_PREFIX = "phase/"
 # under their own prefix: the ``phase/`` totals stay a partition of the
 # wall (``phase_seconds``, ``level_complete.unattributed_seconds``).
 SCOPE_PREFIX = "scope/"
+# Spans of the serving layer (server.py, serving/manager.py) observe under
+# a prefix of their own: they run on handler threads and on the executor
+# around a run, on the registry the engines share, and seconds of theirs
+# under ``phase/`` would land in whatever run was open meanwhile.
+SERVE_PREFIX = "serve/"
 
 # What an annotation's name starts with in a profiler capture.
 ANNOTATION_PREFIX = "raft."
@@ -212,6 +217,12 @@ class MetricsRegistry:
         """A span that contains phases (``run``, ``replay``): the same
         sinks, its seconds under ``scope/<name>``."""
         return self._spanning(SCOPE_PREFIX, name, args)
+
+    def serve_timer(self, name: str, **args):
+        """A span of the serving layer (``job``, ``job_setup``,
+        ``job_respond``, ``journal``, ``result_wait``): the same sinks,
+        its seconds under ``serve/<name>``."""
+        return self._spanning(SERVE_PREFIX, name, args)
 
     # -- readers -------------------------------------------------------
     def counter_value(self, name: str) -> float:

@@ -13,7 +13,9 @@ Protocol: newline-delimited JSON over TCP; one request per line, one
 response per line.  Requests:
 
     {"op": "ping"}
-        -> {"ok": true, "platform": "tpu"}
+        -> {"ok": true, "platform": "tpu", "wait": true}
+       "wait" says that this server's "result" op can block (below); a
+       client that does not find the key polls "status" instead.
     {"op": "check", "cfg": "<path>" | "cfg_text": "<.cfg contents>",
      "batch": 1024, "max_seconds": 60.0, "max_diameter": null,
      "queue_capacity": null, "seen_capacity": null, "trace": false,
@@ -88,6 +90,19 @@ Async jobs (serving/ — the multi-tenant job layer; see README
     {"op": "status", "job_id": ID}   -> {"ok": true, "job": {...}}
     {"op": "result", "job_id": ID}   -> {"ok": true, "state": ...,
                                          "result": {<check response>}}
+       of a terminal job that has a result; {"ok": false} otherwise.
+    {"op": "result", "job_id": ID, "wait": S}
+        -> {"ok": true, "state": ..., "result": {...} | null,
+            "job": {<summary>}, "timed_out": false | true}
+       BLOCKS until the job is terminal or S seconds (at most 3600)
+       have passed, then answers the state it has: "timed_out": true
+       and "result": null for a job still queued or running (ask
+       again), "result": null too for a cancelled job or one that
+       failed without a response.  The handler thread waits on the job
+       manager's condition, which the executor's terminal transition
+       and "cancel" notify: one thread a waiting client, as "watch"
+       takes one; it holds neither the device lock nor the executor,
+       so every other op answers meanwhile.  "submit --wait" waits so.
     {"op": "cancel", "job_id": ID}   -> {"ok": true, "job": {...}}
        queued/admitted only — a running single-device job is not
        preemptible; a cancelled job never ran and never will.
@@ -101,10 +116,19 @@ Async jobs (serving/ — the multi-tenant job layer; see README
     event log); every job gets per-tenant counters and queue-wait/SLO
     histograms in the registry (the "stats"/"metrics" ops and the
     --metrics-port HTTP endpoint expose them), and — with --history —
-    a kind=server run-history ledger entry.  The journal in --job-dir
-    makes the registry survive restarts: queued jobs resume, the job a
-    crash caught running is re-run once then failed with a postmortem
-    pointer.
+    a kind=server run-history ledger entry.  Every job the executor
+    finished leaves one "job_end" line in --job-dir/events.jsonl
+    (queue_wait_s, run_s, engine_wall_s, turnaround_s, cached,
+    result_bytes), and inside a profiler capture the spans raft.job
+    (pick to terminal state, tagged job, tenant, class), raft.job_setup
+    (cfg, cache key, engine lookup, config), the engine's raft.run,
+    raft.job_respond (the counterexample's replay, the result
+    document), raft.journal (every append), and on handler threads
+    raft.request/<op> and raft.result_wait; counters
+    server/result_bytes, jobs/executed, jobs/wait_wakeups.  The journal
+    in --job-dir makes the registry survive restarts: queued jobs
+    resume, the job a crash caught running is re-run once then failed
+    with a postmortem pointer.
 
 Errors: {"ok": false, "error": "<message>"}.  check/simulate are served
 one at a time (a checking run owns the device); concurrent connections
@@ -242,31 +266,56 @@ def _do_check(req, telemetry=None):
     ``run_context`` overrides; they are applied to the (possibly warm,
     cached) engine's host-side config on EVERY request — a direct
     check after a job must reset them back to the request's own
-    values, never inherit the job's scoped paths."""
-    from .engine.bfs import EngineConfig
-    from .engine.check import initial_states, make_engine
+    values, never inherit the job's scoped paths.
 
-    from .models.pystate import format_state
+    Three stretches, the first and last under spans of their own
+    (``serve/job_setup``, ``serve/job_respond``; the engine's ``run`` is
+    between them): what a warm engine's job costs around its run."""
+    from .engine.check import initial_states
 
+    tel = telemetry or {}
+    with _METRICS.serve_timer("job_setup"):
+        setup, ident, cfg_text = _load_setup(req)
+        # Engine-tier routing (request "mode" field > cfg "\* TPU: MODE"
+        # directive > exhaustive — the standard precedence): swarm-mode
+        # checks run the randomized-walk tier (engine/swarm.py) through
+        # the same request/telemetry/ledger surface.  Unknown modes
+        # reject cleanly here; submit requests are additionally
+        # validated at admission (_do_submit) so a bad mode never
+        # reaches the executor thread.
+        mode = req.get("mode") or setup.backend.get("MODE") or "exhaustive"
+        if mode not in ("exhaustive", "swarm"):
+            _METRICS.counter("server/rejected/bad_mode")
+            raise ValueError(f"unknown mode {mode!r} (expected "
+                             f"'exhaustive' or 'swarm')")
+        swarm = mode == "swarm"
+        engine = (_swarm_engine if swarm else _check_engine)(
+            req, tel, setup, ident)
+        seed = int(req.get("seed", 0))
+        roots = initial_states(setup, seed=seed)
+    if swarm:
+        # Seed and the step/wall budgets are per-request run() arguments.
+        res = engine.run(roots, seed=seed,
+                         num_steps=(int(req["num_steps"])
+                                    if req.get("num_steps") is not None
+                                    else None),
+                         max_seconds=(req.get("max_seconds")
+                                      if req.get("max_seconds") is not None
+                                      else setup.max_seconds))
+    else:
+        res = engine.run(roots)
+    with _METRICS.serve_timer("job_respond"):
+        return (_swarm_response if swarm else _check_response)(
+            req, tel, setup, cfg_text, engine, res)
+
+
+def _check_engine(req, tel, setup, ident):
+    """The warm engine a check request runs on (built and cached at its
+    first use), its host-side config set to this request's."""
     import dataclasses
-    from .engine.check import engine_config_from_backend
 
-    setup, ident, cfg_text = _load_setup(req)
-    # Engine-tier routing (request "mode" field > cfg "\* TPU: MODE"
-    # directive > exhaustive — the standard precedence): swarm-mode
-    # checks run the randomized-walk tier (engine/swarm.py) through
-    # the same request/telemetry/ledger surface.  Unknown modes reject
-    # cleanly here; submit requests are additionally validated at
-    # admission (_do_submit) so a bad mode never reaches the executor
-    # thread.
-    mode = req.get("mode") or setup.backend.get("MODE") or "exhaustive"
-    if mode == "swarm":
-        return _do_swarm(req, telemetry,
-                         _loaded=(setup, ident, cfg_text))
-    if mode != "exhaustive":
-        _METRICS.counter("server/rejected/bad_mode")
-        raise ValueError(f"unknown mode {mode!r} (expected "
-                         f"'exhaustive' or 'swarm')")
+    from .engine.check import engine_config_from_backend, make_engine
+
     record_trace = bool(req.get("trace", False))
     # Precedence everywhere (utils/cfg.py): request field > cfg "\* TPU:"
     # backend directive > built-in default — the backend-seeded config is
@@ -343,13 +392,18 @@ def _do_check(req, telemetry=None):
     # Per-request telemetry scoping (see docstring): ALWAYS assigned,
     # so a cached engine never leaks one job's event log / postmortem
     # dir / run tags into the next request's run.
-    tel = telemetry or {}
     engine.config.events_out = tel.get("events_out", cfg.events_out)
     engine.config.postmortem_dir = tel.get("postmortem_dir",
                                            cfg.postmortem_dir)
     engine.config.run_context_extra = tel.get("run_context")
+    return engine
+
+
+def _check_response(req, tel, setup, cfg_text, engine, res):
+    """The ledger entry, where one is kept, and the result document."""
+    from .models.pystate import format_state
+
     history_path = tel.get("history")
-    res = engine.run(initial_states(setup, seed=int(req.get("seed", 0))))
     if history_path:
         # Served-traffic leg of the run-history ledger: every
         # server-executed check lands a kind=server entry (host_key +
@@ -405,20 +459,21 @@ def _do_check(req, telemetry=None):
     return out
 
 
-def _do_swarm(req, telemetry=None, _loaded=None):
-    """Run one swarm-mode check request — the cheap high-QPS tier
-    (engine/swarm.py), reached via ``_do_check``'s mode routing.  Same
-    warm-cache + per-request contract as ``_do_check``: the compiled
-    engine is LRU-cached on the program-shaping knobs (walks, depth,
-    batch, pipeline key the cache; seed and the step/wall budgets are
-    per-request run() arguments), and the job executor's scoped
-    ``events_out`` / ``postmortem_dir`` / ``run_context`` are
-    (re)assigned on EVERY request so a cached engine never leaks one
-    job's paths into the next."""
-    from .engine.check import initial_states, make_swarm_engine
+def _do_swarm(req, telemetry=None):
+    """One swarm-mode check request: ``_do_check`` with the mode said."""
+    return _do_check(dict(req, mode="swarm"), telemetry)
 
-    setup, ident, cfg_text = (_loaded if _loaded is not None
-                              else _load_setup(req))
+
+def _swarm_engine(req, tel, setup, ident):
+    """The warm swarm engine (engine/swarm.py, the cheap high-QPS tier)
+    a request runs on.  Same warm-cache + per-request contract as
+    ``_check_engine``: the compiled engine is LRU-cached on the
+    program-shaping knobs (walks, depth, batch, pipeline key the cache),
+    and the job executor's scoped ``events_out`` / ``postmortem_dir`` /
+    ``run_context`` are (re)assigned on EVERY request so a cached engine
+    never leaks one job's paths into the next."""
+    from .engine.check import make_swarm_engine
+
     shape = {k: req.get(k)
              for k in ("walks", "max_depth", "batch", "pipeline")}
     key = (ident, "swarm") + tuple(shape.values())
@@ -426,7 +481,6 @@ def _do_swarm(req, telemetry=None, _loaded=None):
     if eng is None:
         eng = make_swarm_engine(setup, metrics=_METRICS, **shape)
         _cache_put(_SWARMS, key, eng, "swarm_cache")
-    tel = telemetry or {}
     eng.events_out = tel.get("events_out")
     eng.postmortem_dir = tel.get("postmortem_dir")
     eng.run_context_extra = tel.get("run_context")
@@ -436,14 +490,12 @@ def _do_swarm(req, telemetry=None, _loaded=None):
     eng.progress_seconds = (float(req["progress_seconds"])
                             if req.get("progress_seconds") is not None
                             else 5.0)
-    seed = int(req.get("seed", 0))
-    res = eng.run(initial_states(setup, seed=seed), seed=seed,
-                  num_steps=(int(req["num_steps"])
-                             if req.get("num_steps") is not None
-                             else None),
-                  max_seconds=(req.get("max_seconds")
-                               if req.get("max_seconds") is not None
-                               else setup.max_seconds))
+    return eng
+
+
+def _swarm_response(req, tel, setup, cfg_text, eng, res):
+    """The ledger entries, where a ledger is kept, and the result
+    document of one swarm run."""
     history_path = tel.get("history")
     if history_path:
         # Two ledger legs per served swarm run: kind=swarm (the tier's
@@ -663,6 +715,12 @@ def _do_job_op(op: str, req: dict, manager) -> dict:
     # op == "result": state + result read under one manager lock (a
     # retention eviction between two reads must not turn a fetched
     # result into an 'unknown job' error).
+    if req.get("wait") is not None:
+        # Blocks THIS handler thread on the manager's condition, one a
+        # waiting client as ``watch`` takes one; no device lock, no
+        # executor.
+        return {"ok": True,
+                **manager.wait_terminal(job_id, float(req["wait"]))}
     doc = manager.result_doc(job_id)
     return {"ok": True, "state": doc["state"], "result": doc["result"]}
 
@@ -681,12 +739,22 @@ def handle_request(req: dict, manager=None) -> dict:
                             "metrics") + _JOB_OPS else "unknown"
     _METRICS.counter(f"server/requests/{op_label}")
     ok = False
-    with _METRICS.phase_timer(f"request/{op_label}"):
+    # A blocking ``result`` is seconds of waiting for another thread's
+    # work: under ``serve/``, or it would stand among the ``phase/``
+    # seconds of whatever run the shared registry had open meanwhile.
+    # (The other ops keep ``phase/request/<op>``: exported series.)
+    timer = (_METRICS.serve_timer
+             if op == "result" and req.get("wait") is not None
+             else _METRICS.phase_timer)
+    with timer(f"request/{op_label}"):
         try:
             if op == "ping":
                 import jax
+                # "wait": the ``result`` op blocks when asked to; a
+                # client that finds no such key polls.
                 resp = {"ok": True,
-                        "platform": jax.devices()[0].platform}
+                        "platform": jax.devices()[0].platform,
+                        "wait": True}
             elif op == "stats":
                 resp = _do_stats()
             elif op == "metrics":
@@ -769,7 +837,7 @@ class _Handler(socketserver.StreamRequestHandler):
             try:
                 req = json.loads(line)
             except json.JSONDecodeError as e:
-                resp = {"ok": False, "error": f"bad json: {e}"}
+                req, resp = None, {"ok": False, "error": f"bad json: {e}"}
             else:
                 if isinstance(req, dict) and req.get("op") == "watch":
                     # The one streaming op: run attach emits one
@@ -781,8 +849,11 @@ class _Handler(socketserver.StreamRequestHandler):
                     continue
                 resp = handle_request(req,
                                       getattr(self.server, "jobs", None))
-            if not self._try_respond(resp):
+            sent = self._try_respond(resp)
+            if not sent:
                 return
+            if isinstance(req, dict) and req.get("op") == "result":
+                _METRICS.counter("server/result_bytes", sent)
 
     def _serve_watch(self, req: dict) -> bool:
         """Stream flight-recorder snapshots (obs/flight.py) until the
@@ -941,16 +1012,18 @@ class _Handler(socketserver.StreamRequestHandler):
                      "truncated": count <= 0})
             _time.sleep(interval)
 
-    def _try_respond(self, resp: dict) -> bool:
-        """Best-effort one-line reply; False when the client is gone (a
-        failed write must end the handler, never crash the thread)."""
+    def _try_respond(self, resp: dict) -> int:
+        """Best-effort one-line reply: the bytes written, 0 when the
+        client is gone (a failed write must end the handler, never crash
+        the thread)."""
         try:
-            self.wfile.write((json.dumps(resp) + "\n").encode())
+            line = (json.dumps(resp) + "\n").encode()
+            self.wfile.write(line)
             self.wfile.flush()
-            return True
+            return len(line)
         except (TimeoutError, OSError):
             _METRICS.counter("server/rejected/dead_client")
-            return False
+            return 0
 
 
 class CheckerServer(socketserver.ThreadingTCPServer):
@@ -1032,6 +1105,15 @@ def serve(host: str = "127.0.0.1", port: int = 8610,
     def _executor(request, job):
         return _execute_job(request, job, history=history)
 
+    if _METRICS.tracer is None:
+        # Spans opened before the first engine exists (requests, the
+        # first job's set-up) are annotations in a profiler capture too;
+        # an engine built on this registry attaches its own tracer then.
+        import jax
+
+        from .obs.tracing import SpanTracer
+        _METRICS.tracer = SpanTracer(
+            None, annotate=jax.profiler.TraceAnnotation)
     srv.jobs = JobManager(
         job_dir, executor=_executor, metrics=_METRICS,
         history_path=history,

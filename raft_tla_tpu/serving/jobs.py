@@ -95,6 +95,17 @@ def new_job(job_id: str, tenant: str, request: dict, *,
     }
 
 
+def job_class(request: dict) -> str:
+    """What kind of work a job's request asks for, as its spans and its
+    ``job_end`` event are tagged: ``simulate``, or a check's ``mode`` as
+    the request states it (``exhaustive`` where it states none; a cfg
+    whose own directive says ``MODE = swarm`` is read by the executor,
+    not here)."""
+    if request.get("op") == "simulate":
+        return "simulate"
+    return str(request.get("mode") or "exhaustive")
+
+
 #: Fields the ``jobs``/``status`` ops (and the HTTP /jobs endpoint)
 #: expose — everything except the raw request (which can carry a whole
 #: cfg_text) and the result (served by the ``result`` op only).

@@ -31,7 +31,10 @@ counters    ``jobs/submitted/<tenant>``, ``jobs/done/<tenant>``,
             ``jobs/rejected/<tenant>``, ``jobs/slo_ok/<tenant>``,
             ``jobs/slo_miss/<tenant>``, ``server/rejected/queue_full``,
             ``jobs/result_cache/hits|misses``,
-            ``jobs/requeued_after_restart``
+            ``jobs/requeued_after_restart``, ``jobs/executed`` (jobs
+            handed to the executor: device runs, where the result
+            cache answered none), ``jobs/wait_wakeups`` (times a
+            blocking ``result`` woke on the condition)
 gauges      ``jobs/queue_depth``, ``jobs/running``,
             ``jobs/state/<state>`` (one per lifecycle state)
 histograms  ``jobs/queue_wait_seconds``, ``jobs/run_seconds``,
@@ -42,6 +45,16 @@ histograms  ``jobs/queue_wait_seconds``, ``jobs/run_seconds``,
             explicit ``slo_ok``/``slo_miss`` counters track the per-job
             ``slo_seconds`` target (manager default, overridable per
             submit).
+
+spans       ``serve/job`` (the executor, pick to terminal state, one a
+            job, tagged job, tenant and class), ``serve/journal``
+            (every journal append), ``serve/result_wait`` (a handler
+            thread blocked in ``wait_terminal``); inside a profiler
+            capture ``raft.job``, ``raft.journal``, ``raft.result_wait``
+events      ``job_end`` a job the executor finished, in
+            ``<base_dir>/events.jsonl`` and the flight ring:
+            ``queue_wait_s``, ``run_s``, ``engine_wall_s``,
+            ``turnaround_s``, ``cached``, ``result_bytes``
 
 Tenant metric names are client-controlled strings, which must never
 grow the process-global registry without bound (the server's
@@ -54,6 +67,7 @@ the executor callable.
 
 from __future__ import annotations
 
+import json
 import os
 import re
 import threading
@@ -62,8 +76,10 @@ from collections import OrderedDict, deque
 from typing import Callable, Dict, List, Optional
 
 from . import jobs as jobs_mod
+from ..obs.metrics import SERVE_PREFIX
 from .jobs import (LIVE_STATES, QueueFullError, TERMINAL_STATES,
-                   new_job, state_record, submit_record, summarize)
+                   job_class, new_job, state_record, submit_record,
+                   summarize)
 
 _TENANT_RE = re.compile(r"[^a-zA-Z0-9_.-]+")
 
@@ -126,6 +142,9 @@ class JobManager:
         self._stop = False
         self._thread = None
         os.makedirs(self.base_dir, exist_ok=True)
+        from ..obs.events import RunEventLog
+        self._events = RunEventLog(os.path.join(self.base_dir,
+                                                "events.jsonl"))
         self._replay()
         self._update_gauges_locked()
         if start:
@@ -195,6 +214,7 @@ class JobManager:
             self.metrics.counter(
                 f"jobs/cancelled/{self._tenant_label(job['tenant'])}")
             self._update_gauges_locked()
+            self._cond.notify_all()     # a blocking ``result`` wakes
             return summarize(job)
 
     # -- queries -------------------------------------------------------
@@ -221,6 +241,35 @@ class JobManager:
 
     def result(self, job_id: str) -> dict:
         return self.result_doc(job_id)["result"]
+
+    #: The longest one ``wait_terminal`` blocks: an orphaned waiter must
+    #: not pin its handler thread for ever (the ``watch`` op's rule).
+    MAX_WAIT_SECONDS = 3600.0
+
+    def wait_terminal(self, job_id: str, timeout: float) -> dict:
+        """Block on the manager's condition until the job is terminal
+        or ``timeout`` seconds have passed; ``{"state", "result",
+        "job", "timed_out"}`` as they stand then (``result`` None where
+        the job has none: not terminal yet, cancelled, or failed without
+        a response).  Holds no lock while it waits, so submits, the
+        executor and other waiters go on; the executor's terminal
+        transition and ``cancel`` notify it."""
+        deadline = time.monotonic() + min(max(float(timeout), 0.0),
+                                          self.MAX_WAIT_SECONDS)
+        with self.metrics.serve_timer("result_wait", job=job_id), \
+                self._cond:
+            job = self._require(job_id)
+            while job["state"] not in TERMINAL_STATES:
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    break
+                self._cond.wait(left)
+                self.metrics.counter("jobs/wait_wakeups")
+            res = self._results.get(job_id)
+            return {"state": job["state"],
+                    "result": dict(res) if res is not None else None,
+                    "job": summarize(job, has_result=res is not None),
+                    "timed_out": job["state"] not in TERMINAL_STATES}
 
     def jobs_doc(self, tenant: Optional[str] = None,
                  state: Optional[str] = None,
@@ -279,12 +328,14 @@ class JobManager:
             self._stop = True
             self._cond.notify_all()
         t = self._thread
-        if t is None or not t.is_alive():
-            return True
-        if not wait:
-            return False
-        t.join(timeout=wait_timeout)
-        return not t.is_alive()
+        if t is not None and t.is_alive():
+            if not wait:
+                return False
+            t.join(timeout=wait_timeout)
+            if t.is_alive():
+                return False
+        self._events.close()
+        return True
 
     # -- internals -----------------------------------------------------
     def _require(self, job_id: str) -> dict:
@@ -371,7 +422,8 @@ class JobManager:
         executor thread or strand the in-memory registry (the scheduler
         keeps the truth; the journal is its shadow)."""
         try:
-            jobs_mod.append_record(self.journal_path, rec)
+            with self.metrics.serve_timer("journal"):
+                jobs_mod.append_record(self.journal_path, rec)
         except OSError as e:
             self.metrics.counter("jobs/journal_errors")
             import sys
@@ -554,21 +606,42 @@ class JobManager:
                 self._cond.wait(0.25)
             if self._stop and job is None:
                 return False
-            now = round(time.time(), 6)
-            self._transition_locked(job, "admitted",
-                                    patch={"admitted_ts": now})
-            self._update_gauges_locked()
+            # The executor's share of one job, pick to terminal state:
+            # what of it is not the engine's own ``run`` is what the
+            # service adds.  The admission is journaled under the lock
+            # that made the pick, so the journal's order is the order in
+            # which submits and picks happened.
+            span = self.metrics.open_span(
+                "job", SERVE_PREFIX, job=job["id"], tenant=job["tenant"],
+                job_class=job_class(job["request"]))
+            try:
+                self._transition_locked(
+                    job, "admitted",
+                    patch={"admitted_ts": round(time.time(), 6)})
+                self._update_gauges_locked()
+            except BaseException:
+                span.close()
+                raise
+        try:
+            self._run_job(job)
+        finally:
+            span.close()
+        return True
+
+    def _run_job(self, job: dict) -> None:
+        """One admitted job through the executor to its terminal state
+        (or to nothing, where a cancel won the admitted window)."""
         # Per-job artifact dir outside the lock (filesystem work).
         try:
             os.makedirs(job["job_dir"], exist_ok=True)
         except OSError:
             pass
         with self._cond:
-            if job["state"] != "queued" and job["state"] != "admitted":
+            if job["state"] != "admitted":
                 # A cancel won the admitted window: the job is
                 # terminal and must never reach the executor.
                 self._update_gauges_locked()
-                return True
+                return
             now = round(time.time(), 6)
             # Queue wait is measured from the LAST enqueue (submit, or
             # a restart's re-enqueue) — a crash's downtime is turnaround,
@@ -624,7 +697,18 @@ class JobManager:
         if slo:
             mt.counter(f"jobs/slo_{'ok' if turnaround <= slo else 'miss'}"
                        f"/{tlabel}")
-        return True
+        # As ``run_end`` carries a run's share, one line a job; after
+        # the waiters were woken, so no client waits for it.
+        self._events.emit(
+            "job_end", job=job["id"], tenant=job["tenant"],
+            job_class=job_class(job["request"]),
+            state="done" if ok else "failed", cached=cached,
+            queue_wait_s=round(wait, 6), run_s=round(run_s, 6),
+            engine_wall_s=(0.0 if cached or not isinstance(resp, dict)
+                           else resp.get("wall_seconds")),
+            turnaround_s=round(turnaround, 6),
+            result_bytes=(len(json.dumps(resp, default=str))
+                          if isinstance(resp, dict) else 0))
 
     def _execute(self, job: dict):
         """Result-cache check, then the real executor.  Returns
@@ -639,6 +723,7 @@ class JobManager:
                 self.metrics.counter("jobs/result_cache/hits")
                 return dict(hit), True
             self.metrics.counter("jobs/result_cache/misses")
+        self.metrics.counter("jobs/executed")
         resp = self._executor(job["request"], job)
         if key is not None and isinstance(resp, dict) and resp.get("ok"):
             with self._cond:

@@ -12,7 +12,8 @@ for the checkout itself, or a git-ignored copy of another commit such as
 ``_archive_check/parent``), with ``env`` added to the environment
 (``$ROOT`` stands for this checkout).  ``how`` = ``"ops"`` runs
 ``scripts/ops_per_pass.py`` instead, ``"probe"`` ``scripts/setup_probe.py``,
-``"record"`` ``scripts/record_capture.py``, ``"check"`` the CLI's ``check``
+``"record"`` ``scripts/record_capture.py``, ``"waitprobe"``
+``scripts/served_wait_probe.py``, ``"check"`` the CLI's ``check``
 with ``workload`` as its arguments (one string, split at spaces; no result
 line: what it printed of the run's totals is shown), ``"control:<name>"``
 ``benchmark/tests/controls_mesh.py <name>`` around the same run, and
@@ -49,7 +50,9 @@ SHOWN = ("stages:", "idle ", "window compiles", "trace:", "trace reduced",
          "distinct states", "states generated", "diameter", "stop reason",
          "wall seconds", "states/sec", "VIOLATION", "pipeline",
          "setup by", "  marks", "  partition", "  jit", "  compiled",
-         "  runs so far", "variant:", "window generated")
+         "  runs so far", "variant:", "window generated", "host line",
+         "served jobs", "a hit", "window by", "benchmark:",
+         '{"probe"')
 
 
 def command(root: str, workload: str, seed: int, trace: int, how: str):
@@ -61,6 +64,8 @@ def command(root: str, workload: str, seed: int, trace: int, how: str):
                                              "setup_probe.py"), root]
     if how == "record":
         return [sys.executable, "scripts/record_capture.py"]
+    if how == "waitprobe":
+        return [sys.executable, "scripts/served_wait_probe.py"]
     if how == "check":
         return [sys.executable, "-m", "raft_tla_tpu", "check",
                 *workload.split()]

@@ -944,3 +944,393 @@ def test_server_restart_replays_job_journal(tmp_path):
     finally:
         srv2.shutdown()
         srv2.server_close()
+
+
+# ---------------------------------------------------------------------------
+# The blocking wait (ISSUE 50): ``result`` with ``wait``, ``submit --wait``,
+# the serving layer's spans and ``job_end``, the plain reference's order.
+
+def _waiter(fn, *args):
+    """Run ``fn(*args)`` on a thread; ``box`` gets (answer, time.time()
+    at the return)."""
+    box = {}
+
+    def go():
+        box["answer"] = fn(*args)
+        box["at"] = time.time()
+
+    t = threading.Thread(target=go, daemon=True)
+    t.start()
+    return t, box
+
+
+def test_wait_terminal_returns_when_the_job_ends_not_a_poll_later(tmp_path):
+    def ex(req, job):
+        time.sleep(0.3)
+        return {"ok": True, "distinct": 7}
+
+    m = JobManager(str(tmp_path), executor=ex)
+    try:
+        s = m.submit({"op": "check"}, tenant="t")
+        t, box = _waiter(m.wait_terminal, s["id"], 30.0)
+        t.join(timeout=10.0)
+        assert not t.is_alive()
+        got = box["answer"]
+        assert got["state"] == "done" and got["timed_out"] is False
+        assert got["result"] == {"ok": True, "distinct": 7}
+        assert got["job"]["id"] == s["id"] and got["job"]["has_result"]
+        # On the manager's own timestamps: woken by the terminal
+        # transition's notify, not by a timer.
+        assert 0.0 <= box["at"] - got["job"]["finished_ts"] < 0.05
+        assert m.metrics.counter_value("jobs/wait_wakeups") >= 1
+        assert m.metrics.snapshot()["histograms"][
+            "serve/result_wait"]["count"] == 1
+        # An already terminal job answers at once.
+        t0 = time.monotonic()
+        assert m.wait_terminal(s["id"], 30.0)["state"] == "done"
+        assert time.monotonic() - t0 < 0.05
+        with pytest.raises(KeyError):
+            m.wait_terminal("nope", 0.1)
+    finally:
+        m.close(wait=False)
+
+
+def test_wait_terminal_times_out_with_the_state_it_has(tmp_path):
+    gate = threading.Event()
+
+    def ex(req, job):
+        gate.wait(10.0)
+        return {"ok": True}
+
+    m = JobManager(str(tmp_path), executor=ex)
+    try:
+        s = m.submit({"op": "check"}, tenant="t")
+        t0 = time.monotonic()
+        got = m.wait_terminal(s["id"], 0.2)
+        assert 0.2 <= time.monotonic() - t0 < 2.0
+        assert got["timed_out"] is True and got["result"] is None
+        assert got["state"] in ("queued", "admitted", "running")
+        gate.set()
+        assert m.wait_terminal(s["id"], 10.0)["state"] == "done"
+    finally:
+        gate.set()
+        m.close(wait=False)
+
+
+def test_wait_terminal_wakes_on_cancel(tmp_path):
+    m = JobManager(str(tmp_path), executor=lambda r, j: {"ok": True},
+                   start=False)
+    s = m.submit({"op": "check"}, tenant="t")
+    t, box = _waiter(m.wait_terminal, s["id"], 30.0)
+    time.sleep(0.1)
+    assert t.is_alive()
+    m.cancel(s["id"])
+    t.join(timeout=5.0)
+    assert not t.is_alive()
+    got = box["answer"]
+    assert got["state"] == "cancelled" and got["result"] is None
+    assert got["timed_out"] is False
+    assert box["at"] - got["job"]["finished_ts"] < 0.05
+
+
+class _Recorder:
+    """A duck-typed span tracer (obs/metrics.py ``registry.tracer``)."""
+
+    def __init__(self):
+        self.spans, self._lock = [], threading.Lock()
+
+    def begin(self, name, args):
+        return name, time.perf_counter(), args, threading.get_ident()
+
+    def end(self, token):
+        name, start, args, tid = token
+        with self._lock:
+            self.spans.append((name, start, time.perf_counter(), args, tid))
+
+
+def test_the_spans_and_the_job_end_event_of_one_job(tmp_path):
+    from raft_tla_tpu.obs import MetricsRegistry
+    mt = MetricsRegistry()
+    rec = mt.tracer = _Recorder()
+
+    def ex(req, job):
+        with mt.scope("run"):
+            time.sleep(0.05)
+        return {"ok": True, "wall_seconds": 0.05, "distinct": 3}
+
+    m = JobManager(str(tmp_path), executor=ex, metrics=mt)
+    try:
+        s1 = m.submit({"op": "check", "mode": "swarm"}, tenant="acme",
+                      cache_key="k")
+        assert m.wait_terminal(s1["id"], 10.0)["state"] == "done"
+        s2 = m.submit({"op": "check", "mode": "swarm"}, tenant="acme",
+                      cache_key="k")
+        assert m.wait_terminal(s2["id"], 10.0)["job"]["cached"] is True
+    finally:
+        assert m.close(wait=True)
+    by = {}
+    for span in rec.spans:
+        by.setdefault(span[0], []).append(span)
+    (j1, j2) = sorted(by["job"], key=lambda s: s[1])
+    assert j1[3] == {"job": s1["id"], "tenant": "acme",
+                     "job_class": "swarm"}
+    inside = lambda s, outer: (s[4] == outer[4] and outer[1] <= s[1]  # noqa: E731
+                               and s[2] <= outer[2])
+    # The executor's span holds the run and the three transitions'
+    # journal appends; the submit's append lies on the caller's thread.
+    assert [inside(r, j1) for r in by["run"]] == [True]
+    assert sum(inside(j, j1) for j in by["journal"]) == 3
+    assert sum(inside(j, j2) for j in by["journal"]) == 3
+    assert len(by["journal"]) == 8 and len(by["result_wait"]) == 2
+    assert mt.counter_value("jobs/executed") == 1
+    hist = mt.snapshot()["histograms"]
+    assert hist["serve/job"]["count"] == 2
+    assert hist["serve/journal"]["count"] == 8
+    assert not any(k.startswith("phase/") for k in hist)
+    events = [json.loads(line) for line in
+              open(os.path.join(str(tmp_path), "events.jsonl"))]
+    e1, e2 = [e for e in events if e["event"] == "job_end"]
+    assert (e1["job"], e1["cached"], e1["state"]) == (s1["id"], False,
+                                                      "done")
+    assert e1["job_class"] == "swarm" and e1["tenant"] == "acme"
+    assert e1["engine_wall_s"] == 0.05
+    assert e1["turnaround_s"] >= e1["run_s"] >= 0.05
+    assert e1["queue_wait_s"] >= 0
+    assert e1["result_bytes"] == len(json.dumps(m.result(s1["id"])))
+    assert (e2["job"], e2["cached"], e2["engine_wall_s"]) == (
+        s2["id"], True, 0.0)
+    assert e2["result_bytes"] == e1["result_bytes"]
+
+
+def _reference_served():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "reference_served",
+        os.path.join(REPO, "benchmark", "reference", "served.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_the_reference_imports_nothing_of_the_program():
+    with open(os.path.join(REPO, "benchmark", "reference",
+                           "served.py")) as f:
+        text = f.read()
+    assert "raft_tla_tpu" not in text.split('"""', 2)[2]
+
+
+@pytest.mark.parametrize("n_tenants", [3, 4, 5])
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_the_reference_start_order_equals_the_managers(tmp_path, seed,
+                                                       n_tenants):
+    """Seeded submit logs with bursts, cancels and repeated cacheable
+    requests: the order in which ``JobManager`` starts the jobs, and the
+    jobs its result cache answers, are the plain reference's
+    (``benchmark/reference/served.py``), which replays the journal's
+    order of submits and picks with the keys THIS test sent."""
+    import random
+    rng = random.Random(1000 * seed + n_tenants)
+    m = JobManager(str(tmp_path), executor=lambda r, j: {"ok": True},
+                   start=False, queue_capacity=256)
+    tenants = [f"t{i}" for i in range(n_tenants)]
+    sent = {}
+    queued = []
+    for _round in range(30):
+        for _ in range(rng.choice([0, 1, 1, 2, 5])):        # a burst
+            t = rng.choice(tenants)
+            key = rng.choice([None, None, "a", "b", "c"])
+            s = m.submit({"op": "check"}, tenant=t, cache_key=key)
+            sent[s["id"]] = (t, key)
+            queued.append(s["id"])
+        if queued and rng.random() < 0.2:
+            victim = queued.pop(rng.randrange(len(queued)))
+            m.cancel(victim)
+        for _ in range(rng.choice([0, 1, 2, 3])):
+            if m.jobs_doc()["queue_depth"]:
+                assert m._run_one()
+        queued = [j["id"] for j in m.jobs_doc(state="queued")["jobs"]]
+    while m.jobs_doc()["queue_depth"]:
+        assert m._run_one()
+    log, starts = [], []
+    for rec in map(json.loads, open(m.journal_path)):
+        if rec["rec"] == "submit":
+            t, key = sent[rec["job"]["id"]]
+            log.append({"ev": "submit", "job": rec["job"]["id"],
+                        "tenant": t, "key": key})
+        elif rec["state"] == "admitted":
+            log.append({"ev": "pick"})
+            starts.append(rec["id"])
+        elif rec["state"] == "cancelled":
+            log.append({"ev": "cancel", "job": rec["id"]})
+        elif rec["state"] in ("done", "failed"):
+            log.append({"ev": "end", "job": rec["id"],
+                        "ok": rec["state"] == "done"})
+    want = _reference_served().schedule(log)
+    assert len(starts) >= 20
+    assert starts == want["starts"]
+    assert {j["id"] for j in m.jobs_doc()["jobs"] if j["cached"]} \
+        == want["hits"]
+    assert want["hits"], "the log holds no repeat of a cacheable request"
+
+
+def _stub_server(tmp_path, executor):
+    """A checker service on a stub executor (no engine)."""
+    srv = srv_mod.CheckerServer(("127.0.0.1", 0), srv_mod._Handler)
+    srv.jobs = JobManager(str(tmp_path / "jobs"), executor=executor,
+                          metrics=srv_mod._METRICS)
+    t = threading.Thread(target=srv.serve_forever, daemon=True)
+    t.start()
+    return srv
+
+
+def _submit_args(srv, **over):
+    import argparse
+    args = dict(server=f"127.0.0.1:{srv.server_address[1]}", timeout=15.0,
+                poll_interval=1.0)
+    args.update(over)
+    return argparse.Namespace(**args)
+
+
+def test_result_wait_over_the_socket_holds_no_lock(tmp_path):
+    """The op itself: a waiting client is one handler thread; ``stats``,
+    ``status`` and a second waiter answer meanwhile; the answer brings
+    state, result and summary; without ``wait`` the op is what it was."""
+    gate = threading.Event()
+
+    def ex(req, job):
+        gate.wait(20.0)
+        return {"ok": True, "distinct": 11}
+
+    srv = _stub_server(tmp_path, ex)
+    addr = srv.server_address
+    try:
+        assert roundtrip(addr, {"op": "ping"})["wait"] is True
+        job = roundtrip(addr, {"op": "submit", "tenant": "t",
+                               "job": {"op": "check",
+                                       "cfg_text": "x"}})["job"]
+        t, box = _waiter(roundtrip, addr, {"op": "result",
+                                           "job_id": job["id"],
+                                           "wait": 30})
+        time.sleep(0.2)
+        assert t.is_alive()
+        assert roundtrip(addr, {"op": "stats"})["ok"]
+        assert roundtrip(addr, {"op": "status",
+                                "job_id": job["id"]})["job"]["state"] \
+            == "running"
+        early = roundtrip(addr, {"op": "result", "job_id": job["id"],
+                                 "wait": 0.1})
+        assert early["ok"] and early["timed_out"] and early["result"] is None
+        plain = roundtrip(addr, {"op": "result", "job_id": job["id"]})
+        assert not plain["ok"] and "no result yet" in plain["error"]
+        gate.set()
+        t.join(timeout=10.0)
+        assert not t.is_alive()
+        got = box["answer"]
+        assert got["ok"] and got["state"] == "done"
+        assert got["result"] == {"ok": True, "distinct": 11}
+        assert got["job"]["id"] == job["id"] and not got["timed_out"]
+        assert box["at"] - got["job"]["finished_ts"] < 0.05
+        assert roundtrip(addr, {"op": "result", "job_id": job["id"]}) == {
+            "ok": True, "state": "done",
+            "result": {"ok": True, "distinct": 11}}
+        assert srv_mod._METRICS.counter_value("server/result_bytes") > 0
+        bad = roundtrip(addr, {"op": "result", "job_id": "nope",
+                               "wait": 1})
+        assert not bad["ok"] and "unknown job" in bad["error"]
+    finally:
+        gate.set()
+        srv.shutdown()
+        srv.server_close()
+
+
+def test_submit_wait_blocks_on_the_new_server_and_polls_an_old_one(
+        tmp_path, monkeypatch, capsys):
+    from raft_tla_tpu import cli
+
+    def ex(req, job):
+        time.sleep(0.4)
+        return {"ok": True, "distinct": 5, "generated": 9, "diameter": 2,
+                "stop_reason": "exhausted", "violation": None,
+                "deadlock": None}
+
+    srv = _stub_server(tmp_path, ex)
+    addr = srv.server_address
+    mt = srv_mod._METRICS
+    try:
+        # The new server: no status poll, the answer within 50 ms of the
+        # job's end at the DEFAULT --poll-interval of a second.
+        polls = mt.counter_value("server/requests/status")
+        job = roundtrip(addr, {"op": "submit", "tenant": "t",
+                               "job": {"op": "check",
+                                       "cfg_text": "x"}})["job"]
+        done, doc = cli._wait_for_job(_submit_args(srv), job)
+        at = time.time()
+        assert done["state"] == "done" and doc["distinct"] == 5
+        assert at - done["finished_ts"] < 0.05
+        assert mt.counter_value("server/requests/status") == polls
+        # The whole subcommand.
+        rc = cli.main(["submit", CFG, "--server",
+                       f"127.0.0.1:{addr[1]}", "--wait"])
+        assert rc == 0
+        assert "distinct 5 | generated 9" in capsys.readouterr().out
+        assert mt.counter_value("server/requests/status") == polls
+
+        # A server from before the op: its ping has no "wait" and its
+        # ``result`` knows nothing of the field.
+        orig = srv_mod.handle_request
+
+        def old_server(req, manager=None):
+            if req.get("op") == "result":
+                req = {k: v for k, v in req.items() if k != "wait"}
+            resp = orig(req, manager)
+            if req.get("op") == "ping":
+                resp.pop("wait", None)
+            return resp
+
+        monkeypatch.setattr(srv_mod, "handle_request", old_server)
+        rc = cli.main(["submit", CFG, "--server", f"127.0.0.1:{addr[1]}",
+                       "--wait", "--poll-interval", "0.05"])
+        assert rc == 0
+        assert "distinct 5 | generated 9" in capsys.readouterr().out
+        assert mt.counter_value("server/requests/status") > polls
+    finally:
+        srv.shutdown()
+        srv.server_close()
+
+
+def test_a_served_check_leaves_its_spans_and_job_end(jobsrv):
+    """Through the real service and engine: ``job_setup`` and
+    ``job_respond`` around the engine's run, inside the executor's
+    ``job``; the ``job_end`` line beside the journal."""
+    srv, _hist = jobsrv
+    addr = srv.server_address
+    before = roundtrip(addr, {"op": "stats"})["metrics"]
+    job = roundtrip(addr, {"op": "submit", "tenant": "spans",
+                           "job": dict(BASE, max_diameter=3)})["job"]
+    got = roundtrip(addr, {"op": "result", "job_id": job["id"],
+                           "wait": 600})
+    assert got["state"] == "done" and got["result"]["distinct"] == 113
+    after = roundtrip(addr, {"op": "stats"})["metrics"]
+
+    def grew(name):
+        a = after["histograms"].get(name, {"count": 0, "total": 0.0})
+        b = before["histograms"].get(name, {"count": 0, "total": 0.0})
+        return a["count"] - b["count"], a["total"] - b["total"]
+
+    (n_job, s_job), (n_set, s_set) = grew("serve/job"), grew(
+        "serve/job_setup")
+    (n_run, s_run), (n_resp, s_resp) = grew("scope/run"), grew(
+        "serve/job_respond")
+    assert (n_job, n_set, n_run, n_resp) == (1, 1, 1, 1)
+    assert s_job >= s_set + s_run + s_resp > 0
+    assert grew("serve/journal")[0] == 4
+    assert grew("serve/request/result")[0] == 1
+    assert grew("phase/request/result")[0] == 0
+    assert after["counters"]["jobs/executed"] \
+        - before["counters"].get("jobs/executed", 0) == 1
+    ends = [e for e in map(json.loads, open(os.path.join(
+        srv.jobs.base_dir, "events.jsonl")))
+        if e["event"] == "job_end" and e["job"] == job["id"]]
+    assert len(ends) == 1 and ends[0]["cached"] is False
+    assert ends[0]["engine_wall_s"] == got["result"]["wall_seconds"]
+    assert ends[0]["run_s"] >= ends[0]["engine_wall_s"]
