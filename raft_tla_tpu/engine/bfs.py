@@ -709,6 +709,12 @@ class BFSEngine:
         # push the load at most to 1/2 + 1/8 — far from where double-hash
         # probes start failing.  (fpset rounds up to a power of two.)
         self._seen_cap = max(sreq, 8 * K)
+        # What a fresh run() starts its table at: the capacity the last
+        # run from roots needed (``_keep_capacity``), so that the next run
+        # of the same roots pays no growth again.  Set here, so a build
+        # (the degrade rebuild's re-entrant one too) knows only its own
+        # size; ``chunk_avals`` and a resume keep ``_seen_cap``.
+        self._seen_cap_kept = fpset._capacity(self._seen_cap)
         # Queue capacity: floored at one worst-case batch (K rows, every
         # compacted candidate new) — a batch entering at/below the spill
         # watermark (Q - K) can then never overflow.  Rounded to a multiple
@@ -1092,6 +1098,10 @@ class BFSEngine:
             self._xla_capture = None
         self._run_roots = len(init_states or ())
         self._restore_counts = {}   # a resume's rebuild fills it
+        self._start_caps = start_caps = self._start_capacity(resume)
+        if start_caps and (start_caps["seen_capacity"]
+                           > start_caps["seen_capacity_configured"]):
+            mt.counter("engine/seen_capacity_kept")
         evlog.emit(
             "run_start", engine=type(self).__name__, dims=repr(self.dims),
             batch=cfg.batch, sync_every=cfg.sync_every,
@@ -1099,6 +1109,7 @@ class BFSEngine:
             # States the run was given: 1 from Init, more from roots past
             # it; 0 on a resume, whose snapshot brings its own.
             roots=self._run_roots,
+            **start_caps,
             memory=device_memory_stats(),
             process=process_record().run_start())
         self._cur_res = None
@@ -1180,6 +1191,7 @@ class BFSEngine:
                     seen_capacity=int(mt.gauge_value(
                         "engine/seen_capacity")) or None,
                     seen_size=int(mt.gauge_value("engine/seen_size")),
+                    seen_start=self._start_caps,
                     observed_collisions=observed)
                 report_mod.feed_metrics(res.report, mt)
                 evlog.emit("statespace", report=res.report)
@@ -1269,6 +1281,30 @@ class BFSEngine:
         itself resumes after a degradation holds the run's own counts."""
         if self._family_base is None:
             self._family_base = dict(resume.action_counts)
+
+    def _start_capacity(self, resume) -> dict:
+        """``run_start``'s ``seen_capacity`` (slots of the table a run
+        from roots starts at: what the last such run of this engine
+        needed, ``_keep_capacity``) and ``seen_capacity_configured`` (the
+        build's own).  Neither for a resume, whose table is sized from
+        its snapshot's keys when ``restore`` has loaded them, nor from
+        the mesh engine, whose shards stay as grown."""
+        if resume is not None:
+            return {}
+        return {"seen_capacity": self._seen_cap_kept,
+                "seen_capacity_configured": fpset._capacity(self._seen_cap)}
+
+    def _keep_capacity(self, seen) -> None:
+        """At the end of a run from roots: the next one starts its table
+        at what this one needed, the power of two that holds its final
+        keys at a load of 1/2 or less.  No smaller than the configured
+        size, and no larger than the table the run ended with, whose
+        programs are loaded.  So the size follows the traffic down as
+        well as up: a shallow check after a deep one on the same engine
+        (the server's cache) probes a small table again."""
+        need = fpset._capacity(2 * int(seen.size))
+        self._seen_cap_kept = min(
+            len(seen.hi), max(need, fpset._capacity(self._seen_cap)))
 
     def _run_end_extra(self) -> dict:
         """Further fields of ``run_end``: what a resume's seen-set
@@ -1501,8 +1537,13 @@ class BFSEngine:
             QA = Q + self._PAD
             qcur = jax.device_put(jnp.zeros((QA, sw), jnp.uint8), dev)
             qnext = jax.device_put(jnp.zeros((QA, sw), jnp.uint8), dev)
-            seen = jax.device_put(fpset.empty(self._seen_cap), dev)
-            next_count = jnp.int32(0)
+            # A fresh run starts at what the last one needed; a resume
+            # sizes its own table from the snapshot (``restore``).
+            seen = jax.device_put(
+                fpset.empty(self._seen_cap_kept if resume is None
+                            else self._seen_cap), dev)
+            # Committed, as ingest hands it back: see the warm-up.
+            next_count = jax.device_put(jnp.int32(0), dev)
             # The async spill's spare queue (below).
             free_q: List = [
                 jax.device_put(jnp.zeros((QA, sw), jnp.uint8), dev)]
@@ -1545,24 +1586,27 @@ class BFSEngine:
         # Timed as phase "warmup": compilation is off the budget clock but
         # on the telemetry one, so event phase sums still cover the wall.
         with mt.phase_timer("warmup"):
-            out = self._ingest(jnp.zeros((B, sw), jnp.uint8),
-                               jnp.zeros((B,), bool),
-                               qnext, next_count, seen)
-            qnext, next_count, seen = out[0], out[1], out[2]
-            # Placement-fixpoint second ingest (same rationale as the
-            # chunk's fixpoint call below): the first real ingest passes
-            # the warm-up's COMMITTED outputs back in, a different
-            # argument placement than the fresh jnp.int32(0) above —
-            # without this call that variant compiled ON the StopAfter
-            # clock (~5 s on a cold cache, measured 2026-07-31: the whole
-            # reason the literal Smokeraft.cfg's 1-second budget landed
-            # at ~4 s, VERDICT r4 weak #4).
-            out = self._ingest(jnp.zeros((B, sw), jnp.uint8),
-                               jnp.zeros((B,), bool),
-                               qnext, next_count, seen)
-            qnext, next_count, seen = out[0], out[1], out[2]
+            # ``next_count`` goes in committed, as every real ingest call
+            # gets it: the last call's output, or after a spill of the
+            # roots a zero that is committed too.  The jit cache keys on
+            # argument placement: a fresh jnp.int32(0) anywhere is a second
+            # ingest program, and the first real call with the other then
+            # compiles ON the StopAfter clock (~5 s on a cold cache,
+            # measured 2026-07-31: why the literal Smokeraft.cfg's 1-second
+            # budget landed at ~4 s, VERDICT r4 weak #4).  One placement
+            # is also one program a capacity, which a growth loads for the
+            # table it makes (``_grow_precompiled``).
+            qnext, next_count, seen = self._ingest_nothing(
+                qnext, next_count, seen)
+            # Once more on the first call's outputs, as the chunk below:
+            # what a real call passes back in.
+            qnext, next_count, seen = self._ingest_nothing(
+                qnext, next_count, seen)
+            # The chunk takes its counts as the level loop passes them,
+            # fresh scalars, for the same reason: not ingest's committed
+            # ``next_count``.
             out = self._chunk(qcur, jnp.int32(0), jnp.int32(0),
-                              qnext, next_count, seen, tbuf, jnp.int32(0),
+                              qnext, jnp.int32(0), seen, tbuf, jnp.int32(0),
                               jnp.int32(self._CH))
             qnext, seen, tbuf = out[0], out[1], out[2]
             # Second zero-trip call with the first call's OUTPUTS: jit
@@ -1699,7 +1743,8 @@ class BFSEngine:
                     with mt.phase_timer("spill"):
                         spill_next.append(  # watermark is never blown
                             np.asarray(qnext[:nc]), copy=True)
-                        next_count = jnp.int32(0)
+                        # The warm-up's placement, not a fresh scalar.
+                        next_count = jax.device_put(jnp.int32(0), dev)
                     evlog.emit("spill", rows=nc, level=0, where="ingest")
                 if self._check_violation(res, vinfo):
                     break
@@ -2000,6 +2045,8 @@ class BFSEngine:
             pending, spill_next = spill_next, pending
 
         res.wall_seconds = time.time() - t0
+        if resume is None:
+            self._keep_capacity(seen)
         return res
 
     # ------------------------------------------------------------------
@@ -2096,13 +2143,26 @@ class BFSEngine:
         return decode_state(StateBatch(*row), self.dims), g
 
     # ------------------------------------------------------------------
+    def _ingest_nothing(self, qnext, next_count, seen):
+        """The ingest program on a batch with no valid row: nothing is
+        inserted or enqueued, and the program for this table's capacity
+        is compiled or loaded.  ``next_count`` committed, as ingest's own
+        output is.  Returns (qnext, next_count, seen)."""
+        out = self._ingest(jnp.zeros((self._B, self._sw), jnp.uint8),
+                           jnp.zeros((self._B,), bool),
+                           qnext, next_count, seen)
+        return out[0], out[1], out[2]
+
     def _grow_precompiled(self, seen, size, qcur, qnext, next_count, tbuf,
                           t0):
         """Grow the seen set when loaded past threshold, pre-compile the
         chunk program at the new table shape with a zero-trip call, and
         keep the rehash + compile off the duration clock — the StopAfter
         budget measures checking time, not compilation (same rule as the
-        warm-up).  Returns (seen, qnext, tbuf, t0)."""
+        warm-up).  The engine's next run from roots may start at the
+        capacity reached (``_keep_capacity``), so the ingest program, which
+        that run's roots go through, is loaded for the new table here too.
+        Returns (seen, qnext, tbuf, t0)."""
         if self._seen_overloaded(seen, size):
             with self.metrics.phase_timer("grow") as grow:
                 # Committed, like every buffer the chunk takes (see
@@ -2110,8 +2170,10 @@ class BFSEngine:
                 # uncommitted inputs, the chunk hands it back committed,
                 # and the jit cache keys on that — the first real call
                 # after a growth compiled the chunk again, on the clock.
-                seen = jax.device_put(self._maybe_grow_seen(seen, size),
-                                      qcur.devices().pop())
+                dev = qcur.devices().pop()
+                seen = jax.device_put(self._maybe_grow_seen(seen, size), dev)
+                qnext, _count, seen = self._ingest_nothing(
+                    qnext, jax.device_put(jnp.int32(next_count), dev), seen)
                 out = self._chunk(qcur, jnp.int32(0), jnp.int32(0), qnext,
                                   jnp.int32(next_count), seen, tbuf,
                                   jnp.int32(0), jnp.int32(1))

@@ -6,10 +6,13 @@ registry is the live half): engines append one JSON object per line for
 ``checkpoint``, ``violation``, ``deadlock``, and ``run_end``.  Every
 event carries ``ts`` (epoch seconds) and ``elapsed_seconds`` (since the
 log was opened); level and end events add live counters, the per-phase
-wall-time breakdown, and the device memory probe.  The JSONL file is the
-supported interface for dashboards and regression tooling — the bench
-harness fails loudly when a run leaves it missing or malformed
-(``validate_run_events``).
+wall-time breakdown, and the device memory probe; a ``BFSEngine``'s
+``run_start`` of a run from roots carries ``seen_capacity`` (slots of
+the table the run starts at: what the last such run of the same engine
+needed) beside ``seen_capacity_configured``, a resume's neither.  The
+JSONL file is the supported interface for dashboards and regression
+tooling — the bench harness fails loudly when a run leaves it missing
+or malformed (``validate_run_events``).
 
 Placement: ``EngineConfig.events_out`` names the file; when unset it
 defaults to ``events.jsonl`` next to the checkpoint dir (TLC's states/

@@ -24,7 +24,9 @@ record at each level boundary) into one JSON-able dict:
   the level-width curve ScalaBFS/PULSE-style frontier analyses read;
 - ``out_degree``: mean enabled successors per expanded parent, total and
   per action family (from the same packed stats as coverage);
-- ``seen_set``: final load factor, capacity, growths — the load curve.
+- ``seen_set``: final load factor, capacity, growths — the load curve —
+  and the capacity the run started at beside the configured one (a warm
+  engine starts its next run from roots at what the last one needed).
 
 Everything is host-side arithmetic over already-fetched counters: the
 report can never perturb engine results (bit-identity on/off is tested).
@@ -56,6 +58,7 @@ def collision_probability(distinct: int, generated: int) -> float:
 def build_report(result, coverage=None, level_stats=None,
                  seen_capacity: Optional[int] = None,
                  seen_size: Optional[int] = None,
+                 seen_start: Optional[dict] = None,
                  observed_collisions: int = 0) -> dict:
     """Assemble the TLC-parity report dict from a finished run.
 
@@ -65,7 +68,9 @@ def build_report(result, coverage=None, level_stats=None,
     trace-only callers); ``level_stats`` the engines' per-level snapshot
     list (each ``{"level", "frontier", "distinct", "generated",
     "seen_size", "seen_capacity"}``) — levels missing from it (resumed
-    prefixes) still appear in the table with width only."""
+    prefixes) still appear in the table with width only;
+    ``seen_start`` the run's ``run_start`` pair (``seen_capacity``, the
+    table it started at, and ``seen_capacity_configured``)."""
     levels: List[int] = list(getattr(result, "levels", []) or [])
     by_level: Dict[int, dict] = {int(d.get("level", -1)): d
                                  for d in (level_stats or [])}
@@ -110,6 +115,10 @@ def build_report(result, coverage=None, level_stats=None,
         seen["final_load"] = round(
             (seen_size if seen_size is not None else distinct)
             / seen_capacity, 4)
+    if seen_start:
+        seen["start_capacity"] = int(seen_start["seen_capacity"])
+        seen["configured_capacity"] = int(
+            seen_start["seen_capacity_configured"])
     growths = list(getattr(result, "growth_stalls", ()) or ())
     if growths:
         seen["growths"] = [[int(c), float(s)] for c, s in growths]
@@ -211,6 +220,11 @@ def render_report(report: dict) -> str:
     if seen.get("capacity"):
         g = (f", {len(seen['growths'])} growth(s)"
              if seen.get("growths") else "")
+        kept = seen.get("start_capacity", 0)
+        if kept > seen.get("configured_capacity", kept):
+            # A warm engine's later run: the table an earlier run grew.
+            g += (f", started at {kept:,} kept from an earlier run "
+                  f"(configured {seen['configured_capacity']:,})")
         lines.append(f"  seen-set: final load {seen['final_load']:.3f} "
                      f"of {seen['capacity']:,} keys{g}")
     groups = report.get("family_groups") or []

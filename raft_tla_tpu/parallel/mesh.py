@@ -771,6 +771,12 @@ class MeshBFSEngine:
         from ..engine.bfs import BFSEngine
         return BFSEngine._xla_profile_dir(self)
 
+    def _start_capacity(self, resume) -> dict:
+        """Nothing for ``run_start``: the shards are rebuilt in place
+        when they grow (``_grow``) and every run takes them as they are,
+        so no run starts at another size than the engine holds."""
+        return {}
+
     # The level event, the run-end report, the level span, the loop's
     # work counters and the replay are the single-chip engine's own
     # (they touch nothing of its).

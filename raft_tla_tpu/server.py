@@ -7,7 +7,9 @@ the TLC module override shipped in ``native/tlc_override/`` (a Java
 operator that forwards a ``.cfg`` to this service and returns the result
 as a TLA+ record), but also ad-hoc drivers and notebooks.  The service
 holds the compiled engines warm between requests, so repeat checks of the
-same model skip XLA compilation.
+same model skip XLA compilation; an engine also keeps the seen-set
+capacity its last check needed (engine/bfs.py ``_keep_capacity``), so a
+repeat check starts its empty table there and pays no growth again.
 
 Protocol: newline-delimited JSON over TCP; one request per line, one
 response per line.  Requests:

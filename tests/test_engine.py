@@ -775,3 +775,241 @@ def test_every_level_matches_the_pinned_profile(cfg, pins, through,
         path = eng.replay(res.violation.fingerprint)
         assert len(path) == violation_depth + 1
         assert LEADER in path[-1][1].role
+
+
+# -- what a warm engine keeps from one run() to the next -------------------
+
+@pytest.fixture(scope="module")
+def warm_canary(tmp_path_factory):
+    """ONE engine of the canary cfg at its own sizes (a 65,536-slot table
+    that doubles twice a check), run seven times: from the root with
+    snapshots on, from the root again, from the first run's level-3
+    snapshot, from the root once more, from the root to level 2 only,
+    from the root twice more, and after ``_rebuild_at_batch`` only looked
+    at.  Per run: result, replayed steps, events, and the registry's
+    ``engine/seen_capacity_kept`` afterwards."""
+    import os
+    from raft_tla_tpu.engine.check import (engine_config_from_backend,
+                                           initial_states, make_engine)
+    from raft_tla_tpu.obs import validate_run_events
+    from raft_tla_tpu.utils.cfg import load_config
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    d = tmp_path_factory.mktemp("warm_canary")
+    setup = load_config(os.path.join(here, "configs/MCraft_noleader.cfg"))
+    eng = make_engine(setup, engine_config_from_backend(setup))
+    roots = initial_states(setup)
+    out = {"setup": setup, "engine": eng, "runs": {},
+           "avals_before": eng.chunk_avals()}
+
+    def run(name, **kw):
+        eng.config.events_out = str(d / f"{name}.jsonl")
+        res = eng.run(**kw)
+        out["runs"][name] = {
+            "res": res, "steps": (eng.replay(res.violation.fingerprint)
+                                  if res.violation else None),
+            "events": validate_run_events(eng.config.events_out),
+            "kept": eng.metrics.counter_value("engine/seen_capacity_kept")}
+
+    eng.config.checkpoint_dir = str(d / "ck")
+    run("first", init_states=roots)
+    eng.config.checkpoint_dir = None
+    run("second", init_states=roots)
+    run("resumed", resume=str(d / "ck" / "level_00003.npz"))
+    run("fourth", init_states=roots)
+    eng.config.max_diameter = 2
+    run("shallow", init_states=roots)
+    eng.config.max_diameter = None
+    run("after_shallow", init_states=roots)
+    run("last", init_states=roots)
+    out["avals_after"] = eng.chunk_avals()
+    out["kept_before_rebuild"] = eng._seen_cap_kept
+    eng._rebuild_at_batch(128)
+    out["start_after_rebuild"] = eng._start_capacity(None)
+    return out
+
+
+def _events(run, kind):
+    return [e for e in run["events"] if e["event"] == kind]
+
+
+def test_a_first_run_grows_as_before(warm_canary):
+    first = warm_canary["runs"]["first"]
+    assert [c for c, _s in first["res"].growth_stalls] == [1 << 17, 1 << 18]
+    assert [e["capacity"] for e in _events(first, "fpset_resize")] == [
+        1 << 17, 1 << 18]
+    (start,) = _events(first, "run_start")
+    assert (start["seen_capacity"], start["seen_capacity_configured"]) == (
+        1 << 16, 1 << 16)
+    assert _events(first, "run_end")[0]["growth_stalls"] == 2
+    assert first["kept"] == 0
+    seen = first["res"].report["seen_set"]
+    assert (seen["start_capacity"], seen["configured_capacity"],
+            seen["capacity"]) == (1 << 16, 1 << 16, 1 << 18)
+
+
+@pytest.mark.parametrize("name, kept", [("second", 1), ("fourth", 2)])
+def test_a_later_run_starts_where_the_engine_grew_to(warm_canary, name,
+                                                     kept):
+    """No growth, no ``fpset_resize``: the table starts at the capacity
+    the first run ended at, ``run_start`` says so, and the registry counts
+    the run once (the resumed run between them not at all)."""
+    later = warm_canary["runs"][name]
+    assert later["res"].growth_stalls == []
+    assert _events(later, "fpset_resize") == []
+    (start,) = _events(later, "run_start")
+    assert (start["seen_capacity"], start["seen_capacity_configured"]) == (
+        1 << 18, 1 << 16)
+    (end,) = _events(later, "run_end")
+    assert end["growth_stalls"] == 0 and "grow" not in end["phase_seconds"]
+    assert later["kept"] == kept
+    from raft_tla_tpu.obs import report as report_mod
+    assert "started at 262,144 kept from an earlier run (configured " \
+        "65,536)" in report_mod.render_report(later["res"].report)
+
+
+@pytest.mark.parametrize("what", ["levels", "distinct", "generated",
+                                  "action_counts", "violation", "trace"])
+def test_a_later_run_finds_what_the_first_found(warm_canary, what):
+    """A larger empty table changes where keys land, not which states are
+    new: the same counts a level, the same violation at the same depth,
+    and a trace that replays to the same states."""
+    first, later = (warm_canary["runs"][n] for n in ("first", "second"))
+    if what == "violation":
+        got, want = (
+            (r["res"].stop_reason, r["res"].violation.invariant,
+             len(r["steps"]) - 1, r["res"].diameter)
+            for r in (later, first))
+        assert want == ("violation", "NoLeaderElected", 9, 8)
+    elif what == "trace":
+        got, want = later["steps"], first["steps"]
+        assert got[0][0] == -1 and LEADER in got[-1][1].role
+        dims = warm_canary["engine"].dims
+        for (_a, prev), (_b, nxt) in zip(got, got[1:]):
+            assert nxt in orc.successor_set(prev, dims)
+    else:
+        got, want = (getattr(r["res"], what) for r in (later, first))
+    assert got == want
+
+
+def test_the_kept_capacity_follows_the_traffic_down_as_well(warm_canary):
+    """A check to level 2 on the warm engine starts at the large table
+    and ends needing the configured one; the full check after it starts
+    there and grows twice, as a first run does, and the one after that
+    starts at what it grew to again.  Every program was loaded by the
+    first run: no later one compiles."""
+    shallow, after, last = (warm_canary["runs"][n] for n in (
+        "shallow", "after_shallow", "last"))
+    starts = [_events(r, "run_start")[0]["seen_capacity"]
+              for r in (shallow, after, last)]
+    assert starts == [1 << 18, 1 << 16, 1 << 18]
+    assert shallow["res"].stop_reason == "diameter_budget"
+    assert shallow["res"].growth_stalls == []
+    assert shallow["res"].levels == after["res"].levels[:3]
+    assert [c for c, _s in after["res"].growth_stalls] == [1 << 17, 1 << 18]
+    assert last["res"].growth_stalls == []
+    first = warm_canary["runs"]["first"]["res"]
+    for r in (after, last):
+        assert (r["res"].levels, r["res"].distinct, r["res"].generated) == (
+            first.levels, first.distinct, first.generated)
+        assert len(r["steps"]) == 10
+    for r in (shallow, after, last):
+        assert _events(r, "run_end")[0]["compiles"] == {}
+    assert [r["kept"] for r in (shallow, after, last)] == [3, 3, 4]
+
+
+def test_a_resumed_run_does_not_inherit_the_kept_capacity(warm_canary):
+    """It sizes its table from the configured capacity and its
+    snapshot's keys, as before, and so grows again; its ``run_start``
+    names no capacity, the keys not being loaded yet."""
+    resumed = warm_canary["runs"]["resumed"]
+    (start,) = _events(resumed, "run_start")
+    assert start["resume"] is True
+    assert "seen_capacity" not in start
+    assert "seen_capacity_configured" not in start
+    assert "start_capacity" not in resumed["res"].report["seen_set"]
+    assert [c for c, _s in resumed["res"].growth_stalls] == [1 << 17,
+                                                            1 << 18]
+    assert resumed["kept"] == warm_canary["runs"]["second"]["kept"]
+    assert resumed["res"].levels == warm_canary["runs"]["first"]["res"].levels
+    assert len(resumed["steps"]) == 10
+
+
+def test_the_build_keeps_describing_its_configured_size(warm_canary):
+    """``chunk_avals`` (what the compile for a described chip and the
+    launch model read) after grown runs, and a degrade rebuild, which
+    forgets what the engine grew to."""
+    before, after = warm_canary["avals_before"], warm_canary["avals_after"]
+    assert before == after and before[5].hi.shape == (1 << 16,)
+    assert warm_canary["kept_before_rebuild"] == 1 << 18
+    assert warm_canary["start_after_rebuild"] == {
+        "seen_capacity": 1 << 16, "seen_capacity_configured": 1 << 16}
+
+
+def test_a_fresh_engine_of_the_same_setup_starts_at_the_configured_size(
+        warm_canary, tmp_path):
+    from raft_tla_tpu.engine.check import (engine_config_from_backend,
+                                           initial_states, make_engine)
+    from raft_tla_tpu.obs import validate_run_events
+    setup = warm_canary["setup"]
+    ev = str(tmp_path / "ev.jsonl")
+    eng = make_engine(setup, dataclasses.replace(
+        engine_config_from_backend(setup), max_diameter=2, events_out=ev))
+    eng.run(initial_states(setup))
+    start = validate_run_events(ev)[0]
+    assert start["event"] == "run_start"
+    assert (start["seen_capacity"], start["seen_capacity_configured"]) == (
+        1 << 16, 1 << 16)
+    assert eng.metrics.counter_value("engine/seen_capacity_kept") == 0
+
+
+@pytest.fixture(scope="module")
+def spilled_roots(tmp_path_factory):
+    """512 random roots through a queue whose watermark every ingest call
+    passes, so each is followed by a spill and a reset of the count, and
+    a table that doubles twice in level 1: one engine run twice, at sizes
+    no other test uses, so that nothing of it is in the jit cache.
+    (engine, events of the first run, events of the second)"""
+    from raft_tla_tpu.models.smoke import smoke_init_states
+    from raft_tla_tpu.obs import validate_run_events
+    sdims = RaftDims(n_servers=3, n_values=2, max_log=4, n_msg_slots=24)
+    roots = smoke_init_states(sdims, k=2, seed=7)
+    d = tmp_path_factory.mktemp("spilled_roots")
+    eng = BFSEngine(sdims, config=small_config(
+        batch=40, queue_capacity=40, seen_capacity=256, max_diameter=1,
+        record_trace=False), constraint=build_constraint(
+            sdims, Bounds(max_term=4, max_log_len=4, max_msg_count=24)))
+    runs = []
+    for name in ("fresh", "warm"):
+        eng.config.events_out = str(d / f"{name}.jsonl")
+        eng.run(list(roots))
+        runs.append(validate_run_events(eng.config.events_out))
+    return (eng, *runs)
+
+
+@pytest.mark.parametrize("which", ["fresh", "warm"])
+def test_ingest_compiles_off_the_clock_when_the_roots_spill(spilled_roots,
+                                                            which):
+    """Ingest takes its count in ONE placement, after a spill's reset
+    too: the fresh engine compiles it in the warm-up and once a growth
+    (which loads it for the table it makes), never in the ``ingest`` span
+    that the StopAfter clock covers; the warm engine, whose roots go
+    through the kept table, compiles nothing at all."""
+    eng, fresh, warm = spilled_roots
+    events = fresh if which == "fresh" else warm
+    start, end = events[0], events[-1]
+    spills = [e for e in events if e["event"] == "spill"]
+    assert len(spills) > 8 and {e["where"] for e in spills} >= {"ingest"}
+    assert end["levels"] == [512, 15616]
+    by_name = {p["name"]: p for p in end["jit"].get("programs", [])}
+    if which == "fresh":
+        assert start["seen_capacity"] == start["seen_capacity_configured"]
+        assert end["growth_stalls"] == 2
+        assert "ingest" not in end["compiles"], end["compiles"]
+        assert by_name["ingest"]["span"] == "grow"
+        assert set(end["compiles"]) <= {"run_init", "warmup", "spill",
+                                        "grow"}
+    else:
+        assert start["seen_capacity"] == (
+            4 * start["seen_capacity_configured"])
+        assert end["growth_stalls"] == 0
+        assert end["compiles"] == {} and by_name == {}

@@ -79,6 +79,34 @@ def test_check_engine_stays_warm_and_budgets_refresh(server):
     assert r2["levels"] == [1, 3, 18, 79, 318]
 
 
+def test_a_cached_engine_keeps_the_capacity_its_last_check_grew_to(server):
+    """Two ``check`` requests of the canary on one cached engine: the
+    first grows the cfg's 65,536-slot table twice, the second starts at
+    what it grew to, pays no growth and answers the same."""
+    req = {"op": "check", "trace": True,
+           "cfg": os.path.join(REPO, "configs/MCraft_noleader.cfg")}
+    before = roundtrip(server, {"op": "stats"})["metrics"]["counters"]
+    r1 = roundtrip(server, req)
+    r2 = roundtrip(server, req)
+    assert r1["ok"] and r2["ok"], (r1, r2)
+    assert [c for c, _s in r1["growth_stalls"]] == [1 << 17, 1 << 18]
+    assert r2["growth_stalls"] == [] and "grow" not in r2["phases"]
+    for key in ("distinct", "generated", "levels", "diameter",
+                "action_counts", "stop_reason"):
+        assert r2[key] == r1[key], key
+    assert r1["stop_reason"] == "violation" and r1["diameter"] == 8
+    assert r2["violation"]["invariant"] == "NoLeaderElected"
+    assert [s["action"] for s in r2["violation"]["trace"]] == [
+        s["action"] for s in r1["violation"]["trace"]]
+    assert len(r2["violation"]["trace"]) == 10
+    seen = r2["report"]["seen_set"]
+    assert (seen["start_capacity"], seen["configured_capacity"]) == (
+        1 << 18, 1 << 16)
+    after = roundtrip(server, {"op": "stats"})["metrics"]["counters"]
+    assert (after.get("engine/seen_capacity_kept", 0)
+            - before.get("engine/seen_capacity_kept", 0)) == 1
+
+
 def test_cfg_text_and_content_identity(server):
     # cfg_text requests work, and the engine cache keys on CONTENT: two
     # different texts (different MaxTerm) must give different models.
