@@ -66,6 +66,7 @@ from ..obs import (ActionCoverage, MetricsRegistry, RunEventLog,
                    SpanTracer, all_device_memory_stats,
                    device_memory_stats, events_path, peak_host_rss_bytes,
                    phase_delta)
+from ..obs.calls import CallLog
 from ..obs.metrics import SCOPE_PREFIX, process_record, watch_compiles
 from ..obs.flight import RECORDER as _FLIGHT
 from ..resilience import faults as _faults
@@ -176,6 +177,18 @@ def flush_plan(n: int, lengths, size: int) -> List[Tuple[int, int, int, int]]:
     return plan
 
 
+def store_growth(trace, prefix: str) -> dict:
+    """``run_end``'s ``<prefix>rehashes`` and ``<prefix>rehash_s``: what
+    growing has cost a trace store so far (``stats``).  ``trace_`` at a
+    run's end, roots' records and a resume's refill included;
+    ``restore_`` right after that refill.  Zeros for a run that made no
+    store, and from the dict fallback."""
+    grown = (trace.stats() if trace is not None
+             else {"rehashes": 0, "rehash_s": 0.0})
+    return {prefix + "rehashes": grown["rehashes"],
+            prefix + "rehash_s": round(grown["rehash_s"], 6)}
+
+
 class _TraceFlush:
     """One call's trace records on their way to the host store, in two
     halves, so that the device need not wait for the host's.  ``start``
@@ -189,6 +202,9 @@ class _TraceFlush:
     (``flush_drained``).  So at most one flush is owed, and records reach
     the store in the order the calls ran.  A run that raises takes what
     it owed with it: ``_run_degradable`` resumes into a new store.
+
+    ``finish`` returns the seconds its host half took (0.0 with nothing
+    owed), for the call's row.
 
     One class for both host loops.  What differs comes from the engine
     that runs: its ``_fetch(buffer, start, length)`` (five columns of
@@ -215,15 +231,16 @@ class _TraceFlush:
                     owed.append((cols, lo, hi))
             self._owed = owed or None
 
-    def finish(self, counter: str) -> None:
+    def finish(self, counter: str) -> float:
         if self._owed is None:
-            return
+            return 0.0
         eng = self._eng
-        with eng.metrics.phase_timer("trace_flush"):
+        with eng.metrics.phase_timer("trace_flush") as span:
             owed, self._owed = self._owed, None
             for cols, lo, hi in owed:
                 eng._record(self._trace, cols, lo, hi)
         eng.metrics.counter("engine/" + counter)
+        return span.seconds
 
 
 @dataclasses.dataclass
@@ -1062,6 +1079,10 @@ class BFSEngine:
         self._compile_base = compiles_by_span(mt)
         self._jit_base = process_record().jit_reading()
         self._work_base = self._work_level_base = work_counts(mt)
+        # One row a device call (obs/calls.py).  And no store of an
+        # earlier run's: ``_run_impl`` makes this run's own.
+        self._calls = CallLog(self._run_id)
+        self.trace = None
         # ``generated_by_family`` of this run's events is the run's own
         # share: what a resumed snapshot carried is taken where
         # ``_run_impl`` loads it (``_note_family_base``).
@@ -1216,6 +1237,7 @@ class BFSEngine:
                 if pm_path is not None:
                     evlog.emit("postmortem", dump={
                         "path": pm_path, "reason": "run_error"})
+            call_fields = self._calls.run_end_fields(evlog)
             evlog.emit(
                 "run_end",
                 stop_reason=(getattr(res, "stop_reason", None)
@@ -1237,6 +1259,11 @@ class BFSEngine:
                 **self._budget_fields(res),
                 growth_stalls=len(getattr(res, "growth_stalls", ())),
                 phase_seconds=phases,
+                # The run's calls reduced (no row here), its collections;
+                # what growing cost its trace store (a resume's refill:
+                # ``restore_*``).
+                **call_fields,
+                **store_growth(self.trace, "trace_"),
                 # Counted in the loop (WORK_COUNTERS), and the compiles
                 # and cache loads of this run by the span they fell in.
                 **work_counts(mt, self._work_base),
@@ -1625,6 +1652,7 @@ class BFSEngine:
                 for length in self._fetch_lens:
                     self._fetch(tbuf, np.int32(0), length)
         flush = _TraceFlush(self, trace)
+        calls = self._calls
         t0 = time.time()
         last_progress = t0
         self._batch_ema = 0.0   # measured seconds per device batch
@@ -1685,6 +1713,8 @@ class BFSEngine:
                     trace.add_batch(resume.trace_fps, resume.trace_parents,
                                     resume.trace_actions)
                     trace.roots.update(resume.roots)
+                    self._restore_counts.update(
+                        store_growth(trace, "restore_"))
                 elif resume.trace_fps.size > 0 \
                         and cfg.checkpoint_dir is not None:
                     raise ValueError(
@@ -1697,6 +1727,7 @@ class BFSEngine:
             # Ingest initial states in B-sized chunks (roots registered
             # above, before the clock).
             self._open_level_span(0)
+            calls.start()
             for base in range(0, len(rows_np), B):
                 # StopAfter applies during root ingest too (a k=4 smoke
                 # run has 262k roots — TLCGet("duration") doesn't wait
@@ -1716,7 +1747,8 @@ class BFSEngine:
                     if hit:
                         res.stop_reason = hit
                         break
-                with mt.phase_timer("ingest"):
+                calls.dispatch()
+                with mt.phase_timer("ingest") as ingest_span:
                     chunk = rows_np[base:base + B]
                     pad = np.zeros((B - len(chunk), sw), ROW_DTYPE)
                     valid = np.arange(B) < len(chunk)
@@ -1728,9 +1760,17 @@ class BFSEngine:
                     res.distinct += n_new
                 mt.counter("engine/ingest_calls")
                 mt.counter("engine/distinct", n_new)
+                flush_s = 0.0
                 if cfg.record_trace and n_new:
-                    with mt.phase_timer("trace_flush"):
+                    with mt.phase_timer("trace_flush") as flush_span:
                         self._record(trace, tr, 0, n_new)
+                    flush_s = flush_span.seconds
+                # The roots' ingest dispatches and fetches in one span.
+                calls.row("ingest", "ingest", 0, ingest_span.seconds, 0.0,
+                          flush_s, 0.0, base // B + 1, 0, 1, len(chunk),
+                          n_new, distinct=res.distinct,
+                          generated=res.generated, diameter=0,
+                          frontier=len(rows_np), offset=base + len(chunk))
                 if bool(fail):
                     raise RuntimeError(
                         "seen-set probe failure during ingest; raise "
@@ -1774,6 +1814,8 @@ class BFSEngine:
         # empty trace), and its interval clock starts at the restart.
         skip_ckpt_level = resume.diameter if resume is not None else -1
         last_ckpt = time.time() if resume is not None else float("-inf")
+        if resume is not None:
+            calls.start()       # the restore is a span of its own
         while (cur_count > 0 or pending) and res.violation is None \
                 and res.stop_reason == "exhausted":
             if cfg.checkpoint_dir is not None \
@@ -1816,7 +1858,7 @@ class BFSEngine:
                     # batches) from the measured per-batch cost so the run
                     # stops within ~one batch of the deadline, not one
                     # whole sync_every chunk past it.
-                    allowed = self._CH
+                    allowed, rule = self._CH, "full"
                     if cfg.max_seconds is not None:
                         remaining = cfg.max_seconds - (time.time() - t0)
                         if remaining <= 0:
@@ -1833,14 +1875,21 @@ class BFSEngine:
                             # cut this call short.
                             mt.counter("engine/deadline_calls")
                     calls_in_level += 1
+                    # The registry's count: what pairs this call's
+                    # ``chunk`` span with its ``account`` span and its row.
+                    call = int(mt.counter_value("engine/chunk_calls")) + 1
                     if _faults.ACTIVE:
                         # Deterministic injection sites (resilience/):
                         # "kill" dies here (mid-level, past the level's
                         # snapshot), "oom" raises a simulated
-                        # RESOURCE_EXHAUSTED into the degradation path.
+                        # RESOURCE_EXHAUSTED into the degradation path,
+                        # "stall" loses time between two calls here.
                         _faults.fire("kill", level=res.diameter,
                                      chunk=calls_in_level)
                         _faults.fire("oom", level=res.diameter,
+                                     chunk=calls_in_level)
+                        _faults.fire("stall", phase="gap", call=call,
+                                     level=res.diameter,
                                      chunk=calls_in_level)
                     # Device-profiler window (--xla-profile): the
                     # capture starts at the first dispatch and stops
@@ -1851,11 +1900,10 @@ class BFSEngine:
                     step_cm = (cap.step() if cap is not None
                                and not cap.done
                                else contextlib.nullcontext())
-                    # The registry's count: what pairs this span with
-                    # the ``account`` span of the same call.
-                    call = int(mt.counter_value("engine/chunk_calls")) + 1
+                    calls.dispatch()
                     t_call = time.perf_counter()
-                    with mt.phase_timer("chunk", call=call), step_cm:
+                    with mt.phase_timer("chunk", call=call) as chunk_span, \
+                            step_cm:
                         out = self._chunk(qcur, jnp.int32(cur_count),
                                           jnp.int32(offset), qnext,
                                           jnp.int32(next_count_h), seen,
@@ -1864,22 +1912,35 @@ class BFSEngine:
                         qnext, seen, tbuf = out[0], out[1], out[2]
                     # The host half of the previous call's flush, while
                     # the device runs this one.
-                    flush.finish("flush_overlapped")
+                    flush_s = flush.finish("flush_overlapped")
                     # The packed-stats fetch is the loop's one blocking
                     # device sync — its phase time IS the device compute
                     # the dispatch above overlapped.
-                    with mt.phase_timer("stats_fetch"):
+                    with mt.phase_timer("stats_fetch") as fetch_span:
+                        if _faults.ACTIVE:
+                            _faults.fire("stall", phase="wait", call=call,
+                                         level=res.diameter,
+                                         chunk=calls_in_level)
                         st = np.asarray(out[3])
                     # What the call cost the loop, from its dispatch to
                     # its statistics on the host: the flush that ran
                     # under it is inside, where it outlasts the device.
                     call_seconds = time.perf_counter() - t_call
-                    passes = int(st[1])
+                    passes, n_new = int(st[1]), int(st[6])
+                    if passes < allowed:
+                        # The level's end (or a full queue, a loaded
+                        # seen-set) and no rule ended this call.
+                        rule = "level_end"
                     # The host bookkeeping of one call, under the numbers
                     # the fetch brought: what a reader of a profiler
                     # capture matches the call's device time to.
-                    account = mt.open_span("account", call=call,
-                                           passes=passes)
+                    account = mt.open_span(
+                        "account", call=call, passes=passes, rule=rule,
+                        parents=int(st[12]), new=n_new)
+                    if _faults.ACTIVE:
+                        _faults.fire("stall", phase="host", call=call,
+                                     level=res.diameter,
+                                     chunk=calls_in_level)
                     self._count_chunk_call(passes, int(st[12]))
                     if passes:           # st fetch synced: timing is real
                         per = call_seconds / passes
@@ -1894,7 +1955,7 @@ class BFSEngine:
                             max(per, 0.5 * self._batch_ema + 0.5 * per))
                     offset, next_count_h = int(st[0]), int(st[2])
                     seen_size, tcount = int(st[3]), int(st[4])
-                    n_gen, n_new, n_ovf = int(st[5]), int(st[6]), int(st[7])
+                    n_gen, n_ovf = int(st[5]), int(st[7])
                     dead_any, viol_any = bool(st[8]), bool(st[9])
                     vinv, fail = int(st[10]), bool(st[11])
                     res.distinct += n_new
@@ -1920,18 +1981,19 @@ class BFSEngine:
                     coverage.add_chunk(int(st[12]), st[13:13 + F],
                                        st[13 + F:13 + 2 * F],
                                        st[13 + 2 * F:13 + 3 * F])
-                    # Black-box progress snapshot (obs/flight.py):
-                    # rate-limited inside progress(), so the always-on
-                    # cost is a couple of dict appends per second — and
-                    # the watch console / postmortem dump always have a
-                    # current view, with or without --progress-interval.
-                    _FLIGHT.progress(
-                        distinct=res.distinct, generated=res.generated,
-                        diameter=res.diameter, frontier=cur_count,
-                        offset=offset, next_count=next_count_h,
-                        seen_size=seen_size,
-                        elapsed=round(time.time() - t0, 3))
                     account.close()
+                    # The call's one record (obs/calls.py): where its
+                    # time lay, and the run's state as the watch console
+                    # and a postmortem dump show it, with or without
+                    # --progress-interval.
+                    calls.row("chunk", rule, passes, chunk_span.seconds,
+                              fetch_span.seconds, flush_s, account.seconds,
+                              call, res.diameter + 1, allowed, int(st[12]),
+                              n_new, distinct=res.distinct,
+                              generated=res.generated,
+                              diameter=res.diameter, frontier=cur_count,
+                              offset=offset, next_count=next_count_h,
+                              seen_size=seen_size)
                     if cfg.record_trace and tcount:
                         # The device half only: everything below may
                         # raise, branch or dispatch with the flush owed.

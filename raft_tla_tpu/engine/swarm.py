@@ -59,9 +59,11 @@ from ..models.schema import (build_pack_guard, check_packable,
                              flatten_states, stack_states, unflatten_state)
 from ..obs import (MetricsRegistry, RunEventLog, SpanTracer,
                    device_memory_stats, events_path, phase_delta)
+from ..obs.calls import CallLog
 from ..obs.flight import RECORDER as _FLIGHT
 from ..obs.metrics import SCOPE_PREFIX, process_record
 from ..ops.fingerprint import build_fingerprint
+from ..resilience import faults as _faults
 from ..ops.walk_kernels import (CHOICE_STREAM, FAMILY_STREAM, INIT_STREAM,
                                 ROOT_STREAM, bloom_init, bloom_probe,
                                 bloom_push, family_subset, preferred_choice,
@@ -540,6 +542,8 @@ class SwarmEngine:
         self._walkers = None
         compile_base = compiles_by_span(mt)
         jit_base = process_record().jit_reading()
+        # One row a chunk of lockstep steps (obs/calls.py).
+        self._calls = CallLog(self._run_id)
         evlog = RunEventLog(events_path(self.events_out,
                                         self.checkpoint_dir))
         phase_base = mt.phase_seconds()
@@ -634,6 +638,7 @@ class SwarmEngine:
         for name in SWARM_COUNTERS:
             if name not in ("steps", "latch_step"):
                 mt.counter("swarm/" + name, counts[name])
+        call_fields = self._calls.run_end_fields(evlog)
         evlog.emit(
             "run_end",
             stop_reason=(res.stop_reason if err is None else "error"),
@@ -645,6 +650,9 @@ class SwarmEngine:
             diameter=res.diameter, levels=[],
             wall_seconds=res.wall_seconds,
             phase_seconds=res.phases, swarm=swarm_block,
+            # As a BFS engine's ``run_end``: the calls reduced, the
+            # collections.
+            **call_fields,
             # Counted in the loop (SWARM_COUNTERS), and the compiles and
             # cache loads of this run by the span they fell in.
             **counts,
@@ -771,8 +779,14 @@ class SwarmEngine:
         k0 = 0
         depth_max = 0
         last_progress = t0
+        calls = self._calls
+        calls.start()
+        saturation = {}
         while True:
-            with mt.phase_timer("swarm_chunk", step=k0):
+            if _faults.ACTIVE:
+                _faults.fire("stall", phase="gap", call=calls.n + 1)
+            calls.dispatch()
+            with mt.phase_timer("swarm_chunk", step=k0) as chunk_span:
                 step_cm = cap.step() if cap is not None else None
                 if step_cm is not None:
                     step_cm.__enter__()
@@ -804,7 +818,10 @@ class SwarmEngine:
             res.steps += W * stepped
             fired = []
             novel_steps = accept_steps = None
-            with mt.phase_timer("swarm_fetch", step=k_start):
+            visited_was = res.visited
+            with mt.phase_timer("swarm_fetch", step=k_start) as fetch_span:
+                if _faults.ACTIVE:
+                    _faults.fire("stall", phase="wait", call=calls.n + 1)
                 for s in slices:
                     restarts = int(fetch(s["restarts"]))
                     res.traces += restarts
@@ -846,6 +863,9 @@ class SwarmEngine:
                 # partition-invariant pick across slices.
                 latched = [(int(fetch(lt[7])), int(fetch(lt[6])), lt)
                            for lt in fired]
+            t_host = time.perf_counter()
+            if _faults.ACTIVE:
+                _faults.fire("stall", phase="host", call=calls.n + 1)
             if hacc is not None and stepped:
                 hacc.add_steps(k_start + stepped, W * stepped,
                                novel_steps[:stepped],
@@ -859,7 +879,6 @@ class SwarmEngine:
                 res.wall_seconds = now - t0
                 evlog.emit("swarm_progress", depth=k0,
                            swarm=self._swarm_block(res))
-                flight_extra = {}
                 if hacc is not None:
                     snap = hacc.snapshot()
                     mt.gauge("hunt/saturation", snap["saturation"])
@@ -870,10 +889,18 @@ class SwarmEngine:
                              snap["novel_rate_recent"])
                     mt.gauge("hunt/revisit_rate", snap["revisit_rate"])
                     _FLIGHT.record("hunt", steps=res.steps, **snap)
-                    flight_extra["saturation"] = snap["saturation"]
-                _FLIGHT.progress(mode="swarm", steps=res.steps,
-                                 visited=res.visited, traces=res.traces,
-                                 **flight_extra)
+                    saturation = {"saturation": snap["saturation"]}
+            # The chunk's one record (obs/calls.py): ``passes`` are its
+            # lockstep steps, one dispatch a slice under one span, and
+            # the watch console's view of a hunt is read from it.  The
+            # accounting above is under no span, and this loop opens
+            # none between two chunks: ``named_s`` stays 0.
+            calls.row("swarm_chunk", "steps", stepped, chunk_span.seconds,
+                      fetch_span.seconds, 0.0,
+                      time.perf_counter() - t_host, calls.n + 1, k_start,
+                      self.chunk, W * stepped, res.visited - visited_was,
+                      mode="swarm", steps=res.steps, visited=res.visited,
+                      traces=res.traces, **saturation)
             if latched:
                 vstep, vwalk, latch = min(latched, key=lambda f: f[:2])
                 counts["latch_step"] = res.violation_step = vstep

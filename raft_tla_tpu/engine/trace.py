@@ -47,6 +47,11 @@ class PyTraceStore:
     def get(self, fp: int) -> Optional[Tuple[int, int]]:
         return self._d.get(fp)
 
+    def stats(self) -> dict:
+        """What growing has cost the store so far: ``rehashes`` and
+        ``rehash_s``.  A dict grows out of sight: zeros."""
+        return {"rehashes": 0, "rehash_s": 0.0}
+
     def export(self):
         n = len(self._d)
         fps = np.fromiter(self._d.keys(), np.uint64, n)
@@ -116,6 +121,11 @@ class NativeTraceStore(PyTraceStore):
         found = self._lib.ts_get(self._h, np.uint64(fp),
                                  ctypes.byref(parent), ctypes.byref(action))
         return (parent.value, action.value) if found else None
+
+    def stats(self) -> dict:
+        out = (ctypes.c_uint64 * 2)()
+        self._lib.ts_stats(self._h, out)
+        return {"rehashes": int(out[0]), "rehash_s": out[1] / 1e9}
 
     def export(self):
         n = len(self)

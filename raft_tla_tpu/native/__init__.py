@@ -83,6 +83,7 @@ def load() -> Optional[ctypes.CDLL]:
         lib.ts_destroy.argtypes = [ctypes.c_void_p]
         lib.ts_size.restype = ctypes.c_uint64
         lib.ts_size.argtypes = [ctypes.c_void_p]
+        lib.ts_stats.argtypes = [ctypes.c_void_p, u64p]
         lib.ts_add_batch.argtypes = [ctypes.c_void_p, u64p, u64p, i32p,
                                      ctypes.c_uint64]
         lib.ts_get.restype = ctypes.c_int

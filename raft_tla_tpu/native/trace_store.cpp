@@ -14,6 +14,7 @@
 // path; later duplicates arrive only from in-flight batches of the same
 // level and must not overwrite the shortest-path parent).
 
+#include <chrono>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
@@ -31,6 +32,12 @@ struct Store {
     Entry* slots;
     uint64_t capacity;   // power of two
     uint64_t size;
+    // What growing has cost this store (ts_stats): a rehash is a calloc
+    // of the new table and every entry inserted again, inside whichever
+    // add_batch crossed the load, so the caller's clock sees one slow
+    // call and nothing that says why.
+    uint64_t rehashes;
+    uint64_t rehash_ns;
 };
 
 // splitmix64: decorrelates slot index from the engine's own fingerprint
@@ -62,6 +69,7 @@ inline void insert_one(Store* s, uint64_t fp, uint64_t parent,
 }
 
 void rehash(Store* s, uint64_t new_capacity) {
+    auto t0 = std::chrono::steady_clock::now();
     Entry* old = s->slots;
     uint64_t old_cap = s->capacity;
     s->capacity = new_capacity;
@@ -71,6 +79,9 @@ void rehash(Store* s, uint64_t new_capacity) {
         if (old[i].used)
             insert_one(s, old[i].fp, old[i].parent, old[i].action);
     free(old);
+    s->rehashes++;
+    s->rehash_ns += std::chrono::duration_cast<std::chrono::nanoseconds>(
+        std::chrono::steady_clock::now() - t0).count();
 }
 
 // Room for `n` more records before the first of them goes in.  A batch
@@ -97,6 +108,7 @@ void* ts_create(uint64_t initial_capacity) {
     s->slots = static_cast<Entry*>(calloc(cap, sizeof(Entry)));
     s->capacity = cap;
     s->size = 0;
+    s->rehashes = s->rehash_ns = 0;
     return s;
 }
 
@@ -107,6 +119,13 @@ void ts_destroy(void* h) {
 }
 
 uint64_t ts_size(void* h) { return static_cast<Store*>(h)->size; }
+
+// out[0..1]: rehashes so far, nanoseconds spent in them.
+void ts_stats(void* h, uint64_t* out) {
+    Store* s = static_cast<Store*>(h);
+    out[0] = s->rehashes;
+    out[1] = s->rehash_ns;
+}
 
 void ts_add_batch(void* h, const uint64_t* fps, const uint64_t* parents,
                   const int32_t* actions, uint64_t n) {

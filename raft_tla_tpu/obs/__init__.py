@@ -24,8 +24,12 @@ One spine, several legs:
   window over N of the real chunk dispatches behind ``--xla-profile``;
 - :mod:`.coverage` — :class:`ActionCoverage`, TLC-style per-action
   generated/distinct/disabled counters and the run-end coverage table;
+- :mod:`.calls` — one ``call`` row a device call, written by the host
+  loops into the flight ring, and a run's own reduction of them
+  (``run_end.calls``: by rule, the slowest call and the phase its
+  excess lay in);
 - :mod:`.flight` — the always-on :class:`FlightRecorder` black box
-  (bounded ring of recent events/progress snapshots) with the
+  (bounded ring of recent events and call rows) with the
   crash/SIGTERM/fault-kill **postmortem dump** and the process-global
   :data:`~.flight.RECORDER` the live-introspection consumers read;
 - :mod:`.expose` — Prometheus text exposition of the registry

@@ -73,6 +73,12 @@ KNOWN_EVENTS = (
     # ``<job-dir>/events.jsonl``: ``queue_wait_s``, ``run_s``,
     # ``engine_wall_s``, ``turnaround_s``, ``cached``, ``result_bytes``.
     "job_end",
+    # Host loops (obs/calls.py): a call of the run just ended, or the gap
+    # before it, exceeded what it should have cost by over a second; the
+    # call's whole row, flat, with ``expected_s``, ``excess_s`` and the
+    # ``phase`` the excess lay in.  Emitted before ``run_end``, which
+    # counts them (``calls.slow_calls``).
+    "slow_call",
 )
 
 #: Structured payload field each new event type must carry.

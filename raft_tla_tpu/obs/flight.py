@@ -6,10 +6,11 @@ hung device, a SIGTERM'd supervised child, or a fault-injected
 ``os._exit`` leaves nothing but whatever already hit disk.  This module
 is the black box: a bounded in-memory ring of recent telemetry records
 — run events (mirrored automatically from every :class:`RunEventLog`,
-file-backed or not), rate-limited per-chunk progress snapshots, and
+file-backed or not), one ``call`` row a device call of a host loop
+(obs/calls.py: what the call was, and where its time lay), and
 run-context/registry deltas — always
-on at near-zero overhead (a deque append under a lock per record, a few
-records per second at most), plus a **postmortem dump**: when the
+on at near-zero overhead (a deque append under a lock per record),
+plus a **postmortem dump**: when the
 recorder is armed for a run and the process dies abnormally, the ring
 (and a final metrics-registry snapshot) is written to
 ``<workdir>/postmortem.json`` so the last N seconds of telemetry
@@ -52,16 +53,23 @@ from collections import deque
 from typing import Dict, Optional
 
 #: Records kept per kind.  Per-kind rings (not one shared ring) so a
-#: high-rate kind (progress) can never evict the rare, precious ones
+#: high-rate kind (call) can never evict the rare, precious ones
 #: (run events, run context) out of the black box.
 DEFAULT_CAPACITY = 256
 
-#: Minimum seconds between per-chunk progress records — the engines'
-#: chunk loops call :meth:`FlightRecorder.progress` every stats fetch,
-#: and this floor keeps the always-on cost at a few records/second no
-#: matter how fast the host loop spins.  The first record of a run
-#: always lands (the limiter is per-recorder, reset on ``arm``).
-PROGRESS_EVERY_S = 0.5
+#: Kinds that keep more: one ``call`` row a device call, not
+#: rate-limited, and a run's own reduction at its end (obs/calls.py
+#: ``reduce_calls``) reads them back, so the ring holds a run.
+CAPACITIES = {"call": 4096}
+
+#: The kind ``watch``, ``--metrics-port`` and the postmortem read as a
+#: run's current state.  No loop writes it: it is a VIEW of the newest
+#: ``call`` row, these fields of it (a BFS loop's and the swarm's) and
+#: ``elapsed``, the row's ``t``.
+PROGRESS = "progress"
+PROGRESS_FIELDS = ("mode", "distinct", "generated", "diameter", "frontier",
+                   "offset", "next_count", "seen_size", "steps", "visited",
+                   "traces", "saturation")
 
 
 def host_fingerprint() -> dict:
@@ -137,31 +145,41 @@ class FlightRecorder:
         self._live_evlog = None        # run's RunEventLog for watch_attach
         self._hooks_installed = False
         self._prev_sigterm = None
-        self._last_progress = float("-inf")
 
     # -- recording -----------------------------------------------------
-    def record(self, kind: str, **fields) -> int:
+    def record(self, kind: str, /, **fields) -> int:
         """Append one record; returns its ``seq``."""
+        rec = {"seq": 0, "ts": round(time.time(), 6)}
+        rec.update(fields)
+        return self.put(kind, rec)
+
+    def put(self, kind: str, rec: dict) -> int:
+        """``record`` for a caller that has built its record already,
+        ``ts`` included (a host loop's row a call): ``rec`` itself goes
+        into the ring, numbered."""
         with self._lock:
             self._seq += 1
-            seq = self._seq
+            rec["seq"] = seq = self._seq
             ring = self._rings.get(kind)
             if ring is None:
-                ring = self._rings[kind] = deque(maxlen=self.capacity)
-            rec = {"seq": seq, "ts": round(time.time(), 6)}
-            rec.update(fields)
+                ring = self._rings[kind] = deque(maxlen=max(
+                    self.capacity, CAPACITIES.get(kind, 0)))
             ring.append(rec)
         return seq
 
-    def progress(self, **fields) -> Optional[int]:
-        """Rate-limited progress record (the engines' per-chunk call):
-        at most one per :data:`PROGRESS_EVERY_S`; the first call after
-        ``arm()`` always records.  Returns the seq when recorded."""
-        now = time.monotonic()
-        if now - self._last_progress < PROGRESS_EVERY_S:
+    def progress(self) -> Optional[dict]:
+        """The run's current state, as its newest ``call`` row has it
+        (``PROGRESS_FIELDS`` and ``elapsed``, under the row's ``seq`` and
+        ``ts``); None before a loop's first call."""
+        with self._lock:
+            ring = self._rings.get("call")
+            row = ring[-1] if ring else None
+        if row is None:
             return None
-        self._last_progress = now
-        return self.record("progress", **fields)
+        view = {"seq": row["seq"], "ts": row["ts"],
+                "elapsed": round(row.get("t", 0.0), 3)}
+        view.update((k, row[k]) for k in PROGRESS_FIELDS if k in row)
+        return view
 
     # -- reading -------------------------------------------------------
     def seq(self) -> int:
@@ -170,7 +188,8 @@ class FlightRecorder:
 
     def snapshot(self, kinds=None, last: Optional[int] = None) -> dict:
         """{kind: [records oldest->newest]}; ``last`` trims each kind to
-        its newest N records."""
+        its newest N records.  ``progress`` is the one view of
+        :meth:`progress`, where there is one."""
         with self._lock:
             out = {}
             for kind, ring in self._rings.items():
@@ -180,9 +199,14 @@ class FlightRecorder:
                 if last is not None:
                     recs = recs[-last:]
                 out[kind] = recs
-            return out
+        view = self.progress()
+        if view is not None and (kinds is None or PROGRESS in kinds):
+            out[PROGRESS] = [view]
+        return out
 
     def last_record(self, kind: str) -> Optional[dict]:
+        if kind == PROGRESS:
+            return self.progress()
         with self._lock:
             ring = self._rings.get(kind)
             return ring[-1] if ring else None
@@ -239,7 +263,6 @@ class FlightRecorder:
         self._armed_path = path
         self._armed_context = dict(context or {})
         self._metrics = metrics
-        self._last_progress = float("-inf")   # first progress always lands
         if context:
             self.record("run_context", **dict(context))
         self._install_hooks()

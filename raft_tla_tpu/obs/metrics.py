@@ -22,7 +22,12 @@ arguments; its parent is the span open on the same thread when it
 started.  Closing it feeds three sinks: the histogram here, and through
 the duck-typed ``registry.tracer`` hook (obs/tracing.py) the Chrome
 trace file and a ``jax.profiler.TraceAnnotation`` on the device
-profiler's own clock.
+profiler's own clock.  The seconds of the ``phase/`` spans a thread has
+closed are summed for it (``open_spans().phase_s``): what a host loop
+subtracts from the time between two device calls, so that only time in
+no named span can count as a stall (obs/calls.py).  A garbage collection
+that ran on a thread is charged to the innermost span open there, as a
+compile is (``gc/<name>``, ``gc_seconds/<name>``; ``_on_gc``).
 
 One process record (``ProcessRecord``, the module-level ``PROCESS``):
 what the process did before its runs and between them, kept from the
@@ -34,11 +39,13 @@ events by program, each counted whether or not a span is open.  Every
 
 from __future__ import annotations
 
+import gc
 import math
 import os
 import sys
 import threading
 import time
+from collections import deque
 from contextlib import contextmanager
 from typing import Dict, Optional
 
@@ -73,6 +80,26 @@ ANNOTATION_PREFIX = "raft."
 _OPEN = threading.local()
 
 
+class _OpenSpans(list):
+    """The spans open on one thread, outermost first, and ``phase_s``:
+    the seconds of the ``phase/`` spans that thread has closed."""
+
+    __slots__ = ("phase_s",)
+
+    def __init__(self):
+        super().__init__()
+        self.phase_s = 0.0
+
+
+def open_spans() -> _OpenSpans:
+    """The calling thread's record: a host loop takes it once and reads
+    ``phase_s`` at every call (obs/calls.py)."""
+    stack = getattr(_OPEN, "stack", None)
+    if stack is None:
+        stack = _OPEN.stack = _OpenSpans()
+    return stack
+
+
 def innermost_span():
     """The innermost span open on the calling thread, or None."""
     stack = getattr(_OPEN, "stack", None)
@@ -92,10 +119,7 @@ class Span:
         # Seconds jax spent tracing, lowering, compiling or loading
         # inside this span (``_jit_end``): ``seconds - jit`` is its own.
         self.jit = 0.0
-        stack = getattr(_OPEN, "stack", None)
-        if stack is None:
-            stack = _OPEN.stack = []
-        stack.append(self)
+        open_spans().append(self)
         tracer = registry.tracer
         self._token = (tracer.begin(name, args) if tracer is not None
                        else None)
@@ -106,13 +130,17 @@ class Span:
             return
         self.seconds = time.perf_counter() - self._t0
         self.registry.observe(self._hist + self.name, self.seconds)
+        stack = _OPEN.stack
+        if self._hist == PHASE_PREFIX:
+            stack.phase_s += self.seconds
         PROCESS.span_closed(self._hist + self.name, self.seconds, self.jit)
+        if _GC_EVENTS:
+            fold_collections()
         if self._token is not None:
             self.registry.tracer.end(self._token)
         # A loop-shaped span (``level``) may be closed after an
         # exception unwound past it: whatever was opened inside it and
         # never closed goes with it.
-        stack = _OPEN.stack
         if self in stack:
             del stack[stack.index(self):]
 
@@ -382,6 +410,11 @@ class ProcessRecord:
         self._programs: Dict[str, _Program] = {}
         # histogram name -> [count, seconds, jit seconds inside]
         self._spans: Dict[str, list] = {}
+        # Garbage collections (``fold_collections``): how many and their
+        # seconds by generation, and the seconds by the span they fell in.
+        self._gc_n = [0, 0, 0]
+        self._gc_s = [0.0, 0.0, 0.0]
+        self._gc_by_span: Dict[str, float] = {}
         self._early: list = []
         self.metrics = MetricsRegistry()
         self.metrics.tracer = self
@@ -433,6 +466,14 @@ class ProcessRecord:
                 if stage == "compile":
                     p.compiles += 1
                     p.compile_s += seconds
+
+    def gc_event(self, generation: int, seconds: float,
+                 span: Optional[str]) -> None:
+        with self._lock:
+            self._gc_n[generation] += 1
+            self._gc_s[generation] += seconds
+            key = span or "(none)"
+            self._gc_by_span[key] = self._gc_by_span.get(key, 0.0) + seconds
 
     def cache_event(self, retrieval_s: float = 0.0, stored: int = 0) -> None:
         with self._lock:
@@ -512,6 +553,30 @@ class ProcessRecord:
                         SCOPE_PREFIX + "make_engine", (0, 0.0, 0.0))),
                     "phases": dict(phases[:self.PHASES])},
             }
+
+    def gc_reading(self):
+        """The collections as they stand, for ``gc_since``."""
+        fold_collections()
+        with self._lock:
+            return (tuple(self._gc_n), tuple(self._gc_s),
+                    dict(self._gc_by_span))
+
+    def gc_since(self, base) -> dict:
+        """What ``run_end.gc`` carries: the process's collections since
+        ``base`` (a ``gc_reading``), whichever thread ran them (a
+        collection holds the interpreter, so every thread waits for it):
+        ``collections`` and ``seconds_by_generation`` as [gen 0, 1, 2],
+        ``seconds``, and ``by_span`` {innermost span open on the
+        collecting thread, ``(none)`` where none was: seconds}."""
+        n0, s0, by0 = base
+        n1, s1, by1 = self.gc_reading()
+        gen_s = [round(b - a, 6) for a, b in zip(s0, s1)]
+        return {"collections": [b - a for a, b in zip(n0, n1)],
+                "seconds": round(sum(s1) - sum(s0), 6),
+                "seconds_by_generation": gen_s,
+                "by_span": {k: round(v - by0.get(k, 0.0), 6)
+                            for k, v in by1.items()
+                            if v - by0.get(k, 0.0) > 0.0}}
 
     def jit_reading(self):
         """The jit totals as they stand, for ``jit_since``."""
@@ -648,16 +713,79 @@ def _jit_end(event: str, duration: float, fun_name=None, **_kw) -> None:
                       span.name if span is not None else None)
 
 
+# -- collections ------------------------------------------------------------
+# The interpreter calls ``gc.callbacks`` at the start and the stop of
+# every collection, on the thread whose allocation set it off, which may
+# be inside one of this module's own locked sections (a histogram made
+# under the registry's lock is such an allocation).  So the hook takes no
+# lock: it appends what it saw to a deque and the next ``Span.close`` or
+# reading folds that into the registries and the process record.
+_GC_EVENTS: deque = deque()
+_ANNOTATE = None            # jax.profiler.TraceAnnotation, once watching
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        note = None
+        if _ANNOTATE is not None and _ANNOTATE.is_enabled():
+            # Inside a capture a collection is ``raft.gc`` on the line of
+            # the thread that ran it, on the device trace's own clock.
+            note = _ANNOTATE(ANNOTATION_PREFIX + "gc",
+                             generation=info["generation"])
+            note.__enter__()
+        _OPEN.gc = (time.perf_counter(), note)
+        return
+    begun = getattr(_OPEN, "gc", None)
+    if begun is None:       # started before the hook was registered
+        return
+    _OPEN.gc = None
+    seconds = time.perf_counter() - begun[0]
+    if begun[1] is not None:
+        begun[1].set_metadata(collected=info.get("collected", 0))
+        begun[1].__exit__(None, None, None)
+    stack = getattr(_OPEN, "stack", None)
+    _GC_EVENTS.append((info["generation"], seconds,
+                       stack[-1] if stack else None))
+
+
+def fold_collections() -> None:
+    """Charge the collections the hook has seen since the last call:
+    each to the registry of the span it fell in (``gc/<span>``,
+    ``gc_seconds/<span>``) and to the process record."""
+    while _GC_EVENTS:
+        try:
+            generation, seconds, span = _GC_EVENTS.popleft()
+        except IndexError:      # another thread folded it
+            return
+        if span is not None:
+            span.registry.counter("gc/" + span.name)
+            span.registry.counter("gc_seconds/" + span.name, seconds)
+        PROCESS.gc_event(generation, seconds,
+                         span.name if span is not None else None)
+
+
+def gc_seconds() -> float:
+    """Seconds of the process's collections so far (0.0 until
+    ``watch_compiles`` has registered the hook): what a host loop reads
+    at both ends of a call."""
+    if _GC_EVENTS:
+        fold_collections()
+    return sum(PROCESS._gc_s)
+
+
 _watching = False
 
 
 def watch_compiles() -> None:
-    """Register the listeners, once per process (jax keeps listeners for
-    the life of the process)."""
-    global _watching
+    """Register jax's listeners and the collector's hook, once per
+    process (both keep them for the life of the process)."""
+    global _watching, _ANNOTATE
     if not _watching:
         import jax.monitoring as monitoring
+        import jax.profiler
         monitoring.register_scalar_listener(_jit_begin)
         monitoring.register_event_listener(_jit_mark)
         monitoring.register_event_duration_secs_listener(_jit_end)
+        _ANNOTATE = jax.profiler.TraceAnnotation
+        gc.callbacks.append(_on_gc)
         _watching = True

@@ -28,6 +28,14 @@ Sites and their actions:
                           (``engine/spillpool.py``)
 ``trace_piece_delay``     sleep ``seconds`` before writing this
                           controller's trace piece (``parallel/mesh.py``)
+``stall``                 lose ``seconds`` in a host loop's call, once
+                          (the three loops; params ``phase``, ``level``,
+                          ``chunk``, ``call``): ``phase=wait`` sleeps
+                          before the statistics' fetch, ``phase=gap``
+                          before the dispatch, ``phase=host`` spins
+                          inside the call's accounting, so the thread's
+                          CPU clock follows the wall there and nowhere
+                          else — what obs/calls.py must tell apart
 ========================  ====================================================
 
 A fault fires when every one of its params is present in the call site's
@@ -95,12 +103,12 @@ class Fault:
 
 _SITE_RE = re.compile(r"^[a-z][a-z0-9_]*$")
 KNOWN_SITES = ("kill", "ckpt_torn_write", "ckpt_piece_missing", "oom",
-               "spill_write", "trace_piece_delay")
+               "spill_write", "trace_piece_delay", "stall")
 #: Plan params that configure the fault's ACTION rather than select when
 #: it fires — match() must not require them in the call site's context
 #: (``trace_piece_delay@seconds=2`` would otherwise never fire: no site
 #: passes ``seconds``).
-ACTION_PARAMS = {"trace_piece_delay": {"seconds"}}
+ACTION_PARAMS = {"trace_piece_delay": {"seconds"}, "stall": {"seconds"}}
 
 
 class FaultPlan:
@@ -206,6 +214,14 @@ class FaultPlan:
             raise OSError(f"injected spill write failure: {fault}")
         elif site == "trace_piece_delay":
             time.sleep(float(fault.params.get("seconds", 1)))
+        elif site == "stall":
+            seconds = float(fault.params.get("seconds", 1))
+            if ctx.get("phase") == "host":
+                until = time.perf_counter() + seconds
+                while time.perf_counter() < until:
+                    pass
+            else:
+                time.sleep(seconds)
         return True
 
 

@@ -13,7 +13,9 @@ for the checkout itself, or a git-ignored copy of another commit such as
 (``$ROOT`` stands for this checkout).  ``how`` = ``"ops"`` runs
 ``scripts/ops_per_pass.py`` instead, ``"probe"`` ``scripts/setup_probe.py``,
 ``"record"`` ``scripts/record_capture.py``, ``"waitprobe"``
-``scripts/served_wait_probe.py``, ``"check"`` the CLI's ``check``
+``scripts/served_wait_probe.py``, ``"cost"`` ``scripts/call_cost.py`` on
+``root``'s program, ``"stall"`` the same run under ``env``'s
+``FAULT_PLAN`` (``scripts/stall_bench.py``), ``"check"`` the CLI's ``check``
 with ``workload`` as its arguments (one string, split at spaces; no result
 line: what it printed of the run's totals is shown), ``"control:<name>"``
 ``benchmark/tests/controls_mesh.py <name>`` around the same run, and
@@ -52,7 +54,8 @@ SHOWN = ("stages:", "idle ", "window compiles", "trace:", "trace reduced",
          "setup by", "  marks", "  partition", "  jit", "  compiled",
          "  runs so far", "variant:", "window generated", "host line",
          "served jobs", "a hit", "window by", "benchmark:",
-         '{"probe"')
+         '{"probe"', "calls", "gc:", "rehash:", "round trip:",
+         "slow call:", "stall:", "fault plan", '{"checkout"')
 
 
 def command(root: str, workload: str, seed: int, trace: int, how: str):
@@ -66,6 +69,9 @@ def command(root: str, workload: str, seed: int, trace: int, how: str):
         return [sys.executable, "scripts/record_capture.py"]
     if how == "waitprobe":
         return [sys.executable, "scripts/served_wait_probe.py"]
+    if how == "cost":
+        return [sys.executable, os.path.join(ROOT, "scripts",
+                                             "call_cost.py"), root]
     if how == "check":
         return [sys.executable, "-m", "raft_tla_tpu", "check",
                 *workload.split()]
@@ -76,6 +82,9 @@ def command(root: str, workload: str, seed: int, trace: int, how: str):
         return [sys.executable,
                 f"benchmark/tests/controls_{script or 'mesh'}.py", name,
                 "--", *run]
+    if how == "stall":
+        return [sys.executable, os.path.join(ROOT, "scripts",
+                                             "stall_bench.py"), *run]
     return [sys.executable, "benchmark/run.py", *run]
 
 

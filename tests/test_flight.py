@@ -14,6 +14,7 @@ with ``--xla-profile`` / ``--metrics-port`` on vs off, and the
 import json
 import os
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -26,6 +27,8 @@ from raft_tla_tpu.models.pystate import init_state
 from raft_tla_tpu.obs import (MetricsRegistry, parse_prometheus,
                               render_prometheus, validate_run_events)
 from raft_tla_tpu.obs.expose import counter_sample, start_metrics_server
+from raft_tla_tpu.obs import flight as flight_mod
+from raft_tla_tpu.obs.calls import CallLog
 from raft_tla_tpu.obs.flight import RECORDER, FlightRecorder
 
 DIMS = RaftDims(n_servers=3, n_values=2, max_log=4, n_msg_slots=32)
@@ -45,17 +48,17 @@ def small_config(**kw):
 def test_ring_eviction_keeps_newest_per_kind():
     fr = FlightRecorder(capacity=8)
     for i in range(20):
-        fr.record("progress", i=i)
+        fr.record("hunt", i=i)
     fr.record("event", event="run_start")
     snap = fr.snapshot()
-    assert len(snap["progress"]) == 8
-    assert [r["i"] for r in snap["progress"]] == list(range(12, 20))
+    assert len(snap["hunt"]) == 8
+    assert [r["i"] for r in snap["hunt"]] == list(range(12, 20))
     # A high-rate kind never evicts a rare one: per-kind rings.
     assert len(snap["event"]) == 1
     # seq is process-monotone across kinds.
     seqs = [r["seq"] for recs in snap.values() for r in recs]
     assert len(set(seqs)) == len(seqs)
-    assert fr.last_record("progress")["i"] == 19
+    assert fr.last_record("hunt")["i"] == 19
     assert fr.last_event("run_start")["event"] == "run_start"
     assert fr.last_event("run_end") is None
 
@@ -80,15 +83,63 @@ def test_ring_thread_safety():
     assert fr.seq() == 8 * 200
 
 
-def test_progress_rate_limit_first_always_lands():
+def test_progress_is_a_view_of_the_newest_call_row():
+    """No loop writes a ``progress`` record any more: what ``watch``,
+    ``--metrics-port`` and a postmortem read under that name is the
+    newest ``call`` row's state fields, under that row's ``seq``."""
     fr = FlightRecorder()
-    fr.arm(None)                      # resets the limiter, armed bookkeeping
-    assert fr.progress(distinct=1) is not None
-    # Immediately after: suppressed by the rate limiter.
-    assert fr.progress(distinct=2) is None
-    assert fr.last_record("progress")["distinct"] == 1
+    fr.arm(None)
+    assert fr.progress() is None and fr.last_record("progress") is None
+    assert "progress" not in fr.snapshot()
+    log = CallLog(1, recorder=fr)
+    log.start()
+    for call in (1, 2):
+        log.dispatch()
+        log.row("chunk", "full", 4, 0.001, 0.002, 0.0, 0.0005, call, 3, 4,
+                7, 2, distinct=10 * call, generated=30 * call, diameter=2,
+                frontier=7, offset=4, next_count=5, seen_size=10 * call)
+    view = fr.progress()
+    row = fr.last_record("call")
+    assert view["seq"] == row["seq"] and view["distinct"] == 20
+    assert set(view) == {"seq", "ts", "distinct", "generated", "diameter",
+                         "frontier", "offset", "next_count", "seen_size",
+                         "elapsed"}          # and no other clock of the row
+    assert view["elapsed"] == round(row["t"], 3)
+    assert abs(row["ts"] - time.time()) < 5.0    # the wall's, as any record's
+    assert fr.last_record("progress") == view
+    snap = fr.snapshot()
+    assert snap["progress"] == [view] and len(snap["call"]) == 2
+    assert "progress" not in fr.snapshot(kinds=("call",))
+    # One record a call is all the loop wrote.
+    assert fr.seq() == row["seq"] and [r["call"] for r in snap["call"]] \
+        == [1, 2]
     fr.disarm()
     assert not fr.armed
+
+
+def test_the_call_ring_holds_a_run():
+    """Kinds keep ``DEFAULT_CAPACITY`` records but ``call``, which keeps
+    ``CAPACITIES['call']``: a run's own reduction reads its rows back."""
+    fr = FlightRecorder()
+    log = CallLog(7, recorder=fr)
+    n = flight_mod.CAPACITIES["call"] + 10
+    for call in range(n):
+        log.dispatch()
+        log.row("chunk", "ramp" if call % 2 else "full", 2, 0.0, 0.001,
+                0.0, 0.0, call, 1, 2, 1, 1)
+        fr.record("hunt", i=call)
+    snap = fr.snapshot()
+    assert len(snap["call"]) == flight_mod.CAPACITIES["call"] == 4096
+    assert len(snap["hunt"]) == flight_mod.DEFAULT_CAPACITY
+    assert snap["call"][-1]["call"] == n - 1
+    # The tallies need no row: they hold for every call of the run.
+    calls = log.reduce()
+    assert calls["n"] == n and calls["rows"] == 4096
+    assert sum(r["calls"] for r in calls["by_rule"].values()) == n
+    assert sum(r["passes"] for r in calls["by_rule"].values()) == 2 * n
+    # Another run's rows, and rows from before the log, are not its own.
+    other = CallLog(8, recorder=fr)
+    assert other.rows() == [] and other.reduce()["slowest"] is None
 
 
 def test_dump_and_disarm(tmp_path):
@@ -327,7 +378,7 @@ def test_watch_http_console_renders(tmp_path, capsys):
     one rendered line, clean exit on --count."""
     from raft_tla_tpu.cli import _watch_http
     mt = MetricsRegistry()
-    RECORDER.record("progress", distinct=11, generated=22, diameter=1,
+    RECORDER.record("call", distinct=11, generated=22, diameter=1,
                     frontier=3, next_count=4, elapsed=1.0)
     srv, _t = start_metrics_server(0, mt, flight=RECORDER)
     try:
@@ -339,3 +390,83 @@ def test_watch_http_console_renders(tmp_path, capsys):
     assert rc == 0
     out = capsys.readouterr().out
     assert "watch[" in out and "distinct 11" in out
+
+
+# ---------------------------------------------------------------------------
+# One row a device call: every host loop writes them (obs/calls.py)
+
+def test_the_mesh_loop_writes_a_row_a_call(tmp_path):
+    """Two of the suite's virtual devices: the mesh's own loop feeds the
+    same rows, the same ``run_end.calls`` and through them the same
+    watch view as the one-chip loop."""
+    import jax
+    from raft_tla_tpu.parallel.mesh import MeshBFSEngine
+    ev = tmp_path / "e.jsonl"
+    eng = MeshBFSEngine(
+        DIMS, constraint=build_constraint(DIMS, BOUNDS),
+        devices=jax.devices()[:2],
+        config=small_config(batch=8, sync_every=2, max_diameter=5,
+                            events_out=str(ev)))
+    res = eng.run([init_state(DIMS)])
+    rows = eng._calls.rows()
+    end = validate_run_events(str(ev))[-1]
+    assert {r["kind"] for r in rows} == {"ingest", "chunk"}
+    assert len(rows) == end["chunk_calls"] + end["ingest_calls"] \
+        == end["calls"]["n"]
+    assert sum(r["passes"] for r in rows) == end["passes"]
+    assert sum(r["new"] for r in rows) == res.distinct
+    by_rule = end["calls"]["by_rule"]
+    assert sum(r["passes"] for r in by_rule.values()) == end["passes"]
+    assert {"ingest", "level_end"} <= set(by_rule) <= {"ingest", "full",
+                                                       "level_end"}
+    last = [r for r in rows if r["kind"] == "chunk"][-1]
+    assert (last["distinct"], last["level"]) == (res.distinct, 5)
+    view = RECORDER.last_record("progress")
+    assert view["seq"] == last["seq"] and view["distinct"] == res.distinct
+    assert {"gc", "trace_rehashes"} <= set(end)
+
+
+def test_the_swarm_loop_writes_a_row_a_chunk(tmp_path):
+    """One slice: a row a chunk of lockstep steps (``passes`` are the
+    steps), and the watch view of a hunt read from the newest."""
+    from raft_tla_tpu.engine.swarm import SwarmEngine
+    ev = tmp_path / "e.jsonl"
+    eng = SwarmEngine(DIMS, constraint=build_constraint(DIMS, BOUNDS),
+                      walks=32, max_depth=12, batch=32, chunk=8, ring=8,
+                      events_out=str(ev))
+    res = eng.run([init_state(DIMS)], seed=3, num_steps=24)
+    rows = eng._calls.rows()
+    end = validate_run_events(str(ev))[-1]
+    assert [r["kind"] for r in rows] == ["swarm_chunk"] * 3
+    assert [r["passes"] for r in rows] == [8, 8, 8]
+    assert [r["level"] for r in rows] == [0, 8, 16]      # the start step
+    assert len(rows) == end["chunk_calls"]          # one slice
+    assert sum(r["new"] for r in rows) == res.visited
+    assert end["calls"]["by_rule"]["steps"]["passes"] == 24
+    assert end["calls"]["n"] == 3 and "gc" in end
+    assert rows[-1]["steps"] == res.steps == 32 * 24
+    view = RECORDER.last_record("progress")
+    assert view["mode"] == "swarm" and view["steps"] == res.steps
+    assert view["visited"] == res.visited
+
+
+def test_a_dump_holds_the_last_calls_and_the_view(tmp_path):
+    """A postmortem's ``records`` carry the run's last ``call`` rows (where
+    each call's time lay) and, under ``progress``, the view of the newest:
+    what the supervisor and ``scripts/chaos_check.py`` read."""
+    eng = BFSEngine(DIMS, constraint=build_constraint(DIMS, BOUNDS),
+                    config=small_config(max_diameter=4))
+    eng.run([init_state(DIMS)])
+    RECORDER.arm(None)
+    try:
+        path = RECORDER.dump("test", path=str(tmp_path / "pm.json"))
+    finally:
+        RECORDER.disarm()
+    doc = json.loads(open(path).read())
+    rows = [r for r in doc["records"]["call"]
+            if r.get("run") == eng._run_id]
+    assert rows and {"dispatch_s", "wait_s", "flush_s", "host_s", "gap_s",
+                     "cpu_s", "gc_s", "rule", "passes"} <= set(rows[-1])
+    (view,) = doc["records"]["progress"]
+    assert view["seq"] == doc["records"]["call"][-1]["seq"]
+    assert view["distinct"] == doc["records"]["call"][-1]["distinct"]

@@ -17,9 +17,13 @@ import pytest
 
 from raft_tla_tpu.engine import bfs as bfs_mod
 from raft_tla_tpu.engine import chunk as chunk_mod
-from raft_tla_tpu.engine.bfs import EngineConfig
+from raft_tla_tpu.engine.bfs import BFSEngine, EngineConfig
 from raft_tla_tpu.engine.check import initial_states, make_engine
+from raft_tla_tpu.models.dims import RaftDims
+from raft_tla_tpu.models.invariants import Bounds, build_constraint
+from raft_tla_tpu.models.pystate import init_state
 from raft_tla_tpu.obs import MetricsRegistry, SpanTracer
+from raft_tla_tpu.obs import calls as calls_mod
 from raft_tla_tpu.obs import metrics as metrics_mod
 from raft_tla_tpu.obs.metrics import innermost_span
 from raft_tla_tpu.utils.cfg import load_config
@@ -145,7 +149,9 @@ def test_run_level_replay_reach_all_three_sinks(verdict):
 def test_spans_nest_and_share_the_run_id(verdict):
     eng, res, steps, events, chrome, noted = verdict
     assert {e["args"]["run"] for e in chrome} == {eng._run_id}
-    assert {s["run"] for _n, s in noted} == {eng._run_id}
+    # (A collection's annotation comes from the collector's hook, which
+    # knows no run: ``test_a_collection_is_charged_to_the_span_it_fell_in``.)
+    assert {s["run"] for n, s in noted if n != "raft.gc"} == {eng._run_id}
     by = {}
     for e in chrome:
         by.setdefault(e["name"], []).append(e)
@@ -170,7 +176,8 @@ def test_spans_nest_and_share_the_run_id(verdict):
     # A span carries what identifies it and what a reader matches on,
     # nothing the events already say.
     stats = {n: set(s) for n, s in noted}
-    assert stats["raft.account"] == {"run", "call", "passes"}
+    assert stats["raft.account"] == {"run", "call", "passes", "rule",
+                                     "parents", "new"}
     assert stats["raft.chunk"] == {"run", "call"}
     assert stats["raft.trace_flush"] == {"run"}
     assert stats["raft.replay_scan"] == {"run", "steps"}
@@ -451,3 +458,345 @@ def test_level_counts_equal_the_pinned_profile(pipeline):
         pinned[-1]["distinct"], pinned[-1]["generated"]) == (9457, 24429)
     assert [(r["distinct"], r["generated"]) for r in res.level_stats] == [
         (r["distinct"], r["generated"]) for r in pinned]
+
+
+# -- one row a device call (obs/calls.py) -----------------------------------------
+
+DIMS = RaftDims(n_servers=3, n_values=2, max_log=4, n_msg_slots=32)
+BOUNDS = Bounds(max_term=2, max_log_len=1, max_msg_count=1)
+
+
+def small_engine(tmp_path, **kw):
+    """Levels 0-5 hold 1, 3, 15, 52, 162 and 486 states: at one batch of
+    16 a call, level 4 is built in 4 calls (4-7) and level 5 in 11
+    (8-18)."""
+    base = dict(batch=16, queue_capacity=1 << 12, seen_capacity=1 << 15,
+                check_deadlock=False, sync_every=1, max_diameter=5,
+                record_trace=False,
+                events_out=str(tmp_path / "ev.jsonl"))
+    base.update(kw)
+    return BFSEngine(DIMS, constraint=build_constraint(DIMS, BOUNDS),
+                     config=EngineConfig(**base))
+
+
+def events_of(eng):
+    with open(eng.config.events_out, encoding="utf-8") as f:
+        return [json.loads(line) for line in f]
+
+
+def test_the_rows_of_a_run_sum_to_its_counters(tmp_path):
+    eng = small_engine(tmp_path, record_trace=True, sync_every=4)
+    eng.run([init_state(DIMS)])
+    rows = eng._calls.rows()
+    events = events_of(eng)
+    end = events[-1]
+    assert end["event"] == "run_end"
+    assert len(rows) == end["chunk_calls"] + end["ingest_calls"]
+    assert sum(r["passes"] for r in rows) == end["passes"]
+    assert sum(r["parents"] for r in rows if r["kind"] == "chunk") \
+        == end["parents_expanded"]
+    assert [r["call"] for r in rows if r["kind"] == "chunk"] == list(
+        range(1, end["chunk_calls"] + 1))
+    calls = end["calls"]
+    assert calls["n"] == calls["rows"] == len(rows)
+    by_rule = calls["by_rule"]
+    assert sum(r["calls"] for r in by_rule.values()) == len(rows)
+    assert sum(r["passes"] for r in by_rule.values()) == end["passes"]
+    for rule, tally in by_rule.items():
+        mine = [r for r in rows if r["rule"] == rule]
+        assert (tally["calls"], tally["passes"]) == (
+            len(mine), sum(r["passes"] for r in mine))
+    # No budget: a call takes what it is allowed (``full``) but the last
+    # of a level, and the first levels are under a batch.
+    assert set(by_rule) == {"ingest", "full", "level_end"}
+    assert by_rule["ingest"] == {"calls": 1, "passes": 0,
+                                 "seconds": by_rule["ingest"]["seconds"]}
+    # A row's clocks: the gaps and the calls lie inside the run, in order.
+    assert all(b["t"] >= a["t"] + a["dispatch_s"] for a, b in zip(rows,
+                                                                  rows[1:]))
+    assert abs(calls["gap_s"] - sum(r["gap_s"] for r in rows)) < 1e-4
+    # What of a gap lay in spans of the loop's own: a level's end is one.
+    assert all(0.0 <= r["named_s"] <= r["gap_s"] for r in rows)
+    first_of_level = [b for a, b in zip(rows, rows[1:])
+                      if b["level"] > a["level"] > 0]
+    assert first_of_level and all(r["named_s"] > 0 for r in first_of_level)
+    # The event log holds no line a call.
+    assert len(events) < 20 and "call" not in {e["event"] for e in events}
+    assert {"gc", "trace_rehashes", "trace_rehash_s"} <= set(end)
+    # The spans keep the wall alone; the rows carry the thread's CPU.
+    assert "phases_cpu" not in end and sum(r["cpu_s"] for r in rows) >= 0
+
+
+def test_a_budgeted_run_names_the_rule_that_sized_each_call(tmp_path):
+    eng = small_engine(tmp_path, max_seconds=60.0, sync_every=8,
+                       max_diameter=5)
+    eng.run([init_state(DIMS)])
+    end = events_of(eng)[-1]
+    by_rule = end["calls"]["by_rule"]
+    assert sum(r["calls"] for r in by_rule.values()) \
+        == end["chunk_calls"] + end["ingest_calls"]
+    assert sum(r["passes"] for r in by_rule.values()) == end["passes"]
+    # One probe (no estimate yet), the ramp from 2, and the calls a level's
+    # end cut short; the minute left never sizes one.
+    assert by_rule["probe"]["calls"] == end["probe_calls"] == 1
+    assert by_rule["ramp"]["calls"] >= 1 and "deadline" not in by_rule
+    assert by_rule["level_end"]["calls"] >= 3
+    rows = eng._calls.rows()
+    assert all(r["passes"] == r["allowed"] for r in rows
+               if r["rule"] == "ramp")
+    assert all(r["passes"] < r["allowed"] for r in rows
+               if r["rule"] == "level_end")
+
+
+@pytest.mark.parametrize("phase", ["wait", "host", "gap"])
+def test_a_stall_is_named_by_call_and_phase(phase, tmp_path, capfd):
+    """A ``stall`` fault of 0.3 s in one call of level 5: ``slowest`` is
+    that call, its phase the one the time went to, ``stall_s`` the 0.3 s;
+    the thread's CPU clock follows the wall where the loop spun
+    (``host``) and not where it slept."""
+    from raft_tla_tpu.resilience import faults
+    eng = small_engine(tmp_path)
+    faults.install(f"stall@phase={phase};call=12;seconds=0.3", hard=False)
+    try:
+        eng.run([init_state(DIMS)])
+    finally:
+        faults.clear()
+    events = events_of(eng)
+    calls = events[-1]["calls"]
+    slowest = calls["slowest"]
+    assert (slowest["call"], slowest["kind"], slowest["level"]) == (
+        12, "chunk", 5)
+    assert slowest["phase"] == phase
+    assert abs(slowest["excess_s"] - 0.3) < 0.05
+    assert abs(calls["stall_s"] - 0.3) < 0.05 and calls["stall_calls"] == 1
+    assert slowest[phase + "_s"] >= 0.3
+    if phase == "host":
+        # (Not the whole 0.3 s where the suite's other workers take the
+        # core meanwhile: that difference is what the clock is for.)
+        assert slowest["cpu_s"] > 0.1
+    else:
+        assert slowest["cpu_s"] < 0.05
+    assert slowest["expected_s"] < 0.05
+    # Under a second: no event of its own, one line of the run's log.
+    assert calls["slow_calls"] == 0
+    assert "slow_call" not in {e["event"] for e in events}
+    err = capfd.readouterr().err
+    assert "stall: run 1 call 12 (chunk, level 5" in err
+    assert f"in {phase} (" in err and "slow call" not in err
+
+
+def test_a_stall_of_over_a_second_is_an_event_of_its_own(tmp_path, capfd):
+    from raft_tla_tpu.resilience import faults
+    eng = small_engine(tmp_path)
+    faults.install("stall@phase=wait;call=10;seconds=1.2", hard=False)
+    try:
+        eng.run([init_state(DIMS)])
+    finally:
+        faults.clear()
+    events = events_of(eng)
+    slow = [e for e in events if e["event"] == "slow_call"]
+    assert len(slow) == 1 and events[-1]["calls"]["slow_calls"] == 1
+    (e,) = slow
+    assert (e["call"], e["phase"], e["rule"]) == (10, "wait", "full")
+    assert 1.15 < e["excess_s"] < 1.3 and e["wait_s"] > 1.2
+    assert e["cpu_s"] < 0.1 and e["expected_s"] < 0.05
+    assert events.index(e) == len(events) - 2       # just before run_end
+    # And the run's own log names it.
+    assert "slow call: run 1 call 10 (chunk, level 5" in capfd.readouterr().err
+    # Without a plan the sites cost a module flag: nothing fires again.
+    eng.run([init_state(DIMS)])
+    assert events_of(eng)[-1]["calls"]["stall_calls"] == 0
+
+
+def row(call, level, passes, wait_s, **kw):
+    base = dict(call=call, kind="chunk", level=level, passes=passes,
+                gap_s=0.0001, named_s=0.0, dispatch_s=0.0005, flush_s=0.0,
+                wait_s=wait_s, host_s=0.0002)
+    base.update(kw)
+    return base
+
+
+@pytest.mark.parametrize("case", ["level_median", "run_median", "too_few",
+                                  "gap", "named_gap", "under_the_floor"])
+def test_the_reduction_of_a_runs_rows(case):
+    reduce_calls = calls_mod.reduce_calls
+    if case == "level_median":
+        # Level 2 costs ten times level 1 a pass: each is held to its own.
+        rows = ([row(i, 1, 4, 0.004) for i in range(4)]
+                + [row(4 + i, 2, 4, 0.040) for i in range(3)]
+                + [row(7, 2, 8, 0.300)])
+        out = reduce_calls(rows)
+        assert out["slowest"]["call"] == 7 and out["slowest"]["phase"] == "wait"
+        # 8 passes at level 2's median 10.2 ms a pass (0.7 ms a call of
+        # dispatch and accounting included).
+        assert abs(out["slowest"]["expected_s"] - 8 * 0.0407 / 4) < 1e-3
+        assert out["stall_calls"] == 1
+        assert abs(out["stall_s"] - (0.3007 - 0.0814)) < 1e-3
+    elif case == "run_median":
+        # Two calls a level: the run's median a pass stands for all.
+        rows = [row(i, i // 2, 2, 0.002) for i in range(8)]
+        rows[5]["dispatch_s"] = 0.2
+        out = reduce_calls(rows)
+        assert (out["slowest"]["call"], out["slowest"]["phase"]) == (
+            5, "dispatch")
+        assert out["stall_calls"] == 1
+    elif case == "too_few":
+        # Two calls in all: nothing to hold either to, but its gap.
+        out = reduce_calls([row(0, 1, 2, 0.002), row(1, 1, 2, 2.0)])
+        assert out["stall_calls"] == 0 and out["slowest"]["phase"] == "gap"
+        assert reduce_calls([])["slowest"] is None
+    elif case == "gap":
+        rows = [row(i, 1, 4, 0.004) for i in range(6)]
+        rows[3]["gap_s"] = 1.5
+        out = reduce_calls(rows)
+        assert (out["slowest"]["call"], out["slowest"]["phase"]) == (3, "gap")
+        assert len(out["slow"]) == 1 and out["slow"][0]["call"] == 3
+        assert abs(out["stall_s"] - 1.4999) < 1e-3
+    elif case == "named_gap":
+        # 3.6 s between two calls, all but 20 ms of it a seen-set's growth
+        # (a span of the loop's own): named work, no stall.  With 200 ms
+        # of it in no span, those are the stall.
+        rows = [row(i, 1, 4, 0.004) for i in range(6)]
+        rows[3].update(gap_s=3.6, named_s=3.58)
+        out = reduce_calls(rows)
+        assert out["stall_calls"] == 0 and out["slow"] == []
+        rows[3]["named_s"] = 3.4
+        out = reduce_calls(rows)
+        assert (out["slowest"]["call"], out["slowest"]["phase"]) == (3, "gap")
+        assert out["stall_calls"] == 1 and abs(out["stall_s"] - 0.1999) < 1e-3
+    else:
+        # 40 ms over is under STALL_MIN_S; 60 ms over a 100 ms call is
+        # under STALL_FACTOR: neither is a stall, the larger is slowest.
+        rows = ([row(i, 1, 1, 0.004) for i in range(5)]
+                + [row(5, 1, 1, 0.044)]
+                + [row(6 + i, 2, 1, 0.100) for i in range(5)]
+                + [row(11, 2, 1, 0.160)])
+        out = reduce_calls(rows)
+        assert out["stall_calls"] == 0 and out["stall_s"] == 0.0
+        assert out["slowest"]["call"] == 11
+
+
+# -- CPU beside wall, and collections, on every span --------------------------------
+
+def test_a_gap_is_split_into_named_spans_and_the_rest():
+    """Between two calls the loop slept 0.15 s inside a span of its own
+    and 0.1 s in none: the row's ``gap_s`` holds both, ``named_s`` the
+    first, and only the second is a stall.  Spans under another prefix,
+    and another thread's, are not the loop's."""
+    import threading
+    import time
+    from raft_tla_tpu.obs.flight import FlightRecorder
+    mt = MetricsRegistry()
+    log = calls_mod.CallLog(7, recorder=FlightRecorder())
+    log.start()
+
+    def call(i, before=lambda: None):
+        before()
+        log.dispatch()
+        with mt.phase_timer("chunk") as c:
+            pass
+        with mt.phase_timer("stats_fetch") as f:
+            time.sleep(0.002)
+        acc = mt.open_span("account")
+        acc.close()
+        log.row("chunk", "full", 1, c.seconds, f.seconds, 0.0, acc.seconds,
+                i, 1, 1, 1, 1, distinct=i)
+
+    def between():
+        with mt.phase_timer("grow"):
+            time.sleep(0.15)
+        with mt.scope("replay"):
+            time.sleep(0.05)
+
+        def elsewhere():
+            with mt.phase_timer("grow"):
+                time.sleep(0.05)
+
+        other = threading.Thread(target=elsewhere)
+        other.start()
+        other.join()
+
+    for i in range(1, 7):
+        call(i, between if i == 4 else (lambda: None))
+    rows = log.rows()
+    assert [r["call"] for r in rows] == [1, 2, 3, 4, 5, 6]
+    # (Sleeps overshoot on a loaded host: the bounds leave room above.)
+    assert rows[3]["gap_s"] >= 0.25 and 0.15 <= rows[3]["named_s"] < 0.3
+    assert rows[3]["gap_s"] - rows[3]["named_s"] >= 0.1
+    assert all(r["named_s"] < 0.001 for r in rows if r["call"] != 4)
+    out = log.reduce()
+    assert (out["slowest"]["call"], out["slowest"]["phase"]) == (4, "gap")
+    assert out["stall_calls"] == 1 and 0.09 < out["stall_s"] < 0.4
+    assert metrics_mod.open_spans().phase_s >= 0.15
+
+
+def test_a_collection_is_charged_to_the_span_it_fell_in(tmp_path):
+    import gc
+    metrics_mod.watch_compiles()        # registers the collector's hook
+    mt = MetricsRegistry()
+    record = metrics_mod.process_record()
+    base = record.gc_reading()
+    total = metrics_mod.gc_seconds()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "xplane"), profiler_options=opts)
+    try:
+        with mt.scope("outer"):
+            with mt.phase_timer("inner"):
+                cycles = []
+                for _ in range(1000):
+                    a, b = [], []
+                    a.append(b), b.append(a)
+                    cycles.append(a)
+                del cycles
+                gc.collect()
+            with mt.phase_timer("after"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    since = record.gc_since(base)
+    assert since["collections"][2] >= 1
+    assert since["by_span"].get("inner", 0) > 0
+    assert "after" not in since["by_span"] or since["by_span"]["after"] < \
+        since["by_span"]["inner"]
+    assert abs(sum(since["by_span"].values()) - since["seconds"]) < 1e-5
+    assert abs(sum(since["seconds_by_generation"]) - since["seconds"]) < 1e-5
+    assert mt.counter_value("gc/inner") >= 1
+    assert abs(mt.counter_value("gc_seconds/inner")
+               - since["by_span"]["inner"]) < 1e-5
+    assert metrics_mod.gc_seconds() - total >= since["by_span"]["inner"] - 1e-5
+    # Inside the capture the collection is ``raft.gc`` on the host's line.
+    from jax.profiler import ProfileData
+    pb = glob.glob(str(tmp_path / "xplane" / "**" / "*.xplane.pb"),
+                   recursive=True)[0]
+    noted = [dict(ev.stats)
+             for plane in ProfileData.from_file(pb).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines
+             for ev in line.events if ev.name == "raft.gc"]
+    full = [s for s in noted if str(s.get("generation")) == "2"]
+    assert full and all("collected" in s for s in noted)
+    assert max(int(s["collected"]) for s in full) >= 1000
+
+
+def test_run_end_carries_the_runs_collections(tmp_path, monkeypatch):
+    import gc
+    eng = small_engine(tmp_path, max_diameter=3)
+    level_event = BFSEngine._level_event
+
+    def collecting(self, res, frontier_rows):
+        gc.collect()
+        level_event(self, res, frontier_rows)
+
+    monkeypatch.setattr(BFSEngine, "_level_event", collecting)
+    eng.run([init_state(DIMS)])
+    end = events_of(eng)[-1]
+    assert end["gc"]["collections"][2] >= 4         # levels 0-3
+    assert end["gc"]["by_span"]["level_end"] > 0
+    assert end["gc"]["seconds"] >= end["gc"]["by_span"]["level_end"]
+    # A call's row holds the collections of its gap and itself: the first
+    # call of a level follows a level's end.
+    rows = eng._calls.rows()
+    first = [r for r in rows if r["kind"] == "chunk"
+             and r["level"] >= 2][0]
+    assert first["gc_s"] > 0

@@ -23,12 +23,14 @@ import pytest
 
 from raft_tla_tpu.engine import bfs as bfs_mod
 from raft_tla_tpu.engine import checkpoint as ckpt_mod
+from raft_tla_tpu.engine import trace as trace_mod
 from raft_tla_tpu.engine.bfs import BFSEngine, EngineConfig, flush_plan
 from raft_tla_tpu.models import oracle as orc
 from raft_tla_tpu.models.dims import LEADER, RaftDims
 from raft_tla_tpu.models.invariants import (Bounds, build_constraint,
                                             build_type_ok)
 from raft_tla_tpu.models.pystate import init_state
+from raft_tla_tpu.parallel import mesh as mesh_mod
 from raft_tla_tpu.parallel.mesh import MeshBFSEngine
 
 DIMS = RaftDims(n_servers=3, n_values=2, max_log=4, n_msg_slots=32)
@@ -277,6 +279,12 @@ def drained_where_the_seen_set_grows(kind, tmp_path):
     assert res.levels == (LEVELS + [1378])[:depth + 1]
     assert len(eng.trace.export()[0]) == res.distinct
     assert_every_chain_reaches_a_root(eng.trace)
+    # The growth lay between two calls in a span of the loop's own
+    # (``grow``, seconds of compiling here): the rows take it for named
+    # work (``named_s``), so it is no stall however long it took.
+    named = sum(r["named_s"] for r in eng._calls.rows())
+    assert named >= sum(s for _cap, s in res.growth_stalls) - 0.01
+    assert run_end(ev)["calls"]["slow_calls"] == 0
     return [eng]
 
 
@@ -347,7 +355,7 @@ def test_a_calls_cost_runs_from_its_dispatch_to_its_statistics(kind,
     def slow(self, counter):
         if counter == "flush_overlapped" and self._owed is not None:
             time.sleep(0.25)
-        finish(self, counter)
+        return finish(self, counter)
 
     monkeypatch.setattr(bfs_mod._TraceFlush, "finish", slow)
     # Level 3's 52 parents: 4 calls, of 16 or of four chips' 4.
@@ -371,3 +379,90 @@ def test_without_trace_recording_no_fetch_is_dispatched(kind, started,
     counts = bfs_mod.work_counts(eng.metrics)
     assert counts["flush_overlapped"] == counts["flush_drained"] == 0
     assert counts["chunk_calls"] > 5
+
+
+# -- what growing costs the store -------------------------------------------------
+
+def _records(n, seed=0):
+    rng = np.random.default_rng(seed)
+    fps = rng.integers(1, 2 ** 63, size=n, dtype=np.uint64)
+    return fps, fps >> np.uint64(1), np.zeros(n, np.int32)
+
+
+@pytest.mark.parametrize("store", ["native", "python"])
+def test_a_store_says_when_it_rehashed(store):
+    """The native store doubles at 70 % load with a ``calloc`` and every
+    entry inserted again, inside ``add_batch``: ``stats`` counts the
+    doublings and their seconds.  The dict fallback grows out of sight
+    and reads zeros."""
+    trace = (trace_mod.make_trace_store(1 << 10) if store == "native"
+             else trace_mod.PyTraceStore())
+    if store == "native" and not isinstance(trace,
+                                            trace_mod.NativeTraceStore):
+        pytest.skip("no compiler on this host: the dict store stands in")
+    assert trace.stats()["rehashes"] == 0
+    fps, parents, actions = _records(700)
+    trace.add_batch(fps, parents, actions)          # 68 % of 1,024
+    first = trace.stats()
+    trace.add_batch(*_records(100, seed=1))         # past 70 %: one doubling
+    grown = trace.stats()
+    assert len(trace) == 800
+    if store == "python":
+        assert first == grown == {"rehashes": 0, "rehash_s": 0.0}
+        return
+    assert first == {"rehashes": 0, "rehash_s": 0.0}
+    assert grown["rehashes"] == 1 and grown["rehash_s"] > 0.0
+    # A batch is given its room BEFORE its first record goes in
+    # (``reserve``): 6,000 more are ONE rehash to 2^14, not three.
+    trace.add_batch(*_records(6000, seed=2))
+    assert trace.stats()["rehashes"] == 2
+    assert trace.stats()["rehash_s"] > grown["rehash_s"]
+    assert trace.get(int(fps[0])) == (int(parents[0]), 0)
+
+
+def test_run_end_carries_what_the_stores_growing_cost(kind, tmp_path,
+                                                      monkeypatch):
+    """A run's store started small: ``run_end`` carries the store's own
+    total of what crossing the load cost it, read once at the run's end
+    (nothing asks the store anything in the loop)."""
+    small = functools.partial(trace_mod.make_trace_store, 1 << 10)
+    if not isinstance(small(), trace_mod.NativeTraceStore):
+        pytest.skip("no compiler on this host")
+    monkeypatch.setattr(bfs_mod, "make_trace_store", small)
+    monkeypatch.setattr(mesh_mod, "make_trace_store", small)
+    ev = str(tmp_path / "ev.jsonl")
+    eng = make_engine(kind, events_out=ev)
+    res = eng.run([init_state(DIMS)])
+    assert res.distinct == DISTINCT
+    end = run_end(ev)
+    grown = eng.trace.stats()
+    assert end["trace_rehashes"] == grown["rehashes"] == 1
+    assert end["trace_rehash_s"] == round(grown["rehash_s"], 6) > 0
+    # A second run makes a store of its own: its total starts again.
+    eng.config.max_diameter = 2
+    eng.run([init_state(DIMS)])
+    assert run_end(ev)["trace_rehashes"] == 0
+
+
+def test_a_resumes_refill_is_one_rehash_of_an_empty_table(kind, tmp_path,
+                                                          monkeypatch):
+    """``restore`` hands the snapshot's records to a new store in one
+    ``add_batch``, which takes its room first: one doubling that moves
+    nothing, whatever the snapshot's size (``restore_rehashes``)."""
+    if not isinstance(trace_mod.make_trace_store(),
+                      trace_mod.NativeTraceStore):
+        pytest.skip("no compiler on this host")
+    ck, ev = str(tmp_path / "states"), str(tmp_path / "ev.jsonl")
+    make_engine(kind, checkpoint_dir=ck).run([init_state(DIMS)])
+    snap = ckpt_mod.load(ckpt_mod.latest(ck))
+    assert snap.diameter == 5 and snap.trace_fps.size == DISTINCT
+    small = functools.partial(trace_mod.make_trace_store, 1 << 10)
+    monkeypatch.setattr(bfs_mod, "make_trace_store", small)
+    monkeypatch.setattr(mesh_mod, "make_trace_store", small)
+    resumed = make_engine(kind, events_out=ev, max_diameter=6)
+    resumed.run(resume=snap)
+    end = run_end(ev)
+    # 1,313 records into 1,024 slots: straight to 2^11, nothing moved.
+    assert (end["restore_rehashes"], end["restore_rehash_s"] > 0) == (1, True)
+    # Level 6 then outgrows 2^11 inside the run's own flushes.
+    assert end["trace_rehashes"] > end["restore_rehashes"]
