@@ -88,9 +88,13 @@ _I32 = jnp.int32
 # evaluates an invariant once a new distinct state).  ``deadline_calls``
 # and ``probe_calls``: chunk calls of a budgeted run whose size the
 # time left set, and those of one batch made for want of an estimate
-# (``budget_call_size``).
+# (``budget_call_size``).  ``flush_*`` and ``level_closes_*``: trace
+# flushes and level closes by where their host half ran, behind a
+# dispatched call or with the device empty (``_TraceFlush``,
+# ``_LevelClose``).
 WORK_COUNTERS = ("chunk_calls", "passes", "inv_lanes", "ingest_calls",
                  "parents_expanded", "flush_overlapped", "flush_drained",
+                 "level_closes_overlapped", "level_closes_drained",
                  "deadline_calls", "probe_calls")
 
 
@@ -197,11 +201,13 @@ class _TraceFlush:
     whatever is dispatched next, which may donate those buffers.
     ``finish`` waits for the copies and hands the records to the store.
     The loop calls it behind the next chunk dispatch (counted as
-    ``flush_overlapped``), or with the device empty where something other
-    than a plain next call comes first: the end of a level, any stop
-    (``flush_drained``).  So at most one flush is owed, and records reach
-    the store in the order the calls ran.  A run that raises takes what
-    it owed with it: ``_run_degradable`` resumes into a new store.
+    ``flush_overlapped``), the first call of the next level included, or
+    with the device empty where something other than a plain next call
+    comes first: a snapshot due at a level's end, any stop, the run's end
+    (``flush_drained``; the mesh loop drains at every level's end).  So
+    at most one flush is owed, and records reach the store in the order
+    the calls ran.  A run that raises takes what it owed with it:
+    ``_run_degradable`` resumes into a new store.
 
     ``finish`` returns the seconds its host half took (0.0 with nothing
     owed), for the call's row.
@@ -240,6 +246,54 @@ class _TraceFlush:
             for cols, lo, hi in owed:
                 eng._record(self._trace, cols, lo, hi)
         eng.metrics.counter("engine/" + counter)
+        return span.seconds
+
+
+class _LevelClose:
+    """A level's ``level_complete`` on its way out, in two halves like
+    the flush it follows, so that the next level's first call need not
+    wait for it.  ``owe``, at the boundary, reads everything the event
+    says (``BFSEngine._level_fields``).  ``finish`` writes the line, as
+    the ``level_end`` span.  ``BFSEngine``'s loop calls it right after
+    the flush's ``finish``: behind the first dispatch of the next level
+    (``level_closes_overlapped``), or with the device empty where the
+    boundary is followed by anything else, a snapshot, a stop, the run's
+    end (``level_closes_drained``).  So at most one close is owed, it is
+    written before any event of the next level, and a run that raises
+    takes it along as it does its flush.
+
+    ``finish`` returns the seconds of its span (0.0 with nothing owed),
+    for the call's row."""
+
+    def __init__(self, engine):
+        self._eng, self._owed = engine, None
+
+    @property
+    def owed(self) -> bool:
+        return self._owed is not None
+
+    def owe(self, res, frontier_rows) -> None:
+        assert self._owed is None, "a level's close is owed already"
+        eng = self._eng
+        self._owed = (eng._level_fields(res, frontier_rows),
+                      eng._level_span)
+
+    def finish(self, counter: str) -> float:
+        if self._owed is None:
+            return 0.0
+        eng = self._eng
+        (fields, level_span), self._owed = self._owed, None
+        with eng.metrics.phase_timer("level_end") as span:
+            eng._evlog.emit("level_complete", **fields)
+        eng.metrics.counter("engine/" + counter)
+        if eng._level_span is level_span:
+            # No next level was opened: the close is the last span
+            # inside the level's own.
+            eng._close_level_span()
+        if eng.tracer.enabled:
+            # Level-boundary durability: a crash loses at most the
+            # current level's spans (atomic rewrite, off the hot loop).
+            eng.tracer.write()
         return span.seconds
 
 
@@ -462,7 +516,7 @@ class EngineResult:
     report: Dict = dataclasses.field(default_factory=dict)
     # Per-level boundary snapshots feeding the report's level table
     # ({level, frontier, distinct, generated, seen_size,
-    # seen_capacity}), appended by _emit_level_event.  A resumed run's
+    # seen_capacity}), appended by _level_fields.  A resumed run's
     # pre-resume levels appear in the report with frontier width only.
     level_stats: List = dataclasses.field(default_factory=list)
     # Paths of the auto-rendered counterexample artifacts
@@ -1370,25 +1424,26 @@ class BFSEngine:
         return "counterexample"
 
     def _emit_level_event(self, res, frontier_rows):
-        """level_complete: live counters + cumulative per-phase wall-time
-        breakdown.  ``unattributed_seconds`` closes the accounting —
-        phases + unattributed == elapsed since run_start — so a phase
-        that silently stops being timed shows up as growing slack, not a
-        plausible-looking breakdown.  Runs as the ``level_end`` span, the
-        last inside the level's own, which it then closes (one ``level``
-        span per BFS level)."""
-        with self.metrics.phase_timer("level_end"):
-            self._level_event(res, frontier_rows)
-        self._close_level_span()
-        if self.tracer.enabled:
-            # Level-boundary durability: a crash loses at most the
-            # current level's spans (atomic rewrite, off the hot loop).
-            self.tracer.write()
+        """A level's close, both halves at once, with the device empty
+        (``_LevelClose``): the ``level_end`` span is the last inside the
+        level's own, which is then closed (one ``level`` span per BFS
+        level).  The mesh loop's boundary.  ``BFSEngine``'s loop owes
+        the close instead and finishes it behind the next level's first
+        dispatch: level L's ``level_end`` span (after its last
+        ``trace_flush``) then lies inside level L+1's ``level`` span,
+        between that level's first ``chunk`` span and its first
+        ``stats_fetch``, and level L's own span ends where L+1's
+        begins."""
+        close = _LevelClose(self)
+        close.owe(res, frontier_rows)
+        close.finish("level_closes_drained")
 
     def _open_level_span(self, level: int) -> None:
-        """The ``level`` span of the level about to be built.
-        Loop-shaped, so held open on the engine; ``_emit_level_event``
-        closes it, or ``_end_run`` when the level is left unfinished."""
+        """The ``level`` span of the level about to be built, which ends
+        the one before it.  Loop-shaped, so held open on the engine; the
+        level's close ends it where no next level follows
+        (``_LevelClose.finish``), or ``_end_run`` when the level is left
+        unfinished."""
         self._close_level_span()
         self._level_span = self.metrics.open_span(
             "level", SCOPE_PREFIX, level=level)
@@ -1407,7 +1462,15 @@ class BFSEngine:
         mt.counter("engine/inv_lanes", passes * self._inv_lanes_a_pass)
         mt.counter("engine/parents_expanded", parents)
 
-    def _level_event(self, res, frontier_rows):
+    def _level_fields(self, res, frontier_rows) -> dict:
+        """What ``level_complete`` says of the level just built: live
+        counters + cumulative per-phase wall-time breakdown, all read
+        HERE, at the boundary (the next call's dispatch moves the phase
+        seconds, the budget counters and the device's memory in use).
+        ``unattributed_seconds`` closes the accounting — phases +
+        unattributed == elapsed since run_start — so a phase that
+        silently stops being timed shows up as growing slack, not a
+        plausible-looking breakdown."""
         evlog = self._evlog
         # Per-level device-HBM watermark: run_end's one-shot
         # devices_memory probe cannot say WHICH level drove the peak —
@@ -1467,10 +1530,11 @@ class BFSEngine:
             extra = {"frontier_skew": skew.get("frontier_skew"),
                      "seen_skew": skew.get("seen_skew"),
                      "shard_frontier": skew.get("shard_frontier")}
-        work = work_counts(self.metrics, self._work_level_base)
-        self._work_level_base = work_counts(self.metrics)
-        evlog.emit(
-            "level_complete", level=res.diameter,
+        now = work_counts(self.metrics)
+        work = {k: n - self._work_level_base[k] for k, n in now.items()}
+        self._work_level_base = now
+        return dict(
+            level=res.diameter,
             frontier_rows=frontier_rows, distinct=res.distinct,
             generated=res.generated,
             generated_by_family=self._generated_by_family(res),
@@ -1652,6 +1716,15 @@ class BFSEngine:
                 for length in self._fetch_lens:
                     self._fetch(tbuf, np.int32(0), length)
         flush = _TraceFlush(self, trace)
+        close = _LevelClose(self)
+
+        def settle():
+            """With the device empty: the flush and the level's close
+            that are owed, before anything reads the store, snapshots
+            the run or leaves the loop."""
+            flush.finish("flush_drained")
+            close.finish("level_closes_drained")
+
         calls = self._calls
         t0 = time.time()
         last_progress = t0
@@ -1799,7 +1872,7 @@ class BFSEngine:
             # leak into this run's level-0 row.
             mt.gauge("engine/seen_capacity", len(seen.hi))
             mt.gauge("engine/seen_size", int(seen.size))
-            self._emit_level_event(res, res.levels[-1])
+            close.owe(res, res.levels[-1])
             qcur, qnext = qnext, qcur
             cur_count = int(next_count)
             pending, spill_next = spill_next, pending
@@ -1823,6 +1896,7 @@ class BFSEngine:
                     and res.diameter != skip_ckpt_level \
                     and (time.time() - last_ckpt
                          >= cfg.checkpoint_interval_seconds):
+                settle()        # the snapshot reads the store
                 with mt.phase_timer("checkpoint"):
                     self._write_checkpoint(qcur, cur_count, pending, seen,
                                            res, trace,
@@ -1911,8 +1985,10 @@ class BFSEngine:
                                           jnp.int32(allowed))
                         qnext, seen, tbuf = out[0], out[1], out[2]
                     # The host half of the previous call's flush, while
-                    # the device runs this one.
-                    flush_s = flush.finish("flush_overlapped")
+                    # the device runs this one; behind a level's first
+                    # call, the close of the level before it too.
+                    flush_s = (flush.finish("flush_overlapped")
+                               + close.finish("level_closes_overlapped"))
                     # The packed-stats fetch is the loop's one blocking
                     # device sync — its phase time IS the device compute
                     # the dispatch above overlapped.
@@ -2085,27 +2161,33 @@ class BFSEngine:
                 if res.stop_reason != "exhausted" \
                         or res.violation is not None or not pending:
                     break
-                # Upload the next host segment of this level.
+                # Upload the next host segment of this level (a level
+                # that begins with one: its predecessor's close first).
+                if close.owed:
+                    settle()
                 with mt.phase_timer("upload"):
                     seg = pending.pop(0)
                     buf = np.zeros((QA, sw), ROW_DTYPE)
                     buf[:len(seg)] = seg
                     qcur = jax.device_put(buf, qcur.devices().pop())
                     cur_count = len(seg)
-            # The level is built, or the run stops: what follows reads
-            # the store (a snapshot, a replay) or closes the level's span.
-            flush.finish("flush_drained")
             if res.stop_reason != "exhausted" or res.violation is not None:
                 break  # aborted mid-level: diameter counts completed levels
             resolve_spill()      # level boundary: all drains must land
             res.diameter += 1
             res.levels.append(next_count_h
                               + spill_next.total_rows())
-            self._emit_level_event(res, res.levels[-1])
+            # The level is built.  Its last flush and its close stay
+            # owed: where the next level follows they ride behind its
+            # first dispatch, and everything else settles them first.
+            close.owe(res, res.levels[-1])
             qcur, qnext = qnext, qcur
             cur_count = next_count_h
             pending, spill_next = spill_next, pending
 
+        # The run stops: what follows reads the store (a replay) or ends
+        # the run.
+        settle()
         res.wall_seconds = time.time() - t0
         if resume is None:
             self._keep_capacity(seen)

@@ -781,7 +781,7 @@ class MeshBFSEngine:
     # (they touch nothing of its).
     _emit_level_event = BFSEngine._emit_level_event
     _end_run = BFSEngine._end_run
-    _level_event = BFSEngine._level_event
+    _level_fields = BFSEngine._level_fields
     _open_level_span = BFSEngine._open_level_span
     _close_level_span = BFSEngine._close_level_span
     _count_chunk_call = BFSEngine._count_chunk_call

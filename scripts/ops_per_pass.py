@@ -12,9 +12,9 @@ opens no span (copy it there, beside this PR's ``benchmark/`` files): the
 number to compare between two commits that must run the same program.
 
 Beside the counts it prints what the window's own ``run_end`` event
-counted (chunk calls, passes, trace flushes overlapped and drained,
-the mesh's insert windows by chip and what its resume overlapped,
-compiles by span), the pools' fill,
+counted (chunk calls, passes, trace flushes and level closes
+overlapped and drained, the mesh's insert windows by chip and what its
+resume overlapped, compiles by span), the pools' fill,
 the window's phases and the per-layer metrics as ``run.py --trace 1``
 reduces them.
 
@@ -157,7 +157,8 @@ def main() -> int:
                     if e["event"] == "run_end"), {})
         out["run_end"] = {k: end[k] for k in (
             "chunk_calls", "passes", "ingest_calls", "flush_overlapped",
-            "flush_drained", "chip_insert_windows", "restore_pieces",
+            "flush_drained", "level_closes_overlapped",
+            "level_closes_drained", "chip_insert_windows", "restore_pieces",
             "restore_rounds", "restore_lane_rounds", "restore_host_s",
             "restore_wait_s", "compiles") if k in end}
         out["fill"] = run.get("counters")

@@ -6,6 +6,7 @@ their stages and carry the names' tag in what is hashed for the compile
 cache.
 """
 
+import dataclasses
 import glob
 import json
 import os
@@ -22,7 +23,7 @@ from raft_tla_tpu.engine.check import initial_states, make_engine
 from raft_tla_tpu.models.dims import RaftDims
 from raft_tla_tpu.models.invariants import Bounds, build_constraint
 from raft_tla_tpu.models.pystate import init_state
-from raft_tla_tpu.obs import MetricsRegistry, SpanTracer
+from raft_tla_tpu.obs import MetricsRegistry, RunEventLog, SpanTracer
 from raft_tla_tpu.obs import calls as calls_mod
 from raft_tla_tpu.obs import metrics as metrics_mod
 from raft_tla_tpu.obs.metrics import innermost_span
@@ -236,14 +237,105 @@ def test_loop_counters_equal_a_hand_count(noleader, tmp_path):
         again = [json.loads(line) for line in f][-1]
     assert (again["chunk_calls"], again["passes"], again["ingest_calls"],
             again["parents_expanded"]) == (4, 4, 1, 71)
-    # One call a level: each flush is drained at its level's end.
+    # One call a level: each flush rides behind the next level's first
+    # call, with the close of its level (the root level's too); the one
+    # before ``max_diameter`` stops the run is drained.
     assert bfs_mod.work_counts(eng.metrics) == {
         "chunk_calls": 8, "passes": 8, "inv_lanes": 8 * eng._K,
         "ingest_calls": 2,
-        "parents_expanded": 142, "flush_overlapped": 0,
-        "flush_drained": 8,
+        "parents_expanded": 142, "flush_overlapped": 6,
+        "flush_drained": 2,
+        "level_closes_overlapped": 8, "level_closes_drained": 2,
         # No duration budget: no call is a probe or sized by a deadline.
         "deadline_calls": 0, "probe_calls": 0}
+
+
+def test_a_levels_close_lies_behind_the_next_levels_first_call(verdict):
+    """Level L+1's ``level`` span opens before its first ``chunk``; level
+    L's last flush and its ``level_end`` lie inside it, between that
+    ``chunk`` and the ``stats_fetch`` that follows; level L's own span
+    ends where L+1's begins.  (The benchmark's readers charge idle time
+    to the innermost span open: ``benchmark/readers/spans.py``.)"""
+    eng, res, steps, events, chrome, noted = verdict
+    by = {}
+    for e in sorted(chrome, key=lambda e: e["ts"]):
+        by.setdefault(e["name"], []).append(e)
+    levels, closes = by["level"], by["level_end"]
+    end = lambda e: e["ts"] + e["dur"]          # noqa: E731
+    # Every level but the violation's was closed, each behind a call.
+    assert len(closes) == len(levels) - 1 == len(res.levels)
+    leaves = [e for e in sorted(chrome, key=lambda e: e["ts"])
+              if e["name"] not in CONTAINERS]
+    for closed, (lv, nxt) in enumerate(zip(levels, levels[1:])):
+        assert end(lv) <= nxt["ts"] + 1e-3
+        inside = [e["name"] for e in leaves
+                  if nxt["ts"] <= e["ts"] and end(e) <= end(nxt) + 1e-3]
+        # The root level's records went through ``ingest`` itself.
+        flush = ["trace_flush"] if closed else []
+        assert inside[:len(flush) + 3] == (
+            ["chunk"] + flush + ["level_end", "stats_fetch"]), (closed,
+                                                                inside)
+    run_end = events[-1]
+    assert (run_end["level_closes_overlapped"],
+            run_end["level_closes_drained"]) == (len(closes), 0)
+    # Nothing but the violating call's own flush met an empty device.
+    assert run_end["flush_drained"] == 1
+
+
+@pytest.mark.parametrize("max_diameter", [None, 6],
+                         ids=["to_the_violation", "to_a_diameter"])
+def test_level_complete_says_the_same_behind_a_call_as_with_the_device_empty(
+        noleader, max_diameter, tmp_path):
+    """The canary's check twice: as it runs (every close but the last
+    behind the next level's first call) and with every boundary settled
+    at once.  The events agree field by field, but for the clocks and
+    for the four counters that say WHERE a flush and a close ran (a
+    level's last flush counts in the next level's row when it rides
+    behind that level's first call)."""
+    cfg = EngineConfig(batch=64, queue_capacity=1 << 14,
+                       seen_capacity=1 << 17, max_diameter=max_diameter)
+    logs = []
+    # The drained order, through a snapshot due at every level.
+    for name, ck in (("riding", None), ("settled", str(tmp_path / "ck"))):
+        ev = str(tmp_path / (name + ".jsonl"))
+        eng = make_engine(noleader, dataclasses.replace(
+            cfg, events_out=ev, checkpoint_dir=ck))
+        res = eng.run(initial_states(noleader))
+        with open(ev, encoding="utf-8") as f:
+            logs.append((res, [json.loads(line) for line in f]))
+    (res, riding), (_res, settled) = logs
+    end = riding[-1]
+    n_levels = len(res.levels)
+    assert n_levels == (9 if max_diameter is None else 7)
+    assert end["flush_drained"] <= 2
+    assert end["level_closes_overlapped"] >= n_levels - 2
+    assert end["level_closes_overlapped"] + end["level_closes_drained"] \
+        == n_levels
+    assert (settled[-1]["level_closes_overlapped"],
+            settled[-1]["level_closes_drained"]) == (0, n_levels)
+    clocks = {"ts", "elapsed_seconds", "phase_seconds",
+              "unattributed_seconds", "memory"}
+    where = {"flush_overlapped", "flush_drained", "level_closes_overlapped",
+             "level_closes_drained"}
+
+    def closes(events):
+        return [{k: v for k, v in e.items() if k not in clocks | where}
+                for e in events if e["event"] == "level_complete"]
+
+    assert closes(riding) == closes(settled)
+    assert len(closes(riding)) == n_levels
+    assert {"level", "frontier_rows", "distinct", "generated",
+            "generated_by_family", "chunk_calls", "passes",
+            "parents_expanded", "ingest_calls"} <= set(closes(riding)[0])
+    # Every flush and every close is in some row, or in the run's end.
+    for events in (riding, settled):
+        for k in where:
+            assert sum(e[k] for e in events
+                       if e["event"] == "level_complete") <= events[-1][k]
+    # ... and a level's close precedes every event of the next level.
+    names = [(e["event"], e.get("level")) for e in riding]
+    assert names.index(("level_complete", n_levels - 1)) \
+        < names.index(("run_end", None))
 
 
 def test_deeper_levels_take_the_passes_their_frontier_needs(verdict):
@@ -277,7 +369,8 @@ def test_compiles_are_charged_to_the_span_they_fell_in(noleader, tmp_path):
     n, seconds = compiles["warmup"]
     # ingest, chunk and one fetch program a length
     assert n >= 2 + len(eng._fetch_lens) and seconds > 0
-    assert end["flush_drained"] == 5            # five flushes, five lengths
+    # five flushes, five lengths
+    assert end["flush_overlapped"] + end["flush_drained"] == 5
     for span in ("trace_flush", "stats_fetch", "account", "chunk"):
         assert span not in compiles, compiles
     # The same sizes again: everything is in the jit cache, and a run
@@ -525,6 +618,26 @@ def test_the_rows_of_a_run_sum_to_its_counters(tmp_path):
     assert {"gc", "trace_rehashes", "trace_rehash_s"} <= set(end)
     # The spans keep the wall alone; the rows carry the thread's CPU.
     assert "phases_cpu" not in end and sum(r["cpu_s"] for r in rows) >= 0
+
+
+def test_a_close_behind_a_call_is_in_that_calls_row(tmp_path):
+    """With no trace to flush, ``flush_s`` is the close alone: the first
+    call of every level carries the ``level_end`` span that ran behind
+    its dispatch, no other call carries anything, and the one close
+    ``max_diameter`` settled is in no row."""
+    eng = small_engine(tmp_path)
+    res = eng.run([init_state(DIMS)])
+    rows = eng._calls.rows()
+    first = [b["call"] for a, b in zip(rows, rows[1:])
+             if b["level"] > a["level"]]
+    assert first == [1, 2, 3, 4, 8]
+    assert [r["call"] for r in rows
+            if r["kind"] == "chunk" and r["flush_s"] > 0] == first
+    riding = sum(r["flush_s"] for r in rows)
+    assert 0 < riding < res.phases["level_end"]
+    end = events_of(eng)[-1]
+    assert (end["level_closes_overlapped"],
+            end["level_closes_drained"]) == (5, 1)
 
 
 def test_a_budgeted_run_names_the_rule_that_sized_each_call(tmp_path):
@@ -782,20 +895,21 @@ def test_a_collection_is_charged_to_the_span_it_fell_in(tmp_path):
 def test_run_end_carries_the_runs_collections(tmp_path, monkeypatch):
     import gc
     eng = small_engine(tmp_path, max_diameter=3)
-    level_event = BFSEngine._level_event
+    emit = RunEventLog.emit
 
-    def collecting(self, res, frontier_rows):
-        gc.collect()
-        level_event(self, res, frontier_rows)
+    def collecting(self, event, **fields):
+        if event == "level_complete":       # inside the ``level_end`` span
+            gc.collect()
+        emit(self, event, **fields)
 
-    monkeypatch.setattr(BFSEngine, "_level_event", collecting)
+    monkeypatch.setattr(RunEventLog, "emit", collecting)
     eng.run([init_state(DIMS)])
     end = events_of(eng)[-1]
     assert end["gc"]["collections"][2] >= 4         # levels 0-3
     assert end["gc"]["by_span"]["level_end"] > 0
     assert end["gc"]["seconds"] >= end["gc"]["by_span"]["level_end"]
-    # A call's row holds the collections of its gap and itself: the first
-    # call of a level follows a level's end.
+    # A call's row holds the collections of its gap and itself: behind
+    # the first call of a level runs the close of the level before it.
     rows = eng._calls.rows()
     first = [r for r in rows if r["kind"] == "chunk"
              and r["level"] >= 2][0]

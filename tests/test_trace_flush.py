@@ -10,6 +10,10 @@ virtual devices, four parents a chip a call: from one root everything
 lies on chip 0 until level 2's 15 rows are dealt out, so its chips'
 counts differ and some are 0; its roots' records go through the flush
 too, drained at once (the one-chip ingest hands its own back itself).
+
+The one-chip loop owes a level's close (``_LevelClose``) with its last
+flush and finishes both behind the next level's first dispatch; the mesh
+loop drains at every level's end.
 """
 
 import functools
@@ -63,11 +67,26 @@ def make_engine(kind="one_chip", invariants=None, chips=4, **kw):
                config=EngineConfig(**base))
 
 
-def run_end(path):
+def events_of(path):
     with open(path, encoding="utf-8") as f:
-        end = [json.loads(line) for line in f][-1]
+        return [json.loads(line) for line in f]
+
+
+def run_end(path):
+    end = events_of(path)[-1]
     assert end["event"] == "run_end"
     return end
+
+
+def one_vote_short():
+    """A root and the invariants it breaks two levels on (the root of
+    tests/test_engine.py: a candidate one vote short of quorum)."""
+    s0 = init_state(DIMS).replace(
+        role=(1, 0, 0), current_term=(2, 2, 2), voted_for=(1, 1, 1),
+        votes_responded=(0b001, 0, 0), votes_granted=(0b001, 0, 0),
+        messages=frozenset({((1, 1, 0, 2, 1, ()), 1)}))
+    return s0, {"TypeOK": build_type_ok(DIMS),
+                "NoLeader": lambda st: jnp.all(st.role != LEADER)}
 
 
 @pytest.fixture
@@ -221,21 +240,15 @@ def drained_before_a_checkpoint(kind, tmp_path):
 
 def drained_before_a_replay(kind, tmp_path):
     """The violation lies in a call dispatched with its predecessor's
-    flush still owed; the replay needs both calls' records.  (The root
-    of tests/test_engine.py: a candidate one vote short of quorum.)"""
-    s0 = init_state(DIMS).replace(
-        role=(1, 0, 0), current_term=(2, 2, 2), voted_for=(1, 1, 1),
-        votes_responded=(0b001, 0, 0), votes_granted=(0b001, 0, 0),
-        messages=frozenset({((1, 1, 0, 2, 1, ()), 1)}))
+    flush still owed; the replay needs both calls' records."""
+    s0, invariants = one_vote_short()
     ev = str(tmp_path / "ev.jsonl")
     eng = make_engine(
-        kind, invariants={"TypeOK": build_type_ok(DIMS),
-                          "NoLeader": lambda st: jnp.all(st.role != LEADER)},
+        kind, invariants=invariants,
         batch=1 if kind == "mesh" else 2, max_diameter=None, events_out=ev)
     res = eng.run([s0])
     assert res.stop_reason == "violation"
-    with open(ev, encoding="utf-8") as f:
-        events = [json.loads(line) for line in f]
+    events = events_of(ev)
     whole_levels = sum(e["chunk_calls"] for e in events
                        if e["event"] == "level_complete")
     assert events[-1]["chunk_calls"] - whole_levels >= 2
@@ -288,10 +301,52 @@ def drained_where_the_seen_set_grows(kind, tmp_path):
     return [eng]
 
 
+def settled_before_a_snapshot_among_owed_closes(kind, tmp_path):
+    """A snapshot every other level: the closes of levels 1 and 3 ride
+    behind the next level's first call, those of levels 0, 2 and 4 are
+    settled first, and level 4's snapshot holds every record of the
+    level it closes, its last call's among them: the resumed run ends
+    with the store of an uninterrupted one."""
+    ck, ev = str(tmp_path / "ck"), str(tmp_path / "ev.jsonl")
+    first = make_engine(kind, max_diameter=4, checkpoint_dir=ck,
+                        checkpoint_every=2, events_out=ev)
+    first.run([init_state(DIMS)])
+    events = events_of(ev)
+    order = [(e["event"], e["level"]) for e in events
+             if e["event"] in ("level_complete", "checkpoint")]
+    assert order == [("level_complete", 0), ("checkpoint", 0),
+                     ("level_complete", 1), ("level_complete", 2),
+                     ("checkpoint", 2), ("level_complete", 3),
+                     ("level_complete", 4), ("checkpoint", 4)]
+    if kind == "one_chip":
+        end = events[-1]
+        assert (end["level_closes_overlapped"],
+                end["level_closes_drained"]) == (2, 3)
+    path = ckpt_mod.latest(ck)
+    kept = ckpt_mod.load(path)
+    assert kept.levels == tuple(LEVELS[:5])
+    assert kept.trace_fps.size == kept.distinct
+    second = make_engine(kind)
+    got = second.run(resume=path)
+    assert (got.levels, got.distinct) == (LEVELS, DISTINCT)
+    whole = make_engine(kind)
+    whole.run([init_state(DIMS)])
+    if kind == "one_chip":
+        assert records(second.trace) == records(whole.trace)
+    else:
+        # A resumed mesh deals its frontier out anew: a state two
+        # parents reach may be recorded under the other.
+        assert set(second.trace.export()[0].tolist()) == set(
+            whole.trace.export()[0].tolist())
+    assert_every_chain_reaches_a_root(second.trace)
+    return [first, second, whole]
+
+
 @pytest.mark.parametrize("case", [drained_before_a_checkpoint,
                                   drained_before_a_replay,
                                   drained_at_a_duration_stop,
-                                  drained_where_the_seen_set_grows],
+                                  drained_where_the_seen_set_grows,
+                                  settled_before_a_snapshot_among_owed_closes],
                          ids=lambda f: f.__name__)
 def test_nothing_is_owed_at_a_drain_point(case, kind, tmp_path, started):
     engines = case(kind, tmp_path)
@@ -300,6 +355,37 @@ def test_nothing_is_owed_at_a_drain_point(case, kind, tmp_path, started):
     # Every flush started was finished, one way or the other.
     assert sum(c["flush_overlapped"] + c["flush_drained"]
                for c in counts) == len(started)
+
+
+def test_a_violation_in_a_levels_first_call_replays_through_what_was_owed(
+        tmp_path, started):
+    """The violation lies in the FIRST call of its level: that call was
+    dispatched with the last flush of the level before it, and that
+    level's close, still owed; the replay walks through those records."""
+    s0, invariants = one_vote_short()
+    ev = str(tmp_path / "ev.jsonl")
+    eng = make_engine("one_chip", invariants=invariants, max_diameter=None,
+                      events_out=ev)
+    res = eng.run([s0])
+    assert res.stop_reason == "violation" and res.diameter >= 1
+    events = events_of(ev)
+    end = events[-1]
+    closed = [e for e in events if e["event"] == "level_complete"]
+    assert [e["level"] for e in closed] == list(range(res.diameter + 1))
+    # One call after the last whole level, and the violation is its.
+    assert end["chunk_calls"] - sum(e["chunk_calls"] for e in closed) == 1
+    names = [e["event"] for e in events]
+    assert names.index("violation") > max(
+        i for i, n in enumerate(names) if n == "level_complete")
+    assert (end["level_closes_overlapped"],
+            end["level_closes_drained"]) == (res.diameter + 1, 0)
+    # The violating call's own flush is the one drained.
+    assert (end["flush_overlapped"], end["flush_drained"]) == (
+        len(started) - 1, 1)
+    assert len(eng.trace.export()[0]) == res.distinct
+    steps = eng.replay(res.violation.fingerprint)
+    assert len(steps) == res.diameter + 2
+    assert_legal(steps, s0, last=res.violation.state)
 
 
 def test_a_degraded_resume_takes_no_record_of_the_run_that_died(kind,
@@ -321,6 +407,72 @@ def test_a_degraded_resume_takes_no_record_of_the_run_that_died(kind,
     assert_every_chain_reaches_a_root(eng.trace)
 
 
+def test_an_error_behind_a_boundary_takes_the_owed_close_with_it(tmp_path):
+    """``oom`` before the first call of the level after level 4, with
+    level 4's last flush and its close owed and no snapshot to go back
+    to: the dead attempt's log ends at level 3's close, and the retry
+    from the roots at half the batch ends with the whole run's counts
+    and a whole store."""
+    from raft_tla_tpu.resilience import faults
+    ev = str(tmp_path / "ev.jsonl")
+    faults.install("oom@level=4;chunk=1", hard=False)
+    try:
+        eng = make_engine("one_chip", min_batch=8, events_out=ev)
+        res = eng.run([init_state(DIMS)])
+    finally:
+        faults.clear()
+    assert eng.config.batch == 8
+    assert (res.levels, res.distinct) == (LEVELS, DISTINCT)
+    events = events_of(ev)
+    names = [e["event"] for e in events]
+    died = names.index("degraded")
+    assert [e["level"] for e in events[:died]
+            if e["event"] == "level_complete"] == [0, 1, 2, 3]
+    after = [e for e in events[died:] if e["event"] == "level_complete"]
+    assert [(e["level"], e["frontier_rows"]) for e in after] == list(
+        enumerate(LEVELS))
+    assert len(eng.trace.export()[0]) == res.distinct
+    assert_every_chain_reaches_a_root(eng.trace)
+    # The retry owed nothing at its end; the attempt that died, one each.
+    counts = bfs_mod.work_counts(eng.metrics)
+    assert counts["level_closes_overlapped"] \
+        + counts["level_closes_drained"] == 4 + 6
+
+
+@pytest.mark.parametrize("stop, want", [
+    (dict(max_diameter=3), ("diameter_budget", 3)),
+    # 377 distinct states by level 4's close, 485 and 605 after the first
+    # two calls of eleven that build level 5: the run stops inside the
+    # level, one call behind the one that level 4's close rode behind...
+    (dict(max_diameter=None, exit_conditions=(("distinct", 600),)),
+     ("distinct_budget", 4)),
+    # ... and here by that call itself.
+    (dict(max_diameter=None, exit_conditions=(("distinct", 400),)),
+     ("distinct_budget", 4)),
+], ids=["max_diameter", "exit_condition", "exit_condition_first_call"])
+def test_a_stop_at_or_behind_a_boundary_leaves_no_close_owed(stop, want,
+                                                              started,
+                                                              tmp_path):
+    ev = str(tmp_path / "ev.jsonl")
+    eng = make_engine("one_chip", events_out=ev, **stop)
+    res = eng.run([init_state(DIMS)])
+    assert (res.stop_reason, res.diameter) == want
+    events = events_of(ev)
+    end = events[-1]
+    closed = [e for e in events if e["event"] == "level_complete"]
+    # The last whole level's close is in the log, before the run's end.
+    assert [(e["level"], e["frontier_rows"]) for e in closed] == list(
+        enumerate(LEVELS[:res.diameter + 1]))
+    assert end["level_closes_overlapped"] + end["level_closes_drained"] \
+        == len(closed)
+    at_the_boundary = "max_diameter" in stop and stop["max_diameter"]
+    assert end["level_closes_drained"] == (1 if at_the_boundary else 0)
+    assert end["flush_overlapped"] + end["flush_drained"] == len(started)
+    assert end["flush_drained"] == 1
+    assert len(eng.trace.export()[0]) == res.distinct
+    assert_every_chain_reaches_a_root(eng.trace)
+
+
 # -- the counters -----------------------------------------------------------------
 
 def test_run_end_counts_every_flush_once(kind, started, tmp_path):
@@ -329,18 +481,29 @@ def test_run_end_counts_every_flush_once(kind, started, tmp_path):
     res = eng.run([init_state(DIMS)])
     end = run_end(ev)
     assert end["flush_overlapped"] + end["flush_drained"] == len(started)
-    # One drain a level; every other call of a level is overlapped.
-    assert end["flush_drained"] - (kind == "mesh") == res.diameter == 5
-    assert end["flush_overlapped"] == end["chunk_calls"] - 5 > 0
-    with open(ev, encoding="utf-8") as f:
-        levels = [json.loads(line) for line in f]
+    assert res.diameter == 5
     per_level = [(e["chunk_calls"], e["flush_overlapped"],
-                  e["flush_drained"]) for e in levels
+                  e["flush_drained"]) for e in events_of(ev)
                  if e["event"] == "level_complete" and e["level"] >= 1]
-    # The mesh expands a chip's share of a level, 4 rows a call.
-    calls = ([1, 1, 1, 4, 12] if kind == "mesh"
-             else [-(-n // 16) for n in LEVELS[:5]])
-    assert per_level == [(c, c - 1, 1) for c in calls]
+    closes = (end["level_closes_overlapped"], end["level_closes_drained"])
+    if kind == "mesh":
+        # One drain a level (and the roots'); every other call of a
+        # level is overlapped.  A chip's share of a level, 4 rows a call.
+        calls = [1, 1, 1, 4, 12]
+        assert end["flush_drained"] == 1 + 5
+        assert per_level == [(c, c - 1, 1) for c in calls]
+        assert closes == (0, 6)
+    else:
+        # A level's last flush rides behind the next level's first call
+        # and counts in that level's row; the one before ``max_diameter``
+        # stops the run is drained, after its level's row was read.
+        calls = [-(-n // 16) for n in LEVELS[:5]]
+        assert end["flush_drained"] == 1
+        assert per_level == [(calls[0], 0, 0)] + [(c, c, 0)
+                                                  for c in calls[1:]]
+        assert closes == (5, 1)
+    assert end["flush_overlapped"] == end["chunk_calls"] \
+        - end["flush_drained"] + (kind == "mesh") > 0
 
 
 # -- the cost of a call -----------------------------------------------------------
@@ -361,7 +524,10 @@ def test_a_calls_cost_runs_from_its_dispatch_to_its_statistics(kind,
     # Level 3's 52 parents: 4 calls, of 16 or of four chips' 4.
     eng = make_engine(kind, max_diameter=4)
     eng.run([init_state(DIMS)])
-    assert eng.metrics.counter_value("engine/flush_overlapped") == 3
+    # ... and on one chip the last flush of levels 1, 2 and 3 besides,
+    # each behind the next level's first call.
+    assert eng.metrics.counter_value("engine/flush_overlapped") == (
+        3 if kind == "mesh" else 6)
     assert eng._batch_ema >= 0.25
 
 
