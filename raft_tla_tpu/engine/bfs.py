@@ -91,11 +91,14 @@ _I32 = jnp.int32
 # (``budget_call_size``).  ``flush_*`` and ``level_closes_*``: trace
 # flushes and level closes by where their host half ran, behind a
 # dispatched call or with the device empty (``_TraceFlush``,
-# ``_LevelClose``).
+# ``_LevelClose``).  ``checkpoints_written`` and ``checkpoint_bytes_*``:
+# snapshots acknowledged, their arrays' bytes in memory and their files'
+# on disk (counted by ``checkpoint.save``).
 WORK_COUNTERS = ("chunk_calls", "passes", "inv_lanes", "ingest_calls",
                  "parents_expanded", "flush_overlapped", "flush_drained",
                  "level_closes_overlapped", "level_closes_drained",
-                 "deadline_calls", "probe_calls")
+                 "deadline_calls", "probe_calls", "checkpoints_written",
+                 "checkpoint_bytes_raw", "checkpoint_bytes_written")
 
 
 def work_counts(metrics, base: Optional[dict] = None) -> dict:
@@ -103,6 +106,21 @@ def work_counts(metrics, base: Optional[dict] = None) -> dict:
     base = base or {}
     return {k: int(metrics.counter_value("engine/" + k)) - base.get(k, 0)
             for k in WORK_COUNTERS}
+
+
+def resume_fields(resume) -> dict:
+    """``run_start``'s ``resume_level`` and ``resume_path`` on a resumed
+    run (nothing otherwise): the snapshot's level, by its file's name
+    where the run was given a path (``run_start`` is written before the
+    file is read) and by its own count where it was given the loaded
+    image, whose path is then None."""
+    if resume is None:
+        return {}
+    if isinstance(resume, str):
+        from . import checkpoint as ckpt_mod
+        return {"resume_level": ckpt_mod.level_of(resume),
+                "resume_path": resume}
+    return {"resume_level": int(resume.diameter), "resume_path": None}
 
 
 def budget_call_size(ch: int, remaining: float, batch_ema: float,
@@ -1184,6 +1202,7 @@ class BFSEngine:
             # States the run was given: 1 from Init, more from roots past
             # it; 0 on a resume, whose snapshot brings its own.
             roots=self._run_roots,
+            **resume_fields(resume),
             **start_caps,
             memory=device_memory_stats(),
             process=process_record().run_start())
@@ -1199,6 +1218,13 @@ class BFSEngine:
             try:
                 self._end_run(err)
             finally:
+                # The exception's traceback holds this frame: a reference
+                # kept here would be a cycle, and the failed run's device
+                # pools (the locals of ``_run_impl``, which the traceback
+                # holds too) would outlive the caller's handler until a
+                # collection, under a caller that runs again at once
+                # (a resume on this engine after a crash).
+                err = None
                 run_span.close()
                 if self.tracer.enabled:
                     self.tracer.write()
@@ -1550,7 +1576,10 @@ class BFSEngine:
         sw, B, Q = self._sw, self._B, self._Q
         if resume is not None:
             if isinstance(resume, str):
-                resume = ckpt_mod.load(resume)
+                # What ``--resume`` and a supervisor's restart pay before
+                # the restore: the file read, its pieces inflated.
+                with self.metrics.phase_timer("checkpoint_load"):
+                    resume = ckpt_mod.load(resume)
             if resume.dims != dims:
                 raise ValueError(
                     f"checkpoint dims {resume.dims} != engine dims {dims}")
@@ -1897,13 +1926,16 @@ class BFSEngine:
                     and (time.time() - last_ckpt
                          >= cfg.checkpoint_interval_seconds):
                 settle()        # the snapshot reads the store
-                with mt.phase_timer("checkpoint"):
-                    self._write_checkpoint(qcur, cur_count, pending, seen,
-                                           res, trace,
-                                           wall=time.time() - t0)
+                with mt.phase_timer("checkpoint") as ckpt_span:
+                    written = self._write_checkpoint(
+                        qcur, cur_count, pending, seen, res, trace,
+                        wall=time.time() - t0)
                 last_ckpt = time.time()
+                # The acknowledgement: the file is fsynced, renamed and
+                # its directory fsynced (``checkpoint.save``).
                 evlog.emit("checkpoint", level=res.diameter,
-                           distinct=res.distinct)
+                           distinct=res.distinct,
+                           seconds=round(ckpt_span.seconds, 6), **written)
             if cfg.max_diameter is not None \
                     and res.diameter >= cfg.max_diameter:
                 res.stop_reason = "diameter_budget"
@@ -2395,20 +2427,31 @@ class BFSEngine:
         return seen
 
     def _write_checkpoint(self, qcur, cur_count, pending, seen, res, trace,
-                          wall):
+                          wall) -> dict:
+        """One level-boundary snapshot, written and acknowledged, then
+        the retention; its parts are spans inside the caller's
+        ``checkpoint`` phase.  Returns what the ``checkpoint`` event
+        says of the file: ``path``, ``bytes_raw``, ``bytes_written``
+        (the last two left out where an injected fault skipped the
+        write) and ``parts``, the parts' seconds."""
         from . import checkpoint as ckpt_mod
         import os
+        mt = self.metrics
+        parts_base = mt.part_seconds()
         if self.config.record_trace:
-            tf, tp, ta = trace.export()
-            roots = dict(trace.roots)
+            with mt.part_timer("ckpt_export"):
+                tf, tp, ta = trace.export()
+                roots = dict(trace.roots)
         else:
             tf = np.empty(0, np.uint64)
             tp = np.empty(0, np.uint64)
             ta = np.empty(0, np.int32)
             roots = {}
-        seen_hi, seen_lo = fpset.to_host_keys(seen)
-        frontier, cleanup = pending.concat_with(
-            np.asarray(qcur[:cur_count]))
+        with mt.part_timer("ckpt_keys"):
+            seen_hi, seen_lo = fpset.to_host_keys(seen)
+        with mt.part_timer("ckpt_frontier"):
+            frontier, cleanup = pending.concat_with(
+                np.asarray(qcur[:cur_count]))
         ck = ckpt_mod.Checkpoint(
             dims=self.dims,
             frontier=frontier,
@@ -2418,17 +2461,22 @@ class BFSEngine:
             action_counts=dict(res.action_counts),
             wall_seconds=wall,
             trace_fps=tf, trace_parents=tp, trace_actions=ta, roots=roots)
+        path = os.path.join(self.config.checkpoint_dir,
+                            f"level_{res.diameter:05d}.npz")
         try:
-            ckpt_mod.save(os.path.join(self.config.checkpoint_dir,
-                                       f"level_{res.diameter:05d}.npz"), ck)
+            size = ckpt_mod.save(path, ck, metrics=mt)
         finally:
             cleanup()
         # Retention AFTER the successful write: the newest snapshot must
         # land before any older one is considered surplus.
-        removed = ckpt_mod.gc(self.config.checkpoint_dir,
-                              self.config.keep_checkpoints)
+        with mt.part_timer("ckpt_gc"):
+            removed = ckpt_mod.gc(self.config.checkpoint_dir,
+                                  self.config.keep_checkpoints)
         if removed:
-            self.metrics.counter("engine/checkpoints_gcd", removed)
+            mt.counter("engine/checkpoints_gcd", removed)
+        parts = phase_delta(mt.part_seconds(), parts_base)
+        return {"path": path, **(size or {}),
+                "parts": {k: round(v, 6) for k, v in parts.items()}}
 
     @staticmethod
     def _record(trace, cols, lo: int, hi: int) -> None:

@@ -20,6 +20,7 @@ still reaches roots discovered before the interruption.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import os
@@ -103,9 +104,10 @@ class Checkpoint:
     roots: Dict[int, PyState]
 
 
-def _level_of(path: str) -> Optional[int]:
+def level_of(path: str) -> Optional[int]:
     """BFS level encoded in a snapshot filename (single or piece), or
-    None for non-snapshot paths — fault-plan params match on it."""
+    None for non-snapshot paths — fault-plan params match on it, and a
+    resumed run's ``run_start`` says it (``resume_level``)."""
     name = os.path.basename(path)
     m = _PIECE_RE.match(name)
     if m:
@@ -146,15 +148,25 @@ def _inflate(ex, z, name: str, spec: dict) -> np.ndarray:
     return out
 
 
-def save(path: str, ckpt: Checkpoint) -> None:
-    """Atomically write ``ckpt`` to ``path`` (a ``.npz`` file)."""
+def save(path: str, ckpt: Checkpoint, metrics=None) -> Optional[dict]:
+    """Atomically write ``ckpt`` to ``path`` (a ``.npz`` file).  Returns
+    ``{"bytes_raw", "bytes_written"}``: the arrays' bytes as they lay in
+    memory, and the file's (None where an injected fault skipped the
+    write).  With ``metrics`` (the caller's ``MetricsRegistry``) its two
+    parts are spans there, ``ckpt_deflate`` and ``ckpt_write`` (the file,
+    its ``fsync``, the rename, the directory's ``fsync``), and the
+    snapshot is counted: ``engine/checkpoints_written``,
+    ``engine/checkpoint_bytes_raw``, ``engine/checkpoint_bytes_written``
+    (``WORK_COUNTERS`` of engine/bfs.py, so ``run_end`` carries them)."""
     from ..models.schema import state_width
+    part = (metrics.part_timer if metrics is not None
+            else lambda _name: contextlib.nullcontext())
     if faults.ACTIVE:
         m = _PIECE_RE.match(os.path.basename(path))
-        if faults.fire("ckpt_piece_missing", level=_level_of(path),
+        if faults.fire("ckpt_piece_missing", level=level_of(path),
                        piece=int(m.group(2)) if m else 0, path=path):
             # Injected: this controller died before its piece landed.
-            return
+            return None
     arrays = dict(
         frontier=np.ascontiguousarray(ckpt.frontier).astype(
             np.uint8, casting="safe", copy=False),
@@ -180,27 +192,37 @@ def save(path: str, ckpt: Checkpoint) -> None:
     meta["deflated"] = {k: {"shape": list(a.shape), "dtype": a.dtype.str}
                         for k, a in arrays.items()}
     members = {}
-    with _pool() as ex:
+    with part("ckpt_deflate"), _pool() as ex:
         for k, a in arrays.items():
             members[k + "__z"], members[k + "__zoff"] = _deflate(ex, a)
-    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-    tmp = path + ".tmp"
-    with open(tmp, "wb") as f:
-        np.savez(f, meta=np.frombuffer(json.dumps(meta).encode(), np.uint8),
-                 roots=np.frombuffer(pickle.dumps(ckpt.roots), np.uint8),
-                 **members)
-        f.flush()
-        os.fsync(f.fileno())     # the rename must never land a torn file
-    if faults.ACTIVE:
-        # The torn-write crash window: tmp is complete on disk, the
-        # rename has not happened — exactly what a power cut here leaves.
-        faults.fire("ckpt_torn_write", level=_level_of(path), path=path)
-    os.replace(tmp, path)
-    dfd = os.open(os.path.dirname(os.path.abspath(path)), os.O_RDONLY)
-    try:
-        os.fsync(dfd)
-    finally:
-        os.close(dfd)
+    with part("ckpt_write"):
+        os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+        tmp = path + ".tmp"
+        with open(tmp, "wb") as f:
+            np.savez(f,
+                     meta=np.frombuffer(json.dumps(meta).encode(), np.uint8),
+                     roots=np.frombuffer(pickle.dumps(ckpt.roots), np.uint8),
+                     **members)
+            f.flush()
+            os.fsync(f.fileno())  # the rename must never land a torn file
+        if faults.ACTIVE:
+            # The torn-write crash window: tmp is complete on disk, the
+            # rename has not happened — exactly what a power cut here
+            # leaves.
+            faults.fire("ckpt_torn_write", level=level_of(path), path=path)
+        os.replace(tmp, path)
+        dfd = os.open(os.path.dirname(os.path.abspath(path)), os.O_RDONLY)
+        try:
+            os.fsync(dfd)
+        finally:
+            os.close(dfd)
+        size = {"bytes_raw": sum(a.nbytes for a in arrays.values()),
+                "bytes_written": os.path.getsize(path)}
+    if metrics is not None:
+        metrics.counter("engine/checkpoints_written")
+        for name, n in size.items():
+            metrics.counter("engine/checkpoint_" + name, n)
+    return size
 
 
 # Multi-host runs write one PIECE per controller (its frontier slice +

@@ -68,6 +68,10 @@ SCOPE_PREFIX = "scope/"
 # around a run, on the registry the engines share, and seconds of theirs
 # under ``phase/`` would land in whatever run was open meanwhile.
 SERVE_PREFIX = "serve/"
+# Spans INSIDE a phase (the parts of a snapshot's write under
+# ``checkpoint``) observe under a prefix of their own, for the same
+# reason turned round: their seconds are already in the phase's.
+PART_PREFIX = "part/"
 
 # What an annotation's name starts with in a profiler capture.
 ANNOTATION_PREFIX = "raft."
@@ -245,6 +249,19 @@ class MetricsRegistry:
         """A span that contains phases (``run``, ``replay``): the same
         sinks, its seconds under ``scope/<name>``."""
         return self._spanning(SCOPE_PREFIX, name, args)
+
+    def part_timer(self, name: str, **args):
+        """A span inside a phase (``ckpt_export`` ... under
+        ``checkpoint``): the same sinks, its seconds under
+        ``part/<name>``, so ``phase_seconds`` stays a partition."""
+        return self._spanning(PART_PREFIX, name, args)
+
+    def part_seconds(self) -> Dict[str, float]:
+        """{part name: accumulated seconds}, as ``phase_seconds``."""
+        with self._lock:
+            return {name[len(PART_PREFIX):]: h.total
+                    for name, h in self._histograms.items()
+                    if name.startswith(PART_PREFIX)}
 
     def serve_timer(self, name: str, **args):
         """A span of the serving layer (``job``, ``job_setup``,

@@ -1795,7 +1795,9 @@ class MeshBFSEngine:
             path = os.path.join(self.config.checkpoint_dir,
                                 f"level_{res.diameter:05d}.npz")
         try:
-            ckpt_mod.save(path, ck)
+            # Counted there (this controller's piece); the parts before
+            # the deflate are spans of the one-chip engine only.
+            ckpt_mod.save(path, ck, metrics=self.metrics)
         finally:
             front_cleanup()
         # Retention after the successful write (engine/bfs.py rule).

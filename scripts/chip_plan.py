@@ -17,7 +17,9 @@ for the checkout itself, or a git-ignored copy of another commit such as
 ``root``'s program, ``"stall"`` the same run under ``env``'s
 ``FAULT_PLAN`` (``scripts/stall_bench.py``), ``"check"`` the CLI's ``check``
 with ``workload`` as its arguments (one string, split at spaces; no result
-line: what it printed of the run's totals is shown), ``"control:<name>"``
+line: what it printed of the run's totals is shown; ``$RUN_TMP`` in it
+stands for a directory made for this run under ``TMPDIR`` and removed
+after it, so a run's snapshots never meet another's), ``"control:<name>"``
 ``benchmark/tests/controls_mesh.py <name>`` around the same run, and
 ``"control:swarm:<name>"`` ``benchmark/tests/controls_swarm.py <name>``
 (``"control:safety:<name>"`` likewise; a result of ``correct: false`` is
@@ -38,8 +40,10 @@ from __future__ import annotations
 
 import json
 import os
+import shutil
 import subprocess
 import sys
+import tempfile
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -126,6 +130,10 @@ def main() -> int:
         environ.pop("BENCH_RUN", None)
         for key, value in (env or {}).items():
             environ[key] = value.replace("$ROOT", ROOT)
+        run_tmp = None
+        if "$RUN_TMP" in workload:
+            run_tmp = tempfile.mkdtemp(prefix=f"chip_plan_{tag}_")
+            workload = workload.replace("$RUN_TMP", run_tmp)
         t0 = time.time()
         log = os.path.join(OUT, tag + ".log")
         environ["PYTHONUNBUFFERED"] = "1"
@@ -138,6 +146,9 @@ def main() -> int:
             except subprocess.TimeoutExpired:
                 rc = -9
                 f.write(f"\n==== KILLED at its limit of {limit:.0f}s ====\n")
+            finally:
+                if run_tmp:
+                    shutil.rmtree(run_tmp, ignore_errors=True)
         wall = time.time() - t0
         with open(log, encoding="utf-8", errors="replace") as f:
             said = f.read()

@@ -267,4 +267,4 @@ def test_the_rule_that_sizes_a_budgeted_call(ch, remaining, ema, calls,
 
 
 def test_the_new_counters_are_work_counters():
-    assert bfs_mod.WORK_COUNTERS[-2:] == ("deadline_calls", "probe_calls")
+    assert {"deadline_calls", "probe_calls"} <= set(bfs_mod.WORK_COUNTERS)

@@ -247,7 +247,10 @@ def test_loop_counters_equal_a_hand_count(noleader, tmp_path):
         "flush_drained": 2,
         "level_closes_overlapped": 8, "level_closes_drained": 2,
         # No duration budget: no call is a probe or sized by a deadline.
-        "deadline_calls": 0, "probe_calls": 0}
+        "deadline_calls": 0, "probe_calls": 0,
+        # No checkpoint directory: no snapshot.
+        "checkpoints_written": 0, "checkpoint_bytes_raw": 0,
+        "checkpoint_bytes_written": 0}
 
 
 def test_a_levels_close_lies_behind_the_next_levels_first_call(verdict):
@@ -317,13 +320,19 @@ def test_level_complete_says_the_same_behind_a_call_as_with_the_device_empty(
               "unattributed_seconds", "memory"}
     where = {"flush_overlapped", "flush_drained", "level_closes_overlapped",
              "level_closes_drained"}
+    # ... and for the snapshots, which only the settled run writes.
+    saves = {"checkpoints_written", "checkpoint_bytes_raw",
+             "checkpoint_bytes_written"}
 
     def closes(events):
-        return [{k: v for k, v in e.items() if k not in clocks | where}
+        return [{k: v for k, v in e.items()
+                 if k not in clocks | where | saves}
                 for e in events if e["event"] == "level_complete"]
 
     assert closes(riding) == closes(settled)
     assert len(closes(riding)) == n_levels
+    assert (riding[-1]["checkpoints_written"],
+            settled[-1]["checkpoints_written"]) == (0, n_levels)
     assert {"level", "frontier_rows", "distinct", "generated",
             "generated_by_family", "chunk_calls", "passes",
             "parents_expanded", "ingest_calls"} <= set(closes(riding)[0])
