@@ -15,9 +15,10 @@ Three halves, one pass:
   count; any *other* integer narrowing is a WARNING, because that is
   exactly how a lane silently loses bits).
 
-- **Host-loop AST lint**: the steady-state loop (``engine/chunk.py``
-  and ``_run_impl`` in ``engine/bfs.py``) must fetch device data only
-  at sanctioned sync points; any other blocking device read
+- **Host-loop AST lint**: the steady-state loop (``engine/chunk.py``,
+  and ``_run_impl`` with its ``_run_levels`` in ``engine/bfs.py``) must
+  fetch device data only at sanctioned sync points; any other blocking
+  device read
   (``np.asarray`` / ``jax.device_get`` / ``block_until_ready``) inside
   a loop serializes the dispatch pipeline.
   Sanctioned means: under a ``with <registry>.phase_timer(...)`` block
@@ -482,7 +483,7 @@ def _default_targets() -> List[Tuple[str, Optional[Tuple[str, ...]]]]:
     eng = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "engine")
     return [(os.path.join(eng, "chunk.py"), None),
-            (os.path.join(eng, "bfs.py"), ("_run_impl",))]
+            (os.path.join(eng, "bfs.py"), ("_run_impl", "_run_levels"))]
 
 
 # ---------------------------------------------------------------------------

@@ -47,6 +47,7 @@ import contextlib
 import dataclasses
 import functools
 import os
+import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -93,19 +94,28 @@ _I32 = jnp.int32
 # dispatched call or with the device empty (``_TraceFlush``,
 # ``_LevelClose``).  ``checkpoints_written`` and ``checkpoint_bytes_*``:
 # snapshots acknowledged, their arrays' bytes in memory and their files'
-# on disk (counted by ``checkpoint.save``).
+# on disk (counted by ``checkpoint.save``).  ``checkpoints_overlapped``
+# and ``checkpoints_drained``: snapshots by whether their file was made
+# while the loop went on or the loop waited for it, ``checkpoint_wait_s``
+# the seconds it waited (``_SnapshotSave``).
 WORK_COUNTERS = ("chunk_calls", "passes", "inv_lanes", "ingest_calls",
                  "parents_expanded", "flush_overlapped", "flush_drained",
                  "level_closes_overlapped", "level_closes_drained",
                  "deadline_calls", "probe_calls", "checkpoints_written",
-                 "checkpoint_bytes_raw", "checkpoint_bytes_written")
+                 "checkpoint_bytes_raw", "checkpoint_bytes_written",
+                 "checkpoints_overlapped", "checkpoints_drained",
+                 "checkpoint_wait_s")
 
 
 def work_counts(metrics, base: Optional[dict] = None) -> dict:
-    """{name: count} of ``WORK_COUNTERS``, less an earlier reading."""
+    """{name: count} of ``WORK_COUNTERS``, less an earlier reading; a
+    name that ends in ``_s`` counts seconds."""
     base = base or {}
-    return {k: int(metrics.counter_value("engine/" + k)) - base.get(k, 0)
-            for k in WORK_COUNTERS}
+    out = {}
+    for k in WORK_COUNTERS:
+        n = metrics.counter_value("engine/" + k) - base.get(k, 0)
+        out[k] = round(n, 6) if k.endswith("_s") else int(n)
+    return out
 
 
 def resume_fields(resume) -> dict:
@@ -313,6 +323,172 @@ class _LevelClose:
             # current level's spans (atomic rewrite, off the hot loop).
             eng.tracer.write()
         return span.seconds
+
+
+class _SnapshotSave:
+    """A level-boundary snapshot on its way to the disk, in two halves
+    like the flush and the close, so that the device need not wait for
+    the file.  ``capture``, at the boundary, on the loop's thread, inside
+    the ``checkpoint`` phase, reads what the next call donates or the
+    next level changes and nothing else: the trace store's records and
+    roots, the seen-set's two arrays as they stand, the level's rows, the
+    counts.  The image is the boundary's.  ``_commit`` makes the file of
+    it on a thread of this save's own, which calls nothing of jax: the
+    keys masked and sorted (``ckpt_sort``), ``checkpoint.save`` (the
+    deflate; the file, its ``fsync``, the rename, the directory's
+    ``fsync``; the counters), the retention, and then the ``checkpoint``
+    event, the acknowledgement.  Its spans are that thread's and lie
+    after the phase's end.
+
+    At most one save is in flight.  ``finish`` takes it off the books:
+    the loop calls it with ``wait=False`` after every call's accounting,
+    which finds a commit that ended behind the calls
+    (``checkpoints_overlapped``), and waits (``checkpoints_drained``,
+    ``checkpoint_wait_s``; a ``checkpoint`` phase too: the device waits
+    for a save) before the next capture and where the run ends, by an
+    exception too (``_run_impl``).  So acknowledgements come in level
+    order and no ``run()`` leaves a file half made.  A boundary followed
+    by the run's end waits at once: the save as it was before it had two
+    halves.  Either way a commit that raised is raised from the loop, and
+    nothing acknowledges that level.
+
+    The event's ``seconds`` run from the start of the capture to the
+    acknowledgement, its ``stall_seconds`` are what the loop was held
+    for, the capture and any wait: ``phase_seconds.checkpoint`` is their
+    sum.  So a save the loop waits for is acknowledged by the loop, when
+    the wait is over, and every other by its own thread."""
+
+    def __init__(self, engine):
+        self._eng, self._thread = engine, None
+        self._lock = threading.Lock()
+
+    def capture(self, qcur, cur_count, pending, seen, res, trace,
+                wall) -> None:
+        assert self._thread is None, "a snapshot is in flight already"
+        eng = self._eng
+        mt, cfg = eng.metrics, eng.config
+        self._t0 = time.perf_counter()
+        self._parts_base = mt.part_seconds()
+        with mt.phase_timer("checkpoint") as span:
+            # The copies to the host all start now and move side by side
+            # (and beside the store's export); each part waits for its own.
+            rows = qcur[:cur_count]
+            for arr in (seen.hi, seen.lo, rows):
+                arr.copy_to_host_async()
+            if cfg.record_trace:
+                with mt.part_timer("ckpt_export"):
+                    tf, tp, ta = trace.export()
+                    roots = dict(trace.roots)
+            else:
+                tf = np.empty(0, np.uint64)
+                tp = np.empty(0, np.uint64)
+                ta = np.empty(0, np.int32)
+                roots = {}
+            with mt.part_timer("ckpt_keys"):
+                # The table as it stands, empty slots and all.  (Where
+                # this is a view of the device's buffer, on a CPU
+                # backend, the view keeps the next insert from writing
+                # the buffer in place.)
+                table = np.asarray(seen.hi), np.asarray(seen.lo)
+            with mt.part_timer("ckpt_frontier"):
+                frontier, cleanup = pending.concat_with(np.asarray(rows))
+            image = dict(
+                dims=eng.dims, frontier=frontier,
+                distinct=res.distinct, generated=res.generated,
+                diameter=res.diameter, levels=tuple(res.levels),
+                action_counts=dict(res.action_counts), wall_seconds=wall,
+                trace_fps=tf, trace_parents=tp, trace_actions=ta,
+                roots=roots)
+        self._stall = span.seconds
+        self._done = self._waited = False
+        self._error = self._written = None
+        # What the acknowledgement says besides the file's own fields,
+        # and the log it goes to: the run's, as long as the run lasts.
+        self._ack = (eng._evlog, res.diameter, res.distinct)
+        path = os.path.join(cfg.checkpoint_dir,
+                            f"level_{res.diameter:05d}.npz")
+        self._thread = threading.Thread(
+            target=self._commit, name="raft-snapshot",
+            args=(path, image, table, cleanup))
+        self._thread.start()
+
+    def _commit(self, path, image, table, cleanup) -> None:
+        from . import checkpoint as ckpt_mod
+        eng = self._eng
+        mt, cfg = eng.metrics, eng.config
+        written = error = None
+        try:
+            try:
+                with mt.part_timer("ckpt_sort"):
+                    seen_hi, seen_lo = fpset.sorted_keys(*table)
+                size = ckpt_mod.save(
+                    path, ckpt_mod.Checkpoint(
+                        seen_hi=seen_hi, seen_lo=seen_lo, **image),
+                    metrics=mt)
+            finally:
+                cleanup()
+            # Retention AFTER the successful write: the newest snapshot
+            # must land before any older one is considered surplus.
+            with mt.part_timer("ckpt_gc"):
+                removed = ckpt_mod.gc(cfg.checkpoint_dir,
+                                      cfg.keep_checkpoints)
+            if removed:
+                mt.counter("engine/checkpoints_gcd", removed)
+            parts = phase_delta(mt.part_seconds(), self._parts_base)
+            # ``bytes_raw`` and ``bytes_written`` left out where an
+            # injected fault skipped the write.
+            written = {"path": path, **(size or {}),
+                       "parts": {k: round(v, 6) for k, v in parts.items()}}
+        except BaseException as e:      # the loop raises it (``finish``)
+            error = e
+        with self._lock:
+            self._written, self._error = written, error
+            if error is None and not self._waited:
+                self._acknowledge()
+            self._done = True
+
+    def _acknowledge(self) -> None:
+        """The ``checkpoint`` event: the file is fsynced, renamed and its
+        directory fsynced, the retention has run."""
+        evlog, level, distinct = self._ack
+        evlog.emit("checkpoint", level=level, distinct=distinct,
+                   seconds=round(time.perf_counter() - self._t0, 6),
+                   stall_seconds=round(self._stall, 6), **self._written)
+
+    def finish(self, wait: bool = True, quiet: bool = False) -> None:
+        """Take the save in flight off the books if its commit has ended,
+        or wait for that (``wait``).  Raises what the commit raised,
+        unless another exception is on its way out already (``quiet``)."""
+        thread = self._thread
+        if thread is None:
+            return
+        mt = self._eng.metrics
+        with self._lock:
+            done = self._done
+            if not done:
+                if not wait:
+                    return
+                self._waited = True
+        if done:
+            thread.join()
+            mt.counter("engine/checkpoints_overlapped")
+        else:
+            with mt.phase_timer("checkpoint") as span:
+                thread.join()
+            self._stall += span.seconds
+            mt.counter("engine/checkpoints_drained")
+            mt.counter("engine/checkpoint_wait_s", span.seconds)
+            if self._error is None:
+                self._acknowledge()
+        self._thread = None
+        error, self._error = self._error, None
+        if error is not None:
+            if not quiet:
+                raise error
+            import sys as _sys
+            print(f"snapshot of level {self._ack[1]} failed as the run "
+                  f"ended: {type(error).__name__}: {error}",
+                  file=_sys.stderr)
 
 
 @dataclasses.dataclass
@@ -1557,7 +1733,8 @@ class BFSEngine:
                      "seen_skew": skew.get("seen_skew"),
                      "shard_frontier": skew.get("shard_frontier")}
         now = work_counts(self.metrics)
-        work = {k: n - self._work_level_base[k] for k, n in now.items()}
+        work = {k: round(n - self._work_level_base[k], 6)
+                for k, n in now.items()}
         self._work_level_base = now
         return dict(
             level=res.diameter,
@@ -1571,6 +1748,19 @@ class BFSEngine:
 
     def _run_impl(self, init_states: Optional[List[PyState]] = None,
                   resume=None) -> EngineResult:
+        """One attempt at the run (``_run_levels``).  However it ends, no
+        snapshot is left in flight: a return has waited for it, and an
+        exception on its way out waits here, so that whoever looks at the
+        directory next (``checkpoint.latest`` of a supervisor, or of
+        ``_run_degradable``) finds it quiet."""
+        saves = _SnapshotSave(self)
+        try:
+            return self._run_levels(init_states, resume, saves)
+        except BaseException:
+            saves.finish(quiet=True)
+            raise
+
+    def _run_levels(self, init_states, resume, saves) -> EngineResult:
         from . import checkpoint as ckpt_mod
         dims, cfg = self.dims, self.config
         sw, B, Q = self._sw, self._B, self._Q
@@ -1925,17 +2115,13 @@ class BFSEngine:
                     and res.diameter != skip_ckpt_level \
                     and (time.time() - last_ckpt
                          >= cfg.checkpoint_interval_seconds):
-                settle()        # the snapshot reads the store
-                with mt.phase_timer("checkpoint") as ckpt_span:
-                    written = self._write_checkpoint(
-                        qcur, cur_count, pending, seen, res, trace,
-                        wall=time.time() - t0)
+                # One save in flight, acknowledged in level order; then
+                # the store as the level left it, which the capture reads.
+                saves.finish()
+                settle()
+                saves.capture(qcur, cur_count, pending, seen, res, trace,
+                              wall=time.time() - t0)
                 last_ckpt = time.time()
-                # The acknowledgement: the file is fsynced, renamed and
-                # its directory fsynced (``checkpoint.save``).
-                evlog.emit("checkpoint", level=res.diameter,
-                           distinct=res.distinct,
-                           seconds=round(ckpt_span.seconds, 6), **written)
             if cfg.max_diameter is not None \
                     and res.diameter >= cfg.max_diameter:
                 res.stop_reason = "diameter_budget"
@@ -2106,6 +2292,9 @@ class BFSEngine:
                         # The device half only: everything below may
                         # raise, branch or dispatch with the flush owed.
                         flush.start(((tbuf, tcount),))
+                    # A snapshot whose file was made behind this call or
+                    # an earlier one; a commit that failed fails the run.
+                    saves.finish(wait=False)
                     if n_ovf:
                         raise RuntimeError(
                             f"{n_ovf} successors exceeded fixed-width "
@@ -2218,8 +2407,9 @@ class BFSEngine:
             pending, spill_next = spill_next, pending
 
         # The run stops: what follows reads the store (a replay) or ends
-        # the run.
+        # the run, and no run ends with a snapshot unacknowledged.
         settle()
+        saves.finish()
         res.wall_seconds = time.time() - t0
         if resume is None:
             self._keep_capacity(seen)
@@ -2425,58 +2615,6 @@ class BFSEngine:
         the ``fpset_resize`` event."""
         seen, *self._rebuild_counts = fpset.from_host_keys(hi, lo, capacity)
         return seen
-
-    def _write_checkpoint(self, qcur, cur_count, pending, seen, res, trace,
-                          wall) -> dict:
-        """One level-boundary snapshot, written and acknowledged, then
-        the retention; its parts are spans inside the caller's
-        ``checkpoint`` phase.  Returns what the ``checkpoint`` event
-        says of the file: ``path``, ``bytes_raw``, ``bytes_written``
-        (the last two left out where an injected fault skipped the
-        write) and ``parts``, the parts' seconds."""
-        from . import checkpoint as ckpt_mod
-        import os
-        mt = self.metrics
-        parts_base = mt.part_seconds()
-        if self.config.record_trace:
-            with mt.part_timer("ckpt_export"):
-                tf, tp, ta = trace.export()
-                roots = dict(trace.roots)
-        else:
-            tf = np.empty(0, np.uint64)
-            tp = np.empty(0, np.uint64)
-            ta = np.empty(0, np.int32)
-            roots = {}
-        with mt.part_timer("ckpt_keys"):
-            seen_hi, seen_lo = fpset.to_host_keys(seen)
-        with mt.part_timer("ckpt_frontier"):
-            frontier, cleanup = pending.concat_with(
-                np.asarray(qcur[:cur_count]))
-        ck = ckpt_mod.Checkpoint(
-            dims=self.dims,
-            frontier=frontier,
-            seen_hi=seen_hi, seen_lo=seen_lo,
-            distinct=res.distinct, generated=res.generated,
-            diameter=res.diameter, levels=tuple(res.levels),
-            action_counts=dict(res.action_counts),
-            wall_seconds=wall,
-            trace_fps=tf, trace_parents=tp, trace_actions=ta, roots=roots)
-        path = os.path.join(self.config.checkpoint_dir,
-                            f"level_{res.diameter:05d}.npz")
-        try:
-            size = ckpt_mod.save(path, ck, metrics=mt)
-        finally:
-            cleanup()
-        # Retention AFTER the successful write: the newest snapshot must
-        # land before any older one is considered surplus.
-        with mt.part_timer("ckpt_gc"):
-            removed = ckpt_mod.gc(self.config.checkpoint_dir,
-                                  self.config.keep_checkpoints)
-        if removed:
-            mt.counter("engine/checkpoints_gcd", removed)
-        parts = phase_delta(mt.part_seconds(), parts_base)
-        return {"path": path, **(size or {}),
-                "parts": {k: round(v, 6) for k, v in parts.items()}}
 
     @staticmethod
     def _record(trace, cols, lo: int, hi: int) -> None:

@@ -68,9 +68,11 @@ SCOPE_PREFIX = "scope/"
 # around a run, on the registry the engines share, and seconds of theirs
 # under ``phase/`` would land in whatever run was open meanwhile.
 SERVE_PREFIX = "serve/"
-# Spans INSIDE a phase (the parts of a snapshot's write under
-# ``checkpoint``) observe under a prefix of their own, for the same
-# reason turned round: their seconds are already in the phase's.
+# The parts of a piece of work that is no partition of the loop's wall
+# (a snapshot's: those of its capture lie inside the ``checkpoint``
+# phase, those of its commit on a thread of their own) observe under a
+# prefix of their own, for the same reason turned round: their seconds
+# are in a phase's already, or in no phase of the loop's at all.
 PART_PREFIX = "part/"
 
 # What an annotation's name starts with in a profiler capture.
@@ -251,9 +253,10 @@ class MetricsRegistry:
         return self._spanning(SCOPE_PREFIX, name, args)
 
     def part_timer(self, name: str, **args):
-        """A span inside a phase (``ckpt_export`` ... under
-        ``checkpoint``): the same sinks, its seconds under
-        ``part/<name>``, so ``phase_seconds`` stays a partition."""
+        """A part of a snapshot (``ckpt_export`` ... inside the
+        ``checkpoint`` phase, ``ckpt_sort`` ... on the save's own
+        thread): the same sinks, its seconds under ``part/<name>``, so
+        ``phase_seconds`` stays a partition."""
         return self._spanning(PART_PREFIX, name, args)
 
     def part_seconds(self) -> Dict[str, float]:

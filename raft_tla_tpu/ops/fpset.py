@@ -443,15 +443,20 @@ def contains(s: FPSet, qhi, qlo):
     return found[:k]
 
 
-def to_host_keys(s: FPSet) -> Tuple[np.ndarray, np.ndarray]:
-    """Materialize the stored keys host-side, lex-sorted (hi, lo) for a
-    deterministic checkpoint layout."""
-    hi = np.asarray(s.hi)
-    lo = np.asarray(s.lo)
+def sorted_keys(hi: np.ndarray, lo: np.ndarray
+                ) -> Tuple[np.ndarray, np.ndarray]:
+    """The keys of a table fetched as it stands (its two arrays, empty
+    slots and all), lex-sorted (hi, lo) for a deterministic checkpoint
+    layout.  numpy alone."""
     real = ~((hi == SENTINEL) & (lo == SENTINEL))
     hi, lo = hi[real], lo[real]
     order = np.lexsort((lo, hi))
     return hi[order], lo[order]
+
+
+def to_host_keys(s: FPSet) -> Tuple[np.ndarray, np.ndarray]:
+    """Materialize the stored keys host-side, sorted (``sorted_keys``)."""
+    return sorted_keys(np.asarray(s.hi), np.asarray(s.lo))
 
 
 def rebuild_piece(s: FPSet, status, qhi, qlo, valid):

@@ -14,7 +14,8 @@ for the checkout itself, or a git-ignored copy of another commit such as
 ``scripts/ops_per_pass.py`` instead, ``"probe"`` ``scripts/setup_probe.py``,
 ``"record"`` ``scripts/record_capture.py``, ``"waitprobe"``
 ``scripts/served_wait_probe.py``, ``"cost"`` ``scripts/call_cost.py`` on
-``root``'s program, ``"stall"`` the same run under ``env``'s
+``root``'s program, ``"d2h"`` ``scripts/d2h_probe.py`` (what a snapshot's
+capture moves from the device, timed), ``"stall"`` the same run under ``env``'s
 ``FAULT_PLAN`` (``scripts/stall_bench.py``), ``"check"`` the CLI's ``check``
 with ``workload`` as its arguments (one string, split at spaces; no result
 line: what it printed of the run's totals is shown; ``$RUN_TMP`` in it
@@ -59,7 +60,7 @@ SHOWN = ("stages:", "idle ", "window compiles", "trace:", "trace reduced",
          "  runs so far", "variant:", "window generated", "host line",
          "served jobs", "a hit", "window by", "benchmark:",
          '{"probe"', "calls", "gc:", "rehash:", "round trip:",
-         "slow call:", "stall:", "fault plan", '{"checkout"')
+         "slow call:", "stall:", "fault plan", '{"checkout"', '{"what"')
 
 
 def command(root: str, workload: str, seed: int, trace: int, how: str):
@@ -76,6 +77,9 @@ def command(root: str, workload: str, seed: int, trace: int, how: str):
     if how == "cost":
         return [sys.executable, os.path.join(ROOT, "scripts",
                                              "call_cost.py"), root]
+    if how == "d2h":
+        return [sys.executable, os.path.join(ROOT, "scripts",
+                                             "d2h_probe.py")]
     if how == "check":
         return [sys.executable, "-m", "raft_tla_tpu", "check",
                 *workload.split()]

@@ -12,9 +12,10 @@ opens no span (copy it there, beside this PR's ``benchmark/`` files): the
 number to compare between two commits that must run the same program.
 
 Beside the counts it prints what the window's own ``run_end`` event
-counted (chunk calls, passes, trace flushes and level closes
+counted (chunk calls, passes, trace flushes, level closes and snapshots
 overlapped and drained, the mesh's insert windows by chip and what its
-resume overlapped, compiles by span), the pools' fill,
+resume overlapped, compiles by span; one ``run_end`` a run where the
+window holds several, and its ``checkpoint`` events), the pools' fill,
 the window's phases and the per-layer metrics as ``run.py --trace 1``
 reduces them.
 
@@ -151,16 +152,27 @@ def main() -> int:
         if tab:
             out["passes_by_raft_account"] = tab["passes"]
         # What the window's own ``run_end`` counted (the loop's work
-        # counters, its compiles by span), the pools' fill, and the
-        # per-layer metrics as ``run.py --trace 1`` reduces them.
-        end = next((e for e in reversed(run.get("events", []))
-                    if e["event"] == "run_end"), {})
-        out["run_end"] = {k: end[k] for k in (
+        # counters, its compiles by span; one a run where the window
+        # holds several, as ``kill-resume``'s two), its snapshots as
+        # acknowledged, the pools' fill, and the per-layer metrics as
+        # ``run.py --trace 1`` reduces them.
+        ends = [{k: e[k] for k in (
             "chunk_calls", "passes", "ingest_calls", "flush_overlapped",
             "flush_drained", "level_closes_overlapped",
-            "level_closes_drained", "chip_insert_windows", "restore_pieces",
-            "restore_rounds", "restore_lane_rounds", "restore_host_s",
-            "restore_wait_s", "compiles") if k in end}
+            "level_closes_drained", "checkpoints_written",
+            "checkpoints_overlapped", "checkpoints_drained",
+            "checkpoint_wait_s", "parents_expanded", "chip_insert_windows",
+            "restore_pieces", "restore_rounds", "restore_lane_rounds",
+            "restore_host_s", "restore_wait_s", "compiles") if k in e}
+            for e in run.get("events", []) if e["event"] == "run_end"]
+        out["run_end"] = ends[-1] if ends else {}
+        if len(ends) > 1:
+            out["run_ends"] = ends
+        saved = [{k: e[k] for k in ("level", "seconds", "stall_seconds",
+                                    "bytes_raw", "parts") if k in e}
+                 for e in run.get("events", []) if e["event"] == "checkpoint"]
+        if saved:
+            out["checkpoints"] = saved
         out["fill"] = run.get("counters")
         out["phases"] = {k: round(v, 4) for k, v in run["phases"].items()}
         import jax

@@ -250,7 +250,8 @@ def test_loop_counters_equal_a_hand_count(noleader, tmp_path):
         "deadline_calls": 0, "probe_calls": 0,
         # No checkpoint directory: no snapshot.
         "checkpoints_written": 0, "checkpoint_bytes_raw": 0,
-        "checkpoint_bytes_written": 0}
+        "checkpoint_bytes_written": 0, "checkpoints_overlapped": 0,
+        "checkpoints_drained": 0, "checkpoint_wait_s": 0}
 
 
 def test_a_levels_close_lies_behind_the_next_levels_first_call(verdict):
@@ -322,7 +323,8 @@ def test_level_complete_says_the_same_behind_a_call_as_with_the_device_empty(
              "level_closes_drained"}
     # ... and for the snapshots, which only the settled run writes.
     saves = {"checkpoints_written", "checkpoint_bytes_raw",
-             "checkpoint_bytes_written"}
+             "checkpoint_bytes_written", "checkpoints_overlapped",
+             "checkpoints_drained", "checkpoint_wait_s"}
 
     def closes(events):
         return [{k: v for k, v in e.items()
